@@ -1,8 +1,7 @@
 """Analytic roofline for the MFU benchmark configs (v5e single chip).
 
-The round-4 verdict's ask: either a measured flagship MFU >= 0.42 or a
-committed roofline analysis locating the remaining gap. This is the
-analysis, executable: for each benchmark config it derives
+Where would the remaining gap to MFU 1.0 live? This is the analysis,
+executable: for each benchmark config it derives
 
 - the **compute floor**: analytic model FLOPs / peak bf16 FLOP/s (the
   step time at MFU 1.0 — same FLOP accounting as mfu_transformer.py, so
@@ -11,11 +10,11 @@ analysis, executable: for each benchmark config it derives
   optimizer moments, activations, logits) / peak HBM bandwidth;
 - the implied **MFU ceiling** = compute_floor / max(compute, hbm) — what
   a perfectly overlapped execution could reach; and
-- against the newest measured row in tpu_results.jsonl (when present),
-  the **efficiency gap**: measured_step / max(floor) — the factor that
+- against the newest measured row in the results log bench.py appends
+  to (when present), the **efficiency gap**: measured_step / max(floor) — the factor that
   is kernel/overlap inefficiency rather than physics.
 
-The verdict-facing conclusion this model supports: at flagship scale
+The conclusion this model supports: at flagship scale
 (135M params, batch 8, seq 1024) the step is COMPUTE-dominated on paper
 (HBM floor ~1/3 of the compute floor), so a sub-0.9 MFU is NOT
 "memory-bound and irreducible" — the gap lives in kernel efficiency and
@@ -35,13 +34,17 @@ from typing import Optional
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.mfu_transformer import (  # noqa: E402
-    FLAGSHIP, LONGCTX, MEDIUM, MID, PEAK_BF16, model_flops_per_token)
+    FLAGSHIP, LONGCTX, MEDIUM, PEAK_BF16, model_flops_per_token)
+
+#: The chip this model is drawn for, under the name JAX reports for it:
+#: a v5e's ``device_kind`` is "TPU v5 lite" (chip_smoke.py on the chip,
+#: PR 21). A measured record is analyzed against ITS device kind.
+TARGET_KIND = "TPU v5 lite"
 
 # Public per-chip HBM specs (same sourcing rule as PEAK_BF16: only the
 # generation we can run on is judged; others best-effort). Key set
 # MIRRORS PEAK_BF16 exactly — analyze() indexes both with one
-# device_kind, so a key present in one but not the other turned into a
-# bare KeyError for v2/v3/v5 (ADVICE round 5).
+# device_kind.
 HBM_GBPS = {
     "TPU v2": 700e9,
     "TPU v3": 900e9,
@@ -130,7 +133,7 @@ def hbm_bytes_per_step(cfg, *, fused_ce: Optional[bool] = None,
     return items
 
 
-def analyze(cfg, *, device_kind: str = "TPU v5 lite",
+def analyze(cfg, *, device_kind: str = TARGET_KIND,
             fused_ce: Optional[bool] = None, remat=None,
             master_f32: Optional[bool] = None,
             peak_flops: Optional[float] = None,
@@ -271,14 +274,13 @@ def main(argv):
     configs = [
         ("flagship", FLAGSHIP, {}, "bench_mfu"),
         ("flagship+fused_ce", FLAGSHIP, {"fused_ce": True}, None),
-        ("mid", MID, {}, "mfu_mid"),
         ("medium", MEDIUM, {}, "bench_mfu_medium"),
         ("long(seq4096,remat+fce)", LONGCTX,
          {"remat": True, "fused_ce": True}, "mfu_long"),
     ]
-    out = {"device": "TPU v5 lite",
-           "peak_bf16_tflops": PEAK_BF16["TPU v5 lite"] / 1e12,
-           "hbm_gbps": HBM_GBPS["TPU v5 lite"] / 1e9,
+    out = {"device": TARGET_KIND,
+           "peak_bf16_tflops": PEAK_BF16[TARGET_KIND] / 1e12,
+           "hbm_gbps": HBM_GBPS[TARGET_KIND] / 1e9,
            "configs": {}}
     print("# config | params | TF/step | HBM GB/step | compute floor | "
           "HBM floor | bound | MFU ceiling (overlap/none) | measured | "

@@ -126,8 +126,11 @@ TYPED_ERRORS: Dict[str, Tuple[str, ...]] = {
     "WorkerFailure": ("rank", "exitcode", "op", "kind"),
 }
 
+# build/ and chiprun_out/ are git-ignored scratch: an unpacked copy of
+# the tree there (the chip proof run) is not a second repo to lint
 _EXCLUDED_DIRS = {".git", ".github", ".pytest_cache", "__pycache__",
-                  ".claude", ".venv", "node_modules"}
+                  ".claude", ".venv", "node_modules", "build",
+                  "chiprun_out"}
 _EXCLUDED_FILES = {"__graft_entry__.py"}  # harness shim, not repo code
 _ENV_REGISTRY_FILE = os.path.join("distributed_pytorch_tpu", "runtime",
                                   "env.py")

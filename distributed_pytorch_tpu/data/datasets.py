@@ -1,4 +1,4 @@
-"""Datasets for the evaluation ladder (BASELINE.md).
+"""Datasets for the evaluation ladder (BASELINE.json).
 
 ``DummyDataset`` mirrors the reference's seeded toy dataset
 (``min_DDP.py:27-38``): feature = the sample's own index as a float scalar,

@@ -202,10 +202,12 @@ def prefill_partial(model: TransformerLM, params: Params, tokens,
     tokens: (B, S) int32 where only the first ``true_len`` positions are
     real (``true_len`` may be traced — one compile per padded length
     bucket, not per prompt length). Causality makes the pad tail inert:
-    real query positions never attend a later pad key, so the logits at
-    position ``true_len - 1`` are bit-identical to an exact-length
-    :func:`prefill` (the pad keys only ever contribute exact zeros to
-    masked-softmax sums).
+    real query positions never attend a later pad key (the pad keys
+    only ever contribute exact zeros to masked-softmax sums), so the
+    logits at position ``true_len - 1`` pick the same token as an
+    exact-length :func:`prefill` and agree with it to a few f32 ulps —
+    the two lengths are two XLA programs that reduce in different
+    orders, so bit-identity across them is not promised.
 
     Returns ``(logits (B, vocab) at the last real position, ks, vs)``
     where ks/vs are per-layer (B, Hkv, S, Dh) — or, with ``window``, the
@@ -1007,11 +1009,11 @@ def make_generate_fn(model: TransformerLM, max_new: int, *,
     the reshard-free pjit-to-pjit chain — a mismatch raises a typed
     ``HandoffMismatch`` instead of pjit silently copying the weights.
     The check runs on CONCRETE params — i.e. on eager calls of the
-    returned fn (tracers carry no sharding on this jax). If you wrap
-    fn in ``jax.jit`` yourself, run ``verify_handoff(params,
-    param_shardings)`` once before the first call — that is exactly
-    what ``serve.EngineConfig(param_shardings=)`` does at engine
-    construction, the production admit path.
+    returned fn (a tracer carries its abstract type, not a device
+    placement). If you wrap fn in ``jax.jit`` yourself, run
+    ``verify_handoff(params, param_shardings)`` once before the first
+    call — that is exactly what ``serve.EngineConfig(param_shardings=)``
+    does at engine construction, the production admit path.
 
     ``pin_weight_stream``: ties the params consumed by each decode step
     to the loop-varying cache counter through an optimization barrier,

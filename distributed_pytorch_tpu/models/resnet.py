@@ -1,4 +1,4 @@
-"""ResNet-18 — the vision rung of the ladder (BASELINE.md: ResNet-18 on
+"""ResNet-18 — the vision rung of the ladder (BASELINE.json: ResNet-18 on
 CIFAR-10), NHWC/TPU-native (see nn/conv.py for the layout rationale).
 
 Structure matches torchvision resnet18: 7x7/2 stem + maxpool, four stages

@@ -1,7 +1,7 @@
 """Transformer language model — the framework's flagship model.
 
 Covers the reference ladder's 'nn.TransformerEncoder LM on WikiText-2' rung
-(BASELINE.md) as a decoder-only causal LM (the modern equivalent of the
+(BASELINE.json) as a decoder-only causal LM (the modern equivalent of the
 masked-encoder LM setup). Designed mesh-first: every parameter has a
 tensor-parallel PartitionSpec (``parallel/tensor.py``), attention takes a
 pluggable core so sequence parallelism (ring attention) drops in, and the

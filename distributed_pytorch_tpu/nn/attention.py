@@ -1,5 +1,5 @@
 """Attention and transformer blocks — the LM rung of the ladder
-(BASELINE.md: TransformerEncoder LM) and the substrate for long-context
+(BASELINE.json: TransformerEncoder LM) and the substrate for long-context
 sequence parallelism (ring attention lives in ``parallel/sequence.py`` and
 plugs in here via the ``attn_fn`` hook).
 

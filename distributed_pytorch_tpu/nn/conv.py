@@ -1,5 +1,5 @@
 """Convolution / norm / pooling modules for the vision rung of the ladder
-(BASELINE.md: ResNet-18 on CIFAR-10).
+(BASELINE.json: ResNet-18 on CIFAR-10).
 
 Layout is NHWC — the TPU-native image layout (channels-last feeds the MXU's
 128-lane minor dimension directly; NCHW is the CUDA idiom and forces

@@ -1,6 +1,6 @@
 """Flash attention as a Pallas TPU kernel (forward + backward).
 
-The hot op of the Transformer rung (BASELINE.md ladder). The reference
+The hot op of the Transformer rung (BASELINE.json ladder). The reference
 repo has no attention at all (its model is two Linear layers, reference
 ``min_DDP.py:44-48``) — this kernel exists because our framework carries
 full transformer workloads; it is designed for the TPU memory hierarchy
@@ -37,8 +37,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from ..runtime.jax_compat import tpu_compiler_params as _compiler_params
+from ..runtime.context import DATA_AXIS, TENSOR_AXIS
 
 # Large-negative mask value instead of -inf: -inf - (-inf) = NaN would
 # poison the online-softmax rescaling for fully-masked tiles.
@@ -52,9 +53,23 @@ _PARALLEL = ("parallel", "parallel", "arbitrary")  # grid = (bh, outer, inner)
 
 
 def _interpret_default(interpret):
+    """Compiled on a TPU; interpreted only where the CPU platform was
+    ASKED for (``JAX_PLATFORMS=cpu`` / ``jax_platforms``). JAX falls
+    back to the CPU with a warning when it expected a chip and found
+    none — interpreting there would grind a full-size model through the
+    Pallas interpreter and report it as a run, so that case raises."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if (jax.config.jax_platforms or "").split(",")[0] != "cpu":
+        raise RuntimeError(
+            f"flash attention found backend {backend!r} but no platform "
+            "was selected: the TPU was expected and is missing. Set "
+            "JAX_PLATFORMS=cpu to run the kernel in interpret mode on "
+            "purpose.")
+    return True
 
 
 def _ceil128(s):
@@ -63,15 +78,17 @@ def _ceil128(s):
 
 def _block_sizes(s_q, s_k, block_q, block_k, d=64, bwd=False, window=None):
     """Resolve tile sizes. Explicit ints behave as before (clamped to the
-    sequence); ``None`` picks the measured-best default for the chip.
+    sequence); ``None`` picks the default for the chip.
 
-    The on-chip sweep (benchmarks/flash_block_sweep.py, v5e, d=64) showed
-    the kernel is grid-step-bound at moderate seq: 1024-wide tiles beat
-    the old 128x128 default by 3-7x in forward (seq 4096: 1.87ms vs
-    14.2ms) and XLA's dense path by up to 8.5x. Backward caps at 512 —
-    its three (bq, bk) f32 tiles (p, dp, ds) triple the VMEM bill, and
-    (512,512) measured within 8% of the s=1024 optimum. Caps shrink with
-    head_dim since every tile scales with d. With sliding-window
+    Large tiles, because the grid is short at moderate seq and each grid
+    step has a fixed cost: 1024 wide in forward at d <= 64; backward
+    caps at 512 — its three (bq, bk) f32 tiles (p, dp, ds) triple the
+    VMEM bill. Caps shrink with head_dim since every tile scales with d.
+    Every default compiles on the v5e, forward and both backward
+    kernels, at the flagship shape (b8 h12 s1024 d64), seq 4096, d=128,
+    GQA and ``window=`` (chip_smoke.py, PR 21); how fast each is against
+    smaller tiles is not measured (``benchmarks/flash_block_sweep.py``
+    is the sweep). With sliding-window
     attention the k cap clamps near the window width instead — a k tile
     much wider than the band would compute mostly-masked logits and
     degrade the O(S*window) cost toward O(S*block_k)."""
@@ -294,7 +311,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(dimension_semantics=_PARALLEL),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_PARALLEL),
         interpret=_interpret_default(interpret),
     )(q3, k3, v3)
     o = o3[:, :s_q].reshape(b, h, s_q, d)
@@ -466,7 +484,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                    jax.ShapeDtypeStruct((b * h, sk_p, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_compiler_params(dimension_semantics=_PARALLEL),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_PARALLEL),
         interpret=interp,
     )(q3, k3, v3, g3, lse3, delta3)
     dk3, dv3 = dkv
@@ -487,7 +506,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         out_specs=q_spec2,
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(dimension_semantics=_PARALLEL),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_PARALLEL),
         interpret=interp,
     )(q3, k3, v3, g3, lse3, delta3)
 
@@ -539,6 +559,33 @@ def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
 _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 
+def _mesh_island(kernel, q, k, v):
+    """Run ``kernel(q, k, v) -> (o, lse)`` where GSPMD can place it.
+
+    XLA's partitioner cannot split a Mosaic call ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a shard_map"),
+    so inside a GSPMD program on more than one device — the front door's
+    ZeRO/tp spec points, ``FROM_INPUTS`` — the kernel runs as a
+    ``shard_map`` island: batch over ``dp`` and heads over ``tp`` where
+    the mesh has those axes and they divide, everything else (and every
+    other axis) replicated. The mesh is read off the operand's own type;
+    one device, or a caller that is already inside a ``shard_map`` (the
+    stacked-dp engine, the ring-attention islands), calls the kernel as
+    it is."""
+    mesh = jax.typeof(q).sharding.mesh
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(q, k, v)
+
+    def axis(name, *dims):
+        n = mesh.shape.get(name)
+        return name if n and all(d % n == 0 for d in dims) else None
+
+    spec = P(axis(DATA_AXIS, q.shape[0]),
+             axis(TENSOR_AXIS, q.shape[1], k.shape[1]))
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=(spec, spec), check_vma=False)(q, k, v)
+
+
 def flash_attention_with_lse(q, k, v, *, causal: bool = False,
                              scale: Optional[float] = None,
                              block_q: Optional[int] = None,
@@ -579,12 +626,14 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
                          "and requires causal=True")
     *_, dh = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
-    return _flash_lse(q, k, v, causal, float(scale),
-                      int(block_q) if block_q is not None else None,
-                      int(block_k) if block_k is not None else None,
-                      interpret,
-                      int(window) if window is not None else None,
-                      int(causal_offset), int(diag_offset))
+    kernel = functools.partial(
+        _flash_lse, causal=causal, scale=float(scale),
+        block_q=int(block_q) if block_q is not None else None,
+        block_k=int(block_k) if block_k is not None else None,
+        interpret=interpret,
+        window=int(window) if window is not None else None,
+        causal_offset=int(causal_offset), diag_offset=int(diag_offset))
+    return _mesh_island(kernel, q, k, v)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
@@ -606,9 +655,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     chip (large tiles — see ``_block_sizes``); pass explicit ints only to
     pin a tiling (tests, VMEM-constrained fusions).
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU so the same
-    code path runs in CPU tests (conftest's 8-device CPU mesh) and
-    compiled on real chips.
+    ``interpret=None`` compiles on a TPU and interprets where the CPU
+    platform was selected explicitly (tests, sandbox runs); a CPU that
+    JAX fell back to because the chip is missing raises
+    (``_interpret_default``).
     """
     o, _ = flash_attention_with_lse(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
@@ -618,14 +668,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
     return o
 
 
-# Measured flash/dense crossover (v5e, d=64, causal, honest amortized
-# timing — BASELINE.md round-3 table): seq 512 flash runs 0.87x dense
-# (grid too short to amortize kernel overhead); seq 1024 flash wins
-# 1.51x and the gap widens with seq (8.5x at 4096). Below this many
-# KEYS, the dense einsum is the faster O(S^2) and still cheap in
-# memory, so make_flash_attn_fn dispatches to it. The threshold lives
-# in the typed env registry (DPX_FLASH_MIN_SEQ, default = the measured
-# crossover); this module attribute is its import-time read, kept for
+# The flash/dense hand-off. At short seq the kernel's grid is too short
+# to amortize its fixed costs and the dense einsum — still cheap in
+# memory there — is expected to win, so below this many KEYS
+# make_flash_attn_fn dispatches to it. Where the crossover sits on the
+# v5e is not measured (the records behind the 1024 default are gone;
+# ROADMAP Speed 5 re-derives it from the kernel's own timing). The
+# threshold lives in the typed env registry (DPX_FLASH_MIN_SEQ); this
+# module attribute is its import-time read, kept for
 # the consumers that report it (benchmarks/mfu_transformer.py).
 # make_flash_attn_fn re-reads the registry at build time, so a test or
 # deployment that sets the variable after import still takes effect.
@@ -655,12 +705,11 @@ def make_flash_attn_fn(block_q: Optional[int] = None,
     compute and the long-context default for causal decoders.
 
     Below ``min_seq_flash`` keys (default: the typed registry knob
-    ``DPX_FLASH_MIN_SEQ``, whose default is the measured v5e crossover)
-    the call dispatches to the dense einsum instead — same function,
-    faster at short seq — so enabling flash is safe at every sequence
-    length. Shapes are static under jit, so the dispatch costs nothing
-    at runtime. Pass ``min_seq_flash=None`` (or 0) to always run the
-    kernel (tests, kernel benchmarking)."""
+    ``DPX_FLASH_MIN_SEQ``) the call dispatches to the dense einsum
+    instead — same function, expected faster at short seq — so enabling
+    flash is safe at every sequence length. Shapes are static under jit,
+    so the dispatch costs nothing at runtime. Pass ``min_seq_flash=None``
+    (or 0) to always run the kernel (tests, kernel benchmarking)."""
     if min_seq_flash is _MIN_SEQ_ENV:
         min_seq_flash = int(_env.get("DPX_FLASH_MIN_SEQ"))
 
@@ -670,8 +719,8 @@ def make_flash_attn_fn(block_q: Optional[int] = None,
                 _dense_dispatch_logged.append(True)
                 logging.getLogger(__name__).info(
                     "flash attn_fn: %d keys < min_seq_flash=%d, "
-                    "dispatching to dense einsum (measured v5e "
-                    "crossover; numerics identical — logged once)",
+                    "dispatching to dense einsum (numerics identical "
+                    "— logged once)",
                     k.shape[-2], min_seq_flash)
             from ..nn.attention import dense_attention
             return dense_attention(q, k, v, causal=causal, scale=scale,

@@ -6,7 +6,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..runtime.jax_compat import shard_map
 
 
 def cross_entropy_per_example(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
@@ -138,7 +137,7 @@ def make_vocab_parallel_ce_fn(mesh, *, dp: str = "dp", tp: str = "tp"):
                                             axis_name=tp)
 
     def fn(hidden, head_w, labels):
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh,
             in_specs=(P(dp, None, None), P(None, tp), P(dp, None)),
             out_specs=P(dp, None), check_vma=False)(hidden, head_w,

@@ -35,7 +35,6 @@ from ..comm import primitives as prim
 from ..optim import Optimizer
 from ..runtime import context
 from ..runtime.context import DATA_AXIS
-from ..runtime.jax_compat import shard_map
 
 
 class StepOutput(NamedTuple):
@@ -495,7 +494,7 @@ def make_stateful_train_step(loss_fn: Callable, optimizer: Optimizer,
     # state in/out spec: each device keeps its own running stats. The state
     # arrives replicated (same init everywhere) but diverges per device; we
     # shard-map it as per-device local values stacked on a leading axis.
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(DATA_AXIS), P(), P(DATA_AXIS)),
         out_specs=(P(), P(DATA_AXIS), P(), P(DATA_AXIS), P(DATA_AXIS)),
@@ -522,7 +521,7 @@ def make_eval_step(eval_fn: Callable) -> Callable:
         # dpxlint: disable=DPX006 eval does not own the params (the trainer still does)
         return jax.jit(eval_fn)
     mesh = context.get_mesh()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         eval_fn, mesh=mesh,
         in_specs=(P(), P(DATA_AXIS)),
         out_specs=P(DATA_AXIS),
@@ -542,7 +541,7 @@ def make_stateful_eval_step(eval_fn: Callable) -> Callable:
         # dpxlint: disable=DPX006 eval does not own the params (the trainer still does)
         return jax.jit(eval_fn)
     mesh = context.get_mesh()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         eval_fn, mesh=mesh,
         in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=P(DATA_AXIS),
@@ -598,7 +597,7 @@ def make_scan_train_steps(loss_fn: Callable, optimizer: Optimizer,
 
     mesh = context.get_mesh()
     # batches: (n_steps, global_batch, ...) — shard axis 1 over dp
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_scan, mesh=mesh,
         in_specs=(P(), P(), P(None, DATA_AXIS)),
         out_specs=(P(), P(), P(None, DATA_AXIS)),

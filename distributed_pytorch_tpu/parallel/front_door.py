@@ -12,8 +12,8 @@ tp / ZeRO-1 are just PartitionSpec choices, resolved through the
 existing ``parallel.shard_layouts`` / ``opt_state_specs`` contract, and
 the historical builders are thin shims over it (kept API-compatible).
 
-The pjit discipline (SNIPPETS.md — ``in_axis_resources`` /
-``out_axis_resources`` / ``donate_argnums``, mesh at the call site):
+The pjit discipline (``in_axis_resources`` / ``out_axis_resources`` /
+``donate_argnums``, mesh at the call site):
 
 * **Whole-step buffer donation by default** (``donate=None`` reads the
   typed ``DPX_DONATE`` knob, default on): params + optimizer state are
@@ -71,7 +71,6 @@ from ..obs import metrics as _dpxmon
 from ..optim import Optimizer
 from ..runtime import context
 from ..runtime.context import DATA_AXIS
-from ..runtime.jax_compat import shard_map
 from .data_parallel import (GRAD_REDUCE_MODES, MP_POLICIES, StepOutput,
                             _wire_format, _wrap_mixed_precision)
 
@@ -135,6 +134,24 @@ def _shardings(mesh: Mesh, spec_tree):
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P))
+
+
+def _place(tree, shardings):
+    """``tree`` on the step's pinned in-shardings. The sharding is part
+    of an argument's type, so a fresh ``model.init`` tree (one device,
+    no mesh) and the step's own mesh-sharded outputs would trace and
+    compile the same program twice; placing first makes every call the
+    same type. The steady state — the step fed its own outputs — is
+    recognized leaf by leaf and costs no ``device_put`` dispatch."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    want = ([shardings] * len(leaves)
+            if isinstance(shardings, NamedSharding)
+            else jax.tree_util.tree_leaves(shardings))
+    if len(want) == len(leaves) and all(
+            getattr(leaf, "sharding", None) == w
+            for leaf, w in zip(leaves, want)):
+        return tree
+    return jax.device_put(tree, shardings)
 
 
 #: Bounded LRU of built steps. The cache exists for the no-silent-
@@ -219,18 +236,24 @@ class FrontDoorStep:
                     f"program key required, have {set(self._programs)}")
         return self._programs[key]
 
+    def lower(self, params, opt_state, batch, key=None):
+        """The step program lowered for these arguments (``.as_text()``
+        shows which kernels it holds, ``.compile()`` gives XLA's
+        accounting). The lowering retrace is excluded from
+        ``trace_counts``."""
+        self._counting = False
+        try:
+            return self.program(key).lower(params, opt_state, batch)
+        finally:
+            self._counting = True
+
     def memory_analysis(self, params, opt_state, batch, key=None) -> dict:
         """Compile-time memory accounting of the step program via XLA's
         ``memory_analysis`` (the donation A/B evidence): peak bytes =
         arguments + outputs + temps - aliased (donated buffers alias
-        their outputs, so the donated build's peak is strictly lower).
-        The lowering retrace is excluded from ``trace_counts``."""
-        self._counting = False
-        try:
-            ma = self.program(key).lower(
-                params, opt_state, batch).compile().memory_analysis()
-        finally:
-            self._counting = True
+        their outputs, so the donated build's peak is strictly lower)."""
+        ma = self.lower(params, opt_state, batch,
+                        key).compile().memory_analysis()
         out = {k: int(getattr(ma, k + "_size_in_bytes"))
                for k in ("argument", "output", "temp", "alias")}
         out["peak_bytes"] = (out["argument"] + out["output"]
@@ -346,8 +369,9 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     cache point, so a donate/wire/mp change can never inherit a stale
     program built under other flags.
     """
-    from ..runtime import env as _env
+    from ..runtime import compile_cache, env as _env
 
+    compile_cache.enable()
     if wire not in GRAD_REDUCE_MODES:
         raise ValueError(f"wire (grad_reduce) must be one of "
                          f"{'|'.join(GRAD_REDUCE_MODES)}, got {wire!r}")
@@ -545,7 +569,7 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
                           "metrics": dp}
 
     def compile_width(bits, want_stat):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             make_local_step(bits, want_stat), mesh=mesh,
             in_specs=(P(), P(), P(DATA_AXIS)),
             out_specs=(P(), P(), P(DATA_AXIS), P(DATA_AXIS), P()),
@@ -560,7 +584,8 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
         step._programs[fixed_bits] = prog
 
         def call(params, opt_state, batch):
-            return StepOutput(*prog(params, opt_state, batch)[:4])
+            return StepOutput(*prog(_place(params, rep),
+                                    _place(opt_state, rep), batch)[:4])
         step._call = call
         return
 
@@ -577,7 +602,7 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
 
     def call(params, opt_state, batch):
         p, o, loss, metrics, stat = step._programs[chooser.width](
-            params, opt_state, batch)
+            _place(params, rep), _place(opt_state, rep), batch)
         chooser.observe_frac(float(stat))
         return StepOutput(p, o, loss, metrics)
     step._call = call
@@ -659,7 +684,8 @@ def _build_constrained(step, loss_fn, optimizer, mesh, specs: StepSpecs,
                 out_shardings=SpmdStepOutput(p_sh, o_sh, None, None))
             holder["prog"] = prog
             step._programs["constrained"] = prog
-        return prog(params, opt_state, batch)
+        return prog(_place(params, p_sh),
+                    _place(opt_state, step.in_shardings["opt"]), batch)
 
     step._call = call
 
@@ -756,7 +782,7 @@ def _build_sharded(step, loss_fn, optimizer, mesh, world, *,
         step.out_shardings = {"params": rep, "opt": o_sh, "loss": dp,
                               "metrics": dp}
         island = lambda p, s, b: _local_step(layout, sharded, p, s, b)
-        sharded_fn = shard_map(
+        sharded_fn = jax.shard_map(
             island, mesh=mesh,
             in_specs=(P(), specs, P(DATA_AXIS)),
             out_specs=(P(), specs, P(DATA_AXIS), P(DATA_AXIS)),
@@ -772,6 +798,9 @@ def _build_sharded(step, loss_fn, optimizer, mesh, world, *,
         if "compiled" not in holder:
             holder["compiled"] = _build(params, opt_state)
             step._programs["sharded"] = holder["compiled"]
+        if world > 1:
+            params = _place(params, step.in_shardings["params"])
+            opt_state = _place(opt_state, step.in_shardings["opt"])
         return holder["compiled"](params, opt_state, batch)
 
     step._call = call
@@ -833,9 +862,9 @@ def make_eval_step(eval_fn: Callable, *, like=None,
         rep = pinned if isinstance(pinned, NamedSharding) \
             else NamedSharding(mesh, P())
         dp = NamedSharding(mesh, P(DATA_AXIS))
-        island = shard_map(body, mesh=mesh,
-                           in_specs=(P(), P(DATA_AXIS)),
-                           out_specs=P(DATA_AXIS), check_vma=False)
+        island = jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(), P(DATA_AXIS)),
+                               out_specs=P(DATA_AXIS), check_vma=False)
         # dpxlint: disable=DPX006 eval does not own the params (the trainer still does)
         prog = jax.jit(island, in_shardings=(rep, dp), out_shardings=dp)
         in_sh = {"params": rep, "batch": dp}

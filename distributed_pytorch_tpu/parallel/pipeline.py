@@ -45,7 +45,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..comm import primitives as prim
-from ..runtime.jax_compat import shard_map
 
 
 def pipeline_apply(stage_params, microbatches, stage_fn, *,
@@ -121,7 +120,7 @@ def make_gspmd_pipeline_fn(mesh: Mesh, stage_fn: Callable,
             else P(axis_name)
         param_specs = jax.tree_util.tree_map(
             lambda _: leaf_spec, stacked_params)
-        y = shard_map(
+        y = jax.shard_map(
             island, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(),
@@ -311,7 +310,7 @@ def make_pipeline_train_fn(mesh: Mesh, stage_fn: Callable,
 
         param_specs = jax.tree_util.tree_map(
             lambda _: leaf_spec, stacked_params)
-        loss_sum, grads = shard_map(
+        loss_sum, grads = jax.shard_map(
             island, mesh=mesh,
             in_specs=(param_specs, P(), P(), P()),
             out_specs=(P(), param_specs),

@@ -28,7 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..optim import Optimizer
 from ..runtime import context
-from ..runtime.jax_compat import shard_map
 from .sequence import (ring_attention, ring_flash_attention,
                        striped_ring_flash_attention, ulysses_attention)
 
@@ -94,7 +93,7 @@ def make_gspmd_ring_attn_fn(mesh: Mesh, *, dp: str = "dp", tp: str = "tp",
                     window=window)
             return ring_attention(q, k, v, axis_name=sp, causal=causal,
                                   scale=scale)
-        return shard_map(island, mesh=mesh,
+        return jax.shard_map(island, mesh=mesh,
                              in_specs=(qkv_spec, qkv_spec, qkv_spec),
                              out_specs=qkv_spec,
                              check_vma=False)(q, k, v)

@@ -1,4 +1,4 @@
-"""perfbench — the variance-gated, wedge-aware benchmark subsystem.
+"""perfbench — the variance-gated benchmark subsystem.
 
 Replaces the ad-hoc statistics scattered through the old 743-line
 ``bench.py`` with one policy every perf number the repo prints goes
@@ -7,9 +7,9 @@ items 2-4 want to make):
 
 * :mod:`.stats` — warmup-discarded repeated trials, median + IQR, a hard
   spread gate, affinity/thread pinning;
-* :mod:`.runner` — wedge-aware execution: subprocess-isolated TPU
-  probes, bounded exponential-backoff retries, the
-  parseable-record-no-matter-what subprocess contract;
+* :mod:`.runner` — execution: the backend probe in a child (the parent
+  stays off the chip), the parseable-record-no-matter-what subprocess
+  contract;
 * :mod:`.record` — versioned schema-validated records (a null metric is
   a schema violation; ``vs_baseline`` is structurally withheld with a
   reason when either side fails the gate) appended to the line-JSON
@@ -20,8 +20,8 @@ items 2-4 want to make):
   regression diffing (CLI: ``tools/benchdiff.py``);
 * :mod:`.errors` — the typed failure vocabulary (PR-2 style).
 
-``bench.py`` is now a thin shim over this package; run_all_tpu, the
-serve/ckpt benches, and the CI bench-smoke job all build on it.  Every
+``bench.py`` is now a thin shim over this package; the serve/ckpt
+benches and the CI bench-smoke job all build on it.  Every
 module keeps cross-package imports function-scope so ``tools/
 benchdiff.py`` can load the subsystem without the heavy package
 ``__init__`` (the ``tools/dpxlint.py`` contract); docs in

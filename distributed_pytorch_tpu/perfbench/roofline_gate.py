@@ -64,6 +64,11 @@ def attach_flagship(rec: dict, *, announce: bool = True) -> dict:
                 peak_flops=cal["peak_flops"],
                 mem_bytes_per_s=cal["mem_bytes_per_s"])
             analysis["specs_source"] = "calibrated_host"
+        elif det.get("platform") == "tpu":
+            # a chip record is analyzed against the chip it ran on: an
+            # unknown device_kind raises (and lands as a warning) instead
+            # of borrowing another chip's ceilings
+            analysis = analyze(FLAGSHIP, device_kind=det["device"])
         else:
             analysis = analyze(FLAGSHIP)
         rl = attach_measured(analysis, det.get("step_ms_median"))

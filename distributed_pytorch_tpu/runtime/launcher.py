@@ -29,7 +29,7 @@ from typing import Callable
 
 import jax
 
-from . import context
+from . import compile_cache, context
 
 
 def launch(worker_fn: Callable, *args) -> None:
@@ -40,6 +40,7 @@ def launch(worker_fn: Callable, *args) -> None:
     has no analog: TPU topology is discovered from the runtime, so there is
     no footgun of silently grabbing every GPU on a shared box.
     """
+    compile_cache.enable()
     world_size = context.device_count()
 
     if world_size > 1:

@@ -1,13 +1,15 @@
 """ctypes bindings for the native host runtime (native/dpxhost.cpp) —
 the c10d-TCPStore/Gloo replacement (SURVEY.md §2.3 rows 2-3).
 
-Auto-builds ``libdpxhost.so`` with g++ on first use if the Makefile output
-is missing (no pip/pybind dependency; pure C ABI + ctypes).
+Auto-builds ``libdpxhost.so`` with g++ on first use when it is missing
+or was built from another ``dpxhost.cpp`` than the one in the checkout
+(no pip/pybind dependency; pure C ABI + ctypes).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,7 +22,10 @@ from . import env as _envreg
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "dpxhost.cpp")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libdpxhost.so")
+#: sha256 of the dpxhost.cpp the library beside it was built from.
+_DIGEST_PATH = _LIB_PATH + ".sha256"
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -89,33 +94,43 @@ class CommRetryExhausted(CommError):
         self.attempts = attempts
 
 
+def _source_digest() -> str:
+    with open(_SRC_PATH, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
 def _build() -> None:
     # Build to a per-pid temp path and rename atomically: concurrently
     # spawned rank processes may all see the .so missing, and a partially
     # written file must never be dlopen'd.
-    src = os.path.join(_NATIVE_DIR, "dpxhost.cpp")
+    digest = _source_digest()
     tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
     # flags mirror native/Makefile: -fno-math-errno (NOT fast-math) keeps
     # the quantized codec bit-identical to comm/wire.py while letting
     # lrintf/fabsf inline and the quant loops vectorize
     subprocess.run(
         ["g++", "-O3", "-fno-math-errno", "-fPIC", "-std=c++17", "-shared",
-         "-o", tmp, src],
+         "-o", tmp, _SRC_PATH],
         check=True, capture_output=True)
     os.replace(tmp, _LIB_PATH)
+    # the digest lands after the library it describes, as atomically
+    with open(tmp, "w") as f:
+        f.write(digest)
+    os.replace(tmp, _DIGEST_PATH)
 
 
 def _needs_build() -> bool:
-    """Missing OR stale: a checkout where dpxhost.cpp is newer than the
-    built .so must rebuild, or new symbols (e.g. dpx_allreduce_q8) would
-    silently be missing from an old library."""
-    if not os.path.exists(_LIB_PATH):
-        return True
+    """Missing, or built from other source than the checkout holds. The
+    key is the CONTENT of dpxhost.cpp (its sha256, recorded beside the
+    library at build time), never a timestamp: a copied tree carries the
+    untracked binary along with whatever mtimes the copy gave it, and an
+    old library would silently lack new symbols."""
     try:
-        src = os.path.join(_NATIVE_DIR, "dpxhost.cpp")
-        return os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)
+        with open(_DIGEST_PATH) as f:
+            built_from = f.read().strip()
     except OSError:
-        return False
+        return True
+    return not os.path.exists(_LIB_PATH) or built_from != _source_digest()
 
 
 def load_library():

@@ -40,6 +40,7 @@ from ..models.generate import (_check_attn_compatible, _model_window,
                                _sample)
 from ..obs import metrics as dpxmon
 from ..obs import trace as dpxtrace
+from ..runtime import compile_cache
 from ..runtime import env as dpxenv
 from ..runtime import faults
 from ..utils.logging import MetricsLogger
@@ -130,6 +131,7 @@ class InferenceEngine:
 
     def __init__(self, model, params, config: Optional[EngineConfig] = None):
         self.config = cfg = config or EngineConfig()
+        compile_cache.enable()
         if cfg.n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {cfg.n_slots}")
         _check_attn_compatible(model, cfg.allow_custom_attn)
@@ -614,7 +616,7 @@ class InferenceEngine:
             active = np.zeros(self.config.n_slots, bool)
             active[nonspec] = True
             logits = self.pool.decode(self.params,
-                                      jnp.asarray(self._cur_tokens),
+                                      jnp.array(self._cur_tokens),
                                       jnp.asarray(active))
             for slot in nonspec:
                 req = self._running[slot]

@@ -112,7 +112,11 @@ class PagedSlotPool:
             self.v_tail = [jnp.zeros(tshape, jnp.float32)
                            for _ in range(n_layers)]
         # host-side state: page tables / lengths mirror the traced args
-        # (tiny int32 uploads per call), policy state never leaves host
+        # (tiny int32 uploads per call), policy state never leaves host.
+        # They are uploaded with jnp.array (a copy), never jnp.asarray:
+        # on the CPU backend asarray may alias the numpy buffer, dispatch
+        # is asynchronous, and the host advances these arrays right after
+        # a call — the program would read the NEXT step's lengths
         self.tables = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self.lengths = np.zeros((n_slots,), np.int32)
         self.owned: List[List[int]] = [[] for _ in range(n_slots)]
@@ -297,7 +301,7 @@ class PagedSlotPool:
         if self.quant_bits is None:
             logits, self.k_pages, self.v_pages = fn(
                 params, self.k_pages, self.v_pages,
-                jnp.asarray(self.tables[slot]), jnp.asarray(padded),
+                jnp.array(self.tables[slot]), jnp.asarray(padded),
                 jnp.asarray(offset, jnp.int32),
                 jnp.asarray(tail_len, jnp.int32))
         else:
@@ -305,7 +309,7 @@ class PagedSlotPool:
              self.v_scales, self.k_tail, self.v_tail) = fn(
                 params, self.k_pages, self.v_pages, self.k_scales,
                 self.v_scales, self.k_tail, self.v_tail,
-                jnp.asarray(self.tables[slot]), jnp.asarray(padded),
+                jnp.array(self.tables[slot]), jnp.asarray(padded),
                 jnp.asarray(offset, jnp.int32),
                 jnp.asarray(tail_len, jnp.int32),
                 jnp.asarray(slot, jnp.int32))
@@ -337,14 +341,14 @@ class PagedSlotPool:
         if self.quant_bits is None:
             logits, self.k_pages, self.v_pages = self._decode_fn(
                 params, self.k_pages, self.v_pages,
-                jnp.asarray(self.tables), jnp.asarray(self.lengths),
+                jnp.array(self.tables), jnp.array(self.lengths),
                 jnp.asarray(tokens), jnp.asarray(active))
         else:
             (logits, self.k_pages, self.v_pages, self.k_scales,
              self.v_scales, self.k_tail, self.v_tail) = self._decode_fn(
                 params, self.k_pages, self.v_pages, self.k_scales,
                 self.v_scales, self.k_tail, self.v_tail,
-                jnp.asarray(self.tables), jnp.asarray(self.lengths),
+                jnp.array(self.tables), jnp.array(self.lengths),
                 jnp.asarray(tokens), jnp.asarray(active))
         self.lengths[np.asarray(active)] += 1
         return logits
@@ -385,11 +389,11 @@ class PagedSlotPool:
                 else self._verify_q)
         if self.quant_bits is None:
             return fn(params, self.k_pages, self.v_pages,
-                      jnp.asarray(self.tables), jnp.asarray(self.lengths),
+                      jnp.array(self.tables), jnp.array(self.lengths),
                       jnp.asarray(tokens))
         return fn(params, self.k_pages, self.v_pages, self.k_scales,
                   self.v_scales, self.k_tail, self.v_tail,
-                  jnp.asarray(self.tables), jnp.asarray(self.lengths),
+                  jnp.array(self.tables), jnp.array(self.lengths),
                   jnp.asarray(tokens))
 
     def spec_commit(self, sk, sv, commit: np.ndarray) -> None:
@@ -410,14 +414,14 @@ class PagedSlotPool:
             self._commit_fn = fn
         if self.quant_bits is None:
             self.k_pages, self.v_pages = fn(
-                self.k_pages, self.v_pages, jnp.asarray(self.tables),
-                jnp.asarray(self.lengths), sk, sv, jnp.asarray(commit))
+                self.k_pages, self.v_pages, jnp.array(self.tables),
+                jnp.array(self.lengths), sk, sv, jnp.asarray(commit))
         else:
             (self.k_pages, self.v_pages, self.k_scales, self.v_scales,
              self.k_tail, self.v_tail) = fn(
                 self.k_pages, self.v_pages, self.k_scales,
                 self.v_scales, self.k_tail, self.v_tail,
-                jnp.asarray(self.tables), jnp.asarray(self.lengths),
+                jnp.array(self.tables), jnp.array(self.lengths),
                 sk, sv, jnp.asarray(commit))
         self.lengths += np.asarray(commit, np.int32)
 

@@ -128,7 +128,8 @@ class SpecState:
         one lengths rewrite."""
         if len(slots):
             self.len[np.asarray(slots)] += np.asarray(commits, np.int32)
-        self.pool.lengths = jnp.asarray(self.len)
+        # a copy (jnp.array): self.len keeps changing under the host
+        self.pool.lengths = jnp.array(self.len)
 
 
 def accept_greedy(drafts: np.ndarray, logits: np.ndarray,

@@ -9,9 +9,10 @@ metrics), instead of an eager loop with four separate collectives per
 iteration (reference ``min_DDP.py:95-130``).
 
 Run:  python examples/min_ddp.py --epochs 2 --batch-size 8
-(on a CPU-only host, set DPX_CPU_DEVICES=8 with
-XLA_FLAGS=--xla_force_host_platform_device_count=8 for a virtual 8-device
-mesh; on TPU the chips are discovered automatically.)
+(on a CPU-only host, JAX_PLATFORMS=cpu DPX_CPU_DEVICES=8 with
+jax.config.update("jax_num_cpu_devices", 8) before the first backend use
+gives a virtual 8-device mesh; on TPU the chips are discovered
+automatically.)
 """
 
 import argparse

@@ -1,5 +1,5 @@
 """ResNet-18 image-classification training — the vision rung of the
-evaluation ladder (BASELINE.md: ResNet-18 on CIFAR-10).
+evaluation ladder (BASELINE.json: ResNet-18 on CIFAR-10).
 
 Zero-egress data policy: if ``--data-dir`` points at an extracted
 ``cifar-10-batches-py`` directory (the standard CIFAR-10 python pickle

@@ -14,38 +14,20 @@ environment limitation (tests/test_fsdp_multihost.py).
 """
 
 import os
-import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# The launching test session leaks --xla_force_host_platform_device_count=8
-# through XLA_FLAGS (conftest's 8-device mesh sets it process-wide on jax
-# builds without the jax_num_cpu_devices config). Inherited here it would
-# override THIS process's 4-device topology, the two processes would merge
-# to 16 "global" devices, and the span checks below would fail on an env
-# accident — scrub the flag before the backend initializes. (This was the
-# long-standing "1 pre-existing env-dependent failure"; root-caused by the
-# PR 5 capability probe.)
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" in _flags:
-    os.environ["XLA_FLAGS"] = re.sub(
-        r"--xla_force_host_platform_device_count=\d+", "", _flags).strip()
-
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-
-from distributed_pytorch_tpu.runtime.jax_compat import ensure_cpu_devices  # noqa: E402
-
-ensure_cpu_devices(4)  # 4 local x 2 procs = 8 global
+jax.config.update("jax_num_cpu_devices", 4)  # 4 local x 2 procs = 8 global
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from distributed_pytorch_tpu.runtime import multihost  # noqa: E402
-from distributed_pytorch_tpu.runtime.jax_compat import shard_map  # noqa: E402
 
 
 #: --probe exit code meaning "environment cannot do cross-process DCN".
@@ -99,7 +81,7 @@ def main(coordinator: str, num_procs: int, proc_id: int,
         g = jax.grad(lambda w: jnp.mean((x * w) ** 2))(w)
         return jax.lax.pmean(jax.lax.pmean(g, "dp"), "dp_outer")
 
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(("dp_outer", "dp"))),
         out_specs=P(), check_vma=False))

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 import distributed_pytorch_tpu as dist
-from distributed_pytorch_tpu.runtime.jax_compat import shard_map
 
 
 def stacked(world, shape=(3,)):
@@ -104,7 +103,7 @@ def test_in_step_primitives_under_shard_map(group8):
         idx = prim.axis_index("dp")
         return s, g, shifted, idx[None]
 
-    f = shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
                       in_specs=(P("dp"),),
                       out_specs=(P(), P("dp"), P("dp"), P("dp")),
                       check_vma=False)
@@ -133,7 +132,7 @@ def test_line_shift_under_shard_map(group8):
                 prim.line_shift(x, "dp", 0),
                 prim.line_shift(x, "dp", 8))
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("dp"),),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),),
                       out_specs=(P("dp"),) * 4, check_vma=False)
     x = jnp.arange(8.0).reshape(8, 1)
     fwd, bwd, ident, over = jax.jit(f)(x)
@@ -162,7 +161,7 @@ def test_quantized_pmean_error_bound_and_agreement(group8):
     def island(x):
         return prim.quantized_pmean(x[0], "dp")[None]
 
-    f = shard_map(island, mesh=mesh, in_specs=(P("dp"),),
+    f = jax.shard_map(island, mesh=mesh, in_specs=(P("dp"),),
                       out_specs=P("dp"), check_vma=False)
     out = np.asarray(jax.jit(f)(jnp.asarray(xs)))
     exact = xs.mean(0)

@@ -22,8 +22,7 @@ _CODE = r"""
 import json
 import jax
 jax.config.update("jax_platforms", "cpu")
-from distributed_pytorch_tpu.runtime.jax_compat import ensure_cpu_devices
-ensure_cpu_devices(16)
+jax.config.update("jax_num_cpu_devices", 16)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P

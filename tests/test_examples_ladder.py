@@ -1,5 +1,5 @@
 """The evaluation-ladder examples (ResNet-18, Transformer-LM) end-to-end
-on the 8-device virtual mesh — BASELINE.md rungs 3 and 4. Small shapes;
+on the 8-device virtual mesh — BASELINE.json rungs 3 and 4. Small shapes;
 asserts finite, recorded losses and the data-plumbing contracts."""
 
 import os

@@ -113,9 +113,8 @@ def test_mha_with_flash_attn_fn():
 
 
 def test_flash_min_seq_crossover_dispatch(monkeypatch):
-    """Below min_seq_flash keys the attn_fn must run the dense einsum
-    (the measured v5e crossover: flash loses to dense at seq 512,
-    BASELINE.md round-3 table); at/above it, the kernel. Verified by
+    """Below min_seq_flash keys the attn_fn must run the dense einsum;
+    at/above it, the kernel. Verified by
     counting kernel entries, and the two paths must agree numerically."""
     import importlib
     fa = importlib.import_module(
@@ -196,3 +195,71 @@ def test_window_requires_causal():
         flash_attention(q, k, v, causal=False, window=8)
     with pytest.raises(ValueError, match="causal"):
         dense_attention(q, k, v, causal=False, window=8)
+
+
+def _tpu_lowering(fn, *args):
+    """StableHLO text of ``fn`` lowered for the TPU from this CPU host."""
+    return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 12, 12, 1024, 64, None),     # the flagship's attention
+    (2, 12, 12, 4096, 64, None),
+    (4, 8, 2, 2048, 128, None),      # GQA, head_dim 128
+    (2, 12, 12, 4096, 64, 512),      # sliding window
+])
+def test_tpu_lowering_is_three_mosaic_calls(shape):
+    """What the chip compiles is the Mosaic kernel, not the interpreter:
+    forward + backward lower to exactly three ``tpu_custom_call``s (fwd,
+    dK/dV, dQ); the interpreted build this CPU suite runs holds none."""
+    b, h, h_kv, s, d, window = shape
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, h_kv, s, d), jnp.bfloat16)
+
+    def grad_fn(interpret):
+        return jax.jit(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, window=window,
+                interpret=interpret).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))
+
+    assert _tpu_lowering(grad_fn(False), q, kv, kv).count(
+        "tpu_custom_call") == 3
+    assert "tpu_custom_call" not in _tpu_lowering(grad_fn(True), q, kv, kv)
+
+
+def test_tpu_lowering_under_gspmd_is_a_shard_map_island(group8):
+    """XLA cannot partition a Mosaic call ("wrap the call in a
+    shard_map"), so on mesh-sharded operands the kernel must lower as an
+    island by itself — the front door's ZeRO/tp spec points and
+    FROM_INPUTS run it inside a GSPMD program."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import distributed_pytorch_tpu as dist
+
+    sh = NamedSharding(dist.get_mesh(), P("dp"))
+    q = jax.device_put(jnp.ones((8, 4, 256, 64), jnp.bfloat16), sh)
+    fn = jax.jit(jax.grad(
+        lambda q: flash_attention(q, q, q, causal=True,
+                                  interpret=False)
+        .astype(jnp.float32).sum()))
+    assert _tpu_lowering(fn, q).count("tpu_custom_call") == 3
+
+
+def test_interpret_only_where_cpu_was_asked_for():
+    """interpret=None: interpreted because THIS suite selected the cpu
+    platform; a CPU that JAX fell back to (no platform selected, chip
+    missing) must raise instead of grinding through the interpreter."""
+    import importlib
+    fa = importlib.import_module(
+        "distributed_pytorch_tpu.ops.flash_attention")
+
+    assert jax.config.jax_platforms == "cpu"
+    assert fa._interpret_default(None) is True
+    assert fa._interpret_default(False) is False
+    jax.config.update("jax_platforms", None)
+    try:
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            fa._interpret_default(None)
+    finally:
+        jax.config.update("jax_platforms", "cpu")

@@ -234,7 +234,7 @@ def _dcn_capability():
     from distributed_pytorch_tpu.runtime.launcher import find_free_port
 
     # _multihost_worker.PROBE_INCAPABLE — referenced by value: importing
-    # the worker module would run its XLA_FLAGS scrub and platform switch
+    # the worker module would run its platform and device-count switch
     # inside THIS test process
     PROBE_INCAPABLE = 31
 

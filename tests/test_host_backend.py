@@ -297,8 +297,9 @@ class TestPerRankDeviceAssignment:
     def test_accel_optin_assigns_chip_per_rank(self, tmp_path, monkeypatch):
         """DPX_MULTIPROC_ACCEL=tpu: rank r's child owns chip r (the
         torch one-process-per-device model; reference rank->device
-        mapping, distributed.py:88-91). Plumbing contract only — this
-        host has one chip, so the env is asserted, not the execution."""
+        mapping, distributed.py:88-91). The CPU suite asserts the env
+        the children get; the execution ran on a four-chip host
+        (runtime/multiprocess.py)."""
         import json
 
         from distributed_pytorch_tpu.runtime import launch_multiprocess

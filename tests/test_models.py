@@ -8,7 +8,6 @@ import pytest
 
 import distributed_pytorch_tpu as dist
 from distributed_pytorch_tpu import models, optim
-from distributed_pytorch_tpu.runtime.jax_compat import shard_map
 from distributed_pytorch_tpu.ops.losses import (cross_entropy,
                                                 cross_entropy_per_example)
 from distributed_pytorch_tpu.parallel import (make_scan_train_steps,
@@ -211,7 +210,7 @@ class TestSyncBatchNorm:
             y, ns = bn_sync.apply(params, x, state=state, train=True)
             return y, ns["mean"], ns["var"]
 
-        y, nm, nv = jax.jit(shard_map(
+        y, nm, nv = jax.jit(jax.shard_map(
             island, mesh=mesh,
             in_specs=P("dp"), out_specs=(P("dp"), P("dp"), P("dp")),
             check_vma=False))(x)

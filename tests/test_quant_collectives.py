@@ -185,7 +185,6 @@ class TestSpmdQuantPath:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from distributed_pytorch_tpu.runtime.jax_compat import shard_map
 
         mesh = dist.get_mesh()
         xs = np.stack(_ranks(8, 65536, seed=9))
@@ -193,8 +192,8 @@ class TestSpmdQuantPath:
         def island(x):
             return prim.quantized_pmean(x[0], "dp")[None]
 
-        f = shard_map(island, mesh=mesh, in_specs=(P("dp"),),
-                      out_specs=P("dp"), check_vma=False)
+        f = jax.shard_map(island, mesh=mesh, in_specs=(P("dp"),),
+                          out_specs=P("dp"), check_vma=False)
         out = np.asarray(jax.jit(f)(jnp.asarray(xs)))
         exact = xs.mean(axis=0)
         err = np.abs(out[0] - exact).max() / np.abs(exact).max()

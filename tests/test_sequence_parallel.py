@@ -18,7 +18,6 @@ from distributed_pytorch_tpu.parallel.spmd import (make_gspmd_ring_attn_fn,
 from distributed_pytorch_tpu.parallel.tensor import (
     replicated_specs, shard_params, transformer_lm_param_specs)
 from distributed_pytorch_tpu.runtime import context
-from distributed_pytorch_tpu.runtime.jax_compat import shard_map
 
 
 @pytest.fixture
@@ -45,7 +44,7 @@ def test_ring_attention_matches_dense(sp_mesh8, causal, h_kv):
     want = dense_attention(q, k, v, causal=causal)
 
     spec = P(None, None, "sp", None)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="sp",
                                        causal=causal),
         mesh=sp_mesh8,
@@ -199,7 +198,7 @@ def test_ring_flash_matches_dense(sp_mesh8, causal):
                for _ in range(3))
     want = dense_attention(q, k, v, causal=causal)
     spec = P(None, None, "sp", None)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ring_flash_attention(q, k, v, axis_name="sp",
                                              causal=causal, block_q=8,
                                              block_k=8),
@@ -221,7 +220,7 @@ def test_ring_flash_grads_match_dense(sp_mesh8, causal):
                for _ in range(3))
     spec = P(None, None, "sp", None)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_flash_attention(q, k, v, axis_name="sp",
                                              causal=causal, block_q=4,
                                              block_k=4),
@@ -529,7 +528,7 @@ def test_windowed_ring_skips_far_hops_statically(sp_mesh8):
 
     def island(window):
         spec = P(None, None, "sp", None)
-        return shard_map(
+        return jax.shard_map(
             lambda q, k, v: ring_flash_attention(
                 q, k, v, axis_name="sp", causal=True, window=window,
                 block_q=4, block_k=4),

@@ -96,10 +96,14 @@ def _standalone(model, params, prompt, sp, key, max_len=MAX_LEN):
 
 
 class TestSlotCacheOps:
-    def test_prefill_partial_matches_prefill_bitwise(self):
-        """Right-padding is inert under causality: logits at the last
-        real position and the cached K/V prefix are bit-identical to an
-        exact-length prefill."""
+    def test_prefill_partial_matches_prefill(self):
+        """Right-padding is inert under causality: the logits at the
+        last real position pick the same token as an exact-length
+        prefill, and they and the cached K/V prefix agree to a few f32
+        ulps at their O(1) magnitude (2e-6). The 7-wide and the 16-wide
+        programs are two XLA programs that reduce in different orders,
+        so bit-identity across them is not a contract; the first
+        layer's K/V — projections of identical rows — are bit-identical."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(0)
@@ -110,15 +114,20 @@ class TestSlotCacheOps:
         logits_p, ks, vs = jax.jit(
             lambda p, t, n: prefill_partial(model, p, t, n))(
             params, padded, 7)
-        np.testing.assert_array_equal(np.asarray(logits),
-                                      np.asarray(logits_p))
+        logits, logits_p = np.asarray(logits), np.asarray(logits_p)
+        assert logits.argmax() == logits_p.argmax()
+        np.testing.assert_allclose(logits, logits_p, rtol=0, atol=2e-6)
+        np.testing.assert_array_equal(np.asarray(cache.k[0])[:, :, :7],
+                                      np.asarray(ks[0])[:, :, :7])
+        np.testing.assert_array_equal(np.asarray(cache.v[0])[:, :, :7],
+                                      np.asarray(vs[0])[:, :, :7])
         for i in range(model.n_layers):
-            np.testing.assert_array_equal(
+            np.testing.assert_allclose(
                 np.asarray(cache.k[i])[:, :, :7],
-                np.asarray(ks[i])[:, :, :7])
-            np.testing.assert_array_equal(
+                np.asarray(ks[i])[:, :, :7], rtol=0, atol=2e-6)
+            np.testing.assert_allclose(
                 np.asarray(cache.v[i])[:, :, :7],
-                np.asarray(vs[i])[:, :, :7])
+                np.asarray(vs[i])[:, :, :7], rtol=0, atol=2e-6)
 
     def test_prefill_partial_window_layout(self):
         """The gather-built rolling layout (traced true_len) equals

@@ -383,7 +383,6 @@ class TestSpmdSharded:
 
 class TestQuantizedLegPrimitives:
     def test_quantized_reduce_scatter_sums(self, group8):
-        from distributed_pytorch_tpu.runtime.jax_compat import shard_map
         mesh = dist.get_mesh()
         n = 8 * 2 * BLOCK
         xs = np.stack([(np.random.default_rng(r).standard_normal(n))
@@ -392,15 +391,14 @@ class TestQuantizedLegPrimitives:
         def island(x):
             return prim.quantized_reduce_scatter(x[0], "dp")[None]
 
-        f = shard_map(island, mesh=mesh, in_specs=(P("dp"),),
-                      out_specs=P("dp"), check_vma=False)
+        f = jax.shard_map(island, mesh=mesh, in_specs=(P("dp"),),
+                          out_specs=P("dp"), check_vma=False)
         out = np.asarray(jax.jit(f)(jnp.asarray(xs))).ravel()
         exact = xs.sum(axis=0, dtype=np.float64)
         err = np.abs(out - exact).max() / np.abs(exact).max()
         assert err <= 1e-2, err
 
     def test_quantized_all_gather_bit_identical(self, group8):
-        from distributed_pytorch_tpu.runtime.jax_compat import shard_map
         mesh = dist.get_mesh()
         chunk = 2 * BLOCK
         xs = np.stack([(np.random.default_rng(r).standard_normal(chunk))
@@ -409,8 +407,8 @@ class TestQuantizedLegPrimitives:
         def island(x):
             return prim.quantized_all_gather(x[0], "dp")[None]
 
-        f = shard_map(island, mesh=mesh, in_specs=(P("dp"),),
-                      out_specs=P("dp"), check_vma=False)
+        f = jax.shard_map(island, mesh=mesh, in_specs=(P("dp"),),
+                          out_specs=P("dp"), check_vma=False)
         out = np.asarray(jax.jit(f)(jnp.asarray(xs)))
         # every device decoded the same bytes — replicated values
         # rebuilt from sharded updates cannot drift
@@ -425,14 +423,13 @@ class TestQuantizedLegPrimitives:
         assert np.all(np.abs(out[0] - flat) <= per_elem / 2 + 1e-6)
 
     def test_divisibility_validated(self, group8):
-        from distributed_pytorch_tpu.runtime.jax_compat import shard_map
         mesh = dist.get_mesh()
         bad = np.zeros((8, 10), np.float32)
         for fn in (prim.quantized_reduce_scatter,
                    prim.quantized_all_gather):
             island = lambda x: fn(x[0], "dp")[None]  # noqa: B023
-            f = shard_map(island, mesh=mesh, in_specs=(P("dp"),),
-                          out_specs=P("dp"), check_vma=False)
+            f = jax.shard_map(island, mesh=mesh, in_specs=(P("dp"),),
+                              out_specs=P("dp"), check_vma=False)
             with pytest.raises(ValueError, match="divisible"):
                 f(jnp.asarray(bad))
 

@@ -2,7 +2,7 @@
 unmodified, and its numerics match both torch's own DDP and this
 framework's JAX DP engine.
 
-Covers the round-1 gaps (VERDICT.md "What's missing" 1 and 3):
+Covers:
 
 - ``/root/reference/min_DDP.py`` (binding ``import distributed as dist``
   at min_DDP.py:7) executes byte-for-byte against
@@ -267,9 +267,9 @@ class TestCrossImplementationParity:
                                    rtol=1e-5, atol=1e-6)
 
     def test_torch_weights_reproduce_in_jax_model(self):
-        """VERDICT 'missing' #3: export torch-initialized DummyModel
-        weights into the JAX model, feed identical batches, and the
-        per-step losses match to float32 tolerance."""
+        """Export torch-initialized DummyModel weights into the JAX
+        model, feed identical batches, and the per-step losses match to
+        float32 tolerance."""
         import jax
         import jax.numpy as jnp
 
