@@ -1,0 +1,113 @@
+"""``BENCHMARK.json`` against the contract's form and the files it names."""
+
+import json
+import os
+import re
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_keys_and_sizes(manifest):
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    assert 1 <= manifest["run_seconds"] <= 51
+    assert isinstance(manifest["run_seconds"], int)
+    cells = len(manifest["workloads"])
+    # a full check with the full 24 cells must fit into 43200 s
+    s = manifest["run_seconds"]
+    assert (2 + 14 * 24) * (s + 60) + 24 * 2 * 90 + 1200 <= 43200
+    assert 1 <= cells <= 24
+
+
+def test_names_and_units(manifest):
+    names = []
+    for section in ("configs", "workloads", "end_to_end", "per_layer"):
+        for entry in manifest[section]:
+            assert NAME.match(entry["name"]), entry["name"]
+            names.append((section in ("end_to_end", "per_layer"),
+                          entry["name"]))
+    assert len(names) == len(set(names)), "a name is used twice"
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        assert UNIT.match(m["unit"]), m["unit"]
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    for m in manifest["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in manifest["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    for w in manifest["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["traffic"]) and w["chips"] in (1, 4)
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+    for c in manifest["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
+
+
+def test_files_exist(manifest):
+    bench = os.path.join(REPO, manifest["paths"][0])
+    used = {w["config"] for w in manifest["workloads"]}
+    for c in manifest["configs"]:
+        assert c["name"] in used, f"{c['name']} is used by no cell"
+        assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
+        with open(os.path.join(REPO, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["source"] == c["source"]
+        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+        assert os.path.exists(os.path.join(
+            bench, "reference", cfg["family"] + ".py"))
+        assert os.path.exists(os.path.join(
+            bench, "adapters", cfg["family"] + ".py"))
+    pairs = set()
+    for w in manifest["workloads"]:
+        assert (w["config"], w["traffic"]) not in pairs
+        pairs.add((w["config"], w["traffic"]))
+        with open(os.path.join(bench, "traffic", w["traffic"] + ".json")) as f:
+            mix = json.load(f)
+        assert os.path.exists(os.path.join(bench, "kinds",
+                                           mix["kind"] + ".py"))
+        with open(os.path.join(bench, "limits", w["name"] + ".json")) as f:
+            for name, entry in json.load(f).items():
+                assert entry["limit"] >= 0 and entry["why"], name
+    for m in manifest["per_layer"]:
+        assert os.path.exists(os.path.join(bench, "layer_metrics",
+                                           m["name"] + ".py")), m["name"]
+
+
+def test_each_layer_metric_moves_one_reported_metric(manifest):
+    cells = [w["name"] for w in manifest["workloads"]]
+    e2e = {m["name"]: m.get("workloads", cells)
+           for m in manifest["end_to_end"]}
+    assert "setup_s" in e2e and "workloads" not in next(
+        m for m in manifest["end_to_end"] if m["name"] == "setup_s")
+    for m in manifest["per_layer"]:
+        assert m["moves"] in e2e and m["moves"] != "setup_s"
+        for cell in m.get("workloads", cells):
+            assert cell in cells
+            assert cell in e2e[m["moves"]], (m["name"], cell)
+    for cell in cells:
+        assert sum(cell in ws for n, ws in e2e.items() if n != "setup_s") >= 1
+        assert any(cell in m.get("workloads", cells)
+                   for m in manifest["per_layer"])
+
+
+def test_at_most_one_four_chip_cell(manifest):
+    four = [w for w in manifest["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(manifest["workloads"]) // 4)
