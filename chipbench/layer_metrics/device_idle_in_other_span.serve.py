@@ -1,0 +1,13 @@
+"""Share of the traced window in which no operation ran on the chip while the
+engine's thread was inside some other span of its own (``serve.iter``'s own
+time, ``serve.decode.dispatch``, ``serve.decode.capacity``,
+``serve.sweep``, ``serve.idle``), in percent of the window. With its three
+siblings it sums to ``device_idle_share.serve``: the same busy union over
+the same window."""
+
+from chipbench import program_trace
+
+
+def read(trace, counters, cell):
+    parts = program_trace.engine_idle_parts(trace, cell)
+    return None if parts is None else parts["other_span"]

@@ -87,29 +87,30 @@ def prefill(model: TransformerLM, params: Params, tokens,
         x = x + model.pos.apply(params["pos"], positions)
     ks, vs = [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        # rope rotates BEFORE caching: the cache holds post-rotation keys
-        hq, hk = blk.attn.maybe_rope(hq, hk, positions)
-        o = blk.attn.attn_fn(hq, hk, hv, causal=True)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
-        hk = hk.astype(cache.k[i].dtype)
-        hv = hv.astype(cache.v[i].dtype)
-        if w is not None:
-            # keep the LAST min(s, w) positions, laid out so position p
-            # sits at slot p % w (roll of the contiguous tail)
-            keep = min(s, w)
-            hk, hv = hk[:, :, -keep:], hv[:, :, -keep:]
-            shift = (s - keep) % w
-            ks.append(jnp.roll(_pad_to(hk, w), shift, axis=2))
-            vs.append(jnp.roll(_pad_to(hv, w), shift, axis=2))
-        else:
-            ks.append(jax.lax.dynamic_update_slice(
-                cache.k[i], hk, (0, 0, 0, 0)))
-            vs.append(jax.lax.dynamic_update_slice(
-                cache.v[i], hv, (0, 0, 0, 0)))
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            # rope rotates BEFORE caching: the cache holds post-rotation keys
+            hq, hk = blk.attn.maybe_rope(hq, hk, positions)
+            o = blk.attn.attn_fn(hq, hk, hv, causal=True)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
+            hk = hk.astype(cache.k[i].dtype)
+            hv = hv.astype(cache.v[i].dtype)
+            if w is not None:
+                # keep the LAST min(s, w) positions, laid out so position p
+                # sits at slot p % w (roll of the contiguous tail)
+                keep = min(s, w)
+                hk, hv = hk[:, :, -keep:], hv[:, :, -keep:]
+                shift = (s - keep) % w
+                ks.append(jnp.roll(_pad_to(hk, w), shift, axis=2))
+                vs.append(jnp.roll(_pad_to(hv, w), shift, axis=2))
+            else:
+                ks.append(jax.lax.dynamic_update_slice(
+                    cache.k[i], hk, (0, 0, 0, 0)))
+                vs.append(jax.lax.dynamic_update_slice(
+                    cache.v[i], hv, (0, 0, 0, 0)))
     x = model.ln_f.apply(params["ln_f"], x[:, -1:])
     logits = model.project_vocab(params, x)[:, 0]
     return logits, KVCache(k=ks, v=vs,
@@ -166,26 +167,27 @@ def decode_step(model: TransformerLM, params: Params, cache: KVCache,
 
     new_k, new_v = [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, idx[None])
-        k = jax.lax.dynamic_update_slice(
-            cache.k[i], hk.astype(cache.k[i].dtype), (0, 0, write_at, 0))
-        v = jax.lax.dynamic_update_slice(
-            cache.v[i], hv.astype(cache.v[i].dtype), (0, 0, write_at, 0))
-        new_k.append(k)
-        new_v.append(v)
-        if blockwise and window is None:
-            # scalar position broadcast to a length-1 batch axis: the
-            # (1, L) validity mask broadcasts over the B rows
-            o = blockwise_decode_attention(hq, k, v, idx[None],
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, idx[None])
+            k = jax.lax.dynamic_update_slice(
+                cache.k[i], hk.astype(cache.k[i].dtype), (0, 0, write_at, 0))
+            v = jax.lax.dynamic_update_slice(
+                cache.v[i], hv.astype(cache.v[i].dtype), (0, 0, write_at, 0))
+            new_k.append(k)
+            new_v.append(v)
+            if blockwise and window is None:
+                # scalar position broadcast to a length-1 batch axis: the
+                # (1, L) validity mask broadcasts over the B rows
+                o = blockwise_decode_attention(hq, k, v, idx[None],
+                                               scale=scale)
+            else:
+                o = dense_decode_attention(hq, k, v, pos_mask[None, :],
                                            scale=scale)
-        else:
-            o = dense_decode_attention(hq, k, v, pos_mask[None, :],
-                                       scale=scale)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
 
     x = model.ln_f.apply(params["ln_f"], x)
     logits = model.project_vocab(params, x)[:, 0]
@@ -223,29 +225,30 @@ def prefill_partial(model: TransformerLM, params: Params, tokens,
         x = x + model.pos.apply(params["pos"], positions)
     ks, vs = [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, positions)
-        o = blk.attn.attn_fn(hq, hk, hv, causal=True)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
-        hk = hk.astype(model.dtype)
-        hv = hv.astype(model.dtype)
-        if window is not None:
-            # rolling layout with a TRACED true_len: slot j holds the
-            # largest real position ≡ j (mod W) — a gather, so no
-            # dynamic shapes (prefill's roll trick needs static lengths)
-            j = jnp.arange(window)
-            p_j = true_len - 1 - ((true_len - 1 - j) % window)
-            valid = (p_j >= 0)[None, None, :, None]
-            take = jnp.take(hk, jnp.clip(p_j, 0, s - 1), axis=2)
-            ks.append(jnp.where(valid, take, 0))
-            take = jnp.take(hv, jnp.clip(p_j, 0, s - 1), axis=2)
-            vs.append(jnp.where(valid, take, 0))
-        else:
-            ks.append(hk)
-            vs.append(hv)
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, positions)
+            o = blk.attn.attn_fn(hq, hk, hv, causal=True)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
+            hk = hk.astype(model.dtype)
+            hv = hv.astype(model.dtype)
+            if window is not None:
+                # rolling layout with a TRACED true_len: slot j holds the
+                # largest real position ≡ j (mod W) — a gather, so no
+                # dynamic shapes (prefill's roll trick needs static lengths)
+                j = jnp.arange(window)
+                p_j = true_len - 1 - ((true_len - 1 - j) % window)
+                valid = (p_j >= 0)[None, None, :, None]
+                take = jnp.take(hk, jnp.clip(p_j, 0, s - 1), axis=2)
+                ks.append(jnp.where(valid, take, 0))
+                take = jnp.take(hv, jnp.clip(p_j, 0, s - 1), axis=2)
+                vs.append(jnp.where(valid, take, 0))
+            else:
+                ks.append(hk)
+                vs.append(hv)
     x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)
     x_last = model.ln_f.apply(params["ln_f"], x_last)
     return model.project_vocab(params, x_last)[:, 0], ks, vs
@@ -298,20 +301,21 @@ def decode_step_slots(model: TransformerLM, params: Params, ks, vs,
 
     new_k, new_v = [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
-        k = jnp.where(write_mask, hk.astype(ks[i].dtype), ks[i])
-        v = jnp.where(write_mask, hv.astype(vs[i].dtype), vs[i])
-        new_k.append(k)
-        new_v.append(v)
-        if blockwise and window is None:
-            o = blockwise_decode_attention(hq, k, v, idx, scale=scale)
-        else:
-            o = dense_decode_attention(hq, k, v, pos_mask, scale=scale)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
+            k = jnp.where(write_mask, hk.astype(ks[i].dtype), ks[i])
+            v = jnp.where(write_mask, hv.astype(vs[i].dtype), vs[i])
+            new_k.append(k)
+            new_v.append(v)
+            if blockwise and window is None:
+                o = blockwise_decode_attention(hq, k, v, idx, scale=scale)
+            else:
+                o = dense_decode_attention(hq, k, v, pos_mask, scale=scale)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
 
     x = model.ln_f.apply(params["ln_f"], x)
     return model.project_vocab(params, x)[:, 0], new_k, new_v
@@ -416,59 +420,61 @@ def decode_step_slots_paged(model: TransformerLM, params: Params,
     new_kp, new_vp = [], []
     new_ks, new_vs, new_kt, new_vt = [], [], [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
-        if kv_bits is None:
-            kp = k_pages[i].at[dest, :, wo].set(
-                hk[:, :, 0, :].astype(k_pages[i].dtype), mode="drop")
-            vp = v_pages[i].at[dest, :, wo].set(
-                hv[:, :, 0, :].astype(v_pages[i].dtype), mode="drop")
-        else:
-            kt = k_tail[i].at[dest_t, :, wo].set(
-                hk[:, :, 0, :].astype(jnp.float32), mode="drop")
-            vt = v_tail[i].at[dest_t, :, wo].set(
-                hv[:, :, 0, :].astype(jnp.float32), mode="drop")
-            qk, sk = quantize_page_blocks(kt, kv_bits)  # (B,Hkv,L,Dh)
-            qv, sv = quantize_page_blocks(vt, kv_bits)
-            if kv_bits == 4:
-                qk, qv = pack_page_nibbles(qk), pack_page_nibbles(qv)
-            kp = k_pages[i].at[dest_q].set(qk, mode="drop")
-            vp = v_pages[i].at[dest_q].set(qv, mode="drop")
-            ks_i = k_scales[i].at[dest_q].set(sk, mode="drop")
-            vs_i = v_scales[i].at[dest_q].set(sv, mode="drop")
-            new_ks.append(ks_i)
-            new_vs.append(vs_i)
-            new_kt.append(kt)
-            new_vt.append(vt)
-        new_kp.append(kp)
-        new_vp.append(vp)
-        if kv_bits is not None:
-            o = paged_decode_attention(hq, kp, vp, tables, idx,
-                                       hk, hv, scale=scale,
-                                       page_len=page_len,
-                                       k_scales=ks_i, v_scales=vs_i,
-                                       k_tail=kt, v_tail=vt)
-        elif blockwise:
-            # the page gather lives inside the block loop; hk/hv are
-            # re-selected at the write position per block — identity
-            # for active rows (already scattered), and gives inactive
-            # rows decode_step_slots' exact value semantics (their
-            # discarded logits still see "their" key)
-            o = paged_decode_attention(hq, kp, vp, tables, idx,
-                                       hk, hv, scale=scale,
-                                       page_len=page_len)
-        else:
-            # logical rows: gather the updated pool, then re-select the
-            # new key at the write position
-            k = jnp.where(write_mask, hk.astype(kp.dtype),
-                          _gather_pages(kp, tables))
-            v = jnp.where(write_mask, hv.astype(vp.dtype),
-                          _gather_pages(vp, tables))
-            o = dense_decode_attention(hq, k, v, pos_mask, scale=scale)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
+            with jax.named_scope("page_write"):
+                if kv_bits is None:
+                    kp = k_pages[i].at[dest, :, wo].set(
+                        hk[:, :, 0, :].astype(k_pages[i].dtype), mode="drop")
+                    vp = v_pages[i].at[dest, :, wo].set(
+                        hv[:, :, 0, :].astype(v_pages[i].dtype), mode="drop")
+                else:
+                    kt = k_tail[i].at[dest_t, :, wo].set(
+                        hk[:, :, 0, :].astype(jnp.float32), mode="drop")
+                    vt = v_tail[i].at[dest_t, :, wo].set(
+                        hv[:, :, 0, :].astype(jnp.float32), mode="drop")
+                    qk, sk = quantize_page_blocks(kt, kv_bits)  # (B,Hkv,L,Dh)
+                    qv, sv = quantize_page_blocks(vt, kv_bits)
+                    if kv_bits == 4:
+                        qk, qv = pack_page_nibbles(qk), pack_page_nibbles(qv)
+                    kp = k_pages[i].at[dest_q].set(qk, mode="drop")
+                    vp = v_pages[i].at[dest_q].set(qv, mode="drop")
+                    ks_i = k_scales[i].at[dest_q].set(sk, mode="drop")
+                    vs_i = v_scales[i].at[dest_q].set(sv, mode="drop")
+                    new_ks.append(ks_i)
+                    new_vs.append(vs_i)
+                    new_kt.append(kt)
+                    new_vt.append(vt)
+            new_kp.append(kp)
+            new_vp.append(vp)
+            if kv_bits is not None:
+                o = paged_decode_attention(hq, kp, vp, tables, idx,
+                                           hk, hv, scale=scale,
+                                           page_len=page_len,
+                                           k_scales=ks_i, v_scales=vs_i,
+                                           k_tail=kt, v_tail=vt)
+            elif blockwise:
+                # the page gather lives inside the block loop; hk/hv are
+                # re-selected at the write position per block — identity
+                # for active rows (already scattered), and gives inactive
+                # rows decode_step_slots' exact value semantics (their
+                # discarded logits still see "their" key)
+                o = paged_decode_attention(hq, kp, vp, tables, idx,
+                                           hk, hv, scale=scale,
+                                           page_len=page_len)
+            else:
+                # logical rows: gather the updated pool, then re-select the
+                # new key at the write position
+                k = jnp.where(write_mask, hk.astype(kp.dtype),
+                              _gather_pages(kp, tables))
+                v = jnp.where(write_mask, hv.astype(vp.dtype),
+                              _gather_pages(vp, tables))
+                o = dense_decode_attention(hq, k, v, pos_mask, scale=scale)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
 
     x = model.ln_f.apply(params["ln_f"], x)
     logits = model.project_vocab(params, x)[:, 0]
@@ -564,92 +570,94 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
     new_kp, new_vp = [], []
     new_ks, new_vs, new_kt, new_vt = [], [], [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, positions)
-        if kv_bits is None:
-            kp = k_pages[i].at[dest, :, dest_off].set(
-                jnp.moveaxis(hk[0], 1, 0).astype(k_pages[i].dtype),
-                mode="drop")
-            vp = v_pages[i].at[dest, :, dest_off].set(
-                jnp.moveaxis(hv[0], 1, 0).astype(v_pages[i].dtype),
-                mode="drop")
-        else:
-            kp, vp = k_pages[i], v_pages[i]
-            ks_i, vs_i = k_scales[i], v_scales[i]
-            for c in range(n_chunks):
-                lo = c * page_len
-                ck = hk[0, :, lo:lo + page_len, :].astype(jnp.float32)
-                cv = hv[0, :, lo:lo + page_len, :].astype(jnp.float32)
-                qk, sk = quantize_page_blocks(ck, kv_bits)
-                qv, sv = quantize_page_blocks(cv, kv_bits)
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, positions)
+            with jax.named_scope("page_write"):
+                if kv_bits is None:
+                    kp = k_pages[i].at[dest, :, dest_off].set(
+                        jnp.moveaxis(hk[0], 1, 0).astype(k_pages[i].dtype),
+                        mode="drop")
+                    vp = v_pages[i].at[dest, :, dest_off].set(
+                        jnp.moveaxis(hv[0], 1, 0).astype(v_pages[i].dtype),
+                        mode="drop")
+                else:
+                    kp, vp = k_pages[i], v_pages[i]
+                    ks_i, vs_i = k_scales[i], v_scales[i]
+                    for c in range(n_chunks):
+                        lo = c * page_len
+                        ck = hk[0, :, lo:lo + page_len, :].astype(jnp.float32)
+                        cv = hv[0, :, lo:lo + page_len, :].astype(jnp.float32)
+                        qk, sk = quantize_page_blocks(ck, kv_bits)
+                        qv, sv = quantize_page_blocks(cv, kv_bits)
+                        if kv_bits == 4:
+                            qk, qv = (pack_page_nibbles(qk),
+                                      pack_page_nibbles(qv))
+                        # incomplete chunks route out of bounds and drop; the
+                        # page index gather clamps harmlessly for them
+                        comp = (lo + page_len) <= true_len
+                        dpi = jnp.where(
+                            comp,
+                            table_row[jnp.clip(offset // page_len + c, 0,
+                                               table_row.shape[0] - 1)],
+                            n_pages)
+                        kp = kp.at[dpi].set(qk, mode="drop")
+                        vp = vp.at[dpi].set(qv, mode="drop")
+                        ks_i = ks_i.at[dpi].set(sk, mode="drop")
+                        vs_i = vs_i.at[dpi].set(sv, mode="drop")
+                    tk = jnp.where(t_valid,
+                                   jnp.take(hk[0], t_src, axis=1), 0.0) \
+                        .astype(jnp.float32)
+                    tv = jnp.where(t_valid,
+                                   jnp.take(hv[0], t_src, axis=1), 0.0) \
+                        .astype(jnp.float32)
+                    kt = k_tail[i].at[slot].set(tk)
+                    vt = v_tail[i].at[slot].set(tv)
+                    new_ks.append(ks_i)
+                    new_vs.append(vs_i)
+                    new_kt.append(kt)
+                    new_vt.append(vt)
+            new_kp.append(kp)
+            new_vp.append(vp)
+            # prefix keys from the (updated) pool; tail keys inline — the
+            # tail pages were just written, but using the in-register tail
+            # avoids a second gather and keeps the math identical to
+            # prefill_partial's [real | pad] layout
+            if kv_bits is not None:
+                # dequantize the gathered prefix pages (the mask exposes
+                # only positions < offset — complete, quantized, shared);
+                # the tail attends in-register EXACT, so cold admissions
+                # (offset == 0) see zero quantization error
+                gk, gv = kp[table_row], vp[table_row]
                 if kv_bits == 4:
-                    qk, qv = (pack_page_nibbles(qk),
-                              pack_page_nibbles(qv))
-                # incomplete chunks route out of bounds and drop; the
-                # page index gather clamps harmlessly for them
-                comp = (lo + page_len) <= true_len
-                dpi = jnp.where(
-                    comp,
-                    table_row[jnp.clip(offset // page_len + c, 0,
-                                       table_row.shape[0] - 1)],
-                    n_pages)
-                kp = kp.at[dpi].set(qk, mode="drop")
-                vp = vp.at[dpi].set(qv, mode="drop")
-                ks_i = ks_i.at[dpi].set(sk, mode="drop")
-                vs_i = vs_i.at[dpi].set(sv, mode="drop")
-            tk = jnp.where(t_valid,
-                           jnp.take(hk[0], t_src, axis=1), 0.0) \
-                .astype(jnp.float32)
-            tv = jnp.where(t_valid,
-                           jnp.take(hv[0], t_src, axis=1), 0.0) \
-                .astype(jnp.float32)
-            kt = k_tail[i].at[slot].set(tk)
-            vt = v_tail[i].at[slot].set(tv)
-            new_ks.append(ks_i)
-            new_vs.append(vs_i)
-            new_kt.append(kt)
-            new_vt.append(vt)
-        new_kp.append(kp)
-        new_vp.append(vp)
-        # prefix keys from the (updated) pool; tail keys inline — the
-        # tail pages were just written, but using the in-register tail
-        # avoids a second gather and keeps the math identical to
-        # prefill_partial's [real | pad] layout
-        if kv_bits is not None:
-            # dequantize the gathered prefix pages (the mask exposes
-            # only positions < offset — complete, quantized, shared);
-            # the tail attends in-register EXACT, so cold admissions
-            # (offset == 0) see zero quantization error
-            gk, gv = kp[table_row], vp[table_row]
-            if kv_bits == 4:
-                gk, gv = unpack_page_nibbles(gk), unpack_page_nibbles(gv)
-            gk = dequantize_page_blocks(gk, ks_i[table_row], bmap)
-            gv = dequantize_page_blocks(gv, vs_i[table_row], bmap)
-            pref_k = gk.transpose(1, 0, 2, 3) \
-                .reshape(1, -1, width, gk.shape[-1]).astype(hk.dtype)
-            pref_v = gv.transpose(1, 0, 2, 3) \
-                .reshape(1, -1, width, gv.shape[-1]).astype(hv.dtype)
-        else:
-            pref_k = kp[table_row].transpose(1, 0, 2, 3) \
-                .reshape(1, -1, width, kp.shape[-1]).astype(hk.dtype)
-            pref_v = vp[table_row].transpose(1, 0, 2, 3) \
-                .reshape(1, -1, width, vp.shape[-1]).astype(hv.dtype)
-        k_all = jnp.concatenate([pref_k, hk], axis=2)   # (1,Hkv,W+S,Dh)
-        v_all = jnp.concatenate([pref_v, hv], axis=2)
-        bq, hh, _, dd = hq.shape
-        hkv = k_all.shape[1]
-        hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
-        logits = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
-            jnp.float32) * scale                     # (1,Hkv,g,S,W+S)
-        logits = jnp.where(mask[None, None, None, :, :], logits,
-                           -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v_all.dtype)
-        o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
-            .reshape(bq, hh, s, dd)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
+                    gk, gv = unpack_page_nibbles(gk), unpack_page_nibbles(gv)
+                gk = dequantize_page_blocks(gk, ks_i[table_row], bmap)
+                gv = dequantize_page_blocks(gv, vs_i[table_row], bmap)
+                pref_k = gk.transpose(1, 0, 2, 3) \
+                    .reshape(1, -1, width, gk.shape[-1]).astype(hk.dtype)
+                pref_v = gv.transpose(1, 0, 2, 3) \
+                    .reshape(1, -1, width, gv.shape[-1]).astype(hv.dtype)
+            else:
+                pref_k = kp[table_row].transpose(1, 0, 2, 3) \
+                    .reshape(1, -1, width, kp.shape[-1]).astype(hk.dtype)
+                pref_v = vp[table_row].transpose(1, 0, 2, 3) \
+                    .reshape(1, -1, width, vp.shape[-1]).astype(hv.dtype)
+            k_all = jnp.concatenate([pref_k, hk], axis=2)   # (1,Hkv,W+S,Dh)
+            v_all = jnp.concatenate([pref_v, hv], axis=2)
+            bq, hh, _, dd = hq.shape
+            hkv = k_all.shape[1]
+            hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
+            logits = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
+                jnp.float32) * scale                     # (1,Hkv,g,S,W+S)
+            logits = jnp.where(mask[None, None, None, :, :], logits,
+                               -jnp.inf)
+            probs = jax.nn.softmax(logits, axis=-1).astype(v_all.dtype)
+            o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
+                .reshape(bq, hh, s, dd)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
 
     x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)
     x_last = model.ln_f.apply(params["ln_f"], x_last)
@@ -702,25 +710,26 @@ def spec_verify_slots(model: TransformerLM, params: Params, ks, vs,
 
     sk_out, sv_out = [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, positions[:, None, :])
-        sk_out.append(hk.astype(jnp.float32))
-        sv_out.append(hv.astype(jnp.float32))
-        k_all = jnp.concatenate([ks[i].astype(hk.dtype), hk], axis=2)
-        v_all = jnp.concatenate([vs[i].astype(hv.dtype), hv], axis=2)
-        bq, hh, _, dd = hq.shape
-        hkv = k_all.shape[1]
-        hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
-        att = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
-            jnp.float32) * scale
-        att = jnp.where(mask[:, None, None, :, :], att, -jnp.inf)
-        probs = jax.nn.softmax(att, axis=-1).astype(v_all.dtype)
-        o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
-            .reshape(bq, hh, s, dd)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, positions[:, None, :])
+            sk_out.append(hk.astype(jnp.float32))
+            sv_out.append(hv.astype(jnp.float32))
+            k_all = jnp.concatenate([ks[i].astype(hk.dtype), hk], axis=2)
+            v_all = jnp.concatenate([vs[i].astype(hv.dtype), hv], axis=2)
+            bq, hh, _, dd = hq.shape
+            hkv = k_all.shape[1]
+            hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
+            att = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
+                jnp.float32) * scale
+            att = jnp.where(mask[:, None, None, :, :], att, -jnp.inf)
+            probs = jax.nn.softmax(att, axis=-1).astype(v_all.dtype)
+            o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
+                .reshape(bq, hh, s, dd)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
 
     x = model.ln_f.apply(params["ln_f"], x)
     return model.project_vocab(params, x), sk_out, sv_out
@@ -805,43 +814,44 @@ def spec_verify_slots_paged(model: TransformerLM, params: Params,
 
     sk_out, sv_out = [], []
     for i, blk in enumerate(model.blocks):
-        p = params["blocks"][i]
-        hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                          blk.ln1.apply(p["ln1"], x))
-        hq, hk = blk.attn.maybe_rope(hq, hk, positions[:, None, :])
-        sk_out.append(hk.astype(jnp.float32))
-        sv_out.append(hv.astype(jnp.float32))
-        if kv_bits is None:
-            gk = _gather_pages(k_pages[i], tables).astype(hk.dtype)
-            gv = _gather_pages(v_pages[i], tables).astype(hv.dtype)
-        else:
-            qk, qv = k_pages[i][tables], v_pages[i][tables]
-            if kv_bits == 4:
-                qk, qv = unpack_page_nibbles(qk), unpack_page_nibbles(qv)
-            dk = dequantize_page_blocks(qk, k_scales[i][tables], bmap)
-            dv = dequantize_page_blocks(qv, v_scales[i][tables], bmap)
-            bb, pp, hh_kv, ll, dd_h = dk.shape
-            gk = dk.transpose(0, 2, 1, 3, 4).reshape(bb, hh_kv,
-                                                     pp * ll, dd_h)
-            gv = dv.transpose(0, 2, 1, 3, 4).reshape(bb, hh_kv,
-                                                     pp * ll, dd_h)
-            gk = jnp.where(tail_sel, k_tail[i][:, :, toff, :], gk) \
-                .astype(hk.dtype)
-            gv = jnp.where(tail_sel, v_tail[i][:, :, toff, :], gv) \
-                .astype(hv.dtype)
-        k_all = jnp.concatenate([gk, hk], axis=2)
-        v_all = jnp.concatenate([gv, hv], axis=2)
-        bq, hh, _, dd = hq.shape
-        hkv = k_all.shape[1]
-        hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
-        att = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
-            jnp.float32) * scale
-        att = jnp.where(mask[:, None, None, :, :], att, -jnp.inf)
-        probs = jax.nn.softmax(att, axis=-1).astype(v_all.dtype)
-        o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
-            .reshape(bq, hh, s, dd)
-        x = x + blk.attn.project_out(p["attn"], o)
-        x = x + blk.mlp(p, x)
+        with jax.named_scope("blocks"):
+            p = params["blocks"][i]
+            hq, hk, hv = blk.attn.project_qkv(p["attn"],
+                                              blk.ln1.apply(p["ln1"], x))
+            hq, hk = blk.attn.maybe_rope(hq, hk, positions[:, None, :])
+            sk_out.append(hk.astype(jnp.float32))
+            sv_out.append(hv.astype(jnp.float32))
+            if kv_bits is None:
+                gk = _gather_pages(k_pages[i], tables).astype(hk.dtype)
+                gv = _gather_pages(v_pages[i], tables).astype(hv.dtype)
+            else:
+                qk, qv = k_pages[i][tables], v_pages[i][tables]
+                if kv_bits == 4:
+                    qk, qv = unpack_page_nibbles(qk), unpack_page_nibbles(qv)
+                dk = dequantize_page_blocks(qk, k_scales[i][tables], bmap)
+                dv = dequantize_page_blocks(qv, v_scales[i][tables], bmap)
+                bb, pp, hh_kv, ll, dd_h = dk.shape
+                gk = dk.transpose(0, 2, 1, 3, 4).reshape(bb, hh_kv,
+                                                         pp * ll, dd_h)
+                gv = dv.transpose(0, 2, 1, 3, 4).reshape(bb, hh_kv,
+                                                         pp * ll, dd_h)
+                gk = jnp.where(tail_sel, k_tail[i][:, :, toff, :], gk) \
+                    .astype(hk.dtype)
+                gv = jnp.where(tail_sel, v_tail[i][:, :, toff, :], gv) \
+                    .astype(hv.dtype)
+            k_all = jnp.concatenate([gk, hk], axis=2)
+            v_all = jnp.concatenate([gv, hv], axis=2)
+            bq, hh, _, dd = hq.shape
+            hkv = k_all.shape[1]
+            hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
+            att = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
+                jnp.float32) * scale
+            att = jnp.where(mask[:, None, None, :, :], att, -jnp.inf)
+            probs = jax.nn.softmax(att, axis=-1).astype(v_all.dtype)
+            o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
+                .reshape(bq, hh, s, dd)
+            x = x + blk.attn.project_out(p["attn"], o)
+            x = x + blk.mlp(p, x)
 
     x = model.ln_f.apply(params["ln_f"], x)
     return model.project_vocab(params, x), sk_out, sv_out
@@ -912,6 +922,7 @@ def spec_commit_slots_paged(k_pages, v_pages, tables, lengths, sk, sv,
     return kp, vp, ksc, vsc, kt, vt
 
 
+@jax.named_scope("sample")
 def _sample(logits, rng, temperature: float, top_k: Optional[int],
             top_p: Optional[float] = None):
     if temperature == 0.0:

@@ -114,7 +114,7 @@ class TransformerLM(Module):
                              attn_fn=attn_fn, dtype=dtype)
             for _ in range(n_layers)
         ]
-        self.ln_f = LayerNorm(dim, dtype=dtype)
+        self.ln_f = LayerNorm(dim, dtype=dtype, scope="ln_f")
         # tied embeddings (the GPT-2 recipe): the vocab projection reuses
         # the token table transposed — no head parameter exists
         self.tie_embeddings = tie_embeddings
@@ -148,7 +148,8 @@ class TransformerLM(Module):
         """Hidden states (..., dim) → logits (..., vocab). Single source
         of truth for the output projection (training apply and the cached
         decode path both route through it)."""
-        return jnp.matmul(x, self.head_weight(params))
+        with jax.named_scope("head"):
+            return jnp.matmul(x, self.head_weight(params))
 
     def apply(self, params: Params, tokens, *, rng=None, train: bool = False,
               pos_offset=0, positions=None, return_hidden: bool = False,
@@ -177,8 +178,11 @@ class TransformerLM(Module):
             r = jax.random.fold_in(rng, i) if rng is not None else None
 
             def run_block(p, x, blk=blk, r=r):
-                return blk.apply(p, x, rng=r, train=train,
-                                 positions=positions)
+                # one name for every layer: readers of a trace sum over
+                # layers, and XLA may still share their computations
+                with jax.named_scope("blocks"):
+                    return blk.apply(p, x, rng=r, train=train,
+                                     positions=positions)
 
             # per-layer remat policy: "full" recomputes the block in
             # backward instead of saving its activations (~1/3 more

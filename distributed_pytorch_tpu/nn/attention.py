@@ -117,18 +117,20 @@ class MultiHeadAttention(Module):
         KV cache through this method."""
         b, s, _ = x.shape
         dh, h, hkv = self.head_dim, self.n_heads, self.n_kv_heads
-        qkv = self.qkv.apply(params["qkv"], x)      # (B, S, (H+2Hkv)*Dh)
-        q, k, v = jnp.split(qkv, [h * dh, (h + hkv) * dh], axis=-1)
+        with jax.named_scope("attn/qkv"):
+            qkv = self.qkv.apply(params["qkv"], x)  # (B, S, (H+2Hkv)*Dh)
+            q, k, v = jnp.split(qkv, [h * dh, (h + hkv) * dh], axis=-1)
 
-        def heads(t, n):
-            return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
-        return heads(q, h), heads(k, hkv), heads(v, hkv)
+            def heads(t, n):
+                return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
+            return heads(q, h), heads(k, hkv), heads(v, hkv)
 
     def project_out(self, params: Params, o):
         """o (B, H, S, Dh) → output projection (B, S, D)."""
         b, h, s, dh = o.shape
-        return self.out.apply(params["out"],
-                              o.transpose(0, 2, 1, 3).reshape(b, s, h * dh))
+        with jax.named_scope("attn/out"):
+            return self.out.apply(
+                params["out"], o.transpose(0, 2, 1, 3).reshape(b, s, h * dh))
 
     def maybe_rope(self, q, k, positions=None):
         """Rotate q/k when built with ``rope=True`` (no-op otherwise).
@@ -141,13 +143,15 @@ class MultiHeadAttention(Module):
             return q, k
         if positions is None:
             positions = jnp.arange(q.shape[2])
-        return (apply_rope(q, positions, self.rope_base),
-                apply_rope(k, positions, self.rope_base))
+        with jax.named_scope("attn/qkv"):
+            return (apply_rope(q, positions, self.rope_base),
+                    apply_rope(k, positions, self.rope_base))
 
     def apply(self, params: Params, x, *, positions=None, **kwargs):
         q, k, v = self.project_qkv(params, x)
         q, k = self.maybe_rope(q, k, positions)
-        o = self.attn_fn(q, k, v, causal=self.causal)
+        with jax.named_scope("attn/core"):
+            o = self.attn_fn(q, k, v, causal=self.causal)
         return self.project_out(params, o)
 
 
@@ -179,9 +183,10 @@ class TransformerBlock(Module):
     def mlp(self, params: Params, x):
         """LN → fc1 → GELU → fc2 (no residual/dropout). Shared by apply
         and the cached decode path (models/generate.py)."""
-        return self.fc2.apply(params["fc2"],
-                              gelu(self.fc1.apply(params["fc1"],
-                                                  self.ln2.apply(params["ln2"], x))))
+        h = self.ln2.apply(params["ln2"], x)
+        with jax.named_scope("mlp"):
+            return self.fc2.apply(params["fc2"],
+                                  gelu(self.fc1.apply(params["fc1"], h)))
 
     def apply(self, params: Params, x, *, rng=None, train: bool = False,
               positions=None, **_):

@@ -7,6 +7,11 @@ what compiles cleanly under ``jit``/``pjit``: parameters are explicit inputs
 the sharding machinery can annotate (replicated for DP, axis-sharded for TP),
 and a whole training step closes over nothing.
 
+The modules a transformer is made of put their kind into the JAX name
+stack (``jax.named_scope``: ``embed``, ``norm``): names only, the
+computation is the same, and a profiler trace can then say which layer a
+fused device operation belongs to (PERF.md section 3 lists every scope).
+
 Initialization follows the same fan-in uniform scheme torch's ``Linear``
 uses (U(-1/sqrt(fan_in), 1/sqrt(fan_in)) for both weight and bias), so
 model-quality behavior matches the reference workload's.
@@ -88,19 +93,23 @@ class Embedding(Module):
             key, (self.vocab, self.dim)).astype(self.dtype)}
 
     def apply(self, params: Params, ids, **_):
-        if "emb" in params:
-            return jnp.take(params["emb"], ids, axis=0)
-        # int8 table (ops/quant.py): gather the int8 rows, dequantize
-        # only what was looked up
-        rows = jnp.take(params["emb_q"], ids, axis=0).astype(self.dtype)
-        return rows * params["emb_scale"].astype(self.dtype)
+        with jax.named_scope("embed"):
+            if "emb" in params:
+                return jnp.take(params["emb"], ids, axis=0)
+            # int8 table (ops/quant.py): gather the int8 rows, dequantize
+            # only what was looked up
+            rows = jnp.take(params["emb_q"], ids,
+                            axis=0).astype(self.dtype)
+            return rows * params["emb_scale"].astype(self.dtype)
 
 
 class LayerNorm(Module):
-    def __init__(self, dim: int, eps: float = 1e-5, dtype=jnp.float32):
+    def __init__(self, dim: int, eps: float = 1e-5, dtype=jnp.float32,
+                 scope: str = "norm"):
         self.dim = dim
         self.eps = eps
         self.dtype = dtype
+        self.scope = scope      # its name in the JAX name stack
 
     def init(self, key) -> Params:
         del key
@@ -108,10 +117,11 @@ class LayerNorm(Module):
                 "bias": jnp.zeros((self.dim,), self.dtype)}
 
     def apply(self, params: Params, x, **_):
-        mu = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        y = (x - mu) * jax.lax.rsqrt(var + self.eps)
-        return y * params["scale"] + params["bias"]
+        with jax.named_scope(self.scope):
+            mu = jnp.mean(x, axis=-1, keepdims=True)
+            var = jnp.var(x, axis=-1, keepdims=True)
+            y = (x - mu) * jax.lax.rsqrt(var + self.eps)
+            return y * params["scale"] + params["bias"]
 
 
 class Dropout(Module):

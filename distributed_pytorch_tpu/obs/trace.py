@@ -18,6 +18,18 @@ by side. This module is the spine that makes that view exist:
   import) so cross-process merges have a common time base without any
   per-span wall read. Ambient nesting is per-thread (the serve engine
   thread's spans parent under its own stack, never the submitter's).
+* **Profiler annotations** — every span is ALSO a
+  ``jax.profiler.TraceAnnotation("dpx:" + name, **attrs)`` for its
+  duration. Whenever a ``jax.profiler`` session is running
+  (``utils.profiler.trace``, a benchmark's traced run) the span lands
+  on the host plane of the same ``.xplane.pb`` as the device ops, on
+  its own thread's line, on the ONE clock all planes share — so a gap
+  between two device programs can be laid against what the host was
+  doing in it. The profiler session is the switch: no ``DPX_TRACE``
+  needed, and with no session :func:`span` asks ``TraceMe.is_enabled()``
+  and builds nothing.
+  (:func:`emit_span` records an interval that is already over and so
+  cannot be an annotation: ring and sink only.)
 * **Flight recorder** — every finished span also lands in a bounded
   per-process ring (``DPX_TRACE_RING`` spans, drop-counted). Typed
   failure paths (``CommError``, ``HandoffError``, ``PagePoolExhausted``,
@@ -33,9 +45,11 @@ by side. This module is the spine that makes that view exist:
   the straggler detector (:mod:`.detect`).
 
 Overhead contract (gated in ``bench.py --smoke``): with ``DPX_TRACE``
-off, :func:`span` is one module-global read + one ``if`` returning a
-shared no-op context manager — unmeasurable next to any op worth
-tracing. With tracing on, a span costs one ``perf_counter_ns`` pair,
+off and no profiler session, :func:`span` is two module-global reads,
+one ``if`` and ``TraceMe.is_enabled()``, returning a shared no-op
+context manager (half a microsecond; PERF.md has the chip host's
+figure) — unmeasurable next to any op worth tracing. With tracing on, a
+span costs one ``perf_counter_ns`` pair,
 a dict build, a ring append and one locked O_APPEND write; the smoke
 asserts the per-step total stays a small fraction of the dp8 step.
 
@@ -46,9 +60,10 @@ are monotone non-decreasing even when the system clock steps (NTP).
 The dpxlint rule DPX007 keeps ``time.time()`` out of duration math
 package-wide.
 
-Everything here is stdlib-only; the env registry is imported lazily so
-``tools/dpxtrace.py`` can load this module in a bare venv without the
-heavy package ``__init__`` (the ``analysis/lint.py`` contract).
+Everything here is stdlib-only at import; the env registry and JAX's
+profiler are imported lazily, on first use, so ``tools/dpxtrace.py`` can
+load this module in a bare venv without the heavy package ``__init__``
+(the ``analysis/lint.py`` contract). No JAX means no annotation.
 """
 
 from __future__ import annotations
@@ -62,7 +77,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
-    "TRACE_ENV", "RING_ENV", "LOG_ENV",
+    "TRACE_ENV", "RING_ENV", "LOG_ENV", "ANNOTATION_PREFIX",
     "span", "event", "emit_span", "new_trace_id", "enabled", "refresh",
     "configure", "set_rank", "wall_now", "wall_from_ns", "wall_from_mono",
     "flight_snapshot", "flight_dump", "on_typed_failure", "reset",
@@ -242,6 +257,37 @@ def _stack() -> List["_Span"]:
 # ---------------------------------------------------------------------------
 
 
+#: Prefix of every span's name on the profiler's host plane.
+ANNOTATION_PREFIX = "dpx:"
+
+_annotation = None      # None: not looked for yet; False: JAX is absent
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` with the no-op span's surface
+    (what :func:`span` hands out while ``DPX_TRACE`` is off), or False
+    where JAX cannot be imported."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:
+            _annotation = False
+        else:
+            class _Annotation(TraceAnnotation):
+                __slots__ = ()
+                span_id = trace_id = None
+
+                def event(self, name: str, **attrs) -> None:
+                    pass
+
+                def set(self, **attrs) -> None:
+                    self.set_metadata(**attrs)
+
+            _annotation = _Annotation
+    return _annotation
+
+
 def _record(st: _State, rec: Dict[str, Any]) -> None:
     """Ring append (drop-counted) + line-JSON sink. Never raises: a
     tracing failure must not take down the traced op.
@@ -295,6 +341,9 @@ class _NullSpan:
     def event(self, name: str, **attrs) -> None:
         pass
 
+    def set(self, **attrs) -> None:
+        pass
+
     @property
     def span_id(self) -> None:
         return None
@@ -307,7 +356,8 @@ _NULL = _NullSpan()
 
 class _Span:
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "tid",
-                 "attrs", "events", "t0_ns", "t1_ns", "_st", "_ambient")
+                 "attrs", "events", "t0_ns", "t1_ns", "_st", "_ambient",
+                 "_ann")
 
     def __init__(self, st: _State, name: str, trace_id: Optional[str],
                  parent_id: Optional[str], tid: Optional[str],
@@ -333,11 +383,20 @@ class _Span:
                 self.trace_id = top.trace_id
         stack.append(self)
         self._ambient = True
+        ann = _annotation_class()
+        self._ann = False
+        if ann and ann.is_enabled():        # a profiler session is running
+            attrs = self.attrs if self.trace_id is None \
+                else {**self.attrs, "trace_id": self.trace_id}
+            self._ann = ann(ANNOTATION_PREFIX + self.name, **attrs)
+            self._ann.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1_ns = time.perf_counter_ns()
+        if self._ann:
+            self._ann.__exit__(exc_type, exc, tb)
         if self._ambient:
             stack = _stack()
             if stack and stack[-1] is self:
@@ -352,6 +411,13 @@ class _Span:
     def event(self, name: str, **attrs) -> None:
         """Instant event attached to this span's timeline."""
         self.events.append((name, time.perf_counter_ns(), attrs))
+
+    def set(self, **attrs) -> None:
+        """Attributes learned while the span is open (a batch's size
+        once it is known)."""
+        self.attrs.update(attrs)
+        if self._ann:
+            self._ann.set_metadata(**attrs)
 
     def _finish(self) -> None:
         st = self._st
@@ -380,14 +446,23 @@ def span(name: str, *, trace_id: Optional[str] = None,
          **attrs):
     """Open a timed span as a context manager.
 
-    Disabled tracing returns a shared no-op (one global read + one
-    ``if`` — the near-zero-overhead contract the bench smoke gates).
+    The span is a profiler annotation ``dpx:<name>`` carrying ``attrs``
+    (and ``trace_id`` where given) whatever ``DPX_TRACE`` says. With
+    tracing disabled that is ALL it is, and with no profiler session
+    either (or no JAX) it is a shared no-op: two global reads, one
+    ``if`` and ``TraceMe.is_enabled()`` — the near-zero-overhead
+    contract the bench smoke gates.
     ``trace_id``/``parent_id`` default to the ambient per-thread span
     stack; pass them explicitly to stitch lineage across threads (the
     serve request lifecycle does)."""
     st = _state if _state is not None else _init()
     if not st.enabled:
-        return _NULL
+        ann = _annotation if _annotation is not None else _annotation_class()
+        if not ann or not ann.is_enabled():   # no JAX, or no session
+            return _NULL
+        if trace_id is not None:
+            attrs["trace_id"] = trace_id
+        return ann(ANNOTATION_PREFIX + name, **attrs)
     return _Span(st, name, trace_id, parent_id, tid, attrs)
 
 

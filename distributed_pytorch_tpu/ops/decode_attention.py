@@ -75,6 +75,7 @@ def resident_blocks(lengths, block_len: int, total_blocks: int):
     return jnp.minimum(jnp.max(lengths) // block_len + 1, total_blocks)
 
 
+@jax.named_scope("decode_attention")
 def dense_decode_attention(hq, k, v, pos_mask, *, scale):
     """The dense full-width decode softmax — the REFERENCE the
     blockwise kernel is contract-tested against, and the baseline the
@@ -133,6 +134,7 @@ def _finish(m, l, acc, out_dtype):
     return (acc / l_safe[..., None]).astype(out_dtype)
 
 
+@jax.named_scope("decode_attention")
 def blockwise_decode_attention(hq, k, v, idx, *, scale,
                                block_len: Optional[int] = None):
     """Single-token attention over a CONTIGUOUS cache, blockwise.
@@ -182,6 +184,7 @@ def blockwise_decode_attention(hq, k, v, idx, *, scale,
     return _finish(m, l, acc, v.dtype).reshape(b, h, 1, dh)
 
 
+@jax.named_scope("decode_attention")
 def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
                            new_v, *, scale, page_len: int,
                            k_scales=None, v_scales=None,
@@ -230,25 +233,27 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
         tail_page = idx // page_len
 
     def body(j, carry):
-        pids = jax.lax.dynamic_index_in_dim(tables, j, axis=1,
-                                            keepdims=False)     # (B,)
-        k_blk = jnp.take(k_pages, pids, axis=0)  # (B, Hkv, L, Dh)
-        v_blk = jnp.take(v_pages, pids, axis=0)
         pos = j * page_len + jnp.arange(page_len)
-        if quant:
-            if packed:
-                k_blk = unpack_page_nibbles(k_blk)
-                v_blk = unpack_page_nibbles(v_blk)
-            k_blk = dequantize_page_blocks(
-                k_blk, jnp.take(k_scales, pids, axis=0), bmap)
-            v_blk = dequantize_page_blocks(
-                v_blk, jnp.take(v_scales, pids, axis=0), bmap)
-            # the slot's CURRENT page is exact: overlay the f32 tail
-            # buffer before the write-mask overlay (order matters — wm
-            # must still win for inactive rows' value semantics)
-            it = (j == tail_page)[:, None, None, None]
-            k_blk = jnp.where(it, k_tail, k_blk)
-            v_blk = jnp.where(it, v_tail, v_blk)
+        with jax.named_scope("page_gather"):
+            pids = jax.lax.dynamic_index_in_dim(tables, j, axis=1,
+                                                keepdims=False)  # (B,)
+            k_blk = jnp.take(k_pages, pids, axis=0)  # (B, Hkv, L, Dh)
+            v_blk = jnp.take(v_pages, pids, axis=0)
+            if quant:
+                if packed:
+                    k_blk = unpack_page_nibbles(k_blk)
+                    v_blk = unpack_page_nibbles(v_blk)
+                k_blk = dequantize_page_blocks(
+                    k_blk, jnp.take(k_scales, pids, axis=0), bmap)
+                v_blk = dequantize_page_blocks(
+                    v_blk, jnp.take(v_scales, pids, axis=0), bmap)
+                # the slot's CURRENT page is exact: overlay the f32
+                # tail buffer before the write-mask overlay (order
+                # matters — wm must still win for inactive rows' value
+                # semantics)
+                it = (j == tail_page)[:, None, None, None]
+                k_blk = jnp.where(it, k_tail, k_blk)
+                v_blk = jnp.where(it, v_tail, v_blk)
         wm = (pos[None, :] == idx[:, None])[:, None, :, None]
         k_blk = jnp.where(wm, nk_g.astype(k_blk.dtype), k_blk)
         v_blk = jnp.where(wm, nv_g.astype(v_blk.dtype), v_blk)

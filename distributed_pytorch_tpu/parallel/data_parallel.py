@@ -93,7 +93,9 @@ def _wrap_mixed_precision(loss_fn: Callable, policy: str) -> Callable:
         return loss_fn
 
     def mp_loss(params, batch):
-        return loss_fn(mp_cast_params(params), batch)
+        with jax.named_scope("cast"):
+            working = mp_cast_params(params)
+        return loss_fn(working, batch)
 
     return mp_loss
 

@@ -68,8 +68,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import metrics as _dpxmon
+from ..obs import trace as _dpxtrace
 from ..optim import Optimizer
-from ..runtime import context
+from ..runtime import compile_cache, context
 from ..runtime.context import DATA_AXIS
 from .data_parallel import (GRAD_REDUCE_MODES, MP_POLICIES, StepOutput,
                             _wire_format, _wrap_mixed_precision)
@@ -143,15 +144,22 @@ def _place(tree, shardings):
     compile the same program twice; placing first makes every call the
     same type. The steady state — the step fed its own outputs — is
     recognized leaf by leaf and costs no ``device_put`` dispatch."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    want = ([shardings] * len(leaves)
-            if isinstance(shardings, NamedSharding)
-            else jax.tree_util.tree_leaves(shardings))
-    if len(want) == len(leaves) and all(
-            getattr(leaf, "sharding", None) == w
-            for leaf, w in zip(leaves, want)):
-        return tree
-    return jax.device_put(tree, shardings)
+    with _dpxtrace.span("train.place"):
+        leaves = jax.tree_util.tree_leaves(tree)
+        want = ([shardings] * len(leaves)
+                if isinstance(shardings, NamedSharding)
+                else jax.tree_util.tree_leaves(shardings))
+        if len(want) == len(leaves) and all(
+                getattr(leaf, "sharding", None) == w
+                for leaf, w in zip(leaves, want)):
+            return tree
+        return jax.device_put(tree, shardings)
+
+
+def _dispatch(prog, *args):
+    """Hand the step program its (already placed) arguments."""
+    with _dpxtrace.span("train.dispatch"):
+        return prog(*args)
 
 
 #: Bounded LRU of built steps. The cache exists for the no-silent-
@@ -188,7 +196,10 @@ class FrontDoorStep:
 
     * ``trace_counts`` — program key (wire width) -> times traced;
       ``compiles`` is their sum. One program per (mesh, spec, width)
-      point means every value stays 1.
+      point means every value stays 1. ``xla_compiles`` is the
+      process-wide count beside it (``compile_cache.compile_events``:
+      every program XLA built, whoever asked), and ``calls`` the steps
+      taken.
     * ``in_shardings`` / ``out_shardings`` — dicts with ``params`` /
       ``opt`` / ``batch`` entries (None on the single-device and host
       paths). Params and opt are PINNED equal in/out.
@@ -211,12 +222,17 @@ class FrontDoorStep:
         self._programs: Dict[Any, Any] = {}   # key -> jitted program
         self._counting = True
         self._call = None                      # bound by the builder
+        self.calls = 0
 
     # -- observability ------------------------------------------------------
 
     @property
     def compiles(self) -> int:
         return sum(self.trace_counts.values())
+
+    @property
+    def xla_compiles(self) -> Dict[str, Any]:
+        return compile_cache.compile_events()
 
     def _bump(self, key) -> None:
         # trace-time only: executed while jax traces the program body
@@ -263,7 +279,10 @@ class FrontDoorStep:
     # -- call ---------------------------------------------------------------
 
     def __call__(self, params, opt_state, batch):
-        out = self._call(params, opt_state, batch)
+        self.calls += 1
+        # children: train.place (each tree, in _place) and train.dispatch
+        with _dpxtrace.span("train.step_call", step=self.calls):
+            out = self._call(params, opt_state, batch)
         # dpxmon step hook (obs/metrics.py; one global read when off):
         # the mesh engines' python wrapper is the per-call seam — the
         # host-door builders return their own step functions and hook
@@ -400,6 +419,9 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     remat_policy = resolve_remat(remat)
 
     base_loss = loss_fn
+    # the step's words in the JAX name stack (names only): the loss
+    # closure reads ``jvp(loss)`` and not ``jvp()`` on the device plane
+    loss_fn = jax.named_scope("loss")(loss_fn)
     loss_fn = _wrap_mixed_precision(loss_fn, mixed_precision)
     if remat_policy != "none":
         loss_fn = apply_remat_policy(loss_fn, remat_policy)
@@ -544,7 +566,9 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
                     from ..ops.quant import block_outlier_frac_jnp
                     stat = block_outlier_frac_jnp(
                         red, prim.QUANT_BLOCK, DYNRANGE_THRESH)
-            params, opt_state = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                params, opt_state = optimizer.update(grads, opt_state,
+                                                     params)
             return params, opt_state, loss[None], metrics, stat
         return local_step
 
@@ -556,7 +580,8 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
         step._programs[8] = prog
 
         def call(params, opt_state, batch):
-            return StepOutput(*prog(params, opt_state, batch)[:4])
+            return StepOutput(*_dispatch(prog, params, opt_state,
+                                         batch)[:4])
         step._call = call
         return
 
@@ -584,8 +609,9 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
         step._programs[fixed_bits] = prog
 
         def call(params, opt_state, batch):
-            return StepOutput(*prog(_place(params, rep),
-                                    _place(opt_state, rep), batch)[:4])
+            return StepOutput(*_dispatch(prog, _place(params, rep),
+                                         _place(opt_state, rep),
+                                         batch)[:4])
         step._call = call
         return
 
@@ -601,7 +627,8 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
                            4: compile_width(4, True)})
 
     def call(params, opt_state, batch):
-        p, o, loss, metrics, stat = step._programs[chooser.width](
+        p, o, loss, metrics, stat = _dispatch(
+            step._programs[chooser.width],
             _place(params, rep), _place(opt_state, rep), batch)
         chooser.observe_frac(float(stat))
         return StepOutput(p, o, loss, metrics)
@@ -620,12 +647,13 @@ def _build_propagate(step, loss_fn, optimizer, *, donate):
         step._bump("propagate")
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch)
-        params, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state = optimizer.update(grads, opt_state, params)
         return SpmdStepOutput(params, opt_state, loss, metrics)
 
     prog = jax.jit(body, donate_argnums=(0, 1) if donate else ())
     step._programs["propagate"] = prog
-    step._call = prog
+    step._call = lambda *args: _dispatch(prog, *args)
 
 
 # ---------------------------------------------------------------------------
@@ -658,7 +686,8 @@ def _build_constrained(step, loss_fn, optimizer, mesh, specs: StepSpecs,
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch)
         grads = constrain(grads, grad_specs)   # reduce-scatter/all-reduce
-        params, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state = optimizer.update(grads, opt_state, params)
         params = constrain(params, param_specs)
         opt_state = constrain(opt_state, o_specs)
         return SpmdStepOutput(params, opt_state, loss, metrics)
@@ -684,8 +713,8 @@ def _build_constrained(step, loss_fn, optimizer, mesh, specs: StepSpecs,
                 out_shardings=SpmdStepOutput(p_sh, o_sh, None, None))
             holder["prog"] = prog
             step._programs["constrained"] = prog
-        return prog(_place(params, p_sh),
-                    _place(opt_state, step.in_shardings["opt"]), batch)
+        return _dispatch(prog, _place(params, p_sh),
+                         _place(opt_state, step.in_shardings["opt"]), batch)
 
     step._call = call
 
@@ -752,7 +781,8 @@ def _build_sharded(step, loss_fn, optimizer, mesh, world, *,
                 g_slice = prim.reduce_scatter(flat_g, DATA_AXIS) / world
         else:
             g_slice = flat_g
-        new_master, new_state = sharded.update_flat(g_slice, state)
+        with jax.named_scope("optimizer"):
+            new_master, new_state = sharded.update_flat(g_slice, state)
         if world > 1:
             if quant:
                 flat_new = prim.quantized_all_gather(new_master,
@@ -801,7 +831,7 @@ def _build_sharded(step, loss_fn, optimizer, mesh, world, *,
         if world > 1:
             params = _place(params, step.in_shardings["params"])
             opt_state = _place(opt_state, step.in_shardings["opt"])
-        return holder["compiled"](params, opt_state, batch)
+        return _dispatch(holder["compiled"], params, opt_state, batch)
 
     step._call = call
     step.init_opt_state = init_opt_state

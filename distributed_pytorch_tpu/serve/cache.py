@@ -59,6 +59,16 @@ class CompileCounts:
         self.commit[s] = self.commit.get(s, 0) + 1
 
 
+def named_program(fn, name: str, **bound):
+    """``partial(fn, **bound)`` as ``jax.jit`` will call it: the
+    program's name on the profiler's device plane (``jit_<name>``; a bare
+    ``partial`` reads ``jit__unknown`` there, which no reader of a trace
+    can search for)."""
+    fn = partial(fn, **bound)
+    fn.__name__ = name
+    return fn
+
+
 class SlotPool:
     """Owns the pooled cache arrays and the jitted slot programs."""
 
@@ -165,7 +175,8 @@ class SlotPool:
         bucket = tokens_padded.shape[1]
         fn = self._admit_fns.get(bucket)
         if fn is None:
-            fn = jax.jit(partial(self._admit, bucket=bucket),
+            fn = jax.jit(named_program(self._admit, f"prefill_b{bucket}",
+                                       bucket=bucket),
                          donate_argnums=(1, 2, 3))
             self._admit_fns[bucket] = fn
         logits, self.ks, self.vs, self.lengths = fn(

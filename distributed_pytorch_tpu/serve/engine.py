@@ -23,6 +23,13 @@ which is why the contract is over token streams, not logit bits.
 
 SLO metrics (TTFT/TPOT/queue depth/slot occupancy, defined in
 ``serve.metrics``) flow into the line-JSON ``MetricsLogger`` stream.
+
+Where the engine thread's time goes is on two instruments
+(docs/observability.md): dpxtrace spans ``serve.*`` around every phase
+of an iteration down to one row's sample, fetch and emit — profiler
+annotations, so a ``jax.profiler`` session lays them against the device
+ops — and the always-on ``stats()["host_ns"]`` counters, cumulative
+nanoseconds a phase, read twice an iteration and never per row.
 """
 
 from __future__ import annotations
@@ -224,6 +231,12 @@ class InferenceEngine:
         self._free: List[int] = list(range(cfg.n_slots))[::-1]
         self._cur_tokens = np.zeros(cfg.n_slots, np.int32)
         self._iteration = 0
+        # cumulative engine-thread nanoseconds by phase, and what they
+        # bought (stats(); the serve.host_share.* gauges)
+        self._host_ns = dict.fromkeys(
+            ("idle", "admit", "decode_dispatch", "row_loop", "iter"), 0)
+        self._admitted = 0
+        self._rows_decoded = 0
         self._tokens_emitted = 0
         self._completed = 0
         self._failed = 0
@@ -249,6 +262,10 @@ class InferenceEngine:
         :class:`AdmissionRejected` synchronously when the request can
         never be served (or the bounded queue / the tenant's inflight
         quota is full)."""
+        with dpxtrace.span("serve.submit"):
+            return self._submit(prompt, params, rng, on_token, tenant)
+
+    def _submit(self, prompt, params, rng, on_token, tenant):
         sp = params or SamplingParams()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         with self._cond:
@@ -392,12 +409,17 @@ class InferenceEngine:
         out = {"iterations": self._iteration,
                "completed": self._completed, "failed": self._failed,
                "tokens_emitted": self._tokens_emitted,
+               "admitted": self._admitted,
+               "rows_decoded": self._rows_decoded,
+               "host_ns": dict(self._host_ns),
                "queue_depth": len(self._scheduler),
                "active_slots": len(self._running),
                "n_slots": self.config.n_slots,
                "decode_compiles": c.decode,
                "prefill_compiles": dict(c.prefill),
                "sample_compiles": c.sample,
+               # every program XLA built in this process, whoever asked
+               "xla_compiles": compile_cache.compile_events(),
                "buckets": self.buckets,
                "paged": self._paged,
                "spec_decode": self._spec is not None}
@@ -423,7 +445,9 @@ class InferenceEngine:
     # -- engine loop -------------------------------------------------------
 
     def _loop(self) -> None:
+        clock, host = time.perf_counter_ns, self._host_ns
         while True:
+            t_idle = clock()
             with self._cond:
                 # untimed wait is safe: both transitions out of idle
                 # (submit enqueue, shutdown stop flag) notify under
@@ -431,18 +455,28 @@ class InferenceEngine:
                 # queue AND the running set are empty
                 while (not self._stop and not self._running
                        and not len(self._scheduler)):
-                    # dpxlint: disable=DPX003 untimed wait safe per the invariant above: every idle-exit transition notifies under this lock
-                    self._cond.wait()
+                    with dpxtrace.span("serve.idle"):
+                        # dpxlint: disable=DPX003 untimed wait safe per the invariant above: every idle-exit transition notifies under this lock
+                        self._cond.wait()
                 if self._stop:
                     break
+            t_iter = clock()
+            host["idle"] += t_iter - t_idle
             self._iteration += 1
             try:
-                faults.on_serve_iteration(self._iteration)
-                now = time.monotonic()
-                self._sweep_deadlines(now)
-                self._admit_from_queue()
-                if self._running:
-                    self._decode_all()
+                with dpxtrace.span("serve.iter",
+                                   iteration=self._iteration) as it:
+                    faults.on_serve_iteration(self._iteration)
+                    now = time.monotonic()
+                    with dpxtrace.span("serve.sweep",
+                                       iteration=self._iteration):
+                        self._sweep_deadlines(now)
+                    t_admit = clock()
+                    self._admit_from_queue()
+                    host["admit"] += clock() - t_admit
+                    it.set(rows=len(self._running))
+                    if self._running:
+                        self._decode_all()
             except Exception as e:  # noqa: BLE001
                 # an engine-loop crash (XLA error, bad params) must not
                 # strand every future unresolved: fail them typed, with
@@ -454,6 +488,7 @@ class InferenceEngine:
             if (self.metrics is not None
                     and self._iteration % self.config.log_every == 0):
                 self._emit_snapshot()
+            host["iter"] += clock() - t_iter
         self._drain_on_stop()
 
     def _emit_snapshot(self) -> None:
@@ -470,6 +505,14 @@ class InferenceEngine:
         dpxmon.set_gauge("serve.slot_occupancy",
                          len(self._running) / self.config.n_slots)
         dpxmon.set_gauge("serve.tokens_emitted", self._tokens_emitted)
+        # where the engine thread's time went so far: each phase's
+        # share of the loop's working time, the wait's of all of it
+        host = self._host_ns
+        for phase in ("admit", "decode_dispatch", "row_loop"):
+            dpxmon.set_gauge(f"serve.host_share.{phase}",
+                             host[phase] / max(host["iter"], 1))
+        dpxmon.set_gauge("serve.host_share.idle",
+                         host["idle"] / max(host["idle"] + host["iter"], 1))
         if self._paged:
             ps = self.pool.page_stats()
             dpxmon.set_gauge("serve.pool_occupancy",
@@ -516,71 +559,86 @@ class InferenceEngine:
             req = self._scheduler.pop()
             if req is None:
                 return
-            slot = self._free.pop()
-            # claim the slot BEFORE the prefill call: if it raises, the
-            # crash drain finds the request in _running and fails its
-            # future instead of stranding it half-admitted
-            req.state = RUNNING
-            req.slot = slot
-            self._running[slot] = req
-            s = int(req.prompt.shape[0])
-            if self._paged:
-                try:
-                    logits, n_hit, offset = self.pool.admit(
-                        self.params, req.prompt, slot, self.buckets)
-                except PagePoolExhausted as e:
-                    # typed back-pressure into the scheduler: unwind the
-                    # slot claim and retry after a retirement frees
-                    # pages — or fail NOW when no running request could
-                    # ever free them (permanent exhaustion)
-                    self._running.pop(slot, None)
-                    self._free.append(slot)
-                    req.slot = None
-                    if self._running:
-                        req.state = QUEUED
-                        self._scheduler.requeue(req)
-                        return
-                    exc = AdmissionRejected(
-                        f"request {req.request_id}: page pool exhausted "
-                        f"at admission ({e.needed} page(s) needed, "
-                        f"{e.free_pages} free) with no running request "
-                        f"to release pages", reason="no_free_pages",
-                        request_id=req.request_id,
-                        iteration=self._iteration)
-                    exc.__cause__ = e
-                    self._fail(req, exc, outcome="no_free_pages")
-                    continue
-                except AdmissionRejected as e:
-                    # pool-level typed rejection (e.g. tail_too_long):
-                    # deterministic for this prompt — requeueing could
-                    # never succeed, so fail now, request-attributed
-                    self._running.pop(slot, None)
-                    self._free.append(slot)
-                    req.slot = None
-                    exc = AdmissionRejected(
-                        f"request {req.request_id}: {e}", reason=e.reason,
-                        request_id=req.request_id,
-                        iteration=self._iteration)
-                    exc.__cause__ = e
-                    self._fail(req, exc, outcome=e.reason)
-                    continue
-                req.prefix_hit_pages = n_hit
-                req.prefill_tokens_saved = offset
-            else:
-                bucket = next(b for b in self.buckets if b >= s)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :s] = req.prompt
-                logits = self.pool.admit(self.params, jnp.asarray(padded),
-                                         s, slot)
-            if self._spec is not None and req.params.temperature == 0.0:
-                # greedy requests speculate: prefill the draft's own
-                # slot too (a prompt no draft bucket fits just runs
-                # non-speculative — mixed batches are first-class)
-                self._spec.admit(req.prompt, slot, self.buckets)
-            req.admit_t = time.monotonic()
-            req.admit_iteration = self._iteration
-            tok = self._sample_for(req, logits)
-            self._emit(req, tok)
+            with dpxtrace.span("serve.admit", iteration=self._iteration,
+                               trace_id=req.trace_id,
+                               request_id=req.request_id,
+                               prompt_len=int(req.prompt.shape[0])) as adm:
+                slot = self._free.pop()
+                # claim the slot BEFORE the prefill call: if it raises, the
+                # crash drain finds the request in _running and fails its
+                # future instead of stranding it half-admitted
+                req.state = RUNNING
+                req.slot = slot
+                self._running[slot] = req
+                s = int(req.prompt.shape[0])
+                if self._paged:
+                    try:
+                        with dpxtrace.span("serve.admit.prefill",
+                                           iteration=self._iteration):
+                            logits, n_hit, offset = self.pool.admit(
+                                self.params, req.prompt, slot, self.buckets)
+                    except PagePoolExhausted as e:
+                        # typed back-pressure into the scheduler: unwind the
+                        # slot claim and retry after a retirement frees
+                        # pages — or fail NOW when no running request could
+                        # ever free them (permanent exhaustion)
+                        self._running.pop(slot, None)
+                        self._free.append(slot)
+                        req.slot = None
+                        if self._running:
+                            req.state = QUEUED
+                            self._scheduler.requeue(req)
+                            return
+                        exc = AdmissionRejected(
+                            f"request {req.request_id}: page pool exhausted "
+                            f"at admission ({e.needed} page(s) needed, "
+                            f"{e.free_pages} free) with no running request "
+                            f"to release pages", reason="no_free_pages",
+                            request_id=req.request_id,
+                            iteration=self._iteration)
+                        exc.__cause__ = e
+                        self._fail(req, exc, outcome="no_free_pages")
+                        continue
+                    except AdmissionRejected as e:
+                        # pool-level typed rejection (e.g. tail_too_long):
+                        # deterministic for this prompt — requeueing could
+                        # never succeed, so fail now, request-attributed
+                        self._running.pop(slot, None)
+                        self._free.append(slot)
+                        req.slot = None
+                        exc = AdmissionRejected(
+                            f"request {req.request_id}: {e}", reason=e.reason,
+                            request_id=req.request_id,
+                            iteration=self._iteration)
+                        exc.__cause__ = e
+                        self._fail(req, exc, outcome=e.reason)
+                        continue
+                    req.prefix_hit_pages = n_hit
+                    req.prefill_tokens_saved = offset
+                    adm.set(n_hit=n_hit, bucket=next(
+                        b for b in self.buckets if b >= s - offset))
+                else:
+                    bucket = next(b for b in self.buckets if b >= s)
+                    padded = np.zeros((1, bucket), np.int32)
+                    padded[0, :s] = req.prompt
+                    with dpxtrace.span("serve.admit.prefill",
+                                       iteration=self._iteration):
+                        logits = self.pool.admit(
+                            self.params, jnp.asarray(padded), s, slot)
+                    adm.set(n_hit=0, bucket=bucket)
+                if self._spec is not None and req.params.temperature == 0.0:
+                    # greedy requests speculate: prefill the draft's own
+                    # slot too (a prompt no draft bucket fits just runs
+                    # non-speculative — mixed batches are first-class)
+                    self._spec.admit(req.prompt, slot, self.buckets)
+                req.admit_t = time.monotonic()
+                req.admit_iteration = self._iteration
+                self._admitted += 1
+                # the fetch is where the host waits for the prefill
+                with dpxtrace.span("serve.admit.first_token",
+                                   iteration=self._iteration):
+                    tok = int(np.asarray(self._sample_for(req, logits))[0])
+                    self._emit(req, tok)
 
     def _decode_all(self) -> None:
         spec_slots: List[int] = []
@@ -597,31 +655,51 @@ class InferenceEngine:
             # take part: their pages grow AFTER acceptance is known
             # (ensure_spec_capacity), so rejected drafts never demand
             # a page
-            for slot in list(nonspec):
-                req = self._running[slot]
-                try:
-                    self.pool.ensure_decode_capacity(slot)
-                except PagePoolExhausted as e:
-                    self._fail(req, PagePoolExhausted(
-                        f"request {req.request_id}: page pool exhausted "
-                        f"mid-decode after {len(req.out_tokens)} tokens "
-                        f"({e.needed} page(s) needed, {e.free_pages} "
-                        f"free — every page held by a live reader)",
-                        needed=e.needed, free_pages=e.free_pages,
-                        request_id=req.request_id,
-                        iteration=self._iteration),
-                        outcome="no_free_pages")
-                    nonspec.remove(slot)
+            with dpxtrace.span("serve.decode.capacity",
+                               iteration=self._iteration):
+                for slot in list(nonspec):
+                    req = self._running[slot]
+                    try:
+                        self.pool.ensure_decode_capacity(slot)
+                    except PagePoolExhausted as e:
+                        self._fail(req, PagePoolExhausted(
+                            f"request {req.request_id}: page pool "
+                            f"exhausted mid-decode after "
+                            f"{len(req.out_tokens)} tokens ({e.needed} "
+                            f"page(s) needed, {e.free_pages} free — every "
+                            f"page held by a live reader)",
+                            needed=e.needed, free_pages=e.free_pages,
+                            request_id=req.request_id,
+                            iteration=self._iteration),
+                            outcome="no_free_pages")
+                        nonspec.remove(slot)
         if nonspec:
+            clock = time.perf_counter_ns
+            it, rows = self._iteration, len(nonspec)
             active = np.zeros(self.config.n_slots, bool)
             active[nonspec] = True
-            logits = self.pool.decode(self.params,
-                                      jnp.array(self._cur_tokens),
-                                      jnp.asarray(active))
-            for slot in nonspec:
-                req = self._running[slot]
-                tok = self._sample_for(req, logits[slot:slot + 1])
-                self._emit(req, tok)
+            t0 = clock()
+            with dpxtrace.span("serve.decode.dispatch", iteration=it,
+                               rows=rows):
+                logits = self.pool.decode(self.params,
+                                          jnp.array(self._cur_tokens),
+                                          jnp.asarray(active))
+            t1 = clock()
+            with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
+                for slot in nonspec:
+                    req = self._running[slot]
+                    ids = dict(iteration=it, slot=slot,
+                               trace_id=req.trace_id,
+                               request_id=req.request_id)
+                    with dpxtrace.span("serve.row.sample", **ids):
+                        out = self._sample_for(req, logits[slot:slot + 1])
+                    with dpxtrace.span("serve.row.fetch", **ids):
+                        tok = int(np.asarray(out)[0])
+                    with dpxtrace.span("serve.row.emit", **ids):
+                        self._emit(req, tok)
+            self._host_ns["decode_dispatch"] += t1 - t0
+            self._host_ns["row_loop"] += clock() - t1
+            self._rows_decoded += rows
         spec_slots = [s for s in spec_slots if s in self._running]
         if spec_slots:
             self._spec_step(spec_slots)
@@ -653,13 +731,13 @@ class InferenceEngine:
         host bookkeeping (the draft's length rewind)."""
         spec = self._spec
         k = spec.cfg.draft_len
-        tracing = dpxtrace.enabled()
+        ids = dict(iteration=self._iteration, rows=len(spec_slots),
+                   draft_len=k)
         try:
             faults.on_comm_op("draft_propose")
-            t0 = time.monotonic()
-            drafts = spec.propose(spec_slots,
-                                  self._cur_tokens[spec_slots])
-            t1 = time.monotonic()
+            with dpxtrace.span("serve.spec.propose", **ids):
+                drafts = spec.propose(spec_slots,
+                                      self._cur_tokens[spec_slots])
         except Exception as e:  # noqa: BLE001 — victim containment
             self._spec_fail(spec_slots, e, "propose")
             return
@@ -668,24 +746,12 @@ class InferenceEngine:
         tokens[spec_slots, 1:] = drafts
         try:
             faults.on_comm_op("spec_verify")
-            t2 = time.monotonic()
-            logits, sk, sv = self.pool.spec_verify(self.params, tokens)
-            logits_np = np.asarray(logits)
-            t3 = time.monotonic()
+            with dpxtrace.span("serve.spec.verify", **ids):
+                logits, sk, sv = self.pool.spec_verify(self.params, tokens)
+                logits_np = np.asarray(logits)
         except Exception as e:  # noqa: BLE001 — victim containment
             self._spec_fail(spec_slots, e, "verify")
             return
-        if tracing:
-            w = dpxtrace.wall_from_mono
-            for slot in spec_slots:
-                req = self._running[slot]
-                dpxtrace.emit_span("serve.spec.propose", w(t0), w(t1),
-                                   trace_id=req.trace_id,
-                                   request_id=req.request_id)
-                dpxtrace.emit_span("serve.spec.verify", w(t2), w(t3),
-                                   trace_id=req.trace_id,
-                                   request_id=req.request_id,
-                                   draft_len=k)
         commit = np.zeros(self.config.n_slots, np.int32)
         emits: Dict[int, List[int]] = {}
         for i, slot in enumerate(spec_slots):
@@ -725,7 +791,8 @@ class InferenceEngine:
                         iteration=self._iteration),
                         outcome="no_free_pages")
         try:
-            self.pool.spec_commit(sk, sv, commit)
+            with dpxtrace.span("serve.spec.commit", **ids):
+                self.pool.spec_commit(sk, sv, commit)
         except Exception as e:  # noqa: BLE001 — victim containment
             self._spec_fail(list(emits), e, "commit")
             return
@@ -739,7 +806,10 @@ class InferenceEngine:
                 if req.done:
                     break
 
-    def _sample_for(self, req: Request, logits) -> int:
+    def _sample_for(self, req: Request, logits):
+        """Dispatch the request's sampler on ``logits`` (1, vocab);
+        returns the token still on the device, shape (1,) — the caller
+        fetches it, so that dispatch and wait can be told apart."""
         fn = self._samplers.get(req.params.sampler_key)
         if fn is None:
             t, k, p = req.params.sampler_key
@@ -748,10 +818,13 @@ class InferenceEngine:
             def sample(lg, rng, t=t, k=k, p=p):
                 pool.compiles.sample += 1          # trace-time only
                 return _sample(lg, rng, t, k, p)
+            # the program's name on the profiler's device plane
+            sample.__name__ = "sample_" + "_".join(
+                str(v) for v in req.params.sampler_key)
             fn = jax.jit(sample)
             self._samplers[req.params.sampler_key] = fn
         key = jnp.asarray(req.rngs[len(req.out_tokens)])
-        return int(np.asarray(fn(logits, key))[0])
+        return fn(logits, key)
 
     def _emit(self, req: Request, tok: int) -> None:
         now = time.monotonic()

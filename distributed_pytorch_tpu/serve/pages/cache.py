@@ -26,7 +26,6 @@ tail-length bucket (``prefill_partial_paged``), counted by the same
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -39,7 +38,7 @@ from ...models.generate import (decode_step_slots_paged,
                                 spec_commit_slots_paged,
                                 spec_verify_slots_paged)
 from ...runtime import faults
-from ..cache import CompileCounts
+from ..cache import CompileCounts, named_program
 from ..types import AdmissionRejected
 from .pool import PagePool
 from .prefix import PrefixIndex
@@ -291,11 +290,13 @@ class PagedSlotPool:
         padded[0, :tail_len] = prompt[offset:]
         fn = self._admit_fns.get(bucket)
         if fn is None:
+            name = f"prefill_b{bucket}"
             if self.quant_bits is None:
-                fn = jax.jit(partial(self._admit, bucket=bucket),
+                fn = jax.jit(named_program(self._admit, name, bucket=bucket),
                              donate_argnums=(1, 2))
             else:
-                fn = jax.jit(partial(self._admit_q, bucket=bucket),
+                fn = jax.jit(named_program(self._admit_q, name,
+                                           bucket=bucket),
                              donate_argnums=(1, 2, 3, 4, 5, 6))
             self._admit_fns[bucket] = fn
         if self.quant_bits is None:

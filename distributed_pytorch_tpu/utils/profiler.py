@@ -53,15 +53,15 @@ start_trace = jax.profiler.start_trace
 stop_trace = jax.profiler.stop_trace
 
 
-def annotate(name: str):
-    """Named region that shows up on the trace timeline.
-
-    Usable as context manager or decorator::
+def annotate(name: str, **attrs):
+    """Named region that shows up on the trace timeline as
+    ``dpx:<name>``: a dpxtrace span (``obs/trace.py`` — the one way to
+    name a region; under ``DPX_TRACE`` it is recorded there too)::
 
         with profiler.annotate("data-load"):
             batch = next(it)
     """
-    return jax.profiler.TraceAnnotation(name)
+    return _dpxtrace.span(name, **attrs)
 
 
 class CommStats:
@@ -104,19 +104,18 @@ class CommStats:
 
     @contextlib.contextmanager
     def timed(self, op: str, nbytes: int, hidden: bool = False):
-        """Time a collective and record its wire bytes; also emits a
-        trace annotation so the op shows on XProf timelines, and — with
-        ``DPX_TRACE`` on — a dpxtrace span (obs/trace.py), which is how
+        """Time a collective and record its wire bytes; also opens a
+        dpxtrace span (obs/trace.py), so the op shows on XProf
+        timelines as ``dpx:comm:<op>`` and — with ``DPX_TRACE`` on —
         EVERY comm op (quantized/hier ring legs, the disagg
         handoff_send/recv transport included) lands on the cross-rank
         timeline with its overlapped-vs-exposed attribution. ``hidden``
         routes the wall time into the overlapped (vs exposed) bucket."""
         t0 = time.perf_counter()
         try:
-            with annotate(f"comm:{op}"):
-                with _dpxtrace.span(f"comm:{op}", bytes=int(nbytes),
-                                    hidden=hidden):
-                    yield
+            with _dpxtrace.span(f"comm:{op}", bytes=int(nbytes),
+                                hidden=hidden):
+                yield
         finally:
             self.record(op, nbytes, time.perf_counter() - t0,
                         hidden=hidden)

@@ -1,0 +1,320 @@
+"""The program's own instruments (PERF.md section 3): dpxtrace spans as
+profiler annotations on the profiler's clock, the spans and counters of
+the engine loop and the train step, the process-wide compile counter, and
+the ``jax.named_scope``s inside the programs."""
+
+import os
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_pytorch_tpu import models, optim
+from distributed_pytorch_tpu.obs import trace as dpxtrace
+from distributed_pytorch_tpu.parallel import make_train_step
+from distributed_pytorch_tpu.runtime import compile_cache
+from distributed_pytorch_tpu.serve import (EngineConfig, InferenceEngine,
+                                           SamplingParams)
+from distributed_pytorch_tpu.utils import profiler
+
+
+@pytest.fixture(autouse=True)
+def _tracing_off():
+    dpxtrace.reset()
+    dpxtrace.configure(enabled=False, log_path=None)
+    yield
+    dpxtrace.reset()
+
+
+def host_spans(logdir):
+    """{line index: [(name, start ns, end ns, attrs)]} of the ``dpx:``
+    events in the one ``.xplane.pb`` under ``logdir``, read with JAX's own
+    reader (``chipbench/program_trace.py`` has its own tests)."""
+    path = [os.path.join(b, f) for b, _, fs in os.walk(logdir) for f in fs
+            if f.endswith(".xplane.pb")]
+    assert len(path) == 1
+    out = {}
+    data = jax.profiler.ProfileData.from_file(path[0])
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            got = [(e.name[4:], e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("dpx:")]
+            if got:
+                out[i] = sorted(got, key=lambda s: (s[1], -s[2]))
+    return out
+
+
+def named(spans, name):
+    return [s for line in spans.values() for s in line if s[0] == name]
+
+
+# -- a span is a profiler annotation ------------------------------------------
+
+
+def test_span_lands_in_the_profile_on_its_threads_line(tmp_path):
+    def other():
+        with dpxtrace.span("other.thread", k=1):
+            time.sleep(0.002)
+
+    with profiler.trace(str(tmp_path)):
+        with dpxtrace.span("outer", iteration=3) as sp:
+            sp.set(rows=2)
+            with dpxtrace.span("inner", slot=1, trace_id="ab-1"):
+                time.sleep(0.002)
+        t = threading.Thread(target=other, name="test-other-thread")
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    spans = host_spans(tmp_path)
+    (outer,), (inner,) = named(spans, "outer"), named(spans, "inner")
+    assert outer[3] == {"iteration": 3, "rows": 2}
+    assert inner[3] == {"slot": 1, "trace_id": "ab-1"}
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]      # nested
+    line_of = {s[0]: i for i, line in spans.items() for s in line}
+    assert line_of["outer"] == line_of["inner"] != line_of["other.thread"]
+
+
+def test_recorded_span_is_an_annotation_too(tmp_path):
+    dpxtrace.configure(enabled=True, ring=16)
+    with profiler.trace(str(tmp_path)):
+        with dpxtrace.span("both", n=1):
+            pass
+    assert [s[3] for s in named(host_spans(tmp_path), "both")] == [{"n": 1}]
+    ring, _ = dpxtrace.flight_snapshot()
+    assert [r["name"] for r in ring] == ["both"]
+
+
+def test_no_session_and_tracing_off_records_nothing():
+    first, second = dpxtrace.span("a", k=1), dpxtrace.span("b")
+    assert first is second          # the shared no-op: nothing is built
+    with first as sp:
+        sp.event("x")
+        sp.set(rows=1)
+        assert sp.span_id is None and sp.trace_id is None
+    assert dpxtrace.flight_snapshot() == ([], 0)
+
+
+def test_annotate_is_a_span(tmp_path):
+    with profiler.trace(str(tmp_path)):
+        with profiler.annotate("data-load", shard=2):
+            pass
+    assert [s[3] for s in named(host_spans(tmp_path), "data-load")] \
+        == [{"shard": 2}]
+
+
+# -- the engine loop ----------------------------------------------------------
+
+
+def tiny_lm(**kw):
+    return models.TransformerLM(vocab=61, dim=32, n_layers=2, n_heads=4,
+                                n_kv_heads=2, pos="rope", max_seq=64, **kw)
+
+
+@pytest.fixture(scope="module")
+def engine_run(tmp_path_factory):
+    """A tiny paged engine serving three requests under a profiler
+    session: (spans by line, stats at the end)."""
+    logdir = tmp_path_factory.mktemp("engine_profile")
+    model = tiny_lm()
+    params = model.init(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, EngineConfig(
+        paged=True, n_slots=4, max_len=64, buckets=(8, 16), page_len=8))
+    with eng:
+        # warm every program first: a compile inside the session would
+        # only make the trace larger
+        eng.submit(np.arange(5, dtype=np.int32),
+                   SamplingParams(max_new_tokens=2)).result(timeout=300)
+        before = eng.stats()
+        with profiler.trace(str(logdir)):
+            hs = [eng.submit(np.arange(3 + i, dtype=np.int32),
+                             SamplingParams(max_new_tokens=5))
+                  for i in range(3)]
+            for h in hs:
+                h.result(timeout=300)
+        after = eng.stats()
+    return host_spans(logdir), before, after
+
+
+def test_engine_spans_are_the_loops_tree(engine_run):
+    spans, _, _ = engine_run
+    engine_line = [i for i, line in spans.items()
+                   if any(s[0] == "serve.iter" for s in line)]
+    assert len(engine_line) == 1
+    line = spans[engine_line[0]]
+    names = {s[0] for s in line}
+    assert {"serve.iter", "serve.sweep", "serve.admit",
+            "serve.admit.prefill", "serve.admit.first_token",
+            "serve.decode.capacity", "serve.decode.dispatch",
+            "serve.decode.rows", "serve.row.sample", "serve.row.fetch",
+            "serve.row.emit"} <= names
+    # the caller's thread holds serve.submit, not the engine's
+    assert "serve.submit" not in names and len(named(spans, "serve.submit")) == 3
+
+    # the session opens and closes in the middle of an iteration (the
+    # warm-up request's last; the one that retires the last request, whose
+    # serve.iter is still open when the session ends): only what lies
+    # between the first and the last whole iteration is held to the tree
+    iters = [s for s in line if s[0] == "serve.iter"]
+    t0, t1 = min(s[1] for s in iters), max(s[2] for s in iters)
+
+    def inside(child, parent):
+        return all(any(p[1] <= c[1] and c[2] <= p[2] for p in line
+                       if p[0] == parent)
+                   for c in line
+                   if c[0] == child and t0 <= c[1] and c[2] <= t1)
+    for child, parent in (
+            ("serve.admit", "serve.iter"),
+            ("serve.admit.prefill", "serve.admit"),
+            ("serve.admit.first_token", "serve.admit"),
+            ("serve.decode.dispatch", "serve.iter"),
+            ("serve.decode.rows", "serve.iter"),
+            ("serve.row.sample", "serve.decode.rows"),
+            ("serve.row.fetch", "serve.decode.rows"),
+            ("serve.row.emit", "serve.decode.rows")):
+        assert inside(child, parent), (child, parent)
+    assert all("iteration" in s[3] for s in line
+               if s[0].startswith("serve.") and s[0] != "serve.idle")
+    admit = named(spans, "serve.admit")
+    assert sorted(a[3]["prompt_len"] for a in admit) == [3, 4, 5]
+    assert all(a[3]["bucket"] == 8 and a[3]["n_hit"] == 0
+               and "request_id" in a[3] and "trace_id" in a[3]
+               for a in admit)
+
+
+def test_engine_row_spans_count_the_rows_decoded(engine_run):
+    spans, before, after = engine_run
+    rows = after["rows_decoded"] - before["rows_decoded"]
+    assert rows == 3 * 4        # the first token of each comes from admit
+    for name in ("serve.row.sample", "serve.row.fetch", "serve.row.emit"):
+        assert len(named(spans, name)) == rows
+    assert sum(s[3]["rows"] for s in named(spans, "serve.decode.rows")) == rows
+    assert after["admitted"] - before["admitted"] == 3
+
+
+def test_engine_host_ns_counters_nest(engine_run):
+    _, _, after = engine_run
+    host = after["host_ns"]
+    assert set(host) == {"idle", "admit", "decode_dispatch", "row_loop",
+                         "iter"}
+    assert all(v > 0 for v in host.values())
+    assert host["iter"] >= host["row_loop"] + host["decode_dispatch"] \
+        + host["admit"]
+    assert after["xla_compiles"]["compiles"] \
+        + after["xla_compiles"]["cache_hits"] > 0
+
+
+def test_spec_step_emits_each_span_once_an_iteration(tmp_path):
+    model = tiny_lm()
+    params = model.init(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, EngineConfig(
+        n_slots=4, max_len=64, spec_decode=True, draft_model=model,
+        draft_params=params, draft_len=2))
+    with eng:
+        eng.submit(np.arange(4, dtype=np.int32),
+                   SamplingParams(max_new_tokens=3)).result(timeout=300)
+        with profiler.trace(str(tmp_path)):
+            hs = [eng.submit(np.arange(4 + i, dtype=np.int32),
+                             SamplingParams(max_new_tokens=7))
+                  for i in range(3)]
+            for h in hs:
+                h.result(timeout=300)
+    spans = host_spans(tmp_path)
+    verify = named(spans, "serve.spec.verify")
+    # several requests speculate in one iteration: one span, not one each
+    its = [v[3]["iteration"] for v in verify]
+    assert len(its) == len(set(its)) and max(v[3]["rows"] for v in verify) > 1
+    assert all(v[3]["draft_len"] == 2 for v in verify)
+    for name in ("serve.spec.propose", "serve.spec.commit"):
+        assert sorted(s[3]["iteration"] for s in named(spans, name)) \
+            == sorted(its)
+
+
+# -- the compile counter ------------------------------------------------------
+
+
+def test_compile_events_count_a_fresh_compile_and_not_a_cached_call(tmp_path):
+    f = jax.jit(lambda x: x * 3 + 1)
+    x = jnp.arange(7.0)
+    built = lambda e: e["compiles"] + e["cache_hits"]
+    e0 = compile_cache.compile_events()
+    with profiler.trace(str(tmp_path)):
+        with dpxtrace.span("caller"):
+            f(x).block_until_ready()
+    e1 = compile_cache.compile_events()
+    f(x).block_until_ready()
+    e2 = compile_cache.compile_events()
+    assert built(e1) == built(e0) + 1 and built(e2) == built(e1)
+    assert e1["compile_s"] + e1["cache_load_s"] \
+        > e0["compile_s"] + e0["cache_load_s"]
+    # the mark falls inside the span that caused the build
+    spans = host_spans(tmp_path)
+    (mark,), (caller,) = named(spans, "xla.compile"), named(spans, "caller")
+    assert caller[1] <= mark[1] <= mark[2] <= caller[2]
+    assert set(mark[3]) == {"secs", "cached"}
+
+
+# -- scopes inside the programs -----------------------------------------------
+
+
+def op_names(lowered):
+    return set(re.findall(r'loc\("(jit\([^"]+)"',
+                          lowered.as_text(debug_info=True)))
+
+
+def test_train_step_names_its_layers():
+    model = tiny_lm(remat="full")
+    params = model.init(jax.random.PRNGKey(0))
+
+    def loss_fn(p, tokens):
+        logits = model.apply(p, tokens[:, :-1]).astype(jnp.float32)
+        hit = jnp.take_along_axis(logits, tokens[:, 1:, None], -1)[..., 0]
+        return jnp.mean(jax.nn.logsumexp(logits, -1) - hit), {}
+
+    opt = optim.adamw(1e-3)
+    step = make_train_step(loss_fn, opt, mixed_precision="bf16")
+    batch = jnp.zeros((2, 9), jnp.int32)
+    names = op_names(step.lower(params, opt.init(params), batch))
+    for want in ("jvp(loss)/embed/", "jvp(loss)/blocks/attn/qkv/",
+                 "jvp(loss)/blocks/attn/core/", "jvp(loss)/blocks/attn/out/",
+                 "jvp(loss)/blocks/mlp/", "jvp(loss)/blocks/norm/",
+                 "jvp(loss)/ln_f/", "jvp(loss)/head/", "jvp(cast)/",
+                 "transpose(jvp(loss))/", "rematted_computation/blocks/mlp/",
+                 "jit(local_step)/optimizer/"):
+        assert any(want in n for n in names), want
+    out = step(params, opt.init(params), batch)
+    assert np.isfinite(float(out.loss[0])) and step.calls == 1
+    assert set(step.xla_compiles) == {"compiles", "compile_s", "cache_hits",
+                                      "cache_load_s"}
+
+
+def test_serving_programs_name_their_layers_and_themselves():
+    model = tiny_lm()
+    params = model.init(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, EngineConfig(
+        paged=True, n_slots=2, max_len=64, buckets=(8, 16), page_len=8))
+    pool = eng.pool
+    names = op_names(pool._decode_fn.lower(
+        params, pool.k_pages, pool.v_pages, jnp.array(pool.tables),
+        jnp.array(pool.lengths), jnp.zeros(2, jnp.int32),
+        jnp.ones(2, bool)))
+    for want in ("/embed/", "/blocks/norm/", "/blocks/attn/qkv/",
+                 "/blocks/page_write/", "/blocks/decode_attention/",
+                 "/decode_attention/while/body/page_gather/",
+                 "/blocks/attn/out/", "/blocks/mlp/", "/ln_f/", "/head/"):
+        assert any(want in n for n in names), want
+    with eng:
+        eng.submit(np.arange(11, dtype=np.int32), SamplingParams(
+            max_new_tokens=2, temperature=0.7)).result(timeout=300)
+    (prefill,) = pool._admit_fns.values()
+    assert prefill.__wrapped__.__name__ == "prefill_b16"
+    (sampler,) = eng._samplers.values()
+    assert sampler.__wrapped__.__name__ == "sample_0.7_None_None"
+    lowered = sampler.lower(jnp.zeros((1, 61)), jax.random.PRNGKey(0))
+    assert any("/sample/" in n for n in op_names(lowered))
