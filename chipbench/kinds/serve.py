@@ -6,12 +6,22 @@ it was DUE, and each token is stamped in its ``on_token`` callback.
 Load starts ``lead_in_s`` before the window so that the window opens on
 a full engine, and goes on after it until every request due inside the
 window has finished (or ``drain_limit_s`` has passed: what is unfinished
-then has failed). The sample is the requests due inside the window."""
+then has failed). The sample is the requests due inside the window.
+
+A traced run traces the window's last ``trace_iterations`` passes of the
+engine loop, by the pace of the window so far, and never more than its
+last ``trace_seconds``: a trace's size, and the time it takes to write
+out and to read, follow the work it holds (17 k device operations a
+decode program), so a faster engine must not be given a larger one.
+Writing it out (``jax.profiler.stop_trace``: 30 s for 35 iterations on the
+v5e host, during which the engine's thread runs a fifth slower) starts
+when the window closes, on the main thread, while the load goes on from a
+thread of its own; reading it takes 3-4 s, the readers as long again."""
 
 import gc
 import importlib
-import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -85,48 +95,35 @@ def compiles(stats_):
             + sum(stats_["prefill_compiles"].values()))
 
 
-def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
-    """``broken`` is the tests' fault: a function applied to every token
-    where it is produced (it wraps the client's ``on_token``).
-    ``control_mm`` (chipbench/control.py) also reads the control: the gap
-    of the token a lower-precision reference puts first, at the same
-    positions of the same prompts and tokens."""
-    from distributed_pytorch_tpu.serve import SamplingParams
+class Load:
+    """The open loop of one run: every request of the schedule submitted
+    when it is due, from ``lead_in_s`` before the window until the sample
+    (the requests due inside the window) has finished. ``s0``, ``s1`` and
+    ``s2`` are the engine's ``stats()`` at the window's start, at its end
+    and after the drain."""
 
-    cfg, mix = cell.config, cell.traffic
-    schedule = traffic_gen.serve_requests(mix, cell.seed, cell.seconds,
-                                          cfg["vocab_size"])
-    eng = build(cell, cell.seed)
-    eng.start()
-    try:
-        warm_up(eng, mix, cfg["vocab_size"])
-        clients = []
-        lateness, submit_took = [], []
-        stopper = None
-        t_load = time.perf_counter()
-        t_w0 = t_load + mix["lead_in_s"]
-        t_w1 = t_w0 + cell.seconds
-        s0 = s1 = None
-        for req in schedule:
-            due_t = t_load + req["due_s"]
+    def __init__(self, eng, mix, schedule, seconds, broken):
+        self.eng, self.mix, self.schedule, self.broken = \
+            eng, mix, schedule, broken
+        self.clients, self.lateness, self.submit_took = [], [], []
+        self.t_load = time.perf_counter()
+        self.t_w0 = self.t_load + mix["lead_in_s"]
+        self.t_w1 = self.t_w0 + seconds
+        self.s0 = self.s1 = self.s2 = self.t_done = None
+        self.sample = []
+
+    def run(self):
+        from distributed_pytorch_tpu.serve import SamplingParams
+
+        eng, clients, t_w0, t_w1 = self.eng, self.clients, self.t_w0, self.t_w1
+        for req in self.schedule:
+            due_t = self.t_load + req["due_s"]
             while True:
                 now = time.perf_counter()
-                if s0 is None and now >= t_w0:
-                    s0 = eng.stats()
-                # the traced part is the window's last seconds: writing a
-                # trace out takes half a minute and slows the engine's
-                # thread meanwhile, which then falls after the window
-                if (cell.trace and not tracer.on and stopper is None
-                        and now >= t_w1 - mix["trace_seconds"]):
-                    tracer.start()
-                if tracer.on and now >= t_w1:
-                    # off this thread: the schedule must not wait for it
-                    tracer.on = False
-                    stopper = threading.Thread(target=tracer.stop,
-                                               name="chipbench-trace-stop")
-                    stopper.start()
-                if s1 is None and now >= t_w1:
-                    s1 = eng.stats()
+                if self.s0 is None and now >= t_w0:
+                    self.s0 = eng.stats()
+                if self.s1 is None and now >= t_w1:
+                    self.s1 = eng.stats()
                 if now >= due_t:
                     break
                 with jax.profiler.TraceAnnotation("wait_for_due"):
@@ -135,8 +132,9 @@ def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
                     c.done() for c in clients if c.req["in_window"]):
                 break                      # lead-out: the sample is in
             c = Client(req, due_t)
-            lateness.append((now - due_t, due_t - t_w0))
-            on_token = c.on_token if broken is None else broken(c.on_token)
+            self.lateness.append((now - due_t, due_t - t_w0))
+            on_token = c.on_token if self.broken is None \
+                else self.broken(c.on_token)
             try:
                 with jax.profiler.TraceAnnotation("submit"):
                     c.handle = eng.submit(
@@ -145,27 +143,113 @@ def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
                         on_token=on_token)
             except Exception as e:  # noqa: BLE001 - a refusal is a failure
                 c.error = e
-            submit_took.append((time.perf_counter() - now, due_t - t_w0))
+            self.submit_took.append((time.perf_counter() - now,
+                                     due_t - t_w0))
             clients.append(c)
-        if tracer.on:
-            tracer.stop()
-        if s1 is None:
-            s1 = eng.stats()
-        sample = [c for c in clients if c.req["in_window"]]
-        limit = time.perf_counter() + mix["drain_limit_s"]
+        if self.s1 is None:
+            self.s1 = eng.stats()
+        self.sample = [c for c in clients if c.req["in_window"]]
+        limit = time.perf_counter() + self.mix["drain_limit_s"]
         with jax.profiler.TraceAnnotation("drain"):
-            for c in sample:
+            for c in self.sample:
                 if c.error is None:
                     try:
                         c.handle.result(
                             timeout=max(0.0, limit - time.perf_counter()))
                     except Exception as e:  # noqa: BLE001
                         c.error = e
-        s2 = eng.stats()
-        if stopper is not None:
-            stopper.join()
+        self.s2 = eng.stats()
+        self.t_done = time.perf_counter()
+
+
+def trace_lead(mix, stats_, s0, into_window_s):
+    """Seconds before the window's end at which the traced part starts:
+    ``trace_iterations`` passes of the engine loop at the mean pace of
+    the window so far, and at most ``trace_seconds``."""
+    done = stats_["iterations"] - s0["iterations"]
+    if done <= 0:
+        return mix["trace_seconds"]
+    return min(mix["trace_seconds"],
+               mix["trace_iterations"] * into_window_s / done)
+
+
+def trace_window_end(load, tracer):
+    """Trace the last of the window, from the process's main thread while
+    ``load`` runs on another: ``jax.profiler.stop_trace`` takes three
+    times as long from a thread that is not the main one (97-102 s
+    against 29-31 s for the same 32 iterations, PERF.md Findings PR 26).
+    The traced part ends with the window, so that writing the trace out,
+    which slows the engine's thread, falls after it. Returns what the
+    traced part held, as counters."""
+    eng, mix, t_w1 = load.eng, load.mix, load.t_w1
+    while True:
+        now = time.perf_counter()
+        wake = t_w1 - mix["trace_seconds"]
+        if now >= wake and load.s0 is not None:
+            wake = t_w1 - trace_lead(mix, eng.stats(), load.s0,
+                                     now - load.t_w0)
+            if now >= wake:
+                break
+        if now >= t_w1:
+            raise RuntimeError("the window closed before it opened: the "
+                               "load has stopped")
+        time.sleep(min(0.05, max(0.001, wake - now)))
+    tracer.start()
+    s_on, t_on = eng.stats(), time.perf_counter()
+    time.sleep(max(0.0, t_w1 - t_on))
+    s_off, t_off = eng.stats(), time.perf_counter()
+    tracer.stop()
+    return {"trace_lead_s": t_w1 - now, "traced_seconds": t_off - t_on,
+            "traced_iterations": s_off["iterations"] - s_on["iterations"],
+            "traced_admissions": s_off["admitted"] - s_on["admitted"]}
+
+
+def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
+    """``broken`` is the tests' fault: a function applied to every token
+    where it is produced (it wraps the client's ``on_token``).
+    ``control_mm`` (chipbench/control.py) also reads the control: the gap
+    of the token a lower-precision reference puts first, at the same
+    positions of the same prompts and tokens."""
+    cfg, mix = cell.config, cell.traffic
+    schedule = traffic_gen.serve_requests(mix, cell.seed, cell.seconds,
+                                          cfg["vocab_size"])
+    eng = build(cell, cell.seed)
+    eng.start()
+    cell.phases.end("build")
+    traced = {}
+    try:
+        warm_up(eng, mix, cfg["vocab_size"])
+        cell.phases.end("warm_up")
+        load = Load(eng, mix, schedule, cell.seconds, broken)
+        if cell.trace:
+            with ThreadPoolExecutor(
+                    1, thread_name_prefix="chipbench-load") as pool:
+                offered = pool.submit(load.run)
+                traced = trace_window_end(load, tracer)
+                offered.result()
+        else:
+            load.run()
+        t_w0, t_w1 = load.t_w0, load.t_w1
+        cell.phases.end("lead_in", at=t_w0)
+        cell.phases.end("window", at=t_w1)
+        cell.phases.end("drain", at=load.t_done)
+        if cell.trace:
+            cell.phases.end("trace_stop")
+            wrote = tracer.stop_span[1] - tracer.stop_span[0]
+            print(f"chipbench: traced part started "
+                  f"{traced['trace_lead_s']:.2f} s before the window's end "
+                  f"(at most {mix['trace_seconds']} s, "
+                  f"{mix['trace_iterations']} iterations at the window's "
+                  f"pace) and held {traced['traced_iterations']} iterations "
+                  f"and {traced['traced_admissions']} admissions in "
+                  f"{traced['traced_seconds']:.2f} s; writing it out took "
+                  f"{wrote:.1f} s beside a drain of "
+                  f"{load.t_done - t_w1:.1f} s", flush=True)
     finally:
         eng.shutdown()
+    clients, sample, lateness, submit_took = \
+        load.clients, load.sample, load.lateness, load.submit_took
+    s0, s1, s2 = load.s0, load.s1, load.s2
     setup_s = t_w0 - t_start
 
     ok = [c for c in sample
@@ -207,8 +291,9 @@ def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
                   "setup_s": setup_s}
 
     # free the engine and its weights, then the reference walks the model
-    del eng
+    del eng, load
     gc.collect()
+    cell.phases.end("shutdown")
     rng = np.random.default_rng([cell.seed, 4])
     longest = max(ok, key=lambda c: len(c.req["prompt"]) + len(c.tokens))
     rest = [c for c in ok if c is not longest]
@@ -229,12 +314,14 @@ def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
     print(f"chipbench: reference read {n_tok} served tokens of "
           f"{len(picks)} requests in {time.perf_counter() - t0:.1f} s",
           flush=True)
+    cell.phases.end("reference")
     return {
         "checks": [{"name": "served_logit_gap_max", "value": worst,
                     "limit": cell.limits["served_logit_gap_max"]}],
         "attempted": len(sample), "failed": failed,
         "end_to_end": end_to_end,
         "counters": {
+            **traced,
             "compiles_in_window": compiles(s2) - compiles(s0),
             "iterations": s1["iterations"] - s0["iterations"],
             "tokens_emitted": s1["tokens_emitted"] - s0["tokens_emitted"],

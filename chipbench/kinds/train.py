@@ -175,6 +175,7 @@ def run(cell, devices, tracer, t_start, broken=None):
           flush=True)
 
     adapter, step, params, opt_state, place = build(cell, devices, cell.seed)
+    cell.phases.end("build")
     try:
         return _drive(cell, devices, tracer, t_start, broken, adapter, step,
                       params, opt_state, place, feed, ref, t_ref)
@@ -235,6 +236,7 @@ def _drive(cell, devices, tracer, t_start, broken, adapter, step, params,
     print(f"chipbench: first losses program {losses} reference "
           f"{ref['losses']}", flush=True)
 
+    cell.phases.end("first_steps")
     compiles_before = getattr(step, "compiles", 0)
     tokens_per_step = feed.rows * job["seq"]
     all_losses = []
@@ -264,10 +266,16 @@ def _drive(cell, devices, tracer, t_start, broken, adapter, step, params,
         all_losses.append(np.asarray(prev))
         jax.block_until_ready(runner.params)
     t_w1 = time.perf_counter()
+    cell.phases.end("trace_start", at=t_w0)
+    cell.phases.end("window", at=t_w1)
     if tracer.on:
         traced_tps = n * tokens_per_step / (t_w1 - t_w0)
         tracer.stop()
         traced_steps = n
+    if tracer.stop_span:
+        print(f"chipbench: traced part held {traced_steps} steps; writing "
+              f"it out took {tracer.stop_span[1] - tracer.stop_span[0]:.1f}"
+              f" s of the window", flush=True)
     flat = np.array([float(np.mean(l)) for l in all_losses])
     k = min(5, max(1, n // 2))
     fell = float(flat[-k:].mean() - flat[:k].mean())

@@ -4,19 +4,21 @@ and, for every device operation, the JAX name stack it was traced under
 (``jit(local_step)/transpose(jvp(loss))/blocks/attn/qkv/dot_general``,
 with the program's own ``jax.named_scope``s in it).
 
-``trace_reduce.load`` keeps the benchmark's own annotations and the HLO
-text; ``jax.profiler.ProfileData`` does not show the name stack at all (it
-is the stat ``tf_op`` of an event's *metadata*, not of the event). So this
-module reads the ``.xplane.pb`` itself: a protobuf wire-format reader of
-the messages it needs and no more (tensorflow/tsl ``xplane.proto``; field
-numbers beside each use), with nothing but the standard library. Times
-are nanoseconds on the one clock all planes share, as ``trace_reduce``
-has them (a line's ``timestamp_ns`` plus the event's ``offset_ps``).
+``jax.profiler.ProfileData`` does not show the name stack at all (it is
+the stat ``tf_op`` of an event's *metadata*, not of the event), and costs
+a Python string of the whole HLO text an event. So this module reads the
+``.xplane.pb`` itself: a protobuf wire-format reader of the messages it
+needs and no more (tensorflow/tsl ``xplane.proto``; field numbers beside
+each use), with nothing but the standard library. It is the one reader
+of a run's trace: the file is read once, only the planes and lines that
+some reader uses are decoded, and ``trace_reduce.load`` builds its
+``Trace`` from the same parse (``parsed``). Times are nanoseconds on the
+one clock all planes share (a line's ``timestamp_ns`` plus the event's
+``offset_ps``).
 
-The readers under ``layer_metrics/`` that use it get the run's
-``trace_reduce.Trace`` as before and ask here for the rest:
-``program_trace.of(cell)`` finds the trace under ``cell.out_dir/trace``,
-parses it once a process and returns None where there is none."""
+The readers under ``layer_metrics/`` get the run's ``trace_reduce.Trace``
+and ask here for the rest: ``program_trace.of(cell)`` finds the trace
+under ``cell.out_dir/trace`` and returns None where there is none."""
 
 import functools
 import os
@@ -95,75 +97,147 @@ def _stat(buf, span, stat_names):
     return name, value
 
 
+def _events(buf, i, end, names=None):
+    """One XLine: name (2), timestamp_ns (3), events (4) -> (name,
+    timestamp, [(metadata id, offset_ps, duration_ps, where the event's
+    stats start, where it ends)]). XEvent: metadata_id (1), offset_ps
+    (2), duration_ps (3), stats (4). This loop sees every event of a
+    trace, so its varints are read in place; it leans on what the
+    writer (C++ protobuf) guarantees, fields in the order of their
+    numbers: a line's name before its events (a line whose name is not
+    in ``names`` is left unread), an event's stats after its three
+    numbers. A field that breaks that order raises."""
+    name, t0, out = "", 0, []
+    add = out.append
+    while i < end:
+        key = buf[i]
+        i += 1
+        if key == 0x22:                             # events (4), bytes
+            n = buf[i]
+            i += 1
+            if n >= 0x80:
+                n &= 0x7F
+                shift = 7
+                while True:
+                    c = buf[i]
+                    i += 1
+                    n |= (c & 0x7F) << shift
+                    if c < 0x80:
+                        break
+                    shift += 7
+            ev_end = i + n
+            meta = off = dur = 0
+            while i < ev_end:
+                k = buf[i]
+                if k == 0x22:                       # stats (4): the rest
+                    break
+                i += 1
+                v = buf[i]
+                i += 1
+                if v >= 0x80:
+                    v &= 0x7F
+                    shift = 7
+                    while True:
+                        c = buf[i]
+                        i += 1
+                        v |= (c & 0x7F) << shift
+                        if c < 0x80:
+                            break
+                        shift += 7
+                if k == 0x08:
+                    meta = v
+                elif k == 0x10:
+                    off = v
+                elif k == 0x18:
+                    dur = v
+                elif k & 7:                         # no varint: no XEvent
+                    raise ValueError(f"XEvent field key {k} at byte {i}")
+            add((meta, off, dur, i, ev_end))
+            i = ev_end
+            continue
+        if key >= 0x80:
+            key, i = _varint(buf, i - 1)
+        wire = key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+            if key >> 3 == 3:
+                t0 = val
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            if key >> 3 == 2:
+                if out:
+                    raise ValueError("an XLine's name after its events")
+                name = _text(buf, (i, i + n))
+                if names is not None and name not in names:
+                    return name, t0, out
+            i += n
+        elif wire == 1:
+            i += 8
+        elif wire == 5:
+            i += 4
+        else:
+            raise ValueError(f"wire type {wire} at byte {i}")
+    return name, t0, out
+
+
 class _Plane:
     """XPlane: name (2), lines (3), event_metadata (4), stat_metadata (5).
     XEventMetadata: id (1), name (2), stats (5). XStatMetadata: id (1),
-    name (2)."""
+    name (2). The metadata is decoded on first use: most planes of a
+    trace are ones no reader uses."""
 
     def __init__(self, buf, span):
         self.buf, self.name, self.lines = buf, "", []
-        metas, stat_metas = [], []
+        self._metas, self._stat_metas = [], []
         for f, v in _fields(buf, *span):
             if f == 2:
                 self.name = _text(buf, v)
             elif f == 3:
                 self.lines.append(v)
             elif f == 4:
-                metas.append(v)
+                self._metas.append(v)
             elif f == 5:
-                stat_metas.append(v)
-        self.stat_names = {}
-        for entry in stat_metas:
-            key, val = _map_entry(buf, entry)
-            for f, v in _fields(buf, *val):
+                self._stat_metas.append(v)
+
+    @functools.cached_property
+    def stat_names(self):
+        out = {}
+        for entry in self._stat_metas:
+            key, val = _map_entry(self.buf, entry)
+            for f, v in _fields(self.buf, *val):
                 if f == 2:
-                    self.stat_names[key] = _text(buf, v)
-        self.event_names, self._meta_stats = {}, {}
-        for entry in metas:
-            key, val = _map_entry(buf, entry)
-            stats = []
-            for f, v in _fields(buf, *val):
+                    out[key] = _text(self.buf, v)
+        return out
+
+    @functools.cached_property
+    def _names_and_stats(self):
+        names, stats = {}, {}
+        for entry in self._metas:
+            key, val = _map_entry(self.buf, entry)
+            mine = stats[key] = []
+            for f, v in _fields(self.buf, *val):
                 if f == 2:
-                    self.event_names[key] = _text(buf, v)
+                    names[key] = _text(self.buf, v)
                 elif f == 5:
-                    stats.append(v)
-            self._meta_stats[key] = stats
+                    mine.append(v)
+        return names, stats
+
+    @property
+    def event_names(self):
+        return self._names_and_stats[0]
 
     def meta_stat(self, meta_id, name):
-        for span in self._meta_stats.get(meta_id, ()):
+        for span in self._names_and_stats[1].get(meta_id, ()):
             n, v = _stat(self.buf, span, self.stat_names)
             if n == name:
                 return v
         return None
 
-    def line_head(self, span):
-        """XLine: name (2), timestamp_ns (3), events (4)."""
-        name, t0, events = "", 0, []
-        for f, v in _fields(self.buf, *span):
-            if f == 2:
-                name = _text(self.buf, v)
-            elif f == 3:
-                t0 = v
-            elif f == 4:
-                events.append(v)
-        return name, t0, events
-
-    def event(self, span, t0_ns):
-        """XEvent -> (metadata id, start ns, end ns, stat spans):
-        metadata_id (1), offset_ps (2), duration_ps (3), stats (4)."""
-        meta = off = dur = 0
-        stats = []
-        for f, v in _fields(self.buf, *span):
-            if f == 1:
-                meta = v
-            elif f == 2:
-                off = v
-            elif f == 3:
-                dur = v
-            elif f == 4:
-                stats.append(v)
-        start = t0_ns + off // 1000
-        return meta, start, start + dur // 1000, stats
+    def event_stats(self, start, end):
+        """{name: value} of one event's stats (the bytes ``_events``
+        left unread)."""
+        return dict(_stat(self.buf, v, self.stat_names)
+                    for f, v in _fields(self.buf, start, end) if f == 4)
 
 
 # -- what the readers ask for -----------------------------------------------
@@ -176,12 +250,18 @@ class ProgramTrace:
     holds). ``ops[chip]``: (short name, start ns, end ns, name stack) of
     every ``XLA Ops`` event, containers included, in the trace's order.
     ``modules[chip]``: (name, start ns, end ns) of every executed
-    program."""
+    program. ``stats[chip]``: beside ``ops[chip]``, each event's
+    ``{"hlo": the instruction's text}`` as ``trace_reduce.Trace`` hands
+    it to a matcher (one dict an instruction, shared by its executions).
+    ``host``: (name, start ns, end ns) of the benchmark's own
+    annotations (``trace_reduce.HOST_SPANS``)."""
 
-    def __init__(self, spans, ops, modules):
+    def __init__(self, spans, ops, modules, stats, host):
         self.spans = sorted(spans, key=lambda s: (s[2], -s[3]))
         self.ops, self.modules = ops, modules
+        self.stats, self.host = stats, host
         self.memo = {}      # a reduction's result, shared by its readers
+        self._leaf = {}
 
     def spans_named(self, name):
         return [s for s in self.spans if s[0] == name]
@@ -194,14 +274,17 @@ class ProgramTrace:
         return None
 
     def leaf_ops(self, chip):
-        return [o for o in self.ops.get(chip, ())
-                if not trace_reduce.CONTAINER.match(o[0])]
+        if chip not in self._leaf:
+            self._leaf[chip] = [o for o in self.ops.get(chip, ())
+                                if not trace_reduce.is_container(o[0])]
+        return self._leaf[chip]
 
 
 def parse(path):
     with open(path, "rb") as f:
         buf = f.read()
-    spans, ops, modules = [], {}, {}
+    spans, host, ops, modules, stats = [], [], {}, {}, {}
+    device_lines = (trace_reduce.OPS_LINE, trace_reduce.MODULES_LINE)
     n_line = 0
     for f, v in _fields(buf, 0, len(buf)):
         if f != 1:                          # XSpace: planes (1)
@@ -210,42 +293,66 @@ def parse(path):
         m = trace_reduce.DEVICE_PLANE.match(plane.name)
         if m:
             chip = int(m.group(1))
-            stack = {}                      # metadata id -> name stack
+            names = plane.event_names
+            seen = {}           # metadata id -> (short name, stack, stats)
             for span in plane.lines:
-                name, t0, events = plane.line_head(span)
+                name, t0, events = _events(buf, *span, names=device_lines)
                 if name == trace_reduce.OPS_LINE:
                     out = ops.setdefault(chip, [])
-                    for ev in events:
-                        meta, s, e, _ = plane.event(ev, t0)
-                        if meta not in stack:
-                            stack[meta] = plane.meta_stat(meta, "tf_op") or ""
-                        out.append((trace_reduce.short_name(
-                            plane.event_names.get(meta, "")), s, e,
-                            stack[meta]))
+                    beside = stats.setdefault(chip, [])
+                    for meta, off, dur, _, _ in events:
+                        if meta not in seen:
+                            text = names.get(meta, "")
+                            seen[meta] = (
+                                trace_reduce.short_name(text),
+                                plane.meta_stat(meta, "tf_op") or "",
+                                {"hlo": text})
+                        short, stack, st = seen[meta]
+                        start = t0 + off // 1000
+                        out.append((short, start, start + dur // 1000,
+                                    stack))
+                        beside.append(st)
                 elif name == trace_reduce.MODULES_LINE:
                     out = modules.setdefault(chip, [])
-                    for ev in events:
-                        meta, s, e, _ = plane.event(ev, t0)
-                        out.append((plane.event_names.get(meta, ""), s, e))
+                    for meta, off, dur, _, _ in events:
+                        start = t0 + off // 1000
+                        out.append((names.get(meta, ""), start,
+                                    start + dur // 1000))
         elif plane.name.startswith("/host:"):
             ours = {k: n[len(SPAN_PREFIX):]
                     for k, n in plane.event_names.items()
                     if n.startswith(SPAN_PREFIX)}
-            if not ours:
+            bench = {k: n for k, n in plane.event_names.items()
+                     if n in trace_reduce.HOST_SPANS}
+            if not ours and not bench:
                 continue
             for span in plane.lines:
                 n_line += 1
-                _, t0, events = plane.line_head(span)
-                for ev in events:
-                    meta, s, e, stats = plane.event(ev, t0)
+                _, t0, events = _events(buf, *span)
+                for meta, off, dur, at, end in events:
                     if meta in ours:
-                        attrs = dict(_stat(buf, st, plane.stat_names)
-                                     for st in stats)
-                        spans.append((ours[meta], n_line, s, e, attrs))
-    return ProgramTrace(spans, ops, modules)
+                        start = t0 + off // 1000
+                        spans.append((ours[meta], n_line, start,
+                                      start + dur // 1000,
+                                      plane.event_stats(at, end)))
+                    elif meta in bench:
+                        start = t0 + off // 1000
+                        host.append((bench[meta], start,
+                                     start + dur // 1000))
+    return ProgramTrace(spans, ops, modules, stats, host)
 
 
 _parsed = {}
+
+
+def parsed(path):
+    """The one parse of a run's trace, whoever asks first (``run.py``
+    through ``trace_reduce.load``, then every reader through ``of``)."""
+    key = (path, os.path.getmtime(path), os.path.getsize(path))
+    if key not in _parsed:
+        _parsed.clear()
+        _parsed[key] = parse(path)
+    return _parsed[key]
 
 
 def of(cell):
@@ -257,13 +364,7 @@ def of(cell):
         for f in files:
             if f.endswith(".xplane.pb"):
                 path = os.path.join(base, f)
-    if path is None:
-        return None
-    key = (path, os.path.getmtime(path), os.path.getsize(path))
-    if key not in _parsed:
-        _parsed.clear()
-        _parsed[key] = parse(path)
-    return _parsed[key]
+    return None if path is None else parsed(path)
 
 
 # -- reductions the readers share ---------------------------------------------

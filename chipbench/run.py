@@ -4,9 +4,13 @@
 
 Loads and warms up (set-up), measures for ``--seconds``, checks what the
 timed path produced against the plain reference, and prints one JSON
-object as the last line of standard output. Everything else goes on
-earlier lines. A run that finds no TPU, or fewer chips than the cell
-asks for, exits non-zero and prints no result.
+object as the last line of standard output (``correct``, ``attempted``,
+``failed``, ``device``, ``metrics``, with ``--trace 1`` ``breakdown``,
+and last ``checks``: every number compared beside its limit, which are
+also the last lines of standard error). Everything else goes on earlier
+lines, among them ``chipbench: phases ...``: where the run's wall time
+went, in seconds, part by part and in total. A run that finds no TPU, or
+fewer chips than the cell asks for, exits non-zero and prints no result.
 
 Nothing here names a cell, a configuration, a mix or a metric: a cell in
 ``BENCHMARK.json`` names its configuration file and its mix
@@ -35,16 +39,45 @@ REPO = os.path.dirname(HERE)
 sys.path[:] = [p for p in sys.path if os.path.abspath(p or ".") != HERE]
 
 
-def say(msg):
-    print(f"chipbench: {msg}", flush=True)
+def say(msg, file=None):
+    print(f"chipbench: {msg}", file=file, flush=True)
+
+
+def check_line(name, c):
+    return (f"check {name}: {c['value']:.6g} (limit {c['limit']:.6g}) "
+            f"{'ok' if c['ok'] else 'FAILED'}")
+
+
+class Phases:
+    """Where a run's wall time went: consecutive stretches of the main
+    thread, each closed and named by ``end``, so that the parts sum to the
+    total. The driver's limit is on a run's wall time, which no metric
+    reports; every run prints this as one line."""
+
+    def __init__(self, t_start):
+        self.t_start = self.t_last = t_start
+        self.parts = []
+
+    def end(self, name, at=None):
+        """Close the stretch since the last call (at ``at``, or now)."""
+        at = time.perf_counter() if at is None else at
+        self.parts.append((name, at - self.t_last))
+        self.t_last = at
+
+    def line(self):
+        self.end("result")
+        return "phases " + " ".join(f"{n}={s:.2f}" for n, s in self.parts) \
+            + f" total={self.t_last - self.t_start:.2f}"
 
 
 class Cell:
     """Everything one run needs to know, read from data files."""
 
-    def __init__(self, root, workload, seed, seconds, trace):
+    def __init__(self, root, workload, seed, seconds, trace, t_start=None):
         self.root, self.seed, self.seconds, self.trace = \
             root, seed, seconds, bool(trace)
+        self.phases = Phases(time.perf_counter() if t_start is None
+                             else t_start)
         with open(os.path.join(root, "BENCHMARK.json")) as f:
             self.manifest = json.load(f)
         by_name = {w["name"]: w for w in self.manifest["workloads"]}
@@ -119,6 +152,7 @@ class Tracer:
     def __init__(self, cell):
         self.dir = os.path.join(cell.out_dir, "trace")
         self.on = False
+        self.stop_span = None       # (start, end) of stop(), host clock
 
     def start(self):
         import jax
@@ -128,17 +162,26 @@ class Tracer:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
+        # every program's HLO as a protobuf beside the events: no reader
+        # uses it (an event carries its instruction's text and name stack
+        # without it), and writing it out costs as much again as the
+        # events do, more in a process that compiled (PERF.md section 2)
+        opts.enable_hlo_proto = False
         jax.profiler.start_trace(self.dir, profiler_options=opts)
         self.on = True
 
     def stop(self):
-        """Writing the trace out takes seconds (half a minute for the
-        serving cell's): a kind whose load must go on meanwhile calls this
-        from a thread of its own and joins it before it returns."""
+        """The device's part ends at once; converting and writing the
+        trace out then takes seconds (3.6 s for the training cell's 92 k
+        device operations, 30 s for the serving cell's 600 k), and three
+        times as long from a thread that is not the process's main one
+        (PERF.md Findings, PR 26): call it from the main thread."""
         import jax
 
         self.on = False
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        self.stop_span = (t0, time.perf_counter())
 
     def xplane(self):
         for base, _, files in os.walk(self.dir):
@@ -176,20 +219,24 @@ def device_record(devices):
 
 
 def open_cell(root, workload, seed, seconds, trace, require_chip,
-              reference=True):
+              reference=True, t_start=None):
     """The cell, the module that drives its kind, and its chips, in the
     order the chip allows: a kind's ``before_devices`` (the training
     reference's own process) runs before this process touches JAX."""
     for p in (root, REPO):
         if p not in sys.path:
             sys.path.insert(0, p)
-    cell = Cell(root, workload, seed, seconds, trace)
+    cell = Cell(root, workload, seed, seconds, trace, t_start)
     os.makedirs(cell.out_dir, exist_ok=True)
     kind = importlib.import_module(f"chipbench.kinds.{cell.traffic['kind']}")
+    cell.phases.end("imports")
     if reference and hasattr(kind, "before_devices"):
         kind.before_devices(cell, require_chip)
+        cell.phases.end("reference")
     setup_compile_cache()
-    return cell, kind, find_devices(cell, require_chip)
+    devices = find_devices(cell, require_chip)
+    cell.phases.end("devices")
+    return cell, kind, devices
 
 
 def run_cell(argv, root=REPO, require_chip=True):
@@ -204,7 +251,8 @@ def run_cell(argv, root=REPO, require_chip=True):
     args = ap.parse_args(argv)
 
     cell, kind, devices = open_cell(root, args.workload, args.seed,
-                                    args.seconds, args.trace, require_chip)
+                                    args.seconds, args.trace, require_chip,
+                                    t_start=T_START)
     say(f"cell {cell.name} seed {cell.seed} seconds {cell.seconds} trace "
         f"{int(cell.trace)} on {len(devices)} x {devices[0].device_kind}, "
         f"{(devices[0].memory_stats() or {}).get('bytes_limit')} bytes a chip")
@@ -213,12 +261,13 @@ def run_cell(argv, root=REPO, require_chip=True):
     run = kind.run(cell, devices, tracer, T_START)
 
     # every number compared, beside its limit
-    correct = True
+    correct, compared = True, {}
     for c in run["checks"]:
         ok = bool(c["value"] <= c["limit"]) and c["value"] == c["value"]
         correct &= ok
-        say(f"check {c['name']}: {c['value']:.6g} (limit {c['limit']:.6g}) "
-            f"{'ok' if ok else 'FAILED'}")
+        compared[c["name"]] = {"value": float(c["value"]),
+                               "limit": float(c["limit"]), "ok": ok}
+        say(check_line(c["name"], compared[c["name"]]))
     # the peak is the program's: the training reference ran in a process
     # of its own, the serving one walks a layer at a time after the engine
     # and its weights are freed
@@ -228,23 +277,37 @@ def run_cell(argv, root=REPO, require_chip=True):
     if cell.trace:
         from chipbench import trace_reduce
 
-        trace = trace_reduce.load(tracer.xplane(), len(devices))
+        path = tracer.xplane()
+        trace = trace_reduce.load(path, len(devices))
+        cell.phases.end("trace_read")
+        say(f"trace of {os.path.getsize(path)} bytes: "
+            f"{sum(len(v) for v in trace.ops.values())} device operations "
+            f"in {sum(len(v) for v in trace.modules.values())} programs")
         result["metrics"] = read_layer_metrics(cell, trace, run["counters"])
         device["busy_s"] = trace.busy_s()
         device["window_s"] = trace.window_s()
         result["breakdown"] = {"device_ops": trace.top_ops(10),
                                "idle_gaps": trace.idle_gaps(10)}
+        cell.phases.end("readers")
     else:
         want = cell.metrics_of("end_to_end")
         result["metrics"] = {
             m["name"]: {"value": float(run["end_to_end"][m["name"]]),
                         "unit": m["unit"]} for m in want}
+    say(cell.phases.line())
+    result["checks"] = compared         # last in the line
     return result
 
 
-def main():
-    result = run_cell(sys.argv[1:])
+def main(argv=None, **where):
+    """``where`` is the tests' (``run_cell``'s ``root`` and
+    ``require_chip``)."""
+    result = run_cell(sys.argv[1:] if argv is None else argv, **where)
     print(json.dumps(result), flush=True)
+    # and as the last lines of standard error, where a record of a run
+    # that was not correct keeps them
+    for name, c in result["checks"].items():
+        say(check_line(name, c), file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -12,8 +12,13 @@ and whose short name is the JAX scope it was traced in), the line
 ``XLA Modules`` one event per executed program (``jit_<fn>(<id>)``) and
 ``Async XLA Ops`` the copies in flight (not counted as busy: they overlap
 the operations). ``jax.profiler.TraceAnnotation``s land on the host plane
-``/host:CPU``, on the line ``python``. All planes share one clock."""
+``/host:CPU``, on the line ``python``. All planes share one clock.
 
+The file itself is read by ``program_trace.parsed``, once a run; what is
+reduced from it more than once (the operations that are no containers,
+the busy union) is kept on the ``Trace``."""
+
+import functools
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -23,6 +28,8 @@ COLLECTIVE = re.compile(
     r"(-start|-done)?[.\d]*$")
 # parents that span their children: their time is their children's
 CONTAINER = re.compile(r"^(while|conditional|call)[.\d]*$")
+is_container = functools.lru_cache(maxsize=None)(
+    lambda name: CONTAINER.match(name) is not None)
 #: the benchmark's own host annotations (kinds/*.py)
 HOST_SPANS = ("make_batch", "step_call", "submit", "drain", "wait_for_due",
               "window")
@@ -74,14 +81,23 @@ class Trace:
         every = [(s, e) for c in self.chips for _, s, e, _ in ops[c]]
         self.window = (min(s for s, _ in every), max(e for _, e in every)) \
             if every else (0, 0)
+        self._leaf, self._busy = {}, {}
 
     # -- busy and idle ------------------------------------------------------
 
     def leaf_ops(self, chip):
-        return [o for o in self.ops[chip] if not CONTAINER.match(o[0])]
+        if chip not in self._leaf:
+            self._leaf[chip] = [o for o in self.ops[chip]
+                                if not is_container(o[0])]
+        return self._leaf[chip]
 
     def busy(self, chip):
-        return union((s, e) for _, s, e, _ in self.leaf_ops(chip))
+        """Merged (start, end) of the chip's operations; callers read it
+        and do not change it."""
+        if chip not in self._busy:
+            self._busy[chip] = union(
+                (s, e) for _, s, e, _ in self.leaf_ops(chip))
+        return self._busy[chip]
 
     def busy_s(self):
         """Seconds in which an operation ran, averaged over the chips."""
@@ -184,29 +200,17 @@ def short_name(text):
 
 
 def load(path, n_chips):
-    """Read an ``.xplane.pb`` with nothing but JAX."""
-    import jax
+    """The ``Trace`` of an ``.xplane.pb``, from the run's one parse of it
+    (``program_trace.parsed``: the standard library alone)."""
+    from chipbench import program_trace      # it imports this module
 
-    data = jax.profiler.ProfileData.from_file(path)
-    ops, modules, host = {}, {}, []
-    for plane in data.planes:
-        m = DEVICE_PLANE.match(plane.name)
-        if m:
-            chip = int(m.group(1))
-            for line in plane.lines:
-                if line.name not in (OPS_LINE, MODULES_LINE):
-                    continue
-                evs = [(short_name(e.name), int(e.start_ns),
-                        int(e.start_ns + e.duration_ns), {"hlo": e.name})
-                       for e in line.events]
-                (ops if line.name == OPS_LINE else modules)[chip] = evs
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name in HOST_SPANS:
-                        host.append((e.name, int(e.start_ns),
-                                     int(e.start_ns + e.duration_ns)))
-    return Trace(ops, modules, host, n_chips)
+    pt = program_trace.parsed(path)
+    ops = {chip: [(n, s, e, st)
+                  for (n, s, e, _), st in zip(evs, pt.stats[chip])]
+           for chip, evs in pt.ops.items()}
+    modules = {chip: [(n, s, e, {"hlo": n}) for n, s, e in evs]
+               for chip, evs in pt.modules.items()}
+    return Trace(ops, modules, pt.host, n_chips)
 
 
 def from_records(records, n_chips=1):

@@ -83,6 +83,11 @@ def test_files_exist(manifest):
             mix = json.load(f)
         assert os.path.exists(os.path.join(bench, "kinds",
                                            mix["kind"] + ".py"))
+        # a traced part is bounded in seconds, and where the pace is the
+        # host's and moves by factors (an engine loop), in work as well
+        assert mix["trace_seconds"] > 0
+        if mix["kind"] == "serve":
+            assert mix["trace_iterations"] >= 1
         with open(os.path.join(bench, "limits", w["name"] + ".json")) as f:
             for name, entry in json.load(f).items():
                 assert entry["limit"] >= 0 and entry["why"], name

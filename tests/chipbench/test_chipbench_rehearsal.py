@@ -4,10 +4,13 @@ chip and nothing else), the same paths with the timed path broken
 underneath, the lower-precision control, the generator as a pure function
 of the seed, and the references against the program in float32."""
 
+import contextlib
+import io
 import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -37,15 +40,37 @@ def cell_args(name, trace=0, seed=SEED):
             "--trace", str(trace)]
 
 
-@pytest.mark.parametrize("cell,trace", [
-    ("tiny-train-cell", 0), ("tiny-train-cell", 1),
-    ("tiny-serve-cell", 0), ("tiny-serve-cell", 1),
-    ("tiny-train4-cell", 0)])
-def test_run_end_to_end(root, cell, trace):
-    result = run.run_cell(cell_args(cell, trace), root=root,
-                          require_chip=False)
+RUNS = [("tiny-train-cell", 0), ("tiny-train-cell", 1),
+        ("tiny-serve-cell", 0), ("tiny-serve-cell", 1),
+        ("tiny-train4-cell", 0)]
+
+
+@pytest.fixture(scope="module")
+def ran(root):
+    """(result, lines of standard output) of ``run.main`` for a cell, run
+    once however many tests look at it."""
+    made = {}
+
+    def of(cell, trace):
+        if (cell, trace) not in made:
+            out = io.StringIO()
+            with contextlib.redirect_stdout(out):
+                run.main(cell_args(cell, trace), root=root,
+                         require_chip=False)
+            lines = out.getvalue().strip().splitlines()
+            made[cell, trace] = (json.loads(lines[-1]), lines)
+        return made[cell, trace]
+    return of
+
+
+@pytest.mark.parametrize("cell,trace", RUNS)
+def test_run_end_to_end(root, ran, cell, trace):
+    result, _ = ran(cell, trace)
     assert set(result) - {"breakdown"} == {"correct", "attempted", "failed",
-                                           "metrics", "device"}
+                                           "metrics", "device", "checks"}
+    assert list(result)[-1] == "checks" and all(
+        c["ok"] and c["value"] <= c["limit"]
+        for c in result["checks"].values())
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -63,7 +88,82 @@ def test_run_end_to_end(root, cell, trace):
     else:
         assert set(result["metrics"]) == mine
         assert all(v["value"] > 0 for v in result["metrics"].values())
-    json.dumps(result)
+
+
+@pytest.mark.parametrize("cell,trace", RUNS)
+def test_every_run_prints_where_its_wall_time_went(ran, cell, trace):
+    """One line ``chipbench: phases <name>=<seconds> ... total=<seconds>``
+    on an earlier line; the last line is still the one JSON object."""
+    result, lines = ran(cell, trace)
+    assert lines[-1].startswith("{") and set(result) >= {"correct", "metrics"}
+    (line,) = [l for l in lines if l.startswith("chipbench: phases ")]
+    assert lines.index(line) < len(lines) - 1
+    parts = [kv.split("=") for kv in line.split()[2:]]
+    seconds = {k: float(v) for k, v in parts}
+    assert len(seconds) == len(parts), "a phase is named twice"
+    total = seconds.pop("total")
+    assert sum(seconds.values()) == pytest.approx(
+        total, abs=0.005 * (len(seconds) + 1))
+    kind = "train" if "train" in cell else "serve"
+    want = {"imports", "devices", "build", "window", "reference", "result"}
+    want |= {"serve": {"warm_up", "lead_in", "drain", "shutdown"},
+             "train": {"first_steps", "trace_start"}}[kind]
+    if trace:
+        want |= {"trace_read", "readers"} | (
+            {"trace_stop"} if kind == "serve" else set())
+    assert set(seconds) == want
+    # (a traced training run writes its trace out inside the window)
+    assert seconds["window"] >= 1.49 and all(
+        v >= 0 for v in seconds.values())
+
+
+def test_trace_lead_follows_the_pace_up_to_the_cap():
+    mix = {"trace_seconds": 4, "trace_iterations": 32}
+    s0 = {"iterations": 100}
+    at = lambda iters, secs: serve_kind.trace_lead(
+        mix, {"iterations": 100 + iters}, s0, secs)
+    assert at(416, 47.0) == pytest.approx(32 * 47.0 / 416)    # 113 ms: 3.6 s
+    assert at(904, 47.0) == pytest.approx(32 * 47.0 / 904)    # 52 ms: 1.7 s
+    assert at(300, 47.0) == 4                                 # slower: the cap
+    assert at(0, 47.0) == 4                                   # an idle engine
+
+
+@pytest.mark.parametrize("iterations,lead_s", [(3, None), (10 ** 6, 1.0)])
+def test_traced_part_is_bounded_by_iterations_then_seconds(
+        tmp_path, iterations, lead_s):
+    """Counts from ``stats()`` and the start the kind chose, no device
+    times. Every token costs the engine's thread 50 ms here (a slow
+    client), so a pass of the loop takes 0.2 s at four rows and the
+    profiler's own start (inside the lead) is short beside it."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    root = tiny.write_root(str(tmp_path), real, serve={
+        "trace_iterations": iterations, "trace_seconds": lead_s or 3.0,
+        "rate_per_s": 4.0})
+
+    def slow(on_token):
+        def call(tok, i):
+            time.sleep(0.05)
+            return on_token(tok, i)
+        return call
+
+    cell, kind, devices = run.open_cell(root, "tiny-serve-cell", SEED, 4.0,
+                                        1, False)
+    out = kind.run(cell, devices, run.Tracer(cell), time.perf_counter(),
+                   broken=slow)
+    c = out["counters"]
+    assert out["failed"] == 0
+    pace = 4.0 / c["iterations"]
+    assert 0.15 < pace < 0.4
+    if lead_s is None:
+        assert 2 <= c["traced_iterations"] <= 5, c
+        assert c["trace_lead_s"] == pytest.approx(3 * pace, rel=0.35)
+    else:
+        assert c["trace_lead_s"] == pytest.approx(lead_s, abs=0.25)
+        assert c["traced_iterations"] == pytest.approx(lead_s / pace, abs=2)
+    # the profiler's start falls inside the lead; a sleep may overshoot
+    assert c["traced_seconds"] <= c["trace_lead_s"] + 0.25
+    assert os.path.exists(run.Tracer(cell).xplane())
 
 
 def test_train_step_that_returns_its_state_unchanged_is_not_correct(
@@ -80,6 +180,7 @@ def test_train_step_that_returns_its_state_unchanged_is_not_correct(
     result = run.run_cell(cell_args("tiny-train-cell"), root=root,
                           require_chip=False)
     assert result["correct"] is False
+    assert any(not c["ok"] for c in result["checks"].values())
 
 
 def test_served_token_altered_where_it_is_produced_is_not_correct(
@@ -94,6 +195,33 @@ def test_served_token_altered_where_it_is_produced_is_not_correct(
     result = run.run_cell(cell_args("tiny-serve-cell"), root=root,
                           require_chip=False)
     assert result["correct"] is False
+    assert any(not c["ok"] for c in result["checks"].values())
+
+
+def test_engine_is_freed_before_the_reference_walks_the_model(
+        root, monkeypatch):
+    """The peak memory a run reports is the program's: nothing may hold
+    the engine (its weights and pages) while the reference runs. A traced
+    run, whose load runs on a thread of its own."""
+    import weakref
+
+    engines, alive = [], []
+    real_build, real_gaps = serve_kind.build, serve_logits.served_gaps
+
+    def build(*a):
+        eng = real_build(*a)
+        engines.append(weakref.ref(eng))
+        return eng
+
+    def gaps(*a, **k):
+        alive.append(engines[0]() is not None)
+        return real_gaps(*a, **k)
+
+    monkeypatch.setattr(serve_kind, "build", build)
+    monkeypatch.setattr(serve_logits, "served_gaps", gaps)
+    result = run.run_cell(cell_args("tiny-serve-cell", trace=1), root=root,
+                          require_chip=False)
+    assert result["correct"] is True and alive == [False]
 
 
 def test_train_control_in_fp8_fails_a_limit():

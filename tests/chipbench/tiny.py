@@ -28,7 +28,7 @@ SERVE = {"kind": "serve", "rate_per_s": 12.0, "lead_in_s": 0.5,
                            "distinct": 4},
          "engine": {"paged": True, "n_slots": 4, "max_len": 64,
                     "buckets": [8, 16], "max_queue": 256},
-         "check_requests": 4, "trace_seconds": 1}
+         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32}
 
 
 # at this size a leaf has few elements, so bfloat16's noise averages out
@@ -41,9 +41,10 @@ LIMITS = {
     "tiny-serve-cell": {"served_logit_gap_max": 0.25}}
 
 
-def write_root(root, real_manifest):
+def write_root(root, real_manifest, serve=None):
     """``root``/BENCHMARK.json with two tiny cells that report the same
-    metrics as the real train and serve cells."""
+    metrics as the real train and serve cells. ``serve`` replaces fields
+    of the serving mix."""
     bench = os.path.join(root, "chipbench")
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
@@ -51,7 +52,8 @@ def write_root(root, real_manifest):
         with open(os.path.join(bench, "configs", cfg["name"] + ".json"),
                   "w") as f:
             json.dump(cfg, f)
-    for name, mix in (("tiny-train", TRAIN), ("tiny-serve", SERVE)):
+    for name, mix in (("tiny-train", TRAIN),
+                      ("tiny-serve", dict(SERVE, **(serve or {})))):
         with open(os.path.join(bench, "traffic", name + ".json"), "w") as f:
             json.dump(mix, f)
     for cell, limits in LIMITS.items():
