@@ -33,8 +33,9 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ..models.generate import (decode_step_slots, prefill_partial,
-                               spec_commit_slots, spec_verify_slots)
+from ..models.generate import (_sample, decode_step_slots,
+                               prefill_partial, spec_commit_slots,
+                               spec_verify_slots)
 
 
 @dataclass
@@ -67,6 +68,15 @@ def named_program(fn, name: str, **bound):
     fn = partial(fn, **bound)
     fn.__name__ = name
     return fn
+
+
+def greedy_tokens(logits):
+    """The tail of every decode program: each slot's greedy token,
+    (n_slots,) int32 — ``_sample`` at temperature 0 over the same
+    float32 logits, so a greedy stream is bit for bit what a sampler
+    program of its own gave it. The engine reads these in one fetch
+    (``serve/sampling.py`` replaces the rows that sample)."""
+    return _sample(logits, None, 0.0, None)
 
 
 class SlotPool:
@@ -103,7 +113,7 @@ class SlotPool:
                                            lengths, tokens,
                                            window=self.window)
         lengths = jnp.where(active, lengths + 1, lengths)
-        return logits, ks, vs, lengths
+        return greedy_tokens(logits), logits, ks, vs, lengths
 
     def _admit(self, params, ks, vs, lengths, tokens, true_len, slot,
                *, bucket: int):
@@ -187,11 +197,12 @@ class SlotPool:
     def decode(self, params, tokens, active):
         """Advance every slot one position (dead slots masked: their
         lengths freeze and their outputs are discarded by the caller).
-        tokens/active: (n_slots,) int32 / bool. Returns (n_slots, vocab)
-        logits."""
-        logits, self.ks, self.vs, self.lengths = self._decode_fn(
+        tokens/active: (n_slots,) int32 / bool. Returns each slot's
+        greedy token (n_slots,) int32 and the (n_slots, vocab) logits,
+        both left on the device."""
+        out, logits, self.ks, self.vs, self.lengths = self._decode_fn(
             params, self.ks, self.vs, self.lengths, tokens, active)
-        return logits
+        return out, logits
 
     def release(self, slot: int) -> None:
         """Zero a retired slot's length (the engine's every exit path

@@ -30,12 +30,11 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-import jax
 import numpy as np
 
-from ...models.generate import _sample
 from ...runtime import faults
 from ..pages import PagedSlotPool
+from ..sampling import RowSampler
 from ..spec import SpecState, accept_greedy
 from ..types import (HandoffCorrupt, PagePoolExhausted, Request,
                      RequestDeadlineExceeded, SpecDecodeError)
@@ -79,7 +78,7 @@ class DecodeEngine:
         self.spec_tokens = 0
         self.iterations = 0
         self.tokens_emitted = 0
-        self._samplers: Dict[tuple, callable] = {}
+        self._sampler = RowSampler(n_slots, self.pool.compiles)
         self._running: Dict[int, Request] = {}
         self._free: List[int] = list(range(n_slots))[::-1]
         self._cur_tokens = np.zeros(n_slots, np.int32)
@@ -246,8 +245,8 @@ class DecodeEngine:
                 self._spec.admit(req.prompt, slot, self._spec_buckets)
             # token 0: the frame's exact logits + rngs[0] — the same
             # split-schedule position generate() samples first
-            tok = self._sample_for(req, np.asarray(frame.logits)[None])
-            self._emit(req, tok)
+            tok = self._sampler.first(req, np.asarray(frame.logits)[None])
+            self._emit(req, int(np.asarray(tok)[0]))
 
     def _decode_all(self) -> None:
         spec_slots: List[int] = []
@@ -273,13 +272,17 @@ class DecodeEngine:
         if nonspec:
             active = np.zeros(self.n_slots, bool)
             active[nonspec] = True
-            logits = self.pool.decode(self.params,
-                                      np.asarray(self._cur_tokens),
-                                      np.asarray(active))
+            tokens, logits = self.pool.decode(self.params,
+                                              np.asarray(self._cur_tokens),
+                                              np.asarray(active))
+            # as the engine's row path (serve/engine.py): greedy tokens
+            # from the decode program, one sampler a setting, one fetch
+            groups = {}
             for slot in nonspec:
-                req = self._running[slot]
-                tok = self._sample_for(req, logits[slot:slot + 1])
-                self._emit(req, tok)
+                self._sampler.join(groups, slot, self._running[slot])
+            tokens = np.asarray(self._sampler.merge(tokens, logits, groups))
+            for slot in nonspec:
+                self._emit(self._running[slot], int(tokens[slot]))
         spec_slots = [s for s in spec_slots if s in self._running]
         if spec_slots:
             self._spec_step(spec_slots)
@@ -372,20 +375,6 @@ class DecodeEngine:
                     break
 
     # -- per-request mechanics (mirror serve/engine.py) --------------------
-
-    def _sample_for(self, req: Request, logits) -> int:
-        fn = self._samplers.get(req.params.sampler_key)
-        if fn is None:
-            t, k, p = req.params.sampler_key
-            pool = self.pool
-
-            def sample(lg, rng, t=t, k=k, p=p):
-                pool.compiles.sample += 1          # trace-time only
-                return _sample(lg, rng, t, k, p)
-            fn = jax.jit(sample)
-            self._samplers[req.params.sampler_key] = fn
-        key = np.asarray(req.rngs[len(req.out_tokens)])
-        return int(np.asarray(fn(logits, key))[0])
 
     def _emit(self, req: Request, tok: int) -> None:
         now = time.monotonic()

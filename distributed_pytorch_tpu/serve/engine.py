@@ -26,7 +26,7 @@ SLO metrics (TTFT/TPOT/queue depth/slot occupancy, defined in
 
 Where the engine thread's time goes is on two instruments
 (docs/observability.md): dpxtrace spans ``serve.*`` around every phase
-of an iteration down to one row's sample, fetch and emit — profiler
+of an iteration down to its one token fetch and each row's emit — profiler
 annotations, so a ``jax.profiler`` session lays them against the device
 ops — and the always-on ``stats()["host_ns"]`` counters, cumulative
 nanoseconds a phase, read twice an iteration and never per row.
@@ -43,8 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generate import (_check_attn_compatible, _model_window,
-                               _sample)
+from ..models.generate import _check_attn_compatible, _model_window
 from ..obs import metrics as dpxmon
 from ..obs import trace as dpxtrace
 from ..runtime import compile_cache
@@ -54,6 +53,7 @@ from ..utils.logging import MetricsLogger
 from .cache import SlotPool
 from .metrics import emit_request_trace, request_record
 from .pages import PagedSlotPool
+from .sampling import RowSampler
 from .scheduler import AdmissionScheduler
 from .spec import SpecConfig, SpecState, accept_greedy
 from .types import (FAILED, FINISHED, QUEUED, RUNNING, AdmissionRejected,
@@ -226,7 +226,7 @@ class InferenceEngine:
         self._tenant_inflight: Dict[str, int] = {}
         self.metrics = cfg.metrics
         self._scheduler = AdmissionScheduler(cfg.max_queue)
-        self._samplers: Dict[tuple, callable] = {}
+        self._sampler = RowSampler(cfg.n_slots, self.pool.compiles)
         self._running: Dict[int, Request] = {}     # slot -> request
         self._free: List[int] = list(range(cfg.n_slots))[::-1]
         self._cur_tokens = np.zeros(cfg.n_slots, np.int32)
@@ -237,6 +237,7 @@ class InferenceEngine:
             ("idle", "admit", "decode_dispatch", "row_loop", "iter"), 0)
         self._admitted = 0
         self._rows_decoded = 0
+        self._decode_fetches = 0  # device-to-host token reads, decode path
         self._tokens_emitted = 0
         self._completed = 0
         self._failed = 0
@@ -411,6 +412,8 @@ class InferenceEngine:
                "tokens_emitted": self._tokens_emitted,
                "admitted": self._admitted,
                "rows_decoded": self._rows_decoded,
+               "decode_fetches": self._decode_fetches,
+               "sample_dispatches": self._sampler.dispatches,
                "host_ns": dict(self._host_ns),
                "queue_depth": len(self._scheduler),
                "active_slots": len(self._running),
@@ -637,7 +640,8 @@ class InferenceEngine:
                 # the fetch is where the host waits for the prefill
                 with dpxtrace.span("serve.admit.first_token",
                                    iteration=self._iteration):
-                    tok = int(np.asarray(self._sample_for(req, logits))[0])
+                    tok = int(np.asarray(
+                        self._sampler.first(req, logits))[0])
                     self._emit(req, tok)
 
     def _decode_all(self) -> None:
@@ -681,20 +685,39 @@ class InferenceEngine:
             t0 = clock()
             with dpxtrace.span("serve.decode.dispatch", iteration=it,
                                rows=rows):
-                logits = self.pool.decode(self.params,
-                                          jnp.array(self._cur_tokens),
-                                          jnp.asarray(active))
+                tokens, logits = self.pool.decode(
+                    self.params, jnp.array(self._cur_tokens),
+                    jnp.asarray(active))
             t1 = clock()
             with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
+                # a greedy row's token is the decode program's own; the
+                # rows that sample get theirs from one program a setting.
+                # What is left of a row's sample and fetch is host work of
+                # about a microsecond (joining its group; reading its
+                # token from the fetched array); their spans stay for the
+                # metrics that read them (PERF.md section 3)
+                live, groups = [], {}
                 for slot in nonspec:
                     req = self._running[slot]
                     ids = dict(iteration=it, slot=slot,
                                trace_id=req.trace_id,
                                request_id=req.request_id)
+                    live.append((req, ids))
                     with dpxtrace.span("serve.row.sample", **ids):
-                        out = self._sample_for(req, logits[slot:slot + 1])
+                        self._sampler.join(groups, slot, req)
+                if groups:
+                    with dpxtrace.span("serve.decode.sample", iteration=it,
+                                       groups=len(groups)):
+                        tokens = self._sampler.merge(tokens, logits, groups)
+                # the iteration's one read: here the host waits for the
+                # decode program
+                with dpxtrace.span("serve.decode.fetch", iteration=it,
+                                   rows=rows):
+                    tokens = np.asarray(tokens)
+                self._decode_fetches += 1
+                for req, ids in live:
                     with dpxtrace.span("serve.row.fetch", **ids):
-                        tok = int(np.asarray(out)[0])
+                        tok = int(tokens[req.slot])
                     with dpxtrace.span("serve.row.emit", **ids):
                         self._emit(req, tok)
             self._host_ns["decode_dispatch"] += t1 - t0
@@ -805,26 +828,6 @@ class InferenceEngine:
                 self._emit(req, tok)
                 if req.done:
                     break
-
-    def _sample_for(self, req: Request, logits):
-        """Dispatch the request's sampler on ``logits`` (1, vocab);
-        returns the token still on the device, shape (1,) — the caller
-        fetches it, so that dispatch and wait can be told apart."""
-        fn = self._samplers.get(req.params.sampler_key)
-        if fn is None:
-            t, k, p = req.params.sampler_key
-            pool = self.pool
-
-            def sample(lg, rng, t=t, k=k, p=p):
-                pool.compiles.sample += 1          # trace-time only
-                return _sample(lg, rng, t, k, p)
-            # the program's name on the profiler's device plane
-            sample.__name__ = "sample_" + "_".join(
-                str(v) for v in req.params.sampler_key)
-            fn = jax.jit(sample)
-            self._samplers[req.params.sampler_key] = fn
-        key = jnp.asarray(req.rngs[len(req.out_tokens)])
-        return fn(logits, key)
 
     def _emit(self, req: Request, tok: int) -> None:
         now = time.monotonic()

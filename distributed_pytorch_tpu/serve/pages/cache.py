@@ -38,7 +38,7 @@ from ...models.generate import (decode_step_slots_paged,
                                 spec_commit_slots_paged,
                                 spec_verify_slots_paged)
 from ...runtime import faults
-from ..cache import CompileCounts, named_program
+from ..cache import CompileCounts, greedy_tokens, named_program
 from ..types import AdmissionRejected
 from .pool import PagePool
 from .prefix import PrefixIndex
@@ -139,20 +139,20 @@ class PagedSlotPool:
     def _decode(self, params, k_pages, v_pages, tables, lengths, tokens,
                 active):
         self.compiles.decode += 1          # trace-time only
-        return decode_step_slots_paged(self.model, params, k_pages,
-                                       v_pages, tables, lengths, tokens,
-                                       active, page_len=self.page_len)
+        logits, *pool = decode_step_slots_paged(
+            self.model, params, k_pages, v_pages, tables, lengths, tokens,
+            active, page_len=self.page_len)
+        return (greedy_tokens(logits), logits, *pool)
 
     def _decode_q(self, params, k_pages, v_pages, k_scales, v_scales,
                   k_tail, v_tail, tables, lengths, tokens, active):
         self.compiles.decode += 1          # trace-time only
-        return decode_step_slots_paged(self.model, params, k_pages,
-                                       v_pages, tables, lengths, tokens,
-                                       active, page_len=self.page_len,
-                                       kv_bits=self.quant_bits,
-                                       k_scales=k_scales,
-                                       v_scales=v_scales,
-                                       k_tail=k_tail, v_tail=v_tail)
+        logits, *pool = decode_step_slots_paged(
+            self.model, params, k_pages, v_pages, tables, lengths, tokens,
+            active, page_len=self.page_len, kv_bits=self.quant_bits,
+            k_scales=k_scales, v_scales=v_scales, k_tail=k_tail,
+            v_tail=v_tail)
+        return (greedy_tokens(logits), logits, *pool)
 
     def _verify(self, params, k_pages, v_pages, tables, lengths,
                 tokens):
@@ -338,21 +338,22 @@ class PagedSlotPool:
     def decode(self, params, tokens: np.ndarray, active: np.ndarray):
         """Advance every slot one position through the ONE jitted paged
         decode program (inactive rows neither write the pool nor
-        advance). Returns (n_slots, vocab) logits."""
+        advance). Returns each slot's greedy token (n_slots,) int32 and
+        the (n_slots, vocab) logits, both left on the device."""
         if self.quant_bits is None:
-            logits, self.k_pages, self.v_pages = self._decode_fn(
+            out, logits, self.k_pages, self.v_pages = self._decode_fn(
                 params, self.k_pages, self.v_pages,
                 jnp.array(self.tables), jnp.array(self.lengths),
                 jnp.asarray(tokens), jnp.asarray(active))
         else:
-            (logits, self.k_pages, self.v_pages, self.k_scales,
+            (out, logits, self.k_pages, self.v_pages, self.k_scales,
              self.v_scales, self.k_tail, self.v_tail) = self._decode_fn(
                 params, self.k_pages, self.v_pages, self.k_scales,
                 self.v_scales, self.k_tail, self.v_tail,
                 jnp.array(self.tables), jnp.array(self.lengths),
                 jnp.asarray(tokens), jnp.asarray(active))
         self.lengths[np.asarray(active)] += 1
-        return logits
+        return out, logits
 
     def ensure_spec_capacity(self, slot: int, n_new: int) -> None:
         """Grow ``slot``'s page table so the next ``n_new`` committed

@@ -1,0 +1,106 @@
+"""Where a served token is chosen (the engines share this).
+
+A decode program ends in the greedy token of every slot
+(``SlotPool`` / ``PagedSlotPool``), so a greedy row needs nothing more.
+A row that samples joins the rows of its setting: ONE program a distinct
+``(temperature, top_k, top_p)`` among the running rows picks all of
+them from the whole ``(n_slots, vocab)`` logits — each row with its own
+key, ``req.rngs[len(out)]``, so every request keeps ``generate()``'s
+split schedule and its tokens — and merges them into the greedy tokens
+on the device. Every shape is ``n_slots`` wide whatever the number of
+rows: nothing compiles as the batch breathes. The caller then reads the
+tokens of an iteration in one fetch.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..models.generate import _sample
+from .cache import CompileCounts
+from .types import Request
+
+#: the rows of one sampling setting, n_slots wide: each row's PRNG key
+#: (zeros where the row is not of the group) and the row mask
+Group = Tuple[np.ndarray, np.ndarray]
+
+
+def _program_name(prefix: str, sampler_key: tuple) -> str:
+    """The program's name on the profiler's device plane."""
+    return prefix + "_".join(str(v) for v in sampler_key)
+
+
+class RowSampler:
+    """The jitted samplers of one engine, by sampling setting."""
+
+    def __init__(self, n_slots: int, compiles: CompileCounts):
+        self.n_slots = n_slots
+        self.compiles = compiles
+        self.dispatches = 0          # batched sampler programs dispatched
+        self._one: Dict[tuple, callable] = {}
+        self._rows: Dict[tuple, callable] = {}
+
+    def first(self, req: Request, logits):
+        """Dispatch the request's sampler on ``logits`` (1, vocab), a
+        prefill's; returns the token still on the device, shape (1,) —
+        the caller fetches it, so that dispatch and wait can be told
+        apart."""
+        key = req.params.sampler_key
+        fn = self._one.get(key)
+        if fn is None:
+            t, k, p = key
+            compiles = self.compiles
+
+            def sample(lg, rng):
+                compiles.sample += 1               # trace-time only
+                return _sample(lg, rng, t, k, p)
+            sample.__name__ = _program_name("sample_", key)
+            fn = self._one[key] = jax.jit(sample)
+        return fn(logits, jnp.asarray(req.rngs[len(req.out_tokens)]))
+
+    def join(self, groups: Dict[tuple, Group], slot: int,
+             req: Request) -> None:
+        """File a running row under its sampling setting in ``groups``:
+        its key and its place in the row mask. A greedy row joins none:
+        it takes the decode program's own token, and its key stays on
+        the host."""
+        if req.params.temperature == 0.0:
+            return
+        group = groups.get(req.params.sampler_key)
+        if group is None:
+            group = groups[req.params.sampler_key] = (
+                np.zeros((self.n_slots, 2), np.uint32),
+                np.zeros(self.n_slots, bool))
+        keys, mask = group
+        keys[slot] = req.rngs[len(req.out_tokens)]
+        mask[slot] = True
+
+    def merge(self, tokens, logits, groups: Dict[tuple, Group]):
+        """``tokens`` (n_slots,) with every joined row's token replaced
+        by its sample from ``logits`` (n_slots, vocab): one program a
+        group, all on the device."""
+        for key, (keys, mask) in groups.items():
+            fn = self._rows.get(key)
+            if fn is None:
+                fn = self._rows[key] = self._build_rows(key)
+            tokens = fn(logits, keys, mask, tokens)
+            self.dispatches += 1
+        return tokens
+
+    def _build_rows(self, key: tuple):
+        t, k, p = key
+        compiles = self.compiles
+
+        def one_row(lg, rng):
+            # (1, vocab), as first() and generate() sample it
+            return _sample(lg[None], rng, t, k, p)[0]
+
+        def sample_rows(logits, keys, mask, tokens):
+            compiles.sample += 1                   # trace-time only
+            return jnp.where(mask, jax.vmap(one_row)(logits, keys), tokens)
+        sample_rows.__name__ = _program_name("sample_rows_", key)
+        return jax.jit(sample_rows)

@@ -111,12 +111,11 @@ class SpecState:
         toks[np.asarray(slots)] = cur_tokens
         drafts = np.zeros((len(slots), k), np.int32)
         for j in range(k + 1):
-            logits = self.pool.decode(self.cfg.draft_params,
+            nxt, _ = self.pool.decode(self.cfg.draft_params,
                                       jnp.asarray(toks),
                                       jnp.asarray(active))
             if j < k:
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-                drafts[:, j] = nxt[np.asarray(slots)]
+                drafts[:, j] = np.asarray(nxt)[np.asarray(slots)]
                 toks[np.asarray(slots)] = drafts[:, j]
         return drafts
 

@@ -120,7 +120,8 @@ def tiny_lm(**kw):
 @pytest.fixture(scope="module")
 def engine_run(tmp_path_factory):
     """A tiny paged engine serving three requests under a profiler
-    session: (spans by line, stats at the end)."""
+    session, two greedy ones of five tokens and a sampled one of three:
+    (spans by line, stats before and after)."""
     logdir = tmp_path_factory.mktemp("engine_profile")
     model = tiny_lm()
     params = model.init(jax.random.PRNGKey(0))
@@ -129,13 +130,15 @@ def engine_run(tmp_path_factory):
     with eng:
         # warm every program first: a compile inside the session would
         # only make the trace larger
-        eng.submit(np.arange(5, dtype=np.int32),
-                   SamplingParams(max_new_tokens=2)).result(timeout=300)
+        sampled = SamplingParams(max_new_tokens=3, temperature=0.7)
+        for sp in (SamplingParams(max_new_tokens=2), sampled):
+            eng.submit(np.arange(5, dtype=np.int32), sp).result(timeout=300)
         before = eng.stats()
         with profiler.trace(str(logdir)):
-            hs = [eng.submit(np.arange(3 + i, dtype=np.int32),
-                             SamplingParams(max_new_tokens=5))
-                  for i in range(3)]
+            hs = [eng.submit(np.arange(3 + i, dtype=np.int32), sp)
+                  for i, sp in enumerate((SamplingParams(max_new_tokens=5),
+                                          SamplingParams(max_new_tokens=5),
+                                          sampled))]
             for h in hs:
                 h.result(timeout=300)
         after = eng.stats()
@@ -152,8 +155,8 @@ def test_engine_spans_are_the_loops_tree(engine_run):
     assert {"serve.iter", "serve.sweep", "serve.admit",
             "serve.admit.prefill", "serve.admit.first_token",
             "serve.decode.capacity", "serve.decode.dispatch",
-            "serve.decode.rows", "serve.row.sample", "serve.row.fetch",
-            "serve.row.emit"} <= names
+            "serve.decode.rows", "serve.decode.sample", "serve.decode.fetch",
+            "serve.row.sample", "serve.row.fetch", "serve.row.emit"} <= names
     # the caller's thread holds serve.submit, not the engine's
     assert "serve.submit" not in names and len(named(spans, "serve.submit")) == 3
 
@@ -175,6 +178,8 @@ def test_engine_spans_are_the_loops_tree(engine_run):
             ("serve.admit.first_token", "serve.admit"),
             ("serve.decode.dispatch", "serve.iter"),
             ("serve.decode.rows", "serve.iter"),
+            ("serve.decode.sample", "serve.decode.rows"),
+            ("serve.decode.fetch", "serve.decode.rows"),
             ("serve.row.sample", "serve.decode.rows"),
             ("serve.row.fetch", "serve.decode.rows"),
             ("serve.row.emit", "serve.decode.rows")):
@@ -191,11 +196,38 @@ def test_engine_spans_are_the_loops_tree(engine_run):
 def test_engine_row_spans_count_the_rows_decoded(engine_run):
     spans, before, after = engine_run
     rows = after["rows_decoded"] - before["rows_decoded"]
-    assert rows == 3 * 4        # the first token of each comes from admit
+    assert rows == 4 + 4 + 2    # the first token of each comes from admit
     for name in ("serve.row.sample", "serve.row.fetch", "serve.row.emit"):
         assert len(named(spans, name)) == rows
     assert sum(s[3]["rows"] for s in named(spans, "serve.decode.rows")) == rows
     assert after["admitted"] - before["admitted"] == 3
+
+
+def test_engine_fetches_once_an_iteration_and_samples_by_setting(engine_run):
+    spans, before, after = engine_run
+    loops = {s[3]["iteration"]: s for s in named(spans, "serve.decode.rows")}
+    fetches = named(spans, "serve.decode.fetch")
+    # one read of the tokens a decode iteration, of all its rows
+    assert sorted(f[3]["iteration"] for f in fetches) == sorted(loops)
+    assert all(f[3]["rows"] == loops[f[3]["iteration"]][3]["rows"]
+               for f in fetches)
+    assert after["decode_fetches"] - before["decode_fetches"] == len(fetches)
+    # every row joins its group before the read and gets its token after
+    for name, after_fetch in (("serve.row.sample", False),
+                              ("serve.row.fetch", True),
+                              ("serve.row.emit", True)):
+        for f in fetches:
+            assert sum(r[3]["iteration"] == f[3]["iteration"]
+                       and (r[1] >= f[2] if after_fetch else r[2] <= f[1])
+                       for r in named(spans, name)) == f[3]["rows"], name
+    # a sampler is dispatched only while the sampled request has a row:
+    # its two decode iterations of the longest request's four
+    samples = named(spans, "serve.decode.sample")
+    assert len(loops) == 4 and len(samples) == 2
+    assert all(s[3]["groups"] == 1 for s in samples)
+    assert after["sample_dispatches"] - before["sample_dispatches"] == 2
+    fetch_of = {f[3]["iteration"]: f for f in fetches}
+    assert all(s[2] <= fetch_of[s[3]["iteration"]][1] for s in samples)
 
 
 def test_engine_host_ns_counters_nest(engine_run):
@@ -312,9 +344,22 @@ def test_serving_programs_name_their_layers_and_themselves():
     with eng:
         eng.submit(np.arange(11, dtype=np.int32), SamplingParams(
             max_new_tokens=2, temperature=0.7)).result(timeout=300)
+    # the greedy token of every slot is chosen inside the decode program
+    # (jnp.argmax is a call there: the scope is the call site's whole name,
+    # and XLA prefixes it to the inlined reduce, ".../sample/reduce")
+    assert any(n.endswith("/sample") for n in names)
+    # decode_step_device_ms finds the program by this
+    assert "decode" in pool._decode_fn.__wrapped__.__name__
     (prefill,) = pool._admit_fns.values()
     assert prefill.__wrapped__.__name__ == "prefill_b16"
-    (sampler,) = eng._samplers.values()
+    (sampler,) = eng._sampler._one.values()
     assert sampler.__wrapped__.__name__ == "sample_0.7_None_None"
     lowered = sampler.lower(jnp.zeros((1, 61)), jax.random.PRNGKey(0))
     assert any("/sample/" in n for n in op_names(lowered))
+    # the batched sampler: named apart from the decode program, and its
+    # work under the same scope (vmap wraps a scope's name: "vmap(sample)")
+    (rows,) = eng._sampler._rows.values()
+    assert rows.__wrapped__.__name__ == "sample_rows_0.7_None_None"
+    lowered = rows.lower(jnp.zeros((2, 61)), jnp.zeros((2, 2), jnp.uint32),
+                         jnp.ones(2, bool), jnp.zeros(2, jnp.int32))
+    assert any("/vmap(sample)/" in n for n in op_names(lowered))

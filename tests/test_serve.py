@@ -245,7 +245,9 @@ class TestEngine:
         st = eng.stats()
         assert st["decode_compiles"] == 1, st
         assert all(v == 1 for v in st["prefill_compiles"].values()), st
-        assert st["sample_compiles"] == 3, st
+        # a one-row program a setting at admission (3), and one
+        # n_slots-wide program a setting that samples, in decode (2)
+        assert st["sample_compiles"] == 3 + 2, st
         # continuous batching really happened: request 3 (queued beyond
         # the 3 slots) was admitted only after request 1's mid-stream
         # retirement freed one — while request 0 (30 tokens) was STILL
@@ -516,3 +518,176 @@ class TestEngine:
         assert [i for i, _ in seen] == list(range(6))
         np.testing.assert_array_equal(np.asarray([t for _, t in seen]),
                                       out)
+
+
+# ---------------------------------------------------------------------------
+# where a token is chosen: greedy inside the decode program, one batched
+# sampler a setting, one fetch an iteration (serve/sampling.py)
+# ---------------------------------------------------------------------------
+
+POOLS = [pytest.param(dict(paged=False), id="contiguous"),
+         pytest.param(dict(paged=True, page_len=8), id="paged")]
+SP_A = dict(temperature=0.7, top_k=8)
+SP_B = dict(temperature=0.9, top_p=0.9)
+
+
+def _serve_together(model, params, specs, n_slots, record=None, **pool_kw):
+    """Every request of ``specs`` ((prompt_len, SamplingParams) each)
+    queued BEFORE the loop starts, so that all are admitted in its first
+    iteration (``n_slots`` >= their number) in slot order and decode
+    side by side. Returns (prompts, keys, handles, stats)."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 61, (s,)).astype(np.int32)
+               for s, _ in specs]
+    keys = [jax.random.PRNGKey(300 + i) for i in range(len(specs))]
+    eng = InferenceEngine(model, params, EngineConfig(
+        n_slots=n_slots, max_len=MAX_LEN, **pool_kw))
+    if record is not None:
+        build = eng._sampler._build_rows
+
+        def recording(key):
+            fn = build(key)
+
+            def call(logits, keys_, mask, tokens):
+                record.append((key, np.array(keys_), np.array(mask)))
+                return fn(logits, keys_, mask, tokens)
+            return call
+        eng._sampler._build_rows = recording
+    handles = [eng.submit(p, sp, rng=k)
+               for p, (_, sp), k in zip(prompts, specs, keys)]
+    with eng:
+        for h in handles:
+            h.result(timeout=120)
+    return prompts, keys, handles, eng.stats()
+
+
+class TestRowSampling:
+    @pytest.mark.parametrize("pool_kw", POOLS)
+    def test_mixed_batch_bit_identical_one_fetch_an_iteration(self, pool_kw):
+        """Greedy rows and rows of two sampling settings in ONE batch:
+        every stream is generate()'s, token for token; the tokens of an
+        iteration come to the host in one read, and a sampler program
+        runs once a setting that has a row in the iteration. The two
+        short rows retire mid-batch (slots 1 and 3 of 0..4) and leave
+        their neighbours' streams alone."""
+        model = _lm()
+        params = model.init(jax.random.PRNGKey(0))
+        specs = [(5, SamplingParams(max_new_tokens=10)),
+                 (9, SamplingParams(max_new_tokens=4, **SP_B)),
+                 (3, SamplingParams(max_new_tokens=10, **SP_A)),
+                 (7, SamplingParams(max_new_tokens=4, **SP_B)),
+                 (12, SamplingParams(max_new_tokens=10, **SP_A))]
+        prompts, keys, handles, st = _serve_together(
+            model, params, specs, n_slots=5, **pool_kw)
+        for i, (h, (_, sp)) in enumerate(zip(handles, specs)):
+            np.testing.assert_array_equal(
+                h.result(), _standalone(model, params, prompts[i], sp,
+                                        keys[i]), err_msg=f"request {i}")
+        assert [h.metrics["admit_iteration"] for h in handles] == [1] * 5
+        # the first token of each comes from its admission: 9 decode
+        # iterations, setting A in all 9, setting B in the first 3
+        assert st["decode_fetches"] == 9, st
+        assert st["sample_dispatches"] == 9 + 3, st
+        assert st["rows_decoded"] == 3 * 9 + 2 * 3, st
+        assert st["decode_compiles"] == 1, st
+        # three settings admitted, two of them sample in decode
+        assert st["sample_compiles"] == 3 + 2, st
+
+    @pytest.mark.parametrize("pool_kw", POOLS)
+    def test_all_greedy_dispatches_no_sampler(self, pool_kw):
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        record = []
+        specs = [(4 + i, SamplingParams(max_new_tokens=3 + 2 * i))
+                 for i in range(3)]
+        _, _, handles, st = _serve_together(model, params, specs, 3,
+                                            record=record, **pool_kw)
+        assert [len(h.result()) for h in handles] == [3, 5, 7]
+        assert st["decode_fetches"] == 6, st      # the longest row's
+        assert st["sample_dispatches"] == 0 and record == [], st
+        assert st["sample_compiles"] == 1, st     # admission's, greedy
+
+    @pytest.mark.parametrize("rows", [1, 2, 4])
+    @pytest.mark.parametrize("pool_kw", POOLS)
+    def test_no_program_follows_the_rows(self, pool_kw, rows):
+        """One row, half the slots, all of them: one decode program,
+        one sampler program a setting at admission and one a sampling
+        setting in decode — every shape is n_slots wide."""
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        settings = [SP_A, SP_B, {}, SP_A][:rows]
+        specs = [(4 + i, SamplingParams(max_new_tokens=5, **kw))
+                 for i, kw in enumerate(settings)]
+        _, _, _, st = _serve_together(model, params, specs, 4, **pool_kw)
+        sampling = len({tuple(kw.items()) for kw in settings if kw})
+        assert st["decode_compiles"] == 1, st
+        assert st["sample_compiles"] == \
+            len({tuple(kw.items()) for kw in settings}) + sampling, st
+        assert st["decode_fetches"] == 4, st
+        assert st["sample_dispatches"] == 4 * sampling, st
+
+    @pytest.mark.parametrize("pool_kw", POOLS)
+    def test_only_rows_that_sample_upload_a_key(self, pool_kw):
+        """What each batched sampler was given: the mask holds exactly
+        the running rows of its setting, each with the key of its next
+        token (generate()'s split schedule); every other row's key,
+        the greedy rows' among them, is never uploaded (zeros)."""
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        record = []
+        specs = [(4, SamplingParams(max_new_tokens=4)),
+                 (5, SamplingParams(max_new_tokens=4, **SP_A)),
+                 (6, SamplingParams(max_new_tokens=3, **SP_B)),
+                 (7, SamplingParams(max_new_tokens=4, **SP_A))]
+        _, keys, _, st = _serve_together(model, params, specs, 4,
+                                         record=record, **pool_kw)
+        assert st["sample_dispatches"] == len(record) == 3 + 2
+        splits = [np.asarray(jax.random.split(k, sp.max_new_tokens))
+                  for k, (_, sp) in zip(keys, specs)]
+        seen = {}
+        for key, row_keys, mask in record:
+            step = seen[key] = seen.get(key, 0) + 1   # token index
+            want = [i for i, (_, sp) in enumerate(specs)
+                    if sp.sampler_key == key]
+            assert np.flatnonzero(mask).tolist() == want
+            for slot in range(4):
+                np.testing.assert_array_equal(
+                    row_keys[slot],
+                    splits[slot][step] if slot in want else 0)
+
+    @pytest.mark.parametrize("pool_kw", POOLS)
+    def test_failed_row_leaves_co_residents_alone(self, pool_kw):
+        """A row that misses its deadline mid-decode and a row whose
+        callback raises: the greedy and the sampled co-resident still
+        get generate()'s tokens."""
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(7)
+        eng = InferenceEngine(model, params, EngineConfig(
+            n_slots=4, max_len=128, **pool_kw))
+        sp_g = SamplingParams(max_new_tokens=12)
+        sp_s = SamplingParams(max_new_tokens=12, **SP_A)
+        prompts = [rng.integers(0, 61, (5 + i,)).astype(np.int32)
+                   for i in range(4)]
+        key = jax.random.PRNGKey(9)
+
+        def boom(tok, i):
+            raise RuntimeError("a client's callback")
+        with eng:
+            # warm every program: compile time must not eat the deadline
+            for sp in (sp_g, sp_s):
+                eng.submit(np.arange(4, dtype=np.int32), sp).result(
+                    timeout=60)
+            faults.install("delay@op=serve_step,call=3,ms=1200")
+            victim = eng.submit(prompts[0], SamplingParams(
+                max_new_tokens=100, deadline_ms=700.0))
+            hg = eng.submit(prompts[1], sp_g)
+            hs = eng.submit(prompts[2], sp_s, rng=key)
+            hb = eng.submit(prompts[3], sp_s, rng=key, on_token=boom)
+            with pytest.raises(RequestDeadlineExceeded) as ei:
+                victim.result(timeout=60)
+            assert ei.value.stage == "running"
+            outs = [h.result(timeout=60) for h in (hg, hs, hb)]
+        for out, p, sp in zip(outs, prompts[1:], (sp_g, sp_s, sp_s)):
+            np.testing.assert_array_equal(
+                out, _standalone(model, params, p, sp, key, max_len=128))

@@ -302,8 +302,8 @@ class TestDisaggEngine:
             pool = PagedSlotPool(model, 1, MAX_LEN, page_len=L,
                                  n_pages=8, prefix_share=False)
             pool.adopt(0, fr.length, fr.ks, fr.vs)
-            lg = pool.decode(params, np.asarray([prompt[-1]], np.int32),
-                             np.asarray([True]))
+            _, lg = pool.decode(params, np.asarray([prompt[-1]], np.int32),
+                                np.asarray([True]))
             out[bits] = np.asarray(lg)[0]
         assert np.abs(out[8] - out[None]).max() <= 0.05
 

@@ -84,8 +84,11 @@ def _greedy_run(model, params, pool, prompt, steps):
     for _ in range(steps):
         pool.ensure_decode_capacity(0)
         cur[0] = toks[-1]
-        lg = np.asarray(pool.decode(params, cur, active))[0].copy()
+        tok, lg = pool.decode(params, cur, active)
+        lg = np.asarray(lg)[0].copy()
         step_logits.append(lg)
+        # the program's own token is the argmax of the logits beside it
+        assert int(np.asarray(tok)[0]) == int(np.argmax(lg))
         toks.append(int(np.argmax(lg)))
     return toks, first, step_logits
 
@@ -235,6 +238,43 @@ class TestQuantPoolQuality:
                  / pq["bytes_per_resident_token"])
         assert ratio >= 3.5
 
+    @pytest.mark.parametrize("kv_dtype", ["q8", "q4"])
+    def test_engine_quant_mixed_settings_batch_equals_alone(self, kv_dtype):
+        """A quantized pool's decode program ends in the same greedy
+        tail, and its rows join the same batched samplers: greedy rows
+        and rows of two sampling settings side by side get, each, the
+        tokens the same engine gives the request alone (a quantized
+        stream is not generate()'s, but it is its own: no row's token
+        depends on who decodes beside it)."""
+        model = _lm()
+        params = model.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(2)
+        prompts = [rng.integers(0, 61, 5 + 3 * i).astype(np.int32)
+                   for i in range(4)]
+        sps = [SamplingParams(max_new_tokens=12, **kw) for kw in (
+            {}, dict(temperature=0.7, top_k=8),
+            dict(temperature=0.9, top_p=0.9), {})]
+        keys = [jax.random.PRNGKey(40 + i) for i in range(4)]
+        eng = InferenceEngine(model, params, EngineConfig(
+            paged=True, n_slots=4, max_len=MAX_LEN, page_len=L,
+            kv_dtype=kv_dtype, prefix_share=False))
+        with eng:
+            hs = [eng.submit(p, sp, rng=k)
+                  for p, sp, k in zip(prompts, sps, keys)]
+            together = [h.result(timeout=120) for h in hs]
+            s1 = eng.stats()
+            alone = [eng.submit(p, sp, rng=k).result(timeout=120)
+                     for p, sp, k in zip(prompts, sps, keys)]
+            s2 = eng.stats()
+        for i in range(4):
+            np.testing.assert_array_equal(together[i], alone[i],
+                                          err_msg=f"request {i}")
+        assert s2["decode_compiles"] == 1, s2
+        assert s2["sample_compiles"] == 3 + 2, s2
+        # alone: 11 decode iterations a request, a sampler in two of four
+        assert s2["decode_fetches"] - s1["decode_fetches"] == 4 * 11
+        assert s2["sample_dispatches"] - s1["sample_dispatches"] == 2 * 11
+
     @pytest.mark.parametrize("s", [13, 16])   # sub-page tail / aligned
     def test_resident_kv_error_within_half_scale(self, s):
         """Pool-level per-element bound: on a cold prefill (where the
@@ -345,8 +385,8 @@ class TestExtractAdopt:
         cur_d = np.zeros(2, np.int32)
         cur_s[0] = tok
         cur_d[1] = tok
-        lg_s = np.asarray(src.decode(params, cur_s, active_s))[0]
-        lg_d = np.asarray(dst.decode(params, cur_d, active_d))[1]
+        lg_s = np.asarray(src.decode(params, cur_s, active_s)[1])[0]
+        lg_d = np.asarray(dst.decode(params, cur_d, active_d)[1])[1]
         if kv_dtype == "f32":
             assert np.array_equal(lg_s, lg_d)
         else:

@@ -222,6 +222,39 @@ class TestPagedEngine:
         assert [h.metrics["prefix_hit_pages"] for h in hs] == [0, 2, 2]
         assert [h.metrics["prefill_tokens_saved"] for h in hs] == [0, 16, 16]
 
+    def test_shared_prefix_rows_of_mixed_settings(self):
+        """Rows that share prefix pages and differ in sampling: a
+        greedy row, two of one sampling setting and one of another
+        decode side by side — every stream is generate()'s, with one
+        token read an iteration and one sampler program a setting."""
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(13)
+        pfx = rng.integers(0, 61, (16,)).astype(np.int32)   # 2 full pages
+        prompts = [np.concatenate(
+            [pfx, rng.integers(0, 61, (3 + i,))]).astype(np.int32)
+            for i in range(4)]
+        a, b = dict(temperature=0.7, top_k=8), dict(temperature=0.9,
+                                                    top_p=0.9)
+        sps = [SamplingParams(max_new_tokens=6, **kw)
+               for kw in ({}, a, b, a)]
+        keys = [jax.random.PRNGKey(200 + i) for i in range(4)]
+        eng = _paged_engine(model, params, n_slots=4)
+        hs = [eng.submit(p, sp, rng=k)      # queued before the loop
+              for p, sp, k in zip(prompts, sps, keys)]   # starts: one
+        with eng:                                        # admission pass
+            outs = [h.result(timeout=120) for h in hs]
+        for i in range(4):
+            np.testing.assert_array_equal(
+                outs[i], _standalone(model, params, prompts[i], sps[i],
+                                     keys[i]), err_msg=f"request {i}")
+        st = eng.stats()
+        assert [h.metrics["prefix_hit_pages"] for h in hs] == [0, 2, 2, 2]
+        assert st["decode_fetches"] == 5, st
+        assert st["sample_dispatches"] == 5 * 2, st
+        assert st["decode_compiles"] == 1, st
+        assert st["sample_compiles"] == 3 + 2, st
+
     # slow tier: the staggered 2-layer wide mix (five standalone
     # generate compiles); the contract kernel above stays tier-1 and
     # serve_bench --smoke re-asserts it in CI on every push
@@ -418,12 +451,17 @@ class TestPagedEngine:
         assert eng.pool.pool.evictions > 0
         assert eng.pool.pool.live_pages() == 0
 
-    def test_chaos_pool_exhaustion_mid_decode_typed_victim(self):
+    @pytest.mark.parametrize("co_resident", [
+        pytest.param({}, id="greedy"),
+        pytest.param(dict(temperature=0.7, top_k=8), id="sampled")])
+    def test_chaos_pool_exhaustion_mid_decode_typed_victim(self,
+                                                           co_resident):
         """THE chaos satellite: every page held by a live reader when a
         slot's decode crosses a page boundary — the victim fails with a
         typed, attributed PagePoolExhausted (request + iteration) while
-        the co-resident stream is bit-identical to generate(), and the
-        page-op fault grammar demonstrably fired."""
+        the co-resident stream (greedy: the decode program's own
+        tokens; sampled: its group's) is bit-identical to generate(),
+        and the page-op fault grammar demonstrably fired."""
         model = _lm1()
         params = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(8)
@@ -433,7 +471,8 @@ class TestPagedEngine:
         a = rng.integers(0, 61, (4,)).astype(np.int32)   # 1 page
         b = rng.integers(0, 61, (8,)).astype(np.int32)   # 2 pages
         ka, kb = jax.random.PRNGKey(1), jax.random.PRNGKey(2)
-        sp_a = SamplingParams(max_new_tokens=4)   # grows to page 1, stops
+        # grows to page 1, stops
+        sp_a = SamplingParams(max_new_tokens=4, **co_resident)
         sp_b = SamplingParams(max_new_tokens=6)   # needs page 2 mid-decode
         with eng:
             ha = eng.submit(a, sp_a, rng=ka)

@@ -231,34 +231,46 @@ class TestGreedyContract:
         assert d["spec"]["verify_compiles"] == {4: 1}
         assert d["prefill_compiles"] == {}     # the split held
 
-    def test_mixed_spec_and_sampled_batch(self):
-        """Spec (greedy) and non-spec (sampled) requests share the
-        batch: the sampled stream is bit-identical to its standalone
-        reference — speculation next door is invisible."""
+    @pytest.mark.parametrize("pool_kw", [
+        pytest.param(dict(paged=False), id="contiguous"),
+        pytest.param(dict(paged=True, page_len=L), id="paged")])
+    def test_mixed_spec_and_sampled_batch(self, pool_kw):
+        """A speculating (greedy) row and sampled rows of two settings
+        share the batch: every stream is bit-identical to its
+        standalone reference — speculation next door is invisible, and
+        the rows that do not speculate get their tokens from the one
+        decode program and one sampler a setting, in one read an
+        iteration."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
         dm = _draft()
         dp = dm.init(jax.random.PRNGKey(1))
         prompts = _prompts()
         n = 10
-        ref_g = np.asarray(generate(model, params,
-                                    jnp.asarray(prompts[0][None]),
-                                    n)[0])
-        sp_s = SamplingParams(max_new_tokens=n, temperature=0.7,
-                              top_k=8)
-        key = jax.random.PRNGKey(5)
-        ref_s = _standalone(model, params, prompts[1], sp_s, key)
-        eng = InferenceEngine(model, params, _spec_cfg(dm, dp))
+        sps = [SamplingParams(max_new_tokens=n),
+               SamplingParams(max_new_tokens=n, temperature=0.7, top_k=8),
+               SamplingParams(max_new_tokens=n, temperature=0.9,
+                              top_p=0.9),
+               SamplingParams(max_new_tokens=n, temperature=0.7, top_k=8)]
+        keys = [jax.random.PRNGKey(5 + i) for i in range(4)]
+        refs = [_standalone(model, params, p, sp, k)
+                for p, sp, k in zip(prompts, sps, keys)]
+        eng = InferenceEngine(model, params, _spec_cfg(dm, dp, **pool_kw))
+        hs = [eng.submit(p, sp, rng=k)      # queued before the loop
+              for p, sp, k in zip(prompts, sps, keys)]   # starts
         with eng:
-            hg = eng.submit(prompts[0],
-                            SamplingParams(max_new_tokens=n))
-            hs = eng.submit(prompts[1], sp_s, rng=key)
-            out_g = np.asarray(hg.result(timeout=120))
-            out_s = np.asarray(hs.result(timeout=120))
-        np.testing.assert_array_equal(out_g, ref_g)
-        np.testing.assert_array_equal(out_s, ref_s)
-        st = eng.stats()["spec"]
-        assert st["proposed"] > 0              # the greedy row DID spec
+            outs = [np.asarray(h.result(timeout=120)) for h in hs]
+        for i in range(4):
+            np.testing.assert_array_equal(outs[i], refs[i],
+                                          err_msg=f"request {i}")
+        st = eng.stats()
+        assert st["spec"]["proposed"] > 0      # the greedy row DID spec
+        # the three rows that sample: 9 decode iterations, two settings
+        # in each; the speculating row's tokens come from its verify
+        assert st["decode_fetches"] == n - 1, st
+        assert st["sample_dispatches"] == 2 * (n - 1), st
+        assert st["rows_decoded"] == 3 * (n - 1), st
+        assert st["decode_compiles"] == 1, st
 
 
 # ---------------------------------------------------------------------------
