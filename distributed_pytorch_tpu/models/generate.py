@@ -35,7 +35,9 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.attention import dense_attention
+from ..nn.attention import dense_attention, prefix_tail_attention
+from ..nn.attention import gather_pages as _gather_pages
+from ..nn.paged import DecodeCtx, PrefillCtx
 from ..ops.decode_attention import (blockwise_decode_attention,
                                     dense_decode_attention,
                                     paged_decode_attention)
@@ -321,25 +323,23 @@ def decode_step_slots(model: TransformerLM, params: Params, ks, vs,
     return model.project_vocab(params, x)[:, 0], new_k, new_v
 
 
-def _gather_pages(pool, tables):
-    """Gather a slot batch's pages into contiguous rows.
-
-    pool: (n_pages, Hkv, page_len, Dh); tables: (B, P) int32 page ids
-    (unallocated entries may hold any valid id — the caller's position
-    mask hides them). Returns (B, Hkv, P*page_len, Dh)."""
-    g = pool[tables]                       # (B, P, Hkv, page_len, Dh)
-    b, p, h, l, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, p * l, d)
-
-
 def decode_step_slots_paged(model: TransformerLM, params: Params,
                             k_pages, v_pages, tables, lengths, tokens,
                             active, *, page_len: int,
                             blockwise: bool = True, kv_bits=None,
                             k_scales=None, v_scales=None,
-                            k_tail=None, v_tail=None
+                            k_tail=None, v_tail=None, moe_stats=None
                             ) -> Tuple[jnp.ndarray, list, list]:
     """One decode step over a PAGED slot pool (``serve/pages/``).
+
+    **The block owns its page layout.** On the exact path each layer's
+    arrays go to the block's own ``decode_paged`` (``nn/paged.py``):
+    multi-head attention keeps ``k_pages[i]`` and ``v_pages[i]`` as
+    described below; latent attention keeps ONE array a layer, the
+    entries ``[c | k_r]``, in ``k_pages[i]`` with ``v_pages`` an empty
+    list, and attends in its absorbed form. Under hyper-connections the
+    residual streams travel as (B, 1, streams, D). ``moe_stats``: a list
+    that every expert layer appends its counts (3,) to.
 
     The paged counterpart of :func:`decode_step_slots`: instead of each
     slot owning a contiguous (max_len) cache row, K/V live in a shared
@@ -389,12 +389,15 @@ def decode_step_slots_paged(model: TransformerLM, params: Params,
     if kv_bits is not None and not blockwise:
         raise ValueError("quantized paged KV (kv_bits) requires the "
                          "blockwise decode path")
+    if kv_bits is not None:
+        refuse_latent(model, "quantized pages (kv_dtype q8/q4)")
     idx = lengths
     n_pages = k_pages[0].shape[0]
     width = tables.shape[1] * page_len
     x = model.tok.apply(params["tok"], tokens[:, None])       # (B,1,D)
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], idx[:, None])
+    x = model.streams_in(x)
     scale = 1.0 / math.sqrt(model.dim // model.n_heads)
     pos_mask = jnp.arange(width)[None, :] <= idx[:, None]
     write_mask = (jnp.arange(width)[None, :]
@@ -419,75 +422,83 @@ def decode_step_slots_paged(model: TransformerLM, params: Params,
 
     new_kp, new_vp = [], []
     new_ks, new_vs, new_kt, new_vt = [], [], [], []
+    ctx = DecodeCtx(tables=tables, idx=idx, dest=dest, wo=wo, active=active,
+                    pos_mask=pos_mask, write_mask=write_mask,
+                    page_len=page_len, blockwise=blockwise,
+                    moe_stats=moe_stats)
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
             p = params["blocks"][i]
+            if kv_bits is None:
+                x, pages = blk.decode_paged(
+                    p, x, _layer_pages(k_pages, v_pages, i), ctx)
+                new_kp.append(pages[0])
+                new_vp.extend(pages[1:])
+                continue
             hq, hk, hv = blk.attn.project_qkv(p["attn"],
                                               blk.ln1.apply(p["ln1"], x))
             hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
             with jax.named_scope("page_write"):
-                if kv_bits is None:
-                    kp = k_pages[i].at[dest, :, wo].set(
-                        hk[:, :, 0, :].astype(k_pages[i].dtype), mode="drop")
-                    vp = v_pages[i].at[dest, :, wo].set(
-                        hv[:, :, 0, :].astype(v_pages[i].dtype), mode="drop")
-                else:
-                    kt = k_tail[i].at[dest_t, :, wo].set(
-                        hk[:, :, 0, :].astype(jnp.float32), mode="drop")
-                    vt = v_tail[i].at[dest_t, :, wo].set(
-                        hv[:, :, 0, :].astype(jnp.float32), mode="drop")
-                    qk, sk = quantize_page_blocks(kt, kv_bits)  # (B,Hkv,L,Dh)
-                    qv, sv = quantize_page_blocks(vt, kv_bits)
-                    if kv_bits == 4:
-                        qk, qv = pack_page_nibbles(qk), pack_page_nibbles(qv)
-                    kp = k_pages[i].at[dest_q].set(qk, mode="drop")
-                    vp = v_pages[i].at[dest_q].set(qv, mode="drop")
-                    ks_i = k_scales[i].at[dest_q].set(sk, mode="drop")
-                    vs_i = v_scales[i].at[dest_q].set(sv, mode="drop")
-                    new_ks.append(ks_i)
-                    new_vs.append(vs_i)
-                    new_kt.append(kt)
-                    new_vt.append(vt)
+                kt = k_tail[i].at[dest_t, :, wo].set(
+                    hk[:, :, 0, :].astype(jnp.float32), mode="drop")
+                vt = v_tail[i].at[dest_t, :, wo].set(
+                    hv[:, :, 0, :].astype(jnp.float32), mode="drop")
+                qk, sk = quantize_page_blocks(kt, kv_bits)  # (B,Hkv,L,Dh)
+                qv, sv = quantize_page_blocks(vt, kv_bits)
+                if kv_bits == 4:
+                    qk, qv = pack_page_nibbles(qk), pack_page_nibbles(qv)
+                kp = k_pages[i].at[dest_q].set(qk, mode="drop")
+                vp = v_pages[i].at[dest_q].set(qv, mode="drop")
+                ks_i = k_scales[i].at[dest_q].set(sk, mode="drop")
+                vs_i = v_scales[i].at[dest_q].set(sv, mode="drop")
+                new_ks.append(ks_i)
+                new_vs.append(vs_i)
+                new_kt.append(kt)
+                new_vt.append(vt)
             new_kp.append(kp)
             new_vp.append(vp)
-            if kv_bits is not None:
-                o = paged_decode_attention(hq, kp, vp, tables, idx,
-                                           hk, hv, scale=scale,
-                                           page_len=page_len,
-                                           k_scales=ks_i, v_scales=vs_i,
-                                           k_tail=kt, v_tail=vt)
-            elif blockwise:
-                # the page gather lives inside the block loop; hk/hv are
-                # re-selected at the write position per block — identity
-                # for active rows (already scattered), and gives inactive
-                # rows decode_step_slots' exact value semantics (their
-                # discarded logits still see "their" key)
-                o = paged_decode_attention(hq, kp, vp, tables, idx,
-                                           hk, hv, scale=scale,
-                                           page_len=page_len)
-            else:
-                # logical rows: gather the updated pool, then re-select the
-                # new key at the write position
-                k = jnp.where(write_mask, hk.astype(kp.dtype),
-                              _gather_pages(kp, tables))
-                v = jnp.where(write_mask, hv.astype(vp.dtype),
-                              _gather_pages(vp, tables))
-                o = dense_decode_attention(hq, k, v, pos_mask, scale=scale)
+            o = paged_decode_attention(hq, kp, vp, tables, idx,
+                                       hk, hv, scale=scale,
+                                       page_len=page_len,
+                                       k_scales=ks_i, v_scales=vs_i,
+                                       k_tail=kt, v_tail=vt)
             x = x + blk.attn.project_out(p["attn"], o)
             x = x + blk.mlp(p, x)
 
-    x = model.ln_f.apply(params["ln_f"], x)
+    x = model.ln_f.apply(params["ln_f"], model.streams_out(x))
     logits = model.project_vocab(params, x)[:, 0]
     if kv_bits is None:
         return logits, new_kp, new_vp
     return logits, new_kp, new_vp, new_ks, new_vs, new_kt, new_vt
 
 
+def _layer_pages(k_pages, v_pages, i):
+    """Layer ``i``'s page arrays as its block keeps them: (K, V), or the
+    one latent array where ``v_pages`` is empty."""
+    return (k_pages[i], v_pages[i]) if v_pages else (k_pages[i],)
+
+
+def refuse_latent(model, what: str):
+    """Latent blocks keep one exact array a layer; what has not been
+    carried over to that layout says so by name."""
+    if getattr(model, "attention", "mha") == "latent":
+        raise LatentPagesUnsupported(
+            f"{what} cannot hold latent (MLA) pages yet: a latent block "
+            "keeps one array of [c | k_r] entries a layer, served only by "
+            "the exact paged pool (InferenceEngine(paged=True), "
+            "kv_dtype='f32')")
+
+
+class LatentPagesUnsupported(NotImplementedError):
+    """A serving path that has no layout for latent-attention blocks."""
+
+
 def prefill_partial_paged(model: TransformerLM, params: Params,
                           k_pages, v_pages, table_row, tokens, offset,
                           true_len, *, page_len: int, kv_bits=None,
                           k_scales=None, v_scales=None,
-                          k_tail=None, v_tail=None, slot=None
+                          k_tail=None, v_tail=None, slot=None,
+                          moe_stats=None
                           ) -> Tuple[jnp.ndarray, list, list]:
     """Prefill the TAIL of a prompt into pool pages, attending over a
     page-resident shared prefix (``serve/pages/``).
@@ -512,6 +523,12 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
     Returns ``(logits (1, vocab) at the last real position,
     new_k_pages, new_v_pages)``.
 
+    On the exact path each layer's arrays go to the block's own
+    ``prefill_paged`` (``nn/paged.py``; a latent block keeps one array a
+    layer in ``k_pages`` with ``v_pages`` empty, see
+    :func:`decode_step_slots_paged`); the tail's pad rows are left out
+    of an expert layer's dispatch.
+
     **Quantized resident pool** (``kv_bits`` = 8 | 4; docs/serving.md):
     tail K/V that COMPLETE a page (a full ``page_len`` chunk of the
     tail within ``true_len``) are quantized once — from exact f32, on
@@ -533,6 +550,7 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
     x = model.tok.apply(params["tok"], tokens)
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], positions)
+    x = model.streams_in(x)
     scale = 1.0 / math.sqrt(model.dim // model.n_heads)
     # attention mask over [prefix pages | tail]: prefix columns valid
     # below offset, tail columns causal (pad tail is causally inert)
@@ -548,6 +566,7 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
     dest_off = positions % page_len
     dest = jnp.where(jnp.arange(s) < true_len, dest_page, n_pages)
     if kv_bits is not None:
+        refuse_latent(model, "quantized pages (kv_dtype q8/q4)")
         from ..ops.quant import (dequantize_page_blocks,
                                  page_block_map, pack_page_nibbles,
                                  quantize_page_blocks,
@@ -569,98 +588,80 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
 
     new_kp, new_vp = [], []
     new_ks, new_vs, new_kt, new_vt = [], [], [], []
+    ctx = PrefillCtx(table_row=table_row, positions=positions, offset=offset,
+                     dest=dest, dest_off=dest_off, mask=mask,
+                     row_mask=jnp.arange(s) < true_len, width=width,
+                     moe_stats=moe_stats)
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
             p = params["blocks"][i]
+            if kv_bits is None:
+                x, pages = blk.prefill_paged(
+                    p, x, _layer_pages(k_pages, v_pages, i), ctx)
+                new_kp.append(pages[0])
+                new_vp.extend(pages[1:])
+                continue
             hq, hk, hv = blk.attn.project_qkv(p["attn"],
                                               blk.ln1.apply(p["ln1"], x))
             hq, hk = blk.attn.maybe_rope(hq, hk, positions)
             with jax.named_scope("page_write"):
-                if kv_bits is None:
-                    kp = k_pages[i].at[dest, :, dest_off].set(
-                        jnp.moveaxis(hk[0], 1, 0).astype(k_pages[i].dtype),
-                        mode="drop")
-                    vp = v_pages[i].at[dest, :, dest_off].set(
-                        jnp.moveaxis(hv[0], 1, 0).astype(v_pages[i].dtype),
-                        mode="drop")
-                else:
-                    kp, vp = k_pages[i], v_pages[i]
-                    ks_i, vs_i = k_scales[i], v_scales[i]
-                    for c in range(n_chunks):
-                        lo = c * page_len
-                        ck = hk[0, :, lo:lo + page_len, :].astype(jnp.float32)
-                        cv = hv[0, :, lo:lo + page_len, :].astype(jnp.float32)
-                        qk, sk = quantize_page_blocks(ck, kv_bits)
-                        qv, sv = quantize_page_blocks(cv, kv_bits)
-                        if kv_bits == 4:
-                            qk, qv = (pack_page_nibbles(qk),
-                                      pack_page_nibbles(qv))
-                        # incomplete chunks route out of bounds and drop; the
-                        # page index gather clamps harmlessly for them
-                        comp = (lo + page_len) <= true_len
-                        dpi = jnp.where(
-                            comp,
-                            table_row[jnp.clip(offset // page_len + c, 0,
-                                               table_row.shape[0] - 1)],
-                            n_pages)
-                        kp = kp.at[dpi].set(qk, mode="drop")
-                        vp = vp.at[dpi].set(qv, mode="drop")
-                        ks_i = ks_i.at[dpi].set(sk, mode="drop")
-                        vs_i = vs_i.at[dpi].set(sv, mode="drop")
-                    tk = jnp.where(t_valid,
-                                   jnp.take(hk[0], t_src, axis=1), 0.0) \
-                        .astype(jnp.float32)
-                    tv = jnp.where(t_valid,
-                                   jnp.take(hv[0], t_src, axis=1), 0.0) \
-                        .astype(jnp.float32)
-                    kt = k_tail[i].at[slot].set(tk)
-                    vt = v_tail[i].at[slot].set(tv)
-                    new_ks.append(ks_i)
-                    new_vs.append(vs_i)
-                    new_kt.append(kt)
-                    new_vt.append(vt)
+                kp, vp = k_pages[i], v_pages[i]
+                ks_i, vs_i = k_scales[i], v_scales[i]
+                for c in range(n_chunks):
+                    lo = c * page_len
+                    ck = hk[0, :, lo:lo + page_len, :].astype(jnp.float32)
+                    cv = hv[0, :, lo:lo + page_len, :].astype(jnp.float32)
+                    qk, sk = quantize_page_blocks(ck, kv_bits)
+                    qv, sv = quantize_page_blocks(cv, kv_bits)
+                    if kv_bits == 4:
+                        qk, qv = (pack_page_nibbles(qk),
+                                  pack_page_nibbles(qv))
+                    # incomplete chunks route out of bounds and drop; the
+                    # page index gather clamps harmlessly for them
+                    comp = (lo + page_len) <= true_len
+                    dpi = jnp.where(
+                        comp,
+                        table_row[jnp.clip(offset // page_len + c, 0,
+                                           table_row.shape[0] - 1)],
+                        n_pages)
+                    kp = kp.at[dpi].set(qk, mode="drop")
+                    vp = vp.at[dpi].set(qv, mode="drop")
+                    ks_i = ks_i.at[dpi].set(sk, mode="drop")
+                    vs_i = vs_i.at[dpi].set(sv, mode="drop")
+                tk = jnp.where(t_valid,
+                               jnp.take(hk[0], t_src, axis=1), 0.0) \
+                    .astype(jnp.float32)
+                tv = jnp.where(t_valid,
+                               jnp.take(hv[0], t_src, axis=1), 0.0) \
+                    .astype(jnp.float32)
+                kt = k_tail[i].at[slot].set(tk)
+                vt = v_tail[i].at[slot].set(tv)
+                new_ks.append(ks_i)
+                new_vs.append(vs_i)
+                new_kt.append(kt)
+                new_vt.append(vt)
             new_kp.append(kp)
             new_vp.append(vp)
-            # prefix keys from the (updated) pool; tail keys inline — the
-            # tail pages were just written, but using the in-register tail
-            # avoids a second gather and keeps the math identical to
-            # prefill_partial's [real | pad] layout
-            if kv_bits is not None:
-                # dequantize the gathered prefix pages (the mask exposes
-                # only positions < offset — complete, quantized, shared);
-                # the tail attends in-register EXACT, so cold admissions
-                # (offset == 0) see zero quantization error
-                gk, gv = kp[table_row], vp[table_row]
-                if kv_bits == 4:
-                    gk, gv = unpack_page_nibbles(gk), unpack_page_nibbles(gv)
-                gk = dequantize_page_blocks(gk, ks_i[table_row], bmap)
-                gv = dequantize_page_blocks(gv, vs_i[table_row], bmap)
-                pref_k = gk.transpose(1, 0, 2, 3) \
-                    .reshape(1, -1, width, gk.shape[-1]).astype(hk.dtype)
-                pref_v = gv.transpose(1, 0, 2, 3) \
-                    .reshape(1, -1, width, gv.shape[-1]).astype(hv.dtype)
-            else:
-                pref_k = kp[table_row].transpose(1, 0, 2, 3) \
-                    .reshape(1, -1, width, kp.shape[-1]).astype(hk.dtype)
-                pref_v = vp[table_row].transpose(1, 0, 2, 3) \
-                    .reshape(1, -1, width, vp.shape[-1]).astype(hv.dtype)
-            k_all = jnp.concatenate([pref_k, hk], axis=2)   # (1,Hkv,W+S,Dh)
-            v_all = jnp.concatenate([pref_v, hv], axis=2)
-            bq, hh, _, dd = hq.shape
-            hkv = k_all.shape[1]
-            hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
-            logits = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
-                jnp.float32) * scale                     # (1,Hkv,g,S,W+S)
-            logits = jnp.where(mask[None, None, None, :, :], logits,
-                               -jnp.inf)
-            probs = jax.nn.softmax(logits, axis=-1).astype(v_all.dtype)
-            o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
-                .reshape(bq, hh, s, dd)
+            # dequantize the gathered prefix pages (the mask exposes
+            # only positions < offset — complete, quantized, shared);
+            # the tail attends in-register EXACT, so cold admissions
+            # (offset == 0) see zero quantization error
+            gk, gv = kp[table_row], vp[table_row]
+            if kv_bits == 4:
+                gk, gv = unpack_page_nibbles(gk), unpack_page_nibbles(gv)
+            gk = dequantize_page_blocks(gk, ks_i[table_row], bmap)
+            gv = dequantize_page_blocks(gv, vs_i[table_row], bmap)
+            pref_k = gk.transpose(1, 0, 2, 3) \
+                .reshape(1, -1, width, gk.shape[-1]).astype(hk.dtype)
+            pref_v = gv.transpose(1, 0, 2, 3) \
+                .reshape(1, -1, width, gv.shape[-1]).astype(hv.dtype)
+            o = prefix_tail_attention(hq, hk, hv, pref_k, pref_v, mask,
+                                      scale)
             x = x + blk.attn.project_out(p["attn"], o)
             x = x + blk.mlp(p, x)
 
     x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)
-    x_last = model.ln_f.apply(params["ln_f"], x_last)
+    x_last = model.ln_f.apply(params["ln_f"], model.streams_out(x_last))
     logits = model.project_vocab(params, x_last)[:, 0]
     if kv_bits is None:
         return logits, new_kp, new_vp

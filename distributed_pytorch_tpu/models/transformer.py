@@ -11,13 +11,16 @@ shape.
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Union
+from typing import Callable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
-from ..nn.attention import TransformerBlock
-from ..nn.core import Embedding, LayerNorm, Linear, Module, Params
+from ..nn.attention import MultiHeadAttention, TransformerBlock
+from ..nn.block import Block
+from ..nn.core import (Embedding, GatedMLP, LayerNorm, Linear, Module, Params,
+                       RMSNorm)
+from ..nn.latent import LatentAttention
 
 #: Named per-layer rematerialization policies (docs/compute.md).
 #: ``none``  — save every activation (fastest step, most HBM);
@@ -71,7 +74,25 @@ def apply_remat_policy(fn: Callable, policy: str) -> Callable:
 
 class TransformerLM(Module):
     """Decoder-only causal LM: tok+pos embed → N pre-norm blocks → LN →
-    vocab projection."""
+    vocab projection.
+
+    By default every block is the one fixed ``TransformerBlock``
+    (LayerNorm, multi-head attention, a GELU MLP of ``mlp_ratio * dim``).
+    Any of ``block_kinds``, ``attention="latent"``, ``norm="rms"`` or
+    ``hyper_connections`` builds the blocks from parts instead
+    (``nn/block.py``):
+
+    - ``block_kinds``: one of ``"dense"`` (a gated SiLU MLP of
+      ``ffn_dim``) or ``"moe"`` (a dropless expert layer,
+      ``parallel.moe.DroplessMoE(dim, **moe)``) a layer; default all dense;
+    - ``attention``: ``"mha"`` or ``"latent"``
+      (``nn.latent.LatentAttention(dim, n_heads, **latent)``, which brings
+      its own rotary part: ``pos`` must not be ``"learned"``);
+    - ``norm``: ``"layer"`` or ``"rms"``, with ``norm_eps``;
+    - ``hyper_connections``: the number of parallel residual streams
+      (``nn/hyper.py``; 0 = the plain residual sum), ``hc`` their
+      keyword arguments. The embedding is copied into every stream and
+      the streams are summed before the final norm."""
 
     def __init__(self, vocab: int = 256, dim: int = 128, n_layers: int = 2,
                  n_heads: int = 4, max_seq: int = 512, mlp_ratio: int = 4,
@@ -80,9 +101,21 @@ class TransformerLM(Module):
                  tie_embeddings: bool = False,
                  attn_fn: Optional[Callable] = None,
                  remat: Union[bool, str, None] = False,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32,
+                 block_kinds: Optional[Sequence[str]] = None,
+                 attention: str = "mha", latent: Optional[dict] = None,
+                 norm: str = "layer", norm_eps: Optional[float] = None,
+                 ffn_dim: Optional[int] = None, moe: Optional[dict] = None,
+                 hyper_connections: int = 0, hc: Optional[dict] = None):
         if pos not in ("learned", "rope", "none"):
             raise ValueError(f"pos must be learned|rope|none, got {pos!r}")
+        if attention not in ("mha", "latent"):
+            raise ValueError(f"attention must be mha|latent, got {attention!r}")
+        if norm not in ("layer", "rms"):
+            raise ValueError(f"norm must be layer|rms, got {norm!r}")
+        if attention == "latent" and pos == "learned":
+            raise ValueError("latent attention rotates a part of its own "
+                             "keys: pos must be 'rope' or 'none'")
         self.vocab = vocab
         self.dim = dim
         self.n_layers = n_layers
@@ -107,14 +140,56 @@ class TransformerLM(Module):
         self.tok = Embedding(vocab, dim, std=dim ** -0.5, dtype=dtype)
         self.pos = Embedding(max_seq, dim, std=dim ** -0.5, dtype=dtype) \
             if pos == "learned" else None
-        self.blocks = [
-            TransformerBlock(dim, n_heads, mlp_ratio, causal=True,
-                             dropout=dropout, n_kv_heads=n_kv_heads,
-                             rope=(pos == "rope"), rope_base=rope_base,
-                             attn_fn=attn_fn, dtype=dtype)
-            for _ in range(n_layers)
-        ]
-        self.ln_f = LayerNorm(dim, dtype=dtype, scope="ln_f")
+        self.attention = attention
+        self.streams = int(hyper_connections)
+
+        def make_norm(scope="norm"):
+            kw = {} if norm_eps is None else {"eps": norm_eps}
+            cls = RMSNorm if norm == "rms" else LayerNorm
+            return cls(dim, dtype=dtype, scope=scope, **kw)
+
+        from_parts = (block_kinds is not None or attention != "mha"
+                      or norm != "layer" or self.streams > 0)
+        if not from_parts:
+            self.blocks = [
+                TransformerBlock(dim, n_heads, mlp_ratio, causal=True,
+                                 dropout=dropout, n_kv_heads=n_kv_heads,
+                                 rope=(pos == "rope"), rope_base=rope_base,
+                                 attn_fn=attn_fn, dtype=dtype)
+                for _ in range(n_layers)
+            ]
+        else:
+            kinds = tuple(block_kinds) if block_kinds is not None \
+                else ("dense",) * n_layers
+            if len(kinds) != n_layers or set(kinds) - {"dense", "moe"}:
+                raise ValueError(f"block_kinds must name dense|moe for each "
+                                 f"of {n_layers} layers, got {kinds}")
+            if "moe" in kinds and not moe:
+                raise ValueError("block_kinds names an expert layer: give "
+                                 "moe=dict(n_routed=..., width=..., top_k=...)")
+
+            def make_attn():
+                if attention == "latent":
+                    return LatentAttention(
+                        dim, n_heads, rope_base=rope_base, attn_fn=attn_fn,
+                        dtype=dtype, **(latent or {}))
+                return MultiHeadAttention(
+                    dim, n_heads, causal=True, n_kv_heads=n_kv_heads,
+                    rope=(pos == "rope"), rope_base=rope_base,
+                    attn_fn=attn_fn, dtype=dtype)
+
+            def make_ffn(kind):
+                if kind == "moe":
+                    from ..parallel.moe import DroplessMoE
+                    return DroplessMoE(dim, dtype=dtype, **moe)
+                return GatedMLP(dim, ffn_dim or mlp_ratio * dim, dtype=dtype)
+
+            self.blocks = [
+                Block(dim, norm1=make_norm(), attn=make_attn(),
+                      norm2=make_norm(), ffn=make_ffn(kind),
+                      streams=self.streams, hc=hc)
+                for kind in kinds]
+        self.ln_f = make_norm(scope="ln_f")
         # tied embeddings (the GPT-2 recipe): the vocab projection reuses
         # the token table transposed — no head parameter exists
         self.tie_embeddings = tie_embeddings
@@ -143,6 +218,20 @@ class TransformerLM(Module):
         if self.tie_embeddings:
             return resolve_weight(params["tok"], "emb", self.dtype).T
         return resolve_weight(params["head"], "w", self.dtype)
+
+    def streams_in(self, x):
+        """The embedding (..., D) as the blocks take it: copied into every
+        residual stream (..., streams, D) under hyper-connections."""
+        if not self.streams:
+            return x
+        return jnp.broadcast_to(x[..., None, :],
+                                x.shape[:-1] + (self.streams, self.dim))
+
+    def streams_out(self, x):
+        """What the final norm takes: the sum of the streams."""
+        if not self.streams:
+            return x
+        return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
 
     def project_vocab(self, params, x):
         """Hidden states (..., dim) → logits (..., vocab). Single source
@@ -174,6 +263,7 @@ class TransformerLM(Module):
             positions = pos_offset + jnp.arange(s)
         if self.pos is not None:
             x = x + self.pos.apply(params["pos"], positions)
+        x = self.streams_in(x)
         for i, blk in enumerate(self.blocks):
             r = jax.random.fold_in(rng, i) if rng is not None else None
 
@@ -191,7 +281,7 @@ class TransformerLM(Module):
             # matmul outputs and recomputes only the elementwise chain
             run_block = apply_remat_policy(run_block, self.remat_policy)
             x = run_block(params["blocks"][i], x)
-        x = self.ln_f.apply(params["ln_f"], x)
+        x = self.ln_f.apply(params["ln_f"], self.streams_out(x))
         if return_hidden:
             return x
         return self.project_vocab(params, x)

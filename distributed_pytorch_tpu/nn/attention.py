@@ -154,6 +154,102 @@ class MultiHeadAttention(Module):
             o = self.attn_fn(q, k, v, causal=self.causal)
         return self.project_out(params, o)
 
+    # -- the paged path: the module owns its page layout (nn/paged.py) ------
+
+    def page_shapes(self, page_len: int):
+        """A layer keeps a K and a V array of (n_pages, Hkv, page_len,
+        Dh): the shapes without the page axis."""
+        shape = (self.n_kv_heads, page_len, self.head_dim)
+        return (shape, shape)
+
+    def decode_paged(self, params: Params, x, pages, ctx):
+        """One token a row over the exact paged pool. x (B, 1, D) normed;
+        returns (attention's output (B, 1, D), new (K, V) pages)."""
+        from ..ops.decode_attention import (dense_decode_attention,
+                                            paged_decode_attention)
+        k_pages, v_pages = pages
+        scale = 1.0 / math.sqrt(self.head_dim)
+        hq, hk, hv = self.project_qkv(params, x)
+        hq, hk = self.maybe_rope(hq, hk, ctx.idx[:, None, None])
+        with jax.named_scope("page_write"):
+            kp = k_pages.at[ctx.dest, :, ctx.wo].set(
+                hk[:, :, 0, :].astype(k_pages.dtype), mode="drop")
+            vp = v_pages.at[ctx.dest, :, ctx.wo].set(
+                hv[:, :, 0, :].astype(v_pages.dtype), mode="drop")
+        if ctx.blockwise:
+            # the page gather lives inside the block loop; hk/hv are
+            # re-selected at the write position per block — identity
+            # for active rows (already scattered), and gives inactive
+            # rows decode_step_slots' exact value semantics (their
+            # discarded logits still see "their" key)
+            o = paged_decode_attention(hq, kp, vp, ctx.tables, ctx.idx,
+                                       hk, hv, scale=scale,
+                                       page_len=ctx.page_len)
+        else:
+            # logical rows: gather the updated pool, then re-select the
+            # new key at the write position
+            k = jnp.where(ctx.write_mask, hk.astype(kp.dtype),
+                          gather_pages(kp, ctx.tables))
+            v = jnp.where(ctx.write_mask, hv.astype(vp.dtype),
+                          gather_pages(vp, ctx.tables))
+            o = dense_decode_attention(hq, k, v, ctx.pos_mask, scale=scale)
+        return self.project_out(params, o), (kp, vp)
+
+    def prefill_paged(self, params: Params, x, pages, ctx):
+        """The tail of one prompt over [shared prefix pages | tail].
+        x (1, S, D) normed; returns (output (1, S, D), new pages)."""
+        k_pages, v_pages = pages
+        s, width = x.shape[1], ctx.width
+        scale = 1.0 / math.sqrt(self.head_dim)
+        hq, hk, hv = self.project_qkv(params, x)
+        hq, hk = self.maybe_rope(hq, hk, ctx.positions)
+        with jax.named_scope("page_write"):
+            kp = k_pages.at[ctx.dest, :, ctx.dest_off].set(
+                jnp.moveaxis(hk[0], 1, 0).astype(k_pages.dtype), mode="drop")
+            vp = v_pages.at[ctx.dest, :, ctx.dest_off].set(
+                jnp.moveaxis(hv[0], 1, 0).astype(v_pages.dtype), mode="drop")
+        # prefix keys from the (updated) pool; tail keys inline — the
+        # tail pages were just written, but using the in-register tail
+        # avoids a second gather and keeps the math identical to
+        # prefill_partial's [real | pad] layout
+        pref_k = kp[ctx.table_row].transpose(1, 0, 2, 3) \
+            .reshape(1, -1, width, kp.shape[-1]).astype(hk.dtype)
+        pref_v = vp[ctx.table_row].transpose(1, 0, 2, 3) \
+            .reshape(1, -1, width, vp.shape[-1]).astype(hv.dtype)
+        return self.project_out(
+            params, prefix_tail_attention(hq, hk, hv, pref_k, pref_v,
+                                          ctx.mask, scale)), (kp, vp)
+
+
+def gather_pages(pool, tables):
+    """Gather a slot batch's pages into contiguous rows.
+
+    pool: (n_pages, Hkv, page_len, Dh); tables: (B, P) int32 page ids
+    (unallocated entries may hold any valid id — the caller's position
+    mask hides them). Returns (B, Hkv, P*page_len, Dh)."""
+    g = pool[tables]                       # (B, P, Hkv, page_len, Dh)
+    b, p, h, l, d = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, p * l, d)
+
+
+def prefix_tail_attention(hq, hk, hv, pref_k, pref_v, mask, scale):
+    """Grouped-query attention of a tail's queries (1, H, S, Dh) over
+    [prefix (1, Hkv, W, Dh) | tail (1, Hkv, S, Dh)] under ``mask``
+    (S, W + S); float32 statistics. Returns (1, H, S, Dh)."""
+    s = hq.shape[2]
+    k_all = jnp.concatenate([pref_k, hk], axis=2)   # (1,Hkv,W+S,Dh)
+    v_all = jnp.concatenate([pref_v, hv], axis=2)
+    bq, hh, _, dd = hq.shape
+    hkv = k_all.shape[1]
+    hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
+    logits = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
+        jnp.float32) * scale                     # (1,Hkv,g,S,W+S)
+    logits = jnp.where(mask[None, None, None, :, :], logits,
+                       -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v_all.dtype)
+    return jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
+        .reshape(bq, hh, s, dd)
+
 
 class TransformerBlock(Module):
     """Pre-norm block: x + MHA(LN(x)); x + MLP(LN(x)), GELU MLP."""
@@ -187,6 +283,23 @@ class TransformerBlock(Module):
         with jax.named_scope("mlp"):
             return self.fc2.apply(params["fc2"],
                                   gelu(self.fc1.apply(params["fc1"], h)))
+
+    def page_shapes(self, page_len: int):
+        return self.attn.page_shapes(page_len)
+
+    def decode_paged(self, params: Params, x, pages, ctx):
+        """x (B, 1, D), this layer's page arrays -> (x, new arrays)."""
+        a, pages = self.attn.decode_paged(
+            params["attn"], self.ln1.apply(params["ln1"], x), pages, ctx)
+        x = x + a
+        return x + self.mlp(params, x), pages
+
+    def prefill_paged(self, params: Params, x, pages, ctx):
+        """x (1, S, D): the padded tail of one prompt."""
+        a, pages = self.attn.prefill_paged(
+            params["attn"], self.ln1.apply(params["ln1"], x), pages, ctx)
+        x = x + a
+        return x + self.mlp(params, x), pages
 
     def apply(self, params: Params, x, *, rng=None, train: bool = False,
               positions=None, **_):

@@ -124,6 +124,54 @@ class LayerNorm(Module):
             return y * params["scale"] + params["bias"]
 
 
+class RMSNorm(Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``: no mean, no bias. The
+    statistic is float32 whatever the activations are; the result comes
+    back in the input's type."""
+
+    def __init__(self, dim: int, eps: float = 1e-6, dtype=jnp.float32,
+                 scope: str = "norm"):
+        self.dim = dim
+        self.eps = eps
+        self.dtype = dtype
+        self.scope = scope
+
+    def init(self, key) -> Params:
+        del key
+        return {"scale": jnp.ones((self.dim,), self.dtype)}
+
+    def apply(self, params: Params, x, **_):
+        with jax.named_scope(self.scope):
+            xf = x.astype(jnp.float32)
+            y = xf * jax.lax.rsqrt(
+                jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + self.eps)
+            return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
+
+
+class GatedMLP(Module):
+    """``down(silu(gate(x)) * up(x))`` of any width, no biases (SwiGLU).
+    The feed-forward of a dense layer and of one expert alike; it does
+    not norm its input."""
+
+    def __init__(self, dim: int, hidden: int, dtype=jnp.float32):
+        self.dim = dim
+        self.hidden = hidden
+        self.gate = Linear(dim, hidden, bias=False, dtype=dtype)
+        self.up = Linear(dim, hidden, bias=False, dtype=dtype)
+        self.down = Linear(hidden, dim, bias=False, dtype=dtype)
+
+    def init(self, key) -> Params:
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"gate": self.gate.init(kg), "up": self.up.init(ku),
+                "down": self.down.init(kd)}
+
+    def apply(self, params: Params, x, **_):
+        with jax.named_scope("mlp"):
+            h = jax.nn.silu(self.gate.apply(params["gate"], x)) \
+                * self.up.apply(params["up"], x)
+            return self.down.apply(params["down"], h)
+
+
 class Dropout(Module):
     """Stateless dropout: pass ``rng=`` and ``train=True`` to drop."""
 

@@ -45,3 +45,49 @@ def apply_rope(x, positions, base: float = 10000.0):
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_inv_freq(rot_dim: int, base: float, *, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's frequencies (Peng et al. 2023) for a rotary part of
+    ``rot_dim``: frequency ``i`` of ``rot_dim / 2`` is the plain
+    ``base^(-2i/rot_dim)`` where it turns more than ``beta_fast`` times
+    inside the original context, that divided by ``factor`` where it
+    turns less than ``beta_slow`` times, and a linear blend between.
+    The ramp's ends are the floor and ceiling of the dimension at which a
+    frequency makes exactly ``beta`` turns, clamped to the part."""
+    import math
+
+    half = rot_dim // 2
+    f = base ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot_dim)
+
+    def dim_of(turns):
+        return rot_dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    lo = max(math.floor(dim_of(beta_fast)), 0)
+    hi = min(math.ceil(dim_of(beta_slow)), rot_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 mscale ln(factor) + 1`` (1 where nothing is stretched)."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotate_interleaved(x, positions, inv_freq, mult: float = 1.0):
+    """Rotate the last axis of ``x`` in interleaved pairs (2i, 2i + 1)
+    by ``positions * inv_freq``. ``positions`` broadcasts against the
+    axes of ``x`` before the last (give it a trailing axis per head axis
+    it has to span); angles in float32, dtype preserved."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang) * mult, jnp.sin(ang) * mult
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -188,7 +188,8 @@ def blockwise_decode_attention(hq, k, v, idx, *, scale,
 def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
                            new_v, *, scale, page_len: int,
                            k_scales=None, v_scales=None,
-                           k_tail=None, v_tail=None):
+                           k_tail=None, v_tail=None,
+                           latent_width: Optional[int] = None):
     """Single-token attention over a PAGED pool, one page per step.
 
     hq: (B, H, 1, Dh); k_pages/v_pages: (n_pages[+1], Hkv, page_len,
@@ -215,6 +216,14 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
     buffer, so un-finalized positions attend exactly and quantization
     error only ever comes from completed pages' single rounding. All
     four default to None = the exact path, traced jaxpr unchanged.
+
+    **Latent pool** (``latent_width``; ``nn/latent.py``): the pool is
+    ONE array whose entries are both key and value: ``k_pages`` is
+    ``(n_pages[+1], 1, page_len, E)``, every query head (absorbed
+    queries of width E) scores against the one shared key head, and the
+    values are the first ``latent_width`` of the same gathered page.
+    ``v_pages`` and ``new_v`` are None; the result is (B, H, 1,
+    ``latent_width``).
     """
     b, h, _, dh = hq.shape
     hkv = k_pages.shape[1]
@@ -223,7 +232,11 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
     total = tables.shape[1]
     nb = resident_blocks(idx, page_len, total)
     nk_g = new_k.reshape(b, hkv, 1, dh)
-    nv_g = new_v.reshape(b, hkv, 1, dh)
+    latent = latent_width is not None
+    if latent and (v_pages is not None or k_scales is not None):
+        raise ValueError("a latent pool is one exact array: no v_pages, "
+                         "no quantized pages")
+    nv_g = None if latent else new_v.reshape(b, hkv, 1, dh)
     quant = k_scales is not None
     if quant:
         from .quant import (dequantize_page_blocks, page_block_map,
@@ -238,7 +251,7 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
             pids = jax.lax.dynamic_index_in_dim(tables, j, axis=1,
                                                 keepdims=False)  # (B,)
             k_blk = jnp.take(k_pages, pids, axis=0)  # (B, Hkv, L, Dh)
-            v_blk = jnp.take(v_pages, pids, axis=0)
+            v_blk = None if latent else jnp.take(v_pages, pids, axis=0)
             if quant:
                 if packed:
                     k_blk = unpack_page_nibbles(k_blk)
@@ -256,7 +269,10 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
                 v_blk = jnp.where(it, v_tail, v_blk)
         wm = (pos[None, :] == idx[:, None])[:, None, :, None]
         k_blk = jnp.where(wm, nk_g.astype(k_blk.dtype), k_blk)
-        v_blk = jnp.where(wm, nv_g.astype(v_blk.dtype), v_blk)
+        if latent:
+            v_blk = k_blk[..., :latent_width]
+        else:
+            v_blk = jnp.where(wm, nv_g.astype(v_blk.dtype), v_blk)
         valid = (pos[None, :] <= idx[:, None])
         s = jax.lax.dot_general(
             hq_g.astype(k_blk.dtype), k_blk,
@@ -266,9 +282,11 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
         s = jnp.where(valid5, s, _MASK)
         return _merge_block(carry, s, v_blk, valid5)
 
+    dv = latent_width if latent else dh
     carry = (jnp.full((b, hkv, g, 1), _MASK, jnp.float32),
              jnp.zeros((b, hkv, g, 1), jnp.float32),
-             jnp.zeros((b, hkv, g, 1, dh), jnp.float32))
+             jnp.zeros((b, hkv, g, 1, dv), jnp.float32))
     m, l, acc = jax.lax.fori_loop(0, nb, body, carry)
-    out_dtype = new_v.dtype if quant else v_pages.dtype
-    return _finish(m, l, acc, out_dtype).reshape(b, h, 1, dh)
+    out_dtype = k_pages.dtype if latent else (
+        new_v.dtype if quant else v_pages.dtype)
+    return _finish(m, l, acc, out_dtype).reshape(b, h, 1, dv)

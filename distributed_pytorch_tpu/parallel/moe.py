@@ -1,4 +1,24 @@
-"""Expert parallelism: Switch/GShard-style mixture-of-experts.
+"""Expert parallelism: two mixture-of-experts layers, and why both stand.
+
+**Which layer is which.** :class:`MoELayer` is the *training example's*
+layer (``models/moe_lm.py``, ``examples/train_moe_lm.py``): Switch/GShard
+routing as dense tensor algebra, a one-hot ``(tokens, experts, capacity)``
+dispatch with a capacity factor, tokens over capacity dropped, GELU
+experts with biases, softmax gates and the auxiliary losses a trainer
+needs. Its shapes are static and its exchange over an ``ep`` mesh axis
+falls out of the SPMD partitioner, which is what a trained-from-scratch
+example wants. :class:`DroplessMoE` is the *serving* layer
+(``nn/block.py``, ``models/transformer.py`` ``block_kinds``): sigmoid
+scores with a bias-corrected top-k over all routed experts, the chosen
+(token, expert) pairs sorted by expert, one grouped matmul a projection
+(``jax.lax.ragged_dot``) over the experts this chip holds, the results
+gathered back and weighted, one shared SwiGLU expert added. No capacity,
+no token dropped, none padded into an expert it did not choose, and no
+one-hot tensor: a decode step reads only the experts its batch touches.
+Published sparse models route this way and a served model must compute
+what it was trained to compute, so the capacity layer cannot stand in
+for it; the dropless layer has no auxiliary losses and has been trained
+at no measured size (ROADMAP Reach), so it does not replace the example's.
 
 No reference analog (SURVEY.md §2.4: EP absent). TPU-native design
 (GShard): routing is *dense tensor algebra* — one-hot dispatch/combine
@@ -42,7 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..nn.core import Linear, Module, Params, gelu
+from ..nn.core import GatedMLP, Linear, Module, Params, gelu
 
 
 class MoELayer(Module):
@@ -240,3 +260,113 @@ def moe_param_specs(ep_axis: str = "ep", tp_axis: Optional[str] = None,
         specs["shared"] = {"fc1": {"w": P(None, t), "b": P(t)},
                            "fc2": {"w": P(t, None), "b": P()}}
     return specs
+
+
+class DroplessMoE(Module):
+    """Dropless token-choice experts for serving: x (..., D) -> y (..., D).
+
+    Routes over all ``n_routed`` experts: ``g = sigmoid(x W_r)`` in
+    float32, the ``top_k`` largest of ``g + bias`` (the bias corrects the
+    choice only, DeepSeek-V3's ``noaux_tc``), weights ``g_e / (sum of the
+    chosen g + 1e-20) * scale``. ``held = (first, count)`` says which
+    experts this chip holds (all of them by default): it computes their
+    part of the result, pairs routed elsewhere cost nothing here, and
+    nothing stands in for the absent chips. The shared expert runs on
+    every token (every chip computes it alike, so a sum over shares
+    counts it once). Scopes: ``moe`` > ``route``, ``dispatch``,
+    ``experts``, ``shared``, ``combine``."""
+
+    def __init__(self, dim: int, n_routed: int, width: int, *, top_k: int,
+                 n_shared: int = 1, scale: float = 1.0,
+                 held: Optional[Tuple[int, int]] = None,
+                 dtype=jnp.float32):
+        if not 1 <= top_k <= n_routed:
+            raise ValueError(f"top_k={top_k} not in [1, {n_routed}]")
+        first, count = held if held is not None else (0, n_routed)
+        if first < 0 or count < 1 or first + count > n_routed:
+            raise ValueError(f"held={held} is no range of {n_routed} experts")
+        self.dim, self.n_routed, self.width = dim, n_routed, width
+        self.top_k, self.scale, self.dtype = top_k, scale, dtype
+        self.first, self.count = first, count
+        self.shared = GatedMLP(dim, n_shared * width, dtype=dtype) \
+            if n_shared else None
+
+    def init(self, key) -> Params:
+        kr, kg, ku, kd, ks = jax.random.split(key, 5)
+        c, d, f = self.count, self.dim, self.width
+        u = lambda k, shape, fan: jax.random.uniform(
+            k, shape, self.dtype, -1.0 / math.sqrt(fan), 1.0 / math.sqrt(fan))
+        p = {"router": {"w": u(kr, (d, self.n_routed), d),
+                        "bias": jnp.zeros((self.n_routed,), jnp.float32)},
+             "experts": {"gate": u(kg, (c, d, f), d), "up": u(ku, (c, d, f), d),
+                         "down": u(kd, (c, f, d), f)}}
+        if self.shared is not None:
+            p["shared"] = self.shared.init(ks)
+        return p
+
+    def route(self, params: Params, xt):
+        """xt (T, D) -> chosen experts (T, k) int32, their weights (T, k)
+        float32 and the scores (T, E). Float32 from the activations the
+        layer is given."""
+        with jax.named_scope("route"):
+            g = jax.nn.sigmoid(jnp.matmul(
+                xt.astype(jnp.float32),
+                params["router"]["w"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, top_i = jax.lax.top_k(
+                g + params["router"]["bias"].astype(jnp.float32), self.top_k)
+            top_g = jnp.take_along_axis(g, top_i, axis=-1)
+            w = top_g / (jnp.sum(top_g, -1, keepdims=True) + 1e-20) \
+                * self.scale
+            return top_i.astype(jnp.int32), w, g
+
+    def routed(self, params: Params, xt, row_mask=None):
+        """The held experts' part of the result for xt (T, D), float32,
+        and the counts ``(tokens_routed, experts_touched,
+        tokens_max_expert)`` of this call. ``row_mask`` (T,) bool leaves
+        rows out of the dispatch (idle slots, a padded tail)."""
+        t, k, c = xt.shape[0], self.top_k, self.count
+        top_i, w, _ = self.route(params, xt)
+        with jax.named_scope("dispatch"):
+            eid = top_i.reshape(-1) - self.first
+            here = (eid >= 0) & (eid < c)
+            if row_mask is not None:
+                here &= jnp.repeat(row_mask, k)
+            key = jnp.where(here, eid, c)          # not here: sorted last
+            order = jnp.argsort(key)               # stable
+            sizes = jnp.sum(key[:, None] == jnp.arange(c)[None, :], axis=0,
+                            dtype=jnp.int32)
+            xs = jnp.take(xt, order // k, axis=0)              # (T*k, D)
+        with jax.named_scope("experts"):
+            e = params["experts"]
+            dot = lambda a, b: jax.lax.ragged_dot(
+                a, b, sizes, preferred_element_type=jnp.float32)
+            h = jax.nn.silu(dot(xs, e["gate"])) * dot(xs, e["up"])
+            ys = dot(h.astype(xt.dtype), e["down"])
+        with jax.named_scope("combine"):
+            # rows past the last group belong to no expert here: what the
+            # grouped matmul left there is not a result
+            ys = jnp.where((jnp.arange(t * k) < jnp.sum(sizes))[:, None],
+                           ys, 0.0)
+            back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+                jnp.arange(t * k, dtype=jnp.int32))
+            pairs = jnp.take(ys, back, axis=0).reshape(t, k, self.dim)
+            y = jnp.sum(pairs * jnp.where(here.reshape(t, k), w, 0.0)[..., None],
+                        axis=1)
+        counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                            jnp.max(sizes)]).astype(jnp.int32)
+        return y, counts
+
+    def apply(self, params: Params, x, *, row_mask=None, stats=None, **_):
+        """``stats``: a list the call's counts (3,) int32 are appended
+        to (``stats()``'s ``moe_*`` counters are their sums)."""
+        with jax.named_scope("moe"):
+            xt = x.reshape(-1, self.dim)
+            y, counts = self.routed(
+                params, xt, None if row_mask is None else row_mask.reshape(-1))
+            if stats is not None:
+                stats.append(counts)
+            if self.shared is not None:
+                with jax.named_scope("shared"):
+                    y = y + self.shared.apply(params["shared"], xt)
+            return y.reshape(x.shape).astype(x.dtype)

@@ -34,8 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from ..models.generate import (_sample, decode_step_slots,
-                               prefill_partial, spec_commit_slots,
-                               spec_verify_slots)
+                               prefill_partial, refuse_latent,
+                               spec_commit_slots, spec_verify_slots)
 
 
 @dataclass
@@ -84,6 +84,7 @@ class SlotPool:
 
     def __init__(self, model, n_slots: int, max_len: int,
                  window: Optional[int] = None):
+        refuse_latent(model, "the contiguous SlotPool (paged=False)")
         self.model = model
         self.n_slots = n_slots
         self.max_len = max_len

@@ -53,6 +53,8 @@ class DecodeEngine:
     def __init__(self, model, params, router, transport, *,
                  n_slots: int, max_len: int, page_len: int, n_pages: int,
                  kv_dtype: str = "f32", spec=None, buckets=None):
+        from ...models.generate import refuse_latent
+        refuse_latent(model, "the disaggregated hand-off (serve/disagg)")
         self.model = model
         self.params = params
         self.router = router

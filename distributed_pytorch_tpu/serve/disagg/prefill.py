@@ -45,6 +45,8 @@ class PrefillEngine:
     def __init__(self, model, params, router, transport, *, buckets,
                  page_len: int, n_pages: int, prefix_share: bool,
                  bits: Optional[int], kv_dtype: str = "f32"):
+        from ...models.generate import refuse_latent
+        refuse_latent(model, "the disaggregated hand-off (serve/disagg)")
         self.model = model
         self.params = params
         self.router = router

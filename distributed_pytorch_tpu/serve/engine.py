@@ -43,7 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generate import _check_attn_compatible, _model_window
+from ..models.generate import (_check_attn_compatible, _model_window,
+                               refuse_latent)
 from ..obs import metrics as dpxmon
 from ..obs import trace as dpxtrace
 from ..runtime import compile_cache
@@ -198,6 +199,7 @@ class InferenceEngine:
                    else dpxenv.get("DPX_SPEC_DECODE"))
         self._spec: Optional[SpecState] = None
         if spec_on:
+            refuse_latent(model, "speculative decoding (serve/spec)")
             if self.window is not None:
                 raise ValueError(
                     "spec_decode does not support sliding-window "
@@ -428,6 +430,14 @@ class InferenceEngine:
                "spec_decode": self._spec is not None}
         if self._paged:
             out["pages"] = self.pool.page_stats()
+            moe = self.pool.moe_stats()
+            if moe is not None:
+                # the one place the expert layers' device counters are
+                # read; the mark puts a reading on the profiler's clock,
+                # so that a traced part can be told by two of them
+                out.update(moe)
+                with dpxtrace.span("serve.stats", **moe):
+                    pass
         if self._spec is not None:
             out["spec"] = {
                 "draft_len": self._spec.cfg.draft_len,

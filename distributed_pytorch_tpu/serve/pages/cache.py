@@ -2,8 +2,16 @@
 
 KV memory is a block pool — per layer ONE ``(n_pages, Hkv, page_len,
 Dh)`` buffer for K and V — and each slot addresses its cache through a
-page table row instead of owning a contiguous stripe. Three things fall
-out of that indirection:
+page table row instead of owning a contiguous stripe. The BLOCK owns
+that layout: the pool asks each block for its page arrays
+(``blk.page_shapes(page_len)``, ``nn/paged.py``). Multi-head attention
+answers with the K and V shapes above; latent attention (MLA,
+``nn/latent.py``) with ONE array a layer, ``(n_pages, 1, page_len,
+kv_rank + rope_dim)``, held in ``k_pages`` with ``v_pages`` empty. The
+allocator, the tables and the prefix index count pages and never look
+inside one, so they serve both unchanged; what copies page CONTENTS
+(quantized pages, the disaggregated hand-off) refuses latent blocks by
+name. Three things fall out of the indirection:
 
 - **prefix sharing**: full pages of a prompt are keyed in a radix index
   (:mod:`.prefix`); an admitted request reuses every resident page of
@@ -34,7 +42,7 @@ import numpy as np
 
 from ...comm import wire
 from ...models.generate import (decode_step_slots_paged,
-                                prefill_partial_paged,
+                                prefill_partial_paged, refuse_latent,
                                 spec_commit_slots_paged,
                                 spec_verify_slots_paged)
 from ...runtime import faults
@@ -76,14 +84,30 @@ class PagedSlotPool:
         self.pages_per_slot = -(-max_len // page_len)   # ceil
         dh = model.dim // model.n_heads
         h_kv = getattr(model, "n_kv_heads", model.n_heads)
-        self._page_shape = (h_kv, page_len, dh)
         n_layers = model.n_layers
+        # the block owns its page layout: (K, V) shapes, or one latent
+        layouts = [blk.page_shapes(page_len) for blk in model.blocks]
+        if len({len(shapes) for shapes in layouts}) > 1:
+            raise ValueError("blocks disagree on how many page arrays a "
+                             "layer keeps; the pool needs one layout")
+        self.latent = len(layouts[0]) == 1
+        self._page_shape = layouts[0][0]
+        if self.quant_bits is not None:
+            refuse_latent(model, "quantized pages (kv_dtype q8/q4)")
+        # what an expert layer counts in a decode step, summed on the
+        # device and read only by stats(): tokens routed, experts with a
+        # token, the fullest expert's tokens, decode steps
+        self.moe_layers = sum(hasattr(getattr(blk, "ffn", None), "routed")
+                              for blk in model.blocks)
+        self.moe_counts = jnp.zeros((4,), jnp.int32) \
+            if self.moe_layers else None
         if self.quant_bits is None:
-            shape = (n_pages, h_kv, page_len, dh)
             self.k_pages: List[jax.Array] = [
-                jnp.zeros(shape, model.dtype) for _ in range(n_layers)]
+                jnp.zeros((n_pages,) + shapes[0], model.dtype)
+                for shapes in layouts]
             self.v_pages: List[jax.Array] = [
-                jnp.zeros(shape, model.dtype) for _ in range(n_layers)]
+                jnp.zeros((n_pages,) + shapes[1], model.dtype)
+                for shapes in layouts if len(shapes) > 1]
             self.k_scales = self.v_scales = None
             self.k_tail = self.v_tail = None
         else:
@@ -123,7 +147,10 @@ class PagedSlotPool:
         self.index = PrefixIndex(page_len)
         self.compiles = CompileCounts()
         self._admit_fns: Dict[int, callable] = {}
-        if self.quant_bits is None:
+        if self.moe_layers:
+            self._decode_fn = jax.jit(self._decode_moe,
+                                      donate_argnums=(1, 2))
+        elif self.quant_bits is None:
             self._decode_fn = jax.jit(self._decode, donate_argnums=(1, 2))
         else:
             self._decode_fn = jax.jit(self._decode_q,
@@ -143,6 +170,22 @@ class PagedSlotPool:
             self.model, params, k_pages, v_pages, tables, lengths, tokens,
             active, page_len=self.page_len)
         return (greedy_tokens(logits), logits, *pool)
+
+    def _decode_moe(self, params, k_pages, v_pages, counts, tables,
+                    lengths, tokens, active):
+        """The decode program of a model with expert layers: the same
+        step, and the layers' counts added to ``counts`` on the device."""
+        self.compiles.decode += 1          # trace-time only
+        per_layer = []
+        logits, *pool = decode_step_slots_paged(
+            self.model, params, k_pages, v_pages, tables, lengths, tokens,
+            active, page_len=self.page_len, moe_stats=per_layer)
+        c = jnp.stack(per_layer)                           # (layers, 3)
+        counts = jnp.stack([counts[0] + jnp.sum(c[:, 0]),
+                            counts[1] + jnp.sum(c[:, 1]),
+                            jnp.maximum(counts[2], jnp.max(c[:, 2])),
+                            counts[3] + 1])
+        return (greedy_tokens(logits), logits, *pool, counts)
 
     def _decode_q(self, params, k_pages, v_pages, k_scales, v_scales,
                   k_tail, v_tail, tables, lengths, tokens, active):
@@ -340,7 +383,13 @@ class PagedSlotPool:
         decode program (inactive rows neither write the pool nor
         advance). Returns each slot's greedy token (n_slots,) int32 and
         the (n_slots, vocab) logits, both left on the device."""
-        if self.quant_bits is None:
+        if self.moe_layers:
+            (out, logits, self.k_pages, self.v_pages,
+             self.moe_counts) = self._decode_fn(
+                params, self.k_pages, self.v_pages, self.moe_counts,
+                jnp.array(self.tables), jnp.array(self.lengths),
+                jnp.asarray(tokens), jnp.asarray(active))
+        elif self.quant_bits is None:
             out, logits, self.k_pages, self.v_pages = self._decode_fn(
                 params, self.k_pages, self.v_pages,
                 jnp.array(self.tables), jnp.array(self.lengths),
@@ -442,6 +491,7 @@ class PagedSlotPool:
         and the partial last page is read from the slot's exact f32
         tail buffer (the pool row for it was never written), so the
         extracted tail carries ZERO quantization error."""
+        refuse_latent(self.model, "the disaggregated hand-off (serve/disagg)")
         row = self.owned[slot]
         length = int(self.lengths[slot])
         valid_last = length - (len(row) - 1) * self.page_len
@@ -541,6 +591,7 @@ class PagedSlotPool:
         goes into the slot's exact f32 tail buffer, and the tail buffer
         is defensively zeroed on page-aligned lengths so a previous
         occupant's stale tail can never alias into the new request."""
+        refuse_latent(self.model, "the disaggregated hand-off (serve/disagg)")
         n = int(ks[0].shape[0])
         pids = self._alloc(n)          # all-or-nothing; may raise
         self.tables[slot, :n] = pids
@@ -690,6 +741,18 @@ class PagedSlotPool:
             total += sum(a.nbytes for a in self.k_scales)
             total += sum(a.nbytes for a in self.v_scales)
         return total / float(self.n_pages * self.page_len)
+
+    def moe_stats(self) -> Optional[Dict]:
+        """The expert layers' counters over every decode step so far
+        (one device-to-host read, made here and nowhere else), or None
+        for a model without expert layers."""
+        if self.moe_counts is None:
+            return None
+        routed, touched, fullest, steps = (
+            int(v) for v in np.asarray(self.moe_counts))
+        return {"moe_tokens_routed": routed, "moe_experts_touched": touched,
+                "moe_tokens_max_expert": fullest, "moe_decode_steps": steps,
+                "moe_layers": self.moe_layers}
 
     def page_stats(self) -> Dict:
         return {"n_pages": self.n_pages,

@@ -1,0 +1,317 @@
+"""The blocks made of parts on the serving path, at a tiny size in float32
+on the CPU: multi-head latent attention over a latent page pool (absorbed
+decode against the expanded full forward), the dropless expert layer
+(no token dropped at any imbalance; shares add up), the four-stream
+hyper-connection path (doubly stochastic mixes), YaRN's frequencies
+against values computed by hand, and what the engine refuses for latent
+blocks. The program against the independent reference lives in
+tests/chipbench/test_chipbench_xing4.py."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_pytorch_tpu import models
+from distributed_pytorch_tpu.models.generate import LatentPagesUnsupported
+from distributed_pytorch_tpu.nn.hyper import HyperConnection, sinkhorn
+from distributed_pytorch_tpu.nn.rotary import yarn_inv_freq, yarn_mscale
+from distributed_pytorch_tpu.parallel.moe import DroplessMoE
+from distributed_pytorch_tpu.serve import (EngineConfig, InferenceEngine,
+                                           SamplingParams)
+from distributed_pytorch_tpu.serve.cache import SlotPool
+from distributed_pytorch_tpu.serve.pages import PagedSlotPool
+
+YARN = dict(factor=64, original_max_position_embeddings=4096, beta_fast=32,
+            beta_slow=1, mscale=1, mscale_all_dim=1)
+TINY = dict(vocab=211, dim=64, n_layers=3, n_heads=4, max_seq=64, pos="none",
+            block_kinds=("dense", "moe", "moe"), attention="latent",
+            latent=dict(q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8,
+                        v_dim=16, yarn=YARN),
+            norm="rms", norm_eps=1e-6, ffn_dim=160,
+            moe=dict(n_routed=8, width=32, top_k=2, n_shared=1, scale=2.0),
+            hyper_connections=4, hc=dict(sinkhorn_iters=20, eps=1e-6))
+PAGE = 4
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model = models.TransformerLM(**TINY)
+    params = model.init(jax.random.PRNGKey(7))
+    # the default init mixes the streams by near-identities; give the
+    # residual path something to do
+    for i, blk in enumerate(params["blocks"]):
+        for j, hc in enumerate((blk["hc1"], blk["hc2"])):
+            k = jax.random.fold_in(jax.random.PRNGKey(11), 2 * i + j)
+            hc["b_res"] = jax.random.normal(k, (4, 4))
+            hc["a_res"] = jnp.float32(1.0)
+            hc["b_pre"] = 0.5 * jax.random.normal(jax.random.fold_in(k, 1),
+                                                  (4,))
+    return model, params
+
+
+def full_logits(model, params, tokens):
+    return np.asarray(model.apply(params, jnp.asarray(tokens)[None])[0])
+
+
+def serve_through_pool(model, params, pool, prompt, slot, steps):
+    """Admit ``prompt``, then ``steps`` greedy decode steps; returns
+    (tokens served, the logits each was chosen from, pages hit)."""
+    logits, n_hit, _ = pool.admit(params, prompt, slot, (16, 32))
+    rows, toks = [np.asarray(logits[0])], []
+    cur = np.zeros(pool.n_slots, np.int32)
+    active = np.zeros(pool.n_slots, bool)
+    active[slot] = True
+    for _ in range(steps):
+        toks.append(int(np.argmax(rows[-1])))
+        cur[slot] = toks[-1]
+        pool.ensure_decode_capacity(slot)
+        out, lg = pool.decode(params, cur, active)
+        assert int(out[slot]) == int(np.argmax(np.asarray(lg[slot])))
+        rows.append(np.asarray(lg[slot]))
+    return toks, np.stack(rows[:-1]), n_hit
+
+
+def test_paged_prefill_and_absorbed_decode_agree_with_the_full_forward(tiny):
+    """Cold, then with a shared prefix of two pages (the traced offset):
+    12 decode steps through the latent pages, each step's logits against
+    the expanded full forward over prompt + served tokens."""
+    model, params = tiny
+    pool = PagedSlotPool(model, 3, 64, page_len=PAGE, n_pages=48)
+    assert pool.latent and pool.v_pages == []
+    assert pool.k_pages[0].shape == (48, 1, PAGE, 128)   # 24 wide, padded
+    rng = np.random.default_rng(0)
+    first = rng.integers(0, 211, 13).astype(np.int32)
+    second = np.concatenate([first[:2 * PAGE],
+                             rng.integers(0, 211, 6).astype(np.int32)])
+    for slot, prompt, want_hit in ((0, first, 0), (1, second, 2)):
+        toks, rows, n_hit = serve_through_pool(model, params, pool, prompt,
+                                               slot, 12)
+        assert n_hit == want_hit
+        ref = full_logits(model, params, np.concatenate([prompt, toks]))
+        ref = ref[len(prompt) - 1:len(prompt) - 1 + 12]
+        np.testing.assert_allclose(rows, ref, atol=2e-4, rtol=0)
+    moe = pool.moe_stats()
+    assert moe["moe_decode_steps"] == 24 and moe["moe_layers"] == 2
+    # two rows active at most one at a time here: a step routes 1 token
+    # through 2 experts in each of 2 layers
+    assert moe["moe_tokens_routed"] == 24 * 2 * 2
+    assert moe["moe_experts_touched"] == 24 * 2 * 2
+    assert moe["moe_tokens_max_expert"] == 1
+    entry = 128 * 4
+    assert pool.page_stats()["bytes_per_resident_token"] == 3 * entry
+
+
+def test_dense_decode_path_agrees_with_the_blockwise_one(tiny):
+    from distributed_pytorch_tpu.models.generate import (
+        decode_step_slots_paged)
+    model, params = tiny
+    pool = PagedSlotPool(model, 2, 32, page_len=PAGE, n_pages=16)
+    pool.admit(params, np.arange(9, dtype=np.int32), 0, (16,))
+    pool.ensure_decode_capacity(0)
+    args = (model, params, pool.k_pages, [], jnp.array(pool.tables),
+            jnp.array(pool.lengths), jnp.asarray([5, 0], jnp.int32),
+            jnp.asarray([True, False]))
+    a, _, _ = decode_step_slots_paged(*args, page_len=PAGE, blockwise=True)
+    b, _, _ = decode_step_slots_paged(*args, page_len=PAGE, blockwise=False)
+    np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b[0]), atol=1e-4)
+
+
+def test_engine_serves_latent_blocks_with_one_fetch_an_iteration(tiny):
+    model, params = tiny
+    eng = InferenceEngine(model, params, EngineConfig(
+        paged=True, n_slots=4, max_len=64, buckets=(16, 32),
+        page_len=PAGE)).start()
+    try:
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(0, 211, n).astype(np.int32)
+                   for n in (5, 11, 17)]
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=9))
+                   for p in prompts]
+        outs = [np.asarray(h.result(timeout=600)) for h in handles]
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    for p, t in zip(prompts, outs):
+        ref = full_logits(model, params, np.concatenate([p, t]))
+        gap = ref[len(p) - 1:-1].max(-1) - np.take_along_axis(
+            ref[len(p) - 1:-1], t[:, None], -1)[:, 0]
+        assert gap.max() < 1e-3          # the served token is the best
+    assert st["decode_compiles"] == 1
+    assert st["decode_fetches"] in (st["moe_decode_steps"],
+                                    st["moe_decode_steps"] - 1)
+    assert st["moe_tokens_routed"] == st["rows_decoded"] * 2 * 2
+    assert 0 < st["moe_experts_touched"] <= st["moe_decode_steps"] * 2 * 8
+    assert st["pages"]["bytes_per_resident_token"] == 3 * 128 * 4
+
+
+# -- the expert layer ----------------------------------------------------------
+
+def by_hand(layer, params, x, top_i, w):
+    """Every chosen (token, expert) pair computed alone, nothing sorted."""
+    e = jax.tree_util.tree_map(np.asarray, params["experts"])
+    silu = lambda a: a / (1.0 + np.exp(-a))
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for j in range(top_i.shape[1]):
+            i = int(top_i[t, j])
+            h = silu(x[t] @ e["gate"][i]) * (x[t] @ e["up"][i])
+            out[t] += w[t, j] * (h @ e["down"][i])
+    return out
+
+
+@pytest.mark.parametrize("favoured", [(3,), (2, 5), ()])
+def test_no_token_is_dropped_at_any_imbalance(favoured):
+    """Every token to one expert (or to the same two), the others empty;
+    and the balanced case. The layer's routed part equals the pairs
+    computed one by one."""
+    k = max(len(favoured), 1) if favoured else 2
+    layer = DroplessMoE(24, 8, 16, top_k=k, n_shared=1, scale=2.0)
+    params = layer.init(jax.random.PRNGKey(3))
+    bias = np.zeros(8, np.float32)
+    bias[list(favoured)] = 100.0
+    params["router"]["bias"] = jnp.asarray(bias)
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (37, 24)))
+    top_i, w, _ = layer.route(params, jnp.asarray(x))
+    if favoured:
+        assert set(np.asarray(top_i).ravel().tolist()) == set(favoured)
+    y, counts = layer.routed(params, jnp.asarray(x))
+    np.testing.assert_allclose(
+        np.asarray(y), by_hand(layer, params, x, np.asarray(top_i),
+                               np.asarray(w)), atol=2e-5)
+    routed, touched, fullest = (int(c) for c in counts)
+    assert routed == 37 * k
+    if favoured:
+        assert touched == len(favoured) and fullest == 37
+    # rows left out of the dispatch cost nothing and give nothing
+    mask = np.arange(37) % 3 != 0
+    ym, cm = layer.routed(params, jnp.asarray(x), jnp.asarray(mask))
+    np.testing.assert_allclose(np.asarray(ym)[mask], np.asarray(y)[mask],
+                               atol=2e-5)
+    assert not np.asarray(ym)[~mask].any()
+    assert int(cm[0]) == int(mask.sum()) * k
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """held=(0,4) + held=(4,4): the routed parts add up, and the whole
+    outputs add up once the shared expert is counted once."""
+    whole = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0)
+    params = whole.init(jax.random.PRNGKey(5))
+    x = jax.random.normal(jax.random.PRNGKey(6), (29, 24))
+    y_whole = whole.apply(params, x)
+    shared = whole.shared.apply(params["shared"], x)
+    parts, outs = [], []
+    for first in (0, 4):
+        share = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0,
+                            held=(first, 4))
+        p = dict(params, experts=jax.tree_util.tree_map(
+            lambda a: a[first:first + 4], params["experts"]))
+        parts.append(share.routed(p, x)[0])
+        outs.append(share.apply(p, x))
+    np.testing.assert_allclose(parts[0] + parts[1],
+                               whole.routed(params, x)[0], atol=2e-5)
+    np.testing.assert_allclose(outs[0] + outs[1] - shared, y_whole,
+                               atol=2e-5)
+
+
+# -- the residual path -----------------------------------------------------------
+
+def test_h_res_is_doubly_stochastic():
+    # 20 rounds reach 1e-4 where the logits' spread is about 1, which is
+    # what the configuration's leaves give (chipbench/configs/xing4-*.json)
+    logits = jax.random.normal(jax.random.PRNGKey(8), (50, 4, 4))
+    m = np.asarray(sinkhorn(logits, 20, 1e-6, (-30.0, 30.0)))
+    assert (m > 0).all()
+    np.testing.assert_allclose(m.sum(-1), 1.0, atol=1e-4)
+    np.testing.assert_allclose(m.sum(-2), 1.0, atol=1e-4)
+    # and as the block computes it, from streams
+    hc = HyperConnection(16, 4)
+    p = hc.init(jax.random.PRNGKey(9))
+    p["a_res"], p["b_res"] = jnp.float32(1.0), jax.random.normal(
+        jax.random.PRNGKey(10), (4, 4))
+    xs = jax.random.normal(jax.random.PRNGKey(12), (2, 5, 4, 16))
+    h_pre, h_post, h_res = hc.coeffs(p, xs)
+    assert h_res.shape == (2, 5, 4, 4) and h_pre.shape == (2, 5, 4)
+    np.testing.assert_allclose(np.asarray(h_res).sum(-1), 1.0, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h_res).sum(-2), 1.0, atol=1e-4)
+    assert 0.05 < np.asarray(h_res).std()        # not uniform
+    assert np.asarray(h_res).max() < 0.99        # not a permutation
+    assert ((np.asarray(h_pre) > 0) & (np.asarray(h_pre) < 1)).all()
+    assert ((np.asarray(h_post) > 0) & (np.asarray(h_post) < 2)).all()
+
+
+def test_yarn_frequencies_against_values_computed_by_hand():
+    """rope 64, theta 10000, factor 64, original 4096, beta 32/1: the
+    ramp runs from dimension 10 to 23. i = 3 is untouched, i = 30 is
+    divided by 64, i = 16 is blended 6/13 of the way."""
+    f = np.asarray(yarn_inv_freq(64, 10000.0, factor=64, original_max=4096,
+                                 beta_fast=32, beta_slow=1))
+    lo = math.floor(64 * math.log(4096 / (2 * math.pi * 32))
+                    / (2 * math.log(10000)))
+    hi = math.ceil(64 * math.log(4096 / (2 * math.pi * 1))
+                   / (2 * math.log(10000)))
+    assert (lo, hi) == (10, 23)
+    plain = lambda i: 10000.0 ** (-2 * i / 64)
+    np.testing.assert_allclose(f[3], 0.421696503, rtol=1e-5)     # plain(3)
+    np.testing.assert_allclose(f[3], plain(3), rtol=1e-5)
+    np.testing.assert_allclose(f[30], plain(30) / 64, rtol=1e-5)
+    np.testing.assert_allclose(f[30], 2.7788e-06, rtol=1e-3)
+    r = (16 - 10) / 13
+    np.testing.assert_allclose(f[16], plain(16) * (r / 64 + 1 - r), rtol=1e-5)
+    np.testing.assert_allclose(f[16], 0.0054565, rtol=1e-3)
+    assert abs(yarn_mscale(64, 1) - 1.41589) < 1e-4
+    assert yarn_mscale(1, 1) == 1.0
+
+
+# -- what has not been carried over says so by name ----------------------------
+
+def test_refusals_for_latent_blocks(tiny):
+    model, params = tiny
+    with pytest.raises(LatentPagesUnsupported, match="SlotPool"):
+        SlotPool(model, 2, 32)
+    with pytest.raises(LatentPagesUnsupported, match="SlotPool"):
+        InferenceEngine(model, params, EngineConfig(n_slots=2, max_len=32))
+    for kv in ("q8", "q4"):
+        with pytest.raises(LatentPagesUnsupported, match="quantized pages"):
+            InferenceEngine(model, params, EngineConfig(
+                paged=True, n_slots=2, max_len=32, kv_dtype=kv))
+    draft = models.TransformerLM(vocab=211, dim=32, n_layers=1, n_heads=2,
+                                 max_seq=64)
+    with pytest.raises(LatentPagesUnsupported, match="serve/spec"):
+        InferenceEngine(model, params, EngineConfig(
+            paged=True, n_slots=2, max_len=32, spec_decode=True,
+            draft_model=draft, draft_params=draft.init(jax.random.PRNGKey(0))))
+    pool = PagedSlotPool(model, 2, 32, page_len=PAGE, n_pages=16)
+    with pytest.raises(LatentPagesUnsupported, match="serve/disagg"):
+        pool.extract(0)
+    with pytest.raises(LatentPagesUnsupported, match="serve/disagg"):
+        pool.adopt(0, 4, [], [])
+    from distributed_pytorch_tpu.serve.disagg import DecodeEngine
+    with pytest.raises(LatentPagesUnsupported, match="serve/disagg"):
+        DecodeEngine(model, params, None, None, n_slots=2, max_len=32,
+                     page_len=PAGE, n_pages=16)
+
+
+def test_the_fixed_block_is_untouched_by_the_new_keywords():
+    """A model built without them has the fixed block, its parameter
+    names and no stream axis."""
+    from distributed_pytorch_tpu.nn.attention import TransformerBlock
+    m = models.TransformerLM(vocab=97, dim=32, n_layers=2, n_heads=4,
+                             n_kv_heads=2, max_seq=32, pos="rope")
+    assert all(type(b) is TransformerBlock for b in m.blocks)
+    p = m.init(jax.random.PRNGKey(0))
+    assert set(p["blocks"][0]) == {"ln1", "attn", "ln2", "fc1", "fc2"}
+    assert m.streams == 0 and m.streams_in(jnp.ones((1, 2, 32))).ndim == 3
+    # a block made of parts with multi-head attention keeps K and V pages
+    parts = models.TransformerLM(vocab=97, dim=32, n_layers=2, n_heads=4,
+                                 n_kv_heads=2, max_seq=32, pos="rope",
+                                 norm="rms", ffn_dim=48)
+    pool = PagedSlotPool(parts, 2, 32, page_len=PAGE, n_pages=16)
+    assert not pool.latent and len(pool.v_pages) == 2
+    pp = parts.init(jax.random.PRNGKey(1))
+    prompt = np.arange(7, dtype=np.int32)
+    toks, rows, _ = serve_through_pool(parts, pp, pool, prompt, 0, 6)
+    ref = full_logits(parts, pp, np.concatenate([prompt, toks]))[6:12]
+    np.testing.assert_allclose(rows, ref, atol=2e-4, rtol=0)
