@@ -172,19 +172,20 @@ class MultiHeadAttention(Module):
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.idx[:, None, None])
         with jax.named_scope("page_write"):
-            kp = k_pages.at[ctx.dest, :, ctx.wo].set(
-                hk[:, :, 0, :].astype(k_pages.dtype), mode="drop")
-            vp = v_pages.at[ctx.dest, :, ctx.wo].set(
-                hv[:, :, 0, :].astype(v_pages.dtype), mode="drop")
+            kp = write_rows(k_pages, ctx.dest, ctx.wo, hk[:, :, 0, :])
+            vp = write_rows(v_pages, ctx.dest, ctx.wo, hv[:, :, 0, :])
         if ctx.blockwise:
-            # the page gather lives inside the block loop; hk/hv are
+            # the loop: the page gather lives inside it; hk/hv are
             # re-selected at the write position per block — identity
             # for active rows (already scattered), and gives inactive
             # rows decode_step_slots' exact value semantics (their
-            # discarded logits still see "their" key)
+            # discarded logits still see "their" key). On a TPU the
+            # kernel instead: active rows read their key from the pool,
+            # inactive rows are skipped (ops/decode_attention.py)
             o = paged_decode_attention(hq, kp, vp, ctx.tables, ctx.idx,
                                        hk, hv, scale=scale,
-                                       page_len=ctx.page_len)
+                                       page_len=ctx.page_len,
+                                       active=ctx.active)
         else:
             # logical rows: gather the updated pool, then re-select the
             # new key at the write position
@@ -204,10 +205,10 @@ class MultiHeadAttention(Module):
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.positions)
         with jax.named_scope("page_write"):
-            kp = k_pages.at[ctx.dest, :, ctx.dest_off].set(
-                jnp.moveaxis(hk[0], 1, 0).astype(k_pages.dtype), mode="drop")
-            vp = v_pages.at[ctx.dest, :, ctx.dest_off].set(
-                jnp.moveaxis(hv[0], 1, 0).astype(v_pages.dtype), mode="drop")
+            kp = write_rows(k_pages, ctx.dest, ctx.dest_off,
+                            jnp.moveaxis(hk[0], 1, 0))
+            vp = write_rows(v_pages, ctx.dest, ctx.dest_off,
+                            jnp.moveaxis(hv[0], 1, 0))
         # prefix keys from the (updated) pool; tail keys inline — the
         # tail pages were just written, but using the in-register tail
         # avoids a second gather and keeps the math identical to
@@ -219,6 +220,24 @@ class MultiHeadAttention(Module):
         return self.project_out(
             params, prefix_tail_attention(hq, hk, hv, pref_k, pref_v,
                                           ctx.mask, scale)), (kp, vp)
+
+
+def write_rows(pool, dest, wo, rows):
+    """``pool.at[dest, :, wo].set(rows, mode="drop")`` for a decode
+    step's (B, Hkv, Dh) rows, written as a scatter of Dh-wide rows into
+    the pool seen as (n_pages * Hkv * page_len, Dh). The values are the
+    same; the form is the one XLA's TPU compiler updates in place. For
+    the (page, :, offset) form it moves the whole donated pool to a
+    layout with the head axis next to Dh and back, every layer, every
+    step (two copies of 64 MB a pool: PERF.md, Findings, PR 29). A
+    dropped row's ``dest`` is ``n_pages``: its flat index lies past the
+    end for every head."""
+    n_pages, hkv, page_len, dh = pool.shape
+    flat = (dest[:, None] * hkv + jnp.arange(hkv)[None, :]) * page_len \
+        + wo[:, None]                                      # (B, Hkv)
+    return pool.reshape(-1, dh).at[flat.reshape(-1)].set(
+        rows.reshape(-1, dh).astype(pool.dtype), mode="drop") \
+        .reshape(pool.shape)
 
 
 def gather_pages(pool, tables):

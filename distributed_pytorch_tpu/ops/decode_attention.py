@@ -34,6 +34,18 @@ Numerics contract (the mixed-precision guard, docs/compute.md):
   and ``preferred_element_type=float32`` (the FlashAttention-2 recipe:
   bf16 on the MXU's native path, f32 accumulation).
 
+**On a TPU an exact K/V page pool takes one Mosaic kernel a layer**
+(``ops/paged_attention_kernel.py``) in place of the paged loop: each
+row's resident pages copied from the pool in place, many pages a trip,
+each row walking its own length, rows that hold nothing skipped.
+:func:`paged_decode_attention` picks it from what it can see in its
+input (:func:`_kernel_interpret`: a TPU backend, one device, exact K
+and V pages, not latent, whole-lane heads, whole-tile pages, the rows'
+``active`` mask); everything else — the CPU, the latent pool, the
+quantized pools, head size 64 — runs the loop below exactly as it
+stands, which is also the reference the kernel is tested against. The
+contract above holds in both.
+
 Every decode front door routes here (``models/generate.py``:
 ``decode_step``, ``decode_step_slots``, ``decode_step_slots_paged``),
 so ``serve/cache.py``, ``serve/pages/``, and both the monolithic and
@@ -49,11 +61,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import paged_attention_kernel
 from .flash_attention import _MASK
 
 __all__ = ["DECODE_BLOCK", "blockwise_decode_attention",
-           "dense_decode_attention", "paged_decode_attention",
-           "resident_blocks"]
+           "dense_decode_attention", "kernel_traces",
+           "paged_decode_attention", "resident_blocks"]
 
 #: Default block length for CONTIGUOUS caches (``decode_step`` /
 #: ``decode_step_slots``); paged pools use their ``page_len``. 128 =
@@ -184,12 +197,43 @@ def blockwise_decode_attention(hq, k, v, idx, *, scale,
     return _finish(m, l, acc, v.dtype).reshape(b, h, 1, dh)
 
 
+#: How many times :func:`paged_decode_attention` took the kernel, counted
+#: where it is traced: a pool reads it around the trace of its decode
+#: program (``decode_attention_kernel_layers``).
+_kernel_traces = 0
+
+
+def kernel_traces() -> int:
+    return _kernel_traces
+
+
+def _kernel_interpret(interpret: Optional[bool]) -> Optional[bool]:
+    """The rule's one look at the backend: ``False`` (compile the
+    kernel) on a TPU, ``None`` (no kernel: the loop) anywhere else.
+    ``interpret`` is a test's own answer, as in
+    ``flash_attention._interpret_default``: the Pallas interpreter is
+    never a default, a CPU run takes the loop."""
+    if interpret is not None:
+        return interpret
+    return False if jax.default_backend() == "tpu" else None
+
+
+def _on_one_device(x) -> bool:
+    """GSPMD cannot partition a Mosaic call (``flash_attention
+    ._mesh_island``): a program traced over a mesh of several devices
+    keeps the loop, which it partitions like any other JAX code."""
+    mesh = jax.typeof(x).sharding.mesh
+    return bool(mesh.empty or mesh.size == 1 or mesh.manual_axes)
+
+
 @jax.named_scope("decode_attention")
 def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
                            new_v, *, scale, page_len: int,
                            k_scales=None, v_scales=None,
                            k_tail=None, v_tail=None,
-                           latent_width: Optional[int] = None):
+                           latent_width: Optional[int] = None,
+                           active=None,
+                           interpret: Optional[bool] = None):
     """Single-token attention over a PAGED pool, one page per step.
 
     hq: (B, H, 1, Dh); k_pages/v_pages: (n_pages[+1], Hkv, page_len,
@@ -224,7 +268,27 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
     values are the first ``latent_width`` of the same gathered page.
     ``v_pages`` and ``new_v`` are None; the result is (B, H, 1,
     ``latent_width``).
+
+    **The kernel** (``ops/paged_attention_kernel.py``). ``active`` (B,)
+    bool says which rows wrote this step's K/V into the pool. Given it,
+    an exact pool that ``paged_attention_kernel.kernel_fits``, on one
+    TPU (``interpret=True``: a test's interpreter), is read by one Mosaic
+    kernel instead of the loop: each active row over its own pages, the
+    inactive rows zeros (their logits are discarded; the loop gives them
+    ``new_k`` / ``new_v`` instead). Decided here, at trace time, from
+    the arguments alone.
     """
+    if (active is not None and k_scales is None and latent_width is None
+            and paged_attention_kernel.kernel_fits(k_pages, v_pages,
+                                                   page_len)
+            and _on_one_device(hq)):
+        mode = _kernel_interpret(interpret)
+        if mode is not None:
+            global _kernel_traces
+            _kernel_traces += 1
+            return paged_attention_kernel.paged_attention(
+                hq, k_pages, v_pages, tables, idx, active, scale=scale,
+                page_len=page_len, interpret=mode)
     b, h, _, dh = hq.shape
     hkv = k_pages.shape[1]
     g = h // hkv

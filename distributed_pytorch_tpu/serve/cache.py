@@ -45,6 +45,9 @@ class CompileCounts:
     zero-recompile claim, asserted."""
 
     decode: int = 0
+    #: layers of the decode program, as last traced, whose attention
+    #: took the Mosaic kernel (ops/paged_attention_kernel.py)
+    decode_kernel_layers: int = 0
     prefill: Dict[int, int] = field(default_factory=dict)  # bucket -> n
     sample: int = 0
     verify: Dict[int, int] = field(default_factory=dict)   # k+1 -> n

@@ -428,6 +428,7 @@ class DecodeEngine:
                "active_slots": len(self._running),
                "pending_handoffs": len(self._pending),
                "decode_compiles": c.decode,
+               "decode_attention_kernel_layers": c.decode_kernel_layers,
                "sample_compiles": c.sample,
                "prefill_compiles": dict(c.prefill),   # must stay {}
                "pages": self.pool.page_stats()}

@@ -421,6 +421,7 @@ class InferenceEngine:
                "active_slots": len(self._running),
                "n_slots": self.config.n_slots,
                "decode_compiles": c.decode,
+               "decode_attention_kernel_layers": c.decode_kernel_layers,
                "prefill_compiles": dict(c.prefill),
                "sample_compiles": c.sample,
                # every program XLA built in this process, whoever asked

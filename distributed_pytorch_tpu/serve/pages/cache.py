@@ -45,6 +45,7 @@ from ...models.generate import (decode_step_slots_paged,
                                 prefill_partial_paged, refuse_latent,
                                 spec_commit_slots_paged,
                                 spec_verify_slots_paged)
+from ...ops.decode_attention import kernel_traces
 from ...runtime import faults
 from ..cache import CompileCounts, greedy_tokens, named_program
 from ..types import AdmissionRejected
@@ -163,23 +164,33 @@ class PagedSlotPool:
 
     # -- jitted programs ---------------------------------------------------
 
+    def _step(self, params, k_pages, v_pages, tables, lengths, tokens,
+              active, **kw):
+        """The decode step inside each of the three decode programs,
+        counted where it is traced: the compile, and how many of its
+        layers' attention took the Mosaic kernel."""
+        self.compiles.decode += 1          # trace-time only
+        before = kernel_traces()
+        out = decode_step_slots_paged(
+            self.model, params, k_pages, v_pages, tables, lengths, tokens,
+            active, page_len=self.page_len, **kw)
+        self.compiles.decode_kernel_layers = kernel_traces() - before
+        return out
+
     def _decode(self, params, k_pages, v_pages, tables, lengths, tokens,
                 active):
-        self.compiles.decode += 1          # trace-time only
-        logits, *pool = decode_step_slots_paged(
-            self.model, params, k_pages, v_pages, tables, lengths, tokens,
-            active, page_len=self.page_len)
+        logits, *pool = self._step(params, k_pages, v_pages, tables,
+                                   lengths, tokens, active)
         return (greedy_tokens(logits), logits, *pool)
 
     def _decode_moe(self, params, k_pages, v_pages, counts, tables,
                     lengths, tokens, active):
         """The decode program of a model with expert layers: the same
         step, and the layers' counts added to ``counts`` on the device."""
-        self.compiles.decode += 1          # trace-time only
         per_layer = []
-        logits, *pool = decode_step_slots_paged(
-            self.model, params, k_pages, v_pages, tables, lengths, tokens,
-            active, page_len=self.page_len, moe_stats=per_layer)
+        logits, *pool = self._step(params, k_pages, v_pages, tables,
+                                   lengths, tokens, active,
+                                   moe_stats=per_layer)
         c = jnp.stack(per_layer)                           # (layers, 3)
         counts = jnp.stack([counts[0] + jnp.sum(c[:, 0]),
                             counts[1] + jnp.sum(c[:, 1]),
@@ -189,12 +200,10 @@ class PagedSlotPool:
 
     def _decode_q(self, params, k_pages, v_pages, k_scales, v_scales,
                   k_tail, v_tail, tables, lengths, tokens, active):
-        self.compiles.decode += 1          # trace-time only
-        logits, *pool = decode_step_slots_paged(
-            self.model, params, k_pages, v_pages, tables, lengths, tokens,
-            active, page_len=self.page_len, kv_bits=self.quant_bits,
-            k_scales=k_scales, v_scales=v_scales, k_tail=k_tail,
-            v_tail=v_tail)
+        logits, *pool = self._step(
+            params, k_pages, v_pages, tables, lengths, tokens, active,
+            kv_bits=self.quant_bits, k_scales=k_scales, v_scales=v_scales,
+            k_tail=k_tail, v_tail=v_tail)
         return (greedy_tokens(logits), logits, *pool)
 
     def _verify(self, params, k_pages, v_pages, tables, lengths,
@@ -757,6 +766,8 @@ class PagedSlotPool:
     def page_stats(self) -> Dict:
         return {"n_pages": self.n_pages,
                 "page_len": self.page_len,
+                "decode_attention_kernel_layers":
+                    self.compiles.decode_kernel_layers,
                 "kv_dtype": self.kv_dtype,
                 "kv_bits": self.kv_bits(),
                 "kv_pool_bytes": self.kv_pool_bytes(),
