@@ -35,12 +35,11 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.attention import dense_attention, prefix_tail_attention
-from ..nn.attention import gather_pages as _gather_pages
-from ..nn.paged import DecodeCtx, PrefillCtx
+from ..nn.attention import dense_attention
+from ..nn.paged import (DecodeCtx, LatentPagesUnsupported,  # noqa: F401
+                        PrefillCtx, VerifyCtx, latent_unsupported)
 from ..ops.decode_attention import (blockwise_decode_attention,
-                                    dense_decode_attention,
-                                    paged_decode_attention)
+                                    dense_decode_attention)
 from .transformer import TransformerLM
 
 Params = Dict[str, Any]
@@ -323,82 +322,60 @@ def decode_step_slots(model: TransformerLM, params: Params, ks, vs,
     return model.project_vocab(params, x)[:, 0], new_k, new_v
 
 
-def decode_step_slots_paged(model: TransformerLM, params: Params,
-                            k_pages, v_pages, tables, lengths, tokens,
-                            active, *, page_len: int,
-                            blockwise: bool = True, kv_bits=None,
-                            k_scales=None, v_scales=None,
-                            k_tail=None, v_tail=None, moe_stats=None
-                            ) -> Tuple[jnp.ndarray, list, list]:
+def decode_step_slots_paged(model: TransformerLM, params: Params, state,
+                            tables, lengths, tokens, active, *,
+                            page_len: int, blockwise: bool = True,
+                            moe_stats=None) -> Tuple[jnp.ndarray, list]:
     """One decode step over a PAGED slot pool (``serve/pages/``).
 
-    **The block owns its page layout.** On the exact path each layer's
-    arrays go to the block's own ``decode_paged`` (``nn/paged.py``):
-    multi-head attention keeps ``k_pages[i]`` and ``v_pages[i]`` as
-    described below; latent attention keeps ONE array a layer, the
-    entries ``[c | k_r]``, in ``k_pages[i]`` with ``v_pages`` an empty
-    list, and attends in its absorbed form. Under hyper-connections the
-    residual streams travel as (B, 1, streams, D). ``moe_stats``: a list
-    that every expert layer appends its counts (3,) to.
+    ``state`` is a list, one page store a layer, as each block's
+    attention module made it (``attn.make_pages``, ``nn/paged.py``):
+    exact K and V, quantized K and V, or latent attention's one array of
+    ``[c | k_r]`` entries. This function never looks inside one: each
+    layer's store goes to the block's own ``decode_paged``, which writes
+    this step's entry and attends, and comes back written. Under
+    hyper-connections the residual streams travel as (B, 1, streams, D).
+    ``moe_stats``: a list that every expert layer appends its counts
+    (3,) to.
 
     The paged counterpart of :func:`decode_step_slots`: instead of each
-    slot owning a contiguous (max_len) cache row, K/V live in a shared
-    block pool — per layer ``(n_pages, Hkv, page_len, Dh)`` — and each
-    slot addresses its pages through ``tables`` (B, P) int32. Slots can
-    therefore SHARE full pages (a refcounted common prefix is resident
-    once); sharing is safe because shared pages are immutable — decode
-    only ever writes each slot's private tail page.
+    slot owning a contiguous (max_len) cache row, the entries live in a
+    shared block pool and each slot addresses its pages through
+    ``tables`` (B, P) int32. Slots can therefore SHARE full pages (a
+    refcounted common prefix is resident once); sharing is safe because
+    shared pages are immutable — decode only ever writes each slot's
+    private tail page.
 
     Per-row math is exactly :func:`decode_step_slots`'s: the row's
     logical cache is the page gather (positions ``j`` at page
-    ``tables[b, j // page_len]`` offset ``j % page_len``), the new K/V
-    is written at ``lengths[b]`` (a pool scatter into the slot's tail
-    page; ``active=False`` rows scatter out of bounds and are dropped,
-    so a freed slot's stale table cannot be corrupted), and the position
-    mask exposes ``<= lengths[b]``. ``tables``/``lengths``/``tokens``/
+    ``tables[b, j // page_len]`` offset ``j % page_len``), the new entry
+    is written at ``lengths[b]`` (into the slot's tail page;
+    ``active=False`` rows are routed out of bounds and dropped, so a
+    freed slot's stale table cannot be corrupted), and the position mask
+    exposes ``<= lengths[b]``. ``tables``/``lengths``/``tokens``/
     ``active`` are all traced — ONE compiled program serves every
     request mix and every page-table state.
 
     Attention runs page-blockwise by default
-    (:func:`..ops.decode_attention.paged_decode_attention`): the page
-    gather moved INSIDE the online-softmax block loop, whose traced
-    trip count is the resident page count — per-token cost scales with
-    ``max(lengths)``, not ``tables.shape[1] * page_len``, and dead
-    pages past every slot's length are never even gathered.
-    ``blockwise=False`` keeps the dense full-table gather + softmax
-    (the reference the contract tests pin the kernel against).
+    (:mod:`..ops.decode_attention`): the page gather moved INSIDE the
+    online-softmax block loop, whose traced trip count is the resident
+    page count — per-token cost scales with ``max(lengths)``, not
+    ``tables.shape[1] * page_len``, and dead pages past every slot's
+    length are never even gathered. ``blockwise=False`` keeps the dense
+    full-table gather + softmax (the reference the contract tests pin
+    the kernel against).
 
-    Returns ``(logits (B, vocab), new_k_pages, new_v_pages)``; host-side
-    page allocation (growing a table at page boundaries) and length
+    Returns ``(logits (B, vocab), new state)``; host-side page
+    allocation (growing a table at page boundaries) and length
     bookkeeping belong to the caller.
-
-    **Quantized resident pool** (``kv_bits`` = 8 | 4; docs/serving.md):
-    ``k_pages``/``v_pages`` hold block-quantized int pages and
-    ``k_scales``/``v_scales``/``k_tail``/``v_tail`` are per-layer lists
-    of their scales and per-slot f32 tail buffers. The step's K/V is
-    written to the slot's TAIL buffer (exact f32); when the write lands
-    on the page's last position the whole tail page is quantized ONCE —
-    from exact values, on the wire block grid — and scattered into the
-    int pool with its scales (everything inside this one program, so
-    the compile discipline is unchanged). Attention dequantizes inside
-    the page-gather loop and overlays the exact tail page. Returns the
-    extended tuple ``(logits, new_k_pages, new_v_pages, new_k_scales,
-    new_v_scales, new_k_tail, new_v_tail)``. Requires ``blockwise=True``
-    (the dense fallback would gather the whole int pool undequantized).
     """
-    if kv_bits is not None and not blockwise:
-        raise ValueError("quantized paged KV (kv_bits) requires the "
-                         "blockwise decode path")
-    if kv_bits is not None:
-        refuse_latent(model, "quantized pages (kv_dtype q8/q4)")
     idx = lengths
-    n_pages = k_pages[0].shape[0]
+    n_pages = state[0].n_pages
     width = tables.shape[1] * page_len
     x = model.tok.apply(params["tok"], tokens[:, None])       # (B,1,D)
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], idx[:, None])
     x = model.streams_in(x)
-    scale = 1.0 / math.sqrt(model.dim // model.n_heads)
     pos_mask = jnp.arange(width)[None, :] <= idx[:, None]
     write_mask = (jnp.arange(width)[None, :]
                   == idx[:, None])[:, None, :, None]          # (B,1,W,1)
@@ -408,141 +385,59 @@ def decode_step_slots_paged(model: TransformerLM, params: Params,
                              axis=1)[:, 0]
     wo = idx % page_len
     dest = jnp.where(active, wp, n_pages)
-    if kv_bits is not None:
-        from ..ops.quant import pack_page_nibbles, quantize_page_blocks
-        bsz = tokens.shape[0]
-        n_tail = k_tail[0].shape[0]
-        # tail-buffer write target (one exact f32 page per slot);
-        # inactive rows are dropped exactly like the pool scatter
-        dest_t = jnp.where(active, jnp.arange(bsz), n_tail)
-        # page completion: this write fills position page_len - 1 — the
-        # ONE moment a page's values are quantized (from exact f32)
-        completed = jnp.logical_and(active, wo == page_len - 1)
-        dest_q = jnp.where(completed, wp, n_pages)
-
-    new_kp, new_vp = [], []
-    new_ks, new_vs, new_kt, new_vt = [], [], [], []
     ctx = DecodeCtx(tables=tables, idx=idx, dest=dest, wo=wo, active=active,
                     pos_mask=pos_mask, write_mask=write_mask,
                     page_len=page_len, blockwise=blockwise,
                     moe_stats=moe_stats)
+    state = list(state)
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
-            p = params["blocks"][i]
-            if kv_bits is None:
-                x, pages = blk.decode_paged(
-                    p, x, _layer_pages(k_pages, v_pages, i), ctx)
-                new_kp.append(pages[0])
-                new_vp.extend(pages[1:])
-                continue
-            hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                              blk.ln1.apply(p["ln1"], x))
-            hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
-            with jax.named_scope("page_write"):
-                kt = k_tail[i].at[dest_t, :, wo].set(
-                    hk[:, :, 0, :].astype(jnp.float32), mode="drop")
-                vt = v_tail[i].at[dest_t, :, wo].set(
-                    hv[:, :, 0, :].astype(jnp.float32), mode="drop")
-                qk, sk = quantize_page_blocks(kt, kv_bits)  # (B,Hkv,L,Dh)
-                qv, sv = quantize_page_blocks(vt, kv_bits)
-                if kv_bits == 4:
-                    qk, qv = pack_page_nibbles(qk), pack_page_nibbles(qv)
-                kp = k_pages[i].at[dest_q].set(qk, mode="drop")
-                vp = v_pages[i].at[dest_q].set(qv, mode="drop")
-                ks_i = k_scales[i].at[dest_q].set(sk, mode="drop")
-                vs_i = v_scales[i].at[dest_q].set(sv, mode="drop")
-                new_ks.append(ks_i)
-                new_vs.append(vs_i)
-                new_kt.append(kt)
-                new_vt.append(vt)
-            new_kp.append(kp)
-            new_vp.append(vp)
-            o = paged_decode_attention(hq, kp, vp, tables, idx,
-                                       hk, hv, scale=scale,
-                                       page_len=page_len,
-                                       k_scales=ks_i, v_scales=vs_i,
-                                       k_tail=kt, v_tail=vt)
-            x = x + blk.attn.project_out(p["attn"], o)
-            x = x + blk.mlp(p, x)
+            x, state[i] = blk.decode_paged(params["blocks"][i], x, state[i],
+                                           ctx)
 
     x = model.ln_f.apply(params["ln_f"], model.streams_out(x))
-    logits = model.project_vocab(params, x)[:, 0]
-    if kv_bits is None:
-        return logits, new_kp, new_vp
-    return logits, new_kp, new_vp, new_ks, new_vs, new_kt, new_vt
-
-
-def _layer_pages(k_pages, v_pages, i):
-    """Layer ``i``'s page arrays as its block keeps them: (K, V), or the
-    one latent array where ``v_pages`` is empty."""
-    return (k_pages[i], v_pages[i]) if v_pages else (k_pages[i],)
+    return model.project_vocab(params, x)[:, 0], state
 
 
 def refuse_latent(model, what: str):
-    """Latent blocks keep one exact array a layer; what has not been
-    carried over to that layout says so by name."""
+    """For a path that keeps no page stores (the contiguous
+    ``SlotPool``): the paged path's stores refuse for themselves
+    (``nn/latent.py`` ``LatentPages``)."""
     if getattr(model, "attention", "mha") == "latent":
-        raise LatentPagesUnsupported(
-            f"{what} cannot hold latent (MLA) pages yet: a latent block "
-            "keeps one array of [c | k_r] entries a layer, served only by "
-            "the exact paged pool (InferenceEngine(paged=True), "
-            "kv_dtype='f32')")
+        raise latent_unsupported(what)
 
 
-class LatentPagesUnsupported(NotImplementedError):
-    """A serving path that has no layout for latent-attention blocks."""
-
-
-def prefill_partial_paged(model: TransformerLM, params: Params,
-                          k_pages, v_pages, table_row, tokens, offset,
-                          true_len, *, page_len: int, kv_bits=None,
-                          k_scales=None, v_scales=None,
-                          k_tail=None, v_tail=None, slot=None,
-                          moe_stats=None
-                          ) -> Tuple[jnp.ndarray, list, list]:
+def prefill_partial_paged(model: TransformerLM, params: Params, state,
+                          table_row, tokens, offset, true_len, slot=0, *,
+                          page_len: int, moe_stats=None
+                          ) -> Tuple[jnp.ndarray, list]:
     """Prefill the TAIL of a prompt into pool pages, attending over a
     page-resident shared prefix (``serve/pages/``).
 
     ``tokens`` (1, S) is the right-padded tail — the prompt MINUS its
-    ``offset`` prefix tokens whose K/V are already resident in the pages
-    ``table_row`` (P,) names (``offset`` is page-aligned: only FULL
-    pages are ever shared, so the tail always starts at a page
-    boundary). ``offset`` and ``true_len`` (the real tail length, >= 1)
-    are both TRACED — one compile per padded tail bucket serves cold
-    (``offset == 0``), partially shared, and fully shared admissions
-    alike.
+    ``offset`` prefix tokens whose entries are already resident in the
+    pages ``table_row`` (P,) names (``offset`` is page-aligned: only
+    FULL pages are ever shared, so the tail always starts at a page
+    boundary). ``offset``, ``true_len`` (the real tail length, >= 1) and
+    ``slot`` (the pool row admitted to: a quantized store keeps the
+    prompt's partial last page there) are all TRACED — one compile per
+    padded tail bucket serves cold (``offset == 0``), partially shared,
+    and fully shared admissions alike.
 
     Tail queries run at global positions ``offset + i`` (rope/learned
     positions included) and attend over [shared prefix pages | tail]:
     prefix keys are gathered from the pool and masked to positions
     ``< offset``; the tail is causal, so its pad columns are inert
-    exactly as in :func:`prefill_partial`. Tail K/V are scattered into
+    exactly as in :func:`prefill_partial`. Tail entries are written into
     the slot's own pages (pad positions route out of bounds and drop);
     the shared prefix pages are never written.
 
-    Returns ``(logits (1, vocab) at the last real position,
-    new_k_pages, new_v_pages)``.
-
-    On the exact path each layer's arrays go to the block's own
-    ``prefill_paged`` (``nn/paged.py``; a latent block keeps one array a
-    layer in ``k_pages`` with ``v_pages`` empty, see
-    :func:`decode_step_slots_paged`); the tail's pad rows are left out
-    of an expert layer's dispatch.
-
-    **Quantized resident pool** (``kv_bits`` = 8 | 4; docs/serving.md):
-    tail K/V that COMPLETE a page (a full ``page_len`` chunk of the
-    tail within ``true_len``) are quantized once — from exact f32, on
-    the wire block grid — and scattered into the int pool with their
-    scales; the partial last page goes EXACT into the per-slot f32
-    tail buffer ``k_tail[.][slot]``/``v_tail[.][slot]`` (stale region
-    past ``true_len`` zeroed), where decode continues writing it. The
-    shared prefix is dequantized for the tail's attention; the tail
-    itself attends in-register exact f32, so a cold prompt's logits and
-    written values see no quantization at admission. Returns the
-    extended tuple ``(logits, new_k_pages, new_v_pages, new_k_scales,
-    new_v_scales, new_k_tail, new_v_tail)``."""
+    Each layer's store (``state``, see :func:`decode_step_slots_paged`)
+    goes to the block's own ``prefill_paged``; the tail's pad rows are
+    left out of an expert layer's dispatch. Returns ``(logits (1, vocab)
+    at the last real position, new state)``."""
     b, s = tokens.shape
-    n_pages = k_pages[0].shape[0]
+    n_pages = state[0].n_pages
     width = table_row.shape[0] * page_len
     offset = jnp.asarray(offset, jnp.int32)
     true_len = jnp.asarray(true_len, jnp.int32)
@@ -551,7 +446,6 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], positions)
     x = model.streams_in(x)
-    scale = 1.0 / math.sqrt(model.dim // model.n_heads)
     # attention mask over [prefix pages | tail]: prefix columns valid
     # below offset, tail columns causal (pad tail is causally inert)
     prefix_mask = jnp.broadcast_to((jnp.arange(width) < offset)[None, :],
@@ -565,107 +459,20 @@ def prefill_partial_paged(model: TransformerLM, params: Params,
                                    table_row.shape[0] - 1)]
     dest_off = positions % page_len
     dest = jnp.where(jnp.arange(s) < true_len, dest_page, n_pages)
-    if kv_bits is not None:
-        refuse_latent(model, "quantized pages (kv_dtype q8/q4)")
-        from ..ops.quant import (dequantize_page_blocks,
-                                 page_block_map, pack_page_nibbles,
-                                 quantize_page_blocks,
-                                 unpack_page_nibbles)
-        h_kv = getattr(model, "n_kv_heads", model.n_heads)
-        dh = model.dim // model.n_heads
-        bmap = page_block_map(h_kv, page_len, dh)
-        slot = jnp.asarray(slot, jnp.int32)
-        # the tail starts at a page boundary (offset is page-aligned),
-        # so tail chunk c IS the slot's page offset//page_len + c; the
-        # chunk is complete — quantizable — iff it lies within true_len
-        n_chunks = s // page_len
-        r = jnp.arange(page_len)
-        # partial-page span (tail coordinates): the positions past the
-        # last complete page, exact f32 into the slot's tail buffer
-        floor = (offset + true_len) // page_len * page_len - offset
-        t_src = jnp.clip(floor + r, 0, s - 1)
-        t_valid = ((floor + r) < true_len)[None, :, None]
-
-    new_kp, new_vp = [], []
-    new_ks, new_vs, new_kt, new_vt = [], [], [], []
     ctx = PrefillCtx(table_row=table_row, positions=positions, offset=offset,
+                     true_len=true_len, slot=jnp.asarray(slot, jnp.int32),
                      dest=dest, dest_off=dest_off, mask=mask,
                      row_mask=jnp.arange(s) < true_len, width=width,
                      moe_stats=moe_stats)
+    state = list(state)
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
-            p = params["blocks"][i]
-            if kv_bits is None:
-                x, pages = blk.prefill_paged(
-                    p, x, _layer_pages(k_pages, v_pages, i), ctx)
-                new_kp.append(pages[0])
-                new_vp.extend(pages[1:])
-                continue
-            hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                              blk.ln1.apply(p["ln1"], x))
-            hq, hk = blk.attn.maybe_rope(hq, hk, positions)
-            with jax.named_scope("page_write"):
-                kp, vp = k_pages[i], v_pages[i]
-                ks_i, vs_i = k_scales[i], v_scales[i]
-                for c in range(n_chunks):
-                    lo = c * page_len
-                    ck = hk[0, :, lo:lo + page_len, :].astype(jnp.float32)
-                    cv = hv[0, :, lo:lo + page_len, :].astype(jnp.float32)
-                    qk, sk = quantize_page_blocks(ck, kv_bits)
-                    qv, sv = quantize_page_blocks(cv, kv_bits)
-                    if kv_bits == 4:
-                        qk, qv = (pack_page_nibbles(qk),
-                                  pack_page_nibbles(qv))
-                    # incomplete chunks route out of bounds and drop; the
-                    # page index gather clamps harmlessly for them
-                    comp = (lo + page_len) <= true_len
-                    dpi = jnp.where(
-                        comp,
-                        table_row[jnp.clip(offset // page_len + c, 0,
-                                           table_row.shape[0] - 1)],
-                        n_pages)
-                    kp = kp.at[dpi].set(qk, mode="drop")
-                    vp = vp.at[dpi].set(qv, mode="drop")
-                    ks_i = ks_i.at[dpi].set(sk, mode="drop")
-                    vs_i = vs_i.at[dpi].set(sv, mode="drop")
-                tk = jnp.where(t_valid,
-                               jnp.take(hk[0], t_src, axis=1), 0.0) \
-                    .astype(jnp.float32)
-                tv = jnp.where(t_valid,
-                               jnp.take(hv[0], t_src, axis=1), 0.0) \
-                    .astype(jnp.float32)
-                kt = k_tail[i].at[slot].set(tk)
-                vt = v_tail[i].at[slot].set(tv)
-                new_ks.append(ks_i)
-                new_vs.append(vs_i)
-                new_kt.append(kt)
-                new_vt.append(vt)
-            new_kp.append(kp)
-            new_vp.append(vp)
-            # dequantize the gathered prefix pages (the mask exposes
-            # only positions < offset — complete, quantized, shared);
-            # the tail attends in-register EXACT, so cold admissions
-            # (offset == 0) see zero quantization error
-            gk, gv = kp[table_row], vp[table_row]
-            if kv_bits == 4:
-                gk, gv = unpack_page_nibbles(gk), unpack_page_nibbles(gv)
-            gk = dequantize_page_blocks(gk, ks_i[table_row], bmap)
-            gv = dequantize_page_blocks(gv, vs_i[table_row], bmap)
-            pref_k = gk.transpose(1, 0, 2, 3) \
-                .reshape(1, -1, width, gk.shape[-1]).astype(hk.dtype)
-            pref_v = gv.transpose(1, 0, 2, 3) \
-                .reshape(1, -1, width, gv.shape[-1]).astype(hv.dtype)
-            o = prefix_tail_attention(hq, hk, hv, pref_k, pref_v, mask,
-                                      scale)
-            x = x + blk.attn.project_out(p["attn"], o)
-            x = x + blk.mlp(p, x)
+            x, state[i] = blk.prefill_paged(params["blocks"][i], x, state[i],
+                                            ctx)
 
     x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)
     x_last = model.ln_f.apply(params["ln_f"], model.streams_out(x_last))
-    logits = model.project_vocab(params, x_last)[:, 0]
-    if kv_bits is None:
-        return logits, new_kp, new_vp
-    return logits, new_kp, new_vp, new_ks, new_vs, new_kt, new_vt
+    return model.project_vocab(params, x_last)[:, 0], state
 
 
 def spec_verify_slots(model: TransformerLM, params: Params, ks, vs,
@@ -763,23 +570,20 @@ def spec_commit_slots(ks, vs, lengths, sk, sv,
     return new_k, new_v, lengths + commit
 
 
-def spec_verify_slots_paged(model: TransformerLM, params: Params,
-                            k_pages, v_pages, tables, lengths, tokens,
-                            *, page_len: int, kv_bits=None,
-                            k_scales=None, v_scales=None,
-                            k_tail=None, v_tail=None
+def spec_verify_slots_paged(model: TransformerLM, params: Params, state,
+                            tables, lengths, tokens, *, page_len: int
                             ) -> Tuple[jnp.ndarray, list, list]:
     """Paged twin of :func:`spec_verify_slots`: batched k+1-position
     verify over a PAGED slot pool, read-only.
 
-    Resident keys come from a dense page gather over each row's table
-    (the verify runs once per engine iteration over a short candidate
-    block, so the gather is amortized over k+1 scored positions; a
-    blockwise verify kernel is future work — docs/serving.md). In a
-    quantized pool (``kv_bits`` = 8 | 4) the gathered pages are
-    dequantized and each row's PARTIAL current page is overlaid from
-    its exact f32 tail buffer — the pool row for an incomplete page was
-    never written, exactly as in ``paged_decode_attention``.
+    Each layer's store (``state``, see :func:`decode_step_slots_paged`)
+    goes to the block's ``verify_paged``, which attends over the store's
+    dense rows of each row's table (the verify runs once per engine
+    iteration over a short candidate block, so the gather is amortized
+    over k+1 scored positions; a blockwise verify kernel is future work
+    — docs/serving.md). A quantized store dequantises them and reads
+    each row's PARTIAL current page from its exact tail page — the pool
+    row for an incomplete page was never written, exactly as in decode.
 
     Returns ``(logits (B, S, vocab), sk, sv)`` — the same exact-f32
     scratch contract as the contiguous verify; committing (and, on page
@@ -792,135 +596,48 @@ def spec_verify_slots_paged(model: TransformerLM, params: Params,
     x = model.tok.apply(params["tok"], tokens)
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], positions)
-    scale = 1.0 / math.sqrt(model.dim // model.n_heads)
     prefix_mask = jnp.broadcast_to(
         (jnp.arange(width)[None, :] < idx[:, None])[:, None, :],
         (b, s, width))
     causal = jnp.broadcast_to(
         jnp.tril(jnp.ones((s, s), dtype=bool))[None], (b, s, s))
-    mask = jnp.concatenate([prefix_mask, causal], axis=2)  # (B,S,W+S)
-    if kv_bits is not None:
-        from ..ops.quant import (dequantize_page_blocks, page_block_map,
-                                 unpack_page_nibbles)
-        h_kv = getattr(model, "n_kv_heads", model.n_heads)
-        dh = model.dim // model.n_heads
-        bmap = page_block_map(h_kv, page_len, dh)
-        # positions on a row's CURRENT (partial) page read the slot's
-        # exact f32 tail buffer; the mask hides everything >= lengths,
-        # so a just-completed page never exposes stale tail values
-        jcol = jnp.arange(width)
-        tail_sel = ((jcol[None, :] // page_len)
-                    == (idx[:, None] // page_len))[:, None, :, None]
-        toff = jcol % page_len                      # static (W,) index
-
-    sk_out, sv_out = [], []
+    ctx = VerifyCtx(tables=tables, idx=idx, positions=positions,
+                    mask=jnp.concatenate([prefix_mask, causal], axis=2))
+    sk, sv = [], []
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
-            p = params["blocks"][i]
-            hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                              blk.ln1.apply(p["ln1"], x))
-            hq, hk = blk.attn.maybe_rope(hq, hk, positions[:, None, :])
-            sk_out.append(hk.astype(jnp.float32))
-            sv_out.append(hv.astype(jnp.float32))
-            if kv_bits is None:
-                gk = _gather_pages(k_pages[i], tables).astype(hk.dtype)
-                gv = _gather_pages(v_pages[i], tables).astype(hv.dtype)
-            else:
-                qk, qv = k_pages[i][tables], v_pages[i][tables]
-                if kv_bits == 4:
-                    qk, qv = unpack_page_nibbles(qk), unpack_page_nibbles(qv)
-                dk = dequantize_page_blocks(qk, k_scales[i][tables], bmap)
-                dv = dequantize_page_blocks(qv, v_scales[i][tables], bmap)
-                bb, pp, hh_kv, ll, dd_h = dk.shape
-                gk = dk.transpose(0, 2, 1, 3, 4).reshape(bb, hh_kv,
-                                                         pp * ll, dd_h)
-                gv = dv.transpose(0, 2, 1, 3, 4).reshape(bb, hh_kv,
-                                                         pp * ll, dd_h)
-                gk = jnp.where(tail_sel, k_tail[i][:, :, toff, :], gk) \
-                    .astype(hk.dtype)
-                gv = jnp.where(tail_sel, v_tail[i][:, :, toff, :], gv) \
-                    .astype(hv.dtype)
-            k_all = jnp.concatenate([gk, hk], axis=2)
-            v_all = jnp.concatenate([gv, hv], axis=2)
-            bq, hh, _, dd = hq.shape
-            hkv = k_all.shape[1]
-            hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
-            att = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
-                jnp.float32) * scale
-            att = jnp.where(mask[:, None, None, :, :], att, -jnp.inf)
-            probs = jax.nn.softmax(att, axis=-1).astype(v_all.dtype)
-            o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
-                .reshape(bq, hh, s, dd)
-            x = x + blk.attn.project_out(p["attn"], o)
-            x = x + blk.mlp(p, x)
+            x, (hk, hv) = blk.verify_paged(params["blocks"][i], x, state[i],
+                                           ctx)
+            sk.append(hk)
+            sv.append(hv)
 
     x = model.ln_f.apply(params["ln_f"], x)
-    return model.project_vocab(params, x), sk_out, sv_out
+    return model.project_vocab(params, x), sk, sv
 
 
-def spec_commit_slots_paged(k_pages, v_pages, tables, lengths, sk, sv,
-                            commit, *, page_len: int, kv_bits=None,
-                            k_scales=None, v_scales=None,
-                            k_tail=None, v_tail=None):
-    """Paged twin of :func:`spec_commit_slots`: scatter each row's
-    accepted scratch prefix into its pages.
+def spec_commit_slots_paged(state, tables, lengths, sk, sv, commit, *,
+                            page_len: int) -> list:
+    """Paged twin of :func:`spec_commit_slots`: write each row's
+    accepted scratch prefix into its pages, through each layer's store
+    (``commit``, ``nn/paged.py``).
 
     Position ``lengths[b] + j`` lands in page ``tables[b, (lengths[b] +
     j) // page_len]`` at offset ``(lengths[b] + j) % page_len``;
     rejected positions (``j >= commit[b]``) route out of bounds and
-    drop, so a page can only ever COMPLETE from accepted tokens. In a
-    quantized pool each accepted position is first written to the
-    slot's exact f32 tail buffer, and whenever a write fills offset
-    ``page_len - 1`` the whole tail is quantized ONCE — from exact
-    values, on the wire block grid — and scattered with its scales,
-    preserving the PR 16 quantize-once discipline token-for-token with
-    the non-speculative decode path. Returns ``(new_k_pages,
-    new_v_pages)`` (+ scales and tails in quant mode); advancing the
-    host ``lengths`` by ``commit`` is the caller's business."""
-    s = sk[0].shape[2]
-    n_pages = k_pages[0].shape[0]
-    n_tables = tables.shape[1]
-    bsz = lengths.shape[0]
-    kp, vp = list(k_pages), list(v_pages)
-    if kv_bits is not None:
-        from ..ops.quant import pack_page_nibbles, quantize_page_blocks
-        ksc, vsc = list(k_scales), list(v_scales)
-        kt, vt = list(k_tail), list(v_tail)
-        n_tail = k_tail[0].shape[0]
-    for j in range(s):
-        committed = j < commit                              # (B,)
+    drop, so a page can only ever COMPLETE from accepted tokens — which
+    is what keeps a quantized store's quantize-once discipline
+    token-for-token with the non-speculative decode path. Returns the
+    new state; advancing the host ``lengths`` by ``commit`` is the
+    caller's business."""
+    n_pages, last = state[0].n_pages, tables.shape[1] - 1
+    steps = []
+    for j in range(sk[0].shape[2]):
         pos = lengths + j
         wp = jnp.take_along_axis(
-            tables, jnp.clip(pos // page_len, 0, n_tables - 1)[:, None],
+            tables, jnp.clip(pos // page_len, 0, last)[:, None],
             axis=1)[:, 0]
-        wo = pos % page_len
-        if kv_bits is None:
-            dest = jnp.where(committed, wp, n_pages)
-            for i in range(len(kp)):
-                kp[i] = kp[i].at[dest, :, wo].set(
-                    sk[i][:, :, j, :].astype(kp[i].dtype), mode="drop")
-                vp[i] = vp[i].at[dest, :, wo].set(
-                    sv[i][:, :, j, :].astype(vp[i].dtype), mode="drop")
-        else:
-            dest_t = jnp.where(committed, jnp.arange(bsz), n_tail)
-            completed = jnp.logical_and(committed, wo == page_len - 1)
-            dest_q = jnp.where(completed, wp, n_pages)
-            for i in range(len(kp)):
-                kt[i] = kt[i].at[dest_t, :, wo].set(
-                    sk[i][:, :, j, :].astype(jnp.float32), mode="drop")
-                vt[i] = vt[i].at[dest_t, :, wo].set(
-                    sv[i][:, :, j, :].astype(jnp.float32), mode="drop")
-                qk, sc_k = quantize_page_blocks(kt[i], kv_bits)
-                qv, sc_v = quantize_page_blocks(vt[i], kv_bits)
-                if kv_bits == 4:
-                    qk, qv = pack_page_nibbles(qk), pack_page_nibbles(qv)
-                kp[i] = kp[i].at[dest_q].set(qk, mode="drop")
-                vp[i] = vp[i].at[dest_q].set(qv, mode="drop")
-                ksc[i] = ksc[i].at[dest_q].set(sc_k, mode="drop")
-                vsc[i] = vsc[i].at[dest_q].set(sc_v, mode="drop")
-    if kv_bits is None:
-        return kp, vp
-    return kp, vp, ksc, vsc, kt, vt
+        steps.append((jnp.where(j < commit, wp, n_pages), pos % page_len))
+    return [st.commit(steps, sk[i], sv[i]) for i, st in enumerate(state)]
 
 
 @jax.named_scope("sample")

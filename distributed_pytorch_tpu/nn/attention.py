@@ -154,72 +154,56 @@ class MultiHeadAttention(Module):
             o = self.attn_fn(q, k, v, causal=self.causal)
         return self.project_out(params, o)
 
-    # -- the paged path: the module owns its page layout (nn/paged.py) ------
+    # -- the paged path: the module hands out its page store (nn/paged.py) --
 
-    def page_shapes(self, page_len: int):
-        """A layer keeps a K and a V array of (n_pages, Hkv, page_len,
-        Dh): the shapes without the page axis."""
-        shape = (self.n_kv_heads, page_len, self.head_dim)
-        return (shape, shape)
+    def make_pages(self, n_pages: int, n_slots: int, page_len: int, bits,
+                   dtype):
+        """A layer's store: K and V of (n_pages, Hkv, page_len, Dh),
+        exact in ``dtype`` (``bits`` None) or quantized to 8 or 4 bits."""
+        from .paged import KVPages
+        return KVPages.zeros((self.n_kv_heads, page_len, self.head_dim),
+                             n_pages, n_slots, bits, dtype)
 
     def decode_paged(self, params: Params, x, pages, ctx):
-        """One token a row over the exact paged pool. x (B, 1, D) normed;
-        returns (attention's output (B, 1, D), new (K, V) pages)."""
-        from ..ops.decode_attention import (dense_decode_attention,
-                                            paged_decode_attention)
-        k_pages, v_pages = pages
-        scale = 1.0 / math.sqrt(self.head_dim)
+        """One token a row over the paged pool. x (B, 1, D) normed;
+        returns (attention's output (B, 1, D), the store written)."""
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.idx[:, None, None])
         with jax.named_scope("page_write"):
-            kp = write_rows(k_pages, ctx.dest, ctx.wo, hk[:, :, 0, :])
-            vp = write_rows(v_pages, ctx.dest, ctx.wo, hv[:, :, 0, :])
-        if ctx.blockwise:
-            # the loop: the page gather lives inside it; hk/hv are
-            # re-selected at the write position per block — identity
-            # for active rows (already scattered), and gives inactive
-            # rows decode_step_slots' exact value semantics (their
-            # discarded logits still see "their" key). On a TPU the
-            # kernel instead: active rows read their key from the pool,
-            # inactive rows are skipped (ops/decode_attention.py)
-            o = paged_decode_attention(hq, kp, vp, ctx.tables, ctx.idx,
-                                       hk, hv, scale=scale,
-                                       page_len=ctx.page_len,
-                                       active=ctx.active)
-        else:
-            # logical rows: gather the updated pool, then re-select the
-            # new key at the write position
-            k = jnp.where(ctx.write_mask, hk.astype(kp.dtype),
-                          gather_pages(kp, ctx.tables))
-            v = jnp.where(ctx.write_mask, hv.astype(vp.dtype),
-                          gather_pages(vp, ctx.tables))
-            o = dense_decode_attention(hq, k, v, ctx.pos_mask, scale=scale)
-        return self.project_out(params, o), (kp, vp)
+            pages = pages.write(hk, hv, ctx.dest, ctx.wo)
+        o = pages.attend(ctx, hq, hk, hv, 1.0 / math.sqrt(self.head_dim))
+        return self.project_out(params, o), pages
 
     def prefill_paged(self, params: Params, x, pages, ctx):
         """The tail of one prompt over [shared prefix pages | tail].
-        x (1, S, D) normed; returns (output (1, S, D), new pages)."""
-        k_pages, v_pages = pages
-        s, width = x.shape[1], ctx.width
-        scale = 1.0 / math.sqrt(self.head_dim)
+        x (1, S, D) normed; returns (output (1, S, D), the store
+        written). Prefix keys come from the pool; the tail's are inline:
+        its pages were just written, but the in-register tail avoids a
+        second gather, keeps the math identical to prefill_partial's
+        [real | pad] layout, and is exact where the pool is quantized,
+        so a cold prompt sees no quantization at admission."""
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.positions)
         with jax.named_scope("page_write"):
-            kp = write_rows(k_pages, ctx.dest, ctx.dest_off,
-                            jnp.moveaxis(hk[0], 1, 0))
-            vp = write_rows(v_pages, ctx.dest, ctx.dest_off,
-                            jnp.moveaxis(hv[0], 1, 0))
-        # prefix keys from the (updated) pool; tail keys inline — the
-        # tail pages were just written, but using the in-register tail
-        # avoids a second gather and keeps the math identical to
-        # prefill_partial's [real | pad] layout
-        pref_k = kp[ctx.table_row].transpose(1, 0, 2, 3) \
-            .reshape(1, -1, width, kp.shape[-1]).astype(hk.dtype)
-        pref_v = vp[ctx.table_row].transpose(1, 0, 2, 3) \
-            .reshape(1, -1, width, vp.shape[-1]).astype(hv.dtype)
-        return self.project_out(
-            params, prefix_tail_attention(hq, hk, hv, pref_k, pref_v,
-                                          ctx.mask, scale)), (kp, vp)
+            pages = pages.write_tail(hk, hv, ctx)
+        pref_k, pref_v = pages.rows(ctx.table_row, None, hk, hv)
+        return self.project_out(params, prefix_tail_attention(
+            hq, hk, hv, pref_k, pref_v, ctx.mask,
+            1.0 / math.sqrt(self.head_dim))), pages
+
+    def verify_paged(self, params: Params, x, pages, ctx):
+        """Every row's k + 1 candidates (x (B, S, D) normed) over [its
+        resident rows | the candidates], the store only read: a dense
+        page gather, amortised over the k + 1 scored positions. Returns
+        (output (B, S, D), this layer's exact f32 candidate (K, V), for
+        ``pages.commit``)."""
+        hq, hk, hv = self.project_qkv(params, x)
+        hq, hk = self.maybe_rope(hq, hk, ctx.positions[:, None, :])
+        gk, gv = pages.rows(ctx.tables, ctx.idx, hk, hv)
+        o = prefix_tail_attention(hq, hk, hv, gk, gv, ctx.mask,
+                                  1.0 / math.sqrt(self.head_dim))
+        return self.project_out(params, o), (hk.astype(jnp.float32),
+                                             hv.astype(jnp.float32))
 
 
 def write_rows(pool, dest, wo, rows):
@@ -240,21 +224,11 @@ def write_rows(pool, dest, wo, rows):
         .reshape(pool.shape)
 
 
-def gather_pages(pool, tables):
-    """Gather a slot batch's pages into contiguous rows.
-
-    pool: (n_pages, Hkv, page_len, Dh); tables: (B, P) int32 page ids
-    (unallocated entries may hold any valid id — the caller's position
-    mask hides them). Returns (B, Hkv, P*page_len, Dh)."""
-    g = pool[tables]                       # (B, P, Hkv, page_len, Dh)
-    b, p, h, l, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, p * l, d)
-
-
 def prefix_tail_attention(hq, hk, hv, pref_k, pref_v, mask, scale):
-    """Grouped-query attention of a tail's queries (1, H, S, Dh) over
-    [prefix (1, Hkv, W, Dh) | tail (1, Hkv, S, Dh)] under ``mask``
-    (S, W + S); float32 statistics. Returns (1, H, S, Dh)."""
+    """Grouped-query attention of a tail's queries (B, H, S, Dh) over
+    [prefix (B, Hkv, W, Dh) | tail (B, Hkv, S, Dh)] under ``mask``
+    (S, W + S), or (B, S, W + S) a row; float32 statistics. Returns
+    (B, H, S, Dh)."""
     s = hq.shape[2]
     k_all = jnp.concatenate([pref_k, hk], axis=2)   # (1,Hkv,W+S,Dh)
     v_all = jnp.concatenate([pref_v, hv], axis=2)
@@ -263,8 +237,9 @@ def prefix_tail_attention(hq, hk, hv, pref_k, pref_v, mask, scale):
     hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
     logits = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
         jnp.float32) * scale                     # (1,Hkv,g,S,W+S)
-    logits = jnp.where(mask[None, None, None, :, :], logits,
-                       -jnp.inf)
+    mask = mask[None, None, None, :, :] if mask.ndim == 2 \
+        else mask[:, None, None, :, :]
+    logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1).astype(v_all.dtype)
     return jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
         .reshape(bq, hh, s, dd)
@@ -303,22 +278,23 @@ class TransformerBlock(Module):
             return self.fc2.apply(params["fc2"],
                                   gelu(self.fc1.apply(params["fc1"], h)))
 
-    def page_shapes(self, page_len: int):
-        return self.attn.page_shapes(page_len)
+    def _paged(self, step, params: Params, x, pages, ctx):
+        a, out = step(params["attn"], self.ln1.apply(params["ln1"], x),
+                      pages, ctx)
+        x = x + a
+        return x + self.mlp(params, x), out
 
     def decode_paged(self, params: Params, x, pages, ctx):
-        """x (B, 1, D), this layer's page arrays -> (x, new arrays)."""
-        a, pages = self.attn.decode_paged(
-            params["attn"], self.ln1.apply(params["ln1"], x), pages, ctx)
-        x = x + a
-        return x + self.mlp(params, x), pages
+        """x (B, 1, D), this layer's page store -> (x, the store)."""
+        return self._paged(self.attn.decode_paged, params, x, pages, ctx)
 
     def prefill_paged(self, params: Params, x, pages, ctx):
         """x (1, S, D): the padded tail of one prompt."""
-        a, pages = self.attn.prefill_paged(
-            params["attn"], self.ln1.apply(params["ln1"], x), pages, ctx)
-        x = x + a
-        return x + self.mlp(params, x), pages
+        return self._paged(self.attn.prefill_paged, params, x, pages, ctx)
+
+    def verify_paged(self, params: Params, x, pages, ctx):
+        """x (B, S, D): a verify's candidates -> (x, their (K, V))."""
+        return self._paged(self.attn.verify_paged, params, x, pages, ctx)
 
     def apply(self, params: Params, x, *, rng=None, train: bool = False,
               positions=None, **_):

@@ -5,12 +5,13 @@ feed-forward module and a residual path.
 multi-head attention, a GELU MLP, ``x + f(x)``. This block takes each of
 the four as an object, so that a model can say per layer what it is made
 of (``models/transformer.py`` ``block_kinds``): RMSNorm or LayerNorm;
-any attention module that has ``apply`` and owns its page layout
-(``page_shapes`` / ``decode_paged`` / ``prefill_paged``: ``nn/latent.py``,
-``nn/attention.py``); a gated MLP of any width or a dropless expert layer
-(``parallel/moe.py``); the plain residual sum or ``streams`` parallel
-residual streams under hyper-connections (``nn/hyper.py``), in which case
-the block's input and output are (B, S, streams, D)."""
+any attention module that has ``apply`` and hands out its page store
+(``make_pages`` / ``decode_paged`` / ``prefill_paged``: ``nn/latent.py``,
+``nn/attention.py``, ``nn/paged.py``); a gated MLP of any width or a
+dropless expert layer (``parallel/moe.py``); the plain residual sum or
+``streams`` parallel residual streams under hyper-connections
+(``nn/hyper.py``), in which case the block's input and output are
+(B, S, streams, D)."""
 
 from __future__ import annotations
 
@@ -47,9 +48,6 @@ class Block(Module):
             p["hc2"] = self.hc2.init(ks[5])
         return p
 
-    def page_shapes(self, page_len: int):
-        return self.attn.page_shapes(page_len)
-
     def _residual(self, hc, params, x, fn):
         """``fn`` maps the sublayer's input to its output or to (output,
         aux); the residual path decides what the input is and where the
@@ -84,8 +82,8 @@ class Block(Module):
         return self._ffn(params, x, row_mask, ctx.moe_stats), pages
 
     def decode_paged(self, params: Params, x, pages, ctx):
-        """x (B, 1[, streams], D), this layer's page arrays -> (x, new
-        page arrays). Idle slots are left out of the expert dispatch."""
+        """x (B, 1[, streams], D), this layer's page store -> (x, the
+        store written). Idle slots are left out of the expert dispatch."""
         return self._paged(self.attn.decode_paged, params, x, pages, ctx,
                            ctx.active[:, None])
 

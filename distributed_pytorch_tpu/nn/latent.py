@@ -19,15 +19,16 @@ interleaved, and the softmax scale carries ``mscale_all_dim``'s square."""
 from __future__ import annotations
 
 import math
-from typing import Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import (dense_decode_attention,
-                                    paged_decode_attention)
+                                    paged_loop_attention)
 from .attention import dense_attention
 from .core import Linear, Module, Params, RMSNorm
+from .paged import latent_unsupported
 from .rotary import rotate_interleaved, yarn_inv_freq, yarn_mscale
 
 #: rows of queries the warm paged prefill scores at a time: the float32
@@ -53,6 +54,68 @@ def masked_attention(q, k, v, mask, scale, chunk: int = PREFILL_Q_CHUNK):
     out = jax.lax.map(rows, (q.reshape(h, n, chunk, dq).transpose(1, 0, 2, 3),
                              mask.reshape(n, chunk, -1)))
     return out.transpose(1, 0, 2, 3).reshape(h, s_q, -1)
+
+
+class LatentPages(NamedTuple):
+    """Latent attention's store (``nn/paged.py``): ONE exact array a
+    layer, ``(n_pages, 1, page_len, page_width)``, whose entries
+    ``[c | k_r | 0]`` are key and value both. What has not been carried
+    over to this layout is refused by name, where the engine or the pool
+    that needs it is built (``require``)."""
+    entries: Any
+
+    LACKS = {"commit": "speculative decoding (serve/spec)",
+             "export": "the disaggregated hand-off (serve/disagg)",
+             "adopt": "the disaggregated hand-off (serve/disagg)"}
+
+    @property
+    def n_pages(self) -> int:
+        return self.entries.shape[0]
+
+    def require(self, op: str) -> None:
+        if op in self.LACKS:
+            raise latent_unsupported(self.LACKS[op])
+
+    def commit(self, *_):
+        self.require("commit")
+
+    def export(self, *_, **__):
+        self.require("export")
+
+    def adopt(self, *_, **__):
+        self.require("adopt")
+
+    def write(self, entry, dest, wo):
+        """One entry a row (B, 1, E), or a prompt's tail (S, 1, E) with
+        its own ``dest`` / ``wo`` (S,). (Not ``write_rows``: with one
+        head the (page, :, offset) form moves no pool, and the cell's
+        programs stay the ones measured.)"""
+        return LatentPages(self.entries.at[dest, :, wo].set(
+            entry.astype(self.entries.dtype), mode="drop"))
+
+    def rows(self, tables):
+        """The entries of the pages ``tables`` (P,) or (B, P) names,
+        (1 | B, P * page_len, E)."""
+        g = self.entries[tables]
+        return g.reshape((g.shape[:-4] or (1,)) + (-1, g.shape[-1]))
+
+    def attend(self, ctx, hq, new, scale, width: int):
+        """A decode step, absorbed: hq (B, H, 1, E) against the one
+        shared key head, ``new`` (B, 1, 1, E) this step's entries, the
+        values the first ``width`` of an entry. (B, H, 1, width)."""
+        if ctx.blockwise:
+            return paged_loop_attention(
+                hq, lambda pids, j: jnp.take(self.entries, pids, axis=0),
+                None, ctx.tables, ctx.idx, new, None, scale=scale,
+                page_len=ctx.page_len, out_dtype=self.entries.dtype,
+                value_width=width)
+        k = self.rows(ctx.tables)[:, None]                # (B, 1, W, E)
+        k = jnp.where(ctx.write_mask, new.astype(k.dtype), k)
+        return dense_decode_attention(hq, k, k, ctx.pos_mask,
+                                      scale=scale)[..., :width]
+
+    def resident_bytes(self) -> int:
+        return self.entries.nbytes
 
 
 class LatentAttention(Module):
@@ -192,8 +255,12 @@ class LatentAttention(Module):
 
     # -- the paged path: the module owns its page layout ----------------------
 
-    def page_shapes(self, page_len: int):
-        return ((1, page_len, self.page_width),)
+    def make_pages(self, n_pages: int, n_slots: int, page_len: int, bits,
+                   dtype):
+        if bits is not None:
+            raise latent_unsupported("quantized pages (kv_dtype q8/q4)")
+        return LatentPages(jnp.zeros((n_pages, 1, page_len, self.page_width),
+                                     dtype))
 
     def absorb(self, params: Params, q_n, q_r):
         """Queries against page entries: ``[q_n W_k^T | q_r | 0]``,
@@ -213,30 +280,16 @@ class LatentAttention(Module):
             return jnp.einsum("bshc,chd->bshd", o_c, w_v)
 
     def decode_paged(self, params: Params, x, pages, ctx):
-        """One token a row, absorbed. x (B, 1, D); pages: the layer's one
-        latent array. Returns (attention's output (B, 1, D), new pages)."""
-        (lat,) = pages
+        """One token a row, absorbed. x (B, 1, D); pages: the layer's
+        store. Returns (attention's output (B, 1, D), the store)."""
         q_n, q_r, c, k_r = self.project(params, x, ctx.idx[:, None])
         entry = self.page_entry(c, k_r)                        # (B, 1, E)
         with jax.named_scope("page_write"):
-            lat = lat.at[ctx.dest, :, ctx.wo].set(
-                entry.astype(lat.dtype), mode="drop")
+            pages = pages.write(entry, ctx.dest, ctx.wo)
         hq = self.absorb(params, q_n, q_r).transpose(0, 2, 1, 3)  # (B,H,1,E)
-        new = entry[:, None]                                   # (B, 1, 1, E)
-        if ctx.blockwise:
-            o_c = paged_decode_attention(
-                hq, lat, None, ctx.tables, ctx.idx, new, None,
-                scale=self.scale, page_len=ctx.page_len,
-                latent_width=self.kv_rank)
-        else:
-            g = lat[ctx.tables]                        # (B, P, 1, L, E)
-            k = g.transpose(0, 2, 1, 3, 4).reshape(
-                g.shape[0], 1, -1, self.page_width)
-            k = jnp.where(ctx.write_mask, new.astype(k.dtype), k)
-            o_c = dense_decode_attention(hq, k, k, ctx.pos_mask,
-                                         scale=self.scale)[..., :self.kv_rank]
+        o_c = pages.attend(ctx, hq, entry[:, None], self.scale, self.kv_rank)
         o = self.unabsorb(params, o_c.transpose(0, 2, 1, 3))
-        return self.project_out(params, o), (lat,)
+        return self.project_out(params, o), pages
 
     def prefill_paged(self, params: Params, x, pages, ctx):
         """The tail of one prompt, expanded, attending over [shared
@@ -245,12 +298,10 @@ class LatentAttention(Module):
         pluggable causal core alone; a warm one widens the prefix entries
         through ``kv_b`` and scores densely, a chunk of queries at a
         time. One program serves both (``lax.cond``)."""
-        (lat,) = pages
         q_n, q_r, c, k_r = self.project(params, x, ctx.positions)
         entry = self.page_entry(c, k_r)                        # (1, S, E)
         with jax.named_scope("page_write"):
-            lat = lat.at[ctx.dest, :, ctx.dest_off].set(
-                entry[0][:, None, :].astype(lat.dtype), mode="drop")
+            pages = pages.write(entry[0][:, None, :], ctx.dest, ctx.dest_off)
         k_n, v = self.expand(params, c)
         q, k, v = self._heads(q_n, q_r, k_n, k_r, v)           # (1, H, S, .)
 
@@ -259,9 +310,7 @@ class LatentAttention(Module):
 
         def warm(_):
             with jax.named_scope("attn/core"):
-                pre = lat[ctx.table_row].reshape(1, ctx.width,
-                                                 self.page_width)
-                pre = pre.astype(x.dtype)
+                pre = pages.rows(ctx.table_row).astype(x.dtype)
                 pk_n, pv = self.expand(params, pre[..., :self.kv_rank])
                 pk, pv = self._key_heads(
                     pk_n, pre[..., self.kv_rank:self.entry_dim], pv)
@@ -271,4 +320,4 @@ class LatentAttention(Module):
                     self.scale)[None].astype(v.dtype)
 
         o = jax.lax.cond(ctx.offset > 0, warm, cold, None)
-        return self.project_out(params, o.transpose(0, 2, 1, 3)), (lat,)
+        return self.project_out(params, o.transpose(0, 2, 1, 3)), pages

@@ -1,17 +1,56 @@
-"""What a paged step function hands every block (``models/generate.py``:
-``decode_step_slots_paged``, ``prefill_partial_paged``).
+"""The paged path's one seam: what a step function hands every block,
+and the STORE that holds a layer's resident pages.
 
-The step function owns what is the same for every layer: where this
-step's entries go in the pool, the positions, the masks. A block owns its
-page layout: its attention module says which arrays a layer keeps
-(``page_shapes``), writes its entries and attends over them
-(``decode_paged`` / ``prefill_paged``). Multi-head attention keeps a K
-and a V array of ``(n_pages, Hkv, page_len, Dh)``; latent attention keeps
-ONE array of ``(n_pages, 1, page_len, kv_rank + rope_dim)``."""
+The step functions (``models/generate.py``) own what is the same for
+every layer: where this step's entries go in the pool, the positions,
+the masks (the ``*Ctx`` tuples below). The format of a layer's resident
+pages lives behind the store its attention module hands out
+(``attn.make_pages``): the pool, the step functions and the programs
+carry a list of stores, one a layer, as one opaque pytree, and only the
+attention module and the store look inside one.
+
+- :class:`KVPages` (``nn/attention.py``): a K and a V side, each
+  :class:`ExactSide` (one ``(n_pages, Hkv, page_len, Dh)`` array in the
+  model's dtype) or :class:`QuantSide` (int8 / packed-nibble pages,
+  per-page-per-block f32 scales, per-slot exact f32 tail pages:
+  docs/serving.md "Quantized resident pool"). Every operation is written
+  once, for a side, and applied to K and to V.
+- ``LatentPages`` (``nn/latent.py``): ONE array of ``[c | k_r]`` entries,
+  ``(n_pages, 1, page_len, page_width)``; what it lacks (quantized pages,
+  the speculative commit, the hand-off) it refuses by name with
+  :class:`LatentPagesUnsupported`.
+
+A store is a ``NamedTuple`` of arrays: a pytree that crosses ``jax.jit``
+and is donated whole. What a store answers: ``write`` (one entry a row:
+a decode step, one position of a speculative commit), ``write_tail`` (a
+prompt's tail), ``commit``, ``attend`` (a decode step; the kernel-or-loop
+choice of ``ops/decode_attention.py`` is made from here), ``rows`` (the
+dense, dequantised rows that prefill and verify attend over),
+``resident_bytes``, ``n_pages``, ``require`` and the host side of the
+hand-off, ``export*`` / ``adopt*``.
+
+**Quantize once.** A quantized side writes every entry into its slot's
+exact f32 tail page; the write that fills offset ``page_len - 1``
+quantizes the whole tail page, from exact values, on the wire block
+grid, and scatters it into the int pool with its scales. A dropped row
+(``dest == n_pages``: an idle slot, a rejected speculative position)
+writes nothing anywhere, so no value is ever rounded twice."""
 
 from __future__ import annotations
 
 from typing import Any, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.decode_attention import (dense_decode_attention,
+                                    paged_decode_attention,
+                                    paged_loop_attention)
+from ..ops.quant import (dequantize_page_blocks, pack_page_nibbles,
+                         page_block_map, quantize_page_blocks,
+                         unpack_page_nibbles)
+from .attention import write_rows
 
 
 class DecodeCtx(NamedTuple):
@@ -37,13 +76,366 @@ class PrefillCtx(NamedTuple):
     """The tail of one prompt: ``positions`` (S,) = ``offset`` + arange,
     ``dest`` / ``dest_off`` (S,) where each tail entry goes (pad rows
     route out of bounds), ``mask`` (S, W + S) over [prefix pages | tail] of
-    ``width`` W, ``row_mask`` (S,) the tail's real rows."""
+    ``width`` W, ``row_mask`` (S,) the tail's ``true_len`` real rows,
+    ``slot`` the row of the pool the prompt is admitted to."""
     table_row: Any
     positions: Any
     offset: Any
+    true_len: Any
+    slot: Any
     dest: Any
     dest_off: Any
     mask: Any
     row_mask: Any
     width: int
     moe_stats: Optional[list] = None
+
+
+class VerifyCtx(NamedTuple):
+    """A speculative verify: every row's k + 1 candidates at
+    ``positions`` (B, S) = ``idx`` + arange, attending under ``mask``
+    (B, S, W + S) over [resident rows | candidates]. Reads only."""
+    tables: Any
+    idx: Any
+    positions: Any
+    mask: Any
+
+
+class LatentPagesUnsupported(NotImplementedError):
+    """A serving path that has no layout for latent-attention blocks."""
+
+
+def latent_unsupported(what: str) -> LatentPagesUnsupported:
+    """Latent blocks keep one exact array a layer; what has not been
+    carried over to that layout says so by name."""
+    return LatentPagesUnsupported(
+        f"{what} cannot hold latent (MLA) pages yet: a latent block "
+        "keeps one array of [c | k_r] entries a layer, served only by "
+        "the exact paged pool (InferenceEngine(paged=True), "
+        "kv_dtype='f32')")
+
+
+def dense_rows(g):
+    """Gathered pages as contiguous rows: (P, H, L, D) of one table row
+    -> (1, H, P*L, D); (B, P, H, L, D) of a batch of them -> (B, H, P*L,
+    D). Unallocated table entries may hold any valid id: the caller's
+    position mask hides them."""
+    if g.ndim == 4:
+        return g.transpose(1, 0, 2, 3).reshape(1, g.shape[1], -1,
+                                               g.shape[-1])
+    b, p, h, l, d = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, p * l, d)
+
+
+class ExactSide(NamedTuple):
+    """K or V in the model's dtype, ``(n_pages, Hkv, page_len, Dh)``."""
+    pages: Any
+
+    def write(self, h, dest, wo, j: int = 0):
+        return ExactSide(write_rows(self.pages, dest, wo, h[:, :, j, :]))
+
+    def write_tail(self, h, ctx):
+        return ExactSide(write_rows(self.pages, ctx.dest, ctx.dest_off,
+                                    jnp.moveaxis(h[0], 1, 0)))
+
+    def rows(self, tables, idx=None):
+        return dense_rows(self.pages[tables])
+
+    def resident_bytes(self) -> int:
+        return self.pages.nbytes
+
+    # -- the hand-off's host side ------------------------------------------
+
+    def export(self, idx, slot: int, valid_last: int):
+        """The pages ``idx`` names as one (P, Hkv, L, Dh) f32 numpy
+        array, the last page zeroed past ``valid_last``: a reused page
+        may carry a previous occupant's stale entries there, which no
+        mask would attend but which would poison a quantized frame's
+        scales. (np.array, not asarray: a CPU-backend transfer can alias
+        read-only memory.)"""
+        a = np.array(self.pages[idx], np.float32)
+        a[-1, :, valid_last:, :] = 0.0
+        return a
+
+    def adopt(self, pages, idx, slot: int, valid_last: int):
+        return ExactSide(self.pages.at[idx].set(
+            jnp.asarray(pages, self.pages.dtype)))
+
+
+class QuantSide(NamedTuple):
+    """K or V block-quantized: ``q`` (n_pages, Hkv, page_len, Dh) int8,
+    or (..., Dh // 2) uint8 nibble pairs at four bits; ``scales``
+    (n_pages, nb) f32, 1 where never written (the codec's all-zero
+    snap: such a page dequantizes to exact zeros); ``tail`` (n_slots,
+    Hkv, page_len, Dh) f32, each slot's partial page, exact."""
+    q: Any
+    scales: Any
+    tail: Any
+
+    @property
+    def bits(self) -> int:
+        return 4 if self.q.dtype == jnp.uint8 else 8
+
+    def _quantize(self, pages):
+        q, scales = quantize_page_blocks(pages, self.bits)
+        return (pack_page_nibbles(q) if self.bits == 4 else q), scales
+
+    def _dequantize(self, q, scales):
+        if self.bits == 4:
+            q = unpack_page_nibbles(q)
+        return dequantize_page_blocks(q, scales,
+                                      page_block_map(*self.tail.shape[1:]))
+
+    def write(self, h, dest, wo, j: int = 0):
+        """Row b's entry into slot b's tail page; the pages this write
+        completes are quantized, the one time, and scattered."""
+        n_pages, page_len = self.q.shape[0], self.tail.shape[2]
+        live = dest < n_pages
+        tail = write_rows(
+            self.tail, jnp.where(live, jnp.arange(dest.shape[0]),
+                                 self.tail.shape[0]), wo, h[:, :, j, :])
+        done = jnp.where(live & (wo == page_len - 1), dest, n_pages)
+        q, scales = self._quantize(tail)                # (B, Hkv, L, Dh)
+        return QuantSide(self.q.at[done].set(q, mode="drop"),
+                         self.scales.at[done].set(scales, mode="drop"),
+                         tail)
+
+    def write_tail(self, h, ctx):
+        """The tail starts at a page boundary (only full pages are
+        shared), so its chunk c IS the slot's page ``offset // page_len
+        + c``: a chunk that lies within ``true_len`` is complete and is
+        quantized, the rest goes exact into the slot's tail page (what
+        lies past ``true_len`` zeroed), where decode goes on writing."""
+        q, scales = self.q, self.scales
+        page_len, s = self.tail.shape[2], h.shape[2]
+        last = ctx.table_row.shape[0] - 1
+        for c in range(s // page_len):
+            lo = c * page_len
+            qc, sc = self._quantize(
+                h[0, :, lo:lo + page_len, :].astype(jnp.float32))
+            # incomplete chunks route out of bounds and drop; the page
+            # index gather clamps harmlessly for them
+            pid = jnp.where(
+                lo + page_len <= ctx.true_len,
+                ctx.table_row[jnp.clip(ctx.offset // page_len + c, 0, last)],
+                q.shape[0])
+            q = q.at[pid].set(qc, mode="drop")
+            scales = scales.at[pid].set(sc, mode="drop")
+        floor = (ctx.offset + ctx.true_len) // page_len * page_len \
+            - ctx.offset
+        r = floor + jnp.arange(page_len)
+        part = jnp.where((r < ctx.true_len)[None, :, None],
+                         jnp.take(h[0], jnp.clip(r, 0, s - 1), axis=1), 0.0)
+        return QuantSide(q, scales, self.tail.at[ctx.slot].set(
+            part.astype(jnp.float32)))
+
+    def rows(self, tables, idx=None):
+        """Dequantised rows. With ``idx`` (B,), the positions on each
+        row's CURRENT page are read from its exact tail page: the pool
+        row of an incomplete page was never written. (A prompt's shared
+        prefix is whole pages: no ``idx``.)"""
+        rows = dense_rows(self._dequantize(self.q[tables],
+                                           self.scales[tables]))
+        if idx is None:
+            return rows
+        page_len = self.tail.shape[2]
+        col = jnp.arange(rows.shape[2])
+        current = (col[None, :] // page_len) == (idx[:, None] // page_len)
+        return jnp.where(current[:, None, :, None],
+                         self.tail[:, :, col % page_len, :], rows)
+
+    def loader(self, idx):
+        """Page j of every row for the decode loop: dequantised as it is
+        gathered, a row's current page overlaid from its tail."""
+        tail_page = idx // self.tail.shape[2]
+
+        def load(pids, j):
+            blk = self._dequantize(jnp.take(self.q, pids, axis=0),
+                                   jnp.take(self.scales, pids, axis=0))
+            return jnp.where((j == tail_page)[:, None, None, None],
+                             self.tail, blk)
+        return load
+
+    def resident_bytes(self) -> int:
+        """Pages and scales: the tails are a slot's, not a page's."""
+        return self.q.nbytes + self.scales.nbytes
+
+    # -- the hand-off's host side ------------------------------------------
+
+    def _tail_np(self, slot: int, valid_last: int):
+        t = np.array(self.tail[slot], np.float32)
+        t[:, valid_last:, :] = 0.0
+        return t
+
+    def export_quantized(self, idx, slot: int, valid_last: int):
+        """The resident bits as ``(q (P, Hkv, L, Dh) int8 unpacked,
+        scales (P, nb))``; the partial last page is quantized here,
+        once, from its exact tail page, through the wire codec."""
+        from ..serve.pages import quant as codec
+        q = np.array(self.q[idx])
+        q = np.ascontiguousarray(
+            codec.unpack_pages_np(q) if self.bits == 4 else q, np.int8)
+        scales = np.array(self.scales[idx], np.float32)
+        if valid_last < self.tail.shape[2]:
+            q[-1], scales[-1] = codec.quantize_page_np(
+                self._tail_np(slot, valid_last), self.bits)
+        return q, scales
+
+    def export(self, idx, slot: int, valid_last: int):
+        """Full pages dequantised on the host, the partial last page
+        from the exact tail: it ships with no quantization error."""
+        from ..serve.pages import quant as codec
+        q, scales = self.export_quantized(idx, slot, self.tail.shape[2])
+        a = np.stack([codec.dequantize_page_np(q[p], scales[p])
+                      for p in range(q.shape[0])])
+        if valid_last < self.tail.shape[2]:
+            a[-1] = self._tail_np(slot, valid_last)
+        return a
+
+    def _install(self, idx, slot: int, q, scales, tail):
+        from ..serve.pages import quant as codec
+        if self.bits == 4:
+            q = codec.pack_pages_np(q)
+        return QuantSide(
+            self.q.at[idx].set(jnp.asarray(q)),
+            self.scales.at[idx].set(jnp.asarray(scales, jnp.float32)),
+            self.tail.at[slot].set(jnp.asarray(tail)))
+
+    def adopt(self, pages, idx, slot: int, valid_last: int):
+        """Exact pages in: the full ones are quantized here (their ONE
+        rounding), the partial last page goes into the slot's tail page,
+        which a page-aligned length zeroes, so that a previous
+        occupant's tail can never alias into this request."""
+        from ..serve.pages import quant as codec
+        n, page_len = pages.shape[0], self.tail.shape[2]
+        partial = valid_last < page_len
+        q = np.zeros(pages.shape, np.int8)
+        scales = np.ones((n, self.scales.shape[1]), np.float32)
+        for p in range(n - partial):
+            q[p], scales[p] = codec.quantize_page_np(pages[p], self.bits)
+        tail = np.array(pages[-1], np.float32) if partial \
+            else np.zeros(pages.shape[1:], np.float32)
+        tail[:, valid_last:, :] = 0.0
+        return self._install(idx, slot, q, scales, tail)
+
+    def adopt_quantized(self, pages, idx, slot: int, valid_last: int):
+        """The sender's bits in, verbatim: no rounding here. The partial
+        last page is also dequantised into the slot's tail page
+        (lossless given ``q`` and ``scales``), so that decode's overlay
+        and the completing quantization see what the sender's pool
+        held."""
+        from ..serve.pages import quant as codec
+        q, scales = pages
+        q = np.ascontiguousarray(q, np.int8)
+        if valid_last < self.tail.shape[2]:
+            tail = codec.dequantize_page_np(q[-1], np.asarray(scales[-1]))
+            tail[:, valid_last:, :] = 0.0
+        else:
+            tail = np.zeros(q.shape[1:], np.float32)
+        return self._install(idx, slot, q, scales, tail)
+
+
+class KVPages(NamedTuple):
+    """Multi-head attention's store: a K side and a V side of one kind."""
+    k: Any
+    v: Any
+
+    @classmethod
+    def zeros(cls, shape, n_pages: int, n_slots: int, bits, dtype):
+        """``shape`` = (Hkv, page_len, Dh); ``bits`` None (exact, in
+        ``dtype``), 8 or 4."""
+        if bits is None:
+            side = lambda: ExactSide(jnp.zeros((n_pages,) + shape, dtype))
+        else:
+            from ..comm.wire import num_blocks
+            hkv, page_len, dh = shape
+            if bits == 4 and dh % 2:
+                raise ValueError(
+                    f"kv_dtype='q4' packs two nibbles per byte along "
+                    f"the head dim, which must be even (got Dh={dh})")
+            store = (hkv, page_len, dh // 2) if bits == 4 else shape
+            nb = num_blocks(hkv * page_len * dh)
+            side = lambda: QuantSide(
+                jnp.zeros((n_pages,) + store,
+                          jnp.uint8 if bits == 4 else jnp.int8),
+                jnp.ones((n_pages, nb), jnp.float32),
+                jnp.zeros((n_slots,) + shape, jnp.float32))
+        return cls(side(), side())
+
+    @property
+    def n_pages(self) -> int:
+        return self.k[0].shape[0]        # a side's first array: its pages
+
+    def _both(self, op, k, v, *args):
+        return KVPages(getattr(self.k, op)(k, *args),
+                       getattr(self.v, op)(v, *args))
+
+    def write(self, hk, hv, dest, wo, j: int = 0):
+        """One entry a row, position ``j`` of hk / hv (B, Hkv, S, Dh),
+        to offset ``wo`` of page ``dest`` (B,); ``dest == n_pages``
+        drops the row."""
+        return self._both("write", hk, hv, dest, wo, j)
+
+    def write_tail(self, hk, hv, ctx: PrefillCtx):
+        """A prompt's tail, (1, Hkv, S, Dh) each."""
+        return self._both("write_tail", hk, hv, ctx)
+
+    def commit(self, steps, sk, sv):
+        """The accepted prefix of a verify's scratch (B, Hkv, S, Dh):
+        ``steps[j]`` = (dest, wo) of candidate j, a rejected one routed
+        out of bounds, so a page only ever completes from accepted
+        tokens, position by position as decode would have written
+        them."""
+        pages = self
+        for j, (dest, wo) in enumerate(steps):
+            pages = pages.write(sk, sv, dest, wo, j)
+        return pages
+
+    def rows(self, tables, idx, like_k, like_v):
+        """The resident rows a prefill (``tables`` (P,), no ``idx``) or
+        a verify (``tables`` (B, P)) attends over, in the dtypes of this
+        step's K and V."""
+        return (self.k.rows(tables, idx).astype(like_k.dtype),
+                self.v.rows(tables, idx).astype(like_v.dtype))
+
+    def attend(self, ctx: DecodeCtx, hq, hk, hv, scale):
+        """A decode step's attention, this step's entries written.
+        Blockwise, hk / hv are re-selected at the write position per
+        block: identity for active rows (already written), and gives
+        inactive rows ``decode_step_slots``' exact value semantics
+        (their discarded logits still see "their" key). On a TPU an
+        exact store takes the kernel instead: active rows read their key
+        from the pool, inactive rows are skipped
+        (``ops/decode_attention.py``)."""
+        if not ctx.blockwise:
+            # logical rows: gather the updated pool, then re-select the
+            # new key at the write position
+            k, v = self.k.rows(ctx.tables, ctx.idx), \
+                self.v.rows(ctx.tables, ctx.idx)
+            return dense_decode_attention(
+                hq, jnp.where(ctx.write_mask, hk.astype(k.dtype), k),
+                jnp.where(ctx.write_mask, hv.astype(v.dtype), v),
+                ctx.pos_mask, scale=scale)
+        if isinstance(self.k, ExactSide):
+            return paged_decode_attention(
+                hq, self.k.pages, self.v.pages, ctx.tables, ctx.idx, hk, hv,
+                scale=scale, page_len=ctx.page_len, active=ctx.active)
+        return paged_loop_attention(
+            hq, self.k.loader(ctx.idx), self.v.loader(ctx.idx), ctx.tables,
+            ctx.idx, hk, hv, scale=scale, page_len=ctx.page_len,
+            out_dtype=hv.dtype)
+
+    def resident_bytes(self) -> int:
+        return self.k.resident_bytes() + self.v.resident_bytes()
+
+    def require(self, op: str) -> None:
+        """Every operation is here."""
+
+    def export(self, idx, slot: int, valid_last: int, quantized=False):
+        op = "export_quantized" if quantized else "export"
+        return (getattr(self.k, op)(idx, slot, valid_last),
+                getattr(self.v, op)(idx, slot, valid_last))
+
+    def adopt(self, k, v, idx, slot: int, valid_last: int, quantized=False):
+        return self._both("adopt_quantized" if quantized else "adopt",
+                          k, v, idx, slot, valid_last)

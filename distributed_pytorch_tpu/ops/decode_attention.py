@@ -38,12 +38,14 @@ Numerics contract (the mixed-precision guard, docs/compute.md):
 (``ops/paged_attention_kernel.py``) in place of the paged loop: each
 row's resident pages copied from the pool in place, many pages a trip,
 each row walking its own length, rows that hold nothing skipped.
-:func:`paged_decode_attention` picks it from what it can see in its
-input (:func:`_kernel_interpret`: a TPU backend, one device, exact K
-and V pages, not latent, whole-lane heads, whole-tile pages, the rows'
-``active`` mask); everything else — the CPU, the latent pool, the
-quantized pools, head size 64 — runs the loop below exactly as it
-stands, which is also the reference the kernel is tested against. The
+:func:`paged_decode_attention`, the entry of an exact K/V store
+(``nn/paged.py`` ``KVPages.attend``), picks it from what it can see in
+its input (:func:`_kernel_interpret`: a TPU backend, one device,
+whole-lane heads, whole-tile pages, the rows' ``active`` mask);
+everything else — the CPU, head size 64, and the stores of another
+format (the latent pool, the quantized pools), which hand
+:func:`paged_loop_attention` their own page loaders — runs the one loop
+below, which is also the reference the kernel is tested against. The
 contract above holds in both.
 
 Every decode front door routes here (``models/generate.py``:
@@ -66,7 +68,8 @@ from .flash_attention import _MASK
 
 __all__ = ["DECODE_BLOCK", "blockwise_decode_attention",
            "dense_decode_attention", "kernel_traces",
-           "paged_decode_attention", "resident_blocks"]
+           "paged_decode_attention", "paged_loop_attention",
+           "resident_blocks"]
 
 #: Default block length for CONTIGUOUS caches (``decode_step`` /
 #: ``decode_step_slots``); paged pools use their ``page_len``. 128 =
@@ -228,57 +231,28 @@ def _on_one_device(x) -> bool:
 
 @jax.named_scope("decode_attention")
 def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
-                           new_v, *, scale, page_len: int,
-                           k_scales=None, v_scales=None,
-                           k_tail=None, v_tail=None,
-                           latent_width: Optional[int] = None,
-                           active=None,
+                           new_v, *, scale, page_len: int, active=None,
                            interpret: Optional[bool] = None):
-    """Single-token attention over a PAGED pool, one page per step.
+    """Single-token attention over an exact paged K/V pool.
 
     hq: (B, H, 1, Dh); k_pages/v_pages: (n_pages[+1], Hkv, page_len,
     Dh) pool buffers (an out-of-range table id reads garbage a masked
     position never exposes); tables: (B, P) int32 page ids; idx: (B,)
-    int32 positions; new_k/new_v: (B, Hkv, 1, Dh) — THIS step's K/V,
-    re-selected at position ``idx[b]`` so rows whose pool scatter was
-    dropped (inactive slots) still see their own key, value-identical
-    to ``decode_step_slots``' write-mask semantics.
-
-    Visits only ``resident_blocks(idx, page_len, P)`` pages: the page
-    gather itself is inside the loop, so a long pool serving short
-    requests neither reads nor multiplies its dead pages.
-
-    **Quantized resident pool** (``serve/pages``, docs/serving.md):
-    when ``k_scales``/``v_scales`` are given, the pool buffers hold
-    block-quantized int pages (int8 at q8; nibble-packed uint8 with
-    ``Dh/2`` last dim at q4) and ``k_scales``/``v_scales`` are their
-    ``(n_pages[+1], nb)`` f32 per-page-per-block scales — dequant rides
-    the page gather (one scale lookup + multiply per page, f32 math).
-    ``k_tail``/``v_tail`` ``(B, Hkv, page_len, Dh)`` f32 are the
-    per-slot EXACT tail pages (positions not yet quantized): the page
-    holding position ``idx[b]`` is overlaid wholesale from the tail
-    buffer, so un-finalized positions attend exactly and quantization
-    error only ever comes from completed pages' single rounding. All
-    four default to None = the exact path, traced jaxpr unchanged.
-
-    **Latent pool** (``latent_width``; ``nn/latent.py``): the pool is
-    ONE array whose entries are both key and value: ``k_pages`` is
-    ``(n_pages[+1], 1, page_len, E)``, every query head (absorbed
-    queries of width E) scores against the one shared key head, and the
-    values are the first ``latent_width`` of the same gathered page.
-    ``v_pages`` and ``new_v`` are None; the result is (B, H, 1,
-    ``latent_width``).
+    int32 positions; new_k/new_v: (B, Hkv, 1, Dh) — THIS step's K/V
+    (see :func:`paged_loop_attention`).
 
     **The kernel** (``ops/paged_attention_kernel.py``). ``active`` (B,)
     bool says which rows wrote this step's K/V into the pool. Given it,
-    an exact pool that ``paged_attention_kernel.kernel_fits``, on one
-    TPU (``interpret=True``: a test's interpreter), is read by one Mosaic
+    a pool that ``paged_attention_kernel.kernel_fits``, on one TPU
+    (``interpret=True``: a test's interpreter), is read by one Mosaic
     kernel instead of the loop: each active row over its own pages, the
     inactive rows zeros (their logits are discarded; the loop gives them
     ``new_k`` / ``new_v`` instead). Decided here, at trace time, from
-    the arguments alone.
+    the arguments alone. Everything else takes the loop, each page a
+    plain ``take``; so does a store of another format
+    (``nn/paged.py``), which hands the loop its own loaders.
     """
-    if (active is not None and k_scales is None and latent_width is None
+    if (active is not None
             and paged_attention_kernel.kernel_fits(k_pages, v_pages,
                                                    page_len)
             and _on_one_device(hq)):
@@ -289,52 +263,58 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
             return paged_attention_kernel.paged_attention(
                 hq, k_pages, v_pages, tables, idx, active, scale=scale,
                 page_len=page_len, interpret=mode)
+    return _paged_loop(
+        hq, lambda pids, j: jnp.take(k_pages, pids, axis=0),
+        lambda pids, j: jnp.take(v_pages, pids, axis=0), tables, idx,
+        new_k, new_v, scale=scale, page_len=page_len,
+        out_dtype=v_pages.dtype)
+
+
+def _paged_loop(hq, load_k, load_v, tables, idx, new_k, new_v, *, scale,
+                page_len: int, out_dtype, value_width=None):
+    """The paged loop, one page per step, ONE body for every format of
+    resident page: what differs is how page j of every row is loaded.
+
+    ``load_k(pids, j)`` / ``load_v(pids, j)`` give the (B, Hkv,
+    page_len, Dh) block of the pages ``pids`` (B,) = ``tables[:, j]``:
+    a ``take`` for an exact pool; a take, unpack, dequantise and the
+    overlay of the row's exact tail page for a quantized one. With
+    ``load_v`` None the entries are both key and value (the latent
+    pool, ``nn/latent.py``): every query head scores against the one
+    shared key head, the values are the first ``value_width`` of the
+    same block, ``new_v`` is None and the result is (B, H, 1,
+    ``value_width``).
+
+    new_k/new_v (B, Hkv, 1, Dh) are re-selected at position ``idx[b]``
+    so rows whose pool write was dropped (inactive slots) still see
+    their own key, value-identical to ``decode_step_slots``' write-mask
+    semantics (and after a quantized side's tail overlay: the write
+    mask must still win for them).
+
+    Visits only ``resident_blocks(idx, page_len, P)`` pages: the page
+    gather itself is inside the loop, so a long pool serving short
+    requests neither reads nor multiplies its dead pages.
+    """
     b, h, _, dh = hq.shape
-    hkv = k_pages.shape[1]
+    hkv = new_k.shape[1]
     g = h // hkv
     hq_g = hq.reshape(b, hkv, g, 1, dh)
     total = tables.shape[1]
     nb = resident_blocks(idx, page_len, total)
     nk_g = new_k.reshape(b, hkv, 1, dh)
-    latent = latent_width is not None
-    if latent and (v_pages is not None or k_scales is not None):
-        raise ValueError("a latent pool is one exact array: no v_pages, "
-                         "no quantized pages")
-    nv_g = None if latent else new_v.reshape(b, hkv, 1, dh)
-    quant = k_scales is not None
-    if quant:
-        from .quant import (dequantize_page_blocks, page_block_map,
-                            unpack_page_nibbles)
-        packed = k_pages.dtype == jnp.uint8
-        bmap = page_block_map(hkv, page_len, dh)
-        tail_page = idx // page_len
+    nv_g = None if load_v is None else new_v.reshape(b, hkv, 1, dh)
 
     def body(j, carry):
         pos = j * page_len + jnp.arange(page_len)
         with jax.named_scope("page_gather"):
             pids = jax.lax.dynamic_index_in_dim(tables, j, axis=1,
                                                 keepdims=False)  # (B,)
-            k_blk = jnp.take(k_pages, pids, axis=0)  # (B, Hkv, L, Dh)
-            v_blk = None if latent else jnp.take(v_pages, pids, axis=0)
-            if quant:
-                if packed:
-                    k_blk = unpack_page_nibbles(k_blk)
-                    v_blk = unpack_page_nibbles(v_blk)
-                k_blk = dequantize_page_blocks(
-                    k_blk, jnp.take(k_scales, pids, axis=0), bmap)
-                v_blk = dequantize_page_blocks(
-                    v_blk, jnp.take(v_scales, pids, axis=0), bmap)
-                # the slot's CURRENT page is exact: overlay the f32
-                # tail buffer before the write-mask overlay (order
-                # matters — wm must still win for inactive rows' value
-                # semantics)
-                it = (j == tail_page)[:, None, None, None]
-                k_blk = jnp.where(it, k_tail, k_blk)
-                v_blk = jnp.where(it, v_tail, v_blk)
+            k_blk = load_k(pids, j)                  # (B, Hkv, L, Dh)
+            v_blk = None if load_v is None else load_v(pids, j)
         wm = (pos[None, :] == idx[:, None])[:, None, :, None]
         k_blk = jnp.where(wm, nk_g.astype(k_blk.dtype), k_blk)
-        if latent:
-            v_blk = k_blk[..., :latent_width]
+        if load_v is None:
+            v_blk = k_blk[..., :value_width]
         else:
             v_blk = jnp.where(wm, nv_g.astype(v_blk.dtype), v_blk)
         valid = (pos[None, :] <= idx[:, None])
@@ -346,11 +326,14 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
         s = jnp.where(valid5, s, _MASK)
         return _merge_block(carry, s, v_blk, valid5)
 
-    dv = latent_width if latent else dh
+    dv = value_width if load_v is None else dh
     carry = (jnp.full((b, hkv, g, 1), _MASK, jnp.float32),
              jnp.zeros((b, hkv, g, 1), jnp.float32),
              jnp.zeros((b, hkv, g, 1, dv), jnp.float32))
     m, l, acc = jax.lax.fori_loop(0, nb, body, carry)
-    out_dtype = k_pages.dtype if latent else (
-        new_v.dtype if quant else v_pages.dtype)
     return _finish(m, l, acc, out_dtype).reshape(b, h, 1, dv)
+
+
+#: The loop for a store that loads its own pages, under the scope the
+#: trace's readers key on.
+paged_loop_attention = jax.named_scope("decode_attention")(_paged_loop)

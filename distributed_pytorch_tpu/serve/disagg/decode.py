@@ -53,8 +53,6 @@ class DecodeEngine:
     def __init__(self, model, params, router, transport, *,
                  n_slots: int, max_len: int, page_len: int, n_pages: int,
                  kv_dtype: str = "f32", spec=None, buckets=None):
-        from ...models.generate import refuse_latent
-        refuse_latent(model, "the disaggregated hand-off (serve/disagg)")
         self.model = model
         self.params = params
         self.router = router
@@ -65,6 +63,7 @@ class DecodeEngine:
         self.pool = PagedSlotPool(model, n_slots, max_len,
                                   page_len=page_len, n_pages=n_pages,
                                   prefix_share=False, kv_dtype=kv_dtype)
+        self.pool.require("adopt")
         # speculative decoding (serve/spec/): the draft loop lives HERE
         # — this engine owns token cadence, so this is where k-token
         # iterations pay off. ``spec`` is a resolved SpecConfig (the

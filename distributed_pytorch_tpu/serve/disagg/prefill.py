@@ -45,8 +45,6 @@ class PrefillEngine:
     def __init__(self, model, params, router, transport, *, buckets,
                  page_len: int, n_pages: int, prefix_share: bool,
                  bits: Optional[int], kv_dtype: str = "f32"):
-        from ...models.generate import refuse_latent
-        refuse_latent(model, "the disaggregated hand-off (serve/disagg)")
         self.model = model
         self.params = params
         self.router = router
@@ -60,6 +58,7 @@ class PrefillEngine:
                                   page_len=page_len, n_pages=n_pages,
                                   prefix_share=prefix_share,
                                   kv_dtype=kv_dtype)
+        self.pool.require("export")
         self.iterations = 0
         self._cond = threading.Condition()
         self._stop = False

@@ -43,8 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generate import (_check_attn_compatible, _model_window,
-                               refuse_latent)
+from ..models.generate import _check_attn_compatible, _model_window
 from ..obs import metrics as dpxmon
 from ..obs import trace as dpxtrace
 from ..runtime import compile_cache
@@ -199,7 +198,8 @@ class InferenceEngine:
                    else dpxenv.get("DPX_SPEC_DECODE"))
         self._spec: Optional[SpecState] = None
         if spec_on:
-            refuse_latent(model, "speculative decoding (serve/spec)")
+            if cfg.paged:
+                self.pool.require("commit")
             if self.window is not None:
                 raise ValueError(
                     "spec_decode does not support sliding-window "

@@ -39,6 +39,7 @@ from distributed_pytorch_tpu.models.generate import (decode_step_slots,
 from distributed_pytorch_tpu.models.transformer import (REMAT_POLICIES,
                                                         resolve_remat)
 from distributed_pytorch_tpu.nn.attention import dense_attention
+from distributed_pytorch_tpu.nn.paged import ExactSide, KVPages
 from distributed_pytorch_tpu.ops.decode_attention import (
     DECODE_BLOCK, blockwise_decode_attention, paged_decode_attention,
     resident_blocks)
@@ -67,6 +68,12 @@ def _dense_ref(hq, k, v, idx, scale):
 
 def _rand(rng, shape, dtype=jnp.float32):
     return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+def _stores(kp, vp):
+    """Exact K/V page stores, one a layer, over the given pool arrays."""
+    return [KVPages(ExactSide(k), ExactSide(v)) for k, v in zip(kp, vp)]
+
 
 
 class TestBlockwiseKernel:
@@ -342,14 +349,14 @@ class TestDecodePathIntegration:
         active = jnp.asarray([True, True])
         nb = int(resident_blocks(lengths, pl, p_per))
         assert nb == 2 == math.ceil((int(max(lengths)) + 1) / pl)
-        lo, _, _ = decode_step_slots_paged(model, params, kp, vp, tables,
-                                           lengths, tokens, active,
-                                           page_len=pl)
+        lo, _ = decode_step_slots_paged(model, params, _stores(kp, vp),
+                                        tables, lengths, tokens, active,
+                                        page_len=pl)
         poisoned_k = [kp[0].at[4:].set(jnp.nan)]
         poisoned_v = [vp[0].at[4:].set(jnp.nan)]
-        lo_p, _, _ = decode_step_slots_paged(model, params, poisoned_k,
-                                             poisoned_v, tables, lengths,
-                                             tokens, active, page_len=pl)
+        lo_p, _ = decode_step_slots_paged(
+            model, params, _stores(poisoned_k, poisoned_v), tables, lengths,
+            tokens, active, page_len=pl)
         assert bool(jnp.all(jnp.isfinite(lo_p)))
         np.testing.assert_array_equal(np.asarray(lo), np.asarray(lo_p))
 

@@ -16,6 +16,8 @@ import pytest
 
 from distributed_pytorch_tpu import models
 from distributed_pytorch_tpu.models.generate import LatentPagesUnsupported
+from distributed_pytorch_tpu.nn.latent import LatentPages
+from distributed_pytorch_tpu.nn.paged import KVPages
 from distributed_pytorch_tpu.nn.hyper import HyperConnection, sinkhorn
 from distributed_pytorch_tpu.nn.rotary import yarn_inv_freq, yarn_mscale
 from distributed_pytorch_tpu.parallel.moe import DroplessMoE
@@ -80,8 +82,8 @@ def test_paged_prefill_and_absorbed_decode_agree_with_the_full_forward(tiny):
     the expanded full forward over prompt + served tokens."""
     model, params = tiny
     pool = PagedSlotPool(model, 3, 64, page_len=PAGE, n_pages=48)
-    assert pool.latent and pool.v_pages == []
-    assert pool.k_pages[0].shape == (48, 1, PAGE, 128)   # 24 wide, padded
+    assert all(type(st) is LatentPages for st in pool.state)
+    assert pool.state[0].entries.shape == (48, 1, PAGE, 128)  # 24 wide, padded
     rng = np.random.default_rng(0)
     first = rng.integers(0, 211, 13).astype(np.int32)
     second = np.concatenate([first[:2 * PAGE],
@@ -104,6 +106,21 @@ def test_paged_prefill_and_absorbed_decode_agree_with_the_full_forward(tiny):
     assert pool.page_stats()["bytes_per_resident_token"] == 3 * entry
 
 
+def test_a_step_in_flight_leaves_the_counters_readable(tiny):
+    """``stats()`` reads the expert counters from another thread while
+    the engine's thread dispatches a decode step: the step donates the
+    page stores, never the counters it was handed."""
+    model, params = tiny
+    pool = PagedSlotPool(model, 2, 32, page_len=PAGE, n_pages=16)
+    pool.admit(params, np.arange(9, dtype=np.int32), 0, (16,))
+    pool.ensure_decode_capacity(0)
+    before, pages = pool.moe_counts, pool.state[0].entries
+    pool.decode(params, np.asarray([5, 0], np.int32), np.asarray([True, False]))
+    assert pages.is_deleted() and not before.is_deleted()
+    assert np.asarray(before).tolist() == [0, 0, 0, 0]
+    assert pool.moe_stats()["moe_decode_steps"] == 1
+
+
 def test_dense_decode_path_agrees_with_the_blockwise_one(tiny):
     from distributed_pytorch_tpu.models.generate import (
         decode_step_slots_paged)
@@ -111,11 +128,11 @@ def test_dense_decode_path_agrees_with_the_blockwise_one(tiny):
     pool = PagedSlotPool(model, 2, 32, page_len=PAGE, n_pages=16)
     pool.admit(params, np.arange(9, dtype=np.int32), 0, (16,))
     pool.ensure_decode_capacity(0)
-    args = (model, params, pool.k_pages, [], jnp.array(pool.tables),
+    args = (model, params, pool.state, jnp.array(pool.tables),
             jnp.array(pool.lengths), jnp.asarray([5, 0], jnp.int32),
             jnp.asarray([True, False]))
-    a, _, _ = decode_step_slots_paged(*args, page_len=PAGE, blockwise=True)
-    b, _, _ = decode_step_slots_paged(*args, page_len=PAGE, blockwise=False)
+    a, _ = decode_step_slots_paged(*args, page_len=PAGE, blockwise=True)
+    b, _ = decode_step_slots_paged(*args, page_len=PAGE, blockwise=False)
     np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b[0]), atol=1e-4)
 
 
@@ -309,7 +326,7 @@ def test_the_fixed_block_is_untouched_by_the_new_keywords():
                                  n_kv_heads=2, max_seq=32, pos="rope",
                                  norm="rms", ffn_dim=48)
     pool = PagedSlotPool(parts, 2, 32, page_len=PAGE, n_pages=16)
-    assert not pool.latent and len(pool.v_pages) == 2
+    assert all(type(st) is KVPages for st in pool.state)
     pp = parts.init(jax.random.PRNGKey(1))
     prompt = np.arange(7, dtype=np.int32)
     toks, rows, _ = serve_through_pool(parts, pp, pool, prompt, 0, 6)

@@ -14,6 +14,8 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_pytorch_tpu import models
 from distributed_pytorch_tpu.nn.attention import write_rows
+from distributed_pytorch_tpu.nn.latent import LatentPages
+from distributed_pytorch_tpu.nn.paged import DecodeCtx, ExactSide, KVPages
 from distributed_pytorch_tpu.ops import decode_attention, paged_attention_kernel
 from distributed_pytorch_tpu.ops.decode_attention import (
     dense_decode_attention, kernel_traces, paged_decode_attention)
@@ -193,8 +195,10 @@ def test_one_compile_for_every_mix():
 
 def _call(dtype=jnp.bfloat16, dh=DH, page_len=16, latent=False,
           scales=False, active=True, interpret=None):
-    """``paged_decode_attention`` on a small pool; returns how many
-    times it took the kernel (0 or 1)."""
+    """A decode step's attention on a small pool, through the exact
+    entry or, for the latent and the quantized format, through the
+    store's ``attend``; returns how many times it took the kernel (0 or
+    1)."""
     rng = np.random.default_rng(5)
     b, hkv, g, pages = 2, 1, 2, 4
     hq = _rand(rng, (b, hkv * g, 1, dh), dtype)
@@ -202,20 +206,19 @@ def _call(dtype=jnp.bfloat16, dh=DH, page_len=16, latent=False,
     tables = jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
     idx = jnp.asarray([3, 2 * page_len + 1], jnp.int32)
     nk = _new_rows(kp, tables, idx, page_len)
-    kw = {}
-    if scales:
-        from distributed_pytorch_tpu.serve.pages.quant import num_page_blocks
-        ones = jnp.ones((b * pages, num_page_blocks(hkv, page_len, dh)))
-        tail = jnp.zeros((b, hkv, page_len, dh), jnp.float32)
-        kp = jnp.zeros(kp.shape, jnp.int8)
-        kw = dict(k_scales=ones, v_scales=ones, k_tail=tail, v_tail=tail)
+    ctx = DecodeCtx(tables=tables, idx=idx, dest=None, wo=None,
+                    active=jnp.ones((b,), bool), pos_mask=None,
+                    write_mask=None, page_len=page_len)
     before = kernel_traces()
-    out = paged_decode_attention(
-        hq, kp, None if latent else kp, tables, idx, nk,
-        None if latent else nk, scale=SCALE, page_len=page_len,
-        latent_width=dh // 2 if latent else None,
-        active=jnp.ones((b,), bool) if active else None,
-        interpret=interpret, **kw)
+    if latent:
+        out = LatentPages(kp).attend(ctx, hq, nk, SCALE, dh // 2)
+    elif scales:
+        out = KVPages.zeros((hkv, page_len, dh), b * pages, b, 8,
+                            dtype).attend(ctx, hq, nk, nk, SCALE)
+    else:
+        out = paged_decode_attention(
+            hq, kp, kp, tables, idx, nk, nk, scale=SCALE, page_len=page_len,
+            active=ctx.active if active else None, interpret=interpret)
     assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
     return kernel_traces() - before
 
@@ -225,8 +228,8 @@ CHOICES = [
     pytest.param(dict(interpret=True, dtype=jnp.float32, page_len=8), 1,
                  id="f32-page8-takes-the-kernel"),
     pytest.param(dict(), 0, id="cpu-default-takes-the-loop"),
-    pytest.param(dict(interpret=True, latent=True), 0, id="latent-loop"),
-    pytest.param(dict(interpret=True, scales=True), 0, id="quantized-loop"),
+    pytest.param(dict(latent=True), 0, id="latent-loop"),
+    pytest.param(dict(scales=True), 0, id="quantized-loop"),
     pytest.param(dict(interpret=True, dh=64), 0, id="head-64-loop"),
     pytest.param(dict(interpret=True, page_len=8), 0,
                  id="bf16-page8-is-half-a-tile-loop"),
@@ -236,7 +239,12 @@ CHOICES = [
 
 
 @pytest.mark.parametrize("kw,took", CHOICES)
-def test_which_inputs_take_the_kernel(kw, took):
+def test_which_inputs_take_the_kernel(kw, took, monkeypatch):
+    if "interpret" not in kw and kw:
+        # a store of another format never asks: even where the probe
+        # would answer with a kernel, it hands the loop its loaders
+        monkeypatch.setattr(decode_attention, "_kernel_interpret",
+                            lambda interpret: True)
     assert _call(**kw) == took
 
 
@@ -326,17 +334,29 @@ def test_engine_counts_the_layers_that_took_the_kernel(monkeypatch):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("n_rows", [3, 20], ids=["decode", "prefill"])
-def test_write_rows_is_the_page_scatter(n_rows, dtype):
+@pytest.mark.parametrize("case", ["decode", "prefill", "tail", "commit"])
+def test_write_rows_is_the_page_scatter(case, dtype):
     """The row form writes what ``pool.at[dest, :, wo].set`` writes, a
-    dropped row (``dest == n_pages``) included, bit for bit."""
+    dropped row (``dest`` past the end) included, bit for bit: a decode
+    step's rows, a prompt's tail, a quantized side's per-slot tail pages
+    (row b to slot b, an idle row dropped) and one position of a
+    speculative commit (rejected rows dropped)."""
+    n_rows = {"decode": 3, "prefill": 20, "tail": 5, "commit": 4}[case]
     rng = np.random.default_rng(n_rows)
     n_pages, hkv, page_len = 6, 2, 8
-    pool = _rand(rng, (n_pages, hkv, page_len, 16), dtype)
     rows = _rand(rng, (n_rows, hkv, 16), jnp.float32)
-    slots = rng.permutation(n_pages * page_len)[:n_rows]   # no duplicates
-    dest = jnp.asarray(slots // page_len, jnp.int32).at[1].set(n_pages)
-    wo = jnp.asarray(slots % page_len, jnp.int32)
+    if case == "tail":
+        n_pages = n_rows                       # one page a slot
+        dest = jnp.arange(n_rows, dtype=jnp.int32).at[2].set(n_pages)
+        wo = jnp.asarray(rng.integers(0, page_len, n_rows), jnp.int32)
+    else:
+        slots = rng.permutation(n_pages * page_len)[:n_rows]   # no duplicates
+        dest = jnp.asarray(slots // page_len, jnp.int32).at[1].set(n_pages)
+        wo = jnp.asarray(slots % page_len, jnp.int32)
+    if case == "commit":                       # candidate j of a scratch
+        rows = _rand(rng, (n_rows, hkv, 3, 16), jnp.float32)[:, :, 1, :]
+        dest = jnp.where(jnp.asarray([2, 0, 1, 3]) > 1, dest, n_pages)
+    pool = _rand(rng, (n_pages, hkv, page_len, 16), dtype)
     ref = pool.at[dest, :, wo].set(rows.astype(dtype), mode="drop")
     out = write_rows(pool, dest, wo, rows)
     assert out.dtype == dtype
@@ -407,12 +427,13 @@ def test_decode_program_moves_no_pool_for_v5e(one_chip, monkeypatch):
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     pool = [s((slots * pages_per_row, 2, page_len, DH), jnp.bfloat16)] * layers
 
-    def step(params, kp, vp, tables, lengths, tokens, active):
-        return decode_step_slots_paged(model, params, kp, vp, tables,
+    def step(params, state, tables, lengths, tokens, active):
+        return decode_step_slots_paged(model, params, state, tables,
                                        lengths, tokens, active,
                                        page_len=page_len)
-    text = jax.jit(step, donate_argnums=(1, 2)).lower(
-        params, pool, pool, s((slots, pages_per_row), jnp.int32),
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, [KVPages(ExactSide(p), ExactSide(p)) for p in pool],
+        s((slots, pages_per_row), jnp.int32),
         s((slots,), jnp.int32), s((slots,), jnp.int32),
         s((slots,), jnp.bool_)).compile().as_text()
     assert text.count("tpu_custom_call") == layers

@@ -333,7 +333,7 @@ def test_serving_programs_name_their_layers_and_themselves():
         paged=True, n_slots=2, max_len=64, buckets=(8, 16), page_len=8))
     pool = eng.pool
     names = op_names(pool._decode_fn.lower(
-        params, pool.k_pages, pool.v_pages, jnp.array(pool.tables),
+        params, pool.state, pool.moe_counts, jnp.array(pool.tables),
         jnp.array(pool.lengths), jnp.zeros(2, jnp.int32),
         jnp.ones(2, bool)))
     for want in ("/embed/", "/blocks/norm/", "/blocks/attn/qkv/",

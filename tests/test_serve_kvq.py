@@ -300,8 +300,8 @@ class TestQuantPoolQuality:
         assert length == length_q == s
         for i in range(model.n_layers):
             for exact, got, scales in (
-                    (ksf[i], ksq[i], np.asarray(pq.k_scales[i])),
-                    (vsf[i], vsq[i], np.asarray(pq.v_scales[i]))):
+                    (ksf[i], ksq[i], np.asarray(pq.state[i].k.scales)),
+                    (vsf[i], vsq[i], np.asarray(pq.state[i].v.scales))):
                 row = pq.owned[0]
                 per_page = scales[np.asarray(row)]      # (P, nb)
                 bound = per_page[

@@ -21,6 +21,7 @@ from distributed_pytorch_tpu import models
 from distributed_pytorch_tpu.models.generate import (make_generate_fn,
                                                      prefill_partial,
                                                      prefill_partial_paged)
+from distributed_pytorch_tpu.nn.paged import ExactSide, KVPages
 from distributed_pytorch_tpu.runtime import faults
 from distributed_pytorch_tpu.serve import (AdmissionRejected, EngineConfig,
                                            EngineStopped, InferenceEngine,
@@ -167,10 +168,11 @@ class TestPagedOps:
         kp = [jnp.zeros(shape, model.dtype) for _ in range(model.n_layers)]
         vp = [jnp.zeros(shape, model.dtype) for _ in range(model.n_layers)]
         table = jnp.arange(4, dtype=jnp.int32)
-        got, _, _ = jax.jit(
-            lambda p, k, v, tr, t, o, n: prefill_partial_paged(
-                model, p, k, v, tr, t, o, n, page_len=page_len))(
-            params, kp, vp, table, padded, 0, s)
+        state = [KVPages(ExactSide(k), ExactSide(v)) for k, v in zip(kp, vp)]
+        got, _ = jax.jit(
+            lambda p, st, tr, t, o, n: prefill_partial_paged(
+                model, p, st, tr, t, o, n, page_len=page_len))(
+            params, state, table, padded, 0, s)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=2e-5, atol=2e-6)
         assert int(jnp.argmax(ref)) == int(jnp.argmax(got))

@@ -351,10 +351,10 @@ class TestRollbackEdges:
         prompt = (np.arange(1, 7, dtype=np.int32) % 61)   # 6 tokens
         pool.admit(params, prompt, 0, BUCKETS)
         pid0 = pool.owned[0][0]
-        ones = np.ones_like(np.asarray(pool.k_scales[0][pid0]))
+        ones = np.ones_like(np.asarray(pool.state[0].k.scales[pid0]))
         # page 0 incomplete: still tail-resident, scales untouched
         np.testing.assert_array_equal(
-            np.asarray(pool.k_scales[0][pid0]), ones)
+            np.asarray(pool.state[0].k.scales[pid0]), ones)
         toks = np.array([[2, 3, 4, 5]], np.int32)
         _, sk, sv = pool.spec_verify(params, toks)
         # accept 2 of 4: positions 6,7 — ends EXACTLY at the boundary,
@@ -364,7 +364,7 @@ class TestRollbackEdges:
         assert int(pool.lengths[0]) == 8
         # page 0 completed from accepted tokens → quantized now
         assert not np.array_equal(
-            np.asarray(pool.k_scales[0][pid0]), ones)
+            np.asarray(pool.state[0].k.scales[pid0]), ones)
         # the rejected suffix never demanded (or touched) page 1
         assert len(pool.owned[0]) == 1
         # next iteration: accept ONE token into a fresh page — it must
@@ -375,8 +375,8 @@ class TestRollbackEdges:
         assert int(pool.lengths[0]) == 9
         pid1 = pool.owned[0][1]
         np.testing.assert_array_equal(
-            np.asarray(pool.k_scales[0][pid1]), ones)
-        assert np.abs(np.asarray(pool.k_tail[0][0, :, 0, :])).sum() > 0
+            np.asarray(pool.state[0].k.scales[pid1]), ones)
+        assert np.abs(np.asarray(pool.state[0].k.tail[0, :, 0, :])).sum() > 0
 
     def test_draft_len_longer_than_remaining(self):
         """k = 6 against max_new = 3: acceptance is capped by the
