@@ -32,6 +32,7 @@ from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.generate import (_sample, decode_step_slots,
                                prefill_partial, refuse_latent,
@@ -71,6 +72,18 @@ def named_program(fn, name: str, **bound):
     fn = partial(fn, **bound)
     fn.__name__ = name
     return fn
+
+
+def upload(mirror) -> jnp.ndarray:
+    """A host mirror (page tables, lengths, the rows' current tokens) as
+    a device array, from a copy numpy makes HERE and nobody else holds:
+    the host goes on changing the mirror right after the call. Neither
+    ``jnp.asarray`` (may alias the buffer) nor ``jnp.array`` is enough:
+    the transfer of a host buffer may wait behind a program in flight
+    (on the CPU backend half of 50 trials read the value the host wrote
+    AFTER ``jnp.array`` returned), and since chunked prefill a decode
+    step is dispatched behind a chunk nobody waits for."""
+    return jnp.asarray(np.array(mirror))
 
 
 def greedy_tokens(logits):

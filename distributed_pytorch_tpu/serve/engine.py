@@ -7,8 +7,11 @@ One engine thread runs the iteration loop; each iteration
 2. sweeps deadlines (queued AND running requests; a miss surfaces as a
    typed ``RequestDeadlineExceeded`` on that request's future, other
    slots untouched),
-3. admits queued requests into free slots (prefill, right-padded to a
-   length bucket — one compile per bucket), and
+3. prefills: the paged pool at most ONE chunk of one prompt (at most
+   ``serve.pages.cache.chunk_tokens`` tokens, right-padded to a length
+   bucket — one compile per bucket), so that an admitted prompt stalls
+   the running rows for a chunk and not for its whole prefill; the
+   contiguous pool every queued prompt whole, and
 4. advances EVERY active slot one token through the single jitted
    decode program (``serve.cache.SlotPool``), retiring slots that hit
    ``max_new_tokens`` / ``eos_token`` so the next iteration can refill
@@ -50,16 +53,16 @@ from ..runtime import compile_cache
 from ..runtime import env as dpxenv
 from ..runtime import faults
 from ..utils.logging import MetricsLogger
-from .cache import SlotPool
+from .cache import SlotPool, upload
 from .metrics import emit_request_trace, request_record
-from .pages import PagedSlotPool
+from .pages import PagedSlotPool, chunk_tokens
 from .sampling import RowSampler
 from .scheduler import AdmissionScheduler
 from .spec import SpecConfig, SpecState, accept_greedy
-from .types import (FAILED, FINISHED, QUEUED, RUNNING, AdmissionRejected,
-                    EngineStopped, PagePoolExhausted, Request,
-                    RequestDeadlineExceeded, RequestHandle, SamplingParams,
-                    SpecDecodeError)
+from .types import (FAILED, FINISHED, PREFILLING, QUEUED, RUNNING,
+                    AdmissionRejected, EngineStopped, PagePoolExhausted,
+                    Request, RequestDeadlineExceeded, RequestHandle,
+                    SamplingParams, SpecDecodeError)
 
 
 def _default_buckets(cap: int) -> Tuple[int, ...]:
@@ -182,6 +185,7 @@ class InferenceEngine:
                      else dpxenv.get("DPX_SERVE_PREFIX_SHARE"))
             kv_dtype = (cfg.kv_dtype if cfg.kv_dtype is not None
                         else dpxenv.get("DPX_SERVE_KV_DTYPE"))
+            chunk_tokens(self.buckets, page_len)    # refuses what it cannot chunk
             self.pool = PagedSlotPool(model, cfg.n_slots, cfg.max_len,
                                       page_len=page_len, n_pages=n_pages,
                                       prefix_share=bool(share),
@@ -230,6 +234,9 @@ class InferenceEngine:
         self._scheduler = AdmissionScheduler(cfg.max_queue)
         self._sampler = RowSampler(cfg.n_slots, self.pool.compiles)
         self._running: Dict[int, Request] = {}     # slot -> request
+        # the one request between the pool's begin and its last chunk:
+        # it owns its slot and pages and is no row of the decode program
+        self._prefilling: Optional[Request] = None
         self._free: List[int] = list(range(cfg.n_slots))[::-1]
         self._cur_tokens = np.zeros(cfg.n_slots, np.int32)
         self._iteration = 0
@@ -238,6 +245,8 @@ class InferenceEngine:
         self._host_ns = dict.fromkeys(
             ("idle", "admit", "decode_dispatch", "row_loop", "iter"), 0)
         self._admitted = 0
+        self._prefill_chunks = 0            # chunk programs run
+        self._prefill_chunk_iterations = 0  # iterations: a chunk AND a decode
         self._rows_decoded = 0
         self._decode_fetches = 0  # device-to-host token reads, decode path
         self._tokens_emitted = 0
@@ -331,7 +340,9 @@ class InferenceEngine:
             raise AdmissionRejected(
                 f"request {rid}: empty prompt or max_new_tokens < 1",
                 reason="invalid", request_id=rid)
-        if s > max(self.buckets):
+        if not self._paged and s > max(self.buckets):
+            # the paged pool prefills in chunks: any prompt its slot
+            # row holds is admitted
             raise AdmissionRejected(
                 f"request {rid}: prompt length {s} exceeds the largest "
                 f"prefill bucket ({max(self.buckets)})",
@@ -413,6 +424,8 @@ class InferenceEngine:
                "completed": self._completed, "failed": self._failed,
                "tokens_emitted": self._tokens_emitted,
                "admitted": self._admitted,
+               "prefill_chunks": self._prefill_chunks,
+               "prefill_chunk_iterations": self._prefill_chunk_iterations,
                "rows_decoded": self._rows_decoded,
                "decode_fetches": self._decode_fetches,
                "sample_dispatches": self._sampler.dispatches,
@@ -468,6 +481,7 @@ class InferenceEngine:
                 # this lock, and no deadline can be pending while the
                 # queue AND the running set are empty
                 while (not self._stop and not self._running
+                       and self._prefilling is None
                        and not len(self._scheduler)):
                     with dpxtrace.span("serve.idle"):
                         # dpxlint: disable=DPX003 untimed wait safe per the invariant above: every idle-exit transition notifies under this lock
@@ -486,11 +500,13 @@ class InferenceEngine:
                                        iteration=self._iteration):
                         self._sweep_deadlines(now)
                     t_admit = clock()
-                    self._admit_from_queue()
+                    chunks = self._admit_from_queue()
                     host["admit"] += clock() - t_admit
                     it.set(rows=len(self._running))
                     if self._running:
                         self._decode_all()
+                        if chunks:
+                            self._prefill_chunk_iterations += 1
             except Exception as e:  # noqa: BLE001
                 # an engine-loop crash (XLA error, bad params) must not
                 # strand every future unresolved: fail them typed, with
@@ -558,6 +574,17 @@ class InferenceEngine:
                 deadline_ms=req.params.deadline_ms, stage="queued",
                 request_id=req.request_id, iteration=self._iteration),
                 outcome="deadline_queued")
+        req = self._prefilling
+        if (req is not None and req.deadline_t is not None
+                and now >= req.deadline_t):
+            self._fail(req, RequestDeadlineExceeded(
+                f"request {req.request_id} missed its deadline "
+                f"({req.params.deadline_ms} ms) with "
+                f"{self.pool.prefilling[req.slot].done} of "
+                f"{req.prompt.shape[0]} prompt tokens prefilled",
+                deadline_ms=req.params.deadline_ms, stage="prefilling",
+                request_id=req.request_id, iteration=self._iteration),
+                outcome="deadline_prefilling")
         for slot, req in list(self._running.items()):
             if req.deadline_t is not None and now >= req.deadline_t:
                 self._fail(req, RequestDeadlineExceeded(
@@ -568,92 +595,132 @@ class InferenceEngine:
                     request_id=req.request_id, iteration=self._iteration),
                     outcome="deadline_running")
 
-    def _admit_from_queue(self) -> None:
+    def _admit_from_queue(self) -> int:
+        """This iteration's prefill work; returns the chunk programs it
+        ran. Paged: the request mid-prefill gets its next chunk, else
+        the next queued one is begun and gets its first: ONE chunk
+        while rows are running (they decode next, behind it), chunks
+        back to back while none is (an empty engine has nothing to
+        protect). Contiguous: every queued prompt whole."""
+        if not self._paged:
+            while self._free:
+                req = self._scheduler.pop()
+                if req is None:
+                    break
+                self._admit_whole(req)
+            return 0
+        chunks = 0
+        while True:
+            if self._prefilling is None and not self._begin_next():
+                return chunks
+            self._prefill_chunk()
+            chunks += 1
+            if self._running:
+                return chunks
+
+    def _admitted_now(self, req: Request) -> None:
+        req.admit_t = time.monotonic()
+        req.admit_iteration = self._iteration
+        self._admitted += 1
+
+    def _begin_next(self) -> bool:
+        """Give the next queued request a slot and ALL its prompt's
+        pages (``pool.begin``: no program runs). False when nothing can
+        be begun now: no free slot, an empty queue, or a pool without
+        the pages, which requeues the request until a retirement frees
+        some (or fails it, where no running request ever could)."""
         while self._free:
             req = self._scheduler.pop()
             if req is None:
-                return
-            with dpxtrace.span("serve.admit", iteration=self._iteration,
-                               trace_id=req.trace_id,
-                               request_id=req.request_id,
-                               prompt_len=int(req.prompt.shape[0])) as adm:
-                slot = self._free.pop()
-                # claim the slot BEFORE the prefill call: if it raises, the
-                # crash drain finds the request in _running and fails its
-                # future instead of stranding it half-admitted
+                return False
+            # claim the slot BEFORE the pool is asked: whatever raises,
+            # the crash drain finds the request and fails its future
+            # instead of stranding it half-admitted
+            slot = req.slot = self._free.pop()
+            req.state = PREFILLING
+            self._prefilling = req
+            try:
+                req.prefix_hit_pages, req.prefill_tokens_saved = \
+                    self.pool.begin(req.prompt, slot, self.buckets)
+            except PagePoolExhausted as e:
+                # typed back-pressure into the scheduler: unwind the slot
+                # claim and retry after a retirement frees pages — or
+                # fail NOW when no running request could ever free them
+                # (permanent exhaustion)
+                self._prefilling = None
+                self._free.append(slot)
+                req.slot = None
+                if self._running:
+                    req.state = QUEUED
+                    self._scheduler.requeue(req)
+                    return False
+                exc = AdmissionRejected(
+                    f"request {req.request_id}: page pool exhausted at "
+                    f"admission ({e.needed} page(s) needed, "
+                    f"{e.free_pages} free) with no running request to "
+                    f"release pages", reason="no_free_pages",
+                    request_id=req.request_id, iteration=self._iteration)
+                exc.__cause__ = e
+                self._fail(req, exc, outcome="no_free_pages")
+                continue
+            self._admitted_now(req)
+            return True
+        return False
+
+    def _prefill_chunk(self) -> None:
+        """One chunk of the request mid-prefill. A chunk that is not its
+        prompt's last is dispatched and not waited for: nothing of it is
+        fetched, and the decode program queues behind it. The last one
+        samples and emits the first token, and the request is a running
+        row from this iteration's decode on."""
+        req = self._prefilling
+        with dpxtrace.span("serve.admit", iteration=self._iteration,
+                           trace_id=req.trace_id, request_id=req.request_id,
+                           prompt_len=int(req.prompt.shape[0]),
+                           n_hit=req.prefix_hit_pages) as adm:
+            with dpxtrace.span("serve.admit.prefill",
+                               iteration=self._iteration):
+                ch = self.pool.chunk(self.params, req.slot)
+            self._prefill_chunks += 1
+            adm.set(chunk=ch.index, offset=ch.offset, bucket=ch.bucket)
+            if ch.logits is not None:
+                self._prefilling = None
                 req.state = RUNNING
-                req.slot = slot
-                self._running[slot] = req
-                s = int(req.prompt.shape[0])
-                if self._paged:
-                    try:
-                        with dpxtrace.span("serve.admit.prefill",
-                                           iteration=self._iteration):
-                            logits, n_hit, offset = self.pool.admit(
-                                self.params, req.prompt, slot, self.buckets)
-                    except PagePoolExhausted as e:
-                        # typed back-pressure into the scheduler: unwind the
-                        # slot claim and retry after a retirement frees
-                        # pages — or fail NOW when no running request could
-                        # ever free them (permanent exhaustion)
-                        self._running.pop(slot, None)
-                        self._free.append(slot)
-                        req.slot = None
-                        if self._running:
-                            req.state = QUEUED
-                            self._scheduler.requeue(req)
-                            return
-                        exc = AdmissionRejected(
-                            f"request {req.request_id}: page pool exhausted "
-                            f"at admission ({e.needed} page(s) needed, "
-                            f"{e.free_pages} free) with no running request "
-                            f"to release pages", reason="no_free_pages",
-                            request_id=req.request_id,
-                            iteration=self._iteration)
-                        exc.__cause__ = e
-                        self._fail(req, exc, outcome="no_free_pages")
-                        continue
-                    except AdmissionRejected as e:
-                        # pool-level typed rejection (e.g. tail_too_long):
-                        # deterministic for this prompt — requeueing could
-                        # never succeed, so fail now, request-attributed
-                        self._running.pop(slot, None)
-                        self._free.append(slot)
-                        req.slot = None
-                        exc = AdmissionRejected(
-                            f"request {req.request_id}: {e}", reason=e.reason,
-                            request_id=req.request_id,
-                            iteration=self._iteration)
-                        exc.__cause__ = e
-                        self._fail(req, exc, outcome=e.reason)
-                        continue
-                    req.prefix_hit_pages = n_hit
-                    req.prefill_tokens_saved = offset
-                    adm.set(n_hit=n_hit, bucket=next(
-                        b for b in self.buckets if b >= s - offset))
-                else:
-                    bucket = next(b for b in self.buckets if b >= s)
-                    padded = np.zeros((1, bucket), np.int32)
-                    padded[0, :s] = req.prompt
-                    with dpxtrace.span("serve.admit.prefill",
-                                       iteration=self._iteration):
-                        logits = self.pool.admit(
-                            self.params, jnp.asarray(padded), s, slot)
-                    adm.set(n_hit=0, bucket=bucket)
-                if self._spec is not None and req.params.temperature == 0.0:
-                    # greedy requests speculate: prefill the draft's own
-                    # slot too (a prompt no draft bucket fits just runs
-                    # non-speculative — mixed batches are first-class)
-                    self._spec.admit(req.prompt, slot, self.buckets)
-                req.admit_t = time.monotonic()
-                req.admit_iteration = self._iteration
-                self._admitted += 1
-                # the fetch is where the host waits for the prefill
-                with dpxtrace.span("serve.admit.first_token",
-                                   iteration=self._iteration):
-                    tok = int(np.asarray(
-                        self._sampler.first(req, logits))[0])
-                    self._emit(req, tok)
+                self._running[req.slot] = req
+                self._first_token(req, ch.logits)
+
+    def _admit_whole(self, req: Request) -> None:
+        """The contiguous pool's admission: the whole prompt in one
+        prefill, padded to its bucket."""
+        s = int(req.prompt.shape[0])
+        with dpxtrace.span("serve.admit", iteration=self._iteration,
+                           trace_id=req.trace_id, request_id=req.request_id,
+                           prompt_len=s, n_hit=0) as adm:
+            slot = req.slot = self._free.pop()
+            req.state = RUNNING
+            self._running[slot] = req   # before the prefill: see _begin_next
+            bucket = next(b for b in self.buckets if b >= s)
+            adm.set(bucket=bucket)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :s] = req.prompt
+            with dpxtrace.span("serve.admit.prefill",
+                               iteration=self._iteration):
+                logits = self.pool.admit(
+                    self.params, jnp.asarray(padded), s, slot)
+            self._admitted_now(req)
+            self._first_token(req, logits)
+
+    def _first_token(self, req: Request, logits) -> None:
+        if self._spec is not None and req.params.temperature == 0.0:
+            # greedy requests speculate: prefill the draft's own slot
+            # too (a prompt no draft bucket fits just runs
+            # non-speculative — mixed batches are first-class)
+            self._spec.admit(req.prompt, req.slot, self.buckets)
+        # the fetch is where the host waits for the prefill
+        with dpxtrace.span("serve.admit.first_token",
+                           iteration=self._iteration):
+            tok = int(np.asarray(self._sampler.first(req, logits))[0])
+            self._emit(req, tok)
 
     def _decode_all(self) -> None:
         spec_slots: List[int] = []
@@ -697,7 +764,7 @@ class InferenceEngine:
             with dpxtrace.span("serve.decode.dispatch", iteration=it,
                                rows=rows):
                 tokens, logits = self.pool.decode(
-                    self.params, jnp.array(self._cur_tokens),
+                    self.params, upload(self._cur_tokens),
                     jnp.asarray(active))
             t1 = clock()
             with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
@@ -862,7 +929,8 @@ class InferenceEngine:
     def _free_slot(self, req: Request) -> None:
         if req.slot is not None:
             # every exit path (retire, deadline, crash drain) runs
-            # through here. Paged: page refcounts can never leak —
+            # through here, for a running row and for a request still
+            # prefilling. Paged: page refcounts can never leak —
             # private pages free immediately, indexed prompt pages stay
             # resident for future prefix hits. Contiguous: the slot's
             # length zeroes so the blockwise decode's max(lengths) trip
@@ -873,6 +941,8 @@ class InferenceEngine:
                 # typed failure, crash drain alike (serve/spec/)
                 self._spec.release(req.slot)
             self._running.pop(req.slot, None)
+            if self._prefilling is req:
+                self._prefilling = None
             self._free.append(req.slot)
             req.slot = None
 
@@ -938,7 +1008,9 @@ class InferenceEngine:
     def _drain_on_stop(self) -> None:
         cause = f" (engine loop crashed: {self._crash!r})" \
             if self._crash is not None else ""
-        for req in self._scheduler.drain() + list(self._running.values()):
+        held = [self._prefilling] if self._prefilling is not None else []
+        for req in (self._scheduler.drain() + held
+                    + list(self._running.values())):
             exc = EngineStopped(
                 f"engine stopped with request {req.request_id} "
                 f"{req.state}{cause}", request_id=req.request_id,

@@ -13,8 +13,8 @@ substrate; ``EngineConfig(paged=True)`` turns it on. docs/serving.md
 has the layout, lifecycle, and failure model.
 """
 
-from .cache import PagedSlotPool  # noqa: F401
+from .cache import PagedSlotPool, chunk_tokens  # noqa: F401
 from .pool import PagePool  # noqa: F401
 from .prefix import PrefixIndex  # noqa: F401
 
-__all__ = ["PagePool", "PagedSlotPool", "PrefixIndex"]
+__all__ = ["PagePool", "PagedSlotPool", "PrefixIndex", "chunk_tokens"]

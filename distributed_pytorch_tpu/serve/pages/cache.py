@@ -29,13 +29,23 @@ The one-program discipline of ``SlotPool`` is preserved exactly: page
 tables, lengths, offsets and true lengths are all TRACED, so the whole
 serving life is still ONE jitted decode program
 (``models.generate.decode_step_slots_paged``) plus one jitted admit per
-tail-length bucket (``prefill_partial_paged``), counted by the same
+chunk-length bucket (``prefill_partial_paged``), counted by the same
 ``CompileCounts`` the tests assert on.
+
+**Chunked prefill.** An admission is :meth:`PagedSlotPool.begin` (the
+lookup, the refcounts, ALL the prompt's pages: everything that can fail
+for want of a page, before any program runs) and then one
+:meth:`PagedSlotPool.chunk` per at most ``chunk_tokens(buckets,
+page_len)`` tokens, each the same admit program at the traced offset
+where the last one stopped: a chunk is a tail whose prefix the earlier
+chunks made resident. The engine runs one chunk an iteration between two
+decode steps, so a long prompt no longer stalls the running rows for its
+whole prefill; :meth:`PagedSlotPool.admit` is both halves back to back.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,11 +57,58 @@ from ...models.generate import (decode_step_slots_paged,
                                 spec_verify_slots_paged)
 from ...ops.decode_attention import kernel_traces
 from ...runtime import faults
-from ..cache import CompileCounts, greedy_tokens, named_program
-from ..types import AdmissionRejected
+from ..cache import (CompileCounts, greedy_tokens, named_program,
+                     upload)
 from .pool import PagePool
 from .prefix import PrefixIndex
 from .quant import resolve_kv_bits
+
+
+#: the most tokens one prefill program takes. Every chunk reads all the
+#: weights once, so smaller chunks cost more in total; a larger one stalls
+#: the running rows for longer. Read on the chip with sparse experts whose
+#: chunk of 512 is 37 ms and mostly weights (PERF.md, Findings, PR 31):
+#: at 512 the prefill work grew by 60 % and the engine fell behind its
+#: load, at 2048 the longest prompt's stall was the whole prefill's
+PREFILL_CHUNK_TOKENS = 1024
+
+
+def chunk_tokens(buckets: Tuple[int, ...], page_len: int) -> int:
+    """Tokens a prefill chunk holds at most: ``PREFILL_CHUNK_TOKENS`` or
+    the largest bucket, whichever is smaller, in whole pages (the admit
+    program wants a page-aligned offset). Buckets above it are never
+    compiled."""
+    c = min(PREFILL_CHUNK_TOKENS, max(buckets)) // page_len * page_len
+    if c < 1:
+        raise ValueError(
+            f"the largest prefill bucket ({max(buckets)}) holds less than "
+            f"one page ({page_len} tokens): a prompt is prefilled in "
+            "chunks of whole pages")
+    return c
+
+
+class _Prefill(NamedTuple):
+    """A prompt between ``begin`` and its last chunk: ``done`` tokens
+    are resident (the prefix hit, then each of the ``chunks`` so far, at
+    most ``size`` tokens a chunk)."""
+    prompt: np.ndarray
+    n_hit: int
+    done: int
+    chunks: int
+    size: int
+    buckets: Tuple[int, ...]
+
+
+class Chunk(NamedTuple):
+    """What one :meth:`PagedSlotPool.chunk` ran: the ``index``-th chunk
+    of its prompt, ``tokens`` of them at ``offset`` padded to ``bucket``;
+    ``logits`` (1, vocab) of the prompt's last position when it was the
+    last chunk, else None (nothing of a chunk in between is read)."""
+    index: int
+    offset: int
+    tokens: int
+    bucket: int
+    logits: Optional[jnp.ndarray]
 
 
 class PagedSlotPool:
@@ -97,13 +154,15 @@ class PagedSlotPool:
             if self.moe_layers else None
         # host-side state: page tables / lengths mirror the traced args
         # (tiny int32 uploads per call), policy state never leaves host.
-        # They are uploaded with jnp.array (a copy), never jnp.asarray:
-        # on the CPU backend asarray may alias the numpy buffer, dispatch
-        # is asynchronous, and the host advances these arrays right after
-        # a call — the program would read the NEXT step's lengths
+        # They are uploaded through ``upload`` (a copy of their own):
+        # dispatch is asynchronous and the host advances these arrays
+        # right after a call — the program would read the NEXT step's
+        # lengths
         self.tables = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self.lengths = np.zeros((n_slots,), np.int32)
         self.owned: List[List[int]] = [[] for _ in range(n_slots)]
+        # slot -> its prompt's prefill in progress (begin .. last chunk)
+        self.prefilling: Dict[int, _Prefill] = {}
         self.pool = PagePool(n_pages, page_len)
         self.index = PrefixIndex(page_len)
         self.compiles = CompileCounts()
@@ -194,17 +253,17 @@ class PagedSlotPool:
 
     # -- host front ends ---------------------------------------------------
 
-    def admit(self, params, prompt: np.ndarray, slot: int,
-              buckets: Tuple[int, ...]):
-        """Admit ``prompt`` ((S,) np int32) into ``slot``: radix prefix
-        lookup → refcount the matched full pages → allocate + prefill
-        only the tail → index the prompt's full pages for future
-        admissions. Returns ``(last-position logits (1, vocab), n_hit
-        pages, offset tokens)``. Raises :class:`PagePoolExhausted`
-        (pool-attributed, no slot state changed) when the tail cannot
-        be allocated, and a typed :class:`~..types.AdmissionRejected`
-        (``reason="tail_too_long"``) — BEFORE any page is refcounted
-        or allocated — when the tail exceeds every prefill bucket."""
+    def begin(self, prompt: np.ndarray, slot: int,
+              buckets: Tuple[int, ...]) -> Tuple[int, int]:
+        """The half of an admission that runs no program: radix prefix
+        lookup → refcount the matched full pages → allocate ALL the
+        pages the rest of ``prompt`` ((S,) np int32) needs → ``slot``'s
+        table row. Returns ``(n_hit pages, offset tokens)``. Raises
+        :class:`PagePoolExhausted` (pool-attributed, no slot state
+        changed) when they cannot be allocated. ``lengths[slot]`` stays
+        0 and nothing is indexed until the last :meth:`chunk`: a page
+        must not be offered to another admission before it is written."""
+        size = chunk_tokens(buckets, self.page_len)  # refuses before any page
         s = int(prompt.shape[0])
         L = self.page_len
         hits: List[int] = []
@@ -215,29 +274,13 @@ class PagedSlotPool:
             hits = self.index.match(prompt, (s - 1) // L, self.pool)
         self.prefix_lookups += 1
         n_hit = len(hits)
-        offset = n_hit * L
-        tail_len = s - offset
-        n_fresh = -(-s // L) - n_hit
-        # bucket selection BEFORE any state change: a tail longer than
-        # every bucket must reject typed and attributable, not escape
-        # as a bare StopIteration with pages already refcounted
-        bucket = None
-        for b in buckets:
-            if b >= tail_len:
-                bucket = b
-                break
-        if bucket is None:
-            raise AdmissionRejected(
-                f"prompt tail ({tail_len} token(s) after {n_hit} shared "
-                f"page(s)) exceeds the largest prefill bucket "
-                f"({max(buckets)})", reason="tail_too_long")
         # incref matched pages BEFORE allocating: eviction only ever
         # considers refcount-zero pages, so a matched page cannot be
         # stolen to satisfy this very request's tail
         for pid in hits:
             self.pool.incref(pid)
         try:
-            fresh = self._alloc(n_fresh)
+            fresh = self._alloc(-(-s // L) - n_hit)
         except Exception:
             for pid in hits:
                 self.pool.decref(pid)
@@ -246,24 +289,57 @@ class PagedSlotPool:
         self.tables[slot, :len(row)] = row
         self.tables[slot, len(row):] = 0
         self.owned[slot] = row
+        self.prefilling[slot] = _Prefill(prompt, n_hit, n_hit * L, 0, size,
+                                         tuple(buckets))
+        return n_hit, n_hit * L
+
+    def chunk(self, params, slot: int) -> Chunk:
+        """Prefill the next at most ``chunk_tokens`` tokens of the prompt
+        :meth:`begin` gave ``slot``: one call of the admit program of the
+        smallest bucket that holds them, at the traced offset where the
+        last chunk stopped, attending over [the slot's resident pages |
+        the chunk]. Dispatched and not waited for. The last chunk sets
+        the slot's length, indexes the prompt's full pages for future
+        admissions and returns the last position's logits."""
+        pf = self.prefilling[slot]
+        s, L = int(pf.prompt.shape[0]), self.page_len
+        n = min(s - pf.done, pf.size)
+        bucket = next(b for b in pf.buckets if b >= n)
         padded = np.zeros((1, bucket), np.int32)
-        padded[0, :tail_len] = prompt[offset:]
+        padded[0, :n] = pf.prompt[pf.done:pf.done + n]
         fn = self._admit_fns.get(bucket)
         if fn is None:
             fn = self._admit_fns[bucket] = jax.jit(
                 named_program(self._admit, f"prefill_b{bucket}",
                               bucket=bucket), donate_argnums=(1,))
         logits, self.state = fn(
-            params, self.state, jnp.array(self.tables[slot]),
-            jnp.asarray(padded), jnp.asarray(offset, jnp.int32),
-            jnp.asarray(tail_len, jnp.int32), jnp.asarray(slot, jnp.int32))
+            params, self.state, upload(self.tables[slot]),
+            jnp.asarray(padded), jnp.asarray(pf.done, jnp.int32),
+            jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32))
+        out = Chunk(pf.chunks, pf.done, n, bucket, None)
+        if pf.done + n < s:
+            self.prefilling[slot] = pf._replace(done=pf.done + n,
+                                                chunks=pf.chunks + 1)
+            return out
+        del self.prefilling[slot]
         self.lengths[slot] = s
         if self.prefix_share:
-            self.index.insert(prompt, s // L, row, self.pool)
-        self.prefix_hit_pages_total += n_hit
-        self.prefill_tokens_saved_total += offset
+            self.index.insert(pf.prompt, s // L, self.owned[slot], self.pool)
+        self.prefix_hit_pages_total += pf.n_hit
+        self.prefill_tokens_saved_total += pf.n_hit * L
         self.prompt_tokens_total += s
-        return logits, n_hit, offset
+        return out._replace(logits=logits)
+
+    def admit(self, params, prompt: np.ndarray, slot: int,
+              buckets: Tuple[int, ...]):
+        """Admit ``prompt`` into ``slot`` whole: :meth:`begin`, then
+        every :meth:`chunk` back to back. Returns ``(last-position
+        logits (1, vocab), n_hit pages, offset tokens)``."""
+        n_hit, offset = self.begin(prompt, slot, buckets)
+        while True:
+            logits = self.chunk(params, slot).logits
+            if logits is not None:
+                return logits, n_hit, offset
 
     def ensure_decode_capacity(self, slot: int) -> None:
         """Grow ``slot``'s page table if its next decode write crosses a
@@ -284,8 +360,8 @@ class PagedSlotPool:
         advance). Returns each slot's greedy token (n_slots,) int32 and
         the (n_slots, vocab) logits, both left on the device."""
         out, logits, self.state, self.moe_counts = self._decode_fn(
-            params, self.state, self.moe_counts, jnp.array(self.tables),
-            jnp.array(self.lengths), jnp.asarray(tokens),
+            params, self.state, self.moe_counts, upload(self.tables),
+            upload(self.lengths), jnp.asarray(tokens),
             jnp.asarray(active))
         self.lengths[np.asarray(active)] += 1
         return out, logits
@@ -318,8 +394,8 @@ class PagedSlotPool:
         boundary included, never quantizes a partial page). Returns
         (logits (n_slots, k+1, vocab), sk, sv) with sk/sv per-layer
         exact-f32 candidate K/V scratch."""
-        return self._verify_fn(params, self.state, jnp.array(self.tables),
-                               jnp.array(self.lengths), jnp.asarray(tokens))
+        return self._verify_fn(params, self.state, upload(self.tables),
+                               upload(self.lengths), jnp.asarray(tokens))
 
     def spec_commit(self, sk, sv, commit: np.ndarray) -> None:
         """Scatter each row's accepted scratch prefix (``commit``
@@ -330,7 +406,7 @@ class PagedSlotPool:
         rejected suffixes were never written anywhere, so the PR 16
         quantize-once discipline is preserved by construction."""
         self.state = self._commit_fn(
-            self.state, jnp.array(self.tables), jnp.array(self.lengths),
+            self.state, upload(self.tables), upload(self.lengths),
             sk, sv, jnp.asarray(commit))
         self.lengths += np.asarray(commit, np.int32)
 
@@ -411,10 +487,13 @@ class PagedSlotPool:
     def release(self, slot: int) -> None:
         """Drop the slot's references (retirement, failure, or engine
         drain): private pages go straight back to the free list, indexed
-        pages stay resident for future prefix hits until LRU-evicted."""
+        pages stay resident for future prefix hits until LRU-evicted. A
+        prompt still prefilling indexed nothing: its fresh pages go free
+        and its hit pages back to the count they had before ``begin``."""
         for pid in self.owned[slot]:
             self.pool.decref(pid)
         self.owned[slot] = []
+        self.prefilling.pop(slot, None)
         self.tables[slot, :] = 0
         self.lengths[slot] = 0
 

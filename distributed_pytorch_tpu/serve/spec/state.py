@@ -32,7 +32,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..cache import SlotPool
+from ..cache import SlotPool, upload
 
 
 @dataclass
@@ -127,8 +127,8 @@ class SpecState:
         one lengths rewrite."""
         if len(slots):
             self.len[np.asarray(slots)] += np.asarray(commits, np.int32)
-        # a copy (jnp.array): self.len keeps changing under the host
-        self.pool.lengths = jnp.array(self.len)
+        # a copy of its own: self.len keeps changing under the host
+        self.pool.lengths = upload(self.len)
 
 
 def accept_greedy(drafts: np.ndarray, logits: np.ndarray,

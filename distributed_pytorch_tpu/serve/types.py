@@ -58,8 +58,9 @@ class ServeError(RuntimeError):
 
 class AdmissionRejected(ServeError):
     """The front door refused the request outright — bounded queue
-    full, prompt longer than the largest prefill bucket, or a
-    prompt+max_new that cannot fit the slot cache. Raised
+    full, prompt longer than the largest prefill bucket (the contiguous
+    pool; the paged one prefills in chunks), or a prompt+max_new that
+    cannot fit the slot cache. Raised
     synchronously from ``submit`` with ``reason`` set."""
 
     def __init__(self, msg: str, *, reason: str = "rejected",
@@ -73,8 +74,9 @@ class AdmissionRejected(ServeError):
 
 class RequestDeadlineExceeded(ServeError):
     """The request's ``deadline_ms`` SLO elapsed before completion —
-    while still queued (``stage='queued'``) or mid-decode
-    (``stage='running'``). Field names mirror
+    while still queued (``stage='queued'``), between two chunks of its
+    prefill (``stage='prefilling'``) or mid-decode (``stage='running'``).
+    Field names mirror
     ``runtime.native.CommTimeout`` (PR 2's typed-failure vocabulary)."""
 
     def __init__(self, msg: str, *, deadline_ms: float = 0.0,
@@ -172,8 +174,11 @@ class PagePoolExhausted(ServeError):
         self.free_pages = free_pages
 
 
-#: Request lifecycle states (host-side bookkeeping only).
+#: Request lifecycle states (host-side bookkeeping only). PREFILLING: the
+#: paged engine has given the request its slot and pages and is
+#: prefilling its prompt a chunk an iteration; it decodes from RUNNING on.
 QUEUED, RUNNING, FINISHED, FAILED = "queued", "running", "finished", "failed"
+PREFILLING = "prefilling"
 
 
 @dataclass

@@ -220,10 +220,20 @@ def test_engine_fetches_once_an_iteration_and_samples_by_setting(engine_run):
             assert sum(r[3]["iteration"] == f[3]["iteration"]
                        and (r[1] >= f[2] if after_fetch else r[2] <= f[1])
                        for r in named(spans, name)) == f[3]["rows"], name
-    # a sampler is dispatched only while the sampled request has a row:
-    # its two decode iterations of the longest request's four
+    # a request holds a row from the iteration that admits it (its first
+    # token is its prefill's) for max_new - 1 decode iterations, and the
+    # paged engine admits one prompt an iteration: the three overlap. A
+    # sampler is dispatched only while the sampled request (the last
+    # submitted, three tokens) has a row: its two decode iterations
+    admitted = sorted((a[3]["request_id"], a[3]["iteration"])
+                      for a in named(spans, "serve.admit"))
+    its = [it for _, it in admitted]
+    assert len(set(its)) == 3
+    held = [set(range(it, it + n - 1)) for it, n in zip(its, (5, 5, 3))]
+    assert set(loops) == set().union(*held)
     samples = named(spans, "serve.decode.sample")
-    assert len(loops) == 4 and len(samples) == 2
+    assert {s[3]["iteration"] for s in samples} == held[2]
+    assert len(samples) == 2
     assert all(s[3]["groups"] == 1 for s in samples)
     assert after["sample_dispatches"] - before["sample_dispatches"] == 2
     fetch_of = {f[3]["iteration"]: f for f in fetches}

@@ -531,11 +531,43 @@ SP_A = dict(temperature=0.7, top_k=8)
 SP_B = dict(temperature=0.9, top_p=0.9)
 
 
+def _decode_rows(specs, paged):
+    """Which requests of ``specs`` hold a row of the decode program in
+    each iteration it runs in, when all were queued before the loop
+    started: ``{iteration: [request index, ...]}``. A request's first
+    token comes from its prefill, in the iteration ``a`` that admits it,
+    and it decodes from ``a`` to ``a + max_new - 2``. The contiguous pool
+    admits every queued prompt in iteration 1; the paged one prefills ONE
+    chunk an iteration once a row is running, so request ``i`` (a prompt
+    of one chunk) is admitted in iteration ``i + 1``."""
+    rows = {}
+    for i, (_, sp) in enumerate(specs):
+        a = i + 1 if paged else 1
+        for t in range(a, a + sp.max_new_tokens - 1):
+            rows.setdefault(t, []).append(i)
+    return rows
+
+
+def _sampler_calls(specs, paged):
+    """The batched sampler programs ``_decode_rows`` implies, in the
+    order they are dispatched: ``(iteration, sampler_key, [request
+    index, ...])``, one a distinct sampling setting an iteration."""
+    calls = []
+    for t, rows in sorted(_decode_rows(specs, paged).items()):
+        groups = {}
+        for i in rows:
+            if specs[i][1].temperature > 0:
+                groups.setdefault(specs[i][1].sampler_key, []).append(i)
+        calls += [(t, key, members) for key, members in groups.items()]
+    return calls
+
+
 def _serve_together(model, params, specs, n_slots, record=None, **pool_kw):
     """Every request of ``specs`` ((prompt_len, SamplingParams) each)
-    queued BEFORE the loop starts, so that all are admitted in its first
-    iteration (``n_slots`` >= their number) in slot order and decode
-    side by side. Returns (prompts, keys, handles, stats)."""
+    queued BEFORE the loop starts (``n_slots`` >= their number), so that
+    they are admitted in slot order on the schedule ``_decode_rows``
+    gives and decode side by side. Returns (prompts, keys, handles,
+    stats)."""
     rng = np.random.default_rng(11)
     prompts = [rng.integers(0, 61, (s,)).astype(np.int32)
                for s, _ in specs]
@@ -583,11 +615,17 @@ class TestRowSampling:
             np.testing.assert_array_equal(
                 h.result(), _standalone(model, params, prompts[i], sp,
                                         keys[i]), err_msg=f"request {i}")
-        assert [h.metrics["admit_iteration"] for h in handles] == [1] * 5
-        # the first token of each comes from its admission: 9 decode
-        # iterations, setting A in all 9, setting B in the first 3
-        assert st["decode_fetches"] == 9, st
-        assert st["sample_dispatches"] == 9 + 3, st
+        paged = pool_kw["paged"]
+        assert [h.metrics["admit_iteration"] for h in handles] == \
+            ([1, 2, 3, 4, 5] if paged else [1] * 5)
+        # the first token of each comes from its admission: contiguous,
+        # 9 decode iterations, setting A in all 9, setting B in the
+        # first 3; paged, the rows start an iteration apart
+        rows = _decode_rows(specs, paged)
+        assert len(rows) == (13 if paged else 9)
+        assert st["decode_fetches"] == len(rows), st
+        assert st["sample_dispatches"] == len(_sampler_calls(specs, paged)) \
+            == ((9 + 2) + (3 + 2) if paged else 9 + 3), st
         assert st["rows_decoded"] == 3 * 9 + 2 * 3, st
         assert st["decode_compiles"] == 1, st
         # three settings admitted, two of them sample in decode
@@ -603,7 +641,8 @@ class TestRowSampling:
         _, _, handles, st = _serve_together(model, params, specs, 3,
                                             record=record, **pool_kw)
         assert [len(h.result()) for h in handles] == [3, 5, 7]
-        assert st["decode_fetches"] == 6, st      # the longest row's
+        # the longest row's, admitted in iteration 3 of the paged engine
+        assert st["decode_fetches"] == (8 if pool_kw["paged"] else 6), st
         assert st["sample_dispatches"] == 0 and record == [], st
         assert st["sample_compiles"] == 1, st     # admission's, greedy
 
@@ -623,8 +662,12 @@ class TestRowSampling:
         assert st["decode_compiles"] == 1, st
         assert st["sample_compiles"] == \
             len({tuple(kw.items()) for kw in settings}) + sampling, st
-        assert st["decode_fetches"] == 4, st
-        assert st["sample_dispatches"] == 4 * sampling, st
+        paged = pool_kw["paged"]
+        assert st["decode_fetches"] == len(_decode_rows(specs, paged)) \
+            == (4 + rows - 1 if paged else 4), st
+        assert st["sample_dispatches"] == len(_sampler_calls(specs, paged)), st
+        if not paged:
+            assert st["sample_dispatches"] == 4 * sampling, st
 
     @pytest.mark.parametrize("pool_kw", POOLS)
     def test_only_rows_that_sample_upload_a_key(self, pool_kw):
@@ -635,22 +678,28 @@ class TestRowSampling:
         model = _lm1()
         params = model.init(jax.random.PRNGKey(0))
         record = []
-        specs = [(4, SamplingParams(max_new_tokens=4)),
-                 (5, SamplingParams(max_new_tokens=4, **SP_A)),
-                 (6, SamplingParams(max_new_tokens=3, **SP_B)),
-                 (7, SamplingParams(max_new_tokens=4, **SP_A))]
+        # long enough that no slot is free again before the last
+        # request is admitted: request i holds slot i
+        specs = [(4, SamplingParams(max_new_tokens=6)),
+                 (5, SamplingParams(max_new_tokens=6, **SP_A)),
+                 (6, SamplingParams(max_new_tokens=5, **SP_B)),
+                 (7, SamplingParams(max_new_tokens=6, **SP_A))]
         _, keys, _, st = _serve_together(model, params, specs, 4,
                                          record=record, **pool_kw)
-        assert st["sample_dispatches"] == len(record) == 3 + 2
+        paged = pool_kw["paged"]
+        calls = _sampler_calls(specs, paged)
+        assert st["sample_dispatches"] == len(record) == len(calls)
+        if not paged:
+            assert len(calls) == 5 + 4
         splits = [np.asarray(jax.random.split(k, sp.max_new_tokens))
                   for k, (_, sp) in zip(keys, specs)]
-        seen = {}
-        for key, row_keys, mask in record:
-            step = seen[key] = seen.get(key, 0) + 1   # token index
-            want = [i for i, (_, sp) in enumerate(specs)
-                    if sp.sampler_key == key]
+        for (key, row_keys, mask), (t, want_key, want) in zip(record, calls):
+            assert key == want_key
             assert np.flatnonzero(mask).tolist() == want
             for slot in range(4):
+                # request ``slot`` was admitted in iteration a: its token
+                # of iteration t has the index t - a + 1
+                step = t - (slot if paged else 0)
                 np.testing.assert_array_equal(
                     row_keys[slot],
                     splits[slot][step] if slot in want else 0)

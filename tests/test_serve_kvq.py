@@ -17,10 +17,9 @@ What must hold (ISSUE 16 / docs/serving.md "Quantized resident pool"):
   (``extract_quantized``/``encode_frame_quantized``/``decode_frame(
   keep_bits)``/``adopt_quantized``) moves the pool's resident bits
   byte-identically with no dequant→requant double hop;
-- ``PagedSlotPool.admit`` rejects a tail longer than every bucket as a
-  typed ``AdmissionRejected(reason="tail_too_long")`` BEFORE any state
-  change (regression: this used to escape as a bare StopIteration with
-  pages already refcounted).
+- ``PagedSlotPool.admit`` prefills a tail longer than every bucket in
+  chunks (it was refused until PR 31), every page taken before a program
+  runs and all given back, refcounts as before, by a release half-way.
 """
 
 import numpy as np
@@ -45,7 +44,6 @@ from distributed_pytorch_tpu.serve.pages.quant import (dequantize_page_np,
                                                        quantize_page_np,
                                                        resolve_kv_bits,
                                                        unpack_pages_np)
-from distributed_pytorch_tpu.serve.types import AdmissionRejected
 
 MAX_LEN = 64
 L = 8
@@ -460,31 +458,41 @@ class TestExtractAdopt:
 
 
 class TestAdmissionAndConfig:
-    def test_tail_too_long_typed_rejection_no_state_change(self):
-        """Regression: a tail longer than every bucket used to escape
-        ``admit`` as a bare StopIteration from the bucket generator —
-        AFTER the prefix pages were already refcounted. It must be a
-        typed AdmissionRejected raised BEFORE any state change."""
+    def test_prompt_longer_than_every_bucket_is_admitted_in_chunks(self):
+        """A tail longer than every bucket used to be refused
+        (``tail_too_long``); it is prefilled in chunks of the largest
+        bucket's whole pages now, through the one program, with the
+        logits of the whole-prompt admission. ``begin`` takes every page
+        before a program runs; a slot released half-way gives them all
+        back."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
         pool = _pool(model, "f32")
         free_before = pool.pool.free_pages
-        prompt = np.arange(6, dtype=np.int32)
-        with pytest.raises(AdmissionRejected,
-                           match="exceeds the largest prefill bucket") \
-                as ei:
-            pool.admit(params, prompt, 0, (4,))
-        assert ei.value.reason == "tail_too_long"
+        prompt = (np.arange(21, dtype=np.int32) * 7) % 61
+        pool.begin(prompt, 0, (8,))
+        assert pool.pool.free_pages == free_before - 3
+        assert pool.chunk(params, 0).logits is None      # 8 of 21 tokens
+        assert int(pool.lengths[0]) == 0 and len(pool.index) == 0
+        pool.release(0)                                  # half prefilled
         assert pool.pool.free_pages == free_before
-        assert pool.owned[0] == []
-        assert int(pool.lengths[0]) == 0
-        # the same slot still admits normally afterwards
-        pool.admit(params, prompt, 0, BUCKETS)
-        assert int(pool.lengths[0]) == 6
+        assert pool.owned[0] == [] and pool.prefilling == {}
+        logits, n_hit, offset = pool.admit(params, prompt, 0, (8,))
+        assert (n_hit, offset) == (0, 0)
+        assert int(pool.lengths[0]) == 21
+        assert pool.compiles.prefill == {8: 1}           # 3 chunks, 1 program
+        whole = _pool(model, "f32")
+        ref, _, _ = whole.admit(params, prompt, 0, BUCKETS)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        with pytest.raises(ValueError, match="less than one page"):
+            pool.admit(params, prompt, 1, (4,))          # page_len is 8
+        assert pool.owned[1] == []
 
-    def test_tail_too_long_after_prefix_hit_keeps_refcounts(self):
-        """The dangerous variant: matched prefix pages must NOT stay
-        refcounted when the tail rejects."""
+    def test_long_tail_after_prefix_hit_keeps_refcounts(self):
+        """The dangerous variant: matched prefix pages are read by every
+        chunk and written by none, and a tail given up half-way leaves
+        their refcounts as they were before ``begin``."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
         pool = _pool(model, "f32")
@@ -493,11 +501,24 @@ class TestAdmissionAndConfig:
             [shared, np.arange(3, dtype=np.int32) + 40]), 0, BUCKETS)
         pool.release(0)
         refs_before = list(pool.pool.refcount)
+        hit_pages = [pid for pid, on in enumerate(pool.pool.indexed) if on]
+        hit_k = np.asarray(pool.state[0].k.pages)[hit_pages].copy()
         long_tail = np.concatenate(
             [shared, np.arange(9, dtype=np.int32) + 50])
-        with pytest.raises(AdmissionRejected) as ei:
-            pool.admit(params, long_tail, 1, (8,))   # tail 9 > 8
-        assert ei.value.reason == "tail_too_long"
+        assert pool.begin(long_tail, 1, (8,)) == (2, 16)  # tail 9 > 8
+        first = pool.chunk(params, 1)
+        assert (first.offset, first.tokens, first.logits) == (16, 8, None)
+        pool.release(1)
+        assert list(pool.pool.refcount) == refs_before
+        logits, n_hit, offset = pool.admit(params, long_tail, 1, (8,))
+        assert (n_hit, offset) == (2, 16)
+        np.testing.assert_array_equal(
+            np.asarray(pool.state[0].k.pages)[hit_pages], hit_k)
+        cold = _pool(model, "f32", prefix_share=False)
+        ref, _, _ = cold.admit(params, long_tail, 0, BUCKETS)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        pool.release(1)
         assert list(pool.pool.refcount) == refs_before
 
     def test_non_paged_explicit_kv_dtype_raises(self):

@@ -244,7 +244,7 @@ class TestPagedEngine:
         eng = _paged_engine(model, params, n_slots=4)
         hs = [eng.submit(p, sp, rng=k)      # queued before the loop
               for p, sp, k in zip(prompts, sps, keys)]   # starts: one
-        with eng:                                        # admission pass
+        with eng:                           # prompt admitted an iteration
             outs = [h.result(timeout=120) for h in hs]
         for i in range(4):
             np.testing.assert_array_equal(
@@ -252,8 +252,11 @@ class TestPagedEngine:
                                      keys[i]), err_msg=f"request {i}")
         st = eng.stats()
         assert [h.metrics["prefix_hit_pages"] for h in hs] == [0, 2, 2, 2]
-        assert st["decode_fetches"] == 5, st
-        assert st["sample_dispatches"] == 5 * 2, st
+        # request i holds a row in iterations i + 1 .. i + 5: 8 decode
+        # iterations; setting a has a row in 2 .. 8, setting b in 3 .. 7
+        assert [h.metrics["admit_iteration"] for h in hs] == [1, 2, 3, 4]
+        assert st["decode_fetches"] == 8, st
+        assert st["sample_dispatches"] == 7 + 5, st
         assert st["decode_compiles"] == 1, st
         assert st["sample_compiles"] == 3 + 2, st
 

@@ -265,10 +265,15 @@ class TestGreedyContract:
                                           err_msg=f"request {i}")
         st = eng.stats()
         assert st["spec"]["proposed"] > 0      # the greedy row DID spec
-        # the three rows that sample: 9 decode iterations, two settings
-        # in each; the speculating row's tokens come from its verify
-        assert st["decode_fetches"] == n - 1, st
-        assert st["sample_dispatches"] == 2 * (n - 1), st
+        # the three rows that sample: 9 decode iterations each, two
+        # settings; the speculating row's tokens come from its verify.
+        # The contiguous pool admits all four in iteration 1; the paged
+        # one admits a prompt an iteration, so rows 1-3 decode in
+        # iterations 2-10, 3-11, 4-12: setting A in 2-12, B in 3-11
+        paged = pool_kw["paged"]
+        assert st["decode_fetches"] == (n + 1 if paged else n - 1), st
+        assert st["sample_dispatches"] == \
+            ((n + 1) + (n - 1) if paged else 2 * (n - 1)), st
         assert st["rows_decoded"] == 3 * (n - 1), st
         assert st["decode_compiles"] == 1, st
 
