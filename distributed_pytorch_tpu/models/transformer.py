@@ -72,6 +72,13 @@ def apply_remat_policy(fn: Callable, policy: str) -> Callable:
     return jax.checkpoint(fn, policy=jax.checkpoint_policies.dots_saveable)
 
 
+def _with_bias(block_params, bias):
+    """A block's parameters with its router's bias replaced."""
+    ffn = block_params["ffn"]
+    return {**block_params,
+            "ffn": {**ffn, "router": {**ffn["router"], "bias": bias}}}
+
+
 class TransformerLM(Module):
     """Decoder-only causal LM: tok+pos embed → N pre-norm blocks → LN →
     vocab projection.
@@ -92,7 +99,16 @@ class TransformerLM(Module):
     - ``hyper_connections``: the number of parallel residual streams
       (``nn/hyper.py``; 0 = the plain residual sum), ``hc`` their
       keyword arguments. The embedding is copied into every stream and
-      the streams are summed before the final norm."""
+      the streams are summed before the final norm.
+
+    ``mtp=1`` adds one multi-token-prediction module (DeepSeek-V3 section
+    2.2; needs blocks made of parts and the plain residual sum): position
+    ``i`` merges the embedding of token ``i + 1`` with the last block's
+    output at ``i`` (a norm each, concatenated embedding first, a
+    ``2 dim -> dim`` projection), runs one more block of the last layer's
+    kind and a norm, and predicts token ``i + 2`` through the model's own
+    head. :meth:`heads_hidden` is its entry, ``ops.losses.lm_mtp_loss``
+    the loss over both heads; ``apply`` and serving never run it."""
 
     def __init__(self, vocab: int = 256, dim: int = 128, n_layers: int = 2,
                  n_heads: int = 4, max_seq: int = 512, mlp_ratio: int = 4,
@@ -106,7 +122,8 @@ class TransformerLM(Module):
                  attention: str = "mha", latent: Optional[dict] = None,
                  norm: str = "layer", norm_eps: Optional[float] = None,
                  ffn_dim: Optional[int] = None, moe: Optional[dict] = None,
-                 hyper_connections: int = 0, hc: Optional[dict] = None):
+                 hyper_connections: int = 0, hc: Optional[dict] = None,
+                 mtp: int = 0):
         if pos not in ("learned", "rope", "none"):
             raise ValueError(f"pos must be learned|rope|none, got {pos!r}")
         if attention not in ("mha", "latent"):
@@ -184,11 +201,23 @@ class TransformerLM(Module):
                     return DroplessMoE(dim, dtype=dtype, **moe)
                 return GatedMLP(dim, ffn_dim or mlp_ratio * dim, dtype=dtype)
 
-            self.blocks = [
-                Block(dim, norm1=make_norm(), attn=make_attn(),
-                      norm2=make_norm(), ffn=make_ffn(kind),
-                      streams=self.streams, hc=hc)
-                for kind in kinds]
+            def make_block(kind):
+                return Block(dim, norm1=make_norm(), attn=make_attn(),
+                             norm2=make_norm(), ffn=make_ffn(kind),
+                             streams=self.streams, hc=hc)
+
+            self.blocks = [make_block(kind) for kind in kinds]
+        if mtp not in (0, 1):
+            raise ValueError(f"mtp must be 0 or 1 (the depth of the "
+                             f"prediction module), got {mtp!r}")
+        if mtp and (not from_parts or self.streams):
+            raise ValueError("mtp=1 needs blocks made of parts "
+                             "(block_kinds, attention, norm) and the plain "
+                             "residual sum")
+        self.mtp = None if not mtp else {
+            "norm_e": make_norm(), "norm_h": make_norm(),
+            "proj": Linear(2 * dim, dim, bias=False, dtype=dtype),
+            "block": make_block(kinds[-1]), "norm": make_norm()}
         self.ln_f = make_norm(scope="ln_f")
         # tied embeddings (the GPT-2 recipe): the vocab projection reuses
         # the token table transposed — no head parameter exists
@@ -207,6 +236,11 @@ class TransformerLM(Module):
             p["head"] = self.head.init(ks[-1])
         if self.pos is not None:
             p["pos"] = self.pos.init(ks[1])
+        if self.mtp is not None:
+            km = jax.random.split(jax.random.fold_in(key, self.n_layers + 3),
+                                  len(self.mtp))
+            p["mtp"] = {name: part.init(k)
+                        for (name, part), k in zip(self.mtp.items(), km)}
         return p
 
     def head_weight(self, params):
@@ -218,6 +252,51 @@ class TransformerLM(Module):
         if self.tie_embeddings:
             return resolve_weight(params["tok"], "emb", self.dtype).T
         return resolve_weight(params["head"], "w", self.dtype)
+
+    @staticmethod
+    def router_bias_mask(params):
+        """A tree of bools shaped like ``params``, True at every expert
+        layer's router bias: the leaves that live outside the optimizer
+        (``parallel.Buffers(mask=...)``)."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: [getattr(k, "key", None) for k in path[-2:]]
+            == ["router", "bias"], params)
+
+    def router_metrics(self, params: Params, load):
+        """A step's counters of the expert layers, float32 scalars, from
+        ``load`` (layers, n_routed): ``moe_pairs_here`` (the pairs sent to
+        the experts held here, all layers), ``moe_load_max`` and
+        ``moe_load_mean`` (the largest and the mean load among the held
+        experts of a layer) and ``moe_bias_abs_max`` (the largest router
+        bias, before this step's update)."""
+        ffn = next(b.ffn for b in self.blocks + (
+            [self.mtp["block"]] if self.mtp else [])
+            if getattr(b, "_sparse", False))
+        here = load[:, ffn.first:ffn.first + ffn.count].astype(jnp.float32)
+        biases = [x for x, m in zip(
+            jax.tree_util.tree_leaves(params),
+            jax.tree_util.tree_leaves(self.router_bias_mask(params))) if m]
+        return {"moe_pairs_here": jnp.sum(here),
+                "moe_load_max": jnp.max(here),
+                "moe_load_mean": jnp.mean(here),
+                "moe_bias_abs_max": jnp.max(jnp.abs(jnp.stack(biases)))}
+
+    def balance_router_bias(self, params: Params, load, speed: float):
+        """``params`` with every router bias moved by its layer's rule
+        (``DroplessMoE.balance``) from ``load`` (layers, n_routed), the
+        ``moe_load`` of ``heads_hidden``: the ``update`` of
+        ``parallel.Buffers``. Copies no other leaf."""
+        new = {**params, "blocks": list(params["blocks"])}
+        slots = [(blk, new["blocks"], j) for j, blk in enumerate(self.blocks)]
+        if self.mtp is not None:
+            new["mtp"] = dict(params["mtp"])
+            slots.append((self.mtp["block"], new["mtp"], "block"))
+        # in the order ``heads_hidden`` stacks the loads
+        sparse = [s for s in slots if getattr(s[0], "_sparse", False)]
+        for (blk, holder, key), layer_load in zip(sparse, load):
+            holder[key] = _with_bias(holder[key], blk.ffn.balance(
+                holder[key]["ffn"]["router"]["bias"], layer_load, speed))
+        return new
 
     def streams_in(self, x):
         """The embedding (..., D) as the blocks take it: copied into every
@@ -257,31 +336,92 @@ class TransformerLM(Module):
         input contract of ``ops.losses.fused_linear_cross_entropy`` (pass
         ``model.head_weight(params)`` as its weight), which streams the projection
         chunkwise so the full (B, S, vocab) logits never materialize."""
-        b, s = tokens.shape
+        x, _ = self._trunk(params, tokens, rng=rng, train=train,
+                           pos_offset=pos_offset, positions=positions)
+        x = self.ln_f.apply(params["ln_f"], x)
+        if return_hidden:
+            return x
+        return self.project_vocab(params, x)
+
+    def _run_block(self, blk, p, x, **kw):
+        """One block under the model's remat policy -> ``(x, load)``: an
+        expert layer's block hands out the pairs its router sent to each
+        expert (as an output, so that they survive its
+        rematerialisation); None for every other block. One name for
+        every layer: readers of a trace sum over layers, and XLA may
+        still share their computations."""
+        def run_block(p, x):
+            with jax.named_scope("blocks"):
+                out = blk.apply(p, x, **kw)
+            return out if isinstance(out, tuple) else (out, None)
+
+        # per-layer remat policy: "full" recomputes the block in
+        # backward instead of saving its activations (~1/3 more
+        # FLOPs for O(n_layers) less activation HBM, buying batch
+        # size on memory-bound configs); "dots_saveable" keeps the
+        # matmul outputs and recomputes only the elementwise chain
+        return apply_remat_policy(run_block, self.remat_policy)(p, x)
+
+    def _trunk(self, params, tokens, *, rng=None, train=False, pos_offset=0,
+               positions=None):
+        """Embedding and blocks: what the final norm takes, and each
+        expert layer's pairs per expert, in layer order."""
+        s = tokens.shape[1]
         x = self.tok.apply(params["tok"], tokens)
         if positions is None:
             positions = pos_offset + jnp.arange(s)
         if self.pos is not None:
             x = x + self.pos.apply(params["pos"], positions)
         x = self.streams_in(x)
+        loads = []
         for i, blk in enumerate(self.blocks):
             r = jax.random.fold_in(rng, i) if rng is not None else None
+            x, load = self._run_block(
+                blk, params["blocks"][i], x, rng=r, train=train,
+                positions=positions)
+            if load is not None:
+                loads.append(load)
+        return self.streams_out(x), loads
 
-            def run_block(p, x, blk=blk, r=r):
-                # one name for every layer: readers of a trace sum over
-                # layers, and XLA may still share their computations
+    def heads_hidden(self, params: Params, tokens, *, rng=None,
+                     train: bool = False):
+        """What a loss over both heads needs, without logits: tokens
+        (B, S + 1) -> the main head's hidden states (B, S, dim) after the
+        final norm (position ``i`` predicts token ``i + 1``), the
+        prediction module's (B, S - 1, dim) after its own norm (position
+        ``i`` predicts token ``i + 2``; None with ``mtp=0``), and the
+        pairs each expert layer's router sent to each expert, (layers,
+        n_routed) int32, the module's layer last (None without expert
+        layers). Both go through ``head_weight(params)``
+        (``ops.losses.lm_mtp_loss``).
+
+        The module runs over all S positions, so that its sequence is the
+        trunk's (and a length the attention kernel tiles); the last
+        position, which has no token ``i + 2`` to predict, is left out of
+        its experts' dispatch and load and cut from the result. Under
+        causal attention no other position sees it."""
+        s = tokens.shape[1] - 1
+        positions = jnp.arange(s)
+        h, loads = self._trunk(params, tokens[:, :-1], rng=rng, train=train,
+                               positions=positions)
+        main = self.ln_f.apply(params["ln_f"], h)
+        mtp = None
+        if self.mtp is not None:
+            m, p = self.mtp, params["mtp"]
+            live = jnp.broadcast_to(positions < s - 1, tokens[:, 1:].shape)
+            # the module is one more layer: its parts read mtp/blocks/...
+            # in a trace (``_run_block`` opens ``blocks`` itself)
+            with jax.named_scope("mtp"):
+                with jax.named_scope("blocks"), jax.named_scope("merge"):
+                    e = self.tok.apply(params["tok"], tokens[:, 1:])
+                    x = m["proj"].apply(p["proj"], jnp.concatenate(
+                        [m["norm_e"].apply(p["norm_e"], e),
+                         m["norm_h"].apply(p["norm_h"], h)], -1))
+                x, load = self._run_block(
+                    m["block"], p["block"], x, positions=positions,
+                    row_mask=live)
+                if load is not None:
+                    loads.append(load)
                 with jax.named_scope("blocks"):
-                    return blk.apply(p, x, rng=r, train=train,
-                                     positions=positions)
-
-            # per-layer remat policy: "full" recomputes the block in
-            # backward instead of saving its activations (~1/3 more
-            # FLOPs for O(n_layers) less activation HBM, buying batch
-            # size on memory-bound configs); "dots_saveable" keeps the
-            # matmul outputs and recomputes only the elementwise chain
-            run_block = apply_remat_policy(run_block, self.remat_policy)
-            x = run_block(params["blocks"][i], x)
-        x = self.ln_f.apply(params["ln_f"], self.streams_out(x))
-        if return_hidden:
-            return x
-        return self.project_vocab(params, x)
+                    mtp = m["norm"].apply(p["norm"], x[:, :-1])
+        return main, mtp, (jnp.stack(loads) if loads else None)

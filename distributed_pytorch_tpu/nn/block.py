@@ -58,28 +58,35 @@ class Block(Module):
         return (x + out[0], out[1]) if isinstance(out, tuple) else x + out
 
     def _ffn(self, params: Params, x, row_mask=None, moe_stats=None):
+        """-> ``(x, load)``; ``load`` is None where the layer is dense."""
         def fn(u):
             h = self.ln2.apply(params["ln2"], u)
             if self._sparse:
                 return self.ffn.apply(params["ffn"], h, row_mask=row_mask,
                                       stats=moe_stats)
             return self.ffn.apply(params["ffn"], h)
-        return self._residual(self.hc2, params.get("hc2"), x, fn)
+        out = self._residual(self.hc2, params.get("hc2"), x, fn)
+        return out if self._sparse else (out, None)
 
-    def apply(self, params: Params, x, *, positions=None, **_):
+    def apply(self, params: Params, x, *, positions=None, row_mask=None,
+              **_):
+        """-> ``(x, load)``: the pairs this call's router sent to each
+        expert (``parallel.moe.DroplessMoE.apply``), None for a dense
+        layer; ``row_mask`` (B, S) bool leaves positions out of the
+        experts' dispatch and of the load."""
         x = self._residual(
             self.hc1, params.get("hc1"), x,
             lambda u: self.attn.apply(params["attn"],
                                       self.ln1.apply(params["ln1"], u),
                                       positions=positions))
-        return self._ffn(params, x)
+        return self._ffn(params, x, row_mask)
 
     def _paged(self, step, params, x, pages, ctx, row_mask):
         x, pages = self._residual(
             self.hc1, params.get("hc1"), x,
             lambda u: step(params["attn"], self.ln1.apply(params["ln1"], u),
                            pages, ctx))
-        return self._ffn(params, x, row_mask, ctx.moe_stats), pages
+        return self._ffn(params, x, row_mask, ctx.moe_stats)[0], pages
 
     def decode_paged(self, params: Params, x, pages, ctx):
         """x (B, 1[, streams], D), this layer's page store -> (x, the

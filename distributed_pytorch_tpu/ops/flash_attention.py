@@ -88,7 +88,14 @@ def _block_sizes(s_q, s_k, block_q, block_k, d=64, bwd=False, window=None):
     kernels, at the flagship shape (b8 h12 s1024 d64), seq 4096, d=128,
     GQA and ``window=`` (chip_smoke.py, PR 21); how fast each is against
     smaller tiles is not measured (``benchmarks/flash_block_sweep.py``
-    is the sweep). With sliding-window
+    is the sweep). Head 192 (latent attention expanded: nope 128 + rope
+    64, values padded to it) at b1 h32 s8192 takes the "d > 128" caps,
+    256 x 256 in forward and in both backward kernels: they compile and
+    run on the v5e (PR 32's cell ``train-joyai-flash-8k-1chip``; its
+    share of the roofline is in PERF.md section 5). 256 and not 512
+    because a 192-wide block is laid out over two lanes of 128, so a
+    tile costs the VMEM of one 256 wide and the backward kernels hold
+    five of them beside three (bq, bk) float32 tiles. With sliding-window
     attention the k cap clamps near the window width instead — a k tile
     much wider than the band would compute mostly-masked logits and
     degrade the O(S*window) cost toward O(S*block_k)."""

@@ -76,6 +76,33 @@ def fused_linear_cross_entropy(hidden: jnp.ndarray, w: jnp.ndarray,
     return total / n
 
 
+def lm_mtp_loss(model, params, tokens, *, weight: float):
+    """Next-token loss plus ``weight`` times the multi-token-prediction
+    module's (DeepSeek-V3 section 2.2), each a mean over its own
+    positions: tokens (B, S + 1) -> ``(loss, aux)``.
+
+    Both heads go through :func:`fused_linear_cross_entropy` with
+    ``model.head_weight(params)``, so no ``(B, S, vocab)`` logits are
+    formed, once or twice (scopes ``loss`` > ``main`` and ``loss`` >
+    ``mtp``); the shared head and embedding get both heads' gradients.
+    ``model`` is a ``TransformerLM(mtp=1)`` (``heads_hidden``). ``aux``
+    holds float32 scalars ``loss_main`` and ``loss_mtp`` and, where the
+    model has expert layers, ``moe_load`` (layers, n_routed) int32, which
+    a bias rule reads (``TransformerLM.balance_router_bias``), and the
+    scalars of ``TransformerLM.router_metrics``."""
+    main, mtp, load = model.heads_hidden(params, tokens, train=True)
+    w = model.head_weight(params)
+    with jax.named_scope("loss"):
+        with jax.named_scope("main"):
+            loss_main = fused_linear_cross_entropy(main, w, tokens[:, 1:])
+        with jax.named_scope("mtp"):
+            loss_mtp = fused_linear_cross_entropy(mtp, w, tokens[:, 2:])
+    aux = {"loss_main": loss_main, "loss_mtp": loss_mtp}
+    if load is not None:
+        aux.update(moe_load=load, **model.router_metrics(params, load))
+    return loss_main + weight * loss_mtp, aux
+
+
 def vocab_parallel_cross_entropy(logits_local: jnp.ndarray, labels,
                                  *, axis_name: str = "tp") -> jnp.ndarray:
     """Per-example CE from VOCAB-SHARDED logits — call inside

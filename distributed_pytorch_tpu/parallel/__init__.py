@@ -9,7 +9,7 @@ from .data_parallel import (DataParallel, make_eval_step,
                             make_scan_train_steps, make_stateful_eval_step,
                             make_stateful_train_step, make_train_step,
                             mp_cast_params, prepare_ddp_model, stack_state)
-from .front_door import (FROM_INPUTS, FrontDoorStep, HandoffMismatch,
+from .front_door import (FROM_INPUTS, Buffers, FrontDoorStep, HandoffMismatch,
                          StepSpecs, handoff_shardings, make_step,
                          verify_handoff)
 from .fsdp import (fsdp_param_specs, make_fsdp_train_step,

@@ -70,8 +70,11 @@ def mp_cast_params(params):
         if hasattr(p, "dtype") and p.dtype == jnp.float32 else p, params)
 
 
-def _wrap_mixed_precision(loss_fn: Callable, policy: str) -> Callable:
-    """``bf16``: the loss consumes the bf16 CAST of the f32 params.
+def _wrap_mixed_precision(loss_fn: Callable, policy: str,
+                          buffers=None) -> Callable:
+    """``bf16``: the loss consumes the bf16 CAST of the f32 params
+    (leaves outside the optimizer, ``buffers``, are state and not weights:
+    the loss sees them as the step carries them).
 
     This is the master-weights recipe (docs/compute.md, the same
     error-feedback shape as PR 7's sharded gather leg and
@@ -94,7 +97,10 @@ def _wrap_mixed_precision(loss_fn: Callable, policy: str) -> Callable:
 
     def mp_loss(params, batch):
         with jax.named_scope("cast"):
-            working = mp_cast_params(params)
+            working = mp_cast_params(
+                params if buffers is None else buffers.trainable(params))
+        if buffers is not None:
+            working = buffers.merge(working, params)
         return loss_fn(working, batch)
 
     return mp_loss
@@ -115,7 +121,8 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
                     overlap: Optional[bool] = None,
                     comm_buckets: Optional[int] = None,
                     on_bucket_ready: Optional[Callable] = None,
-                    mixed_precision: Optional[str] = None) -> Callable:
+                    mixed_precision: Optional[str] = None,
+                    buffers=None) -> Callable:
     """Compile a data-parallel training step.
 
     Thin shim over the one mesh-addressed front door
@@ -185,13 +192,18 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
     live on flat 1/world slices. The sharded path speaks the fixed q8
     wire only (its gather leg's error feedback owns the exact master
     copy); combine q4/adaptive with ``weight_update="replicated"``.
+
+    ``buffers``: a :class:`.front_door.Buffers`, the leaves of the
+    parameter tree that live outside the optimizer and the rule that
+    moves them (docs/front_door.md, "Leaves outside the optimizer").
     """
     from .front_door import make_step
     return make_step(loss_fn, optimizer, wire=grad_reduce,
                      weight_update=weight_update,
                      mixed_precision=mixed_precision,
                      overlap=overlap, comm_buckets=comm_buckets,
-                     on_bucket_ready=on_bucket_ready, donate=donate)
+                     on_bucket_ready=on_bucket_ready, donate=donate,
+                     buffers=buffers)
 
 
 def _partition_contiguous(sizes, k: int):

@@ -98,6 +98,67 @@ class StepSpecs(NamedTuple):
     grads: Any = None
 
 
+class Buffers(NamedTuple):
+    """Leaves of the parameter tree that live outside the optimizer:
+    state the model carries that no gradient moves (a router's balancing
+    bias, a running statistic kept beside the weights).
+
+    ``mask(params)`` is a tree of bools shaped like ``params``, True at
+    such a leaf. ``update(params, metrics)`` is the model's rule: it gets
+    the parameters after ``optimizer.update`` and the loss's own second
+    output, and returns the parameters with those leaves moved; what it
+    does to any other leaf is dropped. The step (docs/front_door.md):
+
+    * differentiates with respect to the other leaves only, so no
+      gradient is formed for a buffer;
+    * hands the optimizer trees with ``None`` at the buffers (``init`` on
+      :meth:`trainable` makes no moments there, ``update`` applies no
+      decay);
+    * leaves them out of the mixed-precision cast;
+    * applies ``update`` inside the same compiled program, under the
+      scope ``optimizer`` > ``buffers``, donated like the rest."""
+
+    mask: Callable[[Any], Any]
+    update: Callable[[Any, Any], Any]
+
+    def trainable(self, params):
+        """``params`` with None at every buffer: what ``optimizer.init``
+        takes (``opt_state = opt.init(buffers.trainable(params))``)."""
+        return jax.tree_util.tree_map(
+            lambda m, p: None if m else p, self.mask(params), params)
+
+    def merge(self, trained, params):
+        """The buffers of ``params`` beside the other leaves of
+        ``trained`` (which may hold anything, or None, at a buffer)."""
+        return jax.tree_util.tree_map(
+            lambda m, t, p: p if m else t, self.mask(params), trained,
+            params, is_leaf=lambda x: x is None)
+
+
+def _value_and_grad(loss_fn, buffers, params, batch):
+    """((loss, metrics), grads): with ``buffers``, the gradient's tree
+    has None where a buffer is."""
+    if buffers is None:
+        return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+    return jax.value_and_grad(
+        lambda t: loss_fn(buffers.merge(t, params), batch),
+        has_aux=True)(buffers.trainable(params))
+
+
+def _update(optimizer, buffers, grads, opt_state, params, metrics):
+    """The optimizer's step, then the buffers' rule."""
+    with jax.named_scope("optimizer"):
+        if buffers is None:
+            return optimizer.update(grads, opt_state, params)
+        trained, opt_state = optimizer.update(
+            grads, opt_state, buffers.trainable(params))
+        params = buffers.merge(trained, params)
+        with jax.named_scope("buffers"):
+            # only the buffers are taken from the rule's result
+            params = buffers.merge(params, buffers.update(params, metrics))
+        return params, opt_state
+
+
 class HandoffMismatch(ValueError):
     """A pjit-to-pjit handoff would have resharded: the tree does not
     already carry the expected shardings. Raised INSTEAD of copying —
@@ -353,7 +414,8 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
               comm_buckets: Optional[int] = None,
               on_bucket_ready: Optional[Callable] = None,
               donate: Optional[bool] = None,
-              pad_multiple: Optional[int] = None) -> Callable:
+              pad_multiple: Optional[int] = None,
+              buffers: Optional[Buffers] = None) -> Callable:
     """Build THE train step: ``step(params, opt_state, batch)``.
 
     ``loss_fn(params, batch) -> (loss, metrics)``. Parallelism is a
@@ -381,6 +443,12 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     ``comm_buckets`` / ``on_bucket_ready`` are host-door knobs
     (bucketed update overlap); the compiled mesh engines ignore them
     (XLA already schedules the fused reduce against compute).
+
+    ``buffers`` (:class:`Buffers`): leaves outside the optimizer and the
+    rule that moves them from the loss's second output, inside the step
+    program. Taken where the loss is the whole batch's: one device, or
+    ``specs=FROM_INPUTS``; the per-shard engines refuse it by name (a
+    rule would see one shard's metrics).
 
     Builds are cached on the full config tuple — re-requesting an
     identical config returns the SAME step object (compile counters
@@ -422,12 +490,13 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     # the step's words in the JAX name stack (names only): the loss
     # closure reads ``jvp(loss)`` and not ``jvp()`` on the device plane
     loss_fn = jax.named_scope("loss")(loss_fn)
-    loss_fn = _wrap_mixed_precision(loss_fn, mixed_precision)
+    loss_fn = _wrap_mixed_precision(loss_fn, mixed_precision, buffers)
     if remat_policy != "none":
         loss_fn = apply_remat_policy(loss_fn, remat_policy)
 
     # -- host (per-rank-process) door: its engines are not pjit programs
     if context.get_host_comm() is not None:
+        _refuse_buffers(buffers, "the host door")
         if weight_update == "sharded":
             from ..optim.sharded.host import make_host_sharded_train_step
             if pad_multiple is not None:
@@ -449,7 +518,7 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
 
     key = ("front_door", base_loss, optimizer, _mesh_key(mesh), world,
            _spec_key(specs), wire, weight_update, mixed_precision,
-           remat_policy, bool(donate), pad_multiple)
+           remat_policy, bool(donate), pad_multiple, buffers)
     try:
         cached = _CACHE.get(key)
     except TypeError:                    # unhashable loss/optimizer
@@ -461,14 +530,19 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     step = FrontDoorStep(config=key or ("front_door", "<unhashable>"),
                          donated=bool(donate))
     if weight_update == "sharded":
+        _refuse_buffers(buffers, "weight_update='sharded'")
         _build_sharded(step, loss_fn, optimizer, mesh, world,
                        wire=wire, donate=donate, pad_multiple=pad_multiple)
     elif isinstance(specs, _FromInputs):
-        _build_propagate(step, loss_fn, optimizer, donate=donate)
+        _build_propagate(step, loss_fn, optimizer, donate=donate,
+                         buffers=buffers)
     elif specs is None:
+        if world > 1:
+            _refuse_buffers(buffers, "the dp island over several devices")
         _build_stacked_dp(step, loss_fn, optimizer, mesh, world,
-                          wire=wire, donate=donate)
+                          wire=wire, donate=donate, buffers=buffers)
     else:
+        _refuse_buffers(buffers, "the constraint ladder (StepSpecs)")
         if not isinstance(specs, StepSpecs):
             specs = StepSpecs(params=specs)
         _build_constrained(step, loss_fn, optimizer, mesh, specs,
@@ -485,6 +559,15 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
 # ---------------------------------------------------------------------------
 
 
+def _refuse_buffers(buffers, where: str) -> None:
+    if buffers is not None:
+        raise ValueError(
+            f"buffers= is not carried by {where}: its rule would see one "
+            f"shard's metrics, or its state is laid out from the whole "
+            f"parameter tree. Use one device or specs=FROM_INPUTS "
+            f"(docs/front_door.md, 'Leaves outside the optimizer')")
+
+
 def _leaf_offsets(leaves, block: int):
     """Start offset of each leaf inside the block-padded flat bucket."""
     offs, off = [], 0
@@ -495,7 +578,7 @@ def _leaf_offsets(leaves, block: int):
 
 
 def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
-                      wire, donate):
+                      wire, donate, buffers=None):
     """The DDP engine: forward -> backward -> gradient mean over ``dp``
     -> replicated update, ONE XLA program, per-rank stacked losses.
     Quantized wires ride one flat block-aligned bucket through
@@ -555,8 +638,8 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
     def make_local_step(bits, want_stat):
         def local_step(params, opt_state, batch):
             step._bump(bits)             # trace-time compile counter
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, batch)
+            (loss, metrics), grads = _value_and_grad(loss_fn, buffers,
+                                                     params, batch)
             stat = jnp.float32(0.0)
             if world > 1:
                 grads, red = _reduce_grads(grads, bits,
@@ -566,9 +649,8 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
                     from ..ops.quant import block_outlier_frac_jnp
                     stat = block_outlier_frac_jnp(
                         red, prim.QUANT_BLOCK, DYNRANGE_THRESH)
-            with jax.named_scope("optimizer"):
-                params, opt_state = optimizer.update(grads, opt_state,
-                                                     params)
+            params, opt_state = _update(optimizer, buffers, grads,
+                                        opt_state, params, metrics)
             return params, opt_state, loss[None], metrics, stat
         return local_step
 
@@ -640,15 +722,15 @@ def _build_stacked_dp(step, loss_fn, optimizer, mesh, world, *,
 # ---------------------------------------------------------------------------
 
 
-def _build_propagate(step, loss_fn, optimizer, *, donate):
+def _build_propagate(step, loss_fn, optimizer, *, donate, buffers=None):
     from .spmd import SpmdStepOutput
 
     def body(params, opt_state, batch):
         step._bump("propagate")
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
-        with jax.named_scope("optimizer"):
-            params, opt_state = optimizer.update(grads, opt_state, params)
+        (loss, metrics), grads = _value_and_grad(loss_fn, buffers, params,
+                                                 batch)
+        params, opt_state = _update(optimizer, buffers, grads, opt_state,
+                                    params, metrics)
         return SpmdStepOutput(params, opt_state, loss, metrics)
 
     prog = jax.jit(body, donate_argnums=(0, 1) if donate else ())

@@ -7,8 +7,9 @@ dispatch with a capacity factor, tokens over capacity dropped, GELU
 experts with biases, softmax gates and the auxiliary losses a trainer
 needs. Its shapes are static and its exchange over an ``ep`` mesh axis
 falls out of the SPMD partitioner, which is what a trained-from-scratch
-example wants. :class:`DroplessMoE` is the *serving* layer
-(``nn/block.py``, ``models/transformer.py`` ``block_kinds``): sigmoid
+example wants. :class:`DroplessMoE` is the layer published sparse models
+have (``nn/block.py``, ``models/transformer.py`` ``block_kinds``), served
+and trained: sigmoid
 scores with a bias-corrected top-k over all routed experts, the chosen
 (token, expert) pairs sorted by expert, one grouped matmul a projection
 (``jax.lax.ragged_dot``) over the experts this chip holds, the results
@@ -17,8 +18,14 @@ no token dropped, none padded into an expert it did not choose, and no
 one-hot tensor: a decode step reads only the experts its batch touches.
 Published sparse models route this way and a served model must compute
 what it was trained to compute, so the capacity layer cannot stand in
-for it; the dropless layer has no auxiliary losses and has been trained
-at no measured size (ROADMAP Reach), so it does not replace the example's.
+for it. It trains through ``jax.lax.ragged_dot``'s own derivatives (``dx``
+another grouped matmul, ``dW`` a product whose contracting dimension is
+the ragged one; :func:`grouped_matmul` zeroes the rows of ``dx`` that no
+group's kernel writes) and balances its experts without an auxiliary loss: the
+router's bias is a leaf outside the optimizer that the step moves from the
+step's own counts (:meth:`DroplessMoE.balance`, ``parallel.Buffers``,
+docs/front_door.md). It has no exchange over an ``ep`` axis yet (ROADMAP
+Reach), so it does not replace the example's.
 
 No reference analog (SURVEY.md §2.4: EP absent). TPU-native design
 (GShard): routing is *dense tensor algebra* — one-hot dispatch/combine
@@ -262,8 +269,43 @@ def moe_param_specs(ep_axis: str = "ep", tp_axis: Optional[str] = None,
     return specs
 
 
+@jax.custom_vjp
+def grouped_matmul(xs, w, sizes):
+    """``jax.lax.ragged_dot`` with float32 results: rows of ``xs`` (R, K),
+    sorted into groups of ``sizes`` (G,), each group through its own
+    ``w[g]`` (G, K, N). Rows past the last group belong to no group and
+    what the result holds there is not a result: on a TPU the kernel never
+    writes them.
+
+    Its derivatives are ``ragged_dot``'s own (``dxs`` another grouped
+    matmul, ``dw`` a product whose contracting dimension is the ragged
+    one), with one repair: the rows of ``dxs`` past the last group are
+    set to zero. Left as the kernel leaves them they are whatever the
+    buffer held before, and a trainer scatters every row of ``dxs`` back
+    onto its token (on the chip, with a sixteenth of the experts held,
+    that is 19 rows in 20: PERF.md Findings, PR 32)."""
+    return jax.lax.ragged_dot(xs, w, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _grouped_matmul_fwd(xs, w, sizes):
+    return grouped_matmul(xs, w, sizes), (xs, w, sizes)
+
+
+def _grouped_matmul_bwd(res, g):
+    xs, w, sizes = res
+    _, pull = jax.vjp(lambda a, b: jax.lax.ragged_dot(
+        a, b, sizes, preferred_element_type=jnp.float32), xs, w)
+    dxs, dw = pull(g)
+    grouped = (jnp.arange(xs.shape[0]) < jnp.sum(sizes))[:, None]
+    return jnp.where(grouped, dxs, 0), dw, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
 class DroplessMoE(Module):
-    """Dropless token-choice experts for serving: x (..., D) -> y (..., D).
+    """Dropless token-choice experts: x (..., D) -> y (..., D).
 
     Routes over all ``n_routed`` experts: ``g = sigmoid(x W_r)`` in
     float32, the ``top_k`` largest of ``g + bias`` (the bias corrects the
@@ -274,7 +316,12 @@ class DroplessMoE(Module):
     nothing stands in for the absent chips. The shared expert runs on
     every token (every chip computes it alike, so a sum over shares
     counts it once). Scopes: ``moe`` > ``route``, ``dispatch``,
-    ``experts``, ``shared``, ``combine``."""
+    ``experts``, ``shared``, ``combine``.
+
+    Gradients flow through the weights to the router and through the
+    experts; the choice has none, and neither has the bias, which
+    :meth:`balance` moves instead, from the load that ``apply``
+    returns."""
 
     def __init__(self, dim: int, n_routed: int, width: int, *, top_k: int,
                  n_shared: int = 1, scale: float = 1.0,
@@ -322,11 +369,20 @@ class DroplessMoE(Module):
 
     def routed(self, params: Params, xt, row_mask=None):
         """The held experts' part of the result for xt (T, D), float32,
-        and the counts ``(tokens_routed, experts_touched,
-        tokens_max_expert)`` of this call. ``row_mask`` (T,) bool leaves
-        rows out of the dispatch (idle slots, a padded tail)."""
+        the counts ``(tokens_routed, experts_touched,
+        tokens_max_expert)`` of this call, and its load: the (token,
+        expert) pairs the router sent to each of ALL ``n_routed`` experts
+        (int32), whichever of them are held here. ``row_mask`` (T,) bool
+        leaves rows out of the dispatch (idle slots, a padded tail) and
+        of the load."""
         t, k, c = xt.shape[0], self.top_k, self.count
         top_i, w, _ = self.route(params, xt)
+        with jax.named_scope("route"):
+            sent = jnp.ones((t, k), jnp.int32) if row_mask is None \
+                else jnp.broadcast_to(row_mask[:, None], (t, k)).astype(
+                    jnp.int32)
+            load = jnp.zeros((self.n_routed,), jnp.int32).at[
+                top_i.reshape(-1)].add(sent.reshape(-1))
         with jax.named_scope("dispatch"):
             eid = top_i.reshape(-1) - self.first
             here = (eid >= 0) & (eid < c)
@@ -339,8 +395,7 @@ class DroplessMoE(Module):
             xs = jnp.take(xt, order // k, axis=0)              # (T*k, D)
         with jax.named_scope("experts"):
             e = params["experts"]
-            dot = lambda a, b: jax.lax.ragged_dot(
-                a, b, sizes, preferred_element_type=jnp.float32)
+            dot = lambda a, b: grouped_matmul(a, b, sizes)
             h = jax.nn.silu(dot(xs, e["gate"])) * dot(xs, e["up"])
             ys = dot(h.astype(xt.dtype), e["down"])
         with jax.named_scope("combine"):
@@ -355,18 +410,32 @@ class DroplessMoE(Module):
                         axis=1)
         counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
                             jnp.max(sizes)]).astype(jnp.int32)
-        return y, counts
+        return y, counts, load
 
     def apply(self, params: Params, x, *, row_mask=None, stats=None, **_):
-        """``stats``: a list the call's counts (3,) int32 are appended
-        to (``stats()``'s ``moe_*`` counters are their sums)."""
+        """-> ``(y, load)``: the call's pairs per expert (n_routed,)
+        int32 are an output, which is how a trainer gets them out of a
+        rematerialised block; a program that does not use them does not
+        compute them. ``stats``: a list the call's counts (3,) int32 are
+        appended to (``stats()``'s ``moe_*`` counters are their sums)."""
         with jax.named_scope("moe"):
             xt = x.reshape(-1, self.dim)
-            y, counts = self.routed(
+            y, counts, load = self.routed(
                 params, xt, None if row_mask is None else row_mask.reshape(-1))
             if stats is not None:
                 stats.append(counts)
             if self.shared is not None:
                 with jax.named_scope("shared"):
                     y = y + self.shared.apply(params["shared"], xt)
-            return y.reshape(x.shape).astype(x.dtype)
+            return y.reshape(x.shape).astype(x.dtype), load
+
+    @staticmethod
+    def balance(bias, load, speed: float):
+        """The router bias after a step that sent ``load`` (n_routed,)
+        pairs to each expert (DeepSeek-V3 section 2.1.2, ``noaux_tc``):
+        ``b_e + speed * sign(mean(load) - load_e)``. An overloaded expert
+        is chosen a little less, with no gradient and no loss term."""
+        with jax.named_scope("moe/bias_update"):
+            load = load.astype(jnp.float32)
+            return bias + speed * jnp.sign(
+                jnp.mean(load, -1, keepdims=True) - load).astype(bias.dtype)

@@ -38,9 +38,14 @@ def test_forward_matches_dense(causal, s_q, s_k, bq, bk):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("s_q,s_k", [(64, 64), (50, 50)])
-def test_grads_match_dense(causal, s_q, s_k):
-    q, k, v = _qkv(jax.random.PRNGKey(1), s_q=s_q, s_k=s_k)
+@pytest.mark.parametrize("s_q,s_k,d", [
+    (64, 64, 16), (50, 50, 16),
+    (64, 64, 192),   # latent attention's expanded head: nope 128 + rope 64
+])
+def test_grads_match_dense(causal, s_q, s_k, d):
+    q, k, v = _qkv(jax.random.PRNGKey(1), s_q=s_q, s_k=s_k, d=d)
+    if d == 192:     # values 128 wide, padded to the keys' width
+        v = v.at[..., 128:].set(0.0)
 
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v, causal=causal) ** 2)
@@ -49,6 +54,9 @@ def test_grads_match_dense(causal, s_q, s_k):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
                                        block_q=16, block_k=16) ** 2)
 
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=causal, block_q=16, block_k=16),
+        dense_attention(q, k, v, causal=causal), atol=2e-5, rtol=2e-5)
     want = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for g, w, name in zip(got, want, "qkv"):
@@ -207,6 +215,7 @@ def _tpu_lowering(fn, *args):
     (2, 12, 12, 4096, 64, None),
     (4, 8, 2, 2048, 128, None),      # GQA, head_dim 128
     (2, 12, 12, 4096, 64, 512),      # sliding window
+    (1, 32, 32, 8192, 192, None),    # latent attention expanded, 8k
 ])
 def test_tpu_lowering_is_three_mosaic_calls(shape):
     """What the chip compiles is the Mosaic kernel, not the interpreter:

@@ -521,3 +521,91 @@ class TestHandoffChain:
             assert step.in_shardings["params"] == \
                 step.out_shardings["params"]
             assert step.in_shardings["opt"] == step.out_shardings["opt"]
+
+
+# ---------------------------------------------------------------------------
+# leaves outside the optimizer (docs/front_door.md)
+# ---------------------------------------------------------------------------
+
+
+def _buffered():
+    """A model with one weight and one counter beside it: the loss reads
+    both, the rule adds what the loss's second output says."""
+    from distributed_pytorch_tpu.parallel import Buffers
+
+    params = {"w": jnp.asarray([1.0, -2.0, 3.0]),
+              "seen": jnp.asarray([0.25], jnp.float32)}
+
+    def loss_fn(p, batch):
+        pred = batch @ p["w"] + p["seen"][0]
+        return jnp.mean(jnp.square(pred)), {
+            "rows": jnp.float32(batch.shape[0]), "seen_dtype_is_f32":
+            jnp.float32(p["seen"].dtype == jnp.float32)}
+
+    buffers = Buffers(
+        mask=lambda p: {"w": False, "seen": True},
+        update=lambda p, m: {"w": p["w"] * 0.0,       # dropped: no buffer
+                             "seen": p["seen"] + m["rows"]})
+    return params, loss_fn, buffers
+
+
+class TestBuffers:
+    def test_rule_moves_the_buffer_and_the_optimizer_never_sees_it(self):
+        params, loss_fn, buffers = _buffered()
+        opt = optim.adamw(1e-2, weight_decay=0.5)
+        state = opt.init(buffers.trainable(params))
+        assert state.mu == {"w": state.mu["w"], "seen": None}
+        step = make_train_step(loss_fn, opt, donate=False, buffers=buffers,
+                               mixed_precision="bf16")
+        batch = jnp.asarray(np.random.default_rng(0).random((4, 3)),
+                            jnp.float32)
+        plain = make_train_step(
+            lambda p, b: loss_fn({**p, "seen": params["seen"]}, b), opt,
+            donate=False, mixed_precision="bf16")
+        want = plain({"w": params["w"]}, opt.init({"w": params["w"]}), batch)
+        out = step(params, state, batch)
+        # the weight took exactly the optimizer's step; the rule's own
+        # change to it was dropped
+        np.testing.assert_array_equal(out.params["w"], want.params["w"])
+        # the buffer moved by the rule alone: no decay, no moment, and it
+        # reached the loss uncast under the bf16 working copy
+        np.testing.assert_array_equal(out.params["seen"], [4.25])
+        assert out.opt_state.mu["seen"] is None
+        assert float(out.metrics["seen_dtype_is_f32"]) == 1.0
+        out = step(out.params, out.opt_state, batch)
+        np.testing.assert_array_equal(out.params["seen"], [8.25])
+
+    def test_empty_mask_lowers_to_the_step_without_buffers(self):
+        """With no leaf outside the optimizer the program is the one
+        built without the keyword, text for text."""
+        from distributed_pytorch_tpu.parallel import Buffers
+
+        _, params, opt, loss_fn = _setup()
+        batch = (jnp.ones((8, 1)), jnp.zeros((8,), jnp.int32))
+        state = opt.init(params)
+        empty = Buffers(
+            mask=lambda p: jax.tree_util.tree_map(lambda _: False, p),
+            update=lambda p, m: p)
+        texts = [make_train_step(loss_fn, opt, donate=True,
+                                 mixed_precision="bf16", buffers=b)
+                 .lower(params, state, batch).as_text()
+                 for b in (None, empty)]
+        assert texts[0] == texts[1]
+
+    def test_runs_where_the_loss_is_the_whole_batchs(self, group8):
+        params, loss_fn, buffers = _buffered()
+        opt = optim.adamw(1e-2)
+        step = make_step(loss_fn, opt, specs=FROM_INPUTS, donate=False,
+                         buffers=buffers)
+        out = step(params, opt.init(buffers.trainable(params)),
+                   jnp.ones((8, 3)))
+        np.testing.assert_array_equal(out.params["seen"], [8.25])
+
+    @pytest.mark.parametrize("kw,where", [
+        (dict(), "dp island"),
+        (dict(weight_update="sharded"), "sharded"),
+        (dict(specs=StepSpecs(params={"w": P(), "seen": P()})), "ladder")])
+    def test_per_shard_engines_refuse_by_name(self, group8, kw, where):
+        params, loss_fn, buffers = _buffered()
+        with pytest.raises(ValueError, match="buffers= is not carried by"):
+            make_step(loss_fn, optim.adamw(1e-2), buffers=buffers, **kw)

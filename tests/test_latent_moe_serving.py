@@ -194,7 +194,7 @@ def test_no_token_is_dropped_at_any_imbalance(favoured):
     top_i, w, _ = layer.route(params, jnp.asarray(x))
     if favoured:
         assert set(np.asarray(top_i).ravel().tolist()) == set(favoured)
-    y, counts = layer.routed(params, jnp.asarray(x))
+    y, counts, _ = layer.routed(params, jnp.asarray(x))
     np.testing.assert_allclose(
         np.asarray(y), by_hand(layer, params, x, np.asarray(top_i),
                                np.asarray(w)), atol=2e-5)
@@ -204,7 +204,7 @@ def test_no_token_is_dropped_at_any_imbalance(favoured):
         assert touched == len(favoured) and fullest == 37
     # rows left out of the dispatch cost nothing and give nothing
     mask = np.arange(37) % 3 != 0
-    ym, cm = layer.routed(params, jnp.asarray(x), jnp.asarray(mask))
+    ym, cm, _ = layer.routed(params, jnp.asarray(x), jnp.asarray(mask))
     np.testing.assert_allclose(np.asarray(ym)[mask], np.asarray(y)[mask],
                                atol=2e-5)
     assert not np.asarray(ym)[~mask].any()
@@ -213,24 +213,135 @@ def test_no_token_is_dropped_at_any_imbalance(favoured):
 
 def test_shares_add_up_to_the_uncut_layer():
     """held=(0,4) + held=(4,4): the routed parts add up, and the whole
-    outputs add up once the shared expert is counted once."""
+    outputs add up once the shared expert is counted once; so do their
+    gradients (the router's and the input's add up over the shares, an
+    expert's is its own share's, the shared expert's is counted once)."""
     whole = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0)
     params = whole.init(jax.random.PRNGKey(5))
     x = jax.random.normal(jax.random.PRNGKey(6), (29, 24))
-    y_whole = whole.apply(params, x)
+    cot = jax.random.normal(jax.random.PRNGKey(7), (29, 24))
+    y_whole = whole.apply(params, x)[0]
     shared = whole.shared.apply(params["shared"], x)
-    parts, outs = [], []
+    loss = lambda layer: lambda p, x: jnp.sum(layer.apply(p, x)[0] * cot)
+    g_whole, gx_whole = jax.jit(jax.grad(loss(whole), argnums=(0, 1)))(
+        params, x)
+    g_shared, gx_shared = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(whole.shared.apply(p, x) * cot),
+        argnums=(0, 1)))(params["shared"], x)
+    parts, outs, grads = [], [], []
     for first in (0, 4):
         share = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0,
                             held=(first, 4))
         p = dict(params, experts=jax.tree_util.tree_map(
             lambda a: a[first:first + 4], params["experts"]))
         parts.append(share.routed(p, x)[0])
-        outs.append(share.apply(p, x))
+        outs.append(share.apply(p, x)[0])
+        grads.append(jax.jit(jax.grad(loss(share), argnums=(0, 1)))(p, x))
     np.testing.assert_allclose(parts[0] + parts[1],
                                whole.routed(params, x)[0], atol=2e-5)
     np.testing.assert_allclose(outs[0] + outs[1] - shared, y_whole,
                                atol=2e-5)
+    (g0, gx0), (g1, gx1) = grads
+    np.testing.assert_allclose(gx0 + gx1 - gx_shared, gx_whole, atol=2e-5)
+    np.testing.assert_allclose(g0["router"]["w"] + g1["router"]["w"],
+                               g_whole["router"]["w"], atol=2e-5)
+    for name in ("gate", "up", "down"):
+        np.testing.assert_allclose(
+            np.concatenate([g0["experts"][name], g1["experts"][name]]),
+            g_whole["experts"][name], atol=2e-5)
+        for g in (g0, g1, g_whole):     # every chip computes it alike
+            np.testing.assert_allclose(g["shared"][name]["w"],
+                                       g_shared[name]["w"], atol=2e-5)
+    # the bias corrects the choice only: no gradient reaches it
+    assert not np.asarray(g_whole["router"]["bias"]).any()
+
+
+def per_expert_loop(layer, params, x, top_i):
+    """The routed part as a differentiable masked sum over ALL experts,
+    one at a time, nothing sorted and no grouped matmul: the choice
+    ``top_i`` is given, the weights are recomputed from the scores."""
+    g = jax.nn.sigmoid(x @ params["router"]["w"])
+    chosen = jnp.zeros(g.shape, bool).at[
+        jnp.arange(x.shape[0])[:, None], top_i].set(True)
+    top = jnp.where(chosen, g, 0.0)
+    w = top / (jnp.sum(top, -1, keepdims=True) + 1e-20) * layer.scale
+    e, out = params["experts"], 0.0
+    for i in range(layer.n_routed):
+        h = jax.nn.silu(x @ e["gate"][i]) * (x @ e["up"][i])
+        out = out + w[:, i:i + 1] * (h @ e["down"][i])
+    return out
+
+
+@pytest.mark.parametrize("favoured", [(3,), (2, 5), ()])
+def test_gradients_match_a_per_expert_loop_at_any_imbalance(favoured):
+    """``jax.lax.ragged_dot``'s own derivatives over the sorted pairs
+    against a loop over experts: every pair to one expert (the others'
+    groups empty), to the same two, and the balanced case. No pair is
+    dropped in the backward pass either, and the load counts every pair."""
+    k = max(len(favoured), 1) if favoured else 2
+    layer = DroplessMoE(24, 8, 16, top_k=k, n_shared=1, scale=2.0)
+    params = layer.init(jax.random.PRNGKey(3))
+    bias = np.zeros(8, np.float32)
+    bias[list(favoured)] = 100.0
+    params["router"]["bias"] = jnp.asarray(bias)
+    x = jax.random.normal(jax.random.PRNGKey(4), (37, 24))
+    cot = jax.random.normal(jax.random.PRNGKey(9), (37, 24))
+    top_i, _, _ = layer.route(params, x)
+    got = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(layer.routed(p, x)[0] * cot),
+        argnums=(0, 1)))(params, x)
+    want = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(per_expert_loop(layer, p, x, top_i) * cot),
+        argnums=(0, 1)))(params, x)
+    np.testing.assert_allclose(got[1], want[1], atol=5e-5)
+    np.testing.assert_allclose(got[0]["router"]["w"], want[0]["router"]["w"],
+                               atol=5e-5)
+    for name in ("gate", "up", "down"):
+        np.testing.assert_allclose(got[0]["experts"][name],
+                                   want[0]["experts"][name], atol=5e-5)
+        if favoured:                    # an empty group has no gradient
+            idle = [i for i in range(8) if i not in favoured]
+            assert not np.asarray(got[0]["experts"][name])[idle].any()
+    _, _, load = layer.routed(params, x)
+    assert int(load.sum()) == 37 * k
+    assert np.array_equal(load, np.bincount(np.asarray(top_i).ravel(),
+                                            minlength=8))
+    # masked rows are neither dispatched nor counted
+    mask = jnp.arange(37) % 3 != 0
+    _, _, load_m = layer.routed(params, x, mask)
+    assert int(load_m.sum()) == int(mask.sum()) * k
+
+
+def test_rows_no_group_computed_get_a_zero_gradient():
+    """``grouped_matmul``: the rows past the last group are the pairs
+    routed to experts this chip does not hold. On the chip the kernel
+    leaves whatever the buffer held there, in the result and in ``dxs``
+    alike; the derivative zeroes them whatever cotangent arrives."""
+    from distributed_pytorch_tpu.parallel.moe import grouped_matmul
+    xs = jax.random.normal(jax.random.PRNGKey(0), (12, 8))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 8, 5))
+    sizes = jnp.asarray([4, 0, 3], jnp.int32)          # rows 7.. in no group
+    cot = jax.random.normal(jax.random.PRNGKey(2), (12, 5)).at[7:].set(1e30)
+    out, pull = jax.vjp(lambda a, b: grouped_matmul(a, b, sizes), xs, w)
+    dxs, dw = pull(cot)
+    want = jax.lax.ragged_dot(xs, w, sizes)
+    np.testing.assert_allclose(out[:7], want[:7], atol=1e-5)
+    assert not np.asarray(dxs)[7:].any()
+    np.testing.assert_allclose(dxs[:4], cot[:4] @ w[0].T, atol=1e-4)
+    np.testing.assert_allclose(dxs[4:7], cot[4:7] @ w[2].T, atol=1e-4)
+    np.testing.assert_allclose(dw[0], xs[:4].T @ cot[:4], atol=1e-4)
+    assert not np.asarray(dw[1]).any()                 # an empty group
+
+
+def test_bias_rule_against_values_worked_by_hand():
+    """b_e <- b_e + speed * sign(mean(c) - c_e): mean 4, so the experts
+    with 1 and 3 pairs rise, the one with 4 stays, those with 5 and 7
+    fall."""
+    bias = jnp.asarray([0.0, 0.01, -0.02, 0.003, 0.0])
+    load = jnp.asarray([1, 3, 4, 5, 7], jnp.int32)
+    np.testing.assert_allclose(
+        DroplessMoE.balance(bias, load, 0.001),
+        [0.001, 0.011, -0.02, 0.002, -0.001], atol=1e-9)
 
 
 # -- the residual path -----------------------------------------------------------
@@ -332,3 +443,74 @@ def test_the_fixed_block_is_untouched_by_the_new_keywords():
     toks, rows, _ = serve_through_pool(parts, pp, pool, prompt, 0, 6)
     ref = full_logits(parts, pp, np.concatenate([prompt, toks]))[6:12]
     np.testing.assert_allclose(rows, ref, atol=2e-4, rtol=0)
+
+
+# -- the prediction module ------------------------------------------------------
+
+MTP_TINY = {**{k: v for k, v in TINY.items()
+               if k not in ("hyper_connections", "hc")},
+            "moe": dict(TINY["moe"], held=(2, 4))}
+
+
+def test_mtp_zero_builds_what_was_built_before_and_apply_never_runs_it():
+    plain = models.TransformerLM(**MTP_TINY)
+    zero = models.TransformerLM(**MTP_TINY, mtp=0)
+    one = models.TransformerLM(**MTP_TINY, mtp=1)
+    key = jax.random.PRNGKey(2)
+    p_plain, p_zero, p_one = plain.init(key), zero.init(key), one.init(key)
+    assert plain.mtp is None and zero.mtp is None and "mtp" not in p_zero
+    same = lambda a, b: jax.tree_util.tree_all(
+        jax.tree_util.tree_map(lambda x, y: bool(jnp.array_equal(x, y)),
+                               a, b))
+    assert same(p_plain, p_zero)
+    # the module is one more subtree: every other leaf is the same array
+    assert set(p_one) == set(p_plain) | {"mtp"}
+    assert same({k: v for k, v in p_one.items() if k != "mtp"}, p_plain)
+    assert set(p_one["mtp"]) == {"norm_e", "norm_h", "proj", "block", "norm"}
+    assert p_one["mtp"]["proj"]["w"].shape == (128, 64)
+    assert set(p_one["mtp"]["block"]) == set(p_plain["blocks"][-1])
+    tokens = jnp.asarray(np.arange(24).reshape(2, 12) % 211)
+    np.testing.assert_array_equal(jax.jit(one.apply)(p_one, tokens),
+                                  jax.jit(plain.apply)(p_plain, tokens))
+    # and the entry for a loss over both heads
+    main, mtp, load = jax.jit(one.heads_hidden)(p_one, tokens)
+    np.testing.assert_allclose(
+        main, jax.jit(lambda p, t: plain.apply(p, t, return_hidden=True))(
+            p_plain, tokens[:, :-1]), atol=1e-6)
+    assert mtp.shape == (2, 10, 64) and load.shape == (3, 8)
+    # 2 rows x 11 positions x 2 a token; the module one position fewer
+    assert np.asarray(load).sum(-1).tolist() == [44, 44, 40]
+    main0, mtp0, load0 = jax.jit(plain.heads_hidden)(p_plain, tokens)
+    assert mtp0 is None and np.array_equal(load0, load[:2])
+    mask = one.router_bias_mask(p_one)
+    assert sum(jax.tree_util.tree_leaves(mask)) == 3
+    assert mask["mtp"]["block"]["ffn"]["router"]["bias"] is True
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(mtp=2), "0 or 1"),
+    (dict(mtp=1, hyper_connections=4), "plain residual"),
+    (dict(mtp=1, block_kinds=None, attention="mha", norm="layer",
+          pos="learned"), "made of parts")])
+def test_mtp_refusals(kw, match):
+    with pytest.raises(ValueError, match=match):
+        models.TransformerLM(**{**MTP_TINY, **kw})
+
+
+def test_the_modules_last_position_is_cut_not_counted_and_unseen():
+    """The module runs over all S positions and cuts the last: what it
+    returns and counts equals a run over S - 1 positions alone."""
+    one = models.TransformerLM(**MTP_TINY, mtp=1)
+    p = one.init(jax.random.PRNGKey(2))
+    tokens = jnp.asarray((np.arange(26).reshape(2, 13) * 7) % 211)
+    h, _ = one._trunk(p, tokens[:, :-1], positions=jnp.arange(12))
+    _, mtp, load = one.heads_hidden(p, tokens)
+    m, q = one.mtp, p["mtp"]
+    x = m["proj"].apply(q["proj"], jnp.concatenate(
+        [m["norm_e"].apply(q["norm_e"], one.tok.apply(p["tok"],
+                                                      tokens[:, 1:-1])),
+         m["norm_h"].apply(q["norm_h"], h[:, :-1])], -1))
+    y, load_m = m["block"].apply(q["block"], x, positions=jnp.arange(11))
+    np.testing.assert_allclose(mtp, m["norm"].apply(q["norm"], y),
+                               atol=1e-5)
+    assert np.array_equal(load[-1], load_m)

@@ -440,3 +440,24 @@ def test_decode_program_moves_no_pool_for_v5e(one_chip, monkeypatch):
     moved = [line for line in text.splitlines()
              if " copy(" in line and "8192,2,16,128" in line]
     assert not moved, moved[:2]
+
+
+def test_flash_at_head_192_and_8k_compiles_for_v5e(one_chip):
+    """Latent attention's expanded form at the training cell's size (one
+    row, 32 heads, 8192 positions, keys of nope 128 + rope 64, the values
+    padded to them): forward and both backward kernels at the default
+    ("d > 128") tiles, which the chip's compiler has to take whole."""
+    from distributed_pytorch_tpu.ops import flash_attention
+    from distributed_pytorch_tpu.ops.flash_attention import _block_sizes
+
+    assert _block_sizes(8192, 8192, None, None, d=192) == (256, 256)
+    assert _block_sizes(8192, 8192, None, None, d=192, bwd=True) == (256,
+                                                                     256)
+    qkv = [jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16,
+                                sharding=one_chip)] * 3
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5,
+            interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    assert grad.lower(*qkv).compile().as_text().count(
+        "tpu_custom_call") == 3
