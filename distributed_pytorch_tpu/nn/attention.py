@@ -26,7 +26,8 @@ def dense_attention(q, k, v, *, causal: bool = False,
                     window: Optional[int] = None):
     """Reference attention: softmax(q k^T / sqrt(d)) v.
 
-    q: (B, H, S, Dh); k, v: (B, Hkv, S, Dh) where Hkv divides H —
+    q: (B, H, S, Dh); k: (B, Hkv, S, Dh), v: (B, Hkv, S, Dv) where Dv may
+    differ from Dh (the result is Dv wide) and Hkv divides H —
     Hkv < H is grouped-query attention (each kv head serves H/Hkv query
     heads), computed via a grouped einsum so the kv tensors are never
     repeated in memory.
@@ -71,7 +72,13 @@ def dense_attention(q, k, v, *, causal: bool = False,
         logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bngqk,bnkd->bngqd", probs, v) \
-        .reshape(b, h, s_q, dh)
+        .reshape(b, h, s_q, v.shape[-1])
+
+
+#: values narrower than the keys are taken as they are; a caller that
+#: holds unequal widths (``nn.latent.LatentAttention._core``) reads this
+#: off its core and pads for one that does not say so
+dense_attention.narrow_values = True
 
 
 class MultiHeadAttention(Module):

@@ -236,11 +236,17 @@ class LatentAttention(Module):
         return (q,) + self._key_heads(k_n, k_r, v)
 
     def _core(self, q, k, v):
-        """The pluggable causal core on equal head widths: the values are
-        padded to the keys' width and cut back."""
+        """The pluggable causal core: queries and keys ``qk_dim`` wide,
+        values and the result ``v_dim``. A core that says it takes values
+        narrower than the keys (``narrow_values``: the dense einsum, the
+        flash kernel) gets them as they are; any other (the ring paths
+        accumulate at the queries' width) gets them padded to the keys'
+        width, and its result is cut back."""
         with jax.named_scope("attn/core"):
             pad = self.qk_dim - self.v_dim
-            vp = jnp.pad(v, ((0, 0),) * 3 + ((0, pad),)) if pad > 0 else v
+            if pad <= 0 or getattr(self.attn_fn, "narrow_values", False):
+                return self.attn_fn(q, k, v, causal=True, scale=self.scale)
+            vp = jnp.pad(v, ((0, 0),) * 3 + ((0, pad),))
             o = self.attn_fn(q, k, vp, causal=True, scale=self.scale)
             return o[..., :self.v_dim]
 

@@ -22,6 +22,12 @@ Backward follows FlashAttention-2: recompute p = exp(qk - lse) blockwise;
 one kernel accumulates dK/dV over q-blocks, a second accumulates dQ over
 k-blocks. Residuals are (q, k, v, o, lse) — no S^2 tensor is ever saved.
 
+Heads wider than one lane group of 128, or values narrower than the keys
+(``_wide``), take tiles from the sweep at that width, index maps that hand
+the pipeline no new block on a grid step the causal mask skips, and a
+``jax.jit`` around the calls so that a step's layers share one traced and
+lowered kernel; every other call builds the program it always built.
+
 Numerics are validated against ``dense_attention`` (values and grads) in
 ``tests/test_flash_attention.py`` using interpret mode on CPU.
 """
@@ -76,31 +82,64 @@ def _ceil128(s):
     return -(-s // 128) * 128
 
 
+def _wide(d, d_v):
+    """Whether a call takes the path of heads wider than one lane group
+    (``d > 128``) or of values narrower than the keys: tiles from the
+    sweep at that width, K/V (dK/dV: q/dO) index maps that name no new
+    block on a grid step the causal frontier skips, and output, dO, dV
+    and the accumulator at the values' own width. Every other call builds
+    the three ``pallas_call``s with the tiles, index maps, kernel bodies
+    and compiler parameters they had before PR 36: its lowered program is
+    that one, text for text (CHANGES.md, PR 36; a Pallas call costs a
+    tenth of a second to trace and lower on every run, and a step may
+    hold 80)."""
+    return d > _LANES or d_v != d
+
+
 def _block_sizes(s_q, s_k, block_q, block_k, d=64, bwd=False, window=None):
-    """Resolve tile sizes. Explicit ints behave as before (clamped to the
+    """Resolve tile sizes, of the forward kernel or (``bwd``) of the two
+    backward kernels. Explicit ints behave as before (clamped to the
     sequence); ``None`` picks the default for the chip.
 
     Large tiles, because the grid is short at moderate seq and each grid
-    step has a fixed cost: 1024 wide in forward at d <= 64; backward
-    caps at 512 — its three (bq, bk) f32 tiles (p, dp, ds) triple the
-    VMEM bill. Caps shrink with head_dim since every tile scales with d.
-    Every default compiles on the v5e, forward and both backward
-    kernels, at the flagship shape (b8 h12 s1024 d64), seq 4096, d=128,
-    GQA and ``window=`` (chip_smoke.py, PR 21); how fast each is against
-    smaller tiles is not measured (``benchmarks/flash_block_sweep.py``
-    is the sweep). Head 192 (latent attention expanded: nope 128 + rope
-    64, values padded to it) at b1 h32 s8192 takes the "d > 128" caps,
-    256 x 256 in forward and in both backward kernels: they compile and
-    run on the v5e (PR 32's cell ``train-joyai-flash-8k-1chip``; its
-    share of the roofline is in PERF.md section 5). 256 and not 512
-    because a 192-wide block is laid out over two lanes of 128, so a
-    tile costs the VMEM of one 256 wide and the backward kernels hold
-    five of them beside three (bq, bk) float32 tiles. With sliding-window
-    attention the k cap clamps near the window width instead — a k tile
-    much wider than the band would compute mostly-masked logits and
-    degrade the O(S*window) cost toward O(S*block_k)."""
-    cap = (512 if d <= 64 else 256) if bwd else \
-        (1024 if d <= 64 else (512 if d <= 128 else 256))
+    step has a fixed cost. Head widths up to 128 take CAPS: 1024 wide in
+    forward at d <= 64 and 512 at 128; the backward kernels 512 and 256
+    -- their three (bq, bk) f32 tiles (p, dp, ds) triple the VMEM bill.
+    Every one compiles on the v5e, forward and both backward kernels, at
+    the flagship shape (b8 h12 s1024 d64), seq 4096, d=128, GQA and
+    ``window=`` (chip_smoke.py, PR 21); they are what they were before
+    PR 36 (``tests/test_flash_attention.py`` holds the table), and what
+    the sweep reads at GPT-2 XL's shape is ROADMAP Speed 6's.
+
+    Head widths over 128 (latent attention expanded: keys nope 128 + rope
+    64 = 192, values 128) take what the v5e measured, kernel by kernel
+    (``benchmarks/flash_block_sweep.py --shape 1,32,<s>,192,128`` at s
+    1024, 2048, 4096 and 8192; the table is in PERF.md, Findings, PR 36).
+    FORWARD: 1024 x 1024 at every length (s 8192: 7.3 ms a call against
+    26.0 at the 256 x 256 every such shape had before; s 1024: one tile a
+    head, 0.17 ms against 0.29 at 512 x 512 and 0.47 at 256 x 256, though
+    it computes the masked quarter of the square). BACKWARD, dK/dV and
+    dQ alike: SQUARE TILES OF HALF THE SHORTER SEQUENCE, AT LEAST 256 AND
+    AT MOST 1024 (s 8192: 11.6 and 11.4 ms at 1024 x 1024 against 21.1
+    and 16.5; s 1024: 512 x 512 beats 1024 x 1024 by 7 and 4 %; s 2048:
+    the two within 3 %). A grid step at 256 x 256 cost as much as its
+    work, and tiles of 2048 either way gain nothing more. A (rows, 192)
+    bf16 block is laid out over two lane groups of 128 and costs the
+    VMEM of one 256 wide; each ``pallas_call`` of that path states a
+    bound on what it holds (``_vmem_limit``).
+
+    With sliding-window attention the k cap clamps near the window width
+    instead -- a k tile much wider than the band would compute
+    mostly-masked logits and degrade the O(S*window) cost toward
+    O(S*block_k)."""
+    if d > _LANES:
+        half = max(min(s_q, s_k) // 2, 1)
+        cap = min(1024, max(256, 1 << (half.bit_length() - 1))) if bwd \
+            else 1024
+    elif bwd:
+        cap = 512 if d <= 64 else 256
+    else:
+        cap = 1024 if d <= 64 else 512
     cap_k = min(cap, max(128, _ceil128(window))) if window is not None \
         else cap
     bq = min(cap, _ceil128(s_q)) if block_q is None \
@@ -108,6 +147,52 @@ def _block_sizes(s_q, s_k, block_q, block_k, d=64, bwd=False, window=None):
     bk = min(cap_k, _ceil128(s_k)) if block_k is None \
         else max(min(block_k, s_k), 1)
     return bq, bk
+
+
+def _vmem_limit(kernel, bq, bk, d, d_v, itemsize):
+    """``vmem_limit_bytes`` of one kernel of the wide path: an UPPER
+    bound counted from its shapes, a quarter on top, and never under the
+    16 MiB the compiler scopes by default. Counted as if held at once:
+    operand and result blocks double-buffered by the pipeline (a block's
+    trailing dimension laid out over whole lane groups of 128), the
+    float32 scratch accumulators, and every (bq, bk) tile the body names
+    (forward: scores and probabilities in float32 and the probabilities
+    again in the operands' type; backward: p, dp, ds and two such casts).
+    Mosaic holds less than that: at 1024 x 1024, keys 192 and values 128,
+    the three kernels compile for a DESCRIBED v5e from 8, 8 and 9 MiB
+    (fwd, dK/dV, dQ; bisected on the CPU container, PR 36) where this
+    says 19, 30 and 29. On the chip the parent's kernels at 1024 x 1024
+    with the values padded to 192 and no limit stated ran out of VMEM in
+    the backward (PR 36, chip call 1), so the bound is stated; it also
+    lets explicit tiles up to 2048 x 1024 (15-17 MiB by the same
+    bisection) compile."""
+    def blk(rows, width, nbytes=itemsize):
+        return rows * _ceil128(width) * nbytes
+
+    stats = blk(bq, _STATS, 4)
+    io = blk(bq, d) + blk(bk, d) + blk(bk, d_v) + blk(bq, d_v)
+    if kernel == "fwd":
+        io += stats
+        scratch = 2 * blk(bq, _LANES, 4) + blk(bq, d_v, 4)
+        held = 2 * 4 + itemsize
+    elif kernel == "dkv":
+        io += 2 * stats + blk(bk, d) + blk(bk, d_v)
+        scratch = blk(bk, d, 4) + blk(bk, d_v, 4)
+        held = 3 * 4 + 2 * itemsize
+    else:
+        io += 2 * stats + blk(bq, d)
+        scratch = blk(bq, d, 4)
+        held = 3 * 4 + 2 * itemsize
+    need = 2 * io + scratch + held * bq * bk
+    return max(16 * 2 ** 20, need + need // 4)
+
+
+def _compiler_params(kernel, wide, bq, bk, d, d_v, itemsize):
+    if not wide:
+        return pltpu.CompilerParams(dimension_semantics=_PARALLEL)
+    return pltpu.CompilerParams(
+        dimension_semantics=_PARALLEL,
+        vmem_limit_bytes=_vmem_limit(kernel, bq, bk, d, d_v, itemsize))
 
 
 def _pad_seq(x, block, axis):
@@ -133,7 +218,9 @@ def _frontier_ok(iq, ik, *, block_q, block_k, q_len, k_len, window=None,
     entirely below it are skipped — that skip is what makes windowed
     attention O(S*window) instead of O(S^2/2). Single source of truth
     for fwd and both bwd kernels — the masks must never desynchronize or
-    gradients silently break."""
+    gradients silently break. The wide path's index maps (``_held_k``,
+    ``_held_q``) ask it too: a block index is the grid's own wherever
+    this says the body runs."""
     off = k_len - q_len + diag_offset
     ok = ik * block_k <= (iq + 1) * block_q - 1 + off
     if window is not None:
@@ -141,6 +228,54 @@ def _frontier_ok(iq, ik, *, block_q, block_k, q_len, k_len, window=None,
         ok = jnp.logical_and(
             ok, ik * block_k + block_k - 1 >= iq * block_q + off - window + 1)
     return ok
+
+
+def _floor_div_pos(x, n):
+    """``x // n`` for a numerator clipped at 0 first (an edge that lies
+    before the first tile is the first tile): truncating division is
+    then the floor, without the sign repair ``//`` traces."""
+    return jax.lax.div(jnp.maximum(x, 0), n)
+
+
+def _held_k(iq, ik, n_k, *, block_q, block_k, q_len, k_len, window,
+            diag_offset):
+    """The k tile a K or V BlockSpec names at grid step (iq, ik) of the
+    wide path's causal forward and dQ kernels: ``ik`` wherever
+    ``_frontier_ok`` runs the body; on a step it skips, the nearest tile
+    it admits for ``iq`` -- the last one past the diagonal, with a
+    ``window`` the first one before the band's lower edge -- so the
+    pipeline sees the block index it holds and copies nothing. The
+    nearest tile is ``_frontier_ok``'s inequalities solved for ``ik``
+    (``tests/test_flash_attention.py`` holds the two together step by
+    step); were it ever wrong, a skipped step would copy a block nobody
+    reads, and no result would change. Always inside ``[0, n_k)``, also
+    where ``iq`` sees nothing."""
+    off = k_len - q_len + diag_offset
+    near = jnp.minimum(ik, _floor_div_pos((iq + 1) * block_q - 1 + off,
+                                          block_k))
+    if window is not None:
+        first = _floor_div_pos(iq * block_q + off - window + 1, block_k)
+        near = jnp.maximum(near, jnp.minimum(first, n_k - 1))
+    ok = _frontier_ok(iq, ik, block_q=block_q, block_k=block_k, q_len=q_len,
+                      k_len=k_len, window=window, diag_offset=diag_offset)
+    return jnp.where(ok, ik, near)
+
+
+def _held_q(ik, iq, n_q, *, block_q, block_k, q_len, k_len, window,
+            diag_offset):
+    """``_held_k``'s mirror for the dK/dV kernel, whose grid is (bh, ik,
+    iq): the q tile that the q, dO and row-statistics BlockSpecs name.
+    Causality bounds the q tiles of ``ik`` from below (the steps before
+    its first visible q tile name that tile), a ``window`` from above."""
+    off = k_len - q_len + diag_offset
+    first = _floor_div_pos(ik * block_k - off, block_q)
+    near = jnp.maximum(iq, jnp.minimum(first, n_q - 1))
+    if window is not None:
+        near = jnp.minimum(near, _floor_div_pos(
+            ik * block_k + block_k - 2 - off + window, block_q))
+    ok = _frontier_ok(iq, ik, block_q=block_q, block_k=block_k, q_len=q_len,
+                      k_len=k_len, window=window, diag_offset=diag_offset)
+    return jnp.where(ok, iq, near)
 
 
 def _tile_mask(iq, ik, *, block_q, block_k, q_len, k_len, causal,
@@ -276,16 +411,44 @@ def _kv_index(bh, h, h_kv, g):
     return bh // h * h_kv + bh % h // g
 
 
+def _kv_map(h, h_kv, g, n_k, held, **geom):
+    """The index map of a K or V block under the grid (bh, iq, ik) of the
+    forward and dQ kernels: the grid's own ``ik``, or (``held``: the wide
+    path under a causal mask) ``_held_k``'s."""
+    if held:
+        return lambda bh, iq, ik: (_kv_index(bh, h, h_kv, g),
+                                   _held_k(iq, ik, n_k, **geom), 0)
+    return lambda bh, iq, ik: (_kv_index(bh, h, h_kv, g), ik, 0)
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                window=None, causal_offset=0, diag_offset=0):
+    """(o, lse). A wide call (``_wide``) goes through ``jax.jit``: a
+    step's layers make the same call again and again, and jit traces and
+    lowers one function for all of them, where a bare ``pallas_call`` is
+    traced and lowered anew each time (on the sandbox's CPU a repeated
+    forward + backward call costs 4 ms to trace and lower against 144 bare;
+    PERF.md, Findings, PR 36). XLA inlines the calls, so the compiled
+    step is the same. Every other call stays bare: its program is the one
+    it was."""
+    impl = _flash_fwd_shared if _wide(q.shape[-1], v.shape[-1]) \
+        else _flash_fwd_impl
+    return impl(q, k, v, causal, scale, block_q, block_k,
+                _interpret_default(interpret), window, causal_offset,
+                diag_offset)
+
+
+def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window, causal_offset, diag_offset):
     b, h, s_q, d = q.shape
-    h_kv, s_k = k.shape[1], k.shape[2]
+    h_kv, s_k, d_v = k.shape[1], k.shape[2], v.shape[-1]
     g = _kv_head_group(h, h_kv)
+    wide = _wide(d, d_v)
     bq, bk = _block_sizes(s_q, s_k, block_q, block_k, d=d, window=window)
 
     q3 = _pad_seq(q.reshape(b * h, s_q, d), bq, 1)
     k3 = _pad_seq(k.reshape(b * h_kv, s_k, d), bk, 1)
-    v3 = _pad_seq(v.reshape(b * h_kv, s_k, d), bk, 1)
+    v3 = _pad_seq(v.reshape(b * h_kv, s_k, d_v), bk, 1)
     sq_p, sk_p = q3.shape[1], k3.shape[1]
     n_q, n_k = sq_p // bq, sk_p // bk
 
@@ -293,36 +456,35 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         _fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=bq, block_k=bk, n_k=n_k, q_len=s_q, k_len=s_k,
         causal_offset=causal_offset, diag_offset=diag_offset)
+    kv_map = _kv_map(h, h_kv, g, n_k, wide and causal, block_q=bq,
+                     block_k=bk, q_len=s_q, k_len=s_k, window=window,
+                     diag_offset=diag_offset)
     o3, lse3 = pl.pallas_call(
         kern,
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bk, d),
-                         lambda bh, iq, ik: (_kv_index(bh, h, h_kv, g),
-                                             ik, 0)),
-            pl.BlockSpec((1, bk, d),
-                         lambda bh, iq, ik: (_kv_index(bh, h, h_kv, g),
-                                             ik, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d_v), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, bq, d_v), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, bq, _STATS), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq_p, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq_p, _STATS), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, d_v), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=_PARALLEL),
-        interpret=_interpret_default(interpret),
+        compiler_params=_compiler_params("fwd", wide, bq, bk, d, d_v,
+                                         q.dtype.itemsize),
+        interpret=interpret,
     )(q3, k3, v3)
-    o = o3[:, :s_q].reshape(b, h, s_q, d)
+    o = o3[:, :s_q].reshape(b, h, s_q, d_v)
     lse = lse3[:, :s_q, 0].reshape(b, h, s_q)
     return o, lse
 
@@ -431,15 +593,126 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
+def _bwd_operands(q, k, v, g, lse, delta, bq, bk):
+    """The six operands of a backward kernel, flattened over batch*heads
+    and padded to whole (bq, bk) tiles."""
+    (b, h, s_q, d), (_, h_kv, s_k, d_v) = q.shape, v.shape
+    q3 = _pad_seq(q.reshape(b * h, s_q, d), bq, 1)
+    k3 = _pad_seq(k.reshape(b * h_kv, s_k, d), bk, 1)
+    v3 = _pad_seq(v.reshape(b * h_kv, s_k, d_v), bk, 1)
+    g3 = _pad_seq(g.reshape(b * h, s_q, d_v), bq, 1)
+    # Row stats replicated to a narrow (BH, S, _STATS) trailing axis — see
+    # the lse layout note in _fwd_kernel.
+    lse2 = _pad_seq(lse.reshape(b * h, s_q), bq, 1)
+    delta2 = _pad_seq(delta.reshape(b * h, s_q), bq, 1)
+    lse3 = jnp.broadcast_to(lse2[..., None], lse2.shape + (_STATS,))
+    delta3 = jnp.broadcast_to(delta2[..., None], lse3.shape)
+    return q3, k3, v3, g3, lse3, delta3
+
+
+def _bwd_dkv(ops, shape, bq, bk, causal, scale, interp, window,
+             causal_offset, diag_offset):
+    """dK and dV PER Q-HEAD, (B*H, Sk padded, d) and (B*H, Sk padded,
+    d_v): grid programs may not reduce into a shared output block, so a
+    kv group's partials are summed by the caller -- one extra (B, H, Sk,
+    D) temp, only when the group is larger than 1. ``shape`` is (b, h,
+    h_kv, s_q, s_k, d, d_v) of the call, ``ops`` its ``_bwd_operands``
+    at (bq, bk)."""
+    b, h, h_kv, s_q, s_k, d, d_v = shape
+    grp = _kv_head_group(h, h_kv)
+    wide = _wide(d, d_v)
+    n_q, n_k = ops[0].shape[1] // bq, ops[1].shape[1] // bk
+    if wide and causal:
+        def row_map(bh, ik, iq):
+            return bh, _held_q(ik, iq, n_q, block_q=bq, block_k=bk,
+                               q_len=s_q, k_len=s_k, window=window,
+                               diag_offset=diag_offset), 0
+    else:
+        def row_map(bh, ik, iq):
+            return bh, iq, 0
+    q_spec = pl.BlockSpec((1, bq, d), row_map)
+    do_spec = pl.BlockSpec((1, bq, d_v), row_map)
+    row_spec = pl.BlockSpec((1, bq, _STATS), row_map)
+
+    def kv_map(bh, ik, iq):
+        return _kv_index(bh, h, h_kv, grp), ik, 0
+
+    def out_map(bh, ik, iq):
+        return bh, ik, 0
+
+    return pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                          window=window, block_q=bq, block_k=bk, n_q=n_q,
+                          q_len=s_q, k_len=s_k,
+                          causal_offset=causal_offset,
+                          diag_offset=diag_offset),
+        grid=(b * h, n_k, n_q),
+        in_specs=[q_spec, pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d_v), kv_map), do_spec, row_spec,
+                  row_spec],
+        out_specs=[pl.BlockSpec((1, bk, d), out_map),
+                   pl.BlockSpec((1, bk, d_v), out_map)],
+        out_shape=[jax.ShapeDtypeStruct((b * h, n_k * bk, d), ops[1].dtype),
+                   jax.ShapeDtypeStruct((b * h, n_k * bk, d_v),
+                                        ops[2].dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d_v), jnp.float32)],
+        compiler_params=_compiler_params("dkv", wide, bq, bk, d, d_v,
+                                         ops[0].dtype.itemsize),
+        interpret=interp,
+    )(*ops)
+
+
+def _bwd_dq(ops, shape, bq, bk, causal, scale, interp, window,
+            causal_offset, diag_offset):
+    """dQ, (B*H, Sq padded, d); arguments as ``_bwd_dkv``'s."""
+    b, h, h_kv, s_q, s_k, d, d_v = shape
+    grp = _kv_head_group(h, h_kv)
+    wide = _wide(d, d_v)
+    n_q, n_k = ops[0].shape[1] // bq, ops[1].shape[1] // bk
+    kv_map = _kv_map(h, h_kv, grp, n_k, wide and causal, block_q=bq,
+                     block_k=bk, q_len=s_q, k_len=s_k, window=window,
+                     diag_offset=diag_offset)
+
+    def row_map(bh, iq, ik):
+        return bh, iq, 0
+
+    q_spec = pl.BlockSpec((1, bq, d), row_map)
+    row_spec = pl.BlockSpec((1, bq, _STATS), row_map)
+    return pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                          window=window, block_q=bq, block_k=bk, n_k=n_k,
+                          q_len=s_q, k_len=s_k,
+                          causal_offset=causal_offset,
+                          diag_offset=diag_offset),
+        grid=(b * h, n_q, n_k),
+        in_specs=[q_spec, pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d_v), kv_map),
+                  pl.BlockSpec((1, bq, d_v), row_map), row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, n_q * bq, d), ops[0].dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_compiler_params("dq", wide, bq, bk, d, d_v,
+                                         ops[0].dtype.itemsize),
+        interpret=interp,
+    )(*ops)
+
+
 def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                interpret, g_lse=None, window=None, causal_offset=0,
                diag_offset=0):
-    b, h, s_q, d = q.shape
-    h_kv, s_k = k.shape[1], k.shape[2]
+    """(dq, dk, dv); a wide call through ``jax.jit`` as ``_flash_fwd``'s."""
+    impl = _flash_bwd_shared if _wide(q.shape[-1], v.shape[-1]) \
+        else _flash_bwd_impl
+    return impl(q, k, v, o, lse, g, g_lse, causal, scale, block_q, block_k,
+                _interpret_default(interpret), window, causal_offset,
+                diag_offset)
+
+
+def _flash_bwd_impl(q, k, v, o, lse, g, g_lse, causal, scale, block_q,
+                    block_k, interp, window, causal_offset, diag_offset):
+    (b, h, s_q, d), (_, h_kv, s_k, d_v) = q.shape, v.shape
     grp = _kv_head_group(h, h_kv)
-    bq, bk = _block_sizes(s_q, s_k, block_q, block_k, d=d, bwd=True,
-                          window=window)
-    interp = _interpret_default(interpret)
 
     # delta_i = sum_d dO_i * O_i — tiny elementwise+reduce; XLA fuses it.
     # Zero cotangent elements contribute exactly zero even where O is
@@ -456,79 +729,31 @@ def _flash_bwd(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         # kernels run unchanged with delta' = delta - g_lse.
         delta = delta - g_lse.astype(jnp.float32)
 
-    q3 = _pad_seq(q.reshape(b * h, s_q, d), bq, 1)
-    k3 = _pad_seq(k.reshape(b * h_kv, s_k, d), bk, 1)
-    v3 = _pad_seq(v.reshape(b * h_kv, s_k, d), bk, 1)
-    g3 = _pad_seq(g.reshape(b * h, s_q, d), bq, 1)
-    # Row stats replicated to a narrow (BH, S, _STATS) trailing axis — see
-    # the lse layout note in _fwd_kernel.
-    lse2 = _pad_seq(lse.reshape(b * h, s_q), bq, 1)
-    delta2 = _pad_seq(delta.reshape(b * h, s_q), bq, 1)
-    lse3 = jnp.broadcast_to(lse2[..., None], lse2.shape + (_STATS,))
-    delta3 = jnp.broadcast_to(delta2[..., None], lse3.shape)
-    sq_p, sk_p = q3.shape[1], k3.shape[1]
-    n_q, n_k = sq_p // bq, sk_p // bk
-
-    q_spec = pl.BlockSpec((1, bq, d), lambda bh, ik, iq: (bh, iq, 0))
-    kv_spec = pl.BlockSpec((1, bk, d),
-                           lambda bh, ik, iq: (_kv_index(bh, h, h_kv, grp),
-                                               ik, 0))
-    dkv_spec = pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh, ik, 0))
-    row_spec = pl.BlockSpec((1, bq, _STATS), lambda bh, ik, iq: (bh, iq, 0))
-    # dK/dV are written PER Q-HEAD (grid programs may not reduce into a
-    # shared output block) and group-summed by XLA below — one extra
-    # (B, H, Sk, D) temp, only when grp > 1.
-    dkv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          window=window, block_q=bq, block_k=bk, n_q=n_q,
-                          q_len=s_q, k_len=s_k,
-                          causal_offset=causal_offset,
-                          diag_offset=diag_offset),
-        grid=(b * h, n_k, n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[dkv_spec, dkv_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk_p, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=_PARALLEL),
-        interpret=interp,
-    )(q3, k3, v3, g3, lse3, delta3)
-    dk3, dv3 = dkv
-
-    q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0))
-    kv_spec2 = pl.BlockSpec((1, bk, d),
-                            lambda bh, iq, ik: (_kv_index(bh, h, h_kv, grp),
-                                                ik, 0))
-    row_spec2 = pl.BlockSpec((1, bq, _STATS), lambda bh, iq, ik: (bh, iq, 0))
-    dq3 = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          window=window, block_q=bq, block_k=bk, n_k=n_k,
-                          q_len=s_q, k_len=s_k,
-                          causal_offset=causal_offset,
-                          diag_offset=diag_offset),
-        grid=(b * h, n_q, n_k),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=q_spec2,
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=_PARALLEL),
-        interpret=interp,
-    )(q3, k3, v3, g3, lse3, delta3)
+    bq, bk = _block_sizes(s_q, s_k, block_q, block_k, d=d, bwd=True,
+                          window=window)
+    ops = _bwd_operands(q, k, v, g, lse, delta, bq, bk)
+    rest = ((b, h, h_kv, s_q, s_k, d, d_v), bq, bk, causal, scale, interp,
+            window, causal_offset, diag_offset)
+    dk3, dv3 = _bwd_dkv(ops, *rest)
+    dq3 = _bwd_dq(ops, *rest)
 
     dq = dq3[:, :s_q].reshape(b, h, s_q, d)
     dk = dk3[:, :s_k].reshape(b, h, s_k, d)
-    dv = dv3[:, :s_k].reshape(b, h, s_k, d)
+    dv = dv3[:, :s_k].reshape(b, h, s_k, d_v)
     if grp > 1:
         # sum the g per-q-head partials of each kv group (f32 to avoid
         # bf16 accumulation error across the group)
         dk = dk.reshape(b, h_kv, grp, s_k, d).astype(jnp.float32) \
                .sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(b, h_kv, grp, s_k, d).astype(jnp.float32) \
+        dv = dv.reshape(b, h_kv, grp, s_k, d_v).astype(jnp.float32) \
                .sum(axis=2).astype(v.dtype)
     return dq, dk, dv
+
+
+_flash_fwd_shared = jax.jit(_flash_fwd_impl,
+                            static_argnums=tuple(range(3, 11)))
+_flash_bwd_shared = jax.jit(_flash_bwd_impl,
+                            static_argnums=tuple(range(7, 15)))
 
 
 # ---------------------------------------------------------------------------
@@ -653,8 +878,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     Drop-in for :func:`nn.attention.dense_attention` (same signature,
     same result up to float tolerance) with O(S) memory and MXU-tiled
-    pallas kernels. q: (B, H, Sq, Dh); k, v: (B, Hkv, Sk, Dh) with Hkv
-    dividing H — Hkv < H is grouped-query attention, served zero-copy by
+    pallas kernels. q: (B, H, Sq, Dh); k: (B, Hkv, Sk, Dh); v: (B, Hkv,
+    Sk, Dv), where Dv may differ from Dh (latent attention: keys 192,
+    values 128 — the result, dO, dV and the accumulator are Dv wide; do
+    NOT pad the values to the keys, a third of the PV and dV FLOPs would
+    multiply zeros) and Hkv divides H — Hkv < H is grouped-query
+    attention, served zero-copy by
     the kv BlockSpec index maps (do NOT repeat kv heads to H yourself;
     that materializes exactly the memory GQA removes). Sequence lengths
     need not divide the block sizes (tiles are padded+masked).
@@ -743,4 +972,7 @@ def make_flash_attn_fn(block_q: Optional[int] = None,
     # (O(window)-memory) cache that reproduces it exactly
     attn_fn.dense_equivalent = window is None
     attn_fn.window = window
+    # the kernel and the dense einsum both take values narrower than the
+    # keys (nn/latent.py ``_core`` asks)
+    attn_fn.narrow_values = True
     return attn_fn

@@ -272,3 +272,227 @@ def test_interpret_only_where_cpu_was_asked_for():
             fa._interpret_default(None)
     finally:
         jax.config.update("jax_platforms", "cpu")
+
+
+# ---------------------------------------------------------------------------
+# heads wider than one lane group, values narrower than the keys (PR 36)
+# ---------------------------------------------------------------------------
+
+
+def _wide_qkv(key, s_q, s_k, h, h_kv, d_v, d=192):
+    kq, kk, kv = jax.random.split(key, 3)
+    return (jax.random.normal(kq, (1, h, s_q, d), jnp.float32),
+            jax.random.normal(kk, (1, h_kv, s_k, d), jnp.float32),
+            jax.random.normal(kv, (1, h_kv, s_k, d_v), jnp.float32))
+
+
+@pytest.mark.parametrize("s_q,s_k,h,h_kv,d_v,bq,bk,causal,window", [
+    (2000, 2000, 2, 2, 128, None, None, True, None),  # default tiles, ragged:
+    #   forward 1024 x 1024, backward 512 x 512
+    (3000, 3000, 2, 1, 128, 256, 1024, True, None),   # grouped, bq < bk
+    (2048, 2048, 1, 1, 192, 512, 256, True, None),    # whole tiles, bq > bk
+    (1000, 2000, 2, 2, 128, 512, 256, True, None),    # s_q != s_k
+    (1024, 3000, 2, 1, 192, 256, 1024, True, None),
+    (2000, 2000, 2, 1, 128, 256, 1024, False, None),  # no frontier to clamp
+    (1024, 1024, 2, 2, 192, None, None, False, None),
+    (2000, 2000, 2, 2, 128, 256, 256, True, 300),     # the band's lower edge
+])
+def test_wide_heads_match_dense(s_q, s_k, h, h_kv, d_v, bq, bk, causal,
+                                window):
+    """Keys 192 wide with values 128 or 192: output, and the gradients of
+    q, k and v, against the dense einsum. The output and dV are as wide as
+    the values."""
+    q, k, v = _wide_qkv(jax.random.PRNGKey(s_q + d_v), s_q, s_k, h, h_kv,
+                        d_v)
+    # a cotangent that is not a function of the output, so each gradient
+    # is one vjp of the kernel and nothing else
+    w = jax.random.normal(jax.random.PRNGKey(9), (1, h, s_q, d_v))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window, block_q=bq, block_k=bk)
+    dense = lambda q, k, v: dense_attention(q, k, v, causal=causal,
+                                            window=window)
+    got = flash(q, k, v)
+    assert got.shape == (1, h, s_q, d_v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(q, k, v)),
+                               atol=1e-4, rtol=1e-4)
+    gq, gk, gv = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    wq, wk, wv = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    assert gv.shape == v.shape
+    for g, want, name in ((gq, wq, "q"), (gk, wk, "k"), (gv, wv, "v")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want),
+                                   atol=2e-4, rtol=2e-3,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_narrow_values_equal_the_padded_form():
+    """Values 128 wide against the same values padded with zeros to the
+    keys' 192 and the result cut back, which is what callers did before
+    the kernel took them narrow: the columns dropped were zeros."""
+    q, k, v = _wide_qkv(jax.random.PRNGKey(3), 600, 600, 2, 1, 128)
+    vp = jnp.pad(v, ((0, 0),) * 3 + ((0, 64),))
+    narrow = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             block_q=256, block_k=256)
+    padded = lambda q, k, vp: narrow(q, k, vp)[..., :128]
+    np.testing.assert_allclose(np.asarray(narrow(q, k, v)),
+                               np.asarray(padded(q, k, vp)),
+                               atol=1e-6, rtol=1e-6)
+    g = jax.grad(lambda *a: jnp.sum(narrow(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    gp = jax.grad(lambda *a: jnp.sum(padded(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, vp)
+    for a, b in zip(g, (gp[0], gp[1], gp[2][..., :128])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def _specs_of_a_call(monkeypatch, q, k, v, **kw):
+    """The (grid, in_specs) of the three ``pallas_call``s that forward +
+    backward of one call build, in the order built: fwd, dK/dV, dQ."""
+    import importlib
+    fa = importlib.import_module(
+        "distributed_pytorch_tpu.ops.flash_attention")
+    seen = []
+    real = fa.pl.pallas_call
+
+    def recording(kernel, **call_kw):
+        seen.append((call_kw["grid"], call_kw["in_specs"]))
+        return real(kernel, **call_kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", recording)
+    jax.clear_caches()
+    from distributed_pytorch_tpu.ops import flash_attention_with_lse
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: flash_attention_with_lse(q, k, v, **kw)[0].sum(),
+        argnums=(0, 1, 2)), q, k, v)
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert len(seen) == 3
+    return fa, seen
+
+
+@pytest.mark.parametrize("s_q,s_k,bq,bk,window,diag_offset", [
+    (2048, 2048, 256, 256, None, 0),
+    (2000, 2000, 256, 512, None, 0),    # ragged, bq < bk
+    (3000, 3000, 1024, 256, None, 0),   # bq > bk
+    (1000, 2000, 256, 256, None, 0),    # the diagonal shifted right
+    (2000, 1000, 256, 256, None, 0),    # q tiles that see nothing
+    (2048, 2048, 256, 256, 300, 0),     # a band: skipped tiles on both sides
+    (2000, 3000, 512, 256, 700, 0),
+    (1024, 1024, 256, 256, 1500, 1024),  # a windowed ring hop's kv block
+])
+def test_index_maps_name_the_nearest_tile_the_frontier_admits(
+        monkeypatch, s_q, s_k, bq, bk, window, diag_offset):
+    """The wide path's causal index maps, read off the ``pallas_call``s
+    themselves, at every grid step: the grid's own index wherever
+    ``_frontier_ok`` runs the body, and on a skipped step the nearest tile
+    it admits for that outer index (past the diagonal the LAST visible
+    one), so the pipeline is handed the block it holds and copies nothing.
+    Operands on the other grid axis keep the grid's index."""
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.float32) for s in
+               ((1, 1, s_q, 192), (1, 1, s_k, 192), (1, 1, s_k, 128)))
+    fa, calls = _specs_of_a_call(monkeypatch, q, k, v, causal=True,
+                                 block_q=bq, block_k=bk, window=window,
+                                 diag_offset=diag_offset)
+    n_q, n_k = -(-s_q // bq), -(-s_k // bk)
+    ok = np.array([[bool(fa._frontier_ok(
+        iq, ik, block_q=bq, block_k=bk, q_len=s_q, k_len=s_k,
+        window=window, diag_offset=diag_offset))
+        for ik in range(n_k)] for iq in range(n_q)])
+    assert not ok.all() and ok.any()
+
+    def nearest(visible, i):
+        """i if visible, else the closest visible index (None: any)."""
+        idx = np.flatnonzero(visible)
+        if idx.size == 0:
+            return None
+        return int(idx[np.argmin(np.abs(idx - i))])
+
+    def named(spec, *step):
+        return tuple(int(x) for x in spec.index_map(*step))
+
+    (g_f, s_f), (g_kv, s_kv), (g_q, s_q_) = calls
+    assert g_f == g_q == (1, n_q, n_k) and g_kv == (1, n_k, n_q)
+    for iq in range(n_q):
+        for ik in range(n_k):
+            want_k = nearest(ok[iq], ik)
+            want_q = nearest(ok[:, ik], iq)
+            # forward (q k v) and dQ (q k v dO lse delta): K and V held
+            for specs in (s_f, s_q_):
+                for spec in specs[1:3]:
+                    got = named(spec, 0, iq, ik)
+                    assert 0 <= got[1] < n_k
+                    assert want_k is None or got == (0, want_k, 0), \
+                        (iq, ik, got, want_k)
+                for spec in (specs[0],) + tuple(specs[3:]):
+                    assert named(spec, 0, iq, ik) == (0, iq, 0)
+            # dK/dV (q k v dO lse delta): the q side held
+            for j, spec in enumerate(s_kv):
+                got = named(spec, 0, ik, iq)
+                if j in (1, 2):
+                    assert got == (0, ik, 0)
+                else:
+                    assert 0 <= got[1] < n_q
+                    assert want_q is None or got == (0, want_q, 0), \
+                        (iq, ik, got, want_q)
+
+
+def test_head_widths_up_to_128_keep_the_plain_index_maps(monkeypatch):
+    """A call at head 64 or 128 with values as wide builds what it built
+    before PR 36: index maps that name the grid's own indices, and no
+    ``vmem_limit_bytes``."""
+    for d in (64, 128):
+        q = jax.ShapeDtypeStruct((1, 1, 1024, d), jnp.float32)
+        _, calls = _specs_of_a_call(monkeypatch, q, q, q, causal=True,
+                                    block_q=256, block_k=256)
+        (_, s_f), (_, s_kv), (_, s_q_) = calls
+        for specs in (s_f, s_q_):
+            assert tuple(specs[1].index_map(0, 0, 3)) == (0, 3, 0)
+        assert tuple(s_kv[0].index_map(0, 3, 0)) == (0, 0, 0)
+
+    def lowered(d, d_v):
+        q = jax.ShapeDtypeStruct((1, 2, 2048, d), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, 2, 2048, d_v), jnp.bfloat16)
+        return _tpu_lowering(jax.jit(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True,
+                interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))), q, q, v)
+
+    # ``vmem_limit_bytes`` reaches the custom call as a scoped memory size
+    assert "scoped_memory_configs" not in lowered(128, 128)
+    assert lowered(192, 128).count("scoped_memory_configs") == 3
+
+
+# (d, s_q, s_k, window, forward tiles, backward tiles) as _block_sizes
+# returned them at the commit before PR 36
+_TILES_BEFORE_PR36 = [
+    (64, 1000, 1000, None, (1024, 1024), (512, 512)),
+    (64, 1000, 1000, 128, (1024, 128), (512, 128)),
+    (64, 1000, 1000, 600, (1024, 640), (512, 512)),
+    (64, 1024, 4096, None, (1024, 1024), (512, 512)),
+    (64, 1024, 4096, 128, (1024, 128), (512, 128)),
+    (64, 8192, 8192, None, (1024, 1024), (512, 512)),
+    (64, 8192, 8192, 600, (1024, 640), (512, 512)),
+    (128, 512, 512, None, (512, 512), (256, 256)),
+    (128, 1000, 1000, None, (512, 512), (256, 256)),
+    (128, 1000, 1000, 128, (512, 128), (256, 128)),
+    (128, 1024, 4096, 600, (512, 512), (256, 256)),
+    (128, 8192, 8192, None, (512, 512), (256, 256)),
+    (128, 8192, 8192, 128, (512, 128), (256, 128)),
+]
+
+
+@pytest.mark.parametrize("d,s_q,s_k,window,fwd,bwd", _TILES_BEFORE_PR36)
+def test_tiles_at_head_widths_up_to_128_are_what_they_were(d, s_q, s_k,
+                                                           window, fwd, bwd):
+    from distributed_pytorch_tpu.ops.flash_attention import _block_sizes
+    assert _block_sizes(s_q, s_k, None, None, d=d, window=window) == fwd
+    assert _block_sizes(s_q, s_k, None, None, d=d, bwd=True,
+                        window=window) == bwd
+    # explicit tiles are the caller's, clamped to the sequence
+    assert _block_sizes(s_q, s_k, 64, 4096, d=d, bwd=True,
+                        window=window) == (64, min(4096, s_k))

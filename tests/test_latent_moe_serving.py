@@ -514,3 +514,42 @@ def test_the_modules_last_position_is_cut_not_counted_and_unseen():
     np.testing.assert_allclose(mtp, m["norm"].apply(q["norm"], y),
                                atol=1e-5)
     assert np.array_equal(load[-1], load_m)
+
+
+@pytest.mark.parametrize("core", ["dense", "flash"])
+def test_narrow_values_through_the_module_equal_the_padded_form(core):
+    """``LatentAttention._core`` hands a core that says ``narrow_values``
+    (the dense einsum, the flash kernel) its values at their own width;
+    any other gets them padded to the keys' width and its result cut
+    back, which is what every core got before PR 36. Output and the
+    gradients of the input and of every parameter agree."""
+    from distributed_pytorch_tpu.nn.attention import dense_attention
+    from distributed_pytorch_tpu.nn.latent import LatentAttention
+    from distributed_pytorch_tpu.ops import make_flash_attn_fn
+
+    narrow = dense_attention if core == "dense" else make_flash_attn_fn(
+        32, 32, min_seq_flash=None)
+    seen = []
+
+    def padded(q, k, v, **kw):       # says nothing: takes equal widths
+        seen.append((k.shape[-1], v.shape[-1]))
+        return narrow(q, k, v, **kw)
+
+    kw = dict(q_rank=16, kv_rank=16, nope_dim=16, rope_dim=8, v_dim=16)
+    a, b = (LatentAttention(48, 3, attn_fn=f, **kw) for f in (narrow, padded))
+    params = a.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 80, 48))
+    w = jax.random.normal(jax.random.PRNGKey(2), (2, 80, 48))
+
+    def loss(attn):
+        return lambda p, x: jnp.sum(attn.apply(p, x) * w)
+
+    np.testing.assert_allclose(np.asarray(a.apply(params, x)),
+                               np.asarray(b.apply(params, x)),
+                               atol=1e-6, rtol=1e-6)
+    assert seen and set(seen) == {(24, 24)}
+    ga = jax.grad(loss(a), argnums=(0, 1))(params, x)
+    gb = jax.grad(loss(b), argnums=(0, 1))(params, x)
+    for u, v in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(v),
+                                   atol=1e-5, rtol=1e-5)

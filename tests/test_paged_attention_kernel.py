@@ -442,22 +442,30 @@ def test_decode_program_moves_no_pool_for_v5e(one_chip, monkeypatch):
     assert not moved, moved[:2]
 
 
-def test_flash_at_head_192_and_8k_compiles_for_v5e(one_chip):
-    """Latent attention's expanded form at the training cell's size (one
-    row, 32 heads, 8192 positions, keys of nope 128 + rope 64, the values
-    padded to them): forward and both backward kernels at the default
-    ("d > 128") tiles, which the chip's compiler has to take whole."""
+@pytest.mark.parametrize("s,d_v", [(8192, 128), (8192, 192), (1024, 128),
+                                   (3000, 128)])
+def test_flash_at_head_192_compiles_for_v5e(one_chip, s, d_v):
+    """Latent attention's expanded form (one row, 32 heads, keys of nope
+    128 + rope 64, values 128 as the module hands them, or as wide as the
+    keys) at the training cell's 8192 positions, a cold prefill chunk's
+    1024 and a length that is no whole tile: forward and both backward
+    kernels at the default tiles of head widths over 128 -- forward 1024
+    x 1024, backward square, half the sequence, between 256 and 1024 --
+    with the clamped index maps and the VMEM limit each states, which the
+    chip's compiler has to take whole."""
     from distributed_pytorch_tpu.ops import flash_attention
     from distributed_pytorch_tpu.ops.flash_attention import _block_sizes
 
-    assert _block_sizes(8192, 8192, None, None, d=192) == (256, 256)
-    assert _block_sizes(8192, 8192, None, None, d=192, bwd=True) == (256,
-                                                                     256)
-    qkv = [jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16,
-                                sharding=one_chip)] * 3
+    tile = {8192: 1024, 3000: 1024, 1024: 512}[s]
+    assert _block_sizes(s, s, None, None, d=192) == (1024, 1024)
+    assert _block_sizes(s, s, None, None, d=192, bwd=True) == (tile, tile)
+    qk = jax.ShapeDtypeStruct((1, 32, s, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, s, d_v), jnp.bfloat16,
+                             sharding=one_chip)
     grad = jax.jit(jax.grad(
         lambda q, k, v: flash_attention(
             q, k, v, causal=True, scale=192 ** -0.5,
             interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
-    assert grad.lower(*qkv).compile().as_text().count(
+    assert grad.lower(qk, qk, v).compile().as_text().count(
         "tpu_custom_call") == 3
