@@ -1,0 +1,49 @@
+"""The two seeded faults of a cell of kind ``serve_blocks``, read on the
+chip at the cell's own size.
+
+    python chipbench/faults_blocks.py --workload <name> --seeds a,b [--seconds s] [--faults token,fill_order]
+
+For each seed the cell is run twice with a fault in the timed path: a
+served token altered where it is produced (``fault_token``), and a fill
+order altered (``fault_fill_order``: the pick takes the LEAST confident
+masked positions). The numbers ``correct`` compares are printed as each
+fault reads them; a limit stands only where the smallest of these lies
+well above the largest that sound runs give (PERF.md section 2). The
+benchmark's own runs never run this."""
+
+import argparse
+import contextlib
+import json
+import time
+
+from run import REPO, Tracer, open_cell, say  # noqa: E402
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--seconds", type=float, default=15.0)
+    ap.add_argument("--faults", default="token,fill_order")
+    args = ap.parse_args()
+    out = []
+    for seed in (int(s) for s in args.seeds.split(",")):
+        for fault in args.faults.split(","):
+            cell, kind, devices = open_cell(REPO, args.workload, seed,
+                                            args.seconds, 0, True,
+                                            reference=False)
+            patch = kind.fault_fill_order() if fault == "fill_order" \
+                else contextlib.nullcontext()
+            with patch:
+                run = kind.run(cell, devices, Tracer(cell),
+                               time.perf_counter(),
+                               broken=kind.fault_token
+                               if fault == "token" else None)
+            reading = {c["name"]: c["value"] for c in run["checks"]}
+            say(f"fault {fault} seed {seed}: {json.dumps(reading)}")
+            out.append({"seed": seed, "fault": fault, **reading})
+    print(json.dumps(out))
+
+
+if __name__ == "__main__":
+    main()

@@ -35,9 +35,10 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..nn.attention import dense_attention
-from ..nn.paged import (DecodeCtx, LatentPagesUnsupported,  # noqa: F401
-                        PrefillCtx, VerifyCtx, latent_unsupported)
+from ..nn.attention import block_causal_mask, dense_attention
+from ..nn.paged import (BlockCtx, BlockGenerationUnsupported,  # noqa: F401
+                        DecodeCtx, LatentPagesUnsupported, PrefillCtx,
+                        VerifyCtx, block_unsupported, latent_unsupported)
 from ..ops.decode_attention import (blockwise_decode_attention,
                                     dense_decode_attention)
 from .transformer import TransformerLM
@@ -407,6 +408,55 @@ def refuse_latent(model, what: str):
         raise latent_unsupported(what)
 
 
+def refuse_blocks(model, what: str):
+    """For a path that steps one token a row: a model that generates by
+    blocks has no such step (``block_step_slots_paged`` is its one)."""
+    if getattr(model, "gen_block", None):
+        raise block_unsupported(what)
+
+
+def block_step_slots_paged(model: TransformerLM, params: Params, state,
+                           tables, lengths, tokens, active, *,
+                           page_len: int, moe_stats=None
+                           ) -> Tuple[jnp.ndarray, list]:
+    """One pass of block generation over a PAGED slot pool: every row's
+    block of ``L = model.gen_block`` positions, ``tokens`` (B, L) int32
+    (``model.mask_id`` where a position is not filled yet), at positions
+    ``lengths[b] .. lengths[b] + L - 1``, over [the row's resident pages
+    | the block].
+
+    The block's keys and values are written into the row's pages in
+    place by every pass (each layer's ``block_paged``); ``lengths`` does
+    not move. A row's length advances, on the host, only after the pass
+    that ran over its clean block (its commit pass): until then the
+    block's positions lie at or past the row's length, where no other
+    pass and no other row reads them, and the next pass overwrites them.
+    ``lengths`` are multiples of ``L`` and ``page_len`` is one, so a
+    block lies inside one page. ``active=False`` rows are routed out of
+    bounds and dropped, and left out of the experts' dispatch.
+
+    Returns ``(float32 logits (B, L, vocab), new state)``: what is filled from
+    them is the caller's (``serve/pages/cache.py`` picks on the device).
+    """
+    block = model.gen_block
+    idx = lengths
+    n_pages = state[0].n_pages
+    positions = idx[:, None] + jnp.arange(block)[None, :]      # (B, L)
+    x = model.streams_in(model.tok.apply(params["tok"], tokens))
+    wp = jnp.take_along_axis(tables, (idx // page_len)[:, None],
+                             axis=1)[:, 0]
+    ctx = BlockCtx(tables=tables, idx=idx, positions=positions,
+                   dest=jnp.where(active, wp, n_pages), wo=idx % page_len,
+                   active=active, page_len=page_len, moe_stats=moe_stats)
+    state = list(state)
+    for i, blk in enumerate(model.blocks):
+        with jax.named_scope("blocks"):
+            x, state[i] = blk.block_paged(params["blocks"][i], x, state[i],
+                                          ctx)
+    x = model.ln_f.apply(params["ln_f"], model.streams_out(x))
+    return model.project_vocab(params, x, jnp.float32), state
+
+
 def prefill_partial_paged(model: TransformerLM, params: Params, state,
                           table_row, tokens, offset, true_len, slot=0, *,
                           page_len: int, moe_stats=None
@@ -427,8 +477,9 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     Tail queries run at global positions ``offset + i`` (rope/learned
     positions included) and attend over [shared prefix pages | tail]:
     prefix keys are gathered from the pool and masked to positions
-    ``< offset``; the tail is causal, so its pad columns are inert
-    exactly as in :func:`prefill_partial`. Tail entries are written into
+    ``< offset``; the tail is causal (block-causal for a model that
+    generates by blocks, whose tails are whole blocks), so its pad
+    columns are inert exactly as in :func:`prefill_partial`. Tail entries are written into
     the slot's own pages (pad positions route out of bounds and drop);
     the shared prefix pages are never written.
 
@@ -451,6 +502,12 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     prefix_mask = jnp.broadcast_to((jnp.arange(width) < offset)[None, :],
                                    (s, width))
     causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+    if getattr(model, "gen_block", None):
+        # a model that generates by blocks: full inside a block of the
+        # tail, causal over blocks (``offset`` is page-aligned and a page
+        # is whole blocks, so the tail's own index gives the block)
+        causal = block_causal_mask(jnp.arange(s), jnp.arange(s),
+                                   model.gen_block)
     mask = jnp.concatenate([prefix_mask, causal], axis=1)   # (S, W+S)
     # tail scatter destinations: position offset+i lives in the slot's
     # page (offset+i)//page_len at offset (offset+i)%page_len; pad
@@ -761,6 +818,8 @@ def make_generate_fn(model: TransformerLM, max_new: int, *,
     exact window semantics in O(window) memory however long generation
     runs."""
     _check_attn_compatible(model, allow_custom_attn)
+    refuse_blocks(model, "generate() (one token a step over a contiguous "
+                         "cache)")
     window = _model_window(model)
 
     def fn(params, prompt, rng):

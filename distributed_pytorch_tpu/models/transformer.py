@@ -123,7 +123,10 @@ class TransformerLM(Module):
                  norm: str = "layer", norm_eps: Optional[float] = None,
                  ffn_dim: Optional[int] = None, moe: Optional[dict] = None,
                  hyper_connections: int = 0, hc: Optional[dict] = None,
-                 mtp: int = 0):
+                 mtp: int = 0, head_dim: Optional[int] = None,
+                 attn_bias: bool = True, qk_norm: Optional[float] = None,
+                 gen_block: Optional[int] = None,
+                 mask_id: Optional[int] = None):
         if pos not in ("learned", "rope", "none"):
             raise ValueError(f"pos must be learned|rope|none, got {pos!r}")
         if attention not in ("mha", "latent"):
@@ -133,6 +136,16 @@ class TransformerLM(Module):
         if attention == "latent" and pos == "learned":
             raise ValueError("latent attention rotates a part of its own "
                              "keys: pos must be 'rope' or 'none'")
+        if gen_block is not None:
+            if gen_block < 1 or mask_id is None:
+                raise ValueError("gen_block needs a block length >= 1 and "
+                                 "the mask_id unfilled positions hold")
+            if attention != "mha" or mtp or pos == "learned":
+                raise ValueError(
+                    "a model that generates by blocks is built of "
+                    "multi-head attention without a prediction module "
+                    "(attention='mha', mtp=0) and pos='rope' or 'none'")
+        self.gen_block, self.mask_id = gen_block, mask_id
         self.vocab = vocab
         self.dim = dim
         self.n_layers = n_layers
@@ -165,8 +178,12 @@ class TransformerLM(Module):
             cls = RMSNorm if norm == "rms" else LayerNorm
             return cls(dim, dtype=dtype, scope=scope, **kw)
 
+        mha = {} if (head_dim is None and attn_bias and qk_norm is None
+                     and gen_block is None) \
+            else dict(head_dim=head_dim, bias=attn_bias, qk_norm=qk_norm,
+                      gen_block=gen_block)
         from_parts = (block_kinds is not None or attention != "mha"
-                      or norm != "layer" or self.streams > 0)
+                      or norm != "layer" or self.streams > 0 or bool(mha))
         if not from_parts:
             self.blocks = [
                 TransformerBlock(dim, n_heads, mlp_ratio, causal=True,
@@ -193,7 +210,7 @@ class TransformerLM(Module):
                 return MultiHeadAttention(
                     dim, n_heads, causal=True, n_kv_heads=n_kv_heads,
                     rope=(pos == "rope"), rope_base=rope_base,
-                    attn_fn=attn_fn, dtype=dtype)
+                    attn_fn=attn_fn, dtype=dtype, **mha)
 
             def make_ffn(kind):
                 if kind == "moe":
@@ -312,12 +329,15 @@ class TransformerLM(Module):
             return x
         return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
 
-    def project_vocab(self, params, x):
+    def project_vocab(self, params, x, out_dtype=None):
         """Hidden states (..., dim) → logits (..., vocab). Single source
         of truth for the output projection (training apply and the cached
-        decode path both route through it)."""
+        decode path both route through it). ``out_dtype``: the logits'
+        type where it is not the operands' (a block step picks by
+        confidence from float32 logits)."""
         with jax.named_scope("head"):
-            return jnp.matmul(x, self.head_weight(params))
+            return jnp.matmul(x, self.head_weight(params),
+                              preferred_element_type=out_dtype)
 
     def apply(self, params: Params, tokens, *, rng=None, train: bool = False,
               pos_offset=0, positions=None, return_hidden: bool = False,

@@ -17,13 +17,14 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from .core import Dropout, LayerNorm, Linear, Module, Params, gelu
+from .core import (Dropout, LayerNorm, Linear, Module, Params, RMSNorm,
+                   gelu)
 from .rotary import apply_rope
 
 
 def dense_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, mask=None):
     """Reference attention: softmax(q k^T / sqrt(d)) v.
 
     q: (B, H, S, Dh); k: (B, Hkv, S, Dh), v: (B, Hkv, S, Dv) where Dv may
@@ -53,6 +54,10 @@ def dense_attention(q, k, v, *, causal: bool = False,
     ``parallel.sequence.ring_attention`` computes the same function with
     K/V sharded around the mesh ring, and ``ops.flash_attention`` is the
     O(S)-memory kernel equivalent.
+
+    ``mask`` (S_q, S_k) bool, True where a key is seen, is any other
+    visibility (the block-causal one of a model that generates by blocks,
+    :func:`block_causal_mask`); the flash kernel takes none.
     """
     b, h, s_q, dh = q.shape
     h_kv, s_k = k.shape[-3], k.shape[-2]
@@ -65,10 +70,13 @@ def dense_attention(q, k, v, *, causal: bool = False,
     logits = jnp.einsum("bngqd,bnkd->bngqk", qg, k).astype(jnp.float32) \
         * scale
     if causal:
+        if mask is not None:
+            raise ValueError("give causal=True or a mask, not both")
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
                               k=s_k - s_q - window)
+    if mask is not None:
         logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bngqk,bnkd->bngqd", probs, v) \
@@ -81,19 +89,40 @@ def dense_attention(q, k, v, *, causal: bool = False,
 dense_attention.narrow_values = True
 
 
+def block_causal_mask(q_pos, k_pos, block: int):
+    """Key ``j`` is seen from query ``i`` iff ``j // block <= i // block``:
+    causal over blocks of ``block`` positions, full inside one. ``q_pos``
+    (..., S_q) and ``k_pos`` (..., S_k) absolute positions -> (..., S_q,
+    S_k) bool."""
+    return (k_pos[..., None, :] // block) <= (q_pos[..., :, None] // block)
+
+
 class MultiHeadAttention(Module):
     """Multi-head self-attention with a pluggable core.
 
     ``attn_fn(q, k, v, causal=...)`` defaults to :func:`dense_attention`;
     the sequence-parallel engine substitutes ring attention without
     touching this module's parameters or callers.
+
+    ``head_dim`` is ``dim // n_heads`` unless given (32 heads of 128 at
+    width 2048: the projections are then ``dim -> n_heads * head_dim``
+    and back); ``bias=False`` leaves the projections' biases out;
+    ``qk_norm`` (an epsilon) puts an RMSNorm over each head's
+    ``head_dim`` values on q and on k, one gain vector for all heads,
+    before the rotation (Qwen3's); ``gen_block`` replaces the causal
+    mask by :func:`block_causal_mask` (a model that generates by blocks,
+    ``models/transformer.py``), which only a core that takes ``mask=``
+    can compute.
     """
 
     def __init__(self, dim: int, n_heads: int, *, causal: bool = False,
                  n_kv_heads: Optional[int] = None, rope: bool = False,
                  rope_base: float = 10000.0,
-                 attn_fn: Optional[Callable] = None, dtype=jnp.float32):
-        if dim % n_heads:
+                 attn_fn: Optional[Callable] = None, dtype=jnp.float32,
+                 head_dim: Optional[int] = None, bias: bool = True,
+                 qk_norm: Optional[float] = None,
+                 gen_block: Optional[int] = None):
+        if head_dim is None and dim % n_heads:
             raise ValueError(f"dim {dim} not divisible by n_heads {n_heads}")
         self.dim = dim
         self.n_heads = n_heads
@@ -101,21 +130,36 @@ class MultiHeadAttention(Module):
         if n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads {n_heads} not divisible by "
                              f"n_kv_heads {self.n_kv_heads}")
-        self.head_dim = dim // n_heads
+        self.head_dim = head_dim if head_dim is not None else dim // n_heads
         self.causal = causal
         self.rope = rope
         self.rope_base = rope_base
         self.attn_fn = attn_fn or dense_attention
+        self.gen_block = gen_block
+        if gen_block and self.attn_fn is not dense_attention:
+            raise ValueError(
+                "a model that generates by blocks attends under the "
+                "block-causal mask, which this attn_fn cannot take: leave "
+                "attn_fn unset (dense_attention)")
         # GQA (n_kv_heads < n_heads) shrinks the k/v projections and the
         # decode KV cache by n_heads/n_kv_heads; with the default the
         # parameter tree is identical to classic MHA.
+        q_dim = n_heads * self.head_dim
         kv_dim = self.n_kv_heads * self.head_dim
-        self.qkv = Linear(dim, dim + 2 * kv_dim, dtype=dtype)
-        self.out = Linear(dim, dim, dtype=dtype)
+        self.qkv = Linear(dim, q_dim + 2 * kv_dim, bias=bias, dtype=dtype)
+        self.out = Linear(q_dim, dim, bias=bias, dtype=dtype)
+        self.q_norm = self.k_norm = None
+        if qk_norm is not None:
+            self.q_norm = RMSNorm(self.head_dim, eps=qk_norm, dtype=dtype)
+            self.k_norm = RMSNorm(self.head_dim, eps=qk_norm, dtype=dtype)
 
     def init(self, key) -> Params:
         k1, k2 = jax.random.split(key)
-        return {"qkv": self.qkv.init(k1), "out": self.out.init(k2)}
+        p = {"qkv": self.qkv.init(k1), "out": self.out.init(k2)}
+        if self.q_norm is not None:
+            p["q_norm"] = self.q_norm.init(k1)
+            p["k_norm"] = self.k_norm.init(k2)
+        return p
 
     def project_qkv(self, params: Params, x):
         """x (B, S, D) → q (B, H, S, Dh), k, v (B, Hkv, S, Dh), via the
@@ -130,7 +174,11 @@ class MultiHeadAttention(Module):
 
             def heads(t, n):
                 return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
-            return heads(q, h), heads(k, hkv), heads(v, hkv)
+            q, k, v = heads(q, h), heads(k, hkv), heads(v, hkv)
+            if self.q_norm is not None:
+                q = self.q_norm.apply(params["q_norm"], q)
+                k = self.k_norm.apply(params["k_norm"], k)
+            return q, k, v
 
     def project_out(self, params: Params, o):
         """o (B, H, S, Dh) → output projection (B, S, D)."""
@@ -158,7 +206,13 @@ class MultiHeadAttention(Module):
         q, k, v = self.project_qkv(params, x)
         q, k = self.maybe_rope(q, k, positions)
         with jax.named_scope("attn/core"):
-            o = self.attn_fn(q, k, v, causal=self.causal)
+            if self.gen_block:
+                at = jnp.arange(q.shape[2]) if positions is None \
+                    else positions
+                o = self.attn_fn(q, k, v, mask=block_causal_mask(
+                    at, at, self.gen_block))
+            else:
+                o = self.attn_fn(q, k, v, causal=self.causal)
         return self.project_out(params, o)
 
     # -- the paged path: the module hands out its page store (nn/paged.py) --
@@ -197,6 +251,23 @@ class MultiHeadAttention(Module):
         return self.project_out(params, prefix_tail_attention(
             hq, hk, hv, pref_k, pref_v, ctx.mask,
             1.0 / math.sqrt(self.head_dim))), pages
+
+    def block_paged(self, params: Params, x, pages, ctx):
+        """One pass over every row's block of ``L`` positions (x (B, L,
+        D) normed, ``nn.paged.BlockCtx``): the block's keys and values
+        are written into the row's pages IN PLACE, every pass (positions
+        at or past a row's length are read by no other row, so a noisy
+        pass's entries are overwritten by the next and the commit
+        pass's stay), and the block attends over [resident | block]. All
+        ``L`` positions see the same keys, so they fold into the query
+        group. Returns (output (B, L, D), the store written)."""
+        hq, hk, hv = self.project_qkv(params, x)
+        hq, hk = self.maybe_rope(hq, hk, ctx.positions[:, None, :])
+        with jax.named_scope("page_write"):
+            pages = pages.write_block(hk, hv, ctx.dest, ctx.wo)
+        o = pages.attend_block(ctx, hq, hk, hv,
+                               1.0 / math.sqrt(self.head_dim))
+        return self.project_out(params, o), pages
 
     def verify_paged(self, params: Params, x, pages, ctx):
         """Every row's k + 1 candidates (x (B, S, D) normed) over [its

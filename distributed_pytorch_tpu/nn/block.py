@@ -6,7 +6,8 @@ multi-head attention, a GELU MLP, ``x + f(x)``. This block takes each of
 the four as an object, so that a model can say per layer what it is made
 of (``models/transformer.py`` ``block_kinds``): RMSNorm or LayerNorm;
 any attention module that has ``apply`` and hands out its page store
-(``make_pages`` / ``decode_paged`` / ``prefill_paged``: ``nn/latent.py``,
+(``make_pages`` / ``decode_paged`` / ``prefill_paged``, and
+``block_paged`` where the model generates by blocks: ``nn/latent.py``,
 ``nn/attention.py``, ``nn/paged.py``); a gated MLP of any width or a
 dropless expert layer (``parallel/moe.py``); the plain residual sum or
 ``streams`` parallel residual streams under hyper-connections
@@ -18,6 +19,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from .core import Module, Params
 from .hyper import HyperConnection
@@ -98,3 +100,11 @@ class Block(Module):
         """x (1, S[, streams], D): the padded tail of one prompt."""
         return self._paged(self.attn.prefill_paged, params, x, pages, ctx,
                            ctx.row_mask[None, :])
+
+    def block_paged(self, params: Params, x, pages, ctx):
+        """x (B, L[, streams], D): one pass over every row's block of a
+        model that generates by blocks (``nn.paged.BlockCtx``). Idle
+        slots are left out of the expert dispatch."""
+        return self._paged(self.attn.block_paged, params, x, pages, ctx,
+                           jnp.broadcast_to(ctx.active[:, None],
+                                            x.shape[:2]))

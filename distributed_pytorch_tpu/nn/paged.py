@@ -101,6 +101,23 @@ class VerifyCtx(NamedTuple):
     mask: Any
 
 
+class BlockCtx(NamedTuple):
+    """One pass of block generation over every slot: each row's block of
+    ``L`` positions at ``positions`` (B, L) = ``idx`` + arange, ``idx``
+    (B,) the row's length (the block's first position, a multiple of
+    ``L``). The block lies inside one page (``page_len`` is a multiple of
+    ``L``): ``dest`` (B,) is that page (``n_pages`` for an inactive row:
+    dropped), ``wo`` (B,) the block's first offset inside it."""
+    tables: Any
+    idx: Any
+    positions: Any
+    dest: Any
+    wo: Any
+    active: Any
+    page_len: int
+    moe_stats: Optional[list] = None
+
+
 class LatentPagesUnsupported(NotImplementedError):
     """A serving path that has no layout for latent-attention blocks."""
 
@@ -113,6 +130,23 @@ def latent_unsupported(what: str) -> LatentPagesUnsupported:
         "keeps one array of [c | k_r] entries a layer, served only by "
         "the exact paged pool (InferenceEngine(paged=True), "
         "kv_dtype='f32')")
+
+
+class BlockGenerationUnsupported(NotImplementedError):
+    """A serving path that cannot serve a model that generates by blocks
+    (``TransformerLM(gen_block=...)``)."""
+
+
+def block_unsupported(what: str) -> BlockGenerationUnsupported:
+    """Generation by blocks runs through ONE path: greedy requests on
+    the exact paged pool. What has not been carried over to a block step
+    says so by name."""
+    return BlockGenerationUnsupported(
+        f"{what} cannot serve a model that generates by blocks "
+        "(TransformerLM(gen_block=...)): a block step fills positions of "
+        "a block under a confidence rule, served only greedy, by "
+        "InferenceEngine(paged=True, kv_dtype='f32') without "
+        "speculation")
 
 
 def dense_rows(g):
@@ -137,6 +171,15 @@ class ExactSide(NamedTuple):
     def write_tail(self, h, ctx):
         return ExactSide(write_rows(self.pages, ctx.dest, ctx.dest_off,
                                     jnp.moveaxis(h[0], 1, 0)))
+
+    def write_block(self, h, dest, wo):
+        """Every row's block (B, Hkv, L, Dh) to offsets ``wo`` ..
+        ``wo + L - 1`` of page ``dest`` (B,): one scatter of B x L rows."""
+        b, hkv, l, dh = h.shape
+        return ExactSide(write_rows(
+            self.pages, jnp.repeat(dest, l),
+            (wo[:, None] + jnp.arange(l)[None, :]).reshape(-1),
+            jnp.moveaxis(h, 2, 1).reshape(b * l, hkv, dh)))
 
     def rows(self, tables, idx=None):
         return dense_rows(self.pages[tables])
@@ -425,11 +468,38 @@ class KVPages(NamedTuple):
             ctx.idx, hk, hv, scale=scale, page_len=ctx.page_len,
             out_dtype=hv.dtype)
 
+    def write_block(self, hk, hv, dest, wo):
+        """A pass's block a row, (B, Hkv, L, Dh) each, in place."""
+        return self._both("write_block", hk, hv, dest, wo)
+
+    def attend_block(self, ctx: BlockCtx, hq, hk, hv, scale):
+        """A block pass's attention, the block written: every one of the
+        ``L`` query positions of a row sees the same keys (the resident
+        positions and the whole block, ``<= idx + L - 1``), so they fold
+        into the query group, ``L x g`` query rows a KV head, through
+        the decode step's kernel or loop (``paged_decode_attention``:
+        the loop re-selects the block's LAST key for the rows whose
+        write was dropped, whose result nobody reads)."""
+        b, h, l, dh = hq.shape
+        hkv = hk.shape[1]
+        g = h // hkv
+        folded = hq.reshape(b, hkv, g * l, dh).reshape(b, hkv * g * l, 1, dh)
+        o = paged_decode_attention(
+            folded, self.k.pages, self.v.pages, ctx.tables, ctx.idx + l - 1,
+            hk[:, :, -1:], hv[:, :, -1:], scale=scale,
+            page_len=ctx.page_len, active=ctx.active)
+        return o.reshape(b, hkv, g, l, dh).reshape(b, h, l, dh)
+
     def resident_bytes(self) -> int:
         return self.k.resident_bytes() + self.v.resident_bytes()
 
     def require(self, op: str) -> None:
-        """Every operation is here."""
+        """Every operation is here, but a block step over quantized
+        pages (its in-place rewrite of a block would round an entry more
+        than once)."""
+        if op == "block_step" and not isinstance(self.k, ExactSide):
+            raise block_unsupported("a quantized page pool "
+                                    "(kv_dtype='q8'/'q4')")
 
     def export(self, idx, slot: int, valid_last: int, quantized=False):
         op = "export_quantized" if quantized else "export"

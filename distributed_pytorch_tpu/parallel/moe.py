@@ -307,10 +307,20 @@ grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 class DroplessMoE(Module):
     """Dropless token-choice experts: x (..., D) -> y (..., D).
 
-    Routes over all ``n_routed`` experts: ``g = sigmoid(x W_r)`` in
-    float32, the ``top_k`` largest of ``g + bias`` (the bias corrects the
-    choice only, DeepSeek-V3's ``noaux_tc``), weights ``g_e / (sum of the
-    chosen g + 1e-20) * scale``. ``held = (first, count)`` says which
+    Routes over all ``n_routed`` experts by one of two scoring rules,
+    both float32 from the activations the layer is given:
+
+    - ``score="sigmoid"`` (the default): ``g = sigmoid(x W_r)``, the
+      ``top_k`` largest of ``g + bias`` (the bias corrects the choice
+      only, DeepSeek-V3's ``noaux_tc``), weights ``g_e / (sum of the
+      chosen g + 1e-20) * scale``;
+    - ``score="softmax"``: ``p = softmax(x W_r)`` over all ``n_routed``,
+      the ``top_k`` largest ``p``, weights ``p_e / (sum of the chosen p)
+      * scale`` (Qwen3-MoE's ``norm_topk_prob``). The router has no bias
+      leaf, so :meth:`balance` and the trainers' router-bias buffers do
+      not apply to it.
+
+    ``held = (first, count)`` says which
     experts this chip holds (all of them by default): it computes their
     part of the result, pairs routed elsewhere cost nothing here, and
     nothing stands in for the absent chips. The shared expert runs on
@@ -326,15 +336,17 @@ class DroplessMoE(Module):
     def __init__(self, dim: int, n_routed: int, width: int, *, top_k: int,
                  n_shared: int = 1, scale: float = 1.0,
                  held: Optional[Tuple[int, int]] = None,
-                 dtype=jnp.float32):
+                 score: str = "sigmoid", dtype=jnp.float32):
         if not 1 <= top_k <= n_routed:
             raise ValueError(f"top_k={top_k} not in [1, {n_routed}]")
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score must be sigmoid|softmax, got {score!r}")
         first, count = held if held is not None else (0, n_routed)
         if first < 0 or count < 1 or first + count > n_routed:
             raise ValueError(f"held={held} is no range of {n_routed} experts")
         self.dim, self.n_routed, self.width = dim, n_routed, width
         self.top_k, self.scale, self.dtype = top_k, scale, dtype
-        self.first, self.count = first, count
+        self.first, self.count, self.score = first, count, score
         self.shared = GatedMLP(dim, n_shared * width, dtype=dtype) \
             if n_shared else None
 
@@ -349,6 +361,8 @@ class DroplessMoE(Module):
                          "down": u(kd, (c, f, d), f)}}
         if self.shared is not None:
             p["shared"] = self.shared.init(ks)
+        if self.score == "softmax":
+            del p["router"]["bias"]
         return p
 
     def route(self, params: Params, xt):
@@ -356,6 +370,14 @@ class DroplessMoE(Module):
         float32 and the scores (T, E). Float32 from the activations the
         layer is given."""
         with jax.named_scope("route"):
+            if self.score == "softmax":
+                p = jax.nn.softmax(jnp.matmul(
+                    xt.astype(jnp.float32),
+                    params["router"]["w"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST), axis=-1)
+                top_p, top_i = jax.lax.top_k(p, self.top_k)
+                w = top_p / jnp.sum(top_p, -1, keepdims=True) * self.scale
+                return top_i.astype(jnp.int32), w, p
             g = jax.nn.sigmoid(jnp.matmul(
                 xt.astype(jnp.float32),
                 params["router"]["w"].astype(jnp.float32),

@@ -11,6 +11,7 @@ loop and threaded front door (``engine``), and per-request SLO metrics
 (``metrics``). Architecture and failure grammar: docs/serving.md.
 """
 
+from ..nn.paged import BlockGenerationUnsupported  # noqa: F401
 from .cache import CompileCounts, SlotPool  # noqa: F401
 from .disagg import DisaggConfig, DisaggEngine  # noqa: F401
 from .engine import EngineConfig, InferenceEngine  # noqa: F401
@@ -27,7 +28,8 @@ from .types import (AdmissionRejected, EngineStopped,  # noqa: F401
                     SamplingParams, ServeError, SpecDecodeError)
 
 __all__ = [
-    "AdmissionRejected", "AdmissionScheduler", "CompileCounts",
+    "AdmissionRejected", "AdmissionScheduler",
+    "BlockGenerationUnsupported", "CompileCounts",
     "DisaggConfig", "DisaggEngine", "EngineConfig", "EngineStopped",
     "FleetAutoscaler", "FleetConfig", "FleetHandle", "FleetRouter",
     "HandoffCorrupt", "HandoffError", "HandoffTimeout",

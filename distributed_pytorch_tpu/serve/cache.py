@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import (_sample, decode_step_slots,
-                               prefill_partial, refuse_latent,
+                               prefill_partial, refuse_blocks, refuse_latent,
                                spec_commit_slots, spec_verify_slots)
 
 
@@ -101,6 +101,7 @@ class SlotPool:
     def __init__(self, model, n_slots: int, max_len: int,
                  window: Optional[int] = None):
         refuse_latent(model, "the contiguous SlotPool (paged=False)")
+        refuse_blocks(model, "the contiguous SlotPool (paged=False)")
         self.model = model
         self.n_slots = n_slots
         self.max_len = max_len

@@ -24,6 +24,15 @@ asserted in tests/test_serve.py). Logits agree with the standalone
 pipeline to ~1 ulp — XLA fuses differently at different batch shapes —
 which is why the contract is over token streams, not logit bits.
 
+**A model that generates by blocks** (``TransformerLM(gen_block=L)``;
+docs/serving.md "Generation by blocks") takes step 4 as a BLOCK STEP
+(``_block_all``): every running row holds a block of ``L`` positions,
+one program an iteration runs one pass of every row's block, whatever
+pass each row is in, and fills positions of it on the device; a block's
+tokens are streamed when its last position is filled; prefill emits
+nothing. Greedy requests on the exact paged pool only: everything else
+refuses such a model by name (``nn.paged.block_unsupported``).
+
 SLO metrics (TTFT/TPOT/queue depth/slot occupancy, defined in
 ``serve.metrics``) flow into the line-JSON ``MetricsLogger`` stream.
 
@@ -46,7 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generate import _check_attn_compatible, _model_window
+from ..models.generate import (_check_attn_compatible, _model_window,
+                               block_unsupported)
 from ..obs import metrics as dpxmon
 from ..obs import trace as dpxtrace
 from ..runtime import compile_cache
@@ -56,7 +66,7 @@ from ..utils.logging import MetricsLogger
 from .cache import SlotPool, upload
 from .metrics import emit_request_trace, request_record
 from .pages import PagedSlotPool, chunk_tokens
-from .sampling import RowSampler
+from .sampling import RowSampler, fill_counts
 from .scheduler import AdmissionScheduler
 from .spec import SpecConfig, SpecState, accept_greedy
 from .types import (FAILED, FINISHED, PREFILLING, QUEUED, RUNNING,
@@ -167,6 +177,13 @@ class InferenceEngine:
                 f"largest prefill bucket ({max(self.buckets)}) exceeds "
                 f"max_len ({cfg.max_len}) — the slot row cannot hold it")
         self._paged = cfg.paged
+        # L where the model generates by blocks of L positions, else None
+        self._block = getattr(model, "gen_block", None)
+        if self._block and any(b % self._block for b in self.buckets):
+            raise ValueError(
+                f"prefill buckets {self.buckets} must be multiples of the "
+                f"model's gen_block ({self._block}): a prompt is prefilled "
+                "in whole blocks")
         if cfg.paged:
             if self.window is not None:
                 raise ValueError(
@@ -202,6 +219,8 @@ class InferenceEngine:
                    else dpxenv.get("DPX_SPEC_DECODE"))
         self._spec: Optional[SpecState] = None
         if spec_on:
+            if self._block:
+                raise block_unsupported("speculative decoding (serve/spec)")
             if cfg.paged:
                 self.pool.require("commit")
             if self.window is not None:
@@ -239,6 +258,19 @@ class InferenceEngine:
         self._prefilling: Optional[Request] = None
         self._free: List[int] = list(range(cfg.n_slots))[::-1]
         self._cur_tokens = np.zeros(cfg.n_slots, np.int32)
+        if self._block:
+            # every slot's block: its tokens (the model's mask_id where
+            # a position is still masked), the pass that filled each
+            # position (-1: it came with the prompt), the passes run
+            shape = (cfg.n_slots, self._block)
+            self._blk_tokens = np.zeros(shape, np.int32)
+            self._blk_masked = np.zeros(shape, bool)
+            self._blk_fill_pass = np.zeros(shape, np.int32)
+            self._blk_passes = np.zeros(cfg.n_slots, np.int32)
+        self._block_passes = 0      # row-passes run
+        self._block_commits = 0     # of them over a clean block
+        self._block_fills = 0       # positions filled
+        self._blocks_emitted = 0
         self._iteration = 0
         # cumulative engine-thread nanoseconds by phase, and what they
         # bought (stats(); the serve.host_share.* gauges)
@@ -347,7 +379,28 @@ class InferenceEngine:
                 f"request {rid}: prompt length {s} exceeds the largest "
                 f"prefill bucket ({max(self.buckets)})",
                 reason="prompt_too_long", request_id=rid)
-        if self.window is None and s + sp.max_new_tokens > self.config.max_len:
+        if sp.denoise_steps is not None and not self._block:
+            raise AdmissionRejected(
+                f"request {rid}: denoise_steps is for a model that "
+                "generates by blocks", reason="invalid", request_id=rid)
+        if self._block:
+            if sp.temperature != 0.0:
+                raise block_unsupported(
+                    f"request {rid}: sampling (temperature "
+                    f"{sp.temperature}; a block is filled greedily by "
+                    "confidence)")
+            if sp.denoise_steps is not None \
+                    and not 1 <= sp.denoise_steps <= self._block:
+                raise AdmissionRejected(
+                    f"request {rid}: denoise_steps {sp.denoise_steps} not "
+                    f"in [1, {self._block}]", reason="invalid",
+                    request_id=rid)
+        # the positions written: a block generator writes the whole of
+        # its last block, whatever of it is streamed
+        need = s + sp.max_new_tokens
+        if self._block:
+            need = -(-need // self._block) * self._block
+        if self.window is None and need > self.config.max_len:
             raise AdmissionRejected(
                 f"request {rid}: prompt ({s}) + max_new_tokens "
                 f"({sp.max_new_tokens}) exceeds the slot cache "
@@ -364,7 +417,8 @@ class InferenceEngine:
             # the LAST sampled token retires without a KV write (decode
             # writes positions s .. s+max_new-2), so the true worst
             # case is ceil((s + max_new - 1) / page_len) pages
-            worst = -(-(s + sp.max_new_tokens - 1) // self.pool.page_len)
+            worst = -(-(need if self._block else need - 1)
+                      // self.pool.page_len)
             if worst > self.pool.n_pages:
                 # the request could NEVER hold its pages even with the
                 # whole pool to itself — reject synchronously rather
@@ -423,6 +477,11 @@ class InferenceEngine:
         out = {"iterations": self._iteration,
                "completed": self._completed, "failed": self._failed,
                "tokens_emitted": self._tokens_emitted,
+               # block generation (0 for a token-a-step model)
+               "block_passes": self._block_passes,
+               "block_commits": self._block_commits,
+               "block_fills": self._block_fills,
+               "blocks_emitted": self._blocks_emitted,
                "admitted": self._admitted,
                "prefill_chunks": self._prefill_chunks,
                "prefill_chunk_iterations": self._prefill_chunk_iterations,
@@ -450,6 +509,11 @@ class InferenceEngine:
                 # read; the mark puts a reading on the profiler's clock,
                 # so that a traced part can be told by two of them
                 out.update(moe)
+                if self._block:
+                    # a block generator's marks carry its own counters
+                    moe = {**moe, **{k: out[k] for k in (
+                        "block_passes", "block_commits", "block_fills",
+                        "blocks_emitted", "tokens_emitted")}}
                 with dpxtrace.span("serve.stats", **moe):
                     pass
         if self._spec is not None:
@@ -504,7 +568,10 @@ class InferenceEngine:
                     host["admit"] += clock() - t_admit
                     it.set(rows=len(self._running))
                     if self._running:
-                        self._decode_all()
+                        if self._block:
+                            self._block_all()
+                        else:
+                            self._decode_all()
                         if chunks:
                             self._prefill_chunk_iterations += 1
             except Exception as e:  # noqa: BLE001
@@ -637,11 +704,21 @@ class InferenceEngine:
             # the crash drain finds the request and fails its future
             # instead of stranding it half-admitted
             slot = req.slot = self._free.pop()
+            head = req.prompt
+            if self._block:
+                # the prompt's whole blocks are prefilled; the remainder
+                # opens the first block (a prompt shorter than a block
+                # has nothing to prefill and runs at once)
+                head = head[:len(head) - len(head) % self._block]
+                if not len(head):
+                    self._admitted_now(req)
+                    self._run_blocks(req)
+                    continue
             req.state = PREFILLING
             self._prefilling = req
             try:
                 req.prefix_hit_pages, req.prefill_tokens_saved = \
-                    self.pool.begin(req.prompt, slot, self.buckets)
+                    self.pool.begin(head, slot, self.buckets)
             except PagePoolExhausted as e:
                 # typed back-pressure into the scheduler: unwind the slot
                 # claim and retry after a retirement frees pages — or
@@ -685,6 +762,9 @@ class InferenceEngine:
             adm.set(chunk=ch.index, offset=ch.offset, bucket=ch.bucket)
             if ch.logits is not None:
                 self._prefilling = None
+                if self._block:
+                    self._run_blocks(req)       # prefill emits nothing
+                    return
                 req.state = RUNNING
                 self._running[req.slot] = req
                 self._first_token(req, ch.logits)
@@ -804,6 +884,123 @@ class InferenceEngine:
         spec_slots = [s for s in spec_slots if s in self._running]
         if spec_slots:
             self._spec_step(spec_slots)
+
+    # -- generation by blocks ------------------------------------------------
+
+    def _run_blocks(self, req: Request) -> None:
+        """``req`` becomes a running row of the block step: its first
+        block opens with what the prompt's whole blocks left over."""
+        req.state = RUNNING
+        self._running[req.slot] = req
+        req.fill_schedule = fill_counts(
+            self._block, req.params.denoise_steps or self._block)
+        self._open_block(req.slot, req.prompt[
+            len(req.prompt) - len(req.prompt) % self._block:])
+
+    def _open_block(self, slot: int, given=()) -> None:
+        """A new block for ``slot``: ``given`` tokens, then the mask id."""
+        n = len(given)
+        self._blk_tokens[slot] = self.model.mask_id
+        self._blk_tokens[slot, :n] = given
+        self._blk_masked[slot] = True
+        self._blk_masked[slot, :n] = False
+        self._blk_fill_pass[slot] = -1
+        self._blk_passes[slot] = 0
+
+    def _block_all(self) -> None:
+        """One pass of block generation for every running row, in ONE
+        program whatever pass each row is in: a row with masked
+        positions has the pass's count of them filled (on the device, by
+        confidence); a row whose block is clean runs its commit pass,
+        after which its length advances and its next block opens. A
+        block's tokens are streamed in position order in the iteration
+        that fills its last masked position, before its commit pass."""
+        L, it = self._block, self._iteration
+        slots = sorted(self._running)
+        with dpxtrace.span("serve.decode.capacity", iteration=it):
+            for slot in list(slots):
+                req = self._running[slot]
+                try:
+                    # the block's L positions lie inside one page
+                    self.pool.ensure_spec_capacity(slot, L)
+                except PagePoolExhausted as e:
+                    self._fail(req, PagePoolExhausted(
+                        f"request {req.request_id}: page pool exhausted "
+                        f"mid-block after {len(req.out_tokens)} tokens "
+                        f"({e.needed} page(s) needed, {e.free_pages} free "
+                        f"— every page held by a live reader)",
+                        needed=e.needed, free_pages=e.free_pages,
+                        request_id=req.request_id, iteration=it),
+                        outcome="no_free_pages")
+                    slots.remove(slot)
+        if not slots:
+            return
+        clock = time.perf_counter_ns
+        rows = len(slots)
+        active = np.zeros(self.config.n_slots, bool)
+        active[slots] = True
+        commit = active & ~self._blk_masked.any(axis=1)
+        n_fill = np.zeros(self.config.n_slots, np.int32)
+        for slot in slots:
+            if not commit[slot]:
+                sched = self._running[slot].fill_schedule
+                n_fill[slot] = sched[min(self._blk_passes[slot],
+                                         len(sched) - 1)]
+        commits = int(commit.sum())
+        t0 = clock()
+        with dpxtrace.span("serve.decode.dispatch", iteration=it, rows=rows):
+            out = self.pool.block_step(
+                self.params, self._blk_tokens, self._blk_masked, n_fill,
+                active, commit)
+        t1 = clock()
+        with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
+            # the iteration's one read: here the host waits for the
+            # block-step program
+            with dpxtrace.span("serve.decode.fetch", iteration=it,
+                               rows=rows, commits=commits) as fetch:
+                out = np.asarray(out)
+                filled = out[:, 1].astype(bool)
+                fills = int(filled[slots].sum())
+                fetch.set(fills=fills)
+            self._decode_fetches += 1
+            emitted = 0
+            with dpxtrace.span("serve.block.advance", iteration=it) as adv:
+                for slot in slots:
+                    req = self._running[slot]
+                    if commit[slot]:
+                        self._open_block(slot)   # its length has advanced
+                        continue
+                    self._blk_tokens[slot] = out[slot, 0]
+                    self._blk_masked[slot] &= ~filled[slot]
+                    self._blk_fill_pass[slot, filled[slot]] = \
+                        self._blk_passes[slot]
+                    self._blk_passes[slot] += 1
+                    if not self._blk_masked[slot].any():
+                        self._emit_block(req)
+                        emitted += 1
+                adv.set(blocks=emitted, commits=commits)
+        self._host_ns["decode_dispatch"] += t1 - t0
+        self._host_ns["row_loop"] += clock() - t1
+        self._rows_decoded += rows
+        self._block_passes += rows
+        self._block_commits += commits
+        self._block_fills += fills
+        self._blocks_emitted += emitted
+
+    def _emit_block(self, req: Request) -> None:
+        """Stream ``req``'s finished block in position order: the
+        positions the prompt gave are not output, and what lies past
+        ``max_new_tokens`` (or an ``eos_token``) is dropped with the
+        request's retirement."""
+        slot = req.slot
+        for tok, at in zip(self._blk_tokens[slot].tolist(),
+                           self._blk_fill_pass[slot].tolist()):
+            if at < 0:
+                continue
+            req.fill_pass.append(at)
+            self._emit(req, tok)
+            if req.done:
+                return
 
     def _spec_fail(self, slots: List[int], cause: Exception,
                    stage: str) -> None:

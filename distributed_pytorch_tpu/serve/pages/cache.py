@@ -51,7 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.generate import (decode_step_slots_paged,
+from ...models.generate import (block_step_slots_paged, block_unsupported,
+                                decode_step_slots_paged,
                                 prefill_partial_paged,
                                 spec_commit_slots_paged,
                                 spec_verify_slots_paged)
@@ -59,6 +60,7 @@ from ...ops.decode_attention import kernel_traces
 from ...runtime import faults
 from ..cache import (CompileCounts, greedy_tokens, named_program,
                      upload)
+from ..sampling import fill_block
 from .pool import PagePool
 from .prefix import PrefixIndex
 from .quant import resolve_kv_bits
@@ -124,6 +126,11 @@ class PagedSlotPool:
     element is quantized exactly ONCE, on page completion, inside the
     same one decode program (``nn/paged.py`` ``QuantSide``)."""
 
+    #: what a model that generates by blocks cannot be served through
+    BLOCKS_LACK = {"commit": "speculative decoding (serve/spec)",
+                   "export": "the disaggregated hand-off (serve/disagg)",
+                   "adopt": "the disaggregated hand-off (serve/disagg)"}
+
     def __init__(self, model, n_slots: int, max_len: int, *,
                  page_len: int, n_pages: int, prefix_share: bool = True,
                  kv_dtype: str = "f32"):
@@ -143,6 +150,17 @@ class PagedSlotPool:
         self.state = [blk.attn.make_pages(n_pages, n_slots, page_len,
                                           self.quant_bits, model.dtype)
                       for blk in model.blocks]
+        # a model that generates by blocks (TransformerLM(gen_block=L))
+        # runs block_step in decode's place; a block must not straddle a
+        # page, and its stores must be able to rewrite a block in place
+        self.gen_block = getattr(model, "gen_block", None)
+        if self.gen_block:
+            self.require("block_step")
+            if page_len % self.gen_block:
+                raise ValueError(
+                    f"page_len ({page_len}) must be a multiple of the "
+                    f"model's gen_block ({self.gen_block}): a block is "
+                    "written into one page")
         # what an expert layer counts in a decode step, summed on the
         # device and read only by stats(): tokens routed, experts with a
         # token, the fullest expert's tokens, decode steps. None for a
@@ -170,6 +188,9 @@ class PagedSlotPool:
         # the stores are donated; the counters are not: stats() reads
         # them from another thread while a step is in flight
         self._decode_fn = jax.jit(self._decode, donate_argnums=(1,))
+        self._block_fn = jax.jit(
+            named_program(self._decode_block, "decode_block_step"),
+            donate_argnums=(1,)) if self.gen_block else None
         # NOT donated: the pool survives a verify
         self._verify_fn = jax.jit(self._verify)
         self._commit_fn = jax.jit(self._commit, donate_argnums=(0,))
@@ -194,13 +215,35 @@ class PagedSlotPool:
             self.model, params, state, tables, lengths, tokens, active,
             page_len=self.page_len, moe_stats=per_layer)
         self.compiles.decode_kernel_layers = kernel_traces() - before
-        if counts is not None:
-            c = jnp.stack(per_layer)                       # (layers, 3)
-            counts = jnp.stack([counts[0] + jnp.sum(c[:, 0]),
-                                counts[1] + jnp.sum(c[:, 1]),
-                                jnp.maximum(counts[2], jnp.max(c[:, 2])),
-                                counts[3] + 1])
+        counts = self._counted(counts, per_layer)
         return greedy_tokens(logits), logits, state, counts
+
+    @staticmethod
+    def _counted(counts, per_layer):
+        """The expert layers' counters after one more step."""
+        if counts is None:
+            return None
+        c = jnp.stack(per_layer)                           # (layers, 3)
+        return jnp.stack([counts[0] + jnp.sum(c[:, 0]),
+                          counts[1] + jnp.sum(c[:, 1]),
+                          jnp.maximum(counts[2], jnp.max(c[:, 2])),
+                          counts[3] + 1])
+
+    def _decode_block(self, params, state, counts, tables, lengths, tokens,
+                      masked, n_fill, active):
+        """The ONE block-step program of a model that generates by
+        blocks, in the decode program's place (and counted as it): one
+        pass of the model over every row's block, then the pick
+        (``sampling.fill_block``), all on the device."""
+        self.compiles.decode += 1          # trace-time only
+        before = kernel_traces()
+        per_layer = None if counts is None else []
+        logits, state = block_step_slots_paged(
+            self.model, params, state, tables, lengths, tokens, active,
+            page_len=self.page_len, moe_stats=per_layer)
+        self.compiles.decode_kernel_layers = kernel_traces() - before
+        counts = self._counted(counts, per_layer)
+        return fill_block(logits, tokens, masked, n_fill), state, counts
 
     def _verify(self, params, state, tables, lengths, tokens):
         # trace-time only; one compile per draft-length bucket (the
@@ -227,6 +270,8 @@ class PagedSlotPool:
         (``"commit"``: a speculating engine; ``"export"`` / ``"adopt"``:
         the two sides of the hand-off): a store that lacks it raises by
         name now, not at the first request."""
+        if self.gen_block and op in self.BLOCKS_LACK:
+            raise block_unsupported(self.BLOCKS_LACK[op])
         for st in self.state:
             st.require(op)
 
@@ -366,11 +411,31 @@ class PagedSlotPool:
         self.lengths[np.asarray(active)] += 1
         return out, logits
 
+    def block_step(self, params, tokens: np.ndarray, masked: np.ndarray,
+                   n_fill: np.ndarray, active: np.ndarray,
+                   commit: np.ndarray):
+        """One pass of block generation for every active slot through
+        the ONE jitted block-step program: ``tokens`` (n_slots, L) int32
+        the rows' blocks (the model's ``mask_id`` where ``masked``
+        (n_slots, L) bool), ``n_fill`` (n_slots,) how many masked
+        positions each row's pass fills. A row in ``commit`` (n_slots,)
+        bool runs over its clean block: what this pass writes is the
+        block's resident keys and values, and the row's length advances
+        by ``L`` here. Returns (n_slots, 2, L) int32 on the device: the
+        blocks after the pass, and the positions it filled."""
+        out, self.state, self.moe_counts = self._block_fn(
+            params, self.state, self.moe_counts, upload(self.tables),
+            upload(self.lengths), upload(tokens), upload(masked),
+            upload(n_fill), upload(active))
+        self.lengths[np.asarray(commit)] += self.gen_block
+        return out
+
     def ensure_spec_capacity(self, slot: int, n_new: int) -> None:
         """Grow ``slot``'s page table so the next ``n_new`` committed
         positions all have pages — the multi-token twin of
         :meth:`ensure_decode_capacity`, called AFTER acceptance is
-        known so only accepted tokens ever demand pages. All-or-nothing
+        known so only accepted tokens ever demand pages (and before a
+        block step, for the block's ``L`` positions). All-or-nothing
         (:meth:`_alloc`): on :class:`PagePoolExhausted` no slot state
         changed, and the engine fails ONLY that request typed."""
         if n_new <= 0:

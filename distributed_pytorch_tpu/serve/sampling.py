@@ -34,6 +34,57 @@ def _program_name(prefix: str, sampler_key: tuple) -> str:
     return prefix + "_".join(str(v) for v in sampler_key)
 
 
+def block_confidence(logits):
+    """``x0 = argmax logits`` (B, L) int32 and its confidence ``max
+    softmax(logits)`` (B, L) in float32, a position."""
+    with jax.named_scope("denoise/confidence"):
+        lg = logits.astype(jnp.float32)
+        top = jnp.max(lg, axis=-1)
+        # max softmax = exp(top - logsumexp) = 1 / sum exp(lg - top)
+        return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
+                1.0 / jnp.sum(jnp.exp(lg - top[..., None]), axis=-1))
+
+
+def fill_surest(x0, conf, tokens, masked, n_fill):
+    """``tokens`` with the ``n_fill`` masked positions of highest
+    ``conf`` (> 0; ties: the lowest position) given their ``x0``, and
+    which positions those were: (B, 2, L) int32."""
+    with jax.named_scope("denoise/fill"):
+        conf = jnp.where(masked, conf, -1.0)       # a filled one is last
+        i = jnp.arange(tokens.shape[1])
+        a, b = conf[:, None, :], conf[:, :, None]  # b: the position ranked
+        ahead = (a > b) | ((a == b) & (i[None, None, :] < i[None, :, None]))
+        fill = masked & (jnp.sum(ahead, axis=-1) < n_fill[:, None])
+        return jnp.stack([jnp.where(fill, x0, tokens),
+                          fill.astype(jnp.int32)], axis=1)
+
+
+@jax.named_scope("sample")
+def fill_block(logits, tokens, masked, n_fill):
+    """The pick of one pass of block generation, on the device: at the
+    positions of a block that are still masked, ``x0 = argmax logits``
+    and its confidence ``max softmax(logits)`` in float32; the
+    ``n_fill`` masked positions of highest confidence (ties: the lowest
+    position) take their ``x0`` and are never masked again (SDAR's
+    ``low_confidence_static``).
+
+    logits (B, L, vocab); tokens (B, L) int32; masked (B, L) bool;
+    n_fill (B,) int32 (0 for a row whose block is clean: its commit
+    pass, or an idle row). Returns (B, 2, L) int32, the block after the
+    pass and which positions this pass filled, one array so that the
+    engine reads an iteration in one fetch."""
+    x0, conf = block_confidence(logits)
+    return fill_surest(x0, conf, tokens, masked, n_fill)
+
+
+def fill_counts(block: int, steps: int):
+    """SDAR's transfer schedule: how many positions pass ``s`` of a
+    block fills, ``block // steps`` and one more in the first ``block %
+    steps`` passes. (steps,) int."""
+    return np.asarray([block // steps + (s < block % steps)
+                       for s in range(steps)], np.int32)
+
+
 class RowSampler:
     """The jitted samplers of one engine, by sampling setting."""
 

@@ -38,6 +38,7 @@ class SamplingParams:
     eos_token: Optional[int] = None
     deadline_ms: Optional[float] = None
     priority: int = 0
+    denoise_steps: Optional[int] = None
 
     @property
     def sampler_key(self):
@@ -196,6 +197,11 @@ class Request:
     state: str = QUEUED
     slot: Optional[int] = None
     out_tokens: List[int] = field(default_factory=list)
+    #: block generation: for each output token, the pass of its block
+    #: (0-based) in which its position was filled
+    fill_pass: List[int] = field(default_factory=list)
+    #: block generation: positions each pass of a block fills, by pass
+    fill_schedule: Any = None
     admit_t: Optional[float] = None
     admit_iteration: Optional[int] = None
     # paged-KV accounting (serve/pages/): how many full prefix pages the
@@ -254,6 +260,9 @@ class RequestHandle:
         # appends are GIL-atomic, so mid-stream reads see a consistent
         # prefix of the stream
         self.tokens: List[int] = request.out_tokens
+        # block generation: the pass of its block that filled each token
+        # (same list as the engine's; empty for a token-a-step model)
+        self.fill_pass: List[int] = request.fill_pass
         self.metrics: dict = {}       # filled at completion
 
     @property

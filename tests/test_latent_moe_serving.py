@@ -211,12 +211,16 @@ def test_no_token_is_dropped_at_any_imbalance(favoured):
     assert int(cm[0]) == int(mask.sum()) * k
 
 
-def test_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+def test_shares_add_up_to_the_uncut_layer(score):
     """held=(0,4) + held=(4,4): the routed parts add up, and the whole
     outputs add up once the shared expert is counted once; so do their
     gradients (the router's and the input's add up over the shares, an
-    expert's is its own share's, the shared expert's is counted once)."""
-    whole = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0)
+    expert's is its own share's, the shared expert's is counted once).
+    Under either scoring rule: a share's weights come from the scores of
+    ALL experts, whichever of them it holds."""
+    whole = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0,
+                        score=score)
     params = whole.init(jax.random.PRNGKey(5))
     x = jax.random.normal(jax.random.PRNGKey(6), (29, 24))
     cot = jax.random.normal(jax.random.PRNGKey(7), (29, 24))
@@ -231,7 +235,7 @@ def test_shares_add_up_to_the_uncut_layer():
     parts, outs, grads = [], [], []
     for first in (0, 4):
         share = DroplessMoE(24, 8, 16, top_k=2, n_shared=1, scale=2.0,
-                            held=(first, 4))
+                            held=(first, 4), score=score)
         p = dict(params, experts=jax.tree_util.tree_map(
             lambda a: a[first:first + 4], params["experts"]))
         parts.append(share.routed(p, x)[0])
@@ -252,8 +256,11 @@ def test_shares_add_up_to_the_uncut_layer():
         for g in (g0, g1, g_whole):     # every chip computes it alike
             np.testing.assert_allclose(g["shared"][name]["w"],
                                        g_shared[name]["w"], atol=2e-5)
-    # the bias corrects the choice only: no gradient reaches it
-    assert not np.asarray(g_whole["router"]["bias"]).any()
+    if score == "softmax":
+        assert "bias" not in params["router"]
+    else:
+        # the bias corrects the choice only: no gradient reaches it
+        assert not np.asarray(g_whole["router"]["bias"]).any()
 
 
 def per_expert_loop(layer, params, x, top_i):
