@@ -12,7 +12,10 @@ have (``nn/block.py``, ``models/transformer.py`` ``block_kinds``), served
 and trained: sigmoid
 scores with a bias-corrected top-k over all routed experts, the chosen
 (token, expert) pairs sorted by expert, one grouped matmul a projection
-(``jax.lax.ragged_dot``) over the experts this chip holds, the results
+(``jax.lax.ragged_dot``; on a TPU, in a program that takes no gradient
+of it, the Mosaic kernel of ``ops/grouped_matmul_kernel.py``, which
+copies each touched expert's weights once) over the experts this chip
+holds, the results
 gathered back and weighted, one shared SwiGLU expert added. No capacity,
 no token dropped, none padded into an expert it did not choose, and no
 one-hot tensor: a decode step reads only the experts its batch touches.
@@ -70,6 +73,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..nn.core import GatedMLP, Linear, Module, Params, gelu
+from ..ops import grouped_matmul_kernel
+from ..ops.decode_attention import _on_one_device
 
 
 class MoELayer(Module):
@@ -269,13 +274,43 @@ def moe_param_specs(ep_axis: str = "ep", tp_axis: Optional[str] = None,
     return specs
 
 
+#: Calls of :func:`grouped_matmul` that took the kernel, counted where
+#: they are traced: a pool reads it around the trace of its decode
+#: program (``stats()["moe_kernel_matmuls"]``).
+_kernel_traces = 0
+
+
+def kernel_traces() -> int:
+    return _kernel_traces
+
+
+def _kernel_interpret(xs, w) -> Optional[bool]:
+    """``False`` (compile the kernel) for a call on one TPU device whose
+    operands the kernel takes (``grouped_matmul_kernel.kernel_fits``);
+    ``None`` (``ragged_dot``) anywhere else. No rule on the rows: on the
+    chip the kernel is as fast as ``ragged_dot`` or faster from 4 rows a
+    group to 4096 (``benchmarks/grouped_matmul_sweep.py``; PERF.md
+    Findings, PR 38). The Pallas interpreter is never a default: a test
+    that wants the kernel on a CPU puts its own answer in this function's
+    place."""
+    if jax.default_backend() != "tpu" or not _on_one_device(xs):
+        return None
+    return False if grouped_matmul_kernel.kernel_fits(xs, w) else None
+
+
 @jax.custom_vjp
 def grouped_matmul(xs, w, sizes):
     """``jax.lax.ragged_dot`` with float32 results: rows of ``xs`` (R, K),
     sorted into groups of ``sizes`` (G,), each group through its own
     ``w[g]`` (G, K, N). Rows past the last group belong to no group and
-    what the result holds there is not a result: on a TPU the kernel never
-    writes them.
+    what the result holds there is not a result: ``ragged_dot``'s TPU
+    kernel never writes them.
+
+    A call that is not differentiated (this body: a serving program's)
+    goes through ``ops/grouped_matmul_kernel.py`` where
+    :func:`_kernel_interpret` says so: the same arithmetic, each touched
+    group's weights streamed once, rows past the last group zeros. A
+    differentiated call (:func:`_grouped_matmul_fwd`) is ``ragged_dot``.
 
     Its derivatives are ``ragged_dot``'s own (``dxs`` another grouped
     matmul, ``dw`` a product whose contracting dimension is the ragged
@@ -284,18 +319,27 @@ def grouped_matmul(xs, w, sizes):
     buffer held before, and a trainer scatters every row of ``dxs`` back
     onto its token (on the chip, with a sixteenth of the experts held,
     that is 19 rows in 20: PERF.md Findings, PR 32)."""
+    mode = _kernel_interpret(xs, w)
+    if mode is None:
+        return _ragged_dot(xs, w, sizes)
+    global _kernel_traces
+    _kernel_traces += 1
+    return grouped_matmul_kernel.grouped_matmul(xs, w, sizes,
+                                                interpret=mode)
+
+
+def _ragged_dot(xs, w, sizes):
     return jax.lax.ragged_dot(xs, w, sizes,
                               preferred_element_type=jnp.float32)
 
 
 def _grouped_matmul_fwd(xs, w, sizes):
-    return grouped_matmul(xs, w, sizes), (xs, w, sizes)
+    return _ragged_dot(xs, w, sizes), (xs, w, sizes)
 
 
 def _grouped_matmul_bwd(res, g):
     xs, w, sizes = res
-    _, pull = jax.vjp(lambda a, b: jax.lax.ragged_dot(
-        a, b, sizes, preferred_element_type=jnp.float32), xs, w)
+    _, pull = jax.vjp(lambda a, b: _ragged_dot(a, b, sizes), xs, w)
     dxs, dw = pull(g)
     grouped = (jnp.arange(xs.shape[0]) < jnp.sum(sizes))[:, None]
     return jnp.where(grouped, dxs, 0), dw, None

@@ -49,6 +49,10 @@ class CompileCounts:
     #: layers of the decode program, as last traced, whose attention
     #: took the Mosaic kernel (ops/paged_attention_kernel.py)
     decode_kernel_layers: int = 0
+    #: grouped-matmul call sites of the decode (or block-step) program,
+    #: as last traced, that took the Mosaic kernel
+    #: (ops/grouped_matmul_kernel.py)
+    moe_kernel_matmuls: int = 0
     prefill: Dict[int, int] = field(default_factory=dict)  # bucket -> n
     sample: int = 0
     verify: Dict[int, int] = field(default_factory=dict)   # k+1 -> n

@@ -57,6 +57,7 @@ from ...models.generate import (block_step_slots_paged, block_unsupported,
                                 spec_commit_slots_paged,
                                 spec_verify_slots_paged)
 from ...ops.decode_attention import kernel_traces
+from ...parallel import moe
 from ...runtime import faults
 from ..cache import (CompileCounts, greedy_tokens, named_program,
                      upload)
@@ -207,14 +208,16 @@ class PagedSlotPool:
         """The ONE decode program. ``counts``: the expert layers'
         counters, or None (an empty argument, not a second program).
         Counted where it is traced: the compile, and how many of its
-        layers' attention took the Mosaic kernel."""
+        layers' attention, and of its expert layers' grouped matmuls,
+        took a Mosaic kernel."""
         self.compiles.decode += 1          # trace-time only
-        before = kernel_traces()
+        before, moe_before = kernel_traces(), moe.kernel_traces()
         per_layer = None if counts is None else []
         logits, state = decode_step_slots_paged(
             self.model, params, state, tables, lengths, tokens, active,
             page_len=self.page_len, moe_stats=per_layer)
         self.compiles.decode_kernel_layers = kernel_traces() - before
+        self.compiles.moe_kernel_matmuls = moe.kernel_traces() - moe_before
         counts = self._counted(counts, per_layer)
         return greedy_tokens(logits), logits, state, counts
 
@@ -236,12 +239,13 @@ class PagedSlotPool:
         pass of the model over every row's block, then the pick
         (``sampling.fill_block``), all on the device."""
         self.compiles.decode += 1          # trace-time only
-        before = kernel_traces()
+        before, moe_before = kernel_traces(), moe.kernel_traces()
         per_layer = None if counts is None else []
         logits, state = block_step_slots_paged(
             self.model, params, state, tables, lengths, tokens, active,
             page_len=self.page_len, moe_stats=per_layer)
         self.compiles.decode_kernel_layers = kernel_traces() - before
+        self.compiles.moe_kernel_matmuls = moe.kernel_traces() - moe_before
         counts = self._counted(counts, per_layer)
         return fill_block(logits, tokens, masked, n_fill), state, counts
 
@@ -602,7 +606,8 @@ class PagedSlotPool:
             int(v) for v in np.asarray(self.moe_counts))
         return {"moe_tokens_routed": routed, "moe_experts_touched": touched,
                 "moe_tokens_max_expert": fullest, "moe_decode_steps": steps,
-                "moe_layers": self.moe_layers}
+                "moe_layers": self.moe_layers,
+                "moe_kernel_matmuls": self.compiles.moe_kernel_matmuls}
 
     def page_stats(self) -> Dict:
         return {"n_pages": self.n_pages,

@@ -340,6 +340,97 @@ def test_rows_no_group_computed_get_a_zero_gradient():
     assert not np.asarray(dw[1]).any()                 # an empty group
 
 
+def _kernel_on_this_cpu(monkeypatch):
+    """Put the rule's answer where a TPU would give it: the kernel,
+    interpreted. Returns the kernel's entry point wrapped to count the
+    calls that reach it."""
+    from distributed_pytorch_tpu.parallel import moe
+    calls = []
+    real = moe.grouped_matmul_kernel.grouped_matmul
+
+    def counted(xs, w, sizes, **kw):
+        calls.append(xs.shape)
+        return real(xs, w, sizes, **kw)
+    monkeypatch.setattr(moe, "_kernel_interpret", lambda xs, w: True)
+    monkeypatch.setattr(moe.grouped_matmul_kernel, "grouped_matmul", counted)
+    return calls
+
+
+def test_the_primal_takes_the_kernel_and_the_derivative_ragged_dot(
+        monkeypatch):
+    """What ``grouped_matmul`` observes is whether it is differentiated:
+    a call that takes no gradient (a serving program's) goes through the
+    kernel, ``jax.vjp`` of the same call never reaches it."""
+    from distributed_pytorch_tpu.parallel import moe
+    calls = _kernel_on_this_cpu(monkeypatch)
+    xs = jax.random.normal(jax.random.PRNGKey(0), (32, 128))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 128, 128))
+    sizes = jnp.asarray([9, 0, 12], jnp.int32)         # rows 21.. in no group
+    want = jax.lax.ragged_dot(xs, w, sizes,
+                              precision=jax.lax.Precision.HIGHEST)
+    before = moe.kernel_traces()
+    out = jax.jit(moe.grouped_matmul)(xs, w, sizes)
+    assert len(calls) == 1 and moe.kernel_traces() == before + 1
+    np.testing.assert_allclose(out[:21], want[:21], atol=1e-4)
+    assert not np.asarray(out)[21:].any()               # zeros, not leftovers
+    out, pull = jax.vjp(lambda a, b: moe.grouped_matmul(a, b, sizes), xs, w)
+    dxs, _ = pull(jnp.ones_like(out))
+    assert len(calls) == 1 and moe.kernel_traces() == before + 1
+    np.testing.assert_allclose(out[:21], want[:21], atol=1e-4)
+    assert not np.asarray(dxs)[21:].any()
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)])
+def test_the_layer_agrees_on_both_paths_under_a_row_mask(monkeypatch, held):
+    """``DroplessMoE.apply`` with idle rows masked out (and, with
+    ``held``, pairs routed to experts that are elsewhere): the result
+    through the kernel is the result through ``ragged_dot``, and so is
+    the load."""
+    layer = DroplessMoE(128, 8, 128, top_k=2, n_shared=1, held=held)
+    params = layer.init(jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(4), (6, 4, 128))
+    row_mask = jnp.asarray(np.random.default_rng(5).random((6, 4)) < 0.6)
+    apply = lambda: jax.jit(layer.apply)(params, x, row_mask=row_mask)
+    y_ragged, load_ragged = apply()
+    calls = _kernel_on_this_cpu(monkeypatch)
+    jax.clear_caches()
+    y_kernel, load_kernel = apply()
+    assert len(calls) == 3                              # gate, up, down
+    np.testing.assert_allclose(y_kernel, y_ragged, atol=2e-5)
+    np.testing.assert_array_equal(load_kernel, load_ragged)
+    masked = ~np.asarray(row_mask)
+    shared = layer.shared.apply(params["shared"], x.reshape(-1, 128)) \
+        .reshape(x.shape)
+    np.testing.assert_allclose(np.asarray(y_kernel)[masked],
+                               np.asarray(shared)[masked], atol=2e-5)
+
+
+def test_engine_counts_the_matmuls_that_took_the_kernel(monkeypatch):
+    """``stats()["moe_kernel_matmuls"]``: the decode program's grouped
+    matmul call sites that lowered through the kernel (three a layer),
+    none on a CPU, and the greedy streams are ``ragged_dot``'s."""
+    model = models.TransformerLM(**{**TINY, "dim": 128, "moe": dict(
+        n_routed=8, width=128, top_k=2, n_shared=1, scale=2.0)})
+    params = model.init(jax.random.PRNGKey(7))
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 211, n).astype(np.int32) for n in (5, 11)]
+
+    def serve():
+        with InferenceEngine(model, params, EngineConfig(
+                paged=True, n_slots=2, max_len=64, buckets=(16,),
+                page_len=PAGE)) as eng:
+            hs = [eng.submit(p, SamplingParams(max_new_tokens=6))
+                  for p in prompts]
+            return [list(h.result(timeout=600)) for h in hs], eng.stats()
+    ragged_tokens, st = serve()
+    assert st["moe_kernel_matmuls"] == 0
+    _kernel_on_this_cpu(monkeypatch)
+    kernel_tokens, st = serve()
+    assert st["moe_kernel_matmuls"] == 3 * st["moe_layers"] == 6
+    assert st["decode_compiles"] == 1
+    assert kernel_tokens == ragged_tokens
+
+
 def test_bias_rule_against_values_worked_by_hand():
     """b_e <- b_e + speed * sign(mean(c) - c_e): mean 4, so the experts
     with 1 and 3 pairs rise, the one with 4 stays, those with 5 and 7

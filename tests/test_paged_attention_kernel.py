@@ -469,3 +469,31 @@ def test_flash_at_head_192_compiles_for_v5e(one_chip, s, d_v):
             interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
     assert grad.lower(qk, qk, v).compile().as_text().count(
         "tpu_custom_call") == 3
+
+
+# (groups, rows, K, N): the expert layers' calls in the serving programs
+GROUPED_SHAPES = [
+    pytest.param(128, 2048, 2048, 768, id="sdar-block-step"),
+    pytest.param(128, 8192, 768, 2048, id="sdar-chunk-down"),
+    pytest.param(64, 256, 3584, 1024, id="xing4-decode-step"),
+    pytest.param(64, 4096, 1024, 3584, id="xing4-chunk-down"),
+    pytest.param(8, 40, 256, 128, id="rows-no-whole-tile"),
+    pytest.param(4, 512, 4096, 2048, id="the-largest-slab-that-fits"),
+]
+
+
+@pytest.mark.parametrize("g,r,k,n", GROUPED_SHAPES)
+def test_grouped_matmul_compiles_for_v5e(one_chip, g, r, k, n):
+    """The grouped expert matmul (``ops/grouped_matmul_kernel.py``) at
+    the serving programs' shapes: one Mosaic call whose weight block is
+    an expert's whole slab (7.3 MB at Xing4's widths, double buffered),
+    inside the VMEM limit the call states."""
+    from distributed_pytorch_tpu.ops.grouped_matmul_kernel import (
+        grouped_matmul)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = jax.jit(grouped_matmul).lower(
+        s((r, k), jnp.bfloat16), s((g, k, n), jnp.bfloat16),
+        s((g,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
