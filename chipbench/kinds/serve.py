@@ -12,7 +12,13 @@ A traced run traces the window's last ``trace_iterations`` passes of the
 engine loop, by the pace of the window so far, and never more than its
 last ``trace_seconds``: a trace's size, and the time it takes to write
 out and to read, follow the work it holds (17 k device operations a
-decode program), so a faster engine must not be given a larger one.
+decode program), so a faster engine must not be given a larger one. It
+also has to hold the window's last ``trace_admissions`` arrivals, which
+the benchmark knows from its own schedule: where the iterations' part is
+too short for them (an engine of 12 ms an iteration whose last arrivals
+lie seconds apart) the traced part starts 0.1 s before the earliest of
+them, still inside ``trace_seconds``; else the readers of an admission
+and of a prefill find nothing to read.
 Writing it out (``jax.profiler.stop_trace``: 30 s for 35 iterations on the
 v5e host, during which the engine's thread runs a fifth slower) starts
 when the window closes, on the main thread, while the load goes on from a
@@ -162,15 +168,36 @@ class Load:
         self.t_done = time.perf_counter()
 
 
-def trace_lead(mix, stats_, s0, into_window_s):
-    """Seconds before the window's end at which the traced part starts:
-    ``trace_iterations`` passes of the engine loop at the mean pace of
-    the window so far, and at most ``trace_seconds``."""
+#: seconds before an arrival at which a traced part that has to hold it
+#: starts: the profiler's own start (tens of milliseconds) falls in them
+ARRIVAL_LEAD_S = 0.1
+
+
+def arrivals_before_end(load):
+    """Seconds before the window's end at which each request of the
+    window is due, the last first: the benchmark made the schedule."""
+    end = load.t_w1 - load.t_load
+    return sorted(end - r["due_s"] for r in load.schedule if r["in_window"])
+
+
+def trace_lead(mix, stats_, s0, into_window_s, before_end=()):
+    """(seconds before the window's end at which the traced part starts,
+    what set them): the longer of ``iterations``, ``trace_iterations``
+    passes of the engine loop at the mean pace of the window so far, and
+    ``arrivals``, ``ARRIVAL_LEAD_S`` before the ``trace_admissions``-th
+    last arrival of the window (``before_end``: ``arrivals_before_end``;
+    nothing where the window holds fewer), and at most
+    ``trace_seconds``."""
     done = stats_["iterations"] - s0["iterations"]
-    if done <= 0:
-        return mix["trace_seconds"]
-    return min(mix["trace_seconds"],
-               mix["trace_iterations"] * into_window_s / done)
+    hold = mix["trace_admissions"]
+    leads = {"iterations": mix["trace_seconds"] if done <= 0
+             else mix["trace_iterations"] * into_window_s / done,
+             "arrivals": before_end[hold - 1] + ARRIVAL_LEAD_S
+             if hold <= len(before_end) else 0.0}
+    by = max(leads, key=leads.get)
+    if leads[by] > mix["trace_seconds"]:
+        return mix["trace_seconds"], "trace_seconds"
+    return leads[by], by
 
 
 def trace_window_end(load, tracer):
@@ -182,12 +209,14 @@ def trace_window_end(load, tracer):
     which slows the engine's thread, falls after it. Returns what the
     traced part held, as counters."""
     eng, mix, t_w1 = load.eng, load.mix, load.t_w1
+    before_end = arrivals_before_end(load)
     while True:
         now = time.perf_counter()
         wake = t_w1 - mix["trace_seconds"]
         if now >= wake and load.s0 is not None:
-            wake = t_w1 - trace_lead(mix, eng.stats(), load.s0,
-                                     now - load.t_w0)
+            lead, by = trace_lead(mix, eng.stats(), load.s0,
+                                  now - load.t_w0, before_end)
+            wake = t_w1 - lead
             if now >= wake:
                 break
         if now >= t_w1:
@@ -199,7 +228,8 @@ def trace_window_end(load, tracer):
     time.sleep(max(0.0, t_w1 - t_on))
     s_off, t_off = eng.stats(), time.perf_counter()
     tracer.stop()
-    return {"trace_lead_s": t_w1 - now, "traced_seconds": t_off - t_on,
+    return {"trace_lead_s": t_w1 - now, "trace_lead_by": by,
+            "traced_seconds": t_off - t_on,
             "traced_iterations": s_off["iterations"] - s_on["iterations"],
             "traced_admissions": s_off["admitted"] - s_on["admitted"]}
 
@@ -238,9 +268,12 @@ def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
             wrote = tracer.stop_span[1] - tracer.stop_span[0]
             print(f"chipbench: traced part started "
                   f"{traced['trace_lead_s']:.2f} s before the window's end "
-                  f"(at most {mix['trace_seconds']} s, "
+                  f"(set by {traced['trace_lead_by']}: at most "
+                  f"{mix['trace_seconds']} s, the longer of "
                   f"{mix['trace_iterations']} iterations at the window's "
-                  f"pace) and held {traced['traced_iterations']} iterations "
+                  f"pace and the last {mix['trace_admissions']} "
+                  f"arrivals) and held {traced['traced_iterations']} "
+                  f"iterations "
                   f"and {traced['traced_admissions']} admissions in "
                   f"{traced['traced_seconds']:.2f} s; writing it out took "
                   f"{wrote:.1f} s beside a drain of "

@@ -159,7 +159,8 @@ def run(cell, devices, tracer, t_start, broken=None, control_mm=None):
             wrote = tracer.stop_span[1] - tracer.stop_span[0]
             print(f"chipbench: traced part started "
                   f"{traced['trace_lead_s']:.2f} s before the window's end "
-                  f"and held {traced['traced_iterations']} iterations and "
+                  f"(set by {traced['trace_lead_by']}) and held "
+                  f"{traced['traced_iterations']} iterations and "
                   f"{traced['traced_admissions']} admissions in "
                   f"{traced['traced_seconds']:.2f} s; writing it out took "
                   f"{wrote:.1f} s beside a drain of "

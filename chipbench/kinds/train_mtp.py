@@ -1,8 +1,9 @@
 """A training job whose model brings its own loss and a state outside the
 optimizer: the closed loop of ``kinds/train.py`` (its ``StepRunner``,
-``_drive`` and ``worst_leaf_gap`` unchanged, so the window, the clock, the
-checks' names and the result's keys are the training cell's own; one
-check is added to them, the routers' choice against the reference's,
+``_drive`` and ``worst_leaf_gap`` unchanged, so the window, the clock and
+the checks' names are the training cell's own, and the result's keys but
+for the rate's, which the job names (``rate_metric``); one check is added
+to them, the routers' choice against the reference's,
 ``router_pairs_elsewhere_share``), with the program built through the model's doors: ``TransformerLM(mtp=1)``,
 ``ops.losses.lm_mtp_loss``, and ``make_train_step(buffers=...)`` for the
 router biases. The plain float32 reference follows the same steps first,
@@ -23,6 +24,7 @@ from chipbench import traffic_gen
 from chipbench import weights as W
 from chipbench.kinds.train import StepRunner, _drive, worst_leaf_gap  # noqa: F401
 from chipbench.reference import train_steps_mtp
+from distributed_pytorch_tpu.optim.schedules import ScheduledState
 
 #: window means of the step's own metrics, put among the run's counters
 STEP_COUNTERS = ("loss_main", "loss_mtp", "moe_pairs_here", "moe_load_max",
@@ -33,7 +35,7 @@ def build(cell, devices, seed):
     """The program: model, loss, optimizer, buffers and compiled step
     through the public doors, with weights the benchmark makes from the
     seed."""
-    from distributed_pytorch_tpu import models, optim
+    from distributed_pytorch_tpu import models
     from distributed_pytorch_tpu.ops import make_flash_attn_fn
     from distributed_pytorch_tpu.ops.losses import lm_mtp_loss
     from distributed_pytorch_tpu.parallel import Buffers, make_train_step
@@ -50,18 +52,49 @@ def build(cell, devices, seed):
     def loss_fn(p, tokens):
         return lm_mtp_loss(model, p, tokens, weight=job["mtp_weight"])
 
-    o = job["optimizer"]
-    opt = optim.adamw(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
-                      weight_decay=o["weight_decay"])
+    opt = optimizer(job["optimizer"])
     buffers = Buffers(
         mask=model.router_bias_mask,
         update=lambda p, aux: model.balance_router_bias(
             p, aux["moe_load"], job["bias_update_speed"]))
     params = adapter.to_program(W.make(seed, cfg, jnp.float32))
-    opt_state = opt.init(buffers.trainable(params))
+    opt_state = moments_shown(opt.init(buffers.trainable(params)))
     step = make_train_step(loss_fn, opt, mixed_precision=job["mixed_precision"],
                            donate=job["donate"], buffers=buffers)
     return adapter, step, params, opt_state, jnp.asarray
+
+
+def optimizer(o):
+    """AdamW as the job's ``optimizer`` states it, through what the
+    library gives a user: at the constant ``lr``, or, where the job names
+    ``warmup_steps``, under ``with_schedule`` on a linear ramp from 0 to
+    ``lr`` (the peak) over that many steps and constant after. The
+    schedule counts the optimizer's own steps, so the check steps and the
+    window run on one ramp."""
+    from distributed_pytorch_tpu import optim
+
+    adamw = lambda lr: optim.adamw(lr, b1=o["b1"], b2=o["b2"], eps=o["eps"],
+                                   weight_decay=o["weight_decay"])
+    if "warmup_steps" not in o:
+        return adamw(o["lr"])
+    return optim.with_schedule(adamw, optim.linear_warmup(
+        optim.constant(o["lr"]), o["warmup_steps"]))
+
+
+class _MomentsShown(ScheduledState):
+    """A scheduled optimizer's state, ``(step, inner)``, showing AdamW's
+    first moment where ``_drive`` reads the first gradient from it:
+    ``opt_state.mu``. A named tuple's subclass is the same pytree under
+    another type, so the step is traced once as long as every call is
+    handed this type: ``_Metered`` wraps what comes back."""
+
+    __slots__ = ()
+    mu = property(lambda self: self.inner.mu)
+
+
+def moments_shown(state):
+    return _MomentsShown(*state) if isinstance(state, ScheduledState) \
+        else state
 
 
 def control(cell, devices):
@@ -133,6 +166,7 @@ class _Metered:
 
     def __call__(self, params, opt_state, batch):
         out = self.step(params, opt_state, batch)
+        out = out._replace(opt_state=moments_shown(out.opt_state))
         self.metrics.append({k: out.metrics[k] for k in STEP_COUNTERS})
         if self.first_load is None:
             self.first_load = out.metrics["moe_load"]
@@ -158,6 +192,11 @@ def run(cell, devices, tracer, t_start, broken=None):
     step = _Metered(step)
     out = _drive(cell, devices, tracer, t_start, broken, adapter, step,
                  params, opt_state, place, feed, ref, t_ref)
+    # the window's rate under the name the job gives it: the same number,
+    # an end-to-end metric of its own where the job's pace follows its
+    # seed's routers and cannot stand under the dense cells' bound
+    out["end_to_end"][job["rate_metric"]] = \
+        out["end_to_end"].pop("train_tokens_per_s")
     fetched = jax.device_get(step.metrics)      # one read, after the window
     load = jax.device_get(step.first_load)
     # one comparison of the kind's own beside ``_drive``'s: the routers'

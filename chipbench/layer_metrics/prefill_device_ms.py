@@ -1,6 +1,11 @@
 """Mean device duration of one execution of a prefill program, read from the
 device plane's program line by the programs' name (``prefill_b<bucket>``,
-one a bucket; all buckets together)."""
+one a bucket; all buckets together). Since PR 31 an execution is one
+CHUNK of a prompt (at most ``chunk_tokens`` tokens, or the largest
+bucket), so this is the mean over the chunks of the traced part, not over
+prompts; a prompt that fits one chunk is one execution. Nothing to read
+in a traced part that holds no admission (``trace_admissions`` in the mix
+keeps two in it)."""
 
 from chipbench import program_trace
 

@@ -9,7 +9,9 @@ Imports nothing of the program. The family's reference
 (``reference/<family>.py``) gives ``embed``, ``block`` (which also returns
 its router's load), ``main_loss_sum`` and ``mtp_loss_sum`` (each head's
 summed cross-entropy, the second with the prediction module's load),
-``balance`` and ``is_router_bias``. ``mm`` is
+``balance`` and ``is_router_bias``. The learning rate is the job's law of
+the step, written here on its own (``rate``): constant, or a linear
+warm-up to ``lr`` where the job names ``warmup_steps``. ``mm`` is
 the matrix product; the control passes a lower-precision one. Returns what
 ``correct`` compares: each step's loss (and each head's), the norm of
 every leaf of the first gradient (the biases have none), and the norm of
@@ -31,6 +33,16 @@ def follow(cfg, seed, batches, job, mm=jnp.matmul, devices=None):
     with jax.default_matmul_precision("highest"):
         return _follow(W.family(cfg), cfg, seed, batches, job, mm,
                        list(devices or jax.devices()[:1])[0])
+
+
+def rate(opt, t):
+    """The learning rate of update ``t`` (counted from 1): ``lr``, or
+    under a warm-up of ``warmup_steps`` the ramp ``lr * t / warmup_steps``
+    until it reaches ``lr`` (the update's own count, so step ``k`` counted
+    from 0 runs at ``lr * min(1, (k + 1) / warmup_steps)``)."""
+    if "warmup_steps" not in opt:
+        return opt["lr"]
+    return opt["lr"] * jnp.minimum(1.0, t / opt["warmup_steps"])
 
 
 def programs(fam, cfg, job, mm, n_main, n_mtp):
@@ -88,7 +100,8 @@ def programs(fam, cfg, job, mm, n_main, n_mtp):
                 out[name] = (fam.balance(p[name], loads[name], speed),
                              m[name], v[name])
             else:
-                out[name] = _adamw(p[name], g[name], m[name], v[name], t, opt)
+                out[name] = _adamw(p[name], g[name], m[name], v[name], t,
+                                   {**opt, "lr": rate(opt, t)})
         return tuple({n: o[i] for n, o in out.items()} for i in range(3))
 
     return {

@@ -193,8 +193,12 @@ class Tracer:
 
 def read_layer_metrics(cell, trace, counters):
     """Each per-layer metric of this cell through its own reader. A reader
-    that finds nothing to read returns None and the metric is left out."""
-    out = {}
+    that finds nothing to read returns None and the metric is left out of
+    the result, and named on one line: a metric that ``BENCHMARK.json``
+    lists for the cell and that no traced run reports has read nothing
+    since some change took its events out of the traced part (two did,
+    from PR 29 to PR 40)."""
+    out, silent = {}, []
     for m in cell.metrics_of("per_layer"):
         path = os.path.join(HERE, "layer_metrics", m["name"] + ".py")
         spec = importlib.util.spec_from_file_location(
@@ -203,9 +207,11 @@ def read_layer_metrics(cell, trace, counters):
         spec.loader.exec_module(mod)
         value = mod.read(trace, counters, cell)
         if value is None:
-            say(f"per-layer {m['name']}: nothing to read")
+            silent.append(m["name"])
             continue
         out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    say(f"per-layer metrics of {cell.name} with nothing to read in this "
+        f"traced run: {', '.join(silent) or 'none'}")
     return out
 
 

@@ -53,6 +53,64 @@ def test_reading_equals_what_the_parent_read(want, got, fixture, key):
     assert got(fixture)[key] == want[fixture][key]
 
 
+@pytest.mark.parametrize("alias", golden.ALIASES)
+def test_a_reader_under_a_second_name_reads_what_the_first_reads(
+        want, tmp_path, alias):
+    """``<name>.moe`` moves the sparse-expert jobs' own rate and is the
+    reader ``<name>`` (``chipbench/reader_alias.py``): on every recorded
+    training trace it reads the golden file's number, and where the first
+    finds nothing to read so does the second."""
+    base = alias.rpartition(".")[0]
+    assert base in golden.READERS and alias not in want["recorded_trace"]
+    with open(os.path.join(golden.REPO, "BENCHMARK.json")) as f:
+        by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
+    same = {k: v for k, v in by_name[base].items()
+            if k not in ("name", "moves", "workloads")}
+    assert by_name[alias] == dict(same, name=alias,
+                                  moves=by_name[base]["moves"] + ".moe",
+                                  workloads=by_name[alias]["workloads"])
+    train = [f for f, kind in golden.FIXTURES.items() if kind == "train"]
+    for fixture in train:
+        tmp = tmp_path / fixture
+        tmp.mkdir()
+        trace, cell = golden.run_of(fixture, tmp)
+        got = golden.read(alias, trace, golden.COUNTERS["train"], cell)
+        assert got == want[fixture][base]
+    assert any(want[f][base] is not None for f in train)
+
+
+@pytest.mark.parametrize("fixture,silent", [
+    # a traced part with no admission in it (the parent's excerpt holds
+    # decode programs alone, and none of the program's spans)
+    ("parent_trace_serve", True),
+    # this program's excerpt: an admission, a prefill, every span
+    ("program_trace_serve", False)])
+def test_a_run_names_the_metrics_that_read_nothing(tmp_path, capsys,
+                                                   fixture, silent):
+    """``run.read_layer_metrics`` leaves a silent metric out of the
+    result and names it on ONE line, so that a metric which
+    ``BENCHMARK.json`` lists for a cell and no traced run reports is seen
+    in the first run that loses it."""
+    from chipbench import run
+
+    with open(os.path.join(golden.REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    trace, cell = golden.run_of(fixture, tmp_path)
+    cell.name = "serve-starcoder2-decode"
+    cell.metrics_of = lambda section: [
+        m for m in manifest[section] if cell.name in m["workloads"]]
+    got = run.read_layer_metrics(cell, trace, golden.COUNTERS["serve"])
+    (line,) = [l for l in capsys.readouterr().out.splitlines()
+               if "nothing to read" in l]
+    named = set(line.split(": ")[-1].split(", ")) - {"none"}
+    listed = {m["name"] for m in cell.metrics_of("per_layer")}
+    assert named | set(got) == listed and not named & set(got)
+    assert ({"engine_admit_ms", "prefill_device_ms"} <= named) is silent
+    assert "decode_step_device_ms" in got
+    if not silent:
+        assert line.endswith(": none") and set(got) == listed
+
+
 def load_with_jax(path):
     """``trace_reduce.load`` as it was before PR 26: the events as
     ``jax.profiler.ProfileData`` gives them."""

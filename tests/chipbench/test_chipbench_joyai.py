@@ -3,7 +3,8 @@ the program (``TransformerLM(mtp=1)``, ``lm_mtp_loss``,
 ``make_train_step(buffers=...)``) against the independent float32
 reference (forward, both losses, every leaf's gradient, three steps of
 the follower), a tiny cell through ``run.py``'s test entry, the fp8
-control and a step that returns its state unchanged each failing a limit,
+control, a step that returns its state unchanged and one given half of
+its batch each failing a limit,
 the real configuration file against the catalog's numbers, the count of
 operations against the issue's table, and the by-hand split of a step."""
 
@@ -103,14 +104,12 @@ def test_forward_losses_and_every_gradient_agree_in_float32(model, batches):
             assert reference.is_router_bias(name) == (not np.asarray(x).any())
 
 
-def test_three_steps_follow_the_reference_in_float32(model, batches):
-    """The program's step with the buffers' contract against the
-    follower: losses, every leaf after three steps, the biases moved by
-    the rule alone and AdamW's state holding no moment for them."""
-    ref = train_steps_mtp.follow(CFG, SEED, batches, JOB)
-    o = JOB["optimizer"]
-    opt = optim.adamw(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
-                      weight_decay=o["weight_decay"])
+def three_steps_in_float32(model, batches, opt, loads=None):
+    """The program's step with the buffers' contract, in float32 at
+    ``highest``, from the seed's weights through ``batches`` under
+    ``opt``: (losses, parameters after, parameters before, AdamW's
+    state). ``loads``: the reference's, which every step's have to
+    equal."""
     buffers = Buffers(mask=model.router_bias_mask,
                       update=lambda p, aux: model.balance_router_bias(
                           p, aux["moe_load"], JOB["bias_update_speed"]))
@@ -125,7 +124,34 @@ def test_three_steps_follow_the_reference_in_float32(model, batches):
             out = step(params, state, jnp.asarray(batch))
             params, state = out.params, out.opt_state
             losses.append(float(out.loss[0]))
-            assert np.array_equal(out.metrics["moe_load"], ref["loads"][i])
+            if loads is not None:
+                assert np.array_equal(out.metrics["moe_load"], loads[i])
+    return losses, params, start, getattr(state, "inner", state)
+
+
+def leaf_changes(now, was, like):
+    """The norm of every leaf's change, shaped as the reference's
+    ``delta_norms`` (``like``) and under its names."""
+    now, was = adapter.from_program(now), adapter.from_program(was)
+    norm = lambda a, b: float(jnp.sqrt(jnp.sum(jnp.square(a - b))))
+    group = lambda a, b, names: {n: norm(a[n], b[n]) for n in names}
+    return {"globals": group(now["globals"], was["globals"],
+                             like["globals"]),
+            "layers": [group(a, b, names) for a, b, names in zip(
+                now["layers"], was["layers"], like["layers"])]}
+
+
+@pytest.mark.parametrize("job", [JOB, tiny_joyai.TRAIN_WARMUP],
+                         ids=["constant", "warm_up"])
+def test_three_steps_follow_the_reference_in_float32(model, batches, job):
+    """The program's step with the buffers' contract against the
+    follower: losses, every leaf after three steps, the biases moved by
+    the rule alone and AdamW's state holding no moment for them. At the
+    job's constant rate, and under a warm-up of a few steps, where the
+    program runs ``with_schedule`` and the follower its own law."""
+    ref = train_steps_mtp.follow(CFG, SEED, batches, job)
+    losses, params, start, state = three_steps_in_float32(
+        model, batches, kind.optimizer(job["optimizer"]), ref["loads"])
     np.testing.assert_allclose(losses, ref["losses"], rtol=5e-6)
     # no moments were made for a bias, and none came into being
     for moments in (state.mu, state.nu):
@@ -133,15 +159,13 @@ def test_three_steps_follow_the_reference_in_float32(model, batches):
         assert not any(reference.is_router_bias(n)
                        for group in [flat["globals"]] + flat["layers"]
                        for n in group)
-    norm = lambda a, b: float(jnp.sqrt(jnp.sum(jnp.square(a - b))))
+    want, got = ref["delta_norms"], leaf_changes(params, start,
+                                                 ref["delta_norms"])
+    for g, w in [(got["globals"], want["globals"])] + list(zip(
+            got["layers"], want["layers"])):
+        for name in w:
+            assert g[name] == pytest.approx(float(w[name]), rel=2e-4), name
     now, was = adapter.from_program(params), adapter.from_program(start)
-    for got, old, want in [(now["globals"], was["globals"],
-                            ref["delta_norms"]["globals"])] + list(zip(
-                                now["layers"], was["layers"],
-                                ref["delta_norms"]["layers"])):
-        for name in want:
-            assert norm(got[name], old[name]) == pytest.approx(
-                float(want[name]), rel=2e-4), name
     # three steps of +-0.001 (or 0 where the load sat on the mean): the
     # rule alone moved the biases, by whole multiples of the speed. AdamW
     # with decay would have left other values
@@ -150,6 +174,72 @@ def test_three_steps_follow_the_reference_in_float32(model, batches):
     assert np.abs(moved - np.rint(moved)).max() < 1e-3
     assert set(np.rint(moved).astype(int)) <= {-3, -2, -1, 0, 1, 2, 3}
     assert np.abs(moved).max() >= 1
+
+
+def test_program_at_the_constant_rate_fails_the_scheduled_reference(
+        model, batches):
+    """What holds a step to the job's schedule: the follower runs the
+    warm-up's law, so a program that ignores it (the optimizer the job
+    built before it named ``warmup_steps``) moves every leaf by 3 x the
+    peak where the ramp's three steps sum to 1.5 x, and reads about 1 in
+    ``param_change_worst_leaf`` (1.19 at this seed: the worst leaf's
+    steps do not all point one way); the scheduled program reads
+    rounding, 1.6e-5."""
+    job = tiny_joyai.TRAIN_WARMUP
+    ref = train_steps_mtp.follow(CFG, SEED, batches, job)
+    gap = {}
+    for name, o in (("scheduled", job["optimizer"]),
+                    ("constant", JOB["optimizer"])):
+        _, params, start, _ = three_steps_in_float32(
+            model, batches, kind.optimizer(o))
+        gap[name] = kind.worst_leaf_gap(
+            leaf_changes(params, start, ref["delta_norms"]),
+            ref["delta_norms"])
+    print(gap)
+    limit = tiny_joyai.LIMITS["param_change_worst_leaf"]
+    assert gap["scheduled"] < 1e-3 < limit < gap["constant"]
+    assert 0.8 < gap["constant"] < 1.5      # about double, on every leaf
+
+
+def test_job_without_warmup_steps_builds_the_optimizer_it_built():
+    """``kind.optimizer``: no ``warmup_steps``, the library's AdamW at the
+    constant rate, state and updates to the last bit; with them, AdamW's
+    own moments under ``with_schedule``, step ``k`` (from 0) moving by
+    ``min(1, (k + 1) / warmup_steps)`` of what the constant job moves."""
+    o = JOB["optimizer"]
+    adamw = optim.adamw(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
+                        weight_decay=o["weight_decay"])
+    rng = np.random.default_rng(0)
+    draw = lambda: {"w": jnp.asarray(0.02 * rng.normal(size=(8, 4)),
+                                     jnp.float32)}
+    params, grads = draw(), [draw() for _ in range(6)]
+
+    def moves(opt):
+        """Every update's move of ``w``, and the state after the last."""
+        p, s, out = params, opt.init(params), []
+        for g in grads:
+            new, s = opt.update(g, s, p)
+            out.append(np.asarray(new["w"] - p["w"]))
+            p = new
+        return out, s
+
+    same = lambda a, b: jax.tree_util.tree_all(jax.tree_util.tree_map(
+        np.array_equal, a, b))
+    want, s_want = moves(adamw)
+    got, s_got = moves(kind.optimizer(o))
+    assert type(s_got) is type(s_want)      # no schedule's wrapper
+    assert same(got, want) and same(s_got, s_want)
+    assert kind.moments_shown(s_got) is s_got
+    ramp, s_ramp = moves(kind.optimizer(dict(o, warmup_steps=4)))
+    assert int(s_ramp.step) == 6 and same(s_ramp.inner, s_want)
+    assert kind.moments_shown(s_ramp).mu is s_ramp.inner.mu
+    for k in range(6):
+        np.testing.assert_allclose(ramp[k], min(1.0, (k + 1) / 4) * want[k],
+                                   rtol=2e-3, atol=2e-8)
+    # the first move is a quarter of the peak an element: AdamW's first
+    # update is the gradient's sign
+    assert float(np.abs(ramp[0]).mean()) == pytest.approx(o["lr"] / 4,
+                                                          rel=0.02)
 
 
 def run_once(root, seed, trace):
@@ -181,11 +271,15 @@ def test_tiny_cell_runs_end_to_end(root, trace):
     if trace:
         # on the CPU there is no device plane: the trace-reading metrics
         # find nothing, the counters' ones report
-        assert result["metrics"]["compiles_in_window.train"]["value"] == 0
+        assert result["metrics"]["compiles_in_window.train.moe"][
+            "value"] == 0
         assert any("nothing to read" in l for l in lines)
         assert not {"train_mfu", "flash_roofline"} & set(result["metrics"])
     else:
-        assert set(result["metrics"]) == {"train_tokens_per_s", "setup_s"}
+        # the rate under the name the job gives it (``rate_metric``)
+        assert set(result["metrics"]) == {"train_tokens_per_s.moe",
+                                          "setup_s"}
+        assert result["metrics"]["train_tokens_per_s.moe"]["value"] > 0
 
 
 def test_step_that_returns_its_state_unchanged_is_not_correct(
@@ -203,6 +297,61 @@ def test_step_that_returns_its_state_unchanged_is_not_correct(
                           root=root, require_chip=False)
     assert result["correct"] is False
     assert not result["checks"]["param_change_worst_leaf"]["ok"]
+
+
+@pytest.mark.parametrize("left_out", ["rows", "positions"])
+def test_half_of_the_batch_left_out_is_not_correct(root, monkeypatch,
+                                                   left_out):
+    """The step given half of what the feed hands it, the mean taken over
+    the rest: one of the two rows, or the first half of every row's
+    positions (what ``chipbench/faults_train.py --faults half_batch``
+    plants at the cell's own size, where a step has one row). The run
+    comes out not correct through the harness's own comparison, by the
+    first gradient; the first loss alone need not see it (the two halves
+    of a seeded batch lose alike)."""
+    def broken(step):
+        def call(params, opt_state, batch):
+            half = batch[:1] if left_out == "rows" \
+                else batch[:, :batch.shape[1] // 2 + 1]
+            return step(params, opt_state, half)
+        return call
+
+    real = kind.run
+    monkeypatch.setattr(kind, "run", lambda *a: real(*a, broken=broken))
+    result = run.run_cell(["--workload", tiny_joyai.CELL, "--seed",
+                           str(SEED + 11), "--seconds", "1.0", "--trace",
+                           "0"], root=root, require_chip=False)
+    print({k: v["value"] for k, v in result["checks"].items()})
+    assert result["correct"] is False
+    assert not result["checks"]["grad_norm_worst_leaf"]["ok"]
+    assert result["checks"]["grad_norm_worst_leaf"]["value"] \
+        > 3 * tiny_joyai.LIMITS["grad_norm_worst_leaf"]
+
+
+def test_tiny_cell_under_a_warm_up_is_correct_and_is_held_to_it(
+        tmp_path, monkeypatch):
+    """The whole run with the job under a schedule: ``build`` takes
+    ``with_schedule``, ``_drive`` finds the first moment one level down,
+    the reference's process follows the ramp, and every check holds in
+    bfloat16. Then the same run with the program built at the constant
+    rate, the schedule ignored: not correct, by
+    ``param_change_worst_leaf``."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    root = tiny_joyai.write_root(str(tmp_path), real,
+                                 train=tiny_joyai.TRAIN_WARMUP)
+    args = ["--workload", tiny_joyai.CELL, "--seed", str(SEED + 7),
+            "--seconds", "1.0", "--trace", "0"]
+    result = run.run_cell(args, root=root, require_chip=False)
+    assert result["correct"], result["checks"]
+    print({k: v["value"] for k, v in result["checks"].items()})
+    built = kind.optimizer
+    monkeypatch.setattr(kind, "optimizer", lambda o: built(
+        {k: v for k, v in o.items() if k != "warmup_steps"}))
+    result = run.run_cell(args, root=root, require_chip=False)
+    assert result["correct"] is False
+    assert not result["checks"]["param_change_worst_leaf"]["ok"]
+    assert result["checks"]["grad_norm_worst_leaf"]["ok"]
 
 
 def test_control_in_fp8_fails_a_limit_of_the_tiny_cell(batches):

@@ -96,6 +96,77 @@ def test_files_exist(manifest):
                                            m["name"] + ".py")), m["name"]
 
 
+def test_mixes_say_what_a_traced_part_holds_and_jobs_their_schedule(
+        manifest):
+    """A serving mix names how many of the window's last arrivals its
+    traced part has to hold (without it the readers of an admission and
+    of a prefill read nothing in a fast engine, as from PR 29 to PR 40);
+    a training job may name a warm-up, with the peak, the steps and where
+    they come from."""
+    bench = os.path.join(REPO, manifest["paths"][0])
+    for w in manifest["workloads"]:
+        with open(os.path.join(bench, "traffic", w["traffic"] + ".json")) as f:
+            mix = json.load(f)
+        if mix["kind"].startswith("serve"):
+            assert isinstance(mix["trace_admissions"], int)
+            assert 1 <= mix["trace_admissions"] <= 8
+            # the schedule holds that many arrivals inside the cap often
+            # enough to be worth asking for: at least one a second
+            assert mix["rate_per_s"] * mix["trace_seconds"] \
+                >= mix["trace_admissions"]
+        else:
+            assert "trace_admissions" not in mix
+            o = mix["optimizer"]
+            assert set(o) <= {"name", "lr", "warmup_steps", "b1", "b2",
+                              "eps", "weight_decay"}
+            if "warmup_steps" in o:
+                assert isinstance(o["warmup_steps"], int)
+                assert o["warmup_steps"] >= 1 and o["lr"] > 0
+                assert "arXiv" in mix["warmup_why"]
+
+
+def test_a_training_cell_reports_its_rate_under_one_metric(manifest):
+    """A bound belongs to a metric: the dense job's rate (its six-seed
+    sets spread by 0.0012 %) keeps ``train_tokens_per_s`` and its 1 %; a
+    job of kind ``train_mtp`` names the metric its rate stands under
+    (``rate_metric``), its cell is listed there and under no other rate,
+    and every reader it shares with the dense cell moves that metric
+    under a second name (``chipbench/reader_alias.py``)."""
+    bench = os.path.join(REPO, manifest["paths"][0])
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    rates = [n for n in e2e if n.startswith("train_tokens_per_s")]
+    assert e2e["train_tokens_per_s"]["bound"] == 0.01
+    for w in manifest["workloads"]:
+        with open(os.path.join(bench, "traffic", w["traffic"] + ".json")) as f:
+            mix = json.load(f)
+        if mix["kind"].startswith("serve"):
+            assert not any(w["name"] in e2e[n]["workloads"] for n in rates)
+            continue
+        rate = mix["rate_metric"] if mix["kind"] == "train_mtp" \
+            else "train_tokens_per_s"
+        assert "rate_metric" in mix or mix["kind"] == "train"
+        assert [n for n in rates if w["name"] in e2e[n]["workloads"]] \
+            == [rate], w["name"]
+        assert e2e[rate]["unit"] == "tokens/s"
+        assert e2e[rate]["better"] == "higher"
+        suffix = rate[len("train_tokens_per_s"):]
+        mine = [m for m in manifest["per_layer"]
+                if w["name"] in m["workloads"]]
+        assert mine and all(m["moves"] == rate for m in mine)
+        assert all(m["name"].endswith(suffix) for m in mine)
+        if suffix:
+            by_name = {m["name"]: m for m in manifest["per_layer"]}
+            for m in mine:
+                first = by_name[m["name"][:-len(suffix)]]
+                assert (first["unit"], first["better"], first["source"],
+                        first["layer"]) == (m["unit"], m["better"],
+                                            m["source"], m["layer"])
+                assert first["moves"] == "train_tokens_per_s"
+                with open(os.path.join(bench, "layer_metrics",
+                                       m["name"] + ".py")) as f:
+                    assert "reader_alias.same_as(__file__)" in f.read()
+
+
 def test_each_layer_metric_moves_one_reported_metric(manifest):
     cells = [w["name"] for w in manifest["workloads"]]
     e2e = {m["name"]: m.get("workloads", cells)

@@ -117,29 +117,57 @@ def test_every_run_prints_where_its_wall_time_went(ran, cell, trace):
         v >= 0 for v in seconds.values())
 
 
-def test_trace_lead_follows_the_pace_up_to_the_cap():
-    mix = {"trace_seconds": 4, "trace_iterations": 32}
-    s0 = {"iterations": 100}
-    at = lambda iters, secs: serve_kind.trace_lead(
-        mix, {"iterations": 100 + iters}, s0, secs)
-    assert at(416, 47.0) == pytest.approx(32 * 47.0 / 416)    # 113 ms: 3.6 s
-    assert at(904, 47.0) == pytest.approx(32 * 47.0 / 904)    # 52 ms: 1.7 s
-    assert at(300, 47.0) == 4                                 # slower: the cap
-    assert at(0, 47.0) == 4                                   # an idle engine
+# the last arrivals of a window, in seconds before its end (the
+# StarCoder2 mix's: chipbench/traffic/code-decode.json at 51 s)
+ARRIVALS = [1.556, 3.770, 3.946, 4.019, 4.788]
 
 
-@pytest.mark.parametrize("iterations,lead_s", [(3, None), (10 ** 6, 1.0)])
+@pytest.mark.parametrize("iters,admissions,before_end,want,by", [
+    # a window that holds no arrival: the iterations' lead alone, as
+    # before PR 40
+    (416, 2, (), 32 * 47.0 / 416, "iterations"),      # 113 ms: 3.6 s
+    (904, 2, (), 32 * 47.0 / 904, "iterations"),      # 52 ms: 1.7 s
+    (300, 2, (), 4, "trace_seconds"),                 # slower: the cap
+    (0, 2, (), 4, "iterations"),                      # an idle engine
+    # 12 ms an iteration: 0.4 s would hold no arrival, so the lead is the
+    # second-last arrival's (one: the last one's), 0.1 s before it
+    (3800, 2, ARRIVALS, 3.770 + 0.1, "arrivals"),
+    (3800, 1, ARRIVALS, 1.556 + 0.1, "arrivals"),
+    # an engine slow enough to hold them anyway: the iterations' lead
+    (904, 1, ARRIVALS, 32 * 47.0 / 904, "iterations"),
+    (416, 2, [0.05, 0.2, 0.9], 32 * 47.0 / 416, "iterations"),
+    # the cap stands above both
+    (3800, 3, ARRIVALS, 4, "trace_seconds"),
+    (300, 2, ARRIVALS, 4, "trace_seconds"),
+    # fewer arrivals in the window than asked for: the iterations' lead
+    (3800, 2, [1.5], 32 * 47.0 / 3800, "iterations"),
+    (3800, 5, ARRIVALS[:4], 32 * 47.0 / 3800, "iterations"),
+])
+def test_trace_lead_follows_the_pace_up_to_the_cap(iters, admissions,
+                                                   before_end, want, by):
+    mix = {"trace_seconds": 4, "trace_iterations": 32,
+           "trace_admissions": admissions}
+    stats_, s0 = {"iterations": 100 + iters}, {"iterations": 100}
+    assert serve_kind.trace_lead(mix, stats_, s0, 47.0, before_end) \
+        == (pytest.approx(want), by)
+
+
+@pytest.mark.parametrize("iterations,lead_s,admissions", [
+    (3, None, 1), (10 ** 6, 1.0, 1), (1, None, 3), (10 ** 6, 1.0, 3)])
 def test_traced_part_is_bounded_by_iterations_then_seconds(
-        tmp_path, iterations, lead_s):
+        tmp_path, iterations, lead_s, admissions):
     """Counts from ``stats()`` and the start the kind chose, no device
     times. Every token costs the engine's thread 50 ms here (a slow
     client), so a pass of the loop takes 0.2 s at four rows and the
-    profiler's own start (inside the lead) is short beside it."""
+    profiler's own start (inside the lead) is short beside it. With
+    ``trace_admissions`` the traced part also reaches back to 0.1 s
+    before that many of the window's last arrivals, where the
+    iterations' part is the shorter."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         real = json.load(f)
     root = tiny.write_root(str(tmp_path), real, serve={
         "trace_iterations": iterations, "trace_seconds": lead_s or 3.0,
-        "rate_per_s": 4.0})
+        "rate_per_s": 4.0, "trace_admissions": admissions})
 
     def slow(on_token):
         def call(tok, i):
@@ -155,10 +183,21 @@ def test_traced_part_is_bounded_by_iterations_then_seconds(
     assert out["failed"] == 0
     pace = 4.0 / c["iterations"]
     assert 0.15 < pace < 0.4
-    if lead_s is None:
+    due = sorted(cell.traffic["lead_in_s"] + 4.0 - r["due_s"]
+                 for r in traffic_gen.serve_requests(
+                     cell.traffic, SEED, 4.0, cell.config["vocab_size"])
+                 if r["in_window"])
+    if due[admissions - 1] + 0.1 > iterations * 0.4:
+        # the arrivals' lead is the longer whatever the pace
+        assert c["trace_lead_by"] == "arrivals"
+        assert c["trace_lead_s"] == pytest.approx(
+            due[admissions - 1] + 0.1, abs=0.08)
+    elif lead_s is None:
+        assert c["trace_lead_by"] == "iterations"
         assert 2 <= c["traced_iterations"] <= 5, c
         assert c["trace_lead_s"] == pytest.approx(3 * pace, rel=0.35)
     else:
+        assert c["trace_lead_by"] == "trace_seconds"
         assert c["trace_lead_s"] == pytest.approx(lead_s, abs=0.25)
         assert c["traced_iterations"] == pytest.approx(lead_s / pace, abs=2)
     # the profiler's start falls inside the lead; a sleep may overshoot

@@ -28,7 +28,8 @@ SERVE = {"kind": "serve", "rate_per_s": 12.0, "lead_in_s": 0.5,
                            "distinct": 4},
          "engine": {"paged": True, "n_slots": 4, "max_len": 64,
                     "buckets": [8, 16], "max_queue": 256},
-         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32}
+         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32,
+         "trace_admissions": 1}
 
 
 # at this size a leaf has few elements, so bfloat16's noise averages out

@@ -30,7 +30,11 @@ TRAIN = {"kind": "train_mtp", "seq": 32, "rows_per_chip": 2,
          "mtp_weight": 0.3, "bias_update_speed": 0.001,
          "mixed_precision": "bf16", "remat": "full", "attention": "flash",
          "donate": False, "check_steps": 3, "reference_row_block": 1,
-         "trace_seconds": 1}
+         "trace_seconds": 1, "rate_metric": "train_tokens_per_s.moe"}
+# the same job under a schedule: a linear warm-up of a few steps to the
+# same peak, so that the three check steps run at 1/4, 2/4 and 3/4 of it
+TRAIN_WARMUP = dict(TRAIN, optimizer=dict(TRAIN["optimizer"],
+                                          warmup_steps=4))
 CELL = "tiny-joyai-cell"
 # at this size a leaf has few elements and an expert few tokens, so
 # bfloat16's noise averages out less than at the cell's own size, and one
@@ -42,16 +46,16 @@ LIMITS = {"loss_rel_gap": 0.004, "grad_norm_worst_leaf": 0.015,
           "router_pairs_elsewhere_share": 0.03}
 
 
-def write_root(root, real_manifest):
+def write_root(root, real_manifest, train=TRAIN):
     """``root``/BENCHMARK.json with one tiny cell that reports what the
-    real cell of the family reports."""
+    real cell of the family reports. ``train`` is its job."""
     bench = os.path.join(root, "chipbench")
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
     with open(os.path.join(bench, "configs", "tiny-joyai.json"), "w") as f:
         json.dump(JOYAI, f)
     with open(os.path.join(bench, "traffic", "tiny-mtp.json"), "w") as f:
-        json.dump(TRAIN, f)
+        json.dump(train, f)
     with open(os.path.join(bench, "limits", CELL + ".json"), "w") as f:
         json.dump({k: {"limit": v} for k, v in LIMITS.items()}, f)
     real_cell = next(w["name"] for w in real_manifest["workloads"]

@@ -29,7 +29,8 @@ SERVE = {"kind": "serve_blocks", "rate_per_s": 10.0, "lead_in_s": 0.5,
                            "distinct": 4},
          "engine": {"paged": True, "n_slots": 4, "max_len": 64,
                     "buckets": [8, 16], "max_queue": 256, "page_len": 4},
-         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32}
+         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32,
+         "trace_admissions": 1}
 CELL = "tiny-sdar-cell"
 # at this size a run checks a few dozen filled positions. The bfloat16
 # program against the float32 reference reads a logit gap of 0 to 0.02 and

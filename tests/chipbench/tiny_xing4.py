@@ -36,7 +36,8 @@ SERVE = {"kind": "serve", "rate_per_s": 12.0, "lead_in_s": 0.5,
                            "distinct": 4},
          "engine": {"paged": True, "n_slots": 4, "max_len": 64,
                     "buckets": [8, 16], "max_queue": 256, "page_len": 4},
-         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32}
+         "check_requests": 4, "trace_seconds": 1, "trace_iterations": 32,
+         "trace_admissions": 1}
 CELL = "tiny-xing4-cell"
 # at this size a run checks a few dozen served tokens: the seeds the tests
 # use read 0 and 0, the fp8 control 0.22 to 0.25 over 8 x 48 positions
