@@ -26,6 +26,7 @@ the bounded-variants contract instead of trusting it.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, Optional
@@ -37,6 +38,7 @@ import numpy as np
 from ..models.generate import (_sample, decode_step_slots,
                                prefill_partial, refuse_blocks, refuse_latent,
                                spec_commit_slots, spec_verify_slots)
+from ..obs import trace as dpxtrace
 
 
 @dataclass
@@ -90,6 +92,31 @@ def upload(mirror) -> jnp.ndarray:
     return jnp.asarray(np.array(mirror))
 
 
+def upload_pass(pool, iteration: Optional[int], mirrors, fresh=()):
+    """Every host-to-device copy of one decode (or block) pass's
+    arguments, and nothing else, under the span ``serve.decode.upload``
+    (docs/observability.md), so that what the copies cost can be told
+    from the jitted call that follows them: ``mirrors`` through
+    :func:`upload`, ``fresh`` (host arrays made for this pass alone,
+    which nobody writes again) through ``jnp.asarray``. The span's
+    ``arrays`` and ``bytes`` are counted here, where the work happens;
+    the region's nanoseconds add up in ``pool.upload_ns`` (the engine's
+    ``host_ns["decode_upload"]``). The span is the engine loop's:
+    ``iteration`` None (the draft model's steps, the disaggregated
+    decode loop, which have no spans of their own) copies without it."""
+    t0 = time.perf_counter_ns()
+    copy = lambda: [upload(m) for m in mirrors] \
+        + [jnp.asarray(a) for a in fresh]
+    if iteration is None:
+        out = copy()
+    else:
+        with dpxtrace.span("serve.decode.upload", iteration=iteration) as up:
+            out = copy()
+            up.set(arrays=len(out), bytes=sum(int(a.nbytes) for a in out))
+    pool.upload_ns += time.perf_counter_ns() - t0
+    return out
+
+
 def greedy_tokens(logits):
     """The tail of every decode program: each slot's greedy token,
     (n_slots,) int32 — ``_sample`` at temperature 0 over the same
@@ -120,6 +147,7 @@ class SlotPool:
                                     for _ in range(model.n_layers)]
         self.lengths = jnp.zeros((n_slots,), jnp.int32)
         self.compiles = CompileCounts()
+        self.upload_ns = 0          # cumulative, see upload_pass
         self._admit_fns: Dict[int, callable] = {}
         # donate the pool buffers: the caller always replaces its
         # references with the returned pools, and without donation the
@@ -216,12 +244,16 @@ class SlotPool:
             jnp.asarray(true_len, jnp.int32), jnp.asarray(slot, jnp.int32))
         return logits
 
-    def decode(self, params, tokens, active):
+    def decode(self, params, tokens: np.ndarray, active: np.ndarray,
+               iteration: Optional[int] = None):
         """Advance every slot one position (dead slots masked: their
         lengths freeze and their outputs are discarded by the caller).
-        tokens/active: (n_slots,) int32 / bool. Returns each slot's
-        greedy token (n_slots,) int32 and the (n_slots, vocab) logits,
-        both left on the device."""
+        tokens/active: (n_slots,) int32 / bool, on the host: the two
+        copies to the device are made here (:func:`upload_pass`; the
+        lengths live on the device). Returns each slot's greedy token
+        (n_slots,) int32 and the (n_slots, vocab) logits, both left on
+        the device."""
+        tokens, active = upload_pass(self, iteration, (tokens,), (active,))
         out, logits, self.ks, self.vs, self.lengths = self._decode_fn(
             params, self.ks, self.vs, self.lengths, tokens, active)
         return out, logits

@@ -63,7 +63,7 @@ from ..runtime import compile_cache
 from ..runtime import env as dpxenv
 from ..runtime import faults
 from ..utils.logging import MetricsLogger
-from .cache import SlotPool, upload
+from .cache import SlotPool
 from .metrics import emit_request_trace, request_record
 from .pages import PagedSlotPool, chunk_tokens
 from .sampling import RowSampler, fill_counts
@@ -273,9 +273,13 @@ class InferenceEngine:
         self._blocks_emitted = 0
         self._iteration = 0
         # cumulative engine-thread nanoseconds by phase, and what they
-        # bought (stats(); the serve.host_share.* gauges)
+        # bought (stats(); the serve.host_share.* gauges). Two of them
+        # are parts of another: decode_fetch, the wait for the program,
+        # of row_loop; decode_upload, the pass's copies to the device
+        # (the pool counts them, _host_times), of decode_dispatch
         self._host_ns = dict.fromkeys(
-            ("idle", "admit", "decode_dispatch", "row_loop", "iter"), 0)
+            ("idle", "admit", "decode_dispatch", "decode_fetch",
+             "row_loop", "iter"), 0)
         self._admitted = 0
         self._prefill_chunks = 0            # chunk programs run
         self._prefill_chunk_iterations = 0  # iterations: a chunk AND a decode
@@ -488,7 +492,7 @@ class InferenceEngine:
                "rows_decoded": self._rows_decoded,
                "decode_fetches": self._decode_fetches,
                "sample_dispatches": self._sampler.dispatches,
-               "host_ns": dict(self._host_ns),
+               "host_ns": self._host_times(),
                "queue_depth": len(self._scheduler),
                "active_slots": len(self._running),
                "n_slots": self.config.n_slots,
@@ -588,6 +592,11 @@ class InferenceEngine:
             host["iter"] += clock() - t_iter
         self._drain_on_stop()
 
+    def _host_times(self) -> Dict[str, int]:
+        """``stats()["host_ns"]``: the loop's own counters and the
+        pool's, which makes the copies (``serve.cache.upload_pass``)."""
+        return dict(self._host_ns, decode_upload=self.pool.upload_ns)
+
     def _emit_snapshot(self) -> None:
         """The ONE periodic-metrics emission path (obs/metrics.py):
         engine gauges land in the dpxmon registry and the registry
@@ -597,41 +606,46 @@ class InferenceEngine:
         and the SLO health rules read the same stream."""
         if not dpxmon.enabled():
             return
-        dpxmon.set_gauge("serve.queue_depth", len(self._scheduler))
-        dpxmon.set_gauge("serve.active_slots", len(self._running))
-        dpxmon.set_gauge("serve.slot_occupancy",
-                         len(self._running) / self.config.n_slots)
-        dpxmon.set_gauge("serve.tokens_emitted", self._tokens_emitted)
-        # where the engine thread's time went so far: each phase's
-        # share of the loop's working time, the wait's of all of it
-        host = self._host_ns
-        for phase in ("admit", "decode_dispatch", "row_loop"):
-            dpxmon.set_gauge(f"serve.host_share.{phase}",
-                             host[phase] / max(host["iter"], 1))
-        dpxmon.set_gauge("serve.host_share.idle",
-                         host["idle"] / max(host["idle"] + host["iter"], 1))
-        if self._paged:
-            ps = self.pool.page_stats()
-            dpxmon.set_gauge("serve.pool_occupancy",
-                             ps["pool_occupancy"])
-            dpxmon.set_gauge("serve.free_pages", ps["free_pages"])
-            dpxmon.set_gauge("serve.prefix_hit_rate",
-                             ps["prefix_hit_rate"] or 0.0)
-            dpxmon.set_gauge("serve.page_evictions", ps["evictions"])
-            # resident-KV capacity gauges (gauges are plain floats, so
-            # the storage width rides as numeric bits: 32 / 8 / 4)
-            dpxmon.set_gauge("serve.kv_bits", ps["kv_bits"])
-            dpxmon.set_gauge("serve.kv_pool_bytes", ps["kv_pool_bytes"])
-            dpxmon.set_gauge("serve.bytes_per_resident_token",
-                             ps["bytes_per_resident_token"])
-        if self._spec is not None and self._spec_proposed:
-            dpxmon.set_gauge("serve.spec_acceptance_rate",
-                             self._spec_accepted / self._spec_proposed)
-            dpxmon.set_gauge("serve.spec_tokens_per_iteration",
-                             self._spec_tokens / max(self._spec_iters, 1))
-        dpxmon.emit_snapshot(path=self.metrics.path,
-                             step=self._iteration,
-                             source="serve_engine")
+        with dpxtrace.span("serve.snapshot", iteration=self._iteration):
+            dpxmon.set_gauge("serve.queue_depth", len(self._scheduler))
+            dpxmon.set_gauge("serve.active_slots", len(self._running))
+            dpxmon.set_gauge("serve.slot_occupancy",
+                             len(self._running) / self.config.n_slots)
+            dpxmon.set_gauge("serve.tokens_emitted", self._tokens_emitted)
+            # where the engine thread's time went so far: each phase's
+            # share of the loop's working time, the wait's of all of it.
+            # decode_fetch near the program's share of an iteration: the
+            # chip sets the pace; near 0: the host does
+            host = self._host_times()
+            for phase in ("admit", "decode_dispatch", "decode_upload",
+                          "row_loop", "decode_fetch"):
+                dpxmon.set_gauge(f"serve.host_share.{phase}",
+                                 host[phase] / max(host["iter"], 1))
+            dpxmon.set_gauge(
+                "serve.host_share.idle",
+                host["idle"] / max(host["idle"] + host["iter"], 1))
+            if self._paged:
+                ps = self.pool.page_stats()
+                dpxmon.set_gauge("serve.pool_occupancy",
+                                 ps["pool_occupancy"])
+                dpxmon.set_gauge("serve.free_pages", ps["free_pages"])
+                dpxmon.set_gauge("serve.prefix_hit_rate",
+                                 ps["prefix_hit_rate"] or 0.0)
+                dpxmon.set_gauge("serve.page_evictions", ps["evictions"])
+                # resident-KV capacity gauges (gauges are plain floats, so
+                # the storage width rides as numeric bits: 32 / 8 / 4)
+                dpxmon.set_gauge("serve.kv_bits", ps["kv_bits"])
+                dpxmon.set_gauge("serve.kv_pool_bytes", ps["kv_pool_bytes"])
+                dpxmon.set_gauge("serve.bytes_per_resident_token",
+                                 ps["bytes_per_resident_token"])
+            if self._spec is not None and self._spec_proposed:
+                dpxmon.set_gauge("serve.spec_acceptance_rate",
+                                 self._spec_accepted / self._spec_proposed)
+                dpxmon.set_gauge("serve.spec_tokens_per_iteration",
+                                 self._spec_tokens / max(self._spec_iters, 1))
+            dpxmon.emit_snapshot(path=self.metrics.path,
+                                 step=self._iteration,
+                                 source="serve_engine")
 
     def _sweep_deadlines(self, now: float) -> None:
         for req in self._scheduler.expired(now):
@@ -844,8 +858,7 @@ class InferenceEngine:
             with dpxtrace.span("serve.decode.dispatch", iteration=it,
                                rows=rows):
                 tokens, logits = self.pool.decode(
-                    self.params, upload(self._cur_tokens),
-                    jnp.asarray(active))
+                    self.params, self._cur_tokens, active, iteration=it)
             t1 = clock()
             with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
                 # a greedy row's token is the decode program's own; the
@@ -869,9 +882,11 @@ class InferenceEngine:
                         tokens = self._sampler.merge(tokens, logits, groups)
                 # the iteration's one read: here the host waits for the
                 # decode program
+                t2 = clock()
                 with dpxtrace.span("serve.decode.fetch", iteration=it,
                                    rows=rows):
                     tokens = np.asarray(tokens)
+                self._host_ns["decode_fetch"] += clock() - t2
                 self._decode_fetches += 1
                 for req, ids in live:
                     with dpxtrace.span("serve.row.fetch", **ids):
@@ -951,17 +966,19 @@ class InferenceEngine:
         with dpxtrace.span("serve.decode.dispatch", iteration=it, rows=rows):
             out = self.pool.block_step(
                 self.params, self._blk_tokens, self._blk_masked, n_fill,
-                active, commit)
+                active, commit, iteration=it)
         t1 = clock()
         with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
             # the iteration's one read: here the host waits for the
             # block-step program
+            t2 = clock()
             with dpxtrace.span("serve.decode.fetch", iteration=it,
                                rows=rows, commits=commits) as fetch:
                 out = np.asarray(out)
                 filled = out[:, 1].astype(bool)
                 fills = int(filled[slots].sum())
                 fetch.set(fills=fills)
+            self._host_ns["decode_fetch"] += clock() - t2
             self._decode_fetches += 1
             emitted = 0
             with dpxtrace.span("serve.block.advance", iteration=it) as adv:

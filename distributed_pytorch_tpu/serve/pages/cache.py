@@ -60,7 +60,7 @@ from ...ops.decode_attention import kernel_traces
 from ...parallel import moe
 from ...runtime import faults
 from ..cache import (CompileCounts, greedy_tokens, named_program,
-                     upload)
+                     upload, upload_pass)
 from ..sampling import fill_block
 from .pool import PagePool
 from .prefix import PrefixIndex
@@ -179,6 +179,7 @@ class PagedSlotPool:
         # lengths
         self.tables = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self.lengths = np.zeros((n_slots,), np.int32)
+        self.upload_ns = 0          # cumulative, serve.cache.upload_pass
         self.owned: List[List[int]] = [[] for _ in range(n_slots)]
         # slot -> its prompt's prefill in progress (begin .. last chunk)
         self.prefilling: Dict[int, _Prefill] = {}
@@ -403,21 +404,27 @@ class PagedSlotPool:
         row.append(pid)
         self.tables[slot, need_idx] = pid
 
-    def decode(self, params, tokens: np.ndarray, active: np.ndarray):
+    def decode(self, params, tokens: np.ndarray, active: np.ndarray,
+               iteration: Optional[int] = None):
         """Advance every slot one position through the ONE jitted paged
         decode program (inactive rows neither write the pool nor
-        advance). Returns each slot's greedy token (n_slots,) int32 and
-        the (n_slots, vocab) logits, both left on the device."""
+        advance). ``tokens`` and ``active`` are host arrays: the pass's
+        four copies to the device (tables, lengths, tokens, mask) are
+        made here, under ``serve.decode.upload``
+        (``serve.cache.upload_pass``). Returns each slot's greedy token
+        (n_slots,) int32 and the (n_slots, vocab) logits, both left on
+        the device."""
+        tables, lengths, tokens, mask = upload_pass(
+            self, iteration, (self.tables, self.lengths, tokens), (active,))
         out, logits, self.state, self.moe_counts = self._decode_fn(
-            params, self.state, self.moe_counts, upload(self.tables),
-            upload(self.lengths), jnp.asarray(tokens),
-            jnp.asarray(active))
+            params, self.state, self.moe_counts, tables, lengths, tokens,
+            mask)
         self.lengths[np.asarray(active)] += 1
         return out, logits
 
     def block_step(self, params, tokens: np.ndarray, masked: np.ndarray,
                    n_fill: np.ndarray, active: np.ndarray,
-                   commit: np.ndarray):
+                   commit: np.ndarray, iteration: Optional[int] = None):
         """One pass of block generation for every active slot through
         the ONE jitted block-step program: ``tokens`` (n_slots, L) int32
         the rows' blocks (the model's ``mask_id`` where ``masked``
@@ -425,12 +432,14 @@ class PagedSlotPool:
         positions each row's pass fills. A row in ``commit`` (n_slots,)
         bool runs over its clean block: what this pass writes is the
         block's resident keys and values, and the row's length advances
-        by ``L`` here. Returns (n_slots, 2, L) int32 on the device: the
-        blocks after the pass, and the positions it filled."""
+        by ``L`` here. The pass's six copies to the device are made
+        under ``serve.decode.upload``. Returns (n_slots, 2, L) int32 on
+        the device: the blocks after the pass, and the positions it
+        filled."""
+        args = upload_pass(self, iteration, (
+            self.tables, self.lengths, tokens, masked, n_fill, active))
         out, self.state, self.moe_counts = self._block_fn(
-            params, self.state, self.moe_counts, upload(self.tables),
-            upload(self.lengths), upload(tokens), upload(masked),
-            upload(n_fill), upload(active))
+            params, self.state, self.moe_counts, *args)
         self.lengths[np.asarray(commit)] += self.gen_block
         return out
 

@@ -111,9 +111,7 @@ class SpecState:
         toks[np.asarray(slots)] = cur_tokens
         drafts = np.zeros((len(slots), k), np.int32)
         for j in range(k + 1):
-            nxt, _ = self.pool.decode(self.cfg.draft_params,
-                                      jnp.asarray(toks),
-                                      jnp.asarray(active))
+            nxt, _ = self.pool.decode(self.cfg.draft_params, toks, active)
             if j < k:
                 drafts[:, j] = np.asarray(nxt)[np.asarray(slots)]
                 toks[np.asarray(slots)] = drafts[:, j]

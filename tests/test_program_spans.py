@@ -3,6 +3,7 @@ profiler annotations on the profiler's clock, the spans and counters of
 the engine loop and the train step, the process-wide compile counter, and
 the ``jax.named_scope``s inside the programs."""
 
+import json
 import os
 import re
 import threading
@@ -113,8 +114,9 @@ def test_annotate_is_a_span(tmp_path):
 
 
 def tiny_lm(**kw):
-    return models.TransformerLM(vocab=61, dim=32, n_layers=2, n_heads=4,
-                                n_kv_heads=2, pos="rope", max_seq=64, **kw)
+    return models.TransformerLM(**{**dict(
+        vocab=61, dim=32, n_layers=2, n_heads=4, n_kv_heads=2, pos="rope",
+        max_seq=64), **kw})
 
 
 @pytest.fixture(scope="module")
@@ -155,7 +157,8 @@ def test_engine_spans_are_the_loops_tree(engine_run):
     assert {"serve.iter", "serve.sweep", "serve.admit",
             "serve.admit.prefill", "serve.admit.first_token",
             "serve.decode.capacity", "serve.decode.dispatch",
-            "serve.decode.rows", "serve.decode.sample", "serve.decode.fetch",
+            "serve.decode.upload", "serve.decode.rows",
+            "serve.decode.sample", "serve.decode.fetch",
             "serve.row.sample", "serve.row.fetch", "serve.row.emit"} <= names
     # the caller's thread holds serve.submit, not the engine's
     assert "serve.submit" not in names and len(named(spans, "serve.submit")) == 3
@@ -177,6 +180,7 @@ def test_engine_spans_are_the_loops_tree(engine_run):
             ("serve.admit.prefill", "serve.admit"),
             ("serve.admit.first_token", "serve.admit"),
             ("serve.decode.dispatch", "serve.iter"),
+            ("serve.decode.upload", "serve.decode.dispatch"),
             ("serve.decode.rows", "serve.iter"),
             ("serve.decode.sample", "serve.decode.rows"),
             ("serve.decode.fetch", "serve.decode.rows"),
@@ -244,12 +248,116 @@ def test_engine_host_ns_counters_nest(engine_run):
     _, _, after = engine_run
     host = after["host_ns"]
     assert set(host) == {"idle", "admit", "decode_dispatch", "row_loop",
-                         "iter"}
+                         "iter", "decode_upload", "decode_fetch"}
     assert all(v > 0 for v in host.values())
     assert host["iter"] >= host["row_loop"] + host["decode_dispatch"] \
         + host["admit"]
+    # the copies are a part of the dispatch, the wait for the program of
+    # the row loop
+    assert host["decode_upload"] <= host["decode_dispatch"]
+    assert host["decode_fetch"] <= host["row_loop"]
     assert after["xla_compiles"]["compiles"] \
         + after["xla_compiles"]["cache_hits"] > 0
+
+
+BLOCK_LM = dict(vocab=97, n_heads=8, head_dim=8, attn_bias=False,
+                qk_norm=1e-6, rope_base=1e6,
+                norm="rms", norm_eps=1e-6, block_kinds=("moe", "moe"),
+                moe=dict(n_routed=8, width=16, top_k=2, n_shared=0,
+                         score="softmax"), gen_block=4, mask_id=96)
+
+
+@pytest.mark.parametrize("pool,lm,cfg,program,arrays", [
+    ("paged", {}, dict(paged=True, page_len=8, buckets=(8, 16)),
+     "_decode_fn", 4),
+    ("contiguous", {}, {}, "_decode_fn", 2),
+    ("blocks", BLOCK_LM, dict(paged=True, page_len=8, buckets=(8, 16)),
+     "_block_fn", 6)])
+def test_a_pass_uploads_under_one_span_before_its_program(pool, lm, cfg,
+                                                          program, arrays):
+    """``serve.decode.upload``: once a pass, inside that pass's
+    ``serve.decode.dispatch``, over every copy of the pass's arguments,
+    and closed before the pool calls its jitted program. Read from the
+    flight ring under ``DPX_TRACE=1``: no profiler session."""
+    dpxtrace.configure(enabled=True, ring=4096, log_path=None)
+    model = tiny_lm(**lm)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, EngineConfig(
+        n_slots=3, max_len=64, **cfg))
+    count = lambda name: sum(r["name"] == name
+                             for r in dpxtrace.flight_snapshot()[0])
+    jitted, seen = getattr(eng.pool, program), []
+
+    def spied(*args):
+        seen.append((count("serve.decode.upload"),
+                     count("serve.decode.dispatch")))
+        return jitted(*args)
+    setattr(eng.pool, program, spied)
+    with eng:
+        for n in (5, 6):
+            eng.submit(np.arange(n, dtype=np.int32),
+                       SamplingParams(max_new_tokens=6)).result(timeout=300)
+        stats = eng.stats()
+    ring, dropped = dpxtrace.flight_snapshot()
+    assert dropped == 0
+    ups = [r for r in ring if r["name"] == "serve.decode.upload"]
+    dispatch = {r["span_id"]: r for r in ring
+                if r["name"] == "serve.decode.dispatch"}
+    # the n-th call of the program: n uploads closed, its dispatch open
+    assert len(seen) == len(ups) == len(dispatch) >= 5
+    assert seen == [(n + 1, n) for n in range(len(seen))]
+    for up in ups:
+        parent = dispatch[up["parent_id"]]
+        assert up["attrs"]["iteration"] == parent["attrs"]["iteration"]
+        assert up["attrs"]["arrays"] == arrays
+    n = eng.config.n_slots
+    tokens = n * 4 * (model.gen_block or 1)
+    held = eng.pool.tables.nbytes + n * 4 if cfg else 0
+    extra = (tokens // 4 + n * 4) if model.gen_block else 0  # mask, n_fill
+    assert {u["attrs"]["bytes"] for u in ups} == {held + tokens + n + extra}
+    assert stats["host_ns"]["decode_upload"] == eng.pool.upload_ns \
+        >= sum(u["dur_ns"] for u in ups)
+
+
+@pytest.mark.parametrize("mon", [True, False])
+def test_snapshot_span_only_on_a_pass_that_emits(tmp_path, mon):
+    from distributed_pytorch_tpu.obs import metrics as dpxmon
+    from distributed_pytorch_tpu.utils.logging import MetricsLogger
+
+    dpxtrace.configure(enabled=True, ring=4096, log_path=None)
+    dpxmon.reset()
+    dpxmon.configure(enabled=mon)
+    logger = MetricsLogger(path=str(tmp_path / "serve.jsonl"))
+    model = tiny_lm()
+    try:
+        with InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
+                             EngineConfig(n_slots=2, max_len=64,
+                                          metrics=logger, log_every=3)) as eng:
+            eng.submit(np.arange(5, dtype=np.int32),
+                       SamplingParams(max_new_tokens=11)).result(timeout=300)
+            passes = eng.stats()["iterations"]
+    finally:
+        logger.close()
+        dpxmon.reset()
+    ring, _ = dpxtrace.flight_snapshot()
+    snaps = [r for r in ring if r["name"] == "serve.snapshot"]
+    assert [s["attrs"]["iteration"] for s in snaps] \
+        == (list(range(3, passes + 1, 3)) if mon else [])
+    # between two passes: under no serve.iter
+    assert all(s["parent_id"] is None for s in snaps) and passes >= 9
+    if mon:
+        rows = [json.loads(l) for l in
+                (tmp_path / "serve.jsonl").read_text().splitlines()]
+        last = [r for r in rows if r.get("event") == "metrics_snapshot"][-1]
+        shares = {k: v for k, v in last["metrics"].items()
+                  if k.startswith("serve.host_share.")}
+        assert set(shares) == {"serve.host_share." + k for k in (
+            "admit", "decode_dispatch", "decode_upload", "row_loop",
+            "decode_fetch", "idle")}
+        assert 0 < shares["serve.host_share.decode_upload"] \
+            <= shares["serve.host_share.decode_dispatch"]
+        assert 0 < shares["serve.host_share.decode_fetch"] \
+            <= shares["serve.host_share.row_loop"] < 1
 
 
 def test_spec_step_emits_each_span_once_an_iteration(tmp_path):
