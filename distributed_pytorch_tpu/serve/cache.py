@@ -57,6 +57,9 @@ class CompileCounts:
     moe_kernel_matmuls: int = 0
     prefill: Dict[int, int] = field(default_factory=dict)  # bucket -> n
     sample: int = 0
+    #: the program that puts a new row's first token among the rows'
+    #: tokens on the device (``RowSampler.place``)
+    place: int = 0
     verify: Dict[int, int] = field(default_factory=dict)   # k+1 -> n
     commit: Dict[int, int] = field(default_factory=dict)   # k+1 -> n
 
@@ -98,21 +101,24 @@ def upload_pass(pool, iteration: Optional[int], mirrors, fresh=()):
     (docs/observability.md), so that what the copies cost can be told
     from the jitted call that follows them: ``mirrors`` through
     :func:`upload`, ``fresh`` (host arrays made for this pass alone,
-    which nobody writes again) through ``jnp.asarray``. The span's
+    which nobody writes again) through ``jnp.asarray``. An argument that
+    is on the device already (the engine's tokens: the pass before's
+    output) comes back as it is, uncopied and uncounted. The span's
     ``arrays`` and ``bytes`` are counted here, where the work happens;
     the region's nanoseconds add up in ``pool.upload_ns`` (the engine's
     ``host_ns["decode_upload"]``). The span is the engine loop's:
     ``iteration`` None (the draft model's steps, the disaggregated
     decode loop, which have no spans of their own) copies without it."""
     t0 = time.perf_counter_ns()
-    copy = lambda: [upload(m) for m in mirrors] \
-        + [jnp.asarray(a) for a in fresh]
+    copy = lambda: [m if isinstance(m, jax.Array) else upload(m)
+                    for m in mirrors] + [jnp.asarray(a) for a in fresh]
     if iteration is None:
         out = copy()
     else:
         with dpxtrace.span("serve.decode.upload", iteration=iteration) as up:
             out = copy()
-            up.set(arrays=len(out), bytes=sum(int(a.nbytes) for a in out))
+            sent = [a for a, m in zip(out, (*mirrors, *fresh)) if a is not m]
+            up.set(arrays=len(sent), bytes=sum(int(a.nbytes) for a in sent))
     pool.upload_ns += time.perf_counter_ns() - t0
     return out
 
@@ -244,15 +250,16 @@ class SlotPool:
             jnp.asarray(true_len, jnp.int32), jnp.asarray(slot, jnp.int32))
         return logits
 
-    def decode(self, params, tokens: np.ndarray, active: np.ndarray,
+    def decode(self, params, tokens, active: np.ndarray,
                iteration: Optional[int] = None):
         """Advance every slot one position (dead slots masked: their
         lengths freeze and their outputs are discarded by the caller).
-        tokens/active: (n_slots,) int32 / bool, on the host: the two
-        copies to the device are made here (:func:`upload_pass`; the
-        lengths live on the device). Returns each slot's greedy token
-        (n_slots,) int32 and the (n_slots, vocab) logits, both left on
-        the device."""
+        tokens/active: (n_slots,) int32 / bool. ``tokens`` on the
+        device (a pass's output, the engine's) goes to the program as it
+        is; a host array is copied here with ``active``
+        (:func:`upload_pass`; the lengths live on the device). Returns
+        each slot's greedy token (n_slots,) int32 and the (n_slots,
+        vocab) logits, both left on the device."""
         tokens, active = upload_pass(self, iteration, (tokens,), (active,))
         out, logits, self.ks, self.vs, self.lengths = self._decode_fn(
             params, self.ks, self.vs, self.lengths, tokens, active)

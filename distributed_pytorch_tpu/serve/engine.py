@@ -15,7 +15,11 @@ One engine thread runs the iteration loop; each iteration
 4. advances EVERY active slot one token through the single jitted
    decode program (``serve.cache.SlotPool``), retiring slots that hit
    ``max_new_tokens`` / ``eos_token`` so the next iteration can refill
-   them.
+   them. ONE decode pass is kept in flight (``_decode_all``): an
+   iteration dispatches the next pass, whose tokens argument is the
+   pass before's output still on the device, and only then reads the
+   pass before and emits it, so the chip does not wait for the host
+   between two passes. A prompt's first token is taken after that read.
 
 Determinism contract: each request's token stream is identical to a
 standalone ``models.generate.generate`` call with the same params/rng
@@ -49,7 +53,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +77,19 @@ from .types import (FAILED, FINISHED, PREFILLING, QUEUED, RUNNING,
                     AdmissionRejected, EngineStopped, PagePoolExhausted,
                     Request, RequestDeadlineExceeded, RequestHandle,
                     SamplingParams, SpecDecodeError)
+
+
+class _Pass(NamedTuple):
+    """A decode pass dispatched and not yet read."""
+
+    #: the iteration whose ``serve.decode.dispatch`` launched it
+    iteration: int
+    #: (n_slots,) int32 on the device: every row's token of this pass,
+    #: after the samplers' merge; the next pass's argument as it stands
+    tokens: Any
+    #: slot -> the REQUEST that held it at dispatch: by the read the
+    #: slot (and its pages) may belong to another
+    rows: Dict[int, Request]
 
 
 def _default_buckets(cap: int) -> Tuple[int, ...]:
@@ -257,7 +274,15 @@ class InferenceEngine:
         # it owns its slot and pages and is no row of the decode program
         self._prefilling: Optional[Request] = None
         self._free: List[int] = list(range(cfg.n_slots))[::-1]
-        self._cur_tokens = np.zeros(cfg.n_slots, np.int32)
+        # the token path keeps one decode pass in flight (_decode_all):
+        # the pass dispatched and not yet read; the rows' current tokens
+        # on the device (the newest pass's output with the first tokens
+        # of rows admitted since put in: the next pass's argument; None
+        # before the first); requests whose first token is sampled on
+        # the device and not yet read, each with that (1,) array
+        self._inflight: Optional[_Pass] = None
+        self._dev_tokens = None
+        self._awaiting: Dict[int, Tuple[Request, Any]] = {}   # by slot
         if self._block:
             # every slot's block: its tokens (the model's mask_id where
             # a position is still masked), the pass that filled each
@@ -285,6 +310,8 @@ class InferenceEngine:
         self._prefill_chunk_iterations = 0  # iterations: a chunk AND a decode
         self._rows_decoded = 0
         self._decode_fetches = 0  # device-to-host token reads, decode path
+        self._passes_ahead = 0    # passes dispatched before the last was read
+        self._rows_dropped = 0    # row-steps read for a row no longer there
         self._tokens_emitted = 0
         self._completed = 0
         self._failed = 0
@@ -491,6 +518,12 @@ class InferenceEngine:
                "prefill_chunk_iterations": self._prefill_chunk_iterations,
                "rows_decoded": self._rows_decoded,
                "decode_fetches": self._decode_fetches,
+               # one pass in flight: passes dispatched while the pass
+               # before was not yet read (over decode_fetches, the share
+               # of passes the chip did not wait for), and row-steps run
+               # ahead for a row that had finished or failed by the read
+               "decode_passes_ahead": self._passes_ahead,
+               "decode_rows_dropped": self._rows_dropped,
                "sample_dispatches": self._sampler.dispatches,
                "host_ns": self._host_times(),
                "queue_depth": len(self._scheduler),
@@ -500,6 +533,7 @@ class InferenceEngine:
                "decode_attention_kernel_layers": c.decode_kernel_layers,
                "prefill_compiles": dict(c.prefill),
                "sample_compiles": c.sample,
+               "place_compiles": c.place,
                # every program XLA built in this process, whoever asked
                "xla_compiles": compile_cache.compile_events(),
                "buckets": self.buckets,
@@ -513,12 +547,13 @@ class InferenceEngine:
                 # read; the mark puts a reading on the profiler's clock,
                 # so that a traced part can be told by two of them
                 out.update(moe)
+                also = ("decode_passes_ahead", "decode_rows_dropped")
                 if self._block:
                     # a block generator's marks carry its own counters
-                    moe = {**moe, **{k: out[k] for k in (
-                        "block_passes", "block_commits", "block_fills",
-                        "blocks_emitted", "tokens_emitted")}}
-                with dpxtrace.span("serve.stats", **moe):
+                    also += ("block_passes", "block_commits", "block_fills",
+                             "blocks_emitted", "tokens_emitted")
+                with dpxtrace.span("serve.stats", **moe,
+                                   **{k: out[k] for k in also}):
                     pass
         if self._spec is not None:
             out["spec"] = {
@@ -571,13 +606,13 @@ class InferenceEngine:
                     chunks = self._admit_from_queue()
                     host["admit"] += clock() - t_admit
                     it.set(rows=len(self._running))
-                    if self._running:
-                        if self._block:
+                    if self._running and chunks:
+                        self._prefill_chunk_iterations += 1
+                    if self._block:
+                        if self._running:
                             self._block_all()
-                        else:
-                            self._decode_all()
-                        if chunks:
-                            self._prefill_chunk_iterations += 1
+                    elif self._running or self._inflight is not None:
+                        self._decode_all()
             except Exception as e:  # noqa: BLE001
                 # an engine-loop crash (XLA error, bad params) must not
                 # strand every future unresolved: fail them typed, with
@@ -759,11 +794,14 @@ class InferenceEngine:
         return False
 
     def _prefill_chunk(self) -> None:
-        """One chunk of the request mid-prefill. A chunk that is not its
-        prompt's last is dispatched and not waited for: nothing of it is
-        fetched, and the decode program queues behind it. The last one
-        samples and emits the first token, and the request is a running
-        row from this iteration's decode on."""
+        """One chunk of the request mid-prefill, dispatched and not
+        waited for: the decode program queues behind it. Nothing of a
+        chunk that is not its prompt's last is fetched. Behind the last
+        one the first token's sampler is dispatched too
+        (``_sample_first``): the request is a running row from this
+        iteration's decode pass on, and its first token is read and
+        emitted once the loop has read the pass in flight
+        (``_emit_first``)."""
         req = self._prefilling
         with dpxtrace.span("serve.admit", iteration=self._iteration,
                            trace_id=req.trace_id, request_id=req.request_id,
@@ -781,7 +819,7 @@ class InferenceEngine:
                     return
                 req.state = RUNNING
                 self._running[req.slot] = req
-                self._first_token(req, ch.logits)
+                self._sample_first(req, ch.logits)
 
     def _admit_whole(self, req: Request) -> None:
         """The contiguous pool's admission: the whole prompt in one
@@ -802,27 +840,68 @@ class InferenceEngine:
                 logits = self.pool.admit(
                     self.params, jnp.asarray(padded), s, slot)
             self._admitted_now(req)
-            self._first_token(req, logits)
+            self._sample_first(req, logits)
 
-    def _first_token(self, req: Request, logits) -> None:
+    def _sample_first(self, req: Request, logits) -> None:
+        """Dispatch, behind ``req``'s prefill, what gives it a row of
+        the next decode pass without the host having seen its first
+        token: the one-row sampler on the prefill's ``logits``, and the
+        program that puts that token among the rows' tokens on the
+        device. Nothing is waited for: :meth:`_emit_first` reads it."""
         if self._spec is not None and req.params.temperature == 0.0:
             # greedy requests speculate: prefill the draft's own slot
             # too (a prompt no draft bucket fits just runs
             # non-speculative — mixed batches are first-class)
             self._spec.admit(req.prompt, req.slot, self.buckets)
-        # the fetch is where the host waits for the prefill
+        first = self._sampler.first(req, logits)
+        self._dev_tokens = self._sampler.place(self._dev_tokens, first,
+                                               req.slot)
+        self._awaiting[req.slot] = (req, first)
+
+    def _emit_first(self, req: Request, first) -> None:
+        """Read and emit ``req``'s first token: where the host waits
+        for the prefill. After the read of the decode pass in flight,
+        which a chunk dispatched behind that pass must not hold back."""
         with dpxtrace.span("serve.admit.first_token",
                            iteration=self._iteration):
-            tok = int(np.asarray(self._sampler.first(req, logits))[0])
-            self._emit(req, tok)
+            self._emit(req, int(np.asarray(first)[0]))
 
     def _decode_all(self) -> None:
+        """The token path's step, one decode pass kept in flight: with
+        pass ``k`` still running, dispatch pass ``k + 1`` over the rows
+        that have a token after ``k`` (its argument is ``k``'s output,
+        on the device), and only then read ``k`` and emit it. What the
+        host knows ahead it decides ahead: a row whose token in flight
+        is its last (``max_new_tokens``; ``_validate`` keeps that inside
+        ``max_len``) is not in ``k + 1``, asks for no page and writes
+        nothing. What only the token tells (``eos_token``), a deadline
+        or a failure leaves a row in ``k + 1`` that is gone when it is
+        read: its token is dropped there (``decode_rows_dropped``).
+        Speculating rows decide on the host what they keep
+        (``_spec_step``), so an iteration that has any runs nothing
+        ahead: it reads what is in flight, then its own pass. Last, the
+        first token of a prompt whose prefill this iteration finished
+        dispatching (``_awaiting``): its row is in pass ``k + 1``
+        already, the sampler having put the token there."""
         spec_slots: List[int] = []
         if self._spec is not None:
             spec_slots = [s for s in sorted(self._running)
                           if self._spec.active[s]]
-        nonspec = [s for s in sorted(self._running)
-                   if s not in set(spec_slots)]
+        speculating = set(spec_slots)
+        if spec_slots and self._inflight is not None:
+            self._read_pass()
+        prev, self._inflight = self._inflight, None
+        # (slot, request, index of the token this pass gives it): a
+        # token on the device and not yet read counts as had, be it the
+        # pass in flight's or the first, a prefill's (_awaiting)
+        rows: List[Tuple[int, Request, int]] = []
+        for slot in sorted(self._running):
+            req = self._running[slot]
+            step = len(req.out_tokens) + (slot in self._awaiting) + (
+                prev is not None and prev.rows.get(slot) is req)
+            if slot not in speculating \
+                    and step < req.params.max_new_tokens:
+                rows.append((slot, req, step))
         if self._paged:
             # grow page tables at page boundaries BEFORE the decode
             # write; an exhausted pool fails the victim request typed
@@ -833,8 +912,8 @@ class InferenceEngine:
             # a page
             with dpxtrace.span("serve.decode.capacity",
                                iteration=self._iteration):
-                for slot in list(nonspec):
-                    req = self._running[slot]
+                for row in list(rows):
+                    slot, req, _ = row
                     try:
                         self.pool.ensure_decode_capacity(slot)
                     except PagePoolExhausted as e:
@@ -848,57 +927,98 @@ class InferenceEngine:
                             request_id=req.request_id,
                             iteration=self._iteration),
                             outcome="no_free_pages")
-                        nonspec.remove(slot)
-        if nonspec:
-            clock = time.perf_counter_ns
-            it, rows = self._iteration, len(nonspec)
-            active = np.zeros(self.config.n_slots, bool)
-            active[nonspec] = True
-            t0 = clock()
-            with dpxtrace.span("serve.decode.dispatch", iteration=it,
-                               rows=rows):
-                tokens, logits = self.pool.decode(
-                    self.params, self._cur_tokens, active, iteration=it)
-            t1 = clock()
-            with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
-                # a greedy row's token is the decode program's own; the
-                # rows that sample get theirs from one program a setting.
-                # What is left of a row's sample and fetch is host work of
-                # about a microsecond (joining its group; reading its
-                # token from the fetched array); their spans stay for the
-                # metrics that read them (PERF.md section 3)
-                live, groups = [], {}
-                for slot in nonspec:
-                    req = self._running[slot]
-                    ids = dict(iteration=it, slot=slot,
-                               trace_id=req.trace_id,
-                               request_id=req.request_id)
-                    live.append((req, ids))
-                    with dpxtrace.span("serve.row.sample", **ids):
-                        self._sampler.join(groups, slot, req)
-                if groups:
-                    with dpxtrace.span("serve.decode.sample", iteration=it,
-                                       groups=len(groups)):
-                        tokens = self._sampler.merge(tokens, logits, groups)
-                # the iteration's one read: here the host waits for the
-                # decode program
-                t2 = clock()
-                with dpxtrace.span("serve.decode.fetch", iteration=it,
-                                   rows=rows):
-                    tokens = np.asarray(tokens)
-                self._host_ns["decode_fetch"] += clock() - t2
-                self._decode_fetches += 1
-                for req, ids in live:
-                    with dpxtrace.span("serve.row.fetch", **ids):
-                        tok = int(tokens[req.slot])
-                    with dpxtrace.span("serve.row.emit", **ids):
-                        self._emit(req, tok)
-            self._host_ns["decode_dispatch"] += t1 - t0
-            self._host_ns["row_loop"] += clock() - t1
-            self._rows_decoded += rows
+                        rows.remove(row)
+        if rows:
+            self._dispatch_pass(rows, ahead=prev is not None)
+        if prev is not None:
+            self._read_pass(prev)
+        t0 = time.perf_counter_ns()
+        for req, first in self._awaiting.values():
+            if not req.done:        # the capacity check above may fail it
+                self._emit_first(req, first)
+        self._awaiting.clear()
+        self._host_ns["admit"] += time.perf_counter_ns() - t0
+        if spec_slots and self._inflight is not None:
+            self._read_pass()
         spec_slots = [s for s in spec_slots if s in self._running]
         if spec_slots:
             self._spec_step(spec_slots)
+        if self._inflight is not None and not self._running:
+            # every row of the pass in flight has gone (eos, deadline,
+            # failure): nothing would wake the loop to read it, so it
+            # is read before the engine goes idle
+            self._read_pass()
+
+    def _dispatch_pass(self, rows: List[Tuple[int, Request, int]],
+                       ahead: bool) -> None:
+        """Dispatch one decode pass over ``rows`` and the samplers of
+        those that sample; it is ``_inflight`` from here on. Its tokens
+        argument is already on the device. ``ahead``: the pass before is
+        not yet read."""
+        clock = time.perf_counter_ns
+        it = self._iteration
+        active = np.zeros(self.config.n_slots, bool)
+        active[[slot for slot, _, _ in rows]] = True
+        t0 = clock()
+        with dpxtrace.span("serve.decode.dispatch", iteration=it,
+                           rows=len(rows)):
+            tokens, logits = self.pool.decode(
+                self.params, self._dev_tokens, active, iteration=it)
+        t1 = clock()
+        # a greedy row's token is the decode program's own; the rows
+        # that sample get theirs from one program a setting, each with
+        # the key of the token this pass gives it. What is left of a
+        # row's sample is host work of about a microsecond (joining its
+        # group); its span stays for the metric that reads it (PERF.md
+        # section 3)
+        groups = {}
+        for slot, req, step in rows:
+            with dpxtrace.span("serve.row.sample", iteration=it, slot=slot,
+                               trace_id=req.trace_id,
+                               request_id=req.request_id):
+                self._sampler.join(groups, slot, req, step)
+        if groups:
+            with dpxtrace.span("serve.decode.sample", iteration=it,
+                               groups=len(groups)):
+                tokens = self._sampler.merge(tokens, logits, groups)
+        self._passes_ahead += ahead
+        self._inflight = _Pass(it, tokens, {s: r for s, r, _ in rows})
+        self._dev_tokens = tokens
+        self._host_ns["decode_dispatch"] += t1 - t0
+        self._host_ns["row_loop"] += clock() - t1
+        self._rows_decoded += len(rows)
+
+    def _read_pass(self, p: Optional[_Pass] = None) -> None:
+        """Read a dispatched pass (default: the one in flight, which
+        then is none) and emit it: the pass's one read, where the host
+        waits for the decode program that the dispatch span of iteration
+        ``p.iteration`` launched. A row whose request has finished or
+        failed since is dropped: never emitted, never passed to
+        ``on_token``."""
+        if p is None:
+            p, self._inflight = self._inflight, None
+        clock = time.perf_counter_ns
+        it, rows = self._iteration, len(p.rows)
+        t1 = clock()
+        with dpxtrace.span("serve.decode.rows", iteration=it,
+                           dispatched=p.iteration, rows=rows):
+            t2 = clock()
+            with dpxtrace.span("serve.decode.fetch", iteration=it,
+                               dispatched=p.iteration, rows=rows):
+                tokens = np.asarray(p.tokens)
+            self._host_ns["decode_fetch"] += clock() - t2
+            self._decode_fetches += 1
+            for slot, req in p.rows.items():
+                if req.done:
+                    self._rows_dropped += 1
+                    continue
+                ids = dict(iteration=it, slot=slot, trace_id=req.trace_id,
+                           request_id=req.request_id)
+                with dpxtrace.span("serve.row.fetch", **ids):
+                    tok = int(tokens[slot])
+                with dpxtrace.span("serve.row.emit", **ids):
+                    self._emit(req, tok)
+        self._host_ns["row_loop"] += clock() - t1
 
     # -- generation by blocks ------------------------------------------------
 
@@ -1046,18 +1166,19 @@ class InferenceEngine:
         host bookkeeping (the draft's length rewind)."""
         spec = self._spec
         k = spec.cfg.draft_len
+        cur = np.asarray([self._running[s].out_tokens[-1]
+                          for s in spec_slots], np.int32)
         ids = dict(iteration=self._iteration, rows=len(spec_slots),
                    draft_len=k)
         try:
             faults.on_comm_op("draft_propose")
             with dpxtrace.span("serve.spec.propose", **ids):
-                drafts = spec.propose(spec_slots,
-                                      self._cur_tokens[spec_slots])
+                drafts = spec.propose(spec_slots, cur)
         except Exception as e:  # noqa: BLE001 — victim containment
             self._spec_fail(spec_slots, e, "propose")
             return
         tokens = np.zeros((self.config.n_slots, k + 1), np.int32)
-        tokens[spec_slots, 0] = self._cur_tokens[spec_slots]
+        tokens[spec_slots, 0] = cur
         tokens[spec_slots, 1:] = drafts
         try:
             faults.on_comm_op("spec_verify")
@@ -1128,7 +1249,6 @@ class InferenceEngine:
         if req.first_token_t is None:
             req.first_token_t = now
         req.last_token_t = now
-        self._cur_tokens[req.slot] = tok
         self._tokens_emitted += 1
         if req.on_token is not None:
             try:
@@ -1231,3 +1351,5 @@ class InferenceEngine:
                 iteration=self._iteration)
             exc.__cause__ = self._crash
             self._fail(req, exc, outcome="engine_stopped")
+        # a pass in flight is not read: its requests have just failed
+        self._inflight = None

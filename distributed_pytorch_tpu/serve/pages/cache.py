@@ -404,13 +404,15 @@ class PagedSlotPool:
         row.append(pid)
         self.tables[slot, need_idx] = pid
 
-    def decode(self, params, tokens: np.ndarray, active: np.ndarray,
+    def decode(self, params, tokens, active: np.ndarray,
                iteration: Optional[int] = None):
         """Advance every slot one position through the ONE jitted paged
         decode program (inactive rows neither write the pool nor
-        advance). ``tokens`` and ``active`` are host arrays: the pass's
-        four copies to the device (tables, lengths, tokens, mask) are
-        made here, under ``serve.decode.upload``
+        advance). ``tokens`` on the device (a pass's output, the
+        engine's) goes to the program as it is, and the pass makes three
+        copies to the device (tables, lengths, mask); a host array (the
+        draft model's steps, the disaggregated decode loop) is a fourth.
+        They are made here, under ``serve.decode.upload``
         (``serve.cache.upload_pass``). Returns each slot's greedy token
         (n_slots,) int32 and the (n_slots, vocab) logits, both left on
         the device."""

@@ -5,16 +5,18 @@ A decode program ends in the greedy token of every slot
 A row that samples joins the rows of its setting: ONE program a distinct
 ``(temperature, top_k, top_p)`` among the running rows picks all of
 them from the whole ``(n_slots, vocab)`` logits — each row with its own
-key, ``req.rngs[len(out)]``, so every request keeps ``generate()``'s
-split schedule and its tokens — and merges them into the greedy tokens
-on the device. Every shape is ``n_slots`` wide whatever the number of
-rows: nothing compiles as the batch breathes. The caller then reads the
-tokens of an iteration in one fetch.
+key, ``req.rngs[i]`` for its token ``i``, so every request keeps
+``generate()``'s split schedule and its tokens — and merges them into
+the greedy tokens on the device. Every shape is ``n_slots`` wide
+whatever the number of rows: nothing compiles as the batch breathes. The
+caller reads the tokens of a pass in one fetch, and hands them to the
+next pass where they are: a new row's first token, a prefill's, is put
+among them on the device too (``RowSampler.place``).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +96,7 @@ class RowSampler:
         self.dispatches = 0          # batched sampler programs dispatched
         self._one: Dict[tuple, callable] = {}
         self._rows: Dict[tuple, callable] = {}
+        self._place = None
 
     def first(self, req: Request, logits):
         """Dispatch the request's sampler on ``logits`` (1, vocab), a
@@ -113,12 +116,34 @@ class RowSampler:
             fn = self._one[key] = jax.jit(sample)
         return fn(logits, jnp.asarray(req.rngs[len(req.out_tokens)]))
 
-    def join(self, groups: Dict[tuple, Group], slot: int,
-             req: Request) -> None:
+    def place(self, tokens, first, slot: int):
+        """The rows' tokens (n_slots,) int32 on the device with
+        ``first`` (1,), a new row's first token as :meth:`first` left it
+        there, at ``slot``; ``tokens`` None: there are none yet. One
+        small program, and no copy of the token back to the device.
+        Every array the decode program is handed as its tokens comes out
+        of a program that ran on the engine's params (this one's
+        ``first`` does), the first of them too: jit keys a compile on
+        whether an argument is committed to its device, and an uploaded
+        array is not where such an output may be."""
+        if tokens is None:
+            tokens = jnp.broadcast_to(first, (self.n_slots,))
+        if self._place is None:
+            compiles = self.compiles
+
+            def place_first_token(tokens, first, slot):
+                compiles.place += 1                # trace-time only
+                return tokens.at[slot].set(first[0])
+            self._place = jax.jit(place_first_token)
+        return self._place(tokens, first, np.int32(slot))
+
+    def join(self, groups: Dict[tuple, Group], slot: int, req: Request,
+             step: Optional[int] = None) -> None:
         """File a running row under its sampling setting in ``groups``:
-        its key and its place in the row mask. A greedy row joins none:
-        it takes the decode program's own token, and its key stays on
-        the host."""
+        the key of its token ``step`` (default: its next, for a caller
+        that has read every pass it dispatched) and its place in the row
+        mask. A greedy row joins none: it takes the decode program's own
+        token, and its key stays on the host."""
         if req.params.temperature == 0.0:
             return
         group = groups.get(req.params.sampler_key)
@@ -127,7 +152,7 @@ class RowSampler:
                 np.zeros((self.n_slots, 2), np.uint32),
                 np.zeros(self.n_slots, bool))
         keys, mask = group
-        keys[slot] = req.rngs[len(req.out_tokens)]
+        keys[slot] = req.rngs[len(req.out_tokens) if step is None else step]
         mask[slot] = True
 
     def merge(self, tokens, logits, groups: Dict[tuple, Group]):

@@ -181,9 +181,10 @@ def kv():
 
 def test_one_chunk_and_one_decode_an_iteration_beside_running_rows(kv):
     """A (5 tokens) is running when B's four chunks are prefilled: every
-    iteration runs one chunk and one decode, A gets a token in each, B's
-    first token follows its last chunk, and nothing of B is in the prefix
-    index or in the decode program until then."""
+    iteration runs one chunk and one decode, A gets a token in each (a
+    decode pass is read in the iteration after the one that dispatched
+    it), B's first token follows its last chunk, and nothing of B is in
+    the prefix index or in the decode program until then."""
     model, params = kv
     eng = _engine(model, params)
     a, b = _prompt(5, seed=4), _prompt(4 * C, seed=5)
@@ -201,18 +202,21 @@ def test_one_chunk_and_one_decode_an_iteration_beside_running_rows(kv):
         out_a, out_b = ha.result(timeout=120), hb.result(timeout=120)
     np.testing.assert_array_equal(out_a, _stream(model, params, a, 12))
     np.testing.assert_array_equal(out_b, _stream(model, params, b, 4))
-    # A: its prefill's token and a decode token in iteration 1, then one
-    # an iteration, none skipped while B prefills in iterations 2 .. 5
-    assert [t[0] for t in at["a"]] == [1] + list(range(1, 12))
-    assert [t[0] for t in at["b"]] == [5, 5, 6, 7]
-    for it, indexed, prefilling, lengths in at["a"][2:5]:   # iterations 2-4
+    # A: its prefill's token in iteration 1, where its first decode pass
+    # is dispatched too; that is read in 2, then one an iteration, none
+    # skipped while B prefills in iterations 2 .. 5. B's first token and
+    # the dispatch of its first pass in 5
+    assert [t[0] for t in at["a"]] == list(range(1, 13))
+    assert [t[0] for t in at["b"]] == [5, 6, 7, 8]
+    for it, indexed, prefilling, lengths in at["a"][1:4]:   # iterations 2-4
         assert indexed == 0                       # A's 5 tokens: no full page
         assert prefilling[1].done == (it - 1) * C and lengths[1] == 0
     assert at["b"][0][1] == 4 * C // L and at["b"][0][2] == {}
     st = eng.stats()
     assert st["admitted"] == 2 and st["prefill_chunks"] == 1 + 4
     assert st["prefill_chunk_iterations"] == 5
-    assert st["decode_fetches"] == 11 and st["iterations"] == 11
+    assert st["decode_fetches"] == 11 and st["iterations"] == 12
+    assert st["decode_passes_ahead"] == 10 and st["decode_rows_dropped"] == 0
     assert st["prefill_compiles"] == {8: 1, 16: 1}
     assert hb.metrics["admit_iteration"] == 2
     assert st["pages"]["pages_in_use"] == st["pages"]["indexed_pages"] == 8
@@ -229,7 +233,7 @@ def test_chunks_run_back_to_back_while_no_row_is_running(kv):
         out = hb.result(timeout=120)
     np.testing.assert_array_equal(out, _stream(model, params, b, 4))
     st = eng.stats()
-    assert at == [1, 1, 2, 3]
+    assert at == [1, 2, 3, 4]       # a pass is read an iteration later
     assert st["prefill_chunks"] == 4 and st["prefill_chunk_iterations"] == 1
     assert st["decode_fetches"] == 3
 
@@ -273,8 +277,8 @@ def test_a_request_lost_between_two_chunks_gives_everything_back(kv, what):
             faults.install("flaky@op=serve_step,call=3")
         else:
             def on_a(tok, i):
-                if i == 2:                      # iteration 2: B has a chunk
-                    eng.shutdown(wait=False)
+                if i == 1:      # A's first decode pass, read in iteration
+                    eng.shutdown(wait=False)    # 2: B has a chunk
         with eng._cond:                         # both queued before it wakes
             ha = eng.submit(a, SamplingParams(max_new_tokens=8),
                             on_token=on_a)

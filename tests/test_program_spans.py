@@ -178,13 +178,16 @@ def test_engine_spans_are_the_loops_tree(engine_run):
     for child, parent in (
             ("serve.admit", "serve.iter"),
             ("serve.admit.prefill", "serve.admit"),
-            ("serve.admit.first_token", "serve.admit"),
+            # the first token is taken after the read of the pass in flight
+            ("serve.admit.first_token", "serve.iter"),
             ("serve.decode.dispatch", "serve.iter"),
             ("serve.decode.upload", "serve.decode.dispatch"),
             ("serve.decode.rows", "serve.iter"),
-            ("serve.decode.sample", "serve.decode.rows"),
+            # a pass's samplers are dispatched with it, an iteration
+            # before the pass is read (serve.decode.rows)
+            ("serve.decode.sample", "serve.iter"),
             ("serve.decode.fetch", "serve.decode.rows"),
-            ("serve.row.sample", "serve.decode.rows"),
+            ("serve.row.sample", "serve.iter"),
             ("serve.row.fetch", "serve.decode.rows"),
             ("serve.row.emit", "serve.decode.rows")):
         assert inside(child, parent), (child, parent)
@@ -209,39 +212,54 @@ def test_engine_row_spans_count_the_rows_decoded(engine_run):
 
 def test_engine_fetches_once_an_iteration_and_samples_by_setting(engine_run):
     spans, before, after = engine_run
-    loops = {s[3]["iteration"]: s for s in named(spans, "serve.decode.rows")}
+    # a pass is named by the iteration that dispatched it: a read carries
+    # it as ``dispatched``, beside the iteration it is read in (the next)
+    loops = {s[3]["dispatched"]: s for s in named(spans, "serve.decode.rows")}
     fetches = named(spans, "serve.decode.fetch")
-    # one read of the tokens a decode iteration, of all its rows
-    assert sorted(f[3]["iteration"] for f in fetches) == sorted(loops)
-    assert all(f[3]["rows"] == loops[f[3]["iteration"]][3]["rows"]
+    assert all(s[3]["iteration"] == d + 1 for d, s in loops.items())
+    # one read of the tokens a decode pass, of all its rows
+    assert sorted(f[3]["dispatched"] for f in fetches) == sorted(loops)
+    assert all(f[3]["rows"] == loops[f[3]["dispatched"]][3]["rows"]
+               and f[3]["iteration"] == f[3]["dispatched"] + 1
                for f in fetches)
     assert after["decode_fetches"] - before["decode_fetches"] == len(fetches)
-    # every row joins its group before the read and gets its token after
+    # every row joins its group before the read (in the iteration that
+    # dispatches the pass) and gets its token after (in the next)
     for name, after_fetch in (("serve.row.sample", False),
                               ("serve.row.fetch", True),
                               ("serve.row.emit", True)):
         for f in fetches:
-            assert sum(r[3]["iteration"] == f[3]["iteration"]
+            assert sum(r[3]["iteration"] == f[3]["dispatched"] + after_fetch
                        and (r[1] >= f[2] if after_fetch else r[2] <= f[1])
                        for r in named(spans, name)) == f[3]["rows"], name
     # a request holds a row from the iteration that admits it (its first
-    # token is its prefill's) for max_new - 1 decode iterations, and the
-    # paged engine admits one prompt an iteration: the three overlap. A
-    # sampler is dispatched only while the sampled request (the last
-    # submitted, three tokens) has a row: its two decode iterations
+    # token is its prefill's, sampled on the device behind the prefill
+    # and read at that iteration's end) for max_new - 1 decode passes,
+    # and the paged engine admits one prompt an iteration: the three
+    # overlap. A sampler is dispatched only while the sampled request
+    # (the last submitted, three tokens) has a row: its two decode passes
     admitted = sorted((a[3]["request_id"], a[3]["iteration"])
                       for a in named(spans, "serve.admit"))
     its = [it for _, it in admitted]
     assert len(set(its)) == 3
     held = [set(range(it, it + n - 1)) for it, n in zip(its, (5, 5, 3))]
     assert set(loops) == set().union(*held)
+    dispatches = named(spans, "serve.decode.dispatch")
+    assert {d[3]["iteration"] for d in dispatches} == set(loops)
     samples = named(spans, "serve.decode.sample")
     assert {s[3]["iteration"] for s in samples} == held[2]
     assert len(samples) == 2
     assert all(s[3]["groups"] == 1 for s in samples)
     assert after["sample_dispatches"] - before["sample_dispatches"] == 2
-    fetch_of = {f[3]["iteration"]: f for f in fetches}
+    fetch_of = {f[3]["dispatched"]: f for f in fetches}
     assert all(s[2] <= fetch_of[s[3]["iteration"]][1] for s in samples)
+    # with a pass in flight, the next is dispatched BEFORE it is read
+    start = {d[3]["iteration"]: d[1] for d in dispatches}
+    assert all(start[d + 1] < f[1] for d, f in fetch_of.items()
+               if d + 1 in start)
+    ahead = after["decode_passes_ahead"] - before["decode_passes_ahead"]
+    assert ahead == sum(d + 1 in start for d in fetch_of) == len(loops) - 1
+    assert after["decode_rows_dropped"] == 0
 
 
 def test_engine_host_ns_counters_nest(engine_run):
@@ -269,15 +287,17 @@ BLOCK_LM = dict(vocab=97, n_heads=8, head_dim=8, attn_bias=False,
 
 @pytest.mark.parametrize("pool,lm,cfg,program,arrays", [
     ("paged", {}, dict(paged=True, page_len=8, buckets=(8, 16)),
-     "_decode_fn", 4),
-    ("contiguous", {}, {}, "_decode_fn", 2),
+     "_decode_fn", 3),
+    ("contiguous", {}, {}, "_decode_fn", 1),
     ("blocks", BLOCK_LM, dict(paged=True, page_len=8, buckets=(8, 16)),
      "_block_fn", 6)])
 def test_a_pass_uploads_under_one_span_before_its_program(pool, lm, cfg,
                                                           program, arrays):
     """``serve.decode.upload``: once a pass, inside that pass's
-    ``serve.decode.dispatch``, over every copy of the pass's arguments,
-    and closed before the pool calls its jitted program. Read from the
+    ``serve.decode.dispatch``, over every copy of the pass's arguments
+    (a token pass's tokens are on the device already: the pass before's
+    output), and closed before the pool calls its jitted program. Read
+    from the
     flight ring under ``DPX_TRACE=1``: no profiler session."""
     dpxtrace.configure(enabled=True, ring=4096, log_path=None)
     model = tiny_lm(**lm)
@@ -311,7 +331,7 @@ def test_a_pass_uploads_under_one_span_before_its_program(pool, lm, cfg,
         assert up["attrs"]["iteration"] == parent["attrs"]["iteration"]
         assert up["attrs"]["arrays"] == arrays
     n = eng.config.n_slots
-    tokens = n * 4 * (model.gen_block or 1)
+    tokens = n * 4 * (model.gen_block or 0)      # the blocks; no token row
     held = eng.pool.tables.nbytes + n * 4 if cfg else 0
     extra = (tokens // 4 + n * 4) if model.gen_block else 0  # mask, n_fill
     assert {u["attrs"]["bytes"] for u in ups} == {held + tokens + n + extra}
