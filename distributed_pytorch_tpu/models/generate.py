@@ -37,8 +37,10 @@ import jax.numpy as jnp
 
 from ..nn.attention import block_causal_mask, dense_attention
 from ..nn.paged import (BlockCtx, BlockGenerationUnsupported,  # noqa: F401
-                        DecodeCtx, LatentPagesUnsupported, PrefillCtx,
-                        VerifyCtx, block_unsupported, latent_unsupported)
+                        DecodeCtx, LatentPagesUnsupported,
+                        MixedStoresUnsupported, PrefillCtx, VerifyCtx,
+                        block_unsupported, latent_unsupported,
+                        mixed_unsupported, table_pages)
 from ..ops.decode_attention import (blockwise_decode_attention,
                                     dense_decode_attention)
 from .transformer import TransformerLM
@@ -331,8 +333,11 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
 
     ``state`` is a list, one page store a layer, as each block's
     attention module made it (``attn.make_pages``, ``nn/paged.py``):
-    exact K and V, quantized K and V, or latent attention's one array of
-    ``[c | k_r]`` entries. This function never looks inside one: each
+    exact K and V, quantized K and V, latent attention's one array of
+    ``[c | k_r]`` entries, or a window layer's ring a slot beside the
+    global layers' pages (``tables`` address the pages; a ring finds its
+    entries from the row and ``lengths``, whose last ``window`` are its
+    live positions). This function never looks inside one: each
     layer's store goes to the block's own ``decode_paged``, which writes
     this step's entry and attends, and comes back written. Under
     hyper-connections the residual streams travel as (B, 1, streams, D).
@@ -371,7 +376,7 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
     bookkeeping belong to the caller.
     """
     idx = lengths
-    n_pages = state[0].n_pages
+    n_pages = table_pages(state)
     width = tables.shape[1] * page_len
     x = model.tok.apply(params["tok"], tokens[:, None])       # (B,1,D)
     if getattr(model, "pos", None) is not None:
@@ -468,7 +473,9 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     ``offset`` prefix tokens whose entries are already resident in the
     pages ``table_row`` (P,) names (``offset`` is page-aligned: only
     FULL pages are ever shared, so the tail always starts at a page
-    boundary). ``offset``, ``true_len`` (the real tail length, >= 1) and
+    boundary; for a model that mixes window and global layers, which
+    shares no prefix, it is where the prompt's earlier chunks stopped).
+    ``offset``, ``true_len`` (the real tail length, >= 1) and
     ``slot`` (the pool row admitted to: a quantized store keeps the
     prompt's partial last page there) are all TRACED — one compile per
     padded tail bucket serves cold (``offset == 0``), partially shared,
@@ -488,7 +495,7 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     left out of an expert layer's dispatch. Returns ``(logits (1, vocab)
     at the last real position, new state)``."""
     b, s = tokens.shape
-    n_pages = state[0].n_pages
+    n_pages = table_pages(state)
     width = table_row.shape[0] * page_len
     offset = jnp.asarray(offset, jnp.int32)
     true_len = jnp.asarray(true_len, jnp.int32)
@@ -497,18 +504,26 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], positions)
     x = model.streams_in(x)
-    # attention mask over [prefix pages | tail]: prefix columns valid
-    # below offset, tail columns causal (pad tail is causally inert)
-    prefix_mask = jnp.broadcast_to((jnp.arange(width) < offset)[None, :],
-                                   (s, width))
-    causal = jnp.tril(jnp.ones((s, s), dtype=bool))
-    if getattr(model, "gen_block", None):
-        # a model that generates by blocks: full inside a block of the
-        # tail, causal over blocks (``offset`` is page-aligned and a page
-        # is whole blocks, so the tail's own index gives the block)
-        causal = block_causal_mask(jnp.arange(s), jnp.arange(s),
-                                   model.gen_block)
-    mask = jnp.concatenate([prefix_mask, causal], axis=1)   # (S, W+S)
+    if getattr(model, "layer_windows", None) is not None:
+        # window and global layers in one cache: a window layer attends
+        # in bands over its ring's last entries and the tail, a global
+        # layer over its resident pages in blocks that follow ``offset``
+        # (``nn/attention.py``): no (S, W + S) array is formed
+        mask = None
+    else:
+        # attention mask over [prefix pages | tail]: prefix columns valid
+        # below offset, tail columns causal (pad tail is causally inert)
+        prefix_mask = jnp.broadcast_to(
+            (jnp.arange(width) < offset)[None, :], (s, width))
+        causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+        if getattr(model, "gen_block", None):
+            # a model that generates by blocks: full inside a block of
+            # the tail, causal over blocks (``offset`` is page-aligned and
+            # a page is whole blocks, so the tail's own index gives the
+            # block)
+            causal = block_causal_mask(jnp.arange(s), jnp.arange(s),
+                                       model.gen_block)
+        mask = jnp.concatenate([prefix_mask, causal], axis=1)  # (S, W+S)
     # tail scatter destinations: position offset+i lives in the slot's
     # page (offset+i)//page_len at offset (offset+i)%page_len; pad
     # positions (i >= true_len) route out of bounds and are dropped
@@ -739,16 +754,41 @@ def generate(model: TransformerLM, params: Params, prompt, max_new: int,
         params, prompt, rng if rng is not None else jax.random.PRNGKey(0))
 
 
+def layer_windows(model: TransformerLM) -> Tuple[Optional[int], ...]:
+    """Each layer's sliding-window width, None where the layer sees every
+    earlier position: what the model was told
+    (``TransformerLM(layer_windows=...)``), else what each block's
+    ``attn_fn`` advertises (``make_flash_attn_fn(window=W)``)."""
+    told = getattr(model, "layer_windows", None)
+    if told is not None:
+        return told
+    return tuple(getattr(blk.attn.attn_fn, "window", None)
+                 for blk in model.blocks)
+
+
+def refuse_mixed(model, what: str):
+    """For a path that keeps one cache layout for every layer (the
+    contiguous ``SlotPool``, ``generate()``): a model told its layers'
+    windows is served by the paged pool alone, whose stores differ a
+    layer (``nn/paged.py`` ``WindowPages`` beside ``KVPages``)."""
+    if getattr(model, "layer_windows", None) is not None:
+        raise mixed_unsupported(what)
+
+
 def _model_window(model: TransformerLM) -> Optional[int]:
-    """The model's uniform sliding-window width, or None.
+    """The sliding-window width a contiguous cache rolls over, or None.
 
     A model built with ``make_flash_attn_fn(window=W)`` advertises W on
     every block's attn_fn; a uniform W switches decode to the rolling
-    O(W)-memory cache that reproduces the window exactly. Mixed widths
-    are not a cache layout this path can serve."""
-    widths = {getattr(blk.attn.attn_fn, "window", None)
-              for blk in model.blocks}
-    if widths == {None} or not model.blocks:
+    O(W)-memory cache that reproduces the window exactly. A model that
+    was told a width a layer (``TransformerLM(layer_windows=...)``) has
+    no contiguous layout and answers None here: the paged pool serves it
+    and every other path refuses it by name (:func:`refuse_mixed`).
+    Widths that disagree without having been told are an error."""
+    if getattr(model, "layer_windows", None) is not None:
+        return None
+    widths = set(layer_windows(model))
+    if widths <= {None}:
         return None
     if len(widths) == 1:
         return next(iter(widths))
@@ -763,13 +803,16 @@ def _check_attn_compatible(model: TransformerLM,
     marks itself ``dense_equivalent``), and for uniform sliding-window
     kernels (served by the rolling cache). Refuse behavior-changing
     custom cores (biased, ring islands) unless the caller explicitly
-    opts in."""
+    opts in. A layer that was told its own window
+    (``MultiHeadAttention(window=...)``) computes it itself and never
+    calls the core."""
     if allow_custom_attn:
         return
     for blk in model.blocks:
         f = blk.attn.attn_fn
         if (f is dense_attention or getattr(f, "dense_equivalent", False)
-                or getattr(f, "window", None) is not None):
+                or getattr(f, "window", None) is not None
+                or getattr(blk.attn, "window", None) is not None):
             continue
         raise ValueError(
             "model was built with a custom attn_fn whose semantics the "
@@ -820,6 +863,8 @@ def make_generate_fn(model: TransformerLM, max_new: int, *,
     _check_attn_compatible(model, allow_custom_attn)
     refuse_blocks(model, "generate() (one token a step over a contiguous "
                          "cache)")
+    refuse_mixed(model, "generate() (one contiguous cache layout for "
+                        "every layer)")
     window = _model_window(model)
 
     def fn(params, prompt, rng):

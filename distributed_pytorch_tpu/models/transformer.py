@@ -72,6 +72,12 @@ def apply_remat_policy(fn: Callable, policy: str) -> Callable:
     return jax.checkpoint(fn, policy=jax.checkpoint_policies.dots_saveable)
 
 
+#: positions a trip when a prompt's tail reads a global layer's resident
+#: pages beside window layers (``nn.paged.KVPages.attend_tail``): the
+#: scores of a 1024-token chunk of 64 heads are 134 MB in float32 at 512
+TAIL_BLOCK = 512
+
+
 def _with_bias(block_params, bias):
     """A block's parameters with its router's bias replaced."""
     ffn = block_params["ffn"]
@@ -101,6 +107,17 @@ class TransformerLM(Module):
       keyword arguments. The embedding is copied into every stream and
       the streams are summed before the final norm.
 
+    ``layer_windows`` (blocks made of parts, ``attention="mha"``) mixes
+    sliding-window and global layers in one model: a width a layer, or
+    None where the layer sees every earlier position. Window layer ``l``
+    attends to keys ``i - layer_windows[l] < j <= i`` and keeps, when
+    served, a ring of its last entries a slot in place of pages
+    (``nn.paged.WindowPages``); a global layer keeps pages and reads a
+    prompt's resident prefix in blocks of ``TAIL_BLOCK`` positions.
+    ``layer_rope`` (one bool a layer, with ``pos="rope"``) says which
+    layers rotate q and k; the default rotates every layer. Such a model
+    is served by the paged engine alone (``serve/pages/cache.py``).
+
     ``mtp=1`` adds one multi-token-prediction module (DeepSeek-V3 section
     2.2; needs blocks made of parts and the plain residual sum): position
     ``i`` merges the embedding of token ``i + 1`` with the last block's
@@ -126,7 +143,9 @@ class TransformerLM(Module):
                  mtp: int = 0, head_dim: Optional[int] = None,
                  attn_bias: bool = True, qk_norm: Optional[float] = None,
                  gen_block: Optional[int] = None,
-                 mask_id: Optional[int] = None):
+                 mask_id: Optional[int] = None,
+                 layer_windows: Optional[Sequence[Optional[int]]] = None,
+                 layer_rope: Optional[Sequence[bool]] = None):
         if pos not in ("learned", "rope", "none"):
             raise ValueError(f"pos must be learned|rope|none, got {pos!r}")
         if attention not in ("mha", "latent"):
@@ -146,6 +165,24 @@ class TransformerLM(Module):
                     "multi-head attention without a prediction module "
                     "(attention='mha', mtp=0) and pos='rope' or 'none'")
         self.gen_block, self.mask_id = gen_block, mask_id
+        if layer_windows is not None or layer_rope is not None:
+            if attention != "mha" or gen_block is not None or mtp:
+                raise ValueError(
+                    "layer_windows / layer_rope describe multi-head "
+                    "attention layers of a model that yields a token a "
+                    "step (attention='mha', gen_block=None, mtp=0)")
+            if layer_rope is not None and pos != "rope":
+                raise ValueError("layer_rope chooses among rotating "
+                                 "layers: pos must be 'rope'")
+            for name, per in (("layer_windows", layer_windows),
+                              ("layer_rope", layer_rope)):
+                if per is not None and len(per) != n_layers:
+                    raise ValueError(f"{name} must have one entry for each "
+                                     f"of {n_layers} layers, got {per}")
+        #: a width a layer (None: global), or None for a model that was
+        #: not told: what ``models.generate.layer_windows`` reads
+        self.layer_windows = None if layer_windows is None \
+            else tuple(layer_windows)
         self.vocab = vocab
         self.dim = dim
         self.n_layers = n_layers
@@ -183,7 +220,8 @@ class TransformerLM(Module):
             else dict(head_dim=head_dim, bias=attn_bias, qk_norm=qk_norm,
                       gen_block=gen_block)
         from_parts = (block_kinds is not None or attention != "mha"
-                      or norm != "layer" or self.streams > 0 or bool(mha))
+                      or norm != "layer" or self.streams > 0 or bool(mha)
+                      or layer_windows is not None or layer_rope is not None)
         if not from_parts:
             self.blocks = [
                 TransformerBlock(dim, n_heads, mlp_ratio, causal=True,
@@ -202,15 +240,22 @@ class TransformerLM(Module):
                 raise ValueError("block_kinds names an expert layer: give "
                                  "moe=dict(n_routed=..., width=..., top_k=...)")
 
-            def make_attn():
+            def make_attn(layer=None):
                 if attention == "latent":
                     return LatentAttention(
                         dim, n_heads, rope_base=rope_base, attn_fn=attn_fn,
                         dtype=dtype, **(latent or {}))
+                kw, rope = dict(mha), pos == "rope"
+                if layer is not None and layer_rope is not None:
+                    rope = bool(layer_rope[layer])
+                if layer is not None and layer_windows is not None:
+                    w = layer_windows[layer]
+                    kw.update(window=w,
+                              tail_block=None if w is not None else TAIL_BLOCK)
                 return MultiHeadAttention(
                     dim, n_heads, causal=True, n_kv_heads=n_kv_heads,
-                    rope=(pos == "rope"), rope_base=rope_base,
-                    attn_fn=attn_fn, dtype=dtype, **mha)
+                    rope=rope, rope_base=rope_base,
+                    attn_fn=attn_fn, dtype=dtype, **kw)
 
             def make_ffn(kind):
                 if kind == "moe":
@@ -218,12 +263,13 @@ class TransformerLM(Module):
                     return DroplessMoE(dim, dtype=dtype, **moe)
                 return GatedMLP(dim, ffn_dim or mlp_ratio * dim, dtype=dtype)
 
-            def make_block(kind):
-                return Block(dim, norm1=make_norm(), attn=make_attn(),
+            def make_block(kind, layer=None):
+                return Block(dim, norm1=make_norm(), attn=make_attn(layer),
                              norm2=make_norm(), ffn=make_ffn(kind),
                              streams=self.streams, hc=hc)
 
-            self.blocks = [make_block(kind) for kind in kinds]
+            self.blocks = [make_block(kind, layer)
+                           for layer, kind in enumerate(kinds)]
         if mtp not in (0, 1):
             raise ValueError(f"mtp must be 0 or 1 (the depth of the "
                              f"prediction module), got {mtp!r}")

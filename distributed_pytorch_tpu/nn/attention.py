@@ -11,6 +11,7 @@ activations/params while softmax runs in float32 for stability.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Callable, Optional
 
@@ -113,6 +114,15 @@ class MultiHeadAttention(Module):
     mask by :func:`block_causal_mask` (a model that generates by blocks,
     ``models/transformer.py``), which only a core that takes ``mask=``
     can compute.
+
+    ``window`` and ``tail_block`` are a layer of a model that mixes
+    sliding-window and global layers (``TransformerLM(layer_windows=...)``).
+    ``window`` (a width): query ``i`` sees keys ``i - window < j <= i``,
+    and the layer's store is a ring a slot (``nn.paged.WindowPages``),
+    not pages. ``tail_block`` (a number of positions): a prompt's tail
+    attends over the slot's resident pages that many positions a trip
+    (``nn.paged.KVPages.attend_tail``), not over the gathered row under
+    a dense mask; a global layer of such a model is given it.
     """
 
     def __init__(self, dim: int, n_heads: int, *, causal: bool = False,
@@ -121,7 +131,9 @@ class MultiHeadAttention(Module):
                  attn_fn: Optional[Callable] = None, dtype=jnp.float32,
                  head_dim: Optional[int] = None, bias: bool = True,
                  qk_norm: Optional[float] = None,
-                 gen_block: Optional[int] = None):
+                 gen_block: Optional[int] = None,
+                 window: Optional[int] = None,
+                 tail_block: Optional[int] = None):
         if head_dim is None and dim % n_heads:
             raise ValueError(f"dim {dim} not divisible by n_heads {n_heads}")
         self.dim = dim
@@ -136,6 +148,10 @@ class MultiHeadAttention(Module):
         self.rope_base = rope_base
         self.attn_fn = attn_fn or dense_attention
         self.gen_block = gen_block
+        self.window, self.tail_block = window, tail_block
+        if window is not None and (window < 1 or not causal or gen_block):
+            raise ValueError("window needs a width >= 1 on a causal layer "
+                             "that does not generate by blocks")
         if gen_block and self.attn_fn is not dense_attention:
             raise ValueError(
                 "a model that generates by blocks attends under the "
@@ -211,6 +227,10 @@ class MultiHeadAttention(Module):
                     else positions
                 o = self.attn_fn(q, k, v, mask=block_causal_mask(
                     at, at, self.gen_block))
+            elif self.window is not None:
+                # the layer's own width, whatever core the model was
+                # given (a flash ``attn_fn`` bakes in one width for all)
+                o = dense_attention(q, k, v, causal=True, window=self.window)
             else:
                 o = self.attn_fn(q, k, v, causal=self.causal)
         return self.project_out(params, o)
@@ -220,8 +240,15 @@ class MultiHeadAttention(Module):
     def make_pages(self, n_pages: int, n_slots: int, page_len: int, bits,
                    dtype):
         """A layer's store: K and V of (n_pages, Hkv, page_len, Dh),
-        exact in ``dtype`` (``bits`` None) or quantized to 8 or 4 bits."""
-        from .paged import KVPages
+        exact in ``dtype`` (``bits`` None) or quantized to 8 or 4 bits;
+        for a layer told its window, a ring a slot (exact only)."""
+        from .paged import KVPages, WindowPages, mixed_unsupported
+        if self.window is not None:
+            if bits is not None:
+                raise mixed_unsupported("a quantized page pool "
+                                        "(kv_dtype='q8'/'q4')")
+            return WindowPages.zeros((self.n_kv_heads, self.head_dim),
+                                     n_slots, self.window, page_len, dtype)
         return KVPages.zeros((self.n_kv_heads, page_len, self.head_dim),
                              n_pages, n_slots, bits, dtype)
 
@@ -230,9 +257,20 @@ class MultiHeadAttention(Module):
         returns (attention's output (B, 1, D), the store written)."""
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.idx[:, None, None])
+        scale = 1.0 / math.sqrt(self.head_dim)
+        if self.window is not None:
+            with jax.named_scope("page_write"):
+                pages = pages.write(hk, hv, ctx)
+            with _scopes("decode_attention", "window_attention"):
+                o = pages.attend(ctx, hq, scale, self.window)
+            return self.project_out(params, o), pages
         with jax.named_scope("page_write"):
             pages = pages.write(hk, hv, ctx.dest, ctx.wo)
-        o = pages.attend(ctx, hq, hk, hv, 1.0 / math.sqrt(self.head_dim))
+        # a global layer beside window layers reads under a name of its
+        # own; every other model's programs keep the names they had
+        with _scopes(*(("decode_attention", "global_attention")
+                       if self.tail_block else ())):
+            o = pages.attend(ctx, hq, hk, hv, scale)
         return self.project_out(params, o), pages
 
     def prefill_paged(self, params: Params, x, pages, ctx):
@@ -245,8 +283,25 @@ class MultiHeadAttention(Module):
         so a cold prompt sees no quantization at admission."""
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.positions)
+        scale = 1.0 / math.sqrt(self.head_dim)
+        if self.window is not None:
+            # the ring is read before the tail goes into it: the tail's
+            # last entries may land on the entries before it
+            pk, pv = pages.prior(ctx, self.window)
+            with jax.named_scope("page_write"):
+                pages = pages.write_tail(hk, hv, ctx)
+            with _scopes("attn/core", "window_attention"):
+                o = banded_window_attention(
+                    hq, jnp.concatenate([pk.astype(hk.dtype), hk], axis=2),
+                    jnp.concatenate([pv.astype(hv.dtype), hv], axis=2),
+                    ctx.offset, self.window, scale)
+            return self.project_out(params, o), pages
         with jax.named_scope("page_write"):
             pages = pages.write_tail(hk, hv, ctx)
+        if self.tail_block:
+            with _scopes("attn/core", "global_attention"):
+                o = pages.attend_tail(ctx, hq, scale, self.tail_block)
+            return self.project_out(params, o), pages
         pref_k, pref_v = pages.rows(ctx.table_row, None, hk, hv)
         return self.project_out(params, prefix_tail_attention(
             hq, hk, hv, pref_k, pref_v, ctx.mask,
@@ -282,6 +337,15 @@ class MultiHeadAttention(Module):
                                   1.0 / math.sqrt(self.head_dim))
         return self.project_out(params, o), (hk.astype(jnp.float32),
                                              hv.astype(jnp.float32))
+
+
+@contextlib.contextmanager
+def _scopes(*names):
+    """``jax.named_scope`` of each name, the first outermost."""
+    with contextlib.ExitStack() as stack:
+        for name in names:
+            stack.enter_context(jax.named_scope(name))
+        yield
 
 
 def write_rows(pool, dest, wo, rows):
@@ -321,6 +385,42 @@ def prefix_tail_attention(hq, hk, hv, pref_k, pref_v, mask, scale):
     probs = jax.nn.softmax(logits, axis=-1).astype(v_all.dtype)
     return jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
         .reshape(bq, hh, s, dd)
+
+
+def banded_window_attention(hq, k_all, v_all, offset, window: int, scale):
+    """A prompt's tail under a sliding window, in bands: queries hq
+    (1, H, S, Dh), query ``i`` at position ``offset + i``, over ``k_all``
+    / ``v_all`` (1, Hkv, window + S, Dh) = [the ``window`` entries before
+    the tail | the tail], column ``c`` at position ``offset - window +
+    c``. Query ``i`` sees the ``window`` columns ``i < c <= i + window``
+    that hold a position >= 0, so a block of ``window`` queries needs two
+    blocks of ``window`` columns: scores are (H, S, 2 * window), not
+    (H, S, window + S). Float32 statistics; a tail whose length is no
+    multiple of the window is padded to one (a padded key lies after
+    every real query). Returns (1, H, S, Dh)."""
+    _, h, s, dh = hq.shape
+    hkv, w = k_all.shape[1], window
+    g, nb = h // hkv, -(-s // w)
+    pad = nb * w - s
+    if pad:
+        hq = jnp.pad(hq, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        k_all = jnp.pad(k_all, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v_all = jnp.pad(v_all, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    q = hq.reshape(hkv, g, nb, w, dh)
+
+    def bands(t):
+        blocks = t.reshape(hkv, nb + 1, w, t.shape[-1])
+        return jnp.concatenate([blocks[:, :-1], blocks[:, 1:]], axis=2)
+    k, v = bands(k_all), bands(v_all)                 # (Hkv, nb, 2w, Dh)
+    sc = jnp.einsum("ngbqd,nbkd->ngbqk", q, k,
+                    preferred_element_type=jnp.float32) * scale
+    r, c = jnp.arange(w)[:, None], jnp.arange(2 * w)[None, :]
+    held = (offset - w + jnp.arange(nb)[:, None] * w + c) >= 0    # (nb, 2w)
+    seen = ((c > r) & (c <= r + w))[None] & held[:, None, :]
+    sc = jnp.where(seen[None, None], sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
+    o = jnp.einsum("ngbqk,nbkd->ngbqd", p, v)
+    return o.reshape(1, h, nb * w, dh)[:, :, :s]
 
 
 class TransformerBlock(Module):

@@ -19,6 +19,15 @@ attention module and the store look inside one.
   ``(n_pages, 1, page_len, page_width)``; what it lacks (quantized pages,
   the speculative commit, the hand-off) it refuses by name with
   :class:`LatentPagesUnsupported`.
+- :class:`WindowPages`: a sliding-window layer's K and V as ONE RING a
+  slot, ``(n_slots, Hkv, ring, Dh)``, position ``p`` at ``p % ring``. No
+  table addresses it and the allocator counts no page for it: what a slot
+  keeps resident there is fixed by the window and the page length, not by
+  the context. It stands beside :class:`KVPages` in one ``state`` list
+  where a model's layers mix window and global attention
+  (``TransformerLM(layer_windows=...)``); what such a model cannot do yet
+  (prefix sharing, quantized pages, the speculative commit, the hand-off)
+  is refused by name with :class:`MixedStoresUnsupported`.
 
 A store is a ``NamedTuple`` of arrays: a pytree that crosses ``jax.jit``
 and is donated whole. What a store answers: ``write`` (one entry a row:
@@ -44,7 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.decode_attention import (dense_decode_attention,
+from ..ops.decode_attention import (_MASK, _finish, _merge_block,
+                                    dense_decode_attention,
                                     paged_decode_attention,
                                     paged_loop_attention)
 from ..ops.quant import (dequantize_page_blocks, pack_page_nibbles,
@@ -147,6 +157,30 @@ def block_unsupported(what: str) -> BlockGenerationUnsupported:
         "a block under a confidence rule, served only greedy, by "
         "InferenceEngine(paged=True, kv_dtype='f32') without "
         "speculation")
+
+
+class MixedStoresUnsupported(NotImplementedError):
+    """A serving path that cannot hold a window layer's ring beside a
+    global layer's pages (``TransformerLM(layer_windows=...)``)."""
+
+
+def mixed_unsupported(what: str) -> MixedStoresUnsupported:
+    """Window and global layers in one cache run through ONE path: the
+    exact paged pool without prefix sharing. What has not been carried
+    over to a ring says so by name."""
+    return MixedStoresUnsupported(
+        f"{what} cannot serve a model whose layers mix a sliding window "
+        "with global attention (TransformerLM(layer_windows=...)): a "
+        "window layer keeps a ring of its last entries a slot and no "
+        "pages, served only by InferenceEngine(paged=True, "
+        "kv_dtype='f32', prefix_share=False) without speculation")
+
+
+def table_pages(state):
+    """The page count the tables address: that of the first store that
+    keeps pages by table (a window layer's ring has none; where every
+    layer is one, nothing reads a page id)."""
+    return next((st.n_pages for st in state if st.n_pages is not None), 0)
 
 
 def dense_rows(g):
@@ -468,6 +502,51 @@ class KVPages(NamedTuple):
             ctx.idx, hk, hv, scale=scale, page_len=ctx.page_len,
             out_dtype=hv.dtype)
 
+    def attend_tail(self, ctx: PrefillCtx, hq, scale, block: int):
+        """A prompt's tail over the slot's resident pages, the tail's own
+        entries among them (``write_tail`` ran): queries hq (1, H, S, Dh)
+        at ``ctx.positions`` over the positions ``<=`` their own, read
+        ``block`` positions a trip with the online-softmax merge of
+        ``ops/decode_attention.py`` (float32 statistics, ``_MASK`` and
+        exact-zero probabilities). The trips follow ``ctx.offset``: as
+        many as hold a position of the prompt so far, so that no
+        (S, width) score array is ever formed and a short context pays
+        for no long one. Exact pages only. Returns (1, H, S, Dh)."""
+        pages_k, pages_v = self.k.pages, self.v.pages
+        _, hkv, page_len, dh = pages_k.shape
+        _, h, s, _ = hq.shape
+        g = h // hkv
+        per = max(1, block // page_len)
+        row = ctx.table_row
+        if row.shape[0] % per:
+            row = jnp.pad(row, (0, per - row.shape[0] % per))
+        q = hq.reshape(1, hkv, g, s, dh).astype(pages_k.dtype)
+        span = per * page_len
+
+        def rows(pages, pids):
+            return pages[pids].transpose(1, 0, 2, 3).reshape(1, hkv, span, dh)
+
+        def body(j, carry):
+            pids = jax.lax.dynamic_slice_in_dim(row, j * per, per)
+            pos_k = j * span + jnp.arange(span)
+            # past the prompt so far a page may be any page, or none, and
+            # hold anything (0 x NaN is NaN): those rows read as zeros
+            held = (pos_k < ctx.offset + ctx.true_len)[:, None]
+            k = jnp.where(held, rows(pages_k, pids), 0)
+            v = jnp.where(held, rows(pages_v, pids), 0)
+            sc = jnp.einsum("bngqd,bnkd->bngqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+            seen = (pos_k[None, :] <= ctx.positions[:, None])[None, None,
+                                                              None]
+            return _merge_block(carry, jnp.where(seen, sc, _MASK), v, seen)
+
+        carry = (jnp.full((1, hkv, g, s), _MASK, jnp.float32),
+                 jnp.zeros((1, hkv, g, s), jnp.float32),
+                 jnp.zeros((1, hkv, g, s, dh), jnp.float32))
+        trips = (ctx.offset + ctx.true_len + span - 1) // span
+        m, l, acc = jax.lax.fori_loop(0, trips, body, carry)
+        return _finish(m, l, acc, hq.dtype).reshape(1, h, s, dh)
+
     def write_block(self, hk, hv, dest, wo):
         """A pass's block a row, (B, Hkv, L, Dh) each, in place."""
         return self._both("write_block", hk, hv, dest, wo)
@@ -496,9 +575,15 @@ class KVPages(NamedTuple):
     def require(self, op: str) -> None:
         """Every operation is here, but a block step over quantized
         pages (its in-place rewrite of a block would round an entry more
-        than once)."""
-        if op == "block_step" and not isinstance(self.k, ExactSide):
+        than once) and a place beside a window layer's ring for them
+        (``attend_tail`` reads exact pages)."""
+        if isinstance(self.k, ExactSide):
+            return
+        if op == "block_step":
             raise block_unsupported("a quantized page pool "
+                                    "(kv_dtype='q8'/'q4')")
+        if op == "mixed":
+            raise mixed_unsupported("a quantized page pool "
                                     "(kv_dtype='q8'/'q4')")
 
     def export(self, idx, slot: int, valid_last: int, quantized=False):
@@ -509,3 +594,104 @@ class KVPages(NamedTuple):
     def adopt(self, k, v, idx, slot: int, valid_last: int, quantized=False):
         return self._both("adopt_quantized" if quantized else "adopt",
                           k, v, idx, slot, valid_last)
+
+
+class WindowPages(NamedTuple):
+    """A sliding-window layer's store: K and V, each ONE RING a slot,
+    ``(n_slots, Hkv, ring, Dh)`` in the model's dtype. The entry of
+    position ``p`` of the request in slot ``s`` lives at ``[s, :, p %
+    ring]``; ``ring`` is the window rounded up to whole pages plus one
+    page (the live window of a row lies across that many pages of
+    ``page_len`` positions wherever its newest position falls in one), so
+    what a slot keeps resident is fixed by the window and the page
+    length and does not grow with the context or with ``max_len``. No
+    page is allocated for it and no table addresses it.
+
+    A slot's ring is never cleared: an entry is read only where the
+    position it would hold, ``idx - (idx - r) % ring``, is one of the
+    row's last ``window`` and not below 0, and every such position was
+    written by the request that owns the slot now (a prefill chunk
+    writes its last ``ring`` real entries, a decode step its one), so an
+    earlier occupant's entries are never seen. The window itself is the
+    attention module's (``MultiHeadAttention(window=...)``): it is no
+    part of the pytree."""
+    k: Any
+    v: Any
+
+    @classmethod
+    def zeros(cls, shape, n_slots: int, window: int, page_len: int, dtype):
+        """``shape`` = (Hkv, Dh)."""
+        hkv, dh = shape
+        ring = (-(-window // page_len) + 1) * page_len
+        return cls(jnp.zeros((n_slots, hkv, ring, dh), dtype),
+                   jnp.zeros((n_slots, hkv, ring, dh), dtype))
+
+    @property
+    def n_pages(self):
+        return None                      # no table addresses a ring
+
+    @property
+    def ring(self) -> int:
+        return self.k.shape[2]
+
+    def write(self, hk, hv, ctx: DecodeCtx):
+        """One entry a row, hk / hv (B, Hkv, 1, Dh), at ``ctx.idx %
+        ring`` of row b's own ring; an inactive row writes nothing."""
+        n = self.k.shape[0]
+        dest = jnp.where(ctx.active, jnp.arange(n), n)
+        wo = ctx.idx % self.ring
+        return WindowPages(write_rows(self.k, dest, wo, hk[:, :, 0, :]),
+                           write_rows(self.v, dest, wo, hv[:, :, 0, :]))
+
+    def attend(self, ctx: DecodeCtx, hq, scale, window: int):
+        """A decode step's attention, this step's entry written: row b
+        over its own ring, an entry seen where the position it holds is
+        one of the row's last ``window``. hq (B, H, 1, Dh) -> the
+        same."""
+        r = jnp.arange(self.ring)
+        back = (ctx.idx[:, None] - r[None, :]) % self.ring       # (B, ring)
+        seen = (back < window) & (back <= ctx.idx[:, None])
+        return dense_decode_attention(hq, self.k, self.v, seen, scale=scale)
+
+    def prior(self, ctx: PrefillCtx, window: int):
+        """The ``window`` entries before a prompt's tail, positions
+        ``offset - window .. offset - 1`` of slot ``ctx.slot``: (k, v)
+        (1, Hkv, window, Dh). Positions below 0 read whatever the ring
+        holds there: the caller's mask hides them."""
+        at = (ctx.offset - window + jnp.arange(window)) % self.ring
+        take = lambda side: jnp.take(jax.lax.dynamic_index_in_dim(
+            side, ctx.slot, 0, keepdims=True), at, axis=2)
+        return take(self.k), take(self.v)
+
+    def write_tail(self, hk, hv, ctx: PrefillCtx):
+        """A prompt's tail, (1, Hkv, S, Dh) each: its last ``ring`` real
+        entries (an earlier one is seen by no position after the tail)
+        into the ring of slot ``ctx.slot``."""
+        s, ring, n = hk.shape[2], self.ring, self.k.shape[0]
+        keep = min(s, ring)
+        first = jnp.clip(ctx.true_len - keep, 0, s - keep)
+        i = first + jnp.arange(keep)
+        dest = jnp.where(i < ctx.true_len, ctx.slot, n)
+        wo = (ctx.offset + i) % ring
+
+        def put(side, h):
+            rows = jax.lax.dynamic_slice_in_dim(h[0], first, keep, axis=1)
+            return write_rows(side, dest, wo, jnp.moveaxis(rows, 1, 0))
+        return WindowPages(put(self.k, hk), put(self.v, hv))
+
+    def resident_bytes(self) -> int:
+        return self.k.nbytes + self.v.nbytes
+
+    #: what a ring has no form of yet
+    LACKS = {"commit": "speculative decoding (serve/spec)",
+             "export": "the disaggregated hand-off (serve/disagg)",
+             "adopt": "the disaggregated hand-off (serve/disagg)",
+             "block_step": "generation by blocks "
+                           "(TransformerLM(gen_block=...))",
+             "prefix_share": "prefix sharing (a shared page of a global "
+                             "layer says nothing of a window layer's "
+                             "ring)"}
+
+    def require(self, op: str) -> None:
+        if op in self.LACKS:
+            raise mixed_unsupported(self.LACKS[op])

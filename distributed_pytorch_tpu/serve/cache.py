@@ -37,7 +37,8 @@ import numpy as np
 
 from ..models.generate import (_sample, decode_step_slots,
                                prefill_partial, refuse_blocks, refuse_latent,
-                               spec_commit_slots, spec_verify_slots)
+                               refuse_mixed, spec_commit_slots,
+                               spec_verify_slots)
 from ..obs import trace as dpxtrace
 
 
@@ -139,6 +140,7 @@ class SlotPool:
                  window: Optional[int] = None):
         refuse_latent(model, "the contiguous SlotPool (paged=False)")
         refuse_blocks(model, "the contiguous SlotPool (paged=False)")
+        refuse_mixed(model, "the contiguous SlotPool (paged=False)")
         self.model = model
         self.n_slots = n_slots
         self.max_len = max_len

@@ -36,7 +36,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
-from ...models.generate import _check_attn_compatible, _model_window
+from ...models.generate import (_check_attn_compatible, _model_window,
+                                refuse_mixed)
 from ...obs import metrics as dpxmon
 from ...obs import trace as dpxtrace
 from ...runtime import env as dpxenv
@@ -117,6 +118,7 @@ class DisaggEngine:
         if cfg.n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {cfg.n_slots}")
         _check_attn_compatible(model, cfg.allow_custom_attn)
+        refuse_mixed(model, "the disaggregated hand-off (serve/disagg)")
         if _model_window(model) is not None:
             raise ValueError(
                 "disaggregated serving runs on the paged KV cache, "

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import (_check_attn_compatible, _model_window,
-                               block_unsupported)
+                               block_unsupported, refuse_mixed)
 from ..obs import metrics as dpxmon
 from ..obs import trace as dpxtrace
 from ..runtime import compile_cache
@@ -203,10 +203,16 @@ class InferenceEngine:
                 "in whole blocks")
         if cfg.paged:
             if self.window is not None:
+                # a width the model's attn_fn bakes in for every layer;
+                # a model TOLD its layers' windows
+                # (TransformerLM(layer_windows=...)) is the paged pool's
                 raise ValueError(
-                    "paged KV (serve/pages) does not support "
-                    "sliding-window models — the rolling O(window) "
-                    "SlotPool already bounds their memory (paged=False)")
+                    "paged KV (serve/pages) does not serve a "
+                    "sliding-window model whose width only its attn_fn "
+                    "carries — the rolling O(window) SlotPool bounds its "
+                    "memory (paged=False); the paged pool serves window "
+                    "layers the model was told of "
+                    "(TransformerLM(layer_windows=...))")
             page_len = (cfg.page_len if cfg.page_len is not None
                         else dpxenv.get("DPX_SERVE_PAGE_LEN"))
             n_pages = (cfg.n_pages if cfg.n_pages is not None
@@ -240,6 +246,7 @@ class InferenceEngine:
                 raise block_unsupported("speculative decoding (serve/spec)")
             if cfg.paged:
                 self.pool.require("commit")
+            refuse_mixed(model, "speculative decoding (serve/spec)")
             if self.window is not None:
                 raise ValueError(
                     "spec_decode does not support sliding-window "
@@ -552,7 +559,18 @@ class InferenceEngine:
                     # a block generator's marks carry its own counters
                     also += ("block_passes", "block_commits", "block_fills",
                              "blocks_emitted", "tokens_emitted")
-                with dpxtrace.span("serve.stats", **moe,
+                mixed = {}
+                if self.pool.window_layers:
+                    # window and global layers in one cache: what each
+                    # kind keeps and how long the contexts are
+                    # (whole numbers: a reader of the trace takes no
+                    # doubles from a mark)
+                    mixed = {k: int(round(out["pages"][k])) for k in (
+                        "kv_resident_bytes_global",
+                        "kv_resident_bytes_window", "pages_in_use",
+                        "context_tokens_max", "context_tokens_mean")}
+                    mixed["active_slots"] = out["active_slots"]
+                with dpxtrace.span("serve.stats", **moe, **mixed,
                                    **{k: out[k] for k in also}):
                     pass
         if self._spec is not None:

@@ -12,6 +12,23 @@ the tables and the prefix index count pages, so they serve every format
 unchanged; what a format cannot do (latent: quantized pages, the
 speculative commit, the hand-off) its store refuses by name, when the
 pool or the engine that needs it is built (:meth:`PagedSlotPool.require`).
+
+**Two kinds of store in one pool.** A model that mixes sliding-window and
+global layers (``TransformerLM(layer_windows=...)``) gets, in the same
+``state`` list, pages for its global layers and for each window layer ONE
+RING a slot (``nn.paged.WindowPages``: the window rounded up to whole
+pages plus one page, whatever ``max_len``). The allocator, the tables and
+``pages_in_use`` count the GLOBAL layers' pages only: a request takes
+``ceil(length / page_len)`` page ids, each id one page in every global
+layer, and nothing in a window layer, whose ring belongs to the slot. The
+counters tell the two apart (``kv_resident_bytes_global`` /
+``kv_resident_bytes_window`` in :meth:`PagedSlotPool.page_stats`). What
+such a pool cannot do yet it refuses by name
+(``nn.paged.MixedStoresUnsupported``) when it, or the engine that needs
+it, is built: prefix sharing (a shared page of a global layer says
+nothing of a window layer's ring), quantized pages, the speculative
+commit, the disaggregated hand-off, generation by blocks.
+
 Three things fall out of the indirection:
 
 - **prefix sharing**: full pages of a prompt are keyed in a radix index
@@ -162,6 +179,15 @@ class PagedSlotPool:
                     f"page_len ({page_len}) must be a multiple of the "
                     f"model's gen_block ({self.gen_block}): a block is "
                     "written into one page")
+        # window layers' rings beside global layers' pages: the tables
+        # and the allocator count pages (the global layers'), a ring is
+        # its slot's; what a ring has no form of is refused here by name
+        self.window_layers = [i for i, st in enumerate(self.state)
+                              if st.n_pages is None]
+        if self.window_layers:
+            self.require("mixed")
+            if prefix_share:
+                self.require("prefix_share")
         # what an expert layer counts in a decode step, summed on the
         # device and read only by stats(): tokens routed, experts with a
         # token, the fullest expert's tokens, decode steps. None for a
@@ -594,18 +620,37 @@ class PagedSlotPool:
 
     def kv_pool_bytes(self) -> int:
         """Total resident KV footprint: everything the stores keep
-        (pages, scales, tail pages), all layers. Static for a given
-        config — this is the denominator of the capacity-per-byte
-        story."""
+        (pages, scales, tail pages, window layers' rings), all layers.
+        Static for a given config — this is the denominator of the
+        capacity-per-byte story."""
         return sum(a.nbytes for a in jax.tree.leaves(self.state))
+
+    def kv_resident_bytes(self) -> Tuple[int, int]:
+        """``(global, window)``: the bytes of the stores that keep pages
+        by table, which grow with ``n_pages``, and of the window layers'
+        rings, ``n_slots`` times a ring whatever ``max_len``."""
+        window = sum(self.state[i].resident_bytes()
+                     for i in self.window_layers)
+        return (sum(st.resident_bytes() for st in self.state) - window,
+                window)
 
     def bytes_per_resident_token(self) -> float:
         """Pool bytes (pages + scales; tails are per-slot, not
-        per-resident-page) per token position the pool can hold. The
-        serve_bench capacity arm gates on the f32/q8 ratio of this —
-        a deterministic storage-layout fact, not a runtime sample."""
-        return sum(st.resident_bytes() for st in self.state) \
+        per-resident-page, and so is a window layer's ring) per token
+        position the pool can hold. The serve_bench capacity arm gates
+        on the f32/q8 ratio of this — a deterministic storage-layout
+        fact, not a runtime sample."""
+        return self.kv_resident_bytes()[0] \
             / float(self.n_pages * self.page_len)
+
+    def context_stats(self) -> Tuple[int, float]:
+        """``(longest, mean)`` context among the slots that hold one
+        (0, 0.0 where none does): what a global layer's decode step reads
+        follows these, a window layer's does not."""
+        held = self.lengths[self.lengths > 0]
+        if not held.size:
+            return 0, 0.0
+        return int(held.max()), float(held.mean())
 
     def moe_stats(self) -> Optional[Dict]:
         """The expert layers' counters over every decode step so far
@@ -621,6 +666,8 @@ class PagedSlotPool:
                 "moe_kernel_matmuls": self.compiles.moe_kernel_matmuls}
 
     def page_stats(self) -> Dict:
+        in_global, in_window = self.kv_resident_bytes()
+        longest, mean = self.context_stats()
         return {"n_pages": self.n_pages,
                 "page_len": self.page_len,
                 "decode_attention_kernel_layers":
@@ -628,6 +675,11 @@ class PagedSlotPool:
                 "kv_dtype": self.kv_dtype,
                 "kv_bits": self.kv_bits(),
                 "kv_pool_bytes": self.kv_pool_bytes(),
+                "kv_resident_bytes_global": in_global,
+                "kv_resident_bytes_window": in_window,
+                "window_layers": len(self.window_layers),
+                "context_tokens_max": longest,
+                "context_tokens_mean": mean,
                 "bytes_per_resident_token": self.bytes_per_resident_token(),
                 "free_pages": self.pool.free_pages,
                 "pages_in_use": self.pool.pages_in_use,

@@ -136,6 +136,8 @@ def test_one_trace_serves_every_call_site_of_a_shape():
      True),
     ("a width that is not whole lanes", (64, 96), (8, 96, 128),
      jnp.bfloat16, False),
+    ("the largest slab inside the VMEM budget", (1024, 6144),
+     (16, 6144, 2048), jnp.bfloat16, True),
     ("a slab over the VMEM budget", (64, 8192), (8, 8192, 2048),
      jnp.bfloat16, False),
     ("float32 operands", (2048, 2048), (128, 2048, 768), jnp.float32,

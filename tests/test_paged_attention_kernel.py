@@ -389,6 +389,9 @@ CHIP_SHAPES = [
     pytest.param(64, 2, 12, 16, 128, jnp.bfloat16, id="starcoder2-page16"),
     pytest.param(64, 2, 12, 64, 32, jnp.bfloat16, id="starcoder2-page64"),
     pytest.param(8, 4, 1, 8, 16, jnp.float32, id="f32-mha-page8"),
+    # a global layer beside window layers: rows of up to 33 280 tokens,
+    # 8 query heads a KV head (520 pages a row: 66 KB of tables in SMEM)
+    pytest.param(32, 8, 8, 64, 520, jnp.bfloat16, id="long-rows-page64"),
 ]
 
 
@@ -478,7 +481,9 @@ GROUPED_SHAPES = [
     pytest.param(64, 256, 3584, 1024, id="xing4-decode-step"),
     pytest.param(64, 4096, 1024, 3584, id="xing4-chunk-down"),
     pytest.param(8, 40, 256, 128, id="rows-no-whole-tile"),
-    pytest.param(4, 512, 4096, 2048, id="the-largest-slab-that-fits"),
+    pytest.param(4, 512, 4096, 2048, id="a-slab-of-16-MiB"),
+    pytest.param(16, 1024, 6144, 2048, id="kexaone-chunk-gate"),
+    pytest.param(16, 256, 2048, 6144, id="kexaone-decode-down"),
 ]
 
 
