@@ -330,19 +330,27 @@ class TransformerLM(Module):
         ``load`` (layers, n_routed): ``moe_pairs_here`` (the pairs sent to
         the experts held here, all layers), ``moe_load_max`` and
         ``moe_load_mean`` (the largest and the mean load among the held
-        experts of a layer) and ``moe_bias_abs_max`` (the largest router
-        bias, before this step's update)."""
+        experts of a layer), ``moe_bias_abs_max`` (the largest router
+        bias, before this step's update), and how far a layer that holds
+        a share got through its sorted pairs: ``moe_dispatch_blocks_run``
+        of ``moe_dispatch_blocks`` (``DroplessMoE.dispatch_blocks``, all
+        layers; a layer's row of ``load`` sums to the pairs it routed)."""
         ffn = next(b.ffn for b in self.blocks + (
             [self.mtp["block"]] if self.mtp else [])
             if getattr(b, "_sparse", False))
-        here = load[:, ffn.first:ffn.first + ffn.count].astype(jnp.float32)
+        held = load[:, ffn.first:ffn.first + ffn.count]
+        here = held.astype(jnp.float32)
+        run, blocks = ffn.dispatch_blocks(jnp.sum(load, -1),
+                                          jnp.sum(held, -1))
         biases = [x for x, m in zip(
             jax.tree_util.tree_leaves(params),
             jax.tree_util.tree_leaves(self.router_bias_mask(params))) if m]
         return {"moe_pairs_here": jnp.sum(here),
                 "moe_load_max": jnp.max(here),
                 "moe_load_mean": jnp.mean(here),
-                "moe_bias_abs_max": jnp.max(jnp.abs(jnp.stack(biases)))}
+                "moe_bias_abs_max": jnp.max(jnp.abs(jnp.stack(biases))),
+                "moe_dispatch_blocks_run": jnp.sum(run).astype(jnp.float32),
+                "moe_dispatch_blocks": jnp.sum(blocks).astype(jnp.float32)}
 
     def balance_router_bias(self, params: Params, load, speed: float):
         """``params`` with every router bias moved by its layer's rule

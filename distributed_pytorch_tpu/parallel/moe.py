@@ -16,7 +16,10 @@ scores with a bias-corrected top-k over all routed experts, the chosen
 of it, the Mosaic kernel of ``ops/grouped_matmul_kernel.py``, which
 copies each touched expert's weights once) over the experts this chip
 holds, the results
-gathered back and weighted, one shared SwiGLU expert added. No capacity,
+gathered back and weighted (a chip that holds a SHARE of the experts
+gathers, multiplies and adds back only the blocks of sorted pairs that
+hold a pair of its own: ``DroplessMoE._pairs_here``), one shared SwiGLU
+expert added. No capacity,
 no token dropped, none padded into an expert it did not choose, and no
 one-hot tensor: a decode step reads only the experts its batch touches.
 Published sparse models route this way and a served model must compute
@@ -65,6 +68,7 @@ batch-dependence caveat).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -348,6 +352,186 @@ def _grouped_matmul_bwd(res, g):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
+#: Rows of one block of the sorted pairs. A layer that holds a share of
+#: the experts walks its sorted pairs in blocks of this many rows and
+#: stops at the last block that holds a pair of its own. Chosen on the
+#: chip (``benchmarks/moe_dispatch_sweep.py``; PERF.md Findings, PR 44).
+_BLOCK_ROWS = 1024
+
+
+def _blocks(rows: int) -> Tuple[int, int]:
+    """``(rows a block, blocks)`` that ``rows`` sorted pairs are walked
+    in: one block of them all up to ``_BLOCK_ROWS``."""
+    size = min(_BLOCK_ROWS, rows)
+    return size, -(-rows // size)
+
+
+def _each_block(n, rows: int, body, carry):
+    """``body(size, first_row, live, carry) -> carry`` for every block of
+    ``rows`` rows that holds a row before ``n`` (int32, traced), in
+    order; ``live`` (size, 1) says which of the block's rows lie before
+    ``n``. The trip count is ``n``'s: a block wholly past it costs
+    nothing. One block is run as it stands, without a loop."""
+    size, blocks = _blocks(rows)
+
+    def step(b, carry):
+        at = b * size
+        live = (at + jnp.arange(size, dtype=jnp.int32) < n)[:, None]
+        return body(size, at, live, carry)
+
+    if blocks == 1:
+        return step(0, carry)
+    return jax.lax.fori_loop(0, (n + size - 1) // size, step, carry)
+
+
+def _cut(a, at, size):
+    return jax.lax.dynamic_slice_in_dim(a, at, size)
+
+
+def _paste(a, block, at):
+    return jax.lax.dynamic_update_slice_in_dim(a, block.astype(a.dtype), at, 0)
+
+
+# The three walks below hand on (R, ...) buffers of which only the rows
+# before ``n`` are results. Every other row is what a grouped matmul's
+# rows past its last group are, not a result and never read as one: an
+# unrun block is not written, so it holds what the buffer held, zeros
+# (:func:`_fresh`) or the operand the derivative is written over.
+
+def _fresh(shape, dtype, n):
+    """A buffer for a walk to fill, zeros. The zero is ``n < 0``, which
+    the compiler cannot fold: a constant's broadcast, like ``lax.empty``,
+    depends on nothing, and XLA then makes every layer's buffer at the
+    top of the step program and keeps them all to their walks (nine
+    times 256 MiB in the JoyAI step: PERF.md Findings, PR 44)."""
+    return jnp.full(shape, (n < 0).astype(dtype))
+
+
+def _gather_blocks(src, idx, wts, n, over=None):
+    """``src[idx[i]] * wts[i]`` in the rows ``i < n`` of an (R, D)
+    buffer, ``src``'s type without ``wts`` and float32 with them. With
+    ``over`` (R, D) float32 the rows are written over it, and the
+    products ``<src[idx[i]], over[i]>`` (R,) float32, zeros from ``n``
+    on, come back beside them."""
+    rows = idx.shape[0]
+
+    def body(size, at, live, carry):
+        out, dots = carry
+        got = jnp.take(src, _cut(idx, at, size), axis=0, mode="clip")
+        if over is not None:
+            dot = jnp.sum(got.astype(jnp.float32) * _cut(out, at, size), -1)
+            dots = _paste(dots, jnp.where(live[:, 0], dot, 0.0), at)
+        if wts is not None:
+            got = got.astype(jnp.float32) * _cut(wts, at, size)[:, None]
+        return _paste(out, got, at), dots
+
+    if over is not None:
+        return _each_block(n, rows, body,
+                           (over, jnp.zeros((rows,), jnp.float32)))
+    return _each_block(n, rows, body, (_fresh(
+        (rows, src.shape[1]),
+        src.dtype if wts is None else jnp.float32, n), None))[0]
+
+
+def _scatter_blocks(rows, idx, wts, n, t: int):
+    """(t, D) float32: ``rows[i] * wts[i]`` added onto row ``idx[i]``
+    for every ``i < n``."""
+    def body(size, at, live, acc):
+        new = _cut(rows, at, size).astype(jnp.float32)
+        if wts is not None:
+            new = new * _cut(wts, at, size)[:, None]
+        return acc.at[_cut(idx, at, size)].add(
+            jnp.where(live, new, 0.0), mode="promise_in_bounds")
+
+    return _each_block(n, idx.shape[0], body,
+                       jnp.zeros((t, rows.shape[1]), jnp.float32))
+
+
+@jax.custom_vjp
+def _take_rows(src, idx, n):
+    """The dispatch of a layer that holds a share: ``src[idx[i]]`` in
+    the rows ``i < n`` of an (R, D) buffer, gathered a block at a time
+    and only in the blocks that hold such a row. Its transpose is
+    :func:`_add_rows` over the same blocks."""
+    return _gather_blocks(src, idx, None, n)
+
+
+def _take_rows_fwd(src, idx, n):
+    # a (T, 0) array of src's type: what the transpose has to know of it
+    return _gather_blocks(src, idx, None, n), (src[:, :0], idx, n)
+
+
+def _take_rows_bwd(res, g):
+    like, idx, n = res
+    return (_scatter_blocks(g, idx, None, n, like.shape[0]).astype(
+        like.dtype), None, None)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _add_rows(rows, idx, wts, n, t: int):
+    """The combine of a layer that holds a share: (t, D) float32, each
+    sorted pair ``i < n`` of ``rows`` (R, D) float32 weighted by
+    ``wts[i]`` and added onto its token ``idx[i]``, a block at a time and
+    only in the blocks that hold such a pair. No inverse permutation and
+    no gather back. The derivative for ``rows`` is written over them."""
+    return _scatter_blocks(rows, idx, wts, n, t)
+
+
+def _add_rows_fwd(rows, idx, wts, n, t):
+    return _scatter_blocks(rows, idx, wts, n, t), (rows, idx, wts, n)
+
+
+def _add_rows_bwd(t, res, g):
+    rows, idx, wts, n = res
+    d_rows, d_wts = _gather_blocks(g, idx, wts, n, over=rows)
+    return d_rows, None, d_wts, None
+
+
+_add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
+
+
+def _map_blocks(fn, n, arrays):
+    like = jax.eval_shape(fn, *arrays)
+
+    def body(size, at, live, out):
+        return _paste(out, fn(*(_cut(a, at, size) for a in arrays)), at)
+
+    return _each_block(n, arrays[0].shape[0], body,
+                       _fresh(like.shape, like.dtype, n))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _map_rows(fn, n, arrays):
+    """``fn(*arrays)`` in the rows ``i < n`` of an (R, ...) buffer:
+    ``fn`` acts on rows alone and is run a block at a time, only in the
+    blocks that hold such a row. Its derivative is ``fn``'s own, block
+    by block, written over ``arrays``."""
+    return _map_blocks(fn, n, arrays)
+
+
+def _map_rows_fwd(fn, n, arrays):
+    return _map_blocks(fn, n, arrays), (n, arrays)
+
+
+def _map_rows_bwd(fn, res, g):
+    n, arrays = res
+
+    def body(size, at, live, grads):
+        # ``grads`` holds the operands until a block's derivative is
+        # written over them
+        _, pull = jax.vjp(fn, *(_cut(a, at, size) for a in grads))
+        return tuple(_paste(a, d, at)
+                     for a, d in zip(grads, pull(_cut(g, at, size))))
+
+    return None, _each_block(n, arrays[0].shape[0], body, arrays)
+
+
+_map_rows.defvjp(_map_rows_fwd, _map_rows_bwd)
+
+
 class DroplessMoE(Module):
     """Dropless token-choice experts: x (..., D) -> y (..., D).
 
@@ -366,8 +550,9 @@ class DroplessMoE(Module):
 
     ``held = (first, count)`` says which
     experts this chip holds (all of them by default): it computes their
-    part of the result, pairs routed elsewhere cost nothing here, and
-    nothing stands in for the absent chips. The shared expert runs on
+    part of the result, pairs routed elsewhere cost the sort and nothing
+    after it (:meth:`_pairs_here`), and nothing stands in for the absent
+    chips. The shared expert runs on
     every token (every chip computes it alike, so a sum over shares
     counts it once). Scopes: ``moe`` > ``route``, ``dispatch``,
     ``experts``, ``shared``, ``combine``.
@@ -458,10 +643,25 @@ class DroplessMoE(Module):
             order = jnp.argsort(key)               # stable
             sizes = jnp.sum(key[:, None] == jnp.arange(c)[None, :], axis=0,
                             dtype=jnp.int32)
+        dot = lambda a, b: grouped_matmul(a, b, sizes)
+        e = params["experts"]
+        # a fact of the layer's construction, nothing a caller sets: a
+        # layer that holds every expert has no pair to skip
+        form = self._every_pair if c == self.n_routed else self._pairs_here
+        y = form(xt, e, dot, order, here, w, sizes)
+        counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                            jnp.max(sizes)]).astype(jnp.int32)
+        return y, counts, load
+
+    def _every_pair(self, xt, e, dot, order, here, w, sizes):
+        """The sorted pairs through the experts and back, for a layer
+        that holds EVERY routed expert: no pair is elsewhere, so all
+        ``T * k`` rows are gathered, and gathered back into token order
+        (a gather costs a third of a scatter-add over the same rows)."""
+        t, k = xt.shape[0], self.top_k
+        with jax.named_scope("dispatch"):
             xs = jnp.take(xt, order // k, axis=0)              # (T*k, D)
         with jax.named_scope("experts"):
-            e = params["experts"]
-            dot = lambda a, b: grouped_matmul(a, b, sizes)
             h = jax.nn.silu(dot(xs, e["gate"])) * dot(xs, e["up"])
             ys = dot(h.astype(xt.dtype), e["down"])
         with jax.named_scope("combine"):
@@ -472,11 +672,50 @@ class DroplessMoE(Module):
             back = jnp.zeros((t * k,), jnp.int32).at[order].set(
                 jnp.arange(t * k, dtype=jnp.int32))
             pairs = jnp.take(ys, back, axis=0).reshape(t, k, self.dim)
-            y = jnp.sum(pairs * jnp.where(here.reshape(t, k), w, 0.0)[..., None],
-                        axis=1)
-        counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
-                            jnp.max(sizes)]).astype(jnp.int32)
-        return y, counts, load
+            return jnp.sum(
+                pairs * jnp.where(here.reshape(t, k), w, 0.0)[..., None],
+                axis=1)
+
+    def _pairs_here(self, xt, e, dot, order, here, w, sizes):
+        """The same, under the same signature, for a layer that holds a
+        SHARE of the experts: the sort put the ``n = sum(sizes)`` pairs
+        that are here first, so rows come in, pass the elementwise and go
+        back onto their tokens a block of ``_BLOCK_ROWS`` sorted pairs at
+        a time, and a block wholly past
+        ``n`` is never worked on (:func:`_each_block`). The grouped
+        matmuls stay one call a projection over the whole buffer: they
+        read each touched expert's weights once and no row past ``n``.
+        Dropless at any routing: with every pair here every block runs.
+        A token's pairs are summed in float32, in the sorted order."""
+        t, k = xt.shape[0], self.top_k
+        size, blocks = _blocks(t * k)
+        pad = lambda a: jnp.pad(a, (0, size * blocks - t * k))
+        with jax.named_scope("dispatch"):
+            n = jnp.sum(sizes)
+            tok = pad(order // k)
+            xs = _take_rows(xt, tok, n)
+        with jax.named_scope("experts"):
+            h = _map_rows(
+                lambda g, u: (jax.nn.silu(g) * u).astype(xt.dtype), n,
+                (dot(xs, e["gate"]), dot(xs, e["up"])))
+            ys = dot(h, e["down"])
+        with jax.named_scope("combine"):
+            return _add_rows(ys, tok, pad(jnp.take(w.reshape(-1), order)),
+                             n, t)
+
+    def dispatch_blocks(self, pairs, pairs_here, calls: int = 1):
+        """``(blocks run, blocks)`` of ``calls`` calls that routed
+        ``pairs`` pairs each, ``pairs_here`` of them in all to the held
+        experts (ints or arrays): how much of the sorted buffers
+        dispatch, elementwise and combine worked on. All of it where
+        every expert is held, and where a call is one block, which
+        always runs. Of several calls of several blocks each only the
+        fewest blocks that many pairs can lie in are known."""
+        blocks = calls * -(-pairs // _BLOCK_ROWS)
+        if self.count == self.n_routed:
+            return blocks, blocks
+        return jnp.maximum(-(-pairs_here // _BLOCK_ROWS),
+                           calls * (blocks == calls)), blocks
 
     def apply(self, params: Params, x, *, row_mask=None, stats=None, **_):
         """-> ``(y, load)``: the call's pairs per expert (n_routed,)

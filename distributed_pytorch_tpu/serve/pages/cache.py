@@ -655,15 +655,25 @@ class PagedSlotPool:
     def moe_stats(self) -> Optional[Dict]:
         """The expert layers' counters over every decode step so far
         (one device-to-host read, made here and nowhere else), or None
-        for a model without expert layers."""
+        for a model without expert layers. The blocks of sorted pairs
+        those steps worked on come from the same sums
+        (``DroplessMoE.dispatch_blocks``)."""
         if self.moe_counts is None:
             return None
         routed, touched, fullest, steps = (
             int(v) for v in np.asarray(self.moe_counts))
+        # a pass routes every slot's pairs through each expert layer
+        ffn = next(blk.ffn for blk in self.model.blocks
+                   if hasattr(getattr(blk, "ffn", None), "routed"))
+        run, blocks = ffn.dispatch_blocks(
+            self.n_slots * (self.gen_block or 1) * ffn.top_k, routed,
+            calls=steps * self.moe_layers)
         return {"moe_tokens_routed": routed, "moe_experts_touched": touched,
                 "moe_tokens_max_expert": fullest, "moe_decode_steps": steps,
                 "moe_layers": self.moe_layers,
-                "moe_kernel_matmuls": self.compiles.moe_kernel_matmuls}
+                "moe_kernel_matmuls": self.compiles.moe_kernel_matmuls,
+                "moe_dispatch_blocks_run": int(run),
+                "moe_dispatch_blocks": blocks}
 
     def page_stats(self) -> Dict:
         in_global, in_window = self.kv_resident_bytes()
