@@ -38,9 +38,10 @@ import jax.numpy as jnp
 from ..nn.attention import block_causal_mask, dense_attention
 from ..nn.paged import (BlockCtx, BlockGenerationUnsupported,  # noqa: F401
                         DecodeCtx, LatentPagesUnsupported,
-                        MixedStoresUnsupported, PrefillCtx, VerifyCtx,
-                        block_unsupported, latent_unsupported,
-                        mixed_unsupported, table_pages)
+                        MixedStoresUnsupported, MixerStoresUnsupported,
+                        PrefillCtx, VerifyCtx, block_unsupported,
+                        latent_unsupported, mixed_unsupported,
+                        mixers_unsupported, table_pages)
 from ..ops.decode_attention import (blockwise_decode_attention,
                                     dense_decode_attention)
 from .transformer import TransformerLM
@@ -328,7 +329,8 @@ def decode_step_slots(model: TransformerLM, params: Params, ks, vs,
 def decode_step_slots_paged(model: TransformerLM, params: Params, state,
                             tables, lengths, tokens, active, *,
                             page_len: int, blockwise: bool = True,
-                            moe_stats=None) -> Tuple[jnp.ndarray, list]:
+                            moe_stats=None, sel_stats=None
+                            ) -> Tuple[jnp.ndarray, list]:
     """One decode step over a PAGED slot pool (``serve/pages/``).
 
     ``state`` is a list, one page store a layer, as each block's
@@ -337,12 +339,16 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
     ``[c | k_r]`` entries, or a window layer's ring a slot beside the
     global layers' pages (``tables`` address the pages; a ring finds its
     entries from the row and ``lengths``, whose last ``window`` are its
-    live positions). This function never looks inside one: each
+    live positions), a linear-attention layer's one state a slot, or a
+    sparse-attention layer's pages beside its slot's compressed keys
+    (row b of a state and of the compressed keys is slot b). This
+    function never looks inside one: each
     layer's store goes to the block's own ``decode_paged``, which writes
     this step's entry and attends, and comes back written. Under
     hyper-connections the residual streams travel as (B, 1, streams, D).
     ``moe_stats``: a list that every expert layer appends its counts
-    (3,) to.
+    (3,) to; ``sel_stats``: one that every sparse-attention layer appends
+    its (blocks chosen, blocks resident) to.
 
     The paged counterpart of :func:`decode_step_slots`: instead of each
     slot owning a contiguous (max_len) cache row, the entries live in a
@@ -378,7 +384,7 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
     idx = lengths
     n_pages = table_pages(state)
     width = tables.shape[1] * page_len
-    x = model.tok.apply(params["tok"], tokens[:, None])       # (B,1,D)
+    x = model.embed(params, tokens[:, None])                  # (B,1,D)
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], idx[:, None])
     x = model.streams_in(x)
@@ -394,7 +400,7 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
     ctx = DecodeCtx(tables=tables, idx=idx, dest=dest, wo=wo, active=active,
                     pos_mask=pos_mask, write_mask=write_mask,
                     page_len=page_len, blockwise=blockwise,
-                    moe_stats=moe_stats)
+                    moe_stats=moe_stats, sel_stats=sel_stats)
     state = list(state)
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
@@ -464,7 +470,7 @@ def block_step_slots_paged(model: TransformerLM, params: Params, state,
 
 def prefill_partial_paged(model: TransformerLM, params: Params, state,
                           table_row, tokens, offset, true_len, slot=0, *,
-                          page_len: int, moe_stats=None
+                          page_len: int, moe_stats=None, dense=None
                           ) -> Tuple[jnp.ndarray, list]:
     """Prefill the TAIL of a prompt into pool pages, attending over a
     page-resident shared prefix (``serve/pages/``).
@@ -492,23 +498,28 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
 
     Each layer's store (``state``, see :func:`decode_step_slots_paged`)
     goes to the block's own ``prefill_paged``; the tail's pad rows are
-    left out of an expert layer's dispatch. Returns ``(logits (1, vocab)
-    at the last real position, new state)``."""
+    left out of an expert layer's dispatch. ``dense`` (a traced bool, for
+    a model with sparse-attention layers): the whole prompt is shorter
+    than their ``dense_len``. Returns ``(logits (1, vocab) at the last
+    real position, new state)``."""
     b, s = tokens.shape
     n_pages = table_pages(state)
     width = table_row.shape[0] * page_len
     offset = jnp.asarray(offset, jnp.int32)
     true_len = jnp.asarray(true_len, jnp.int32)
     positions = offset + jnp.arange(s)
-    x = model.tok.apply(params["tok"], tokens)
+    x = model.embed(params, tokens)
     if getattr(model, "pos", None) is not None:
         x = x + model.pos.apply(params["pos"], positions)
     x = model.streams_in(x)
-    if getattr(model, "layer_windows", None) is not None:
+    if (getattr(model, "layer_windows", None) is not None
+            or getattr(model, "layer_mixers", None) is not None):
         # window and global layers in one cache: a window layer attends
         # in bands over its ring's last entries and the tail, a global
         # layer over its resident pages in blocks that follow ``offset``
-        # (``nn/attention.py``): no (S, W + S) array is formed
+        # (``nn/attention.py``): no (S, W + S) array is formed. Nor is
+        # one for linear- and sparse-attention layers, which read a state
+        # and the pages under their own selection
         mask = None
     else:
         # attention mask over [prefix pages | tail]: prefix columns valid
@@ -535,7 +546,7 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
                      true_len=true_len, slot=jnp.asarray(slot, jnp.int32),
                      dest=dest, dest_off=dest_off, mask=mask,
                      row_mask=jnp.arange(s) < true_len, width=width,
-                     moe_stats=moe_stats)
+                     moe_stats=moe_stats, dense=dense)
     state = list(state)
     for i, blk in enumerate(model.blocks):
         with jax.named_scope("blocks"):
@@ -775,6 +786,16 @@ def refuse_mixed(model, what: str):
         raise mixed_unsupported(what)
 
 
+def refuse_mixers(model, what: str):
+    """For a path that keeps pages alone, or one layout for every layer:
+    a model of linear- and sparse-attention layers
+    (``TransformerLM(layer_mixers=...)``) is served by the paged pool
+    alone, which holds a state a slot and compressed keys beside pages
+    (``nn/paged.py`` ``StatePages``, ``SelectedPages``)."""
+    if getattr(model, "layer_mixers", None) is not None:
+        raise mixers_unsupported(what)
+
+
 def _model_window(model: TransformerLM) -> Optional[int]:
     """The sliding-window width a contiguous cache rolls over, or None.
 
@@ -865,6 +886,8 @@ def make_generate_fn(model: TransformerLM, max_new: int, *,
                          "cache)")
     refuse_mixed(model, "generate() (one contiguous cache layout for "
                         "every layer)")
+    refuse_mixers(model, "generate() (one contiguous cache layout for "
+                         "every layer)")
     window = _model_window(model)
 
     def fn(params, prompt, rng):

@@ -21,6 +21,8 @@ from ..nn.block import Block
 from ..nn.core import (Embedding, GatedMLP, LayerNorm, Linear, Module, Params,
                        RMSNorm)
 from ..nn.latent import LatentAttention
+from ..nn.linear_attention import LightningAttention
+from ..nn.sparse_attention import Selection, SparseAttention
 
 #: Named per-layer rematerialization policies (docs/compute.md).
 #: ``none``  — save every activation (fastest step, most HBM);
@@ -118,6 +120,23 @@ class TransformerLM(Module):
     layers rotate q and k; the default rotates every layer. Such a model
     is served by the paged engine alone (``serve/pages/cache.py``).
 
+    ``layer_mixers`` (blocks made of parts) says what mixes the positions
+    of each layer in place of multi-head attention: ``"linear"``
+    (``nn.linear_attention.LightningAttention(dim, n_heads, head_dim=...,
+    **linear)``: one decaying state a head, no cache that grows) or
+    ``"sparse"`` (``nn.sparse_attention.SparseAttention(...,
+    select=Selection(**sparse), **sparse_kw)``: grouped-query attention
+    over blocks it chooses, ``sparse`` the sizes of the choice, the other
+    keys of the dict the module's own). ``pos="rope"`` makes the linear
+    layers rotate (give ``linear=dict(rope=False)`` for none); a sparse
+    layer rotates only when told (``sparse=dict(rope=True)``). Served,
+    a linear layer keeps one state a slot and a sparse layer its pages and
+    compressed keys (``nn.paged.StatePages`` / ``SelectedPages``), through
+    the paged engine alone. ``emb_scale`` multiplies the embedding,
+    ``branch_scale`` what each sublayer adds to the residual sum, and the
+    final hidden states are divided by ``logit_scale`` before the head
+    (muP's three scalings).
+
     ``mtp=1`` adds one multi-token-prediction module (DeepSeek-V3 section
     2.2; needs blocks made of parts and the plain residual sum): position
     ``i`` merges the embedding of token ``i + 1`` with the last block's
@@ -145,7 +164,12 @@ class TransformerLM(Module):
                  gen_block: Optional[int] = None,
                  mask_id: Optional[int] = None,
                  layer_windows: Optional[Sequence[Optional[int]]] = None,
-                 layer_rope: Optional[Sequence[bool]] = None):
+                 layer_rope: Optional[Sequence[bool]] = None,
+                 layer_mixers: Optional[Sequence[str]] = None,
+                 linear: Optional[dict] = None,
+                 sparse: Optional[dict] = None,
+                 emb_scale: float = 1.0, branch_scale: float = 1.0,
+                 logit_scale: float = 1.0):
         if pos not in ("learned", "rope", "none"):
             raise ValueError(f"pos must be learned|rope|none, got {pos!r}")
         if attention not in ("mha", "latent"):
@@ -179,6 +203,35 @@ class TransformerLM(Module):
                 if per is not None and len(per) != n_layers:
                     raise ValueError(f"{name} must have one entry for each "
                                      f"of {n_layers} layers, got {per}")
+        if layer_mixers is not None:
+            if (attention != "mha" or gen_block is not None or mtp
+                    or layer_windows is not None or layer_rope is not None
+                    or hyper_connections or pos == "learned"):
+                raise ValueError(
+                    "layer_mixers describes a model that yields a token a "
+                    "step over the plain residual sum, without windows of "
+                    "its own (attention='mha', gen_block=None, mtp=0, no "
+                    "layer_windows / layer_rope / hyper_connections, "
+                    "pos='rope' or 'none')")
+            if (len(layer_mixers) != n_layers
+                    or set(layer_mixers) - {"linear", "sparse"}):
+                raise ValueError(
+                    f"layer_mixers must name linear|sparse for each of "
+                    f"{n_layers} layers, got {tuple(layer_mixers)}")
+            if "sparse" in layer_mixers and not sparse:
+                raise ValueError(
+                    "layer_mixers names a sparse-attention layer: give "
+                    "sparse=dict(kernel=..., stride=..., block=..., "
+                    "topk=..., init_blocks=..., window=..., dense_len=...)")
+        elif linear or sparse:
+            raise ValueError("linear= / sparse= describe the layers that "
+                             "layer_mixers names")
+        #: "linear" or "sparse" a layer, or None for a model of multi-head
+        #: (or latent) attention: what ``models.generate.refuse_mixers``
+        #: and the paged pool read
+        self.layer_mixers = None if layer_mixers is None \
+            else tuple(layer_mixers)
+        self.emb_scale, self.logit_scale = emb_scale, logit_scale
         #: a width a layer (None: global), or None for a model that was
         #: not told: what ``models.generate.layer_windows`` reads
         self.layer_windows = None if layer_windows is None \
@@ -221,7 +274,8 @@ class TransformerLM(Module):
                       gen_block=gen_block)
         from_parts = (block_kinds is not None or attention != "mha"
                       or norm != "layer" or self.streams > 0 or bool(mha)
-                      or layer_windows is not None or layer_rope is not None)
+                      or layer_windows is not None or layer_rope is not None
+                      or layer_mixers is not None or branch_scale != 1.0)
         if not from_parts:
             self.blocks = [
                 TransformerBlock(dim, n_heads, mlp_ratio, causal=True,
@@ -240,7 +294,25 @@ class TransformerLM(Module):
                 raise ValueError("block_kinds names an expert layer: give "
                                  "moe=dict(n_routed=..., width=..., top_k=...)")
 
+            select = {k: v for k, v in (sparse or {}).items()
+                      if k in Selection._fields}
+
+            def make_mixer(kind):
+                hd = head_dim if head_dim is not None else dim // n_heads
+                if kind == "linear":
+                    return LightningAttention(
+                        dim, n_heads, head_dim=hd, rope_base=rope_base,
+                        qk_norm=qk_norm, dtype=dtype,
+                        **{"rope": pos == "rope", **(linear or {})})
+                return SparseAttention(
+                    dim, n_heads, n_kv_heads=self.n_kv_heads, head_dim=hd,
+                    select=Selection(**select), max_seq=max_seq,
+                    rope_base=rope_base, qk_norm=qk_norm, dtype=dtype,
+                    **{k: v for k, v in sparse.items() if k not in select})
+
             def make_attn(layer=None):
+                if layer is not None and layer_mixers is not None:
+                    return make_mixer(layer_mixers[layer])
                 if attention == "latent":
                     return LatentAttention(
                         dim, n_heads, rope_base=rope_base, attn_fn=attn_fn,
@@ -266,7 +338,8 @@ class TransformerLM(Module):
             def make_block(kind, layer=None):
                 return Block(dim, norm1=make_norm(), attn=make_attn(layer),
                              norm2=make_norm(), ffn=make_ffn(kind),
-                             streams=self.streams, hc=hc)
+                             streams=self.streams, hc=hc,
+                             branch_scale=branch_scale)
 
             self.blocks = [make_block(kind, layer)
                            for layer, kind in enumerate(kinds)]
@@ -383,6 +456,19 @@ class TransformerLM(Module):
             return x
         return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
 
+    def embed(self, params, tokens):
+        """Token ids -> what the first block takes before the positional
+        table and the streams: the embedding, times ``emb_scale``."""
+        x = self.tok.apply(params["tok"], tokens)
+        return x if self.emb_scale == 1.0 \
+            else (x * self.emb_scale).astype(x.dtype)
+
+    def head_input(self, x):
+        """The final norm's output as the head takes it: divided by
+        ``logit_scale``."""
+        return x if self.logit_scale == 1.0 \
+            else (x / self.logit_scale).astype(x.dtype)
+
     def project_vocab(self, params, x, out_dtype=None):
         """Hidden states (..., dim) → logits (..., vocab). Single source
         of truth for the output projection (training apply and the cached
@@ -390,7 +476,7 @@ class TransformerLM(Module):
         type where it is not the operands' (a block step picks by
         confidence from float32 logits)."""
         with jax.named_scope("head"):
-            return jnp.matmul(x, self.head_weight(params),
+            return jnp.matmul(self.head_input(x), self.head_weight(params),
                               preferred_element_type=out_dtype)
 
     def apply(self, params: Params, tokens, *, rng=None, train: bool = False,
@@ -414,7 +500,7 @@ class TransformerLM(Module):
                            pos_offset=pos_offset, positions=positions)
         x = self.ln_f.apply(params["ln_f"], x)
         if return_hidden:
-            return x
+            return self.head_input(x)
         return self.project_vocab(params, x)
 
     def _run_block(self, blk, p, x, **kw):
@@ -441,7 +527,7 @@ class TransformerLM(Module):
         """Embedding and blocks: what the final norm takes, and each
         expert layer's pairs per expert, in layer order."""
         s = tokens.shape[1]
-        x = self.tok.apply(params["tok"], tokens)
+        x = self.embed(params, tokens)
         if positions is None:
             positions = pos_offset + jnp.arange(s)
         if self.pos is not None:
@@ -478,7 +564,7 @@ class TransformerLM(Module):
         positions = jnp.arange(s)
         h, loads = self._trunk(params, tokens[:, :-1], rng=rng, train=train,
                                positions=positions)
-        main = self.ln_f.apply(params["ln_f"], h)
+        main = self.head_input(self.ln_f.apply(params["ln_f"], h))
         mtp = None
         if self.mtp is not None:
             m, p = self.mtp, params["mtp"]
@@ -497,5 +583,6 @@ class TransformerLM(Module):
                 if load is not None:
                     loads.append(load)
                 with jax.named_scope("blocks"):
-                    mtp = m["norm"].apply(p["norm"], x[:, :-1])
+                    mtp = self.head_input(
+                        m["norm"].apply(p["norm"], x[:, :-1]))
         return main, mtp, (jnp.stack(loads) if loads else None)

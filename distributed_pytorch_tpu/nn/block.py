@@ -12,7 +12,8 @@ any attention module that has ``apply`` and hands out its page store
 dropless expert layer (``parallel/moe.py``); the plain residual sum or
 ``streams`` parallel residual streams under hyper-connections
 (``nn/hyper.py``), in which case the block's input and output are
-(B, S, streams, D)."""
+(B, S, streams, D); ``branch_scale`` multiplies what each sublayer adds
+to the plain residual sum (muP's depth scaling, ``x + s * f(norm(x))``)."""
 
 from __future__ import annotations
 
@@ -31,8 +32,13 @@ class Block(Module):
 
     def __init__(self, dim: int, *, norm1: Module, attn: Module,
                  norm2: Module, ffn: Module, streams: int = 0,
-                 hc: Optional[dict] = None):
+                 hc: Optional[dict] = None, branch_scale: float = 1.0):
+        if streams and branch_scale != 1.0:
+            raise ValueError("branch_scale scales the plain residual sum; "
+                             "hyper-connections mix their streams by "
+                             "learned weights")
         self.dim, self.streams = dim, streams
+        self.branch_scale = branch_scale
         self.ln1, self.attn, self.ln2, self.ffn = norm1, attn, norm2, ffn
         self.hc1 = HyperConnection(dim, streams, **(hc or {})) \
             if streams else None
@@ -57,6 +63,10 @@ class Block(Module):
         if hc is not None:
             return hc.apply(params, x, fn)
         out = fn(x)
+        if self.branch_scale != 1.0:
+            scaled = lambda y: (self.branch_scale * y).astype(y.dtype)
+            out = (scaled(out[0]), out[1]) if isinstance(out, tuple) \
+                else scaled(out)
         return (x + out[0], out[1]) if isinstance(out, tuple) else x + out
 
     def _ffn(self, params: Params, x, row_mask=None, moe_stats=None):

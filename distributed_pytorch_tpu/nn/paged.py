@@ -29,6 +29,20 @@ attention module and the store look inside one.
   (prefix sharing, quantized pages, the speculative commit, the hand-off)
   is refused by name with :class:`MixedStoresUnsupported`.
 
+- :class:`StatePages`: a linear-attention layer's ONE STATE a slot,
+  ``(n_slots, H, Dk, Dv)`` float32, whatever the context: a step reads it
+  and writes it back whole, and :meth:`StatePages.reset` zeroes a slot's
+  when the slot is taken (nothing masks a state's past, as positions mask
+  a ring's).
+- :class:`SelectedPages`: a sparse-attention layer's K and V pages (a
+  :class:`KVPages` of exact sides) and beside them the slot's COMPRESSED
+  KEYS, ``(n_slots, Hkv, windows, Dh)``, the selector's own array, written
+  as the windows of ``kernel`` positions fill; a decode step scores them,
+  chooses pages a row and a KV head inside the program and reads those
+  pages alone. What a model with either cannot do yet (prefix sharing,
+  quantized pages, the speculative commit, snapshots, the hand-off) is
+  refused by name with :class:`MixerStoresUnsupported`.
+
 A store is a ``NamedTuple`` of arrays: a pytree that crosses ``jax.jit``
 and is donated whole. What a store answers: ``write`` (one entry a row:
 a decode step, one position of a speculative commit), ``write_tail`` (a
@@ -61,6 +75,7 @@ from ..ops.quant import (dequantize_page_blocks, pack_page_nibbles,
                          page_block_map, quantize_page_blocks,
                          unpack_page_nibbles)
 from .attention import write_rows
+from .sparse_attention import QUERY_BLOCK, choose_blocks, window_probs
 
 
 class DecodeCtx(NamedTuple):
@@ -80,6 +95,9 @@ class DecodeCtx(NamedTuple):
     page_len: int
     blockwise: bool = True
     moe_stats: Optional[list] = None
+    #: a list a sparse-attention layer appends its (blocks chosen, blocks
+    #: resident) to, summed over the active rows and KV heads; or None
+    sel_stats: Optional[list] = None
 
 
 class PrefillCtx(NamedTuple):
@@ -87,7 +105,10 @@ class PrefillCtx(NamedTuple):
     ``dest`` / ``dest_off`` (S,) where each tail entry goes (pad rows
     route out of bounds), ``mask`` (S, W + S) over [prefix pages | tail] of
     ``width`` W, ``row_mask`` (S,) the tail's ``true_len`` real rows,
-    ``slot`` the row of the pool the prompt is admitted to."""
+    ``slot`` the row of the pool the prompt is admitted to; ``dense``
+    (a bool scalar, or None) says that the prompt is shorter than a
+    sparse-attention layer's ``dense_len``, so the request attends to
+    every earlier position for its whole life."""
     table_row: Any
     positions: Any
     offset: Any
@@ -99,6 +120,7 @@ class PrefillCtx(NamedTuple):
     row_mask: Any
     width: int
     moe_stats: Optional[list] = None
+    dense: Any = None
 
 
 class VerifyCtx(NamedTuple):
@@ -174,6 +196,26 @@ def mixed_unsupported(what: str) -> MixedStoresUnsupported:
         "window layer keeps a ring of its last entries a slot and no "
         "pages, served only by InferenceEngine(paged=True, "
         "kv_dtype='f32', prefix_share=False) without speculation")
+
+
+class MixerStoresUnsupported(NotImplementedError):
+    """A serving path that cannot hold a linear-attention layer's state
+    or a sparse-attention layer's compressed keys
+    (``TransformerLM(layer_mixers=...)``)."""
+
+
+def mixers_unsupported(what: str) -> MixerStoresUnsupported:
+    """A model of linear- and sparse-attention layers runs through ONE
+    path: the exact paged pool without prefix sharing. What has not been
+    carried over to a state a slot or to compressed keys says so by
+    name."""
+    return MixerStoresUnsupported(
+        f"{what} cannot serve a model of linear- and sparse-attention "
+        "layers (TransformerLM(layer_mixers=...)): a linear layer keeps "
+        "one state a slot and no pages, a sparse layer its compressed "
+        "keys a slot beside its pages, served only by "
+        "InferenceEngine(paged=True, kv_dtype='f32', prefix_share=False) "
+        "without speculation")
 
 
 def table_pages(state):
@@ -502,7 +544,8 @@ class KVPages(NamedTuple):
             ctx.idx, hk, hv, scale=scale, page_len=ctx.page_len,
             out_dtype=hv.dtype)
 
-    def attend_tail(self, ctx: PrefillCtx, hq, scale, block: int):
+    def attend_tail(self, ctx: PrefillCtx, hq, scale, block: int,
+                    chosen=None):
         """A prompt's tail over the slot's resident pages, the tail's own
         entries among them (``write_tail`` ran): queries hq (1, H, S, Dh)
         at ``ctx.positions`` over the positions ``<=`` their own, read
@@ -511,7 +554,10 @@ class KVPages(NamedTuple):
         exact-zero probabilities). The trips follow ``ctx.offset``: as
         many as hold a position of the prompt so far, so that no
         (S, width) score array is ever formed and a short context pays
-        for no long one. Exact pages only. Returns (1, H, S, Dh)."""
+        for no long one. ``chosen`` (Hkv, S, pages) bool, a sparse layer's
+        (:class:`SelectedPages`): a position is seen only in a page its
+        query chose, and a page that no query chose reads as zeros,
+        whatever it holds. Exact pages only. Returns (1, H, S, Dh)."""
         pages_k, pages_v = self.k.pages, self.v.pages
         _, hkv, page_len, dh = pages_k.shape
         _, h, s, _ = hq.shape
@@ -519,7 +565,10 @@ class KVPages(NamedTuple):
         per = max(1, block // page_len)
         row = ctx.table_row
         if row.shape[0] % per:
-            row = jnp.pad(row, (0, per - row.shape[0] % per))
+            pad = per - row.shape[0] % per
+            row = jnp.pad(row, (0, pad))
+            if chosen is not None:
+                chosen = jnp.pad(chosen, ((0, 0), (0, 0), (0, pad)))
         q = hq.reshape(1, hkv, g, s, dh).astype(pages_k.dtype)
         span = per * page_len
 
@@ -532,12 +581,18 @@ class KVPages(NamedTuple):
             # past the prompt so far a page may be any page, or none, and
             # hold anything (0 x NaN is NaN): those rows read as zeros
             held = (pos_k < ctx.offset + ctx.true_len)[:, None]
+            if chosen is not None:
+                ch = jnp.repeat(jax.lax.dynamic_slice_in_dim(
+                    chosen, j * per, per, axis=2), page_len, axis=2)
+                held = held & jnp.any(ch, axis=1)[None, :, :, None]
             k = jnp.where(held, rows(pages_k, pids), 0)
             v = jnp.where(held, rows(pages_v, pids), 0)
             sc = jnp.einsum("bngqd,bnkd->bngqk", q, k,
                             preferred_element_type=jnp.float32) * scale
             seen = (pos_k[None, :] <= ctx.positions[:, None])[None, None,
                                                               None]
+            if chosen is not None:
+                seen = seen & ch[None, :, None]
             return _merge_block(carry, jnp.where(seen, sc, _MASK), v, seen)
 
         carry = (jnp.full((1, hkv, g, s), _MASK, jnp.float32),
@@ -695,3 +750,279 @@ class WindowPages(NamedTuple):
     def require(self, op: str) -> None:
         if op in self.LACKS:
             raise mixed_unsupported(self.LACKS[op])
+
+
+class StatePages(NamedTuple):
+    """A linear-attention layer's store: ONE STATE a slot, ``(n_slots, H,
+    Dk, Dv)`` float32, the running sum ``S = decay * S + k v^T`` of the
+    request in the slot. It does not grow with the context and no table
+    addresses it: the allocator counts no page for it. A decode step
+    reads every slot's state and writes it back whole (``step``: row b is
+    slot b); a prompt's chunk reads its slot's state and leaves the one
+    after its last real position (``read`` / ``write``), which is how a
+    prefill in chunks carries it from chunk to chunk.
+
+    Nothing masks a state's past as positions mask a ring's: a slot's
+    state is zeroed when the slot is taken (``reset``, the pool's
+    ``begin``). It is float32 whatever the model's dtype: every step
+    rounds it again, and in bfloat16 those roundings add up to a percent
+    of a slowly decaying head's state."""
+    s: Any
+
+    @classmethod
+    def zeros(cls, shape, n_slots: int):
+        """``shape`` = (H, Dk, Dv)."""
+        return cls(jnp.zeros((n_slots,) + shape, jnp.float32))
+
+    @property
+    def n_pages(self):
+        return None                      # no table addresses a state
+
+    def reset(self, slot):
+        """The slot's state zeroed: a new request starts from nothing."""
+        return StatePages(self.s.at[slot].set(0.0))
+
+    def read(self, slot):
+        """(H, Dk, Dv) of slot ``slot`` (traced)."""
+        return jax.lax.dynamic_index_in_dim(self.s, slot, 0, keepdims=False)
+
+    def write(self, slot, s):
+        """The slot's state REPLACED."""
+        return StatePages(jax.lax.dynamic_update_index_in_dim(
+            self.s, s.astype(jnp.float32), slot, 0))
+
+    def step(self, k, v, decay, active):
+        """One recurrence step a row: ``S <- decay * S + k v^T`` where
+        ``active``, k / v (B, H, D) the step's key and value, ``decay``
+        (H,). Returns the store written; its ``s`` is what the step's
+        queries read."""
+        kv = k.astype(jnp.float32)[..., :, None] \
+            * v.astype(jnp.float32)[..., None, :]
+        new = decay[None, :, None, None] * self.s + kv
+        return StatePages(jnp.where(active[:, None, None, None], new, self.s))
+
+    def resident_bytes(self) -> int:
+        return self.s.nbytes
+
+    #: what a state a slot has no form of yet
+    LACKS = {"commit": "speculative decoding (serve/spec: a rejected "
+                       "candidate's step cannot be taken out of a state)",
+             "export": "the disaggregated hand-off (serve/disagg)",
+             "adopt": "the disaggregated hand-off (serve/disagg)",
+             "snapshot": "a preemption snapshot (a state a slot is not "
+                         "pages to park)",
+             "block_step": "generation by blocks "
+                           "(TransformerLM(gen_block=...))",
+             "prefix_share": "prefix sharing (a shared page says nothing "
+                             "of the state after the shared prefix)",
+             "quantized": "a quantized page pool (kv_dtype='q8'/'q4')"}
+
+    def require(self, op: str) -> None:
+        if op in self.LACKS:
+            raise mixers_unsupported(self.LACKS[op])
+
+
+class SelectedPages(NamedTuple):
+    """A sparse-attention layer's store: K and V pages (``kv``, a
+    :class:`KVPages` of exact sides, addressed by the tables like any
+    global layer's) and, beside them, the selector's own array: the
+    COMPRESSED KEYS of the slot's resident context, ``ck`` ``(n_slots,
+    Hkv, windows, Dh)`` in the model's dtype. Window ``j`` is the mean of
+    the keys at positions ``stride * j .. stride * j + kernel - 1``,
+    written when its last position is (a decode step closes one every
+    ``stride`` positions, a prompt's chunk every window that ends inside
+    it); it is read only where ``stride * j + kernel - 1 <=`` the
+    query's position, and every such window was written by the request
+    that owns the slot now, so a slot's compressed keys are never
+    cleared. ``dense`` (n_slots,) bool: the slot's request has a prompt
+    shorter than ``dense_len`` and attends to every earlier position for
+    its whole life (written by every chunk of its prompt).
+
+    ``sel`` in the methods is the layer's ``nn.sparse_attention.Selection``
+    (sizes of the compression and of the choice): no part of the pytree.
+    A block of the selection IS a page (``block == page_len``)."""
+    kv: Any
+    ck: Any
+    dense: Any
+
+    @classmethod
+    def zeros(cls, shape, n_pages: int, n_slots: int, windows: int, dtype):
+        """``shape`` = (Hkv, page_len, Dh)."""
+        hkv, _, dh = shape
+        return cls(KVPages.zeros(shape, n_pages, n_slots, None, dtype),
+                   jnp.zeros((n_slots, hkv, windows, dh), dtype),
+                   jnp.zeros((n_slots,), jnp.bool_))
+
+    @property
+    def n_pages(self) -> int:
+        return self.kv.n_pages
+
+    def _keys_at(self, pids, pos):
+        """The resident keys at positions ``pos`` (..., n) of the pages
+        ``pids`` (..., n) name: (..., n, Hkv, Dh), one gather of Dh-wide
+        rows from the pool seen flat (``write_rows``' form)."""
+        pages = self.kv.k.pages
+        _, hkv, page_len, dh = pages.shape
+        flat = (pids[..., None] * hkv + jnp.arange(hkv)) * page_len \
+            + (pos % page_len)[..., None]
+        return pages.reshape(-1, dh)[flat]
+
+    def write(self, hk, hv, ctx: DecodeCtx):
+        """One entry a row into the pages (:meth:`KVPages.write`)."""
+        return self._replace(kv=self.kv.write(hk, hv, ctx.dest, ctx.wo))
+
+    def compress(self, ctx: DecodeCtx, sel):
+        """The compressed key of the window that this step's entry
+        closes, for the rows whose entry closes one (every ``stride``-th
+        position): the mean of the row's last ``kernel`` resident keys,
+        this step's among them (``write`` ran)."""
+        n = self.ck.shape[0]
+        first = ctx.idx - (sel.kernel - 1)
+        closes = ctx.active & (first >= 0) & (first % sel.stride == 0)
+        pos = jnp.maximum(first[:, None] + jnp.arange(sel.kernel), 0)
+        pids = jnp.take_along_axis(ctx.tables, pos // ctx.page_len, axis=1)
+        kc = jnp.mean(self._keys_at(pids, pos).astype(jnp.float32), axis=1)
+        return self._replace(ck=write_rows(
+            self.ck, jnp.where(closes, jnp.arange(n), n),
+            jnp.clip(first // sel.stride, 0, self.ck.shape[2] - 1), kc))
+
+    def write_tail(self, hk, hv, ctx: PrefillCtx):
+        """A prompt's tail, (1, Hkv, S, Dh) each, into the pages, and the
+        slot's ``dense``."""
+        return self._replace(kv=self.kv.write_tail(hk, hv, ctx),
+                             dense=self.dense.at[ctx.slot].set(ctx.dense))
+
+    def compress_tail(self, hk, ctx: PrefillCtx, sel):
+        """The compressed key of every window that ends inside the real
+        rows of a prompt's tail hk (1, Hkv, S, Dh); the first of them
+        start in the ``kernel - stride`` resident positions before the
+        tail."""
+        n, hkv, windows, dh = self.ck.shape
+        s, page_len = hk.shape[2], self.kv.k.pages.shape[2]
+        lead, per = sel.kernel - sel.stride, sel.kernel // sel.stride
+        pos = jnp.maximum(ctx.offset - lead + jnp.arange(lead), 0)
+        prev = self._keys_at(ctx.table_row[pos // page_len], pos)
+        k_ext = jnp.concatenate([jnp.moveaxis(prev, 0, 1), hk[0]], axis=1)
+        # means of ``stride`` keys, then of ``per`` neighbours: a window
+        sub = jnp.mean(k_ext.astype(jnp.float32).reshape(
+            hkv, (lead + s) // sel.stride, sel.stride, dh), axis=2)
+        n_win = s // sel.stride
+        kc = sum(sub[:, e:e + n_win] for e in range(per)) / per
+        j = ctx.offset // sel.stride - (per - 1) + jnp.arange(n_win)
+        ok = (j >= 0) & (j * sel.stride + sel.kernel
+                         <= ctx.offset + ctx.true_len)
+        return self._replace(ck=write_rows(
+            self.ck, jnp.where(ok, ctx.slot, n),
+            jnp.clip(j, 0, windows - 1), jnp.moveaxis(kc, 0, 1)))
+
+    def attend(self, ctx: DecodeCtx, hq, hk, hv, scale, sel):
+        """A decode step's attention, this step's entries written: every
+        row scores its slot's compressed keys, chooses pages a KV head
+        (``nn.sparse_attention.choose_blocks``) and attends to those
+        alone. The chosen pages of (row, KV head) become one row of a
+        table over the pool seen as ``n_pages * Hkv`` pages of one head,
+        the current page last, so that ``paged_decode_attention`` (the
+        Mosaic kernel on a TPU, the loop elsewhere) walks them as it
+        walks any row's pages; an unchosen page is never read. A
+        ``dense`` row walks its own table, in a second call that skips
+        every other row. hq (B, H, 1, Dh) -> the same."""
+        b, h, _, dh = hq.shape
+        hkv, page_len = hk.shape[1], ctx.page_len
+        g, n_blocks = h // hkv, ctx.tables.shape[1]
+        per = sel.block // sel.stride
+        if self.ck.shape[2] < n_blocks * per:
+            raise ValueError(
+                f"the store keeps {self.ck.shape[2]} compressed keys a slot "
+                f"and a table row addresses {n_blocks} pages of {per}: the "
+                "model's max_seq is shorter than the pool's max_len")
+        t = jnp.broadcast_to(ctx.idx[:, None], (b, hkv))
+        with jax.named_scope("select"):
+            p = window_probs(hq.reshape(b, hkv, g, dh),
+                             self.ck[:, :, :n_blocks * per], t, sel, scale)
+            chosen, exists = choose_blocks(p, t, sel, n_blocks)
+            chosen |= self.dense[:, None, None] & exists
+            width = min(n_blocks, sel.init_blocks
+                        + -(-sel.window // page_len) + 1 + sel.topk)
+            m = jnp.arange(n_blocks)
+            # the chosen blocks first, in their order: the current one last
+            blocks = jnp.sort(jnp.where(chosen, m, m + n_blocks),
+                              axis=-1)[..., :width] % n_blocks
+            pids = jnp.take_along_axis(
+                jnp.broadcast_to(ctx.tables[:, None, :],
+                                 (b, hkv, n_blocks)), blocks, axis=-1)
+            count = jnp.sum(chosen, axis=-1)
+            idx = jnp.maximum(count - 1, 0) * page_len \
+                + (ctx.idx % page_len)[:, None]
+            if ctx.sel_stats is not None:
+                on = ctx.active[:, None]
+                ctx.sel_stats.append(jnp.stack([
+                    jnp.sum(jnp.where(on, count, 0)),
+                    jnp.sum(jnp.where(on, jnp.sum(exists, axis=-1), 0))]))
+        fold = lambda side: side.pages.reshape(-1, 1, page_len, dh)
+        sparse = ctx.active & ~self.dense
+        with jax.named_scope("attend"):
+            o = paged_decode_attention(
+                hq.reshape(b * hkv, g, 1, dh), fold(self.kv.k),
+                fold(self.kv.v),
+                (pids * hkv + jnp.arange(hkv)[None, :, None]).reshape(
+                    b * hkv, width),
+                idx.reshape(-1), hk.reshape(b * hkv, 1, 1, dh),
+                hv.reshape(b * hkv, 1, 1, dh), scale=scale,
+                page_len=page_len, active=jnp.repeat(sparse, hkv),
+                zero_dead=True).reshape(b, h, 1, dh)
+            if sel.dense_len > 0:
+                on = ctx.active & self.dense
+                o = jnp.where(self.dense[:, None, None, None],
+                              paged_decode_attention(
+                                  hq, self.kv.k.pages, self.kv.v.pages,
+                                  ctx.tables, jnp.where(on, ctx.idx, 0), hk,
+                                  hv, scale=scale, page_len=page_len,
+                                  active=on, zero_dead=True), o)
+        return o
+
+    def attend_tail(self, ctx: PrefillCtx, hq, scale, sel, block: int):
+        """A prompt's tail over the slot's resident pages (the tail's own
+        among them: ``write_tail`` ran) UNDER THE SELECTION: every query
+        scores the slot's compressed keys and chooses its blocks a KV
+        head, ``QUERY_BLOCK`` queries at a time (``ctx.dense``: every
+        block), and the tail then walks the resident positions as
+        :meth:`KVPages.attend_tail` does, under the chosen sets' mask.
+        That is the dense walk's cost for the selection's result: what a
+        query did not choose never enters its softmax. Returns (1, H, S,
+        Dh)."""
+        _, hkv, page_len, dh = self.kv.k.pages.shape
+        _, h, s, _ = hq.shape
+        g, n_blocks = h // hkv, ctx.table_row.shape[0]
+        with jax.named_scope("select"):
+            ck = jax.lax.dynamic_index_in_dim(self.ck, ctx.slot, 0, False)[
+                :, :n_blocks * (sel.block // sel.stride)]
+            qb = min(QUERY_BLOCK, s)
+            q = hq[0].reshape(hkv, g, s // qb, qb, dh)
+
+            def choose(args):
+                qq, tt = args                      # (Hkv, g, qb, Dh), (qb,)
+                tt = jnp.broadcast_to(tt[None, :], (hkv, qb))
+                p = window_probs(jnp.moveaxis(qq, 1, 2), ck[:, None], tt,
+                                 sel, scale)
+                return choose_blocks(p, tt, sel, n_blocks)[0]
+
+            chosen = jax.lax.map(choose, (jnp.moveaxis(q, 2, 0),
+                                          ctx.positions.reshape(-1, qb)))
+            chosen = jnp.moveaxis(chosen, 0, 1).reshape(hkv, s, n_blocks) \
+                | ctx.dense
+        with jax.named_scope("attend"):
+            return self.kv.attend_tail(ctx, hq, scale, block, chosen)
+
+    def resident_bytes(self) -> int:
+        return self.kv.resident_bytes() + self.ck.nbytes
+
+    #: what compressed keys a slot have no form of yet
+    LACKS = {**StatePages.LACKS,
+             "commit": "speculative decoding (serve/spec: a verify "
+                       "scores candidates without the selection)",
+             "prefix_share": "prefix sharing (a shared page says nothing "
+                             "of the slot's compressed keys)"}
+
+    def require(self, op: str) -> None:
+        if op in self.LACKS:
+            raise mixers_unsupported(self.LACKS[op])

@@ -232,7 +232,8 @@ def _on_one_device(x) -> bool:
 @jax.named_scope("decode_attention")
 def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
                            new_v, *, scale, page_len: int, active=None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           zero_dead: bool = False):
     """Single-token attention over an exact paged K/V pool.
 
     hq: (B, H, 1, Dh); k_pages/v_pages: (n_pages[+1], Hkv, page_len,
@@ -251,6 +252,12 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
     the arguments alone. Everything else takes the loop, each page a
     plain ``take``; so does a store of another format
     (``nn/paged.py``), which hands the loop its own loaders.
+
+    ``zero_dead``: the loop's value rows past a row's ``idx`` read as
+    zeros, as the kernel's do. For a caller whose table entries past a
+    row's length name pages that may hold anything (a sparse layer's
+    unchosen pages, ``nn.paged.SelectedPages``): the loop visits them
+    where another row is longer, and ``0 x NaN`` is NaN.
     """
     if (active is not None
             and paged_attention_kernel.kernel_fits(k_pages, v_pages,
@@ -263,10 +270,15 @@ def paged_decode_attention(hq, k_pages, v_pages, tables, idx, new_k,
             return paged_attention_kernel.paged_attention(
                 hq, k_pages, v_pages, tables, idx, active, scale=scale,
                 page_len=page_len, interpret=mode)
+    load_v = lambda pids, j: jnp.take(v_pages, pids, axis=0)
+    if zero_dead:
+        def load_v(pids, j):
+            live = j * page_len + jnp.arange(page_len)[None, :] <= idx[:, None]
+            return jnp.where(live[:, None, :, None],
+                             jnp.take(v_pages, pids, axis=0), 0)
     return _paged_loop(
-        hq, lambda pids, j: jnp.take(k_pages, pids, axis=0),
-        lambda pids, j: jnp.take(v_pages, pids, axis=0), tables, idx,
-        new_k, new_v, scale=scale, page_len=page_len,
+        hq, lambda pids, j: jnp.take(k_pages, pids, axis=0), load_v,
+        tables, idx, new_k, new_v, scale=scale, page_len=page_len,
         out_dtype=v_pages.dtype)
 
 

@@ -37,7 +37,8 @@ import numpy as np
 
 from ..models.generate import (_sample, decode_step_slots,
                                prefill_partial, refuse_blocks, refuse_latent,
-                               refuse_mixed, spec_commit_slots,
+                               refuse_mixed, refuse_mixers,
+                               spec_commit_slots,
                                spec_verify_slots)
 from ..obs import trace as dpxtrace
 
@@ -141,6 +142,7 @@ class SlotPool:
         refuse_latent(model, "the contiguous SlotPool (paged=False)")
         refuse_blocks(model, "the contiguous SlotPool (paged=False)")
         refuse_mixed(model, "the contiguous SlotPool (paged=False)")
+        refuse_mixers(model, "the contiguous SlotPool (paged=False)")
         self.model = model
         self.n_slots = n_slots
         self.max_len = max_len

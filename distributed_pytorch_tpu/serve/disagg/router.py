@@ -37,7 +37,7 @@ import jax
 import numpy as np
 
 from ...models.generate import (_check_attn_compatible, _model_window,
-                                refuse_mixed)
+                                refuse_mixed, refuse_mixers)
 from ...obs import metrics as dpxmon
 from ...obs import trace as dpxtrace
 from ...runtime import env as dpxenv
@@ -119,6 +119,7 @@ class DisaggEngine:
             raise ValueError(f"n_slots must be >= 1, got {cfg.n_slots}")
         _check_attn_compatible(model, cfg.allow_custom_attn)
         refuse_mixed(model, "the disaggregated hand-off (serve/disagg)")
+        refuse_mixers(model, "the disaggregated hand-off (serve/disagg)")
         if _model_window(model) is not None:
             raise ValueError(
                 "disaggregated serving runs on the paged KV cache, "

@@ -549,19 +549,22 @@ class InferenceEngine:
         if self._paged:
             out["pages"] = self.pool.page_stats()
             moe = self.pool.moe_stats()
-            if moe is not None:
-                # the one place the expert layers' device counters are
-                # read; the mark puts a reading on the profiler's clock,
-                # so that a traced part can be told by two of them
-                out.update(moe)
+            mixers = self.pool.mixer_stats()
+            if moe is not None or mixers is not None:
+                # the one place the expert layers' and the sparse
+                # layers' device counters are read; the mark puts a
+                # reading on the profiler's clock, so that a traced part
+                # can be told by two of them
+                out.update(moe or {})
+                out.update(mixers or {})
                 also = ("decode_passes_ahead", "decode_rows_dropped")
                 if self._block:
                     # a block generator's marks carry its own counters
                     also += ("block_passes", "block_commits", "block_fills",
                              "blocks_emitted", "tokens_emitted")
                 mixed = {}
-                if self.pool.window_layers:
-                    # window and global layers in one cache: what each
+                if self.pool.window_layers or mixers is not None:
+                    # more than one kind of store in one cache: what each
                     # kind keeps and how long the contexts are
                     # (whole numbers: a reader of the trace takes no
                     # doubles from a mark)
@@ -570,7 +573,8 @@ class InferenceEngine:
                         "kv_resident_bytes_window", "pages_in_use",
                         "context_tokens_max", "context_tokens_mean")}
                     mixed["active_slots"] = out["active_slots"]
-                with dpxtrace.span("serve.stats", **moe, **mixed,
+                with dpxtrace.span("serve.stats", **(moe or {}),
+                                   **(mixers or {}), **mixed,
                                    **{k: out[k] for k in also}):
                     pass
         if self._spec is not None:

@@ -29,6 +29,22 @@ it, is built: prefix sharing (a shared page of a global layer says
 nothing of a window layer's ring), quantized pages, the speculative
 commit, the disaggregated hand-off, generation by blocks.
 
+**A state a slot, and chosen pages.** A model of linear- and
+sparse-attention layers (``TransformerLM(layer_mixers=...)``) gets, in the
+same ``state`` list, for each linear layer ONE STATE a slot
+(``nn.paged.StatePages``: float32, the same bytes whatever the context,
+zeroed by :meth:`PagedSlotPool.begin` when the slot is taken) and for each
+sparse layer its K and V pages beside the slot's compressed keys
+(``nn.paged.SelectedPages``), from which a decode step chooses, inside the
+program, the pages a row and a KV head reads. The allocator counts the
+sparse layers' pages; the counters tell the kinds apart
+(``state_resident_bytes``, ``compressed_keys_resident_bytes``,
+``sparse_blocks_chosen`` of ``sparse_blocks_resident``,
+``slots_state_reset``: :meth:`PagedSlotPool.mixer_stats`). What such a
+pool cannot do yet it refuses by name
+(``nn.paged.MixerStoresUnsupported``): prefix sharing, quantized pages,
+the speculative commit, snapshots, the hand-off, generation by blocks.
+
 Three things fall out of the indirection:
 
 - **prefix sharing**: full pages of a prompt are keyed in a radix index
@@ -68,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...nn.paged import SelectedPages, StatePages, WindowPages
 from ...models.generate import (block_step_slots_paged, block_unsupported,
                                 decode_step_slots_paged,
                                 prefill_partial_paged,
@@ -182,12 +199,34 @@ class PagedSlotPool:
         # window layers' rings beside global layers' pages: the tables
         # and the allocator count pages (the global layers'), a ring is
         # its slot's; what a ring has no form of is refused here by name
-        self.window_layers = [i for i, st in enumerate(self.state)
-                              if st.n_pages is None]
+        kind = lambda cls: [i for i, st in enumerate(self.state)
+                            if isinstance(st, cls)]
+        self.window_layers = kind(WindowPages)
         if self.window_layers:
             self.require("mixed")
             if prefix_share:
                 self.require("prefix_share")
+        # linear-attention layers' states and sparse-attention layers'
+        # compressed keys, a slot each: what they have no form of is
+        # refused here by name too. ``sel_counts``: the blocks the sparse
+        # layers' decode steps chose and had resident, summed on the
+        # device as two (high, low 20 bits) pairs, and the decode steps,
+        # read only by stats(); None without such a layer, like
+        # ``moe_counts``
+        self.state_layers = kind(StatePages)
+        self.sparse_layers = kind(SelectedPages)
+        if (self.state_layers or self.sparse_layers) and prefix_share:
+            self.require("prefix_share")
+        self.sel_counts = jnp.zeros((5,), jnp.int32) \
+            if self.sparse_layers else None
+        self.dense_len = max(
+            (model.blocks[i].attn.select.dense_len
+             for i in self.sparse_layers), default=0)
+        self.slots_state_reset = 0
+        self._reset_fn = jax.jit(
+            lambda state, slot: [st.reset(slot) if isinstance(st, StatePages)
+                                 else st for st in state],
+            donate_argnums=(0,))
         # what an expert layer counts in a decode step, summed on the
         # device and read only by stats(): tokens routed, experts with a
         # token, the fullest expert's tokens, decode steps. None for a
@@ -231,22 +270,30 @@ class PagedSlotPool:
     # -- jitted programs ---------------------------------------------------
 
     def _decode(self, params, state, counts, tables, lengths, tokens,
-                active):
+                active, sel_counts=None):
         """The ONE decode program. ``counts``: the expert layers'
-        counters, or None (an empty argument, not a second program).
-        Counted where it is traced: the compile, and how many of its
-        layers' attention, and of its expert layers' grouped matmuls,
-        took a Mosaic kernel."""
+        counters, ``sel_counts`` the sparse-attention layers', or None
+        (an empty argument, not a second program). Counted where it is
+        traced: the compile, and how many of its layers' attention, and
+        of its expert layers' grouped matmuls, took a Mosaic kernel."""
         self.compiles.decode += 1          # trace-time only
         before, moe_before = kernel_traces(), moe.kernel_traces()
         per_layer = None if counts is None else []
+        chosen = None if sel_counts is None else []
         logits, state = decode_step_slots_paged(
             self.model, params, state, tables, lengths, tokens, active,
-            page_len=self.page_len, moe_stats=per_layer)
+            page_len=self.page_len, moe_stats=per_layer, sel_stats=chosen)
         self.compiles.decode_kernel_layers = kernel_traces() - before
         self.compiles.moe_kernel_matmuls = moe.kernel_traces() - moe_before
         counts = self._counted(counts, per_layer)
-        return greedy_tokens(logits), logits, state, counts
+        if sel_counts is not None:
+            # (high, low) pairs: the low part keeps 20 bits, so a sum of
+            # under 2^30 a step never wraps an int32
+            low = sel_counts[1:4:2] + jnp.sum(jnp.stack(chosen), axis=0)
+            sel_counts = jnp.concatenate([jnp.stack(
+                [sel_counts[0:4:2] + (low >> 20), low & ((1 << 20) - 1)],
+                axis=1).reshape(4), sel_counts[4:] + 1])
+        return greedy_tokens(logits), logits, state, counts, sel_counts
 
     @staticmethod
     def _counted(counts, per_layer):
@@ -290,11 +337,11 @@ class PagedSlotPool:
                                        commit, page_len=self.page_len)
 
     def _admit(self, params, state, table_row, tokens, offset, true_len,
-               slot, *, bucket: int):
+               slot, dense=None, *, bucket: int):
         self.compiles.bump_prefill(bucket)  # trace-time only
         return prefill_partial_paged(self.model, params, state, table_row,
                                      tokens, offset, true_len, slot,
-                                     page_len=self.page_len)
+                                     page_len=self.page_len, dense=dense)
 
     def require(self, op: str) -> None:
         """Asked once by whoever is built on an operation of the stores
@@ -367,6 +414,12 @@ class PagedSlotPool:
         self.owned[slot] = row
         self.prefilling[slot] = _Prefill(prompt, n_hit, n_hit * L, 0, size,
                                          tuple(buckets))
+        if self.state_layers:
+            # a linear layer's state has no positions to mask the last
+            # occupant's by: the slot starts from nothing
+            self.state = self._reset_fn(self.state,
+                                        jnp.asarray(slot, jnp.int32))
+            self.slots_state_reset += 1
         return n_hit, n_hit * L
 
     def chunk(self, params, slot: int) -> Chunk:
@@ -388,10 +441,13 @@ class PagedSlotPool:
             fn = self._admit_fns[bucket] = jax.jit(
                 named_program(self._admit, f"prefill_b{bucket}",
                               bucket=bucket), donate_argnums=(1,))
+        # sparse layers: whether the whole prompt is under their dense_len
+        dense = jnp.asarray(s < self.dense_len) if self.sparse_layers \
+            else None
         logits, self.state = fn(
             params, self.state, upload(self.tables[slot]),
             jnp.asarray(padded), jnp.asarray(pf.done, jnp.int32),
-            jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32))
+            jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32), dense)
         out = Chunk(pf.chunks, pf.done, n, bucket, None)
         if pf.done + n < s:
             self.prefilling[slot] = pf._replace(done=pf.done + n,
@@ -444,9 +500,9 @@ class PagedSlotPool:
         the device."""
         tables, lengths, tokens, mask = upload_pass(
             self, iteration, (self.tables, self.lengths, tokens), (active,))
-        out, logits, self.state, self.moe_counts = self._decode_fn(
-            params, self.state, self.moe_counts, tables, lengths, tokens,
-            mask)
+        out, logits, self.state, self.moe_counts, self.sel_counts = \
+            self._decode_fn(params, self.state, self.moe_counts, tables,
+                            lengths, tokens, mask, self.sel_counts)
         self.lengths[np.asarray(active)] += 1
         return out, logits
 
@@ -628,11 +684,44 @@ class PagedSlotPool:
     def kv_resident_bytes(self) -> Tuple[int, int]:
         """``(global, window)``: the bytes of the stores that keep pages
         by table, which grow with ``n_pages``, and of the window layers'
-        rings, ``n_slots`` times a ring whatever ``max_len``."""
+        rings, ``n_slots`` times a ring whatever ``max_len``. A linear
+        layer's states and a sparse layer's compressed keys are neither
+        (:meth:`mixer_stats` counts them)."""
         window = sum(self.state[i].resident_bytes()
                      for i in self.window_layers)
-        return (sum(st.resident_bytes() for st in self.state) - window,
-                window)
+        slots = sum(self.mixer_bytes())
+        return (sum(st.resident_bytes() for st in self.state) - window
+                - slots, window)
+
+    def mixer_bytes(self) -> Tuple[int, int]:
+        """``(states, compressed keys)``: what the linear layers' states
+        and the sparse layers' compressed keys take, ``n_slots`` times a
+        slot's, whatever the contexts."""
+        return (sum(self.state[i].resident_bytes()
+                    for i in self.state_layers),
+                sum(self.state[i].ck.nbytes for i in self.sparse_layers))
+
+    def mixer_stats(self) -> Optional[Dict]:
+        """A model of linear- and sparse-attention layers: what their
+        stores keep a slot, how often a slot's state was zeroed, and the
+        blocks the sparse layers' decode steps chose of those they had
+        resident (sums over active rows, sparse layers and KV heads: one
+        device-to-host read, made here and nowhere else). None for any
+        other model."""
+        if not (self.state_layers or self.sparse_layers):
+            return None
+        states, keys = self.mixer_bytes()
+        out = {"state_resident_bytes": states,
+               "compressed_keys_resident_bytes": keys,
+               "slots_state_reset": self.slots_state_reset,
+               "state_layers": len(self.state_layers),
+               "sparse_layers": len(self.sparse_layers)}
+        if self.sel_counts is not None:
+            c = [int(v) for v in np.asarray(self.sel_counts)]
+            out["sparse_blocks_chosen"] = (c[0] << 20) + c[1]
+            out["sparse_blocks_resident"] = (c[2] << 20) + c[3]
+            out["sparse_decode_steps"] = c[4]
+        return out
 
     def bytes_per_resident_token(self) -> float:
         """Pool bytes (pages + scales; tails are per-slot, not

@@ -392,6 +392,12 @@ CHIP_SHAPES = [
     # a global layer beside window layers: rows of up to 33 280 tokens,
     # 8 query heads a KV head (520 pages a row: 66 KB of tables in SMEM)
     pytest.param(32, 8, 8, 64, 520, jnp.bfloat16, id="long-rows-page64"),
+    # a sparse-attention layer's chosen pages (PR 45): the pool seen as
+    # pages of ONE KV head, a row a (slot, KV head), 16 query heads, a
+    # table of the 1 + 33 + 64 chosen pages; and its dense rows' walk of
+    # the slot's own table, 1032 pages a row (132 KB of tables in SMEM)
+    pytest.param(64, 1, 16, 64, 98, jnp.bfloat16, id="chosen-pages-folded"),
+    pytest.param(32, 2, 16, 64, 1032, jnp.bfloat16, id="dense-rows-1032"),
 ]
 
 
@@ -442,6 +448,51 @@ def test_decode_program_moves_no_pool_for_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") == layers
     moved = [line for line in text.splitlines()
              if " copy(" in line and "8192,2,16,128" in line]
+    assert not moved, moved[:2]
+
+
+def test_mixers_decode_program_moves_no_store_for_v5e(one_chip, monkeypatch):
+    """A sparse and a linear layer at MiniCPM-SALA's widths, 32 slots of
+    66 048 tokens: the sparse layer's attention is two Mosaic calls (the
+    chosen pages, the dense rows), and no array of a store's shape is
+    copied: not the pool, which the chosen pages' table sees reshaped to
+    pages of one KV head, not the compressed keys, not the states."""
+    from distributed_pytorch_tpu.models.generate import (
+        decode_step_slots_paged)
+    monkeypatch.setattr(decode_attention, "_kernel_interpret",
+                        lambda interpret: False)
+    slots, page_len, pages_per_row = 32, 64, 1032
+    model = models.TransformerLM(
+        vocab=1024, dim=4096, n_layers=2, n_heads=32, n_kv_heads=2,
+        head_dim=DH, attn_bias=False, qk_norm=1e-6, pos="rope",
+        max_seq=pages_per_row * page_len, norm="rms", ffn_dim=16384,
+        layer_mixers=("sparse", "linear"),
+        sparse=dict(kernel=32, stride=16, block=64, topk=64, init_blocks=1,
+                    window=2048, dense_len=8192), dtype=jnp.bfloat16)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+    params = shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    state = shapes(jax.eval_shape(lambda: [
+        blk.attn.make_pages(slots * pages_per_row, slots, page_len, None,
+                            jnp.bfloat16) for blk in model.blocks]))
+
+    def step(params, state, tables, lengths, tokens, active):
+        return decode_step_slots_paged(model, params, state, tables,
+                                       lengths, tokens, active,
+                                       page_len=page_len)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, state, i32(slots, pages_per_row), i32(slots), i32(slots),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    moved = [line for line in text.splitlines() if " copy(" in line and any(
+        shape in line for shape in ("33024,2,64,128", "66048,1,64,128",
+                                    "32,2,4128,128", "32,32,128,128"))]
     assert not moved, moved[:2]
 
 
