@@ -75,7 +75,8 @@ from ..ops.quant import (dequantize_page_blocks, pack_page_nibbles,
                          page_block_map, quantize_page_blocks,
                          unpack_page_nibbles)
 from .attention import write_rows
-from .sparse_attention import QUERY_BLOCK, choose_blocks, window_probs
+from .sparse_attention import (WINDOW_BLOCK, choose_blocks, choose_scored,
+                               chunk_block_scores, window_probs)
 
 
 class DecodeCtx(NamedTuple):
@@ -983,33 +984,25 @@ class SelectedPages(NamedTuple):
     def attend_tail(self, ctx: PrefillCtx, hq, scale, sel, block: int):
         """A prompt's tail over the slot's resident pages (the tail's own
         among them: ``write_tail`` ran) UNDER THE SELECTION: every query
-        scores the slot's compressed keys and chooses its blocks a KV
-        head, ``QUERY_BLOCK`` queries at a time (``ctx.dense``: every
-        block), and the tail then walks the resident positions as
+        scores the compressed keys that the prompt so far has closed,
+        ``WINDOW_BLOCK`` of them a trip
+        (``chunk_block_scores``: a short context pays for no long one),
+        and chooses its blocks a KV head (``ctx.dense``: every block);
+        the tail then walks the resident positions as
         :meth:`KVPages.attend_tail` does, under the chosen sets' mask.
         That is the dense walk's cost for the selection's result: what a
         query did not choose never enters its softmax. Returns (1, H, S,
         Dh)."""
         _, hkv, page_len, dh = self.kv.k.pages.shape
         _, h, s, _ = hq.shape
-        g, n_blocks = h // hkv, ctx.table_row.shape[0]
+        n_blocks = ctx.table_row.shape[0]
         with jax.named_scope("select"):
-            ck = jax.lax.dynamic_index_in_dim(self.ck, ctx.slot, 0, False)[
-                :, :n_blocks * (sel.block // sel.stride)]
-            qb = min(QUERY_BLOCK, s)
-            q = hq[0].reshape(hkv, g, s // qb, qb, dh)
-
-            def choose(args):
-                qq, tt = args                      # (Hkv, g, qb, Dh), (qb,)
-                tt = jnp.broadcast_to(tt[None, :], (hkv, qb))
-                p = window_probs(jnp.moveaxis(qq, 1, 2), ck[:, None], tt,
-                                 sel, scale)
-                return choose_blocks(p, tt, sel, n_blocks)[0]
-
-            chosen = jax.lax.map(choose, (jnp.moveaxis(q, 2, 0),
-                                          ctx.positions.reshape(-1, qb)))
-            chosen = jnp.moveaxis(chosen, 0, 1).reshape(hkv, s, n_blocks) \
-                | ctx.dense
+            score = chunk_block_scores(
+                hq[0].reshape(hkv, h // hkv, s, dh),
+                jax.lax.dynamic_index_in_dim(self.ck, ctx.slot, 0, False),
+                ctx.positions, sel.closed(ctx.offset + ctx.true_len), sel,
+                scale, n_blocks, WINDOW_BLOCK)
+            chosen = choose_scored(score, ctx.positions, sel)[0] | ctx.dense
         with jax.named_scope("attend"):
             return self.kv.attend_tail(ctx, hq, scale, block, chosen)
 

@@ -32,9 +32,10 @@ from ..ops.flash_attention import _MASK
 from .attention import MultiHeadAttention, _scopes, dense_attention
 from .core import Linear, Params
 
-#: queries a step when a prompt's chunk (or a whole sequence) scores the
-#: compressed keys: scores are (Hkv, QUERY_BLOCK, g, windows) float32
-QUERY_BLOCK = 128
+#: compressed keys a trip when a prompt's chunk scores the windows its
+#: context has closed (``chunk_block_scores``): scores are (Hkv, g, S,
+#: WINDOW_BLOCK) float32. Measured: benchmarks/sparse_select_sweep.py
+WINDOW_BLOCK = 512
 
 
 class Selection(NamedTuple):
@@ -62,6 +63,11 @@ class Selection(NamedTuple):
                 "and no size is negative")
         return self
 
+    def closed(self, n):
+        """The windows that have closed once ``n`` positions are there
+        (0 or less: none)."""
+        return (n - self.kernel) // self.stride + 1
+
 
 def window_probs(q, ck, t, sel: Selection, scale):
     """What each compressed key is worth to a query's group: q (..., g,
@@ -80,11 +86,10 @@ def window_probs(q, ck, t, sel: Selection, scale):
     return jnp.sum(e / jnp.where(total == 0.0, 1.0, total), axis=-2)
 
 
-def choose_blocks(p, t, sel: Selection, n_blocks: int):
-    """The blocks a query at ``t`` (...) reads, from its windows' worth
-    ``p`` (..., W), ``W >= n_blocks * block / stride``. -> (chosen, exists),
-    (..., n_blocks) bool each: ``exists`` the blocks that hold a position
-    ``<= t``."""
+def block_scores(p, sel: Selection, n_blocks: int):
+    """What each block is worth to a query, from its windows' worth ``p``
+    (..., W), ``W >= n_blocks * block / stride``: the largest among the
+    windows that overlap the block. -> (..., n_blocks) float32."""
     per, extra = sel.block // sel.stride, sel.kernel // sel.stride - 1
     pb = p[..., :n_blocks * per].reshape(p.shape[:-1] + (n_blocks, per))
     score = jnp.max(pb, axis=-1)
@@ -93,6 +98,14 @@ def choose_blocks(p, t, sel: Selection, n_blocks: int):
         before = jnp.pad(pb[..., :-1, per - e],
                          [(0, 0)] * (pb.ndim - 2) + [(1, 0)])
         score = jnp.maximum(score, before)
+    return score
+
+
+def choose_scored(score, t, sel: Selection):
+    """The blocks a query at ``t`` (...) reads, from its blocks' scores
+    (..., n_blocks). -> (chosen, exists), (..., n_blocks) bool each:
+    ``exists`` the blocks that hold a position ``<= t``."""
+    n_blocks = score.shape[-1]
     m = jnp.arange(n_blocks)
     t = t[..., None]
     exists = m <= t // sel.block
@@ -104,6 +117,87 @@ def choose_blocks(p, t, sel: Selection, n_blocks: int):
     picked = jnp.any((at[..., None] == m) & (top[..., None] > -jnp.inf),
                      axis=-2)
     return forced | picked, exists
+
+
+def choose_blocks(p, t, sel: Selection, n_blocks: int):
+    """:func:`choose_scored` of :func:`block_scores`: the blocks a query at
+    ``t`` (...) reads, from its windows' worth ``p`` (..., W)."""
+    return choose_scored(block_scores(p, sel, n_blocks), t, sel)
+
+
+def chunk_block_scores(q, ck, t, n_closed, sel: Selection, scale,
+                       n_blocks: int, window_block: int):
+    """:func:`block_scores` of :func:`window_probs` for the queries of a
+    prompt's chunk, without the windows' worth ever formed: q (Hkv, g, S,
+    Dh) at positions ``t`` (S,), ck (Hkv, W, Dh) the slot's compressed
+    keys, of which the chunk's real rows have closed the first
+    ``n_closed`` (a traced scalar: no query reads a window past it, so a
+    pad row reads what the last real row may). The keys are walked
+    ``window_block`` windows a trip (a whole number of blocks; at the
+    published sizes 512 windows are 128 blocks, one tile of lanes), as
+    many trips as hold a closed window, twice: a head's softmax needs its
+    normaliser over every closed window before a window's worth can be
+    summed over the group, so the first walk keeps the running maximum
+    and sum a query and head, and the second forms ``p`` a trip, pools it
+    to blocks and writes them. The statistics and probabilities are
+    float32, a window that has not closed at a query's own ``t`` is worth
+    an exact zero, and a block past the last trip scores 0. -> (Hkv, S,
+    n_blocks) float32."""
+    hkv, g, s, dh = q.shape
+    per, extra = sel.block // sel.stride, sel.kernel // sel.stride - 1
+    if window_block % per:
+        raise ValueError(f"a trip of {window_block} windows is no whole "
+                         f"number of blocks of {per}")
+    wb, mb = window_block, window_block // per
+    n_trips = -(-n_blocks // mb)
+    ck = ck[:, :n_blocks * per].astype(q.dtype)
+    ck = jnp.pad(ck, ((0, 0), (0, n_trips * wb - ck.shape[1]), (0, 0)))
+    # a trip's windows by their place in the block first, then by block:
+    # pooling to blocks is then a maximum over ``per`` slabs of ``mb``
+    # lanes, not over neighbours in a lane
+    ck = ck.reshape(hkv, n_trips, mb, per, dh).swapaxes(2, 3).reshape(
+        hkv, n_trips, wb, dh)
+    order = (jnp.arange(wb) % mb) * per + jnp.arange(wb) // mb
+    closed_at = jnp.minimum(sel.closed(t + 1), n_closed)
+
+    def scores(j):
+        sc = jnp.einsum("ngsd,nwd->ngsw", q, ck[:, j],
+                        preferred_element_type=jnp.float32) * scale
+        closed = (j * wb + order)[None, :] < closed_at[:, None]
+        return jnp.where(closed, sc, _MASK)
+
+    def stats(j, carry):
+        m, l = carry
+        sc = scores(j)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        return m_new, l * jnp.exp(m - m_new) + jnp.sum(
+            jnp.exp(sc - m_new[..., None]), axis=-1)
+
+    def pool(j, carry):
+        score, tail = carry
+        # exp(_MASK - m) is an exact zero; where nothing has closed m is
+        # _MASK itself and ``inv`` the zero
+        p = jnp.sum(jnp.exp(scores(j) - m[..., None]) * inv[..., None],
+                    axis=1).reshape(hkv, s, per, mb)
+        sc = jnp.max(p, axis=2)
+        # the windows that start in the block before and end in this one:
+        # the trip's first block takes them from the trip before
+        last = p[:, :, per - extra:, :]
+        if extra:
+            sc = jnp.maximum(sc, jnp.max(jnp.concatenate(
+                [tail[..., None], last[..., :-1]], axis=-1), axis=2))
+        return (jax.lax.dynamic_update_slice_in_dim(score, sc, j * mb, 2),
+                last[..., -1])
+
+    trips = jnp.clip((n_closed + wb - 1) // wb, 0, n_trips)
+    m, l = jax.lax.fori_loop(
+        0, trips, stats, (jnp.full((hkv, g, s), _MASK, jnp.float32),
+                          jnp.zeros((hkv, g, s), jnp.float32)))
+    inv = jnp.where(closed_at > 0, 1.0 / l, 0.0)
+    score, _ = jax.lax.fori_loop(
+        0, trips, pool, (jnp.zeros((hkv, s, n_trips * mb), jnp.float32),
+                         jnp.zeros((hkv, s, extra), jnp.float32)))
+    return score[..., :n_blocks]
 
 
 def compress_keys(k, sel: Selection):

@@ -223,6 +223,11 @@ class PagedSlotPool:
             (model.blocks[i].attn.select.dense_len
              for i in self.sparse_layers), default=0)
         self.slots_state_reset = 0
+        # what the sparse layers' prompt chunks scored: the windows their
+        # contexts had closed, of the windows the store keeps a slot
+        # (summed over chunks and layers, here on the host)
+        self.sparse_prefill_windows_scored = 0
+        self.sparse_prefill_windows_kept = 0
         self._reset_fn = jax.jit(
             lambda state, slot: [st.reset(slot) if isinstance(st, StatePages)
                                  else st for st in state],
@@ -444,6 +449,10 @@ class PagedSlotPool:
         # sparse layers: whether the whole prompt is under their dense_len
         dense = jnp.asarray(s < self.dense_len) if self.sparse_layers \
             else None
+        for i in self.sparse_layers:
+            self.sparse_prefill_windows_scored += max(
+                0, self.model.blocks[i].attn.select.closed(pf.done + n))
+            self.sparse_prefill_windows_kept += self.state[i].ck.shape[2]
         logits, self.state = fn(
             params, self.state, upload(self.tables[slot]),
             jnp.asarray(padded), jnp.asarray(pf.done, jnp.int32),
@@ -703,11 +712,14 @@ class PagedSlotPool:
 
     def mixer_stats(self) -> Optional[Dict]:
         """A model of linear- and sparse-attention layers: what their
-        stores keep a slot, how often a slot's state was zeroed, and the
-        blocks the sparse layers' decode steps chose of those they had
-        resident (sums over active rows, sparse layers and KV heads: one
-        device-to-host read, made here and nowhere else). None for any
-        other model."""
+        stores keep a slot, how often a slot's state was zeroed, the
+        compressed keys the sparse layers' prompt chunks scored (the
+        windows each chunk's context had closed) of those the store keeps
+        a slot (sums over chunks and sparse layers, counted on the host),
+        and the blocks the sparse layers' decode steps chose of those they
+        had resident (sums over active rows, sparse layers and KV heads:
+        one device-to-host read, made here and nowhere else). None for
+        any other model."""
         if not (self.state_layers or self.sparse_layers):
             return None
         states, keys = self.mixer_bytes()
@@ -717,6 +729,10 @@ class PagedSlotPool:
                "state_layers": len(self.state_layers),
                "sparse_layers": len(self.sparse_layers)}
         if self.sel_counts is not None:
+            out["sparse_prefill_windows_scored"] = \
+                self.sparse_prefill_windows_scored
+            out["sparse_prefill_windows_kept"] = \
+                self.sparse_prefill_windows_kept
             c = [int(v) for v in np.asarray(self.sel_counts)]
             out["sparse_blocks_chosen"] = (c[0] << 20) + c[1]
             out["sparse_blocks_resident"] = (c[2] << 20) + c[3]

@@ -34,12 +34,13 @@ from chipbench.reference import minicpm_sala as reference     # noqa: E402
 from distributed_pytorch_tpu import models                    # noqa: E402
 from distributed_pytorch_tpu.models.generate import (         # noqa: E402
     MixerStoresUnsupported, make_generate_fn)
-from distributed_pytorch_tpu.nn import linear_attention       # noqa: E402
+from distributed_pytorch_tpu.nn import linear_attention, paged  # noqa: E402
 from distributed_pytorch_tpu.nn.paged import (DecodeCtx,      # noqa: E402
                                               PrefillCtx, SelectedPages,
                                               StatePages)
 from distributed_pytorch_tpu.nn.sparse_attention import (     # noqa: E402
-    Selection, choose_blocks, window_probs)
+    Selection, block_scores, choose_blocks, choose_scored,
+    chunk_block_scores, window_probs)
 from distributed_pytorch_tpu.serve import (EngineConfig,      # noqa: E402
                                            InferenceEngine, SamplingParams)
 from distributed_pytorch_tpu.serve.disagg import (            # noqa: E402
@@ -343,6 +344,84 @@ def test_a_chunk_reads_what_its_queries_chose_and_no_more():
                                        atol=1e-5)
 
 
+@pytest.mark.parametrize("offset,true_len,dense", [
+    (0, 8, False),      # nothing closed before t = 3
+    (8, 8, False),      # inside the first trip of 8 windows
+    (24, 8, False),     # two trips: window 7 starts in block 3, ends in 4
+    (88, 8, False),     # the slot's last pages, every trip
+    (40, 5, False),     # a partial chunk: three pad rows
+    (24, 8, True),      # a prompt under dense_len chooses every block
+])
+def test_a_chunk_walks_the_closed_windows_to_the_plain_choice(
+        monkeypatch, offset, true_len, dense):
+    """The chunk's walk (8 windows = 4 blocks a trip, as many trips as the
+    prompt so far has closed windows for) against the plain form, every
+    window of the slot scored at once: the same block scores and exactly
+    the same sets for the real rows; and through ``attend_tail``, every
+    query's result the softmax over ITS chosen positions, with every
+    compressed key past the closed count, every other slot's and every
+    position past the prompt so far NaN."""
+    hkv, g, dh, n_blocks, s, wb = 2, 4, 8, 24, 8, 8
+    monkeypatch.setattr(paged, "WINDOW_BLOCK", wb)
+    st = _store(windows=2 * n_blocks, seed=2)
+    row = np.random.default_rng(2).permutation(40)[:n_blocks].astype(np.int32)
+    hq = jax.random.normal(jax.random.PRNGKey(6), (1, hkv * g, s, dh))
+    positions = offset + jnp.arange(s)
+    scale = 1.0 / math.sqrt(dh)
+    t = jnp.broadcast_to(positions[None, :], (hkv, s))
+    q = hq[0].reshape(hkv, g, s, dh)
+    p = window_probs(jnp.moveaxis(q, 1, 2), st.ck[1][:, None], t, SEL, scale)
+    want = np.asarray(block_scores(p, SEL, n_blocks))
+    chosen = np.asarray(choose_blocks(p, t, SEL, n_blocks)[0])
+    n_closed = (offset + true_len - SEL.kernel) // SEL.stride + 1
+    # what the request does not own: NaN
+    ck = np.full(st.ck.shape, np.nan, np.float32)
+    ck[1, :, :max(n_closed, 0)] = np.asarray(st.ck)[1, :, :max(n_closed, 0)]
+    k, v = np.array(st.kv.k.pages), np.array(st.kv.v.pages)
+    for pages in (k, v):
+        held = np.zeros(pages.shape[:1] + pages.shape[2:3], bool)
+        pos = np.arange(offset + true_len)
+        held[row[pos // 4], pos % 4] = True
+        pages[~held[:, None, :, None] & np.ones(pages.shape, bool)] = np.nan
+    got = np.asarray(chunk_block_scores(
+        q, jnp.asarray(ck[1]), positions, jnp.asarray(n_closed), SEL, scale,
+        n_blocks, wb))
+    assert np.isfinite(got).all()                       # the pad rows too
+    np.testing.assert_allclose(got[:, :true_len], want[:, :true_len],
+                               atol=1e-6, rtol=1e-5)
+    sets = np.asarray(choose_scored(jnp.asarray(got), t, SEL)[0])
+    assert (sets[:, :true_len] == chosen[:, :true_len]).all()
+    if offset == 24:
+        # block 4 is the second trip's first: for some query its score is
+        # window 7's, which the first trip handed on
+        pn = np.asarray(p)
+        carried = (pn[..., 7] > np.maximum(pn[..., 8], pn[..., 9])) \
+            & (pn[..., 7] > 0)
+        assert carried.any() and (got[..., 4][carried]
+                                  == pytest.approx(pn[..., 7][carried]))
+    poisoned_st = SelectedPages(
+        st.kv._replace(k=st.kv.k._replace(pages=jnp.asarray(k)),
+                       v=st.kv.v._replace(pages=jnp.asarray(v))),
+        jnp.asarray(ck), st.dense)
+    ctx = PrefillCtx(table_row=jnp.asarray(row), positions=positions,
+                     offset=jnp.asarray(offset),
+                     true_len=jnp.asarray(true_len), slot=jnp.asarray(1),
+                     dest=None, dest_off=None, mask=None, row_mask=None,
+                     width=n_blocks * 4, dense=jnp.asarray(dense))
+    o = np.asarray(poisoned_st.attend_tail(ctx, hq, scale, SEL, 8))
+    assert np.isfinite(o).all()
+    pos = np.arange(n_blocks * 4)
+    clean = lambda pages, n: np.nan_to_num(pages[row, n].reshape(-1, dh))
+    for n in range(hkv):
+        for i in range(true_len):
+            seen = (chosen[n, i][pos // 4] | dense) & (pos <= offset + i)
+            want_o = _dense_softmax(
+                np.asarray(hq[0, n * g:(n + 1) * g, i], np.float64),
+                clean(k, n), clean(v, n), seen, scale)
+            np.testing.assert_allclose(o[0, n * g:(n + 1) * g, i], want_o,
+                                       atol=1e-5)
+
+
 def test_engine_streams_agree_with_the_reference(lm):
     """Seven greedy requests through three slots (every slot reused),
     prompts on both sides of ``dense_len``, prefill chunks between decode
@@ -369,6 +448,22 @@ def test_engine_streams_agree_with_the_reference(lm):
     assert stats["pages"]["prefix_hit_pages"] == 0
     assert stats["decode_compiles"] == 1
     assert stats["decode_passes_ahead"] > 0
+
+
+def test_the_engine_counts_the_windows_its_chunks_scored(lm):
+    """A short and a long prompt, chunks of 16: every chunk of a sparse
+    layer scored the windows that the prompt so far had closed (window j
+    closes at position 2 j + 3), of the 36 the store keeps a slot."""
+    model, params, _ = lm
+    with InferenceEngine(model, params, EngineConfig(**ENGINE)) as eng:
+        for n in (5, 50):
+            eng.submit(_ids(n, seed=5), SamplingParams(max_new_tokens=2)) \
+                .result(timeout=600)
+        stats = eng.stats()
+    ends = (5, 16, 32, 48, 50)                  # where each chunk stopped
+    assert stats["sparse_prefill_windows_scored"] \
+        == 2 * sum((n - 4) // 2 + 1 for n in ends) == 140
+    assert stats["sparse_prefill_windows_kept"] == len(ends) * 2 * 36
 
 
 @pytest.mark.parametrize("fault,n_prompt", [
@@ -419,6 +514,39 @@ def test_the_programs_name_the_new_mechanisms(lm):
                  "blocks/attn/core/sparse_attention/select",
                  "blocks/attn/core/sparse_attention/attend"):
         assert want in prefill, want
+
+
+def test_a_chunk_program_walks_the_windows_and_forms_no_slot_of_them(lm):
+    """Compile-only: the prefill program of a store that keeps 2048
+    windows a slot (4096 positions) holds a loop under the selection's
+    scope and no float32 value as large as one slot's windows for every
+    query and head of a group (the plain form's scores were twice that, a
+    KV head each); the decode program keeps the plain form: its scopes,
+    and no loop under ``select``."""
+    _, params, _ = lm
+    model = models.TransformerLM(**adapter.model_kwargs(CFG, max_len=4096))
+    pool = PagedSlotPool(model, 2, 4096, page_len=4, n_pages=40,
+                         prefix_share=False)
+    windows = pool.state[0].ck.shape[2]
+    assert windows == 2048 == 4 * paged.WINDOW_BLOCK
+    pool.admit(params, _ids(20), 0, (8, 16))
+    lowered = pool._admit_fns[16].lower(
+        params, pool.state, jnp.array(pool.tables[0]),
+        jnp.zeros((1, 16), jnp.int32), jnp.asarray(0), jnp.asarray(16),
+        jnp.asarray(0), jnp.asarray(False))
+    text = lowered.as_text(debug_info=True)
+    assert "blocks/attn/core/sparse_attention/select/while" in text
+    sizes = [math.prod(int(d) for d in dims.split("x"))
+             for dims in re.findall(r"tensor<([0-9x]+)xf32>", text)]
+    g = CFG["num_attention_heads"] // CFG["num_key_value_heads"]
+    assert 16 * g * paged.WINDOW_BLOCK <= max(sizes) < 16 * g * windows
+    decode = pool._decode_fn.lower(
+        params, pool.state, None, jnp.array(pool.tables),
+        jnp.array(pool.lengths), jnp.zeros(2, jnp.int32), jnp.ones(2, bool),
+        pool.sel_counts).as_text(debug_info=True)
+    for scope in ("select", "attend", "compress"):
+        assert f"blocks/decode_attention/sparse_attention/{scope}" in decode
+    assert "sparse_attention/select/while" not in decode
 
 
 def _refuses(what, make):
