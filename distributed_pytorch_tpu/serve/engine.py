@@ -34,8 +34,15 @@ docs/serving.md "Generation by blocks") takes step 4 as a BLOCK STEP
 one program an iteration runs one pass of every row's block, whatever
 pass each row is in, and fills positions of it on the device; a block's
 tokens are streamed when its last position is filled; prefill emits
-nothing. Greedy requests on the exact paged pool only: everything else
-refuses such a model by name (``nn.paged.block_unsupported``).
+nothing. The blocks, tokens and masks, stay on the device from one
+program to the next, and ONE block pass is kept in flight as a decode
+pass is: how many positions a pass fills is fixed at admission, so the
+host counts what each row's next pass is (a fill, its commit, none)
+without reading the last, dispatches it, and only then reads the pass
+before: which positions were filled, with what, and the blocks that
+became clean, which it streams. Greedy requests on the exact paged pool
+only: everything else refuses such a model by name
+(``nn.paged.block_unsupported``).
 
 SLO metrics (TTFT/TPOT/queue depth/slot occupancy, defined in
 ``serve.metrics``) flow into the line-JSON ``MetricsLogger`` stream.
@@ -53,7 +60,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +97,21 @@ class _Pass(NamedTuple):
     #: slot -> the REQUEST that held it at dispatch: by the read the
     #: slot (and its pages) may belong to another
     rows: Dict[int, Request]
+
+
+class _BlockPass(NamedTuple):
+    """A block pass dispatched and not yet read: the block path's
+    :class:`_Pass`."""
+
+    iteration: int
+    #: (n_slots, 3, L) int32 on the device: the rows' blocks after this
+    #: pass, the positions it filled and the positions still masked. The
+    #: next pass runs over this same array (``pool.blocks``, not donated)
+    out: Any
+    #: slot -> (the REQUEST that held it at dispatch; its pass of its
+    #: block, -1 the commit pass; whether the pass fills the block's
+    #: last masked position, so that the read streams the block)
+    rows: Dict[int, Tuple[Request, int, bool]]
 
 
 def _default_buckets(cap: int) -> Tuple[int, ...]:
@@ -281,24 +303,32 @@ class InferenceEngine:
         # it owns its slot and pages and is no row of the decode program
         self._prefilling: Optional[Request] = None
         self._free: List[int] = list(range(cfg.n_slots))[::-1]
-        # the token path keeps one decode pass in flight (_decode_all):
-        # the pass dispatched and not yet read; the rows' current tokens
+        # one pass is kept in flight (_decode_all, _block_all): the pass
+        # dispatched and not yet read, a _Pass or a _BlockPass. The
+        # token path besides: the rows' current tokens
         # on the device (the newest pass's output with the first tokens
         # of rows admitted since put in: the next pass's argument; None
         # before the first); requests whose first token is sampled on
         # the device and not yet read, each with that (1,) array
-        self._inflight: Optional[_Pass] = None
+        self._inflight: Optional[Union[_Pass, _BlockPass]] = None
         self._dev_tokens = None
         self._awaiting: Dict[int, Tuple[Request, Any]] = {}   # by slot
         if self._block:
-            # every slot's block: its tokens (the model's mask_id where
-            # a position is still masked), the pass that filled each
-            # position (-1: it came with the prompt), the passes run
-            shape = (cfg.n_slots, self._block)
-            self._blk_tokens = np.zeros(shape, np.int32)
-            self._blk_masked = np.zeros(shape, bool)
-            self._blk_fill_pass = np.zeros(shape, np.int32)
+            # the blocks are on the device (pool.blocks). What the host
+            # keeps of every slot's, COUNTED AHEAD over the passes it
+            # has dispatched, read or not: the positions still masked,
+            # the passes of the block, and the tokens the request will
+            # have been streamed when this block has been;
+            self._blk_left = np.zeros(cfg.n_slots, np.int32)
             self._blk_passes = np.zeros(cfg.n_slots, np.int32)
+            self._blk_owed = np.zeros(cfg.n_slots, np.int32)
+            # and what it learns at the read: the pass that filled each
+            # position (-1: it came with the prompt). The tokens a
+            # request's first block opens with wait here for its first
+            # pass, by slot
+            self._blk_fill_pass = np.zeros((cfg.n_slots, self._block),
+                                           np.int32)
+            self._opened: Dict[int, np.ndarray] = {}
         self._block_passes = 0      # row-passes run
         self._block_commits = 0     # of them over a clean block
         self._block_fills = 0       # positions filled
@@ -630,11 +660,11 @@ class InferenceEngine:
                     it.set(rows=len(self._running))
                     if self._running and chunks:
                         self._prefill_chunk_iterations += 1
-                    if self._block:
-                        if self._running:
+                    if self._running or self._inflight is not None:
+                        if self._block:
                             self._block_all()
-                    elif self._running or self._inflight is not None:
-                        self._decode_all()
+                        else:
+                            self._decode_all()
             except Exception as e:  # noqa: BLE001
                 # an engine-loop crash (XLA error, bad params) must not
                 # strand every future unresolved: fail them typed, with
@@ -1046,34 +1076,41 @@ class InferenceEngine:
 
     def _run_blocks(self, req: Request) -> None:
         """``req`` becomes a running row of the block step: its first
-        block opens with what the prompt's whole blocks left over."""
+        block opens, in its first pass, with what the prompt's whole
+        blocks left over."""
+        slot, L = req.slot, self._block
         req.state = RUNNING
-        self._running[req.slot] = req
+        self._running[slot] = req
         req.fill_schedule = fill_counts(
-            self._block, req.params.denoise_steps or self._block)
-        self._open_block(req.slot, req.prompt[
-            len(req.prompt) - len(req.prompt) % self._block:])
-
-    def _open_block(self, slot: int, given=()) -> None:
-        """A new block for ``slot``: ``given`` tokens, then the mask id."""
-        n = len(given)
-        self._blk_tokens[slot] = self.model.mask_id
-        self._blk_tokens[slot, :n] = given
-        self._blk_masked[slot] = True
-        self._blk_masked[slot, :n] = False
-        self._blk_fill_pass[slot] = -1
+            L, req.params.denoise_steps or L)
+        head = req.prompt[len(req.prompt) - len(req.prompt) % L:]
+        self._opened[slot] = head
+        self._blk_left[slot] = self._blk_owed[slot] = L - len(head)
         self._blk_passes[slot] = 0
+        # a later block needs no such reset: every position of it is
+        # filled, and its pass recorded, before it is streamed
+        self._blk_fill_pass[slot] = -1
 
     def _block_all(self) -> None:
-        """One pass of block generation for every running row, in ONE
-        program whatever pass each row is in: a row with masked
-        positions has the pass's count of them filled (on the device, by
-        confidence); a row whose block is clean runs its commit pass,
-        after which its length advances and its next block opens. A
-        block's tokens are streamed in position order in the iteration
-        that fills its last masked position, before its commit pass."""
+        """The block path's step, one block pass kept in flight: with
+        pass ``k`` still running, dispatch pass ``k + 1`` over the rows
+        that have one, and only then read ``k``, record which pass
+        filled which position and stream the blocks ``k`` cleaned (in
+        position order, before their commit pass is read). A pass fills
+        ``min(n_fill, masked)`` positions of a row's block and
+        ``n_fill`` is the row's ``fill_schedule``, so the host knows
+        ahead what ``k + 1`` is for every row: a fill; the commit pass
+        of a block ``k`` cleaned; or nothing, where that block streams
+        the request's last token (``max_new_tokens``: no commit pass,
+        nobody reads its keys). What only the tokens tell (an
+        ``eos_token`` inside a block), a deadline or a failure leaves a
+        row in ``k + 1`` that is gone when it is read: it is dropped
+        there (``decode_rows_dropped``)."""
         L, it = self._block, self._iteration
-        slots = sorted(self._running)
+        prev, self._inflight = self._inflight, None
+        slots = [slot for slot, req in sorted(self._running.items())
+                 if self._blk_left[slot]
+                 or self._blk_owed[slot] < req.params.max_new_tokens]
         with dpxtrace.span("serve.decode.capacity", iteration=it):
             for slot in list(slots):
                 req = self._running[slot]
@@ -1090,70 +1127,117 @@ class InferenceEngine:
                         request_id=req.request_id, iteration=it),
                         outcome="no_free_pages")
                     slots.remove(slot)
-        if not slots:
-            return
+        if slots:
+            self._dispatch_blocks(slots, ahead=prev is not None)
+        if prev is not None:
+            self._read_blocks(prev)
+        if self._inflight is not None and not self._running:
+            # as _decode_all: nothing else would wake the loop to read it
+            self._read_blocks()
+
+    def _dispatch_blocks(self, slots: List[int], ahead: bool) -> None:
+        """Dispatch one block pass over the rows in ``slots``, each
+        advanced one pass in the host's count; it is ``_inflight`` from
+        here on. ``ahead``: the pass before is not yet read."""
         clock = time.perf_counter_ns
-        rows = len(slots)
-        active = np.zeros(self.config.n_slots, bool)
-        active[slots] = True
-        commit = active & ~self._blk_masked.any(axis=1)
-        n_fill = np.zeros(self.config.n_slots, np.int32)
+        L, it, n = self._block, self._iteration, self.config.n_slots
+        active = np.zeros(n, bool)
+        n_fill = np.zeros(n, np.int32)
+        given = np.zeros((n, L), np.int32)
+        n_given = np.full(n, -1, np.int32)
+        rows: Dict[int, Tuple[Request, int, bool]] = {}
         for slot in slots:
-            if not commit[slot]:
-                sched = self._running[slot].fill_schedule
-                n_fill[slot] = sched[min(self._blk_passes[slot],
-                                         len(sched) - 1)]
-        commits = int(commit.sum())
+            req = self._running[slot]
+            active[slot] = True
+            head = self._opened.get(slot)
+            if head is not None:
+                given[slot, :len(head)] = head
+                n_given[slot] = len(head)
+            left = self._blk_left[slot]
+            if not left:
+                # its commit pass; the program opens its next block
+                rows[slot] = (req, -1, False)
+                self._blk_left[slot] = L
+                self._blk_owed[slot] += L
+                self._blk_passes[slot] = 0
+                continue
+            at = self._blk_passes[slot]
+            sched = req.fill_schedule
+            n_fill[slot] = sched[min(at, len(sched) - 1)]
+            left = self._blk_left[slot] = max(left - n_fill[slot], 0)
+            self._blk_passes[slot] = at + 1
+            rows[slot] = (req, at, not left)
+        # a request that opened a block and has no row of this pass has
+        # failed since (the capacity check)
+        self._opened.clear()
         t0 = clock()
-        with dpxtrace.span("serve.decode.dispatch", iteration=it, rows=rows):
-            out = self.pool.block_step(
-                self.params, self._blk_tokens, self._blk_masked, n_fill,
-                active, commit, iteration=it)
+        with dpxtrace.span("serve.decode.dispatch", iteration=it,
+                           rows=len(rows)):
+            out = self.pool.block_step(self.params, given, n_given, n_fill,
+                                       active, iteration=it)
+        self._host_ns["decode_dispatch"] += clock() - t0
+        self._passes_ahead += ahead
+        self._inflight = _BlockPass(it, out, rows)
+        self._rows_decoded += len(rows)
+        self._block_passes += len(rows)
+        self._block_commits += sum(at < 0 for _, at, _ in rows.values())
+
+    def _read_blocks(self, p: Optional[_BlockPass] = None) -> None:
+        """Read a dispatched block pass (default: the one in flight,
+        which then is none): the pass's one read, where the host waits
+        for the block-step program that the dispatch span of iteration
+        ``p.iteration`` launched. Then every row's record of the pass,
+        and the stream of each block it cleaned. A row whose request has
+        finished or failed since is dropped."""
+        if p is None:
+            p, self._inflight = self._inflight, None
+        clock = time.perf_counter_ns
+        it, rows = self._iteration, len(p.rows)
+        commits = sum(at < 0 for _, at, _ in p.rows.values())
         t1 = clock()
-        with dpxtrace.span("serve.decode.rows", iteration=it, rows=rows):
-            # the iteration's one read: here the host waits for the
-            # block-step program
+        with dpxtrace.span("serve.decode.rows", iteration=it,
+                           dispatched=p.iteration, rows=rows):
             t2 = clock()
             with dpxtrace.span("serve.decode.fetch", iteration=it,
-                               rows=rows, commits=commits) as fetch:
-                out = np.asarray(out)
+                               dispatched=p.iteration, rows=rows,
+                               commits=commits) as fetch:
+                out = np.asarray(p.out)
                 filled = out[:, 1].astype(bool)
-                fills = int(filled[slots].sum())
+                # a dropped row's fills are nobody's: not counted
+                fills = int(filled[[slot for slot, (req, _, _)
+                                    in p.rows.items()
+                                    if not req.done]].sum())
                 fetch.set(fills=fills)
             self._host_ns["decode_fetch"] += clock() - t2
             self._decode_fetches += 1
             emitted = 0
             with dpxtrace.span("serve.block.advance", iteration=it) as adv:
-                for slot in slots:
-                    req = self._running[slot]
-                    if commit[slot]:
-                        self._open_block(slot)   # its length has advanced
-                        continue
-                    self._blk_tokens[slot] = out[slot, 0]
-                    self._blk_masked[slot] &= ~filled[slot]
-                    self._blk_fill_pass[slot, filled[slot]] = \
-                        self._blk_passes[slot]
-                    self._blk_passes[slot] += 1
-                    if not self._blk_masked[slot].any():
-                        self._emit_block(req)
-                        emitted += 1
+                for slot, (req, at, cleans) in p.rows.items():
+                    if req.done:
+                        self._rows_dropped += 1
+                    elif at >= 0:
+                        self._blk_fill_pass[slot, filled[slot]] = at
+                        if cleans:
+                            # the host's count against the device's mask
+                            if out[slot, 2].any():
+                                raise RuntimeError(
+                                    f"request {req.request_id}: slot "
+                                    f"{slot}'s block counted clean at pass "
+                                    f"{at} holds masked positions")
+                            self._emit_block(req, out[slot, 0])
+                            emitted += 1
                 adv.set(blocks=emitted, commits=commits)
-        self._host_ns["decode_dispatch"] += t1 - t0
         self._host_ns["row_loop"] += clock() - t1
-        self._rows_decoded += rows
-        self._block_passes += rows
-        self._block_commits += commits
         self._block_fills += fills
         self._blocks_emitted += emitted
 
-    def _emit_block(self, req: Request) -> None:
-        """Stream ``req``'s finished block in position order: the
-        positions the prompt gave are not output, and what lies past
-        ``max_new_tokens`` (or an ``eos_token``) is dropped with the
-        request's retirement."""
-        slot = req.slot
-        for tok, at in zip(self._blk_tokens[slot].tolist(),
-                           self._blk_fill_pass[slot].tolist()):
+    def _emit_block(self, req: Request, tokens: np.ndarray) -> None:
+        """Stream ``req``'s finished block, ``tokens`` (L,), in position
+        order: the positions the prompt gave are not output, and what
+        lies past ``max_new_tokens`` (or an ``eos_token``) is dropped
+        with the request's retirement."""
+        for tok, at in zip(tokens.tolist(),
+                           self._blk_fill_pass[req.slot].tolist()):
             if at < 0:
                 continue
             req.fill_pass.append(at)

@@ -95,7 +95,7 @@ from ...parallel import moe
 from ...runtime import faults
 from ..cache import (CompileCounts, greedy_tokens, named_program,
                      upload, upload_pass)
-from ..sampling import fill_block
+from ..sampling import carry_blocks, fill_block, open_blocks
 from .pool import PagePool
 from .prefix import PrefixIndex
 from .quant import resolve_kv_bits
@@ -241,6 +241,13 @@ class PagedSlotPool:
                               for blk in model.blocks)
         self.moe_counts = jnp.zeros((4,), jnp.int32) \
             if self.moe_layers else None
+        # a block generator's rows' blocks: what one block-step program
+        # left (``sampling.carry_blocks``: tokens, the positions it
+        # filled, the positions still masked) is what the next runs
+        # over, never uploaded, and what the host reads of the pass. The
+        # host hands in only the block a request opens with (block_step)
+        self.blocks = jnp.zeros((n_slots, 3, self.gen_block), jnp.int32) \
+            if self.gen_block else None
         # host-side state: page tables / lengths mirror the traced args
         # (tiny int32 uploads per call), policy state never leaves host.
         # They are uploaded through ``upload`` (a copy of their own):
@@ -311,13 +318,20 @@ class PagedSlotPool:
                           jnp.maximum(counts[2], jnp.max(c[:, 2])),
                           counts[3] + 1])
 
-    def _decode_block(self, params, state, counts, tables, lengths, tokens,
-                      masked, n_fill, active):
+    def _decode_block(self, params, state, counts, blocks, tables, lengths,
+                      given, n_given, n_fill, active):
         """The ONE block-step program of a model that generates by
-        blocks, in the decode program's place (and counted as it): one
-        pass of the model over every row's block, then the pick
-        (``sampling.fill_block``), all on the device."""
+        blocks, in the decode program's place (and counted as it): the
+        rows' blocks as the pass before left them, but for the rows the
+        host opens one for (``sampling.open_blocks``); one pass of the
+        model over every row's block; the pick (``sampling.fill_block``);
+        and what the pass leaves (``sampling.carry_blocks``: an active
+        row with nothing to fill ran its commit pass and leaves with a
+        fresh block), all on the device: ONE array, which the host reads
+        a pass later and the next pass runs over."""
         self.compiles.decode += 1          # trace-time only
+        mask_id = self.model.mask_id
+        tokens, masked = open_blocks(blocks, given, n_given, mask_id)
         before, moe_before = kernel_traces(), moe.kernel_traces()
         per_layer = None if counts is None else []
         logits, state = block_step_slots_paged(
@@ -326,7 +340,9 @@ class PagedSlotPool:
         self.compiles.decode_kernel_layers = kernel_traces() - before
         self.compiles.moe_kernel_matmuls = moe.kernel_traces() - moe_before
         counts = self._counted(counts, per_layer)
-        return fill_block(logits, tokens, masked, n_fill), state, counts
+        blocks = carry_blocks(fill_block(logits, tokens, masked, n_fill),
+                              masked, active & (n_fill == 0), mask_id)
+        return blocks, state, counts
 
     def _verify(self, params, state, tables, lengths, tokens):
         # trace-time only; one compile per draft-length bucket (the
@@ -515,26 +531,33 @@ class PagedSlotPool:
         self.lengths[np.asarray(active)] += 1
         return out, logits
 
-    def block_step(self, params, tokens: np.ndarray, masked: np.ndarray,
+    def block_step(self, params, given: np.ndarray, n_given: np.ndarray,
                    n_fill: np.ndarray, active: np.ndarray,
-                   commit: np.ndarray, iteration: Optional[int] = None):
+                   iteration: Optional[int] = None):
         """One pass of block generation for every active slot through
-        the ONE jitted block-step program: ``tokens`` (n_slots, L) int32
-        the rows' blocks (the model's ``mask_id`` where ``masked``
-        (n_slots, L) bool), ``n_fill`` (n_slots,) how many masked
-        positions each row's pass fills. A row in ``commit`` (n_slots,)
-        bool runs over its clean block: what this pass writes is the
-        block's resident keys and values, and the row's length advances
-        by ``L`` here. The pass's six copies to the device are made
-        under ``serve.decode.upload``. Returns (n_slots, 2, L) int32 on
-        the device: the blocks after the pass, and the positions it
-        filled."""
-        args = upload_pass(self, iteration, (
-            self.tables, self.lengths, tokens, masked, n_fill, active))
-        out, self.state, self.moe_counts = self._block_fn(
-            params, self.state, self.moe_counts, *args)
-        self.lengths[np.asarray(commit)] += self.gen_block
-        return out
+        the ONE jitted block-step program, over the rows' blocks where
+        the pass before left them, on the device (``self.blocks``).
+        ``n_fill`` (n_slots,) int32: how many masked positions each
+        row's pass fills; an active row with 0 runs over its clean block
+        (its commit pass): what this pass writes is the block's resident
+        keys and values, the row's length advances by ``L`` here, and
+        its next block opens inside the program. A row whose
+        ``n_given`` (n_slots,) int32 is not -1 opens a block of the
+        host's: that many tokens of ``given`` (n_slots, L) int32, then
+        masked positions. All four are the caller's for this pass alone
+        (nobody writes them again); with the tables and the lengths they
+        are the pass's six copies to the device, made under
+        ``serve.decode.upload``. Returns ``self.blocks`` as the pass
+        leaves it, (n_slots, 3, L) int32 on the device: the blocks after
+        the pass, the positions it filled, the positions still masked
+        (``sampling.carry_blocks``). It is not donated to the next pass:
+        the caller reads it with that pass already dispatched."""
+        args = upload_pass(self, iteration, (self.tables, self.lengths),
+                           (given, n_given, n_fill, active))
+        self.blocks, self.state, self.moe_counts = self._block_fn(
+            params, self.state, self.moe_counts, self.blocks, *args)
+        self.lengths[active & (n_fill == 0)] += self.gen_block
+        return self.blocks
 
     def ensure_spec_capacity(self, slot: int, n_new: int) -> None:
         """Grow ``slot``'s page table so the next ``n_new`` committed

@@ -74,9 +74,42 @@ def fill_block(logits, tokens, masked, n_fill):
     n_fill (B,) int32 (0 for a row whose block is clean: its commit
     pass, or an idle row). Returns (B, 2, L) int32, the block after the
     pass and which positions this pass filled, one array so that the
-    engine reads an iteration in one fetch."""
+    engine reads a pass in one fetch."""
     x0, conf = block_confidence(logits)
     return fill_surest(x0, conf, tokens, masked, n_fill)
+
+
+@jax.named_scope("sample/denoise/open")
+def open_blocks(blocks, given, n_given, mask_id: int):
+    """The blocks one pass runs over. ``blocks`` (B, 3, L) int32 is what
+    the pass before left on the device (:func:`carry_blocks`): the rows'
+    tokens and, in its last row, 1 where a position is still masked. A
+    row the host opens a block for (``n_given`` (B,) int32 >= 0: a
+    request's first block, which its prompt's remainder begins) takes
+    instead the first ``n_given`` of ``given`` (B, L) int32, then
+    ``mask_id``, masked; -1 keeps the row's block as it was left.
+    Returns tokens (B, L) int32 and masked (B, L) bool."""
+    rest = jnp.arange(blocks.shape[2])[None, :] >= n_given[:, None]
+    opened = (n_given >= 0)[:, None]
+    return (jnp.where(opened, jnp.where(rest, mask_id, given), blocks[:, 0]),
+            jnp.where(opened, rest, blocks[:, 2] != 0))
+
+
+@jax.named_scope("sample/denoise/carry")
+def carry_blocks(out, masked, commit, mask_id: int):
+    """What a pass leaves on the device, (B, 3, L) int32: both what the
+    host reads of it a pass later (the blocks' tokens, the positions the
+    pass filled) and what the next pass runs over (the tokens, and 1
+    where a position is still masked). ``out`` is :func:`fill_block`'s
+    of this pass over blocks that were ``masked`` (B, L) bool before it.
+    A row in ``commit`` (B,) bool ran over its clean block, whose keys
+    and values this pass made resident: it leaves with its next block,
+    every position ``mask_id`` and masked (the host streamed the clean
+    block a pass earlier and reads no token of a commit pass)."""
+    fresh = commit[:, None]
+    left = fresh | (masked & (out[:, 1] == 0))
+    return jnp.stack([jnp.where(fresh, mask_id, out[:, 0]), out[:, 1],
+                      left.astype(jnp.int32)], axis=1)
 
 
 def fill_counts(block: int, steps: int):

@@ -331,12 +331,67 @@ def test_a_pass_uploads_under_one_span_before_its_program(pool, lm, cfg,
         assert up["attrs"]["iteration"] == parent["attrs"]["iteration"]
         assert up["attrs"]["arrays"] == arrays
     n = eng.config.n_slots
-    tokens = n * 4 * (model.gen_block or 0)      # the blocks; no token row
+    # a block pass: the tokens a request's first block opens with (the
+    # blocks themselves stay on the device), their count, n_fill
+    given = n * 4 * (model.gen_block or 0)
     held = eng.pool.tables.nbytes + n * 4 if cfg else 0
-    extra = (tokens // 4 + n * 4) if model.gen_block else 0  # mask, n_fill
-    assert {u["attrs"]["bytes"] for u in ups} == {held + tokens + n + extra}
+    extra = 2 * n * 4 if model.gen_block else 0
+    assert {u["attrs"]["bytes"] for u in ups} == {held + given + n + extra}
     assert stats["host_ns"]["decode_upload"] == eng.pool.upload_ns \
         >= sum(u["dur_ns"] for u in ups)
+
+
+def test_block_passes_are_read_an_iteration_after_their_dispatch():
+    """A block generator's ``serve.decode.rows`` and ``serve.decode.fetch``
+    carry ``dispatched`` as the token path's do, the next pass's dispatch
+    begins before the read, ``serve.block.advance`` lies inside the read,
+    and the ``serve.stats`` mark carries the counters of the pass in
+    flight beside the block counters. From the flight ring."""
+    dpxtrace.configure(enabled=True, ring=4096, log_path=None)
+    model = tiny_lm(**BLOCK_LM)
+    eng = InferenceEngine(model, model.init(jax.random.PRNGKey(0)),
+                          EngineConfig(n_slots=3, max_len=64, paged=True,
+                                       page_len=8, buckets=(8, 16)))
+    with eng:
+        hs = [eng.submit(np.arange(n, dtype=np.int32),
+                         SamplingParams(max_new_tokens=new))
+              for n, new in ((9, 7), (4, 10))]
+        for h in hs:
+            h.result(timeout=300)
+        stats = eng.stats()
+    ring, dropped = dpxtrace.flight_snapshot()
+    assert dropped == 0
+    by = lambda name: [r for r in ring if r["name"] == name]
+    rows, fetches = by("serve.decode.rows"), by("serve.decode.fetch")
+    dispatch = {r["attrs"]["iteration"]: r for r in by("serve.decode.dispatch")}
+    assert len(rows) == len(fetches) == len(dispatch) \
+        == stats["decode_fetches"] >= 10
+    for read in rows + fetches:
+        at = read["attrs"]
+        assert at["iteration"] == at["dispatched"] + 1
+        assert at["rows"] == dispatch[at["dispatched"]]["attrs"]["rows"]
+    assert sorted(r["attrs"]["dispatched"] for r in rows) == sorted(dispatch)
+    ended = {r["span_id"]: i for i, r in enumerate(ring)}  # in that order
+    for f in fetches:
+        assert {"commits", "fills"} <= set(f["attrs"])
+        # the next pass is on its way before this one is read
+        nxt = dispatch.get(f["attrs"]["iteration"])
+        assert nxt is None or ended[nxt["span_id"]] < ended[f["span_id"]]
+    parents = {r["span_id"] for r in rows}
+    advances = by("serve.block.advance")
+    assert len(advances) == len(rows)
+    assert all(a["parent_id"] in parents for a in advances + fetches)
+    assert sum(a["attrs"]["blocks"] for a in advances) \
+        == stats["blocks_emitted"]
+    assert sum(f["attrs"]["fills"] for f in fetches) == stats["block_fills"]
+    assert sum(f["attrs"]["commits"] for f in fetches) \
+        == stats["block_commits"]
+    assert stats["decode_passes_ahead"] == len(dispatch) - 1
+    (mark,) = by("serve.stats")
+    for key in ("decode_passes_ahead", "decode_rows_dropped", "block_passes",
+                "block_commits", "block_fills", "blocks_emitted",
+                "tokens_emitted"):
+        assert mark["attrs"][key] == stats[key], key
 
 
 @pytest.mark.parametrize("mon", [True, False])

@@ -243,6 +243,8 @@ def test_a_deadline_fails_only_its_row_with_its_step_in_flight(lm):
                    SamplingParams(max_new_tokens=3, temperature=0.7,
                                   top_k=8)):
             eng.submit(np.arange(4, dtype=np.int32), sp).result(timeout=120)
+        for n in (40, 20):                  # and submit()'s key splits
+            jax.random.split(jax.random.PRNGKey(0), n)
         before = eng.stats()
         # the sixth iteration from here: both rows are decoding
         faults.install("delay@op=serve_step,call=6,ms=1500")
