@@ -1122,8 +1122,9 @@ def bench_decode_attention(max_len: int = DECODE_ATTN_POOL,
     import numpy as np
 
     from distributed_pytorch_tpu import models
-    from distributed_pytorch_tpu.models.generate import (decode_step_slots,
-                                                         make_generate_fn)
+    from distributed_pytorch_tpu.models.generate import (
+        decode_step_slots_paged, make_generate_fn)
+    from distributed_pytorch_tpu.nn.paged import ExactSide, KVPages
     from distributed_pytorch_tpu.ops.decode_attention import (
         DECODE_BLOCK, resident_blocks)
     from distributed_pytorch_tpu.serve import (EngineConfig,
@@ -1158,13 +1159,18 @@ def bench_decode_attention(max_len: int = DECODE_ATTN_POOL,
 
     # (ii) decode step time at short resident length, blockwise vs the
     # dense full-pool softmax — same jitted step, same donation, only
-    # the kernel differs
+    # the kernel differs (a page is one DECODE_BLOCK of positions)
+    per_row = -(-max_len // DECODE_BLOCK)
+    tables = jnp.arange(n_slots * per_row, dtype=jnp.int32).reshape(
+        n_slots, per_row)
+    active = jnp.ones((n_slots,), bool)
+
     def make_step(blockwise):
-        def f(p, ks, vs, lengths, tokens):
-            lo, ks, vs = decode_step_slots(model, p, ks, vs, lengths,
-                                           tokens, blockwise=blockwise)
-            return lo, ks, vs
-        return jax.jit(f, donate_argnums=(1, 2))
+        def f(p, state, lengths, tokens):
+            return decode_step_slots_paged(
+                model, p, state, tables, lengths, tokens, active,
+                page_len=DECODE_BLOCK, blockwise=blockwise)
+        return jax.jit(f, donate_argnums=(1,))
 
     dh = model.dim // model.n_heads
     lengths = jnp.asarray(
@@ -1172,15 +1178,14 @@ def bench_decode_attention(max_len: int = DECODE_ATTN_POOL,
     tokens = jnp.asarray(rng.integers(0, 128, (n_slots,)), jnp.int32)
 
     def one_run(step_fn):
-        ks = [jnp.asarray(rng.standard_normal((n_slots, 2, max_len, dh)),
-                          jnp.float32) for _ in range(model.n_layers)]
-        vs = [jnp.asarray(rng.standard_normal((n_slots, 2, max_len, dh)),
-                          jnp.float32) for _ in range(model.n_layers)]
-        lo, ks, vs = step_fn(params, ks, vs, lengths, tokens)  # compile
+        side = lambda: ExactSide(jnp.asarray(rng.standard_normal(
+            (n_slots * per_row, 2, DECODE_BLOCK, dh)), jnp.float32))
+        state = [KVPages(side(), side()) for _ in range(model.n_layers)]
+        lo, state = step_fn(params, state, lengths, tokens)  # compile
         fetch_fence(lo)
         t0 = time.perf_counter()
         for _ in range(steps):
-            lo, ks, vs = step_fn(params, ks, vs, lengths, tokens)
+            lo, state = step_fn(params, state, lengths, tokens)
         fetch_fence(lo)
         return steps / (time.perf_counter() - t0)   # steps/s
 

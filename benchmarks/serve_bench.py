@@ -165,7 +165,7 @@ def make_shared_requests(n, vocab, max_new, seed, k_prefixes, prefix_len,
 
 
 def run_engine(model, params, reqs, n_slots, max_len, rate=None, seed=0,
-               paged=False, page_len=None, prefix_share=True,
+               page_len=None, prefix_share=True,
                kv_dtype=None, draft_model=None, draft_params=None,
                draft_len=None, metrics=None, log_every=16):
     """Submit ``reqs`` (closed loop, or Poisson open loop at ``rate``)
@@ -176,7 +176,7 @@ def run_engine(model, params, reqs, n_slots, max_len, rate=None, seed=0,
                                                InferenceEngine, aggregate)
     eng = InferenceEngine(model, params,
                           EngineConfig(n_slots=n_slots, max_len=max_len,
-                                       paged=paged, page_len=page_len,
+                                       page_len=page_len,
                                        prefix_share=prefix_share,
                                        kv_dtype=kv_dtype,
                                        spec_decode=draft_model is not None,
@@ -200,8 +200,7 @@ def run_engine(model, params, reqs, n_slots, max_len, rate=None, seed=0,
     rep["stats"] = {k: v for k, v in st.items()
                     if k in ("iterations", "decode_compiles",
                              "prefill_compiles", "sample_compiles")}
-    if paged:
-        rep["pages"] = st["pages"]
+    rep["pages"] = st["pages"]
     if draft_model is not None:
         rep["spec"] = st["spec"]
     return rep, outs
@@ -252,7 +251,6 @@ def run_fleet(model, params, reqs, n_replicas, n_slots, max_len,
         model, params,
         FleetConfig(n_replicas=n_replicas,
                     engine=EngineConfig(n_slots=n_slots, max_len=max_len,
-                                        paged=page_len is not None,
                                         page_len=page_len),
                     metrics=metrics))
     rng = np.random.default_rng(seed)
@@ -420,7 +418,7 @@ def fleet_smoke(argv):
         model, params,
         FleetConfig(n_replicas=2,
                     engine=EngineConfig(n_slots=2, max_len=max_len,
-                                        paged=True, page_len=page_len),
+                                        page_len=page_len),
                     metrics=MetricsLogger(log), log_every=4))
     rng = np.random.default_rng(5)
     with fleet:
@@ -657,7 +655,7 @@ def main(argv):
     def shared_once():
         rep, outs = run_engine(model, params, shared_reqs, n_slots,
                                max_len, rate=rate, seed=seed + 2,
-                               paged=True, page_len=page_len)
+                               page_len=page_len)
         first_shared.setdefault("outs", outs)
         first_shared.setdefault("rep", rep)
         return rep
@@ -669,7 +667,8 @@ def main(argv):
     rec["arms"]["engine_paged_shared"] = shared_rep
     unshared_rep, unshared_st = measured_stats(
         lambda: run_engine(model, params, shared_reqs, n_slots, max_len,
-                           rate=rate, seed=seed + 2)[0],
+                           rate=rate, seed=seed + 2, page_len=page_len,
+                           prefix_share=False)[0],
         ("ttft_ms_p50", "ttft_ms_p99"), warmup=warmup, trials=trials)
     rec["arms"]["engine_unshared_open"] = unshared_rep
     for name, stx in (
@@ -764,8 +763,7 @@ def main(argv):
     rec_d["arms"]["engine_disagg_open"] = disagg_rep
     mono_rep, mono_st = measured_stats(
         lambda: run_engine(model, params, mixed, n_slots, max_len,
-                           rate=rate, seed=seed + 3, paged=True,
-                           page_len=page_len)[0],
+                           rate=rate, seed=seed + 3, page_len=page_len)[0],
         lat_keys, warmup=warmup, trials=trials, absent_as_zero=())
     rec_d["arms"]["engine_monolithic_open"] = mono_rep
     for k in lat_keys:
@@ -880,7 +878,7 @@ def main(argv):
         # closed loop on purpose: identical admission order on every
         # trial makes the q8-vs-f32 token comparison deterministic
         rep, outs = run_engine(model, params, shared_reqs, n_slots,
-                               max_len, paged=True, page_len=page_len,
+                               max_len, page_len=page_len,
                                kv_dtype="q8")
         first_kvq.setdefault("outs", outs)
         first_kvq.setdefault("rep", rep)
@@ -891,8 +889,7 @@ def main(argv):
         trials=trials, absent_as_zero=())
     rec_q["arms"]["engine_paged_q8"] = kvq_rep
     f32_rep, f32_outs = run_engine(model, params, shared_reqs, n_slots,
-                                   max_len, paged=True,
-                                   page_len=page_len)
+                                   max_len, page_len=page_len)
     rec_q["arms"]["engine_paged_f32"] = f32_rep
     for k in ("ttft_ms_p50", "ttft_ms_p99"):
         rec_q["metrics"][f"serve_kvq_{k}"] = pbrecord.make_metric(
@@ -999,7 +996,7 @@ def main(argv):
 
     def spec_once():
         rep, souts = run_engine(model, params, mixed, n_slots, max_len,
-                                paged=True, page_len=page_len,
+                                page_len=page_len,
                                 draft_model=draft_model,
                                 draft_params=draft_params,
                                 draft_len=draft_len)
@@ -1013,7 +1010,7 @@ def main(argv):
     rec_s["arms"]["engine_spec_closed"] = spec_rep
     nonspec_rep, nonspec_sts = measured_stats(
         lambda: run_engine(model, params, mixed, n_slots, max_len,
-                           paged=True, page_len=page_len)[0],
+                           page_len=page_len)[0],
         spec_keys, warmup=warmup, trials=trials, absent_as_zero=())
     rec_s["arms"]["engine_nonspec_closed"] = nonspec_rep
     for k in spec_keys:
@@ -1092,7 +1089,7 @@ def main(argv):
                 "vs_nonspec_tpot_p50_x / vs_nonspec_tpot_p50_withheld")
         workdir = tempfile.mkdtemp(prefix="dpx_spec_smoke_")
         log = os.path.join(workdir, "spec_metrics.jsonl")
-        run_engine(model, params, mixed, n_slots, max_len, paged=True,
+        run_engine(model, params, mixed, n_slots, max_len,
                    page_len=page_len, draft_model=draft_model,
                    draft_params=draft_params, draft_len=draft_len,
                    metrics=MetricsLogger(log), log_every=2)

@@ -8,7 +8,7 @@ the entry points a user calls, at the full width of the pinned 135M
 1. the reference workload: ``dist.launch(min_ddp.main_worker)`` on one chip;
 2. the trainer: the flash kernels against the dense reference, then the
    flagship train step through the front door for a few steps;
-3. the server: ``InferenceEngine(paged=True)`` answering streamed requests,
+3. the server: ``InferenceEngine`` answering streamed requests,
    greedy streams compared with standalone ``generate()`` (equal up to the
    first bf16 tie — docs/serving.md, "On the chip");
 4. with four or more chips: ``min_ddp`` at world 4, the flagship step at
@@ -265,7 +265,7 @@ def phase_server(model, cfg, prompt_lens=(12, 17, 600, 12), max_new=24):
     prompts = [rng.integers(0, cfg["vocab"], n).astype(np.int32)
                for n in prompt_lens]
     eng = InferenceEngine(model, params, EngineConfig(
-        paged=True, n_slots=4, max_len=cfg["seq"]))
+        n_slots=4, max_len=cfg["seq"]))
     check(eng.pool.page_len + 1 in prompt_lens,
           f"no prompt one past a page boundary (page_len "
           f"{eng.pool.page_len})")

@@ -40,8 +40,7 @@ from ..nn.paged import (BlockCtx, BlockGenerationUnsupported,  # noqa: F401
                         DecodeCtx, LatentPagesUnsupported,
                         MixedStoresUnsupported, MixerStoresUnsupported,
                         PrefillCtx, VerifyCtx, block_unsupported,
-                        latent_unsupported, mixed_unsupported,
-                        mixers_unsupported, table_pages)
+                        mixed_unsupported, mixers_unsupported, table_pages)
 from ..ops.decode_attention import (blockwise_decode_attention,
                                     dense_decode_attention)
 from .transformer import TransformerLM
@@ -199,133 +198,6 @@ def decode_step(model: TransformerLM, params: Params, cache: KVCache,
     return logits, KVCache(k=new_k, v=new_v, length=idx + 1)
 
 
-def prefill_partial(model: TransformerLM, params: Params, tokens,
-                    true_len,
-                    window: Optional[int] = None
-                    ) -> Tuple[jnp.ndarray, list, list]:
-    """Prefill over a RIGHT-PADDED prompt — the slot-writable half of
-    :func:`prefill` for the serving engine (``serve/``).
-
-    tokens: (B, S) int32 where only the first ``true_len`` positions are
-    real (``true_len`` may be traced — one compile per padded length
-    bucket, not per prompt length). Causality makes the pad tail inert:
-    real query positions never attend a later pad key (the pad keys
-    only ever contribute exact zeros to masked-softmax sums), so the
-    logits at position ``true_len - 1`` pick the same token as an
-    exact-length :func:`prefill` and agree with it to a few f32 ulps —
-    the two lengths are two XLA programs that reduce in different
-    orders, so bit-identity across them is not promised.
-
-    Returns ``(logits (B, vocab) at the last real position, ks, vs)``
-    where ks/vs are per-layer (B, Hkv, S, Dh) — or, with ``window``, the
-    (B, Hkv, W, Dh) ROLLING layout of :func:`prefill` (position p at
-    slot ``p % W``, unreached slots zeroed) built by gather so
-    ``true_len`` can stay traced. The caller owns writing these rows
-    into a cache pool (``serve/cache.py``)."""
-    b, s = tokens.shape
-    true_len = jnp.asarray(true_len, jnp.int32)
-    x = model.tok.apply(params["tok"], tokens)
-    positions = jnp.arange(s)
-    if getattr(model, "pos", None) is not None:
-        x = x + model.pos.apply(params["pos"], positions)
-    ks, vs = [], []
-    for i, blk in enumerate(model.blocks):
-        with jax.named_scope("blocks"):
-            p = params["blocks"][i]
-            hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                              blk.ln1.apply(p["ln1"], x))
-            hq, hk = blk.attn.maybe_rope(hq, hk, positions)
-            o = blk.attn.attn_fn(hq, hk, hv, causal=True)
-            x = x + blk.attn.project_out(p["attn"], o)
-            x = x + blk.mlp(p, x)
-            hk = hk.astype(model.dtype)
-            hv = hv.astype(model.dtype)
-            if window is not None:
-                # rolling layout with a TRACED true_len: slot j holds the
-                # largest real position ≡ j (mod W) — a gather, so no
-                # dynamic shapes (prefill's roll trick needs static lengths)
-                j = jnp.arange(window)
-                p_j = true_len - 1 - ((true_len - 1 - j) % window)
-                valid = (p_j >= 0)[None, None, :, None]
-                take = jnp.take(hk, jnp.clip(p_j, 0, s - 1), axis=2)
-                ks.append(jnp.where(valid, take, 0))
-                take = jnp.take(hv, jnp.clip(p_j, 0, s - 1), axis=2)
-                vs.append(jnp.where(valid, take, 0))
-            else:
-                ks.append(hk)
-                vs.append(hv)
-    x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)
-    x_last = model.ln_f.apply(params["ln_f"], x_last)
-    return model.project_vocab(params, x_last)[:, 0], ks, vs
-
-
-def decode_step_slots(model: TransformerLM, params: Params, ks, vs,
-                      lengths, tokens,
-                      window: Optional[int] = None,
-                      blockwise: bool = True
-                      ) -> Tuple[jnp.ndarray, list, list]:
-    """One decode step over a SLOT POOL: per-row cache lengths.
-
-    The continuous-batching generalization of :func:`decode_step` — the
-    pool rows are independent requests at different depths, so the
-    scalar ``cache.length`` becomes ``lengths`` (B,) int32 and every
-    row writes/masks at its own position (the write is a where-mask
-    select, value-identical to ``dynamic_update_slice``). ks/vs:
-    per-layer (B, Hkv, max_len, Dh); tokens (B,) int32.
-
-    Attention is page-blockwise by default (see :func:`decode_step`):
-    the cost per step scales with ``max(lengths)``, not the pool's
-    ``max_len`` — a pool sized for long requests no longer taxes every
-    short resident request for its full width. ``blockwise=False``
-    keeps the dense full-width softmax (reference + bench baseline;
-    the sliding-window rolling layout always uses it).
-
-    Per-row math is exactly :func:`decode_step`'s; XLA's fusion choices
-    are batch-shape-dependent, so across DIFFERENT batch shapes logits
-    agree to ~1 ulp rather than bitwise — sampled token streams are
-    what the serving engine guarantees identical (tests/test_serve.py).
-
-    Returns ``(logits (B, vocab), new_ks, new_vs)``; advancing
-    ``lengths`` (and masking dead slots) is the caller's business."""
-    idx = lengths
-    x = model.tok.apply(params["tok"], tokens[:, None])       # (B,1,D)
-    if getattr(model, "pos", None) is not None:
-        x = x + model.pos.apply(params["pos"], idx[:, None])
-    scale = 1.0 / math.sqrt(model.dim // model.n_heads)
-    max_len = ks[0].shape[2]
-    if window is not None:
-        slots = jnp.arange(max_len)[None, :]
-        slot_pos = idx[:, None] - ((idx[:, None] - slots) % window)
-        pos_mask = slot_pos >= 0                           # (B, W)
-        write_at = idx % window
-    else:
-        pos_mask = jnp.arange(max_len)[None, :] <= idx[:, None]
-        write_at = idx
-    write_mask = (jnp.arange(max_len)[None, :]
-                  == write_at[:, None])[:, None, :, None]  # (B,1,L,1)
-
-    new_k, new_v = [], []
-    for i, blk in enumerate(model.blocks):
-        with jax.named_scope("blocks"):
-            p = params["blocks"][i]
-            hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                              blk.ln1.apply(p["ln1"], x))
-            hq, hk = blk.attn.maybe_rope(hq, hk, idx[:, None, None])
-            k = jnp.where(write_mask, hk.astype(ks[i].dtype), ks[i])
-            v = jnp.where(write_mask, hv.astype(vs[i].dtype), vs[i])
-            new_k.append(k)
-            new_v.append(v)
-            if blockwise and window is None:
-                o = blockwise_decode_attention(hq, k, v, idx, scale=scale)
-            else:
-                o = dense_decode_attention(hq, k, v, pos_mask, scale=scale)
-            x = x + blk.attn.project_out(p["attn"], o)
-            x = x + blk.mlp(p, x)
-
-    x = model.ln_f.apply(params["ln_f"], x)
-    return model.project_vocab(params, x)[:, 0], new_k, new_v
-
-
 def decode_step_slots_paged(model: TransformerLM, params: Params, state,
                             tables, lengths, tokens, active, *,
                             page_len: int, blockwise: bool = True,
@@ -350,23 +222,28 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
     (3,) to; ``sel_stats``: one that every sparse-attention layer appends
     its (blocks chosen, blocks resident) to.
 
-    The paged counterpart of :func:`decode_step_slots`: instead of each
-    slot owning a contiguous (max_len) cache row, the entries live in a
-    shared block pool and each slot addresses its pages through
-    ``tables`` (B, P) int32. Slots can therefore SHARE full pages (a
-    refcounted common prefix is resident once); sharing is safe because
-    shared pages are immutable — decode only ever writes each slot's
-    private tail page.
+    The continuous-batching generalization of :func:`decode_step`: the
+    rows are independent requests at different depths, so the scalar
+    ``cache.length`` becomes ``lengths`` (B,) int32 and every row writes
+    and masks at its own position. No row owns a contiguous stripe of
+    ``max_len`` positions: the entries live in a shared block pool and
+    each slot addresses its pages through ``tables`` (B, P) int32. Slots
+    can therefore SHARE full pages (a refcounted common prefix is
+    resident once); sharing is safe because shared pages are immutable:
+    decode only ever writes each slot's private tail page.
 
-    Per-row math is exactly :func:`decode_step_slots`'s: the row's
-    logical cache is the page gather (positions ``j`` at page
+    Per-row math is exactly :func:`decode_step`'s: the row's logical
+    cache is the page gather (positions ``j`` at page
     ``tables[b, j // page_len]`` offset ``j % page_len``), the new entry
     is written at ``lengths[b]`` (into the slot's tail page;
     ``active=False`` rows are routed out of bounds and dropped, so a
     freed slot's stale table cannot be corrupted), and the position mask
-    exposes ``<= lengths[b]``. ``tables``/``lengths``/``tokens``/
-    ``active`` are all traced — ONE compiled program serves every
-    request mix and every page-table state.
+    exposes ``<= lengths[b]``. XLA's fusion choices depend on the batch
+    shape, so across DIFFERENT batch shapes logits agree to ~1 ulp and
+    not bitwise: sampled token streams are what the serving engine
+    guarantees identical (tests/test_serve.py). ``tables``/``lengths``/
+    ``tokens``/``active`` are all traced: ONE compiled program serves
+    every request mix and every page-table state.
 
     Attention runs page-blockwise by default
     (:mod:`..ops.decode_attention`): the page gather moved INSIDE the
@@ -409,14 +286,6 @@ def decode_step_slots_paged(model: TransformerLM, params: Params, state,
 
     x = model.ln_f.apply(params["ln_f"], model.streams_out(x))
     return model.project_vocab(params, x)[:, 0], state
-
-
-def refuse_latent(model, what: str):
-    """For a path that keeps no page stores (the contiguous
-    ``SlotPool``): the paged path's stores refuse for themselves
-    (``nn/latent.py`` ``LatentPages``)."""
-    if getattr(model, "attention", "mha") == "latent":
-        raise latent_unsupported(what)
 
 
 def refuse_blocks(model, what: str):
@@ -484,17 +353,23 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     ``offset``, ``true_len`` (the real tail length, >= 1) and
     ``slot`` (the pool row admitted to: a quantized store keeps the
     prompt's partial last page there) are all TRACED — one compile per
-    padded tail bucket serves cold (``offset == 0``), partially shared,
-    and fully shared admissions alike.
+    padded tail bucket, not per prompt length, serves cold
+    (``offset == 0``), partially shared, and fully shared admissions
+    alike.
 
     Tail queries run at global positions ``offset + i`` (rope/learned
     positions included) and attend over [shared prefix pages | tail]:
     prefix keys are gathered from the pool and masked to positions
     ``< offset``; the tail is causal (block-causal for a model that
-    generates by blocks, whose tails are whole blocks), so its pad
-    columns are inert exactly as in :func:`prefill_partial`. Tail entries are written into
-    the slot's own pages (pad positions route out of bounds and drop);
-    the shared prefix pages are never written.
+    generates by blocks, whose tails are whole blocks), which makes its
+    pad columns inert: a real query position never attends a later pad
+    key (the pad keys only ever contribute exact zeros to masked-softmax
+    sums), so the logits at position ``true_len - 1`` pick the same
+    token as an exact-length :func:`prefill` and agree with it to a few
+    f32 ulps (two lengths are two XLA programs that reduce in different
+    orders, so bit-identity across them is not promised). Tail entries
+    are written into the slot's own pages (pad positions route out of
+    bounds and drop); the shared prefix pages are never written.
 
     Each layer's store (``state``, see :func:`decode_step_slots_paged`)
     goes to the block's own ``prefill_paged``; the tail's pad rows are
@@ -558,106 +433,30 @@ def prefill_partial_paged(model: TransformerLM, params: Params, state,
     return model.project_vocab(params, x_last)[:, 0], state
 
 
-def spec_verify_slots(model: TransformerLM, params: Params, ks, vs,
-                      lengths, tokens) -> Tuple[jnp.ndarray, list, list]:
-    """Speculative-decoding VERIFY over a contiguous slot pool
-    (``serve/spec/``): score all k+1 candidate positions of every row
-    in ONE batched forward, without writing the pool.
-
-    ``tokens`` (B, S) int32 is per row ``[cur, d_1 .. d_k]`` — the
-    slot's current (last-emitted, not-yet-cached) token followed by its
-    k draft proposals; S = k + 1. Row b's queries run at global
-    positions ``lengths[b] + j`` and attend over [pool row masked to
-    positions < lengths[b] | causal in-register candidate block] — the
-    same [resident | inline] layout as :func:`prefill_partial`, so the
-    position-j logits equal what j sequential :func:`decode_step_slots`
-    calls would produce (to the usual ~1-ulp batching tolerance; greedy
-    token streams are the asserted contract, per PR 3).
-
-    READ-ONLY with respect to the pool: nothing is scattered, so a
-    rejected suffix needs no rewind — acceptance is decided on the host
-    and only the accepted prefix is ever written, by
-    :func:`spec_commit_slots`, from the returned scratch K/V.
-
-    Returns ``(logits (B, S, vocab), sk, sv)`` where sk/sv are
-    per-layer (B, Hkv, S, Dh) f32 EXACT candidate K/V (position j holds
-    the key of ``tokens[:, j]`` at ``lengths + j``)."""
-    b, s = tokens.shape
-    idx = lengths
-    width = ks[0].shape[2]
-    positions = idx[:, None] + jnp.arange(s)[None, :]          # (B, S)
-    x = model.tok.apply(params["tok"], tokens)
-    if getattr(model, "pos", None) is not None:
-        # discarded over-length positions may clip into the learned
-        # table's last row — harmless, their logits are never accepted
-        x = x + model.pos.apply(params["pos"], positions)
-    scale = 1.0 / math.sqrt(model.dim // model.n_heads)
-    prefix_mask = jnp.broadcast_to(
-        (jnp.arange(width)[None, :] < idx[:, None])[:, None, :],
-        (b, s, width))
-    causal = jnp.broadcast_to(
-        jnp.tril(jnp.ones((s, s), dtype=bool))[None], (b, s, s))
-    mask = jnp.concatenate([prefix_mask, causal], axis=2)  # (B,S,W+S)
-
-    sk_out, sv_out = [], []
-    for i, blk in enumerate(model.blocks):
-        with jax.named_scope("blocks"):
-            p = params["blocks"][i]
-            hq, hk, hv = blk.attn.project_qkv(p["attn"],
-                                              blk.ln1.apply(p["ln1"], x))
-            hq, hk = blk.attn.maybe_rope(hq, hk, positions[:, None, :])
-            sk_out.append(hk.astype(jnp.float32))
-            sv_out.append(hv.astype(jnp.float32))
-            k_all = jnp.concatenate([ks[i].astype(hk.dtype), hk], axis=2)
-            v_all = jnp.concatenate([vs[i].astype(hv.dtype), hv], axis=2)
-            bq, hh, _, dd = hq.shape
-            hkv = k_all.shape[1]
-            hq_g = hq.reshape(bq, hkv, hh // hkv, s, dd)
-            att = jnp.einsum("bngqd,bnkd->bngqk", hq_g, k_all).astype(
-                jnp.float32) * scale
-            att = jnp.where(mask[:, None, None, :, :], att, -jnp.inf)
-            probs = jax.nn.softmax(att, axis=-1).astype(v_all.dtype)
-            o = jnp.einsum("bngqk,bnkd->bngqd", probs, v_all) \
-                .reshape(bq, hh, s, dd)
-            x = x + blk.attn.project_out(p["attn"], o)
-            x = x + blk.mlp(p, x)
-
-    x = model.ln_f.apply(params["ln_f"], x)
-    return model.project_vocab(params, x), sk_out, sv_out
-
-
-def spec_commit_slots(ks, vs, lengths, sk, sv,
-                      commit) -> Tuple[list, list, jnp.ndarray]:
-    """Scatter the ACCEPTED prefix of a verify's scratch K/V into a
-    contiguous slot pool (``serve/spec/`` — the write half
-    :func:`spec_verify_slots` deliberately does not do).
-
-    ``commit`` (B,) int32 is the per-row accepted position count e
-    (0 = the row took no part in this spec iteration): scratch
-    positions ``0 .. e-1`` land at pool positions ``lengths + 0 ..
-    lengths + e - 1`` and the rejected suffix is simply never written —
-    rollback by construction, no rewind. Returns ``(new_ks, new_vs,
-    lengths + commit)``."""
-    s = sk[0].shape[2]
-    width = ks[0].shape[2]
-    new_k, new_v = list(ks), list(vs)
-    for j in range(s):
-        committed = j < commit                              # (B,)
-        wm = ((jnp.arange(width)[None, :] == (lengths + j)[:, None])
-              & committed[:, None])[:, None, :, None]       # (B,1,W,1)
-        for i in range(len(new_k)):
-            kj = sk[i][:, :, j:j + 1, :].astype(new_k[i].dtype)
-            vj = sv[i][:, :, j:j + 1, :].astype(new_v[i].dtype)
-            new_k[i] = jnp.where(wm, kj, new_k[i])
-            new_v[i] = jnp.where(wm, vj, new_v[i])
-    return new_k, new_v, lengths + commit
-
-
 def spec_verify_slots_paged(model: TransformerLM, params: Params, state,
                             tables, lengths, tokens, *, page_len: int
                             ) -> Tuple[jnp.ndarray, list, list]:
-    """Paged twin of :func:`spec_verify_slots`: batched k+1-position
-    verify over a PAGED slot pool, read-only.
+    """Speculative-decoding VERIFY over a paged slot pool
+    (``serve/spec/``): score all k+1 candidate positions of every row
+    in ONE batched forward, without writing the pool.
+
+    ``tokens`` (B, S) int32 is per row ``[cur, d_1 .. d_k]``: the slot's
+    current (last-emitted, not-yet-cached) token followed by its k draft
+    proposals; S = k + 1. Row b's queries run at global positions
+    ``lengths[b] + j`` and attend over [the row's pages masked to
+    positions < lengths[b] | causal in-register candidate block], the
+    same [resident | inline] layout as :func:`prefill_partial_paged`, so
+    the position-j logits equal what j sequential
+    :func:`decode_step_slots_paged` calls would produce (to the usual
+    ~1-ulp batching tolerance; greedy token streams are the asserted
+    contract). Over-length positions a row will never accept may clip
+    into a learned position table's last row: harmless, their logits are
+    never accepted.
+
+    READ-ONLY with respect to the pool: nothing is scattered, so a
+    rejected suffix needs no rewind. Acceptance is decided on the host
+    and only the accepted prefix is ever written, by
+    :func:`spec_commit_slots_paged`, from the returned scratch K/V.
 
     Each layer's store (``state``, see :func:`decode_step_slots_paged`)
     goes to the block's ``verify_paged``, which attends over the store's
@@ -668,8 +467,9 @@ def spec_verify_slots_paged(model: TransformerLM, params: Params, state,
     each row's PARTIAL current page from its exact tail page — the pool
     row for an incomplete page was never written, exactly as in decode.
 
-    Returns ``(logits (B, S, vocab), sk, sv)`` — the same exact-f32
-    scratch contract as the contiguous verify; committing (and, on page
+    Returns ``(logits (B, S, vocab), sk, sv)`` where sk/sv are per-layer
+    (B, Hkv, S, Dh) f32 EXACT candidate K/V (position j holds the key of
+    ``tokens[:, j]`` at ``lengths + j``); committing (and, on page
     completion, quantizing) accepted positions belongs to
     :func:`spec_commit_slots_paged`."""
     b, s = tokens.shape
@@ -700,18 +500,21 @@ def spec_verify_slots_paged(model: TransformerLM, params: Params, state,
 
 def spec_commit_slots_paged(state, tables, lengths, sk, sv, commit, *,
                             page_len: int) -> list:
-    """Paged twin of :func:`spec_commit_slots`: write each row's
-    accepted scratch prefix into its pages, through each layer's store
-    (``commit``, ``nn/paged.py``).
+    """Write each row's accepted prefix of a verify's scratch K/V into
+    its pages, through each layer's store (``commit``, ``nn/paged.py``):
+    the write half :func:`spec_verify_slots_paged` deliberately does not
+    do.
 
-    Position ``lengths[b] + j`` lands in page ``tables[b, (lengths[b] +
-    j) // page_len]`` at offset ``(lengths[b] + j) % page_len``;
-    rejected positions (``j >= commit[b]``) route out of bounds and
-    drop, so a page can only ever COMPLETE from accepted tokens — which
-    is what keeps a quantized store's quantize-once discipline
-    token-for-token with the non-speculative decode path. Returns the
-    new state; advancing the host ``lengths`` by ``commit`` is the
-    caller's business."""
+    ``commit`` (B,) int32 is the per-row accepted position count e (0 =
+    the row took no part in this spec iteration). Position
+    ``lengths[b] + j`` lands in page ``tables[b, (lengths[b] + j) //
+    page_len]`` at offset ``(lengths[b] + j) % page_len``; rejected
+    positions (``j >= commit[b]``) route out of bounds and drop
+    (rollback by construction, no rewind), so a page can only ever
+    COMPLETE from accepted tokens — which is what keeps a quantized
+    store's quantize-once discipline token-for-token with the
+    non-speculative decode path. Returns the new state; advancing the
+    host ``lengths`` by ``commit`` is the caller's business."""
     n_pages, last = state[0].n_pages, tables.shape[1] - 1
     steps = []
     for j in range(sk[0].shape[2]):
@@ -778,10 +581,11 @@ def layer_windows(model: TransformerLM) -> Tuple[Optional[int], ...]:
 
 
 def refuse_mixed(model, what: str):
-    """For a path that keeps one cache layout for every layer (the
-    contiguous ``SlotPool``, ``generate()``): a model told its layers'
-    windows is served by the paged pool alone, whose stores differ a
-    layer (``nn/paged.py`` ``WindowPages`` beside ``KVPages``)."""
+    """For a path that keeps one cache layout for every layer
+    (``generate()``, the disaggregated hand-off, the verify program): a
+    model told its layers' windows is served by the paged pool alone,
+    whose stores differ a layer (``nn/paged.py`` ``WindowPages`` beside
+    ``KVPages``)."""
     if getattr(model, "layer_windows", None) is not None:
         raise mixed_unsupported(what)
 

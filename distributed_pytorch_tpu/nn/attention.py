@@ -278,8 +278,8 @@ class MultiHeadAttention(Module):
         x (1, S, D) normed; returns (output (1, S, D), the store
         written). Prefix keys come from the pool; the tail's are inline:
         its pages were just written, but the in-register tail avoids a
-        second gather, keeps the math identical to prefill_partial's
-        [real | pad] layout, and is exact where the pool is quantized,
+        second gather, keeps the math that of a right-padded prefill
+        ([real | pad]), and is exact where the pool is quantized,
         so a cold prompt sees no quantization at admission."""
         hq, hk, hv = self.project_qkv(params, x)
         hq, hk = self.maybe_rope(hq, hk, ctx.positions)
@@ -456,7 +456,7 @@ class TransformerBlock(Module):
             return self.fc2.apply(params["fc2"],
                                   gelu(self.fc1.apply(params["fc1"], h)))
 
-    def _paged(self, step, params: Params, x, pages, ctx):
+    def _with_pages(self, step, params: Params, x, pages, ctx):
         a, out = step(params["attn"], self.ln1.apply(params["ln1"], x),
                       pages, ctx)
         x = x + a
@@ -464,15 +464,15 @@ class TransformerBlock(Module):
 
     def decode_paged(self, params: Params, x, pages, ctx):
         """x (B, 1, D), this layer's page store -> (x, the store)."""
-        return self._paged(self.attn.decode_paged, params, x, pages, ctx)
+        return self._with_pages(self.attn.decode_paged, params, x, pages, ctx)
 
     def prefill_paged(self, params: Params, x, pages, ctx):
         """x (1, S, D): the padded tail of one prompt."""
-        return self._paged(self.attn.prefill_paged, params, x, pages, ctx)
+        return self._with_pages(self.attn.prefill_paged, params, x, pages, ctx)
 
     def verify_paged(self, params: Params, x, pages, ctx):
         """x (B, S, D): a verify's candidates -> (x, their (K, V))."""
-        return self._paged(self.attn.verify_paged, params, x, pages, ctx)
+        return self._with_pages(self.attn.verify_paged, params, x, pages, ctx)
 
     def apply(self, params: Params, x, *, rng=None, train: bool = False,
               positions=None, **_):

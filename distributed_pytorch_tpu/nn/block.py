@@ -93,7 +93,7 @@ class Block(Module):
                                       positions=positions))
         return self._ffn(params, x, row_mask)
 
-    def _paged(self, step, params, x, pages, ctx, row_mask):
+    def _with_pages(self, step, params, x, pages, ctx, row_mask):
         x, pages = self._residual(
             self.hc1, params.get("hc1"), x,
             lambda u: step(params["attn"], self.ln1.apply(params["ln1"], u),
@@ -103,18 +103,18 @@ class Block(Module):
     def decode_paged(self, params: Params, x, pages, ctx):
         """x (B, 1[, streams], D), this layer's page store -> (x, the
         store written). Idle slots are left out of the expert dispatch."""
-        return self._paged(self.attn.decode_paged, params, x, pages, ctx,
+        return self._with_pages(self.attn.decode_paged, params, x, pages, ctx,
                            ctx.active[:, None])
 
     def prefill_paged(self, params: Params, x, pages, ctx):
         """x (1, S[, streams], D): the padded tail of one prompt."""
-        return self._paged(self.attn.prefill_paged, params, x, pages, ctx,
+        return self._with_pages(self.attn.prefill_paged, params, x, pages, ctx,
                            ctx.row_mask[None, :])
 
     def block_paged(self, params: Params, x, pages, ctx):
         """x (B, L[, streams], D): one pass over every row's block of a
         model that generates by blocks (``nn.paged.BlockCtx``). Idle
         slots are left out of the expert dispatch."""
-        return self._paged(self.attn.block_paged, params, x, pages, ctx,
+        return self._with_pages(self.attn.block_paged, params, x, pages, ctx,
                            jnp.broadcast_to(ctx.active[:, None],
                                             x.shape[:2]))

@@ -161,8 +161,7 @@ def latent_unsupported(what: str) -> LatentPagesUnsupported:
     return LatentPagesUnsupported(
         f"{what} cannot hold latent (MLA) pages yet: a latent block "
         "keeps one array of [c | k_r] entries a layer, served only by "
-        "the exact paged pool (InferenceEngine(paged=True), "
-        "kv_dtype='f32')")
+        "exact pages (InferenceEngine, kv_dtype='f32')")
 
 
 class BlockGenerationUnsupported(NotImplementedError):
@@ -178,8 +177,7 @@ def block_unsupported(what: str) -> BlockGenerationUnsupported:
         f"{what} cannot serve a model that generates by blocks "
         "(TransformerLM(gen_block=...)): a block step fills positions of "
         "a block under a confidence rule, served only greedy, by "
-        "InferenceEngine(paged=True, kv_dtype='f32') without "
-        "speculation")
+        "InferenceEngine(kv_dtype='f32') without speculation")
 
 
 class MixedStoresUnsupported(NotImplementedError):
@@ -195,8 +193,8 @@ def mixed_unsupported(what: str) -> MixedStoresUnsupported:
         f"{what} cannot serve a model whose layers mix a sliding window "
         "with global attention (TransformerLM(layer_windows=...)): a "
         "window layer keeps a ring of its last entries a slot and no "
-        "pages, served only by InferenceEngine(paged=True, "
-        "kv_dtype='f32', prefix_share=False) without speculation")
+        "pages, served only by InferenceEngine(kv_dtype='f32', "
+        "prefix_share=False) without speculation")
 
 
 class MixerStoresUnsupported(NotImplementedError):
@@ -215,8 +213,8 @@ def mixers_unsupported(what: str) -> MixerStoresUnsupported:
         "layers (TransformerLM(layer_mixers=...)): a linear layer keeps "
         "one state a slot and no pages, a sparse layer its compressed "
         "keys a slot beside its pages, served only by "
-        "InferenceEngine(paged=True, kv_dtype='f32', prefix_share=False) "
-        "without speculation")
+        "InferenceEngine(kv_dtype='f32', prefix_share=False) without "
+        "speculation")
 
 
 def table_pages(state):
@@ -522,7 +520,7 @@ class KVPages(NamedTuple):
         """A decode step's attention, this step's entries written.
         Blockwise, hk / hv are re-selected at the write position per
         block: identity for active rows (already written), and gives
-        inactive rows ``decode_step_slots``' exact value semantics
+        inactive rows the write-mask's exact value semantics
         (their discarded logits still see "their" key). On a TPU an
         exact store takes the kernel instead: active rows read their key
         from the pool, inactive rows are skipped

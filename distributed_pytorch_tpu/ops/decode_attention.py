@@ -49,11 +49,11 @@ below, which is also the reference the kernel is tested against. The
 contract above holds in both.
 
 Every decode front door routes here (``models/generate.py``:
-``decode_step``, ``decode_step_slots``, ``decode_step_slots_paged``),
-so ``serve/cache.py``, ``serve/pages/``, and both the monolithic and
-disaggregated engines share one kernel. The sliding-window rolling
-cache keeps the dense path: its width IS the window, so every slot is
-potentially resident and there is nothing to skip.
+``decode_step``, ``decode_step_slots_paged``), so ``generate()``,
+``serve/pages/``, and both the monolithic and disaggregated engines
+share one kernel. ``generate()``'s sliding-window rolling cache keeps
+the dense path, as a window layer's ring does: its width IS the window,
+so every entry is potentially resident and there is nothing to skip.
 """
 
 from __future__ import annotations
@@ -71,8 +71,8 @@ __all__ = ["DECODE_BLOCK", "blockwise_decode_attention",
            "paged_decode_attention", "paged_loop_attention",
            "resident_blocks"]
 
-#: Default block length for CONTIGUOUS caches (``decode_step`` /
-#: ``decode_step_slots``); paged pools use their ``page_len``. 128 =
+#: Default block length for CONTIGUOUS caches (``decode_step``);
+#: paged pools use their ``page_len``. 128 =
 #: one VPU lane width per gather on TPU, and small enough that a short
 #: resident prefix in a long pool skips most of the width.
 DECODE_BLOCK = 128
@@ -96,8 +96,8 @@ def dense_decode_attention(hq, k, v, pos_mask, *, scale):
     """The dense full-width decode softmax — the REFERENCE the
     blockwise kernel is contract-tested against, and the baseline the
     decode bench arm times. One definition for every ``blockwise=False``
-    branch (decode_step / decode_step_slots / decode_step_slots_paged)
-    and the sliding-window rolling cache, whose width IS the window.
+    branch (decode_step / decode_step_slots_paged), the sliding-window
+    rolling cache and a window layer's ring, whose width IS the window.
 
     hq: (B, H, 1, Dh); k, v: (B, Hkv, W, Dh); pos_mask: (B, W) or
     (1, W) bool — True where the position is visible. The grouped
@@ -299,8 +299,8 @@ def _paged_loop(hq, load_k, load_v, tables, idx, new_k, new_v, *, scale,
 
     new_k/new_v (B, Hkv, 1, Dh) are re-selected at position ``idx[b]``
     so rows whose pool write was dropped (inactive slots) still see
-    their own key, value-identical to ``decode_step_slots``' write-mask
-    semantics (and after a quantized side's tail overlay: the write
+    their own key, value-identical to a write-mask select over the
+    row (and after a quantized side's tail overlay: the write
     mask must still win for them).
 
     Visits only ``resident_blocks(idx, page_len, P)`` pages: the page

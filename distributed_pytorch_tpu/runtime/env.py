@@ -406,8 +406,8 @@ register("DPX_SERVE_PAGE_LEN", "int", 16,
          "docs/serving.md).")
 register("DPX_SERVE_N_PAGES", "int", 0,
          "Total pages of the paged serving KV pool (0 = derive "
-         "n_slots*ceil(max_len/page_len), the same KV budget the "
-         "contiguous slot pool would preallocate).")
+         "n_slots*ceil(max_len/page_len): every slot's worst case at "
+         "once).")
 register("DPX_SERVE_PREFIX_SHARE", "bool", True,
          "Enable radix prefix sharing in the paged serving cache "
          "(refcounted reuse of resident full prompt pages; 0 = paged "
@@ -417,8 +417,8 @@ register("DPX_SERVE_KV_DTYPE", "str", "f32",
          "(exact pages — the bit-exact-tokens default contract), `q8` "
          "(block-int8 pages + per-page scales, ~3.9x resident tokens "
          "per byte) or `q4` (nibble-packed, ~7.5x). Dequant happens "
-         "inside the one paged decode program; ignored by non-paged "
-         "engines (docs/serving.md \"Quantized resident pool\").")
+         "inside the one paged decode program (docs/serving.md "
+         "\"Quantized resident pool\").")
 register("DPX_SERVE_DISAGG", "bool", False,
          "Serve through the disaggregated prefill/decode split "
          "(serve/disagg/) where the front door supports it "

@@ -2,17 +2,17 @@
 
 The ROADMAP's "serves heavy traffic" leg: an Orca-style engine that
 runs many concurrent, independently-arriving requests through ONE
-accelerator with iteration-level scheduling — a slot-pooled, fixed-
-shape KV cache (``cache``), a paged + prefix-shared variant with a
-refcounted block pool and radix index (``pages``,
-``EngineConfig(paged=True)``), an admission scheduler with bounded
+accelerator with iteration-level scheduling — a slot-pooled, paged and
+prefix-shared KV cache of fixed shapes, with a refcounted block pool and
+a radix index (``pages``; what its programs share: ``cache``), an
+admission scheduler with bounded
 queue + priorities + per-request deadlines (``scheduler``), the engine
 loop and threaded front door (``engine``), and per-request SLO metrics
 (``metrics``). Architecture and failure grammar: docs/serving.md.
 """
 
 from ..nn.paged import BlockGenerationUnsupported  # noqa: F401
-from .cache import CompileCounts, SlotPool  # noqa: F401
+from .cache import CompileCounts  # noqa: F401
 from .disagg import DisaggConfig, DisaggEngine  # noqa: F401
 from .engine import EngineConfig, InferenceEngine  # noqa: F401
 from .fleet import (FleetAutoscaler, FleetConfig, FleetHandle,  # noqa: F401
@@ -36,6 +36,6 @@ __all__ = [
     "InferenceEngine", "PagePool", "PagePoolExhausted", "PagedSlotPool",
     "PrefillEngineDied", "PrefixIndex", "ReplicaFailed", "Request",
     "RequestDeadlineExceeded", "RequestHandle", "SamplingParams",
-    "ServeError", "SlotPool", "SpecConfig", "SpecDecodeError",
+    "ServeError", "SpecConfig", "SpecDecodeError",
     "SpecState", "aggregate", "percentile", "request_record",
 ]

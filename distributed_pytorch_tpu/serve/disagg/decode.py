@@ -72,7 +72,7 @@ class DecodeEngine:
         self._spec: Optional[SpecState] = None
         self._spec_buckets = tuple(buckets) if buckets else ()
         if spec is not None:
-            self._spec = SpecState(spec, n_slots, max_len)
+            self._spec = SpecState(spec, n_slots, max_len, page_len)
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_iters = 0

@@ -122,10 +122,10 @@ class DisaggEngine:
         refuse_mixers(model, "the disaggregated hand-off (serve/disagg)")
         if _model_window(model) is not None:
             raise ValueError(
-                "disaggregated serving runs on the paged KV cache, "
-                "which does not support sliding-window models — use the "
-                "monolithic InferenceEngine (its rolling SlotPool "
-                "already bounds their memory)")
+                "disaggregated serving runs on the paged KV cache and "
+                "hands no sliding-window model off: the monolithic "
+                "InferenceEngine serves one told its windows "
+                "(TransformerLM(layer_windows=...))")
         if (getattr(model, "pos", None) is not None
                 and cfg.max_len > model.max_seq):
             raise ValueError(

@@ -7,13 +7,12 @@ One engine thread runs the iteration loop; each iteration
 2. sweeps deadlines (queued AND running requests; a miss surfaces as a
    typed ``RequestDeadlineExceeded`` on that request's future, other
    slots untouched),
-3. prefills: the paged pool at most ONE chunk of one prompt (at most
+3. prefills at most ONE chunk of one prompt (at most
    ``serve.pages.cache.chunk_tokens`` tokens, right-padded to a length
    bucket — one compile per bucket), so that an admitted prompt stalls
-   the running rows for a chunk and not for its whole prefill; the
-   contiguous pool every queued prompt whole, and
+   the running rows for a chunk and not for its whole prefill, and
 4. advances EVERY active slot one token through the single jitted
-   decode program (``serve.cache.SlotPool``), retiring slots that hit
+   decode program (``serve.pages.PagedSlotPool``), retiring slots that hit
    ``max_new_tokens`` / ``eos_token`` so the next iteration can refill
    them. ONE decode pass is kept in flight (``_decode_all``): an
    iteration dispatches the next pass, whose tokens argument is the
@@ -63,7 +62,6 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import (_check_attn_compatible, _model_window,
@@ -74,7 +72,6 @@ from ..runtime import compile_cache
 from ..runtime import env as dpxenv
 from ..runtime import faults
 from ..utils.logging import MetricsLogger
-from .cache import SlotPool
 from .metrics import emit_request_trace, request_record
 from .pages import PagedSlotPool, chunk_tokens
 from .sampling import RowSampler, fill_counts
@@ -127,12 +124,15 @@ def _default_buckets(cap: int) -> Tuple[int, ...]:
 
 @dataclass
 class EngineConfig:
-    """Engine shape and policy. ``n_slots`` × ``max_len`` is the whole
-    KV memory budget (fixed at startup — serving never reallocates);
-    ``buckets`` are the padded prefill lengths (None = powers of two up
-    to ``max_len``); ``max_queue`` bounds admission; ``metrics`` is an
-    optional line-JSON ``MetricsLogger`` receiving per-request SLO
-    events and periodic occupancy records."""
+    """Engine shape and policy. ``n_slots`` requests run at once, each
+    of at most ``max_len`` positions; the KV memory budget is the page
+    pool's, ``n_pages`` pages of ``page_len`` positions (fixed at
+    startup — serving never reallocates; by default what ``n_slots``
+    rows of ``max_len`` need). ``buckets`` are the padded lengths of a
+    prefill chunk (None = powers of two up to ``max_len``); ``max_queue``
+    bounds admission; ``metrics`` is an optional line-JSON
+    ``MetricsLogger`` receiving per-request SLO events and periodic
+    occupancy records."""
 
     n_slots: int = 4
     max_len: int = 256
@@ -141,21 +141,21 @@ class EngineConfig:
     metrics: Optional[MetricsLogger] = None
     log_every: int = 16
     allow_custom_attn: bool = False
-    # paged KV + prefix sharing (serve/pages/; docs/serving.md). With
-    # ``paged=True`` the slot cache becomes a refcounted block pool and
-    # identical prompt prefixes are computed once; the None knobs
-    # default from the typed env registry (DPX_SERVE_PAGE_LEN /
-    # DPX_SERVE_N_PAGES / DPX_SERVE_PREFIX_SHARE).
-    paged: bool = False
+    # not an option: the engine's one pool is the page pool, and False
+    # raises. The word is kept only until a ``benchmark`` PR drops the
+    # key ``"paged": true`` from the five traffic files under
+    # chipbench/traffic/, which build ``EngineConfig(**engine)``.
+    paged: bool = True
+    # the page pool (serve/pages/; docs/serving.md): a refcounted block
+    # pool in which identical prompt prefixes are computed once; the
+    # None knobs default from the typed env registry (DPX_SERVE_PAGE_LEN
+    # / DPX_SERVE_N_PAGES / DPX_SERVE_PREFIX_SHARE).
     page_len: Optional[int] = None
     n_pages: Optional[int] = None
     prefix_share: Optional[bool] = None
-    # resident KV storage width for the paged pool (docs/serving.md
-    # "Quantized resident pool"): "f32" exact (default) | "q8" | "q4".
-    # None defaults from DPX_SERVE_KV_DTYPE. Requires paged=True; an
-    # explicit non-f32 value on the contiguous pool raises, while an
-    # env-driven one is ignored (the env var sizes paged fleets without
-    # breaking non-paged engines in the same process).
+    # resident KV storage width (docs/serving.md "Quantized resident
+    # pool"): "f32" exact (default) | "q8" | "q4". None defaults from
+    # DPX_SERVE_KV_DTYPE.
     kv_dtype: Optional[str] = None
     # speculative decoding (serve/spec/; docs/serving.md "Speculative
     # decoding"): a draft model proposes draft_len tokens per
@@ -194,6 +194,23 @@ class InferenceEngine:
         if cfg.n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {cfg.n_slots}")
         _check_attn_compatible(model, cfg.allow_custom_attn)
+        if not cfg.paged:
+            raise ValueError(
+                "EngineConfig(paged=False): the contiguous slot pool is "
+                "gone, the engine's one pool is the page pool "
+                "(serve.pages.PagedSlotPool), which chunks a prompt of "
+                "any length the slot holds; a sliding-window model is "
+                "served through TransformerLM(layer_windows=...), a ring "
+                "of O(window) a slot and window layer")
+        if _model_window(model) is not None:
+            # a width the model's attn_fn bakes in for every layer; a
+            # model TOLD its layers' windows is served
+            raise ValueError(
+                "the engine does not serve a sliding-window model whose "
+                "width only its attn_fn carries: tell the model its "
+                "windows (TransformerLM(layer_windows=(W,) * n_layers)) "
+                "and the page pool keeps each window layer a ring of "
+                "O(window) a slot")
         self.model = model
         if cfg.param_shardings is not None:
             # the train -> serve-admit half of the reshard-free
@@ -202,8 +219,7 @@ class InferenceEngine:
             params = verify_handoff(params, cfg.param_shardings,
                                     what="serve-admit params")
         self.params = params
-        self.window = _model_window(model)
-        if (self.window is None and getattr(model, "pos", None) is not None
+        if (getattr(model, "pos", None) is not None
                 and cfg.max_len > model.max_seq):
             raise ValueError(
                 f"max_len {cfg.max_len} exceeds the model's max_seq "
@@ -211,11 +227,10 @@ class InferenceEngine:
                 "address slots past their table")
         self.buckets = tuple(sorted(cfg.buckets)) if cfg.buckets \
             else _default_buckets(cfg.max_len)
-        if self.window is None and max(self.buckets) > cfg.max_len:
+        if max(self.buckets) > cfg.max_len:
             raise ValueError(
                 f"largest prefill bucket ({max(self.buckets)}) exceeds "
                 f"max_len ({cfg.max_len}) — the slot row cannot hold it")
-        self._paged = cfg.paged
         # L where the model generates by blocks of L positions, else None
         self._block = getattr(model, "gen_block", None)
         if self._block and any(b % self._block for b in self.buckets):
@@ -223,57 +238,30 @@ class InferenceEngine:
                 f"prefill buckets {self.buckets} must be multiples of the "
                 f"model's gen_block ({self._block}): a prompt is prefilled "
                 "in whole blocks")
-        if cfg.paged:
-            if self.window is not None:
-                # a width the model's attn_fn bakes in for every layer;
-                # a model TOLD its layers' windows
-                # (TransformerLM(layer_windows=...)) is the paged pool's
-                raise ValueError(
-                    "paged KV (serve/pages) does not serve a "
-                    "sliding-window model whose width only its attn_fn "
-                    "carries — the rolling O(window) SlotPool bounds its "
-                    "memory (paged=False); the paged pool serves window "
-                    "layers the model was told of "
-                    "(TransformerLM(layer_windows=...))")
-            page_len = (cfg.page_len if cfg.page_len is not None
-                        else dpxenv.get("DPX_SERVE_PAGE_LEN"))
-            n_pages = (cfg.n_pages if cfg.n_pages is not None
-                       else dpxenv.get("DPX_SERVE_N_PAGES"))
-            if not n_pages:
-                # unshared-equivalent budget: the same KV bytes the
-                # contiguous SlotPool would have preallocated
-                n_pages = cfg.n_slots * (-(-cfg.max_len // page_len))
-            share = (cfg.prefix_share if cfg.prefix_share is not None
-                     else dpxenv.get("DPX_SERVE_PREFIX_SHARE"))
-            kv_dtype = (cfg.kv_dtype if cfg.kv_dtype is not None
-                        else dpxenv.get("DPX_SERVE_KV_DTYPE"))
-            chunk_tokens(self.buckets, page_len)    # refuses what it cannot chunk
-            self.pool = PagedSlotPool(model, cfg.n_slots, cfg.max_len,
-                                      page_len=page_len, n_pages=n_pages,
-                                      prefix_share=bool(share),
-                                      kv_dtype=kv_dtype)
-        else:
-            if cfg.kv_dtype is not None and cfg.kv_dtype != "f32":
-                raise ValueError(
-                    f"kv_dtype={cfg.kv_dtype!r} requires the paged pool "
-                    "(paged=True) — the contiguous SlotPool has no "
-                    "quantized storage mode")
-            self.pool = SlotPool(model, cfg.n_slots, cfg.max_len,
-                                 window=self.window)
+        page_len = (cfg.page_len if cfg.page_len is not None
+                    else dpxenv.get("DPX_SERVE_PAGE_LEN"))
+        n_pages = (cfg.n_pages if cfg.n_pages is not None
+                   else dpxenv.get("DPX_SERVE_N_PAGES"))
+        if not n_pages:
+            # what n_slots rows of max_len positions need, unshared
+            n_pages = cfg.n_slots * (-(-cfg.max_len // page_len))
+        share = (cfg.prefix_share if cfg.prefix_share is not None
+                 else dpxenv.get("DPX_SERVE_PREFIX_SHARE"))
+        kv_dtype = (cfg.kv_dtype if cfg.kv_dtype is not None
+                    else dpxenv.get("DPX_SERVE_KV_DTYPE"))
+        chunk_tokens(self.buckets, page_len)    # refuses what it cannot chunk
+        self.pool = PagedSlotPool(model, cfg.n_slots, cfg.max_len,
+                                  page_len=page_len, n_pages=n_pages,
+                                  prefix_share=bool(share),
+                                  kv_dtype=kv_dtype)
         spec_on = (cfg.spec_decode if cfg.spec_decode is not None
                    else dpxenv.get("DPX_SPEC_DECODE"))
         self._spec: Optional[SpecState] = None
         if spec_on:
             if self._block:
                 raise block_unsupported("speculative decoding (serve/spec)")
-            if cfg.paged:
-                self.pool.require("commit")
+            self.pool.require("commit")
             refuse_mixed(model, "speculative decoding (serve/spec)")
-            if self.window is not None:
-                raise ValueError(
-                    "spec_decode does not support sliding-window "
-                    "models — the batched verify attends the full "
-                    "resident prefix (serve/spec/)")
             if cfg.draft_model is None or cfg.draft_params is None:
                 raise ValueError(
                     "spec_decode=True requires draft_model and "
@@ -285,7 +273,7 @@ class InferenceEngine:
                 SpecConfig(draft_model=cfg.draft_model,
                            draft_params=cfg.draft_params,
                            draft_len=int(draft_len)),
-                cfg.n_slots, cfg.max_len)
+                cfg.n_slots, cfg.max_len, page_len)
         # cumulative speculation accounting (gauges + bench record)
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -440,13 +428,6 @@ class InferenceEngine:
             raise AdmissionRejected(
                 f"request {rid}: empty prompt or max_new_tokens < 1",
                 reason="invalid", request_id=rid)
-        if not self._paged and s > max(self.buckets):
-            # the paged pool prefills in chunks: any prompt its slot
-            # row holds is admitted
-            raise AdmissionRejected(
-                f"request {rid}: prompt length {s} exceeds the largest "
-                f"prefill bucket ({max(self.buckets)})",
-                reason="prompt_too_long", request_id=rid)
         if sp.denoise_steps is not None and not self._block:
             raise AdmissionRejected(
                 f"request {rid}: denoise_steps is for a model that "
@@ -468,34 +449,26 @@ class InferenceEngine:
         need = s + sp.max_new_tokens
         if self._block:
             need = -(-need // self._block) * self._block
-        if self.window is None and need > self.config.max_len:
+        if need > self.config.max_len:
             raise AdmissionRejected(
                 f"request {rid}: prompt ({s}) + max_new_tokens "
                 f"({sp.max_new_tokens}) exceeds the slot cache "
                 f"({self.config.max_len})",
                 reason="too_long", request_id=rid)
-        if (self.window is not None
-                and getattr(self.model, "pos", None) is not None
-                and s + sp.max_new_tokens > self.model.max_seq):
+        # the LAST sampled token retires without a KV write (decode
+        # writes positions s .. s+max_new-2), so the true worst case is
+        # ceil((s + max_new - 1) / page_len) pages
+        worst = -(-(need if self._block else need - 1)
+                  // self.pool.page_len)
+        if worst > self.pool.n_pages:
+            # the request could NEVER hold its pages even with the
+            # whole pool to itself — reject synchronously rather than
+            # let it starve in the queue
             raise AdmissionRejected(
-                f"request {rid}: learned position embeddings cannot "
-                f"extrapolate past max_seq ({self.model.max_seq})",
-                reason="too_long", request_id=rid)
-        if self._paged:
-            # the LAST sampled token retires without a KV write (decode
-            # writes positions s .. s+max_new-2), so the true worst
-            # case is ceil((s + max_new - 1) / page_len) pages
-            worst = -(-(need if self._block else need - 1)
-                      // self.pool.page_len)
-            if worst > self.pool.n_pages:
-                # the request could NEVER hold its pages even with the
-                # whole pool to itself — reject synchronously rather
-                # than let it starve in the queue
-                raise AdmissionRejected(
-                    f"request {rid}: worst-case page need ({worst}) "
-                    f"exceeds the page pool ({self.pool.n_pages} pages "
-                    f"of {self.pool.page_len})",
-                    reason="no_free_pages", request_id=rid)
+                f"request {rid}: worst-case page need ({worst}) "
+                f"exceeds the page pool ({self.pool.n_pages} pages "
+                f"of {self.pool.page_len})",
+                reason="no_free_pages", request_id=rid)
 
     def start(self) -> "InferenceEngine":
         if self._thread is not None:
@@ -574,39 +547,37 @@ class InferenceEngine:
                # every program XLA built in this process, whoever asked
                "xla_compiles": compile_cache.compile_events(),
                "buckets": self.buckets,
-               "paged": self._paged,
-               "spec_decode": self._spec is not None}
-        if self._paged:
-            out["pages"] = self.pool.page_stats()
-            moe = self.pool.moe_stats()
-            mixers = self.pool.mixer_stats()
-            if moe is not None or mixers is not None:
-                # the one place the expert layers' and the sparse
-                # layers' device counters are read; the mark puts a
-                # reading on the profiler's clock, so that a traced part
-                # can be told by two of them
-                out.update(moe or {})
-                out.update(mixers or {})
-                also = ("decode_passes_ahead", "decode_rows_dropped")
-                if self._block:
-                    # a block generator's marks carry its own counters
-                    also += ("block_passes", "block_commits", "block_fills",
-                             "blocks_emitted", "tokens_emitted")
-                mixed = {}
-                if self.pool.window_layers or mixers is not None:
-                    # more than one kind of store in one cache: what each
-                    # kind keeps and how long the contexts are
-                    # (whole numbers: a reader of the trace takes no
-                    # doubles from a mark)
-                    mixed = {k: int(round(out["pages"][k])) for k in (
-                        "kv_resident_bytes_global",
-                        "kv_resident_bytes_window", "pages_in_use",
-                        "context_tokens_max", "context_tokens_mean")}
-                    mixed["active_slots"] = out["active_slots"]
-                with dpxtrace.span("serve.stats", **(moe or {}),
-                                   **(mixers or {}), **mixed,
-                                   **{k: out[k] for k in also}):
-                    pass
+               "spec_decode": self._spec is not None,
+               "pages": self.pool.page_stats()}
+        moe = self.pool.moe_stats()
+        mixers = self.pool.mixer_stats()
+        if moe is not None or mixers is not None:
+            # the one place the expert layers' and the sparse layers'
+            # device counters are read; the mark puts a reading on the
+            # profiler's clock, so that a traced part can be told by two
+            # of them
+            out.update(moe or {})
+            out.update(mixers or {})
+            also = ("decode_passes_ahead", "decode_rows_dropped")
+            if self._block:
+                # a block generator's marks carry its own counters
+                also += ("block_passes", "block_commits", "block_fills",
+                         "blocks_emitted", "tokens_emitted")
+            mixed = {}
+            if self.pool.window_layers or mixers is not None:
+                # more than one kind of store in one cache: what each
+                # kind keeps and how long the contexts are (whole
+                # numbers: a reader of the trace takes no doubles from a
+                # mark)
+                mixed = {k: int(round(out["pages"][k])) for k in (
+                    "kv_resident_bytes_global",
+                    "kv_resident_bytes_window", "pages_in_use",
+                    "context_tokens_max", "context_tokens_mean")}
+                mixed["active_slots"] = out["active_slots"]
+            with dpxtrace.span("serve.stats", **(moe or {}),
+                               **(mixers or {}), **mixed,
+                               **{k: out[k] for k in also}):
+                pass
         if self._spec is not None:
             out["spec"] = {
                 "draft_len": self._spec.cfg.draft_len,
@@ -711,20 +682,18 @@ class InferenceEngine:
             dpxmon.set_gauge(
                 "serve.host_share.idle",
                 host["idle"] / max(host["idle"] + host["iter"], 1))
-            if self._paged:
-                ps = self.pool.page_stats()
-                dpxmon.set_gauge("serve.pool_occupancy",
-                                 ps["pool_occupancy"])
-                dpxmon.set_gauge("serve.free_pages", ps["free_pages"])
-                dpxmon.set_gauge("serve.prefix_hit_rate",
-                                 ps["prefix_hit_rate"] or 0.0)
-                dpxmon.set_gauge("serve.page_evictions", ps["evictions"])
-                # resident-KV capacity gauges (gauges are plain floats, so
-                # the storage width rides as numeric bits: 32 / 8 / 4)
-                dpxmon.set_gauge("serve.kv_bits", ps["kv_bits"])
-                dpxmon.set_gauge("serve.kv_pool_bytes", ps["kv_pool_bytes"])
-                dpxmon.set_gauge("serve.bytes_per_resident_token",
-                                 ps["bytes_per_resident_token"])
+            ps = self.pool.page_stats()
+            dpxmon.set_gauge("serve.pool_occupancy", ps["pool_occupancy"])
+            dpxmon.set_gauge("serve.free_pages", ps["free_pages"])
+            dpxmon.set_gauge("serve.prefix_hit_rate",
+                             ps["prefix_hit_rate"] or 0.0)
+            dpxmon.set_gauge("serve.page_evictions", ps["evictions"])
+            # resident-KV capacity gauges (gauges are plain floats, so
+            # the storage width rides as numeric bits: 32 / 8 / 4)
+            dpxmon.set_gauge("serve.kv_bits", ps["kv_bits"])
+            dpxmon.set_gauge("serve.kv_pool_bytes", ps["kv_pool_bytes"])
+            dpxmon.set_gauge("serve.bytes_per_resident_token",
+                             ps["bytes_per_resident_token"])
             if self._spec is not None and self._spec_proposed:
                 dpxmon.set_gauge("serve.spec_acceptance_rate",
                                  self._spec_accepted / self._spec_proposed)
@@ -765,18 +734,10 @@ class InferenceEngine:
 
     def _admit_from_queue(self) -> int:
         """This iteration's prefill work; returns the chunk programs it
-        ran. Paged: the request mid-prefill gets its next chunk, else
-        the next queued one is begun and gets its first: ONE chunk
-        while rows are running (they decode next, behind it), chunks
-        back to back while none is (an empty engine has nothing to
-        protect). Contiguous: every queued prompt whole."""
-        if not self._paged:
-            while self._free:
-                req = self._scheduler.pop()
-                if req is None:
-                    break
-                self._admit_whole(req)
-            return 0
+        ran. The request mid-prefill gets its next chunk, else the next
+        queued one is begun and gets its first: ONE chunk while rows are
+        running (they decode next, behind it), chunks back to back while
+        none is (an empty engine has nothing to protect)."""
         chunks = 0
         while True:
             if self._prefilling is None and not self._begin_next():
@@ -873,27 +834,6 @@ class InferenceEngine:
                 self._running[req.slot] = req
                 self._sample_first(req, ch.logits)
 
-    def _admit_whole(self, req: Request) -> None:
-        """The contiguous pool's admission: the whole prompt in one
-        prefill, padded to its bucket."""
-        s = int(req.prompt.shape[0])
-        with dpxtrace.span("serve.admit", iteration=self._iteration,
-                           trace_id=req.trace_id, request_id=req.request_id,
-                           prompt_len=s, n_hit=0) as adm:
-            slot = req.slot = self._free.pop()
-            req.state = RUNNING
-            self._running[slot] = req   # before the prefill: see _begin_next
-            bucket = next(b for b in self.buckets if b >= s)
-            adm.set(bucket=bucket)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :s] = req.prompt
-            with dpxtrace.span("serve.admit.prefill",
-                               iteration=self._iteration):
-                logits = self.pool.admit(
-                    self.params, jnp.asarray(padded), s, slot)
-            self._admitted_now(req)
-            self._sample_first(req, logits)
-
     def _sample_first(self, req: Request, logits) -> None:
         """Dispatch, behind ``req``'s prefill, what gives it a row of
         the next decode pass without the host having seen its first
@@ -902,8 +842,7 @@ class InferenceEngine:
         device. Nothing is waited for: :meth:`_emit_first` reads it."""
         if self._spec is not None and req.params.temperature == 0.0:
             # greedy requests speculate: prefill the draft's own slot
-            # too (a prompt no draft bucket fits just runs
-            # non-speculative — mixed batches are first-class)
+            # too
             self._spec.admit(req.prompt, req.slot, self.buckets)
         first = self._sampler.first(req, logits)
         self._dev_tokens = self._sampler.place(self._dev_tokens, first,
@@ -954,32 +893,30 @@ class InferenceEngine:
             if slot not in speculating \
                     and step < req.params.max_new_tokens:
                 rows.append((slot, req, step))
-        if self._paged:
-            # grow page tables at page boundaries BEFORE the decode
-            # write; an exhausted pool fails the victim request typed
-            # (request + iteration attributed) and frees its pages —
-            # co-resident slots decode on, untouched. Spec rows don't
-            # take part: their pages grow AFTER acceptance is known
-            # (ensure_spec_capacity), so rejected drafts never demand
-            # a page
-            with dpxtrace.span("serve.decode.capacity",
-                               iteration=self._iteration):
-                for row in list(rows):
-                    slot, req, _ = row
-                    try:
-                        self.pool.ensure_decode_capacity(slot)
-                    except PagePoolExhausted as e:
-                        self._fail(req, PagePoolExhausted(
-                            f"request {req.request_id}: page pool "
-                            f"exhausted mid-decode after "
-                            f"{len(req.out_tokens)} tokens ({e.needed} "
-                            f"page(s) needed, {e.free_pages} free — every "
-                            f"page held by a live reader)",
-                            needed=e.needed, free_pages=e.free_pages,
-                            request_id=req.request_id,
-                            iteration=self._iteration),
-                            outcome="no_free_pages")
-                        rows.remove(row)
+        # grow page tables at page boundaries BEFORE the decode write; an
+        # exhausted pool fails the victim request typed (request +
+        # iteration attributed) and frees its pages — co-resident slots
+        # decode on, untouched. Spec rows don't take part: their pages
+        # grow AFTER acceptance is known (ensure_spec_capacity), so
+        # rejected drafts never demand a page
+        with dpxtrace.span("serve.decode.capacity",
+                           iteration=self._iteration):
+            for row in list(rows):
+                slot, req, _ = row
+                try:
+                    self.pool.ensure_decode_capacity(slot)
+                except PagePoolExhausted as e:
+                    self._fail(req, PagePoolExhausted(
+                        f"request {req.request_id}: page pool "
+                        f"exhausted mid-decode after "
+                        f"{len(req.out_tokens)} tokens ({e.needed} "
+                        f"page(s) needed, {e.free_pages} free — every "
+                        f"page held by a live reader)",
+                        needed=e.needed, free_pages=e.free_pages,
+                        request_id=req.request_id,
+                        iteration=self._iteration),
+                        outcome="no_free_pages")
+                    rows.remove(row)
         if rows:
             self._dispatch_pass(rows, ahead=prev is not None)
         if prev is not None:
@@ -1309,29 +1246,27 @@ class InferenceEngine:
             self._spec_iters += 1
             commit[slot] = e
             emits[slot] = out
-        if self._paged:
-            # accepted counts are known — only NOW may pages be
-            # demanded; exhaustion fails THAT victim typed (its commit
-            # zeroes, nothing of its iteration lands)
-            for slot in list(emits):
-                req = self._running[slot]
-                try:
-                    self.pool.ensure_spec_capacity(slot,
-                                                   int(commit[slot]))
-                except PagePoolExhausted as e:
-                    n_acc = int(commit[slot])
-                    commit[slot] = 0
-                    del emits[slot]
-                    self._fail(req, PagePoolExhausted(
-                        f"request {req.request_id}: page pool exhausted "
-                        f"committing {n_acc} accepted "
-                        f"token(s) after {len(req.out_tokens)} tokens "
-                        f"({e.needed} page(s) needed, {e.free_pages} "
-                        f"free)", needed=e.needed,
-                        free_pages=e.free_pages,
-                        request_id=req.request_id,
-                        iteration=self._iteration),
-                        outcome="no_free_pages")
+        # accepted counts are known — only NOW may pages be demanded;
+        # exhaustion fails THAT victim typed (its commit zeroes, nothing
+        # of its iteration lands)
+        for slot in list(emits):
+            req = self._running[slot]
+            try:
+                self.pool.ensure_spec_capacity(slot, int(commit[slot]))
+            except PagePoolExhausted as e:
+                n_acc = int(commit[slot])
+                commit[slot] = 0
+                del emits[slot]
+                self._fail(req, PagePoolExhausted(
+                    f"request {req.request_id}: page pool exhausted "
+                    f"committing {n_acc} accepted "
+                    f"token(s) after {len(req.out_tokens)} tokens "
+                    f"({e.needed} page(s) needed, {e.free_pages} "
+                    f"free)", needed=e.needed,
+                    free_pages=e.free_pages,
+                    request_id=req.request_id,
+                    iteration=self._iteration),
+                    outcome="no_free_pages")
         try:
             with dpxtrace.span("serve.spec.commit", **ids):
                 self.pool.spec_commit(sk, sv, commit)
@@ -1370,11 +1305,11 @@ class InferenceEngine:
         if req.slot is not None:
             # every exit path (retire, deadline, crash drain) runs
             # through here, for a running row and for a request still
-            # prefilling. Paged: page refcounts can never leak —
-            # private pages free immediately, indexed prompt pages stay
-            # resident for future prefix hits. Contiguous: the slot's
-            # length zeroes so the blockwise decode's max(lengths) trip
-            # count stops charging for a request that no longer exists.
+            # prefilling. Page refcounts can never leak: private pages
+            # free immediately, indexed prompt pages stay resident for
+            # future prefix hits; the slot's length zeroes, so the
+            # blockwise decode's max(lengths) trip count stops charging
+            # for a request that no longer exists.
             self.pool.release(req.slot)
             if self._spec is not None:
                 # draft state exits through the same funnel — retire,

@@ -173,8 +173,7 @@ class FleetRouter:
             if rep is None:
                 continue
             st = rep.engine.stats()
-            occ = (st["pages"]["pool_occupancy"] if st["paged"]
-                   else st["active_slots"] / max(st["n_slots"], 1))
+            occ = st["pages"]["pool_occupancy"]
             out[rid] = (st["queue_depth"], occ)
         return out
 
@@ -433,8 +432,7 @@ class FleetRouter:
         depths = []
         for rep in reps:
             st = rep.engine.stats()
-            occ = (st["pages"]["pool_occupancy"] if st["paged"]
-                   else st["active_slots"] / max(st["n_slots"], 1))
+            occ = st["pages"]["pool_occupancy"]
             out[f"fleet.r{rep.rid}.queue_depth"] = float(
                 st["queue_depth"])
             out[f"fleet.r{rep.rid}.pool_occupancy"] = float(occ)

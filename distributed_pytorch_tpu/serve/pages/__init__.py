@@ -8,9 +8,8 @@ once: KV lives in a refcounted block pool (``pool``), full prompt
 pages are keyed in a radix index (``prefix``), and an admitted request
 reuses every resident page of its longest matching prefix — tail-only
 prefill, LRU eviction of refcount-zero pages, typed back-pressure when
-the pool is dry. ``PagedSlotPool`` (``cache``) is the drop-in engine
-substrate; ``EngineConfig(paged=True)`` turns it on. docs/serving.md
-has the layout, lifecycle, and failure model.
+the pool is dry. ``PagedSlotPool`` (``cache``) is the engine's one
+pool. docs/serving.md has the layout, lifecycle, and failure model.
 """
 
 from .cache import PagedSlotPool, chunk_tokens  # noqa: F401

@@ -1,4 +1,4 @@
-"""PagedSlotPool: the paged, prefix-shared drop-in for ``serve.cache.SlotPool``.
+"""PagedSlotPool: the engine's slot pool, paged and prefix-shared.
 
 KV memory is a block pool and each slot addresses its cache through a
 page table row instead of owning a contiguous stripe. What a layer's
@@ -58,12 +58,14 @@ Three things fall out of the indirection:
   allocation raises :class:`~..types.PagePoolExhausted` instead of
   corrupting anything (:mod:`.pool`).
 
-The one-program discipline of ``SlotPool`` is preserved exactly: page
-tables, lengths, offsets and true lengths are all TRACED, so the whole
-serving life is still ONE jitted decode program
+All shapes are static and page tables, lengths, offsets and true
+lengths are all TRACED, so the whole serving life is ONE jitted decode
+program
 (``models.generate.decode_step_slots_paged``) plus one jitted admit per
-chunk-length bucket (``prefill_partial_paged``), counted by the same
-``CompileCounts`` the tests assert on.
+chunk-length bucket (``prefill_partial_paged``), counted by the
+``CompileCounts`` the tests assert on. Slot recycling needs no clearing:
+a freed slot's stale entries are never attended, because the per-row
+position mask only exposes positions its CURRENT occupant wrote.
 
 **Chunked prefill.** An admission is :meth:`PagedSlotPool.begin` (the
 lookup, the refcounts, ALL the prompt's pages: everything that can fail

@@ -1,7 +1,7 @@
 """Where a served token is chosen (the engines share this).
 
 A decode program ends in the greedy token of every slot
-(``SlotPool`` / ``PagedSlotPool``), so a greedy row needs nothing more.
+(``PagedSlotPool``), so a greedy row needs nothing more.
 A row that samples joins the rows of its setting: ONE program a distinct
 ``(temperature, top_k, top_p)`` among the running rows picks all of
 them from the whole ``(n_slots, vocab)`` logits — each row with its own

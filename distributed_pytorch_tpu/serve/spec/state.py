@@ -1,11 +1,13 @@
 """Draft-model state for speculative decoding (``serve/spec/``).
 
-The draft model keeps its own KV in its own contiguous
-:class:`~..cache.SlotPool`, slot-for-slot aligned with the target
-engine's pool: admitting / retiring / crash-draining a target slot
-releases the draft slot through the SAME exit paths, so draft state can
-never leak past its request. The invariant the whole subsystem rests on
-is
+The draft model keeps its keys in a page pool of its own
+(:class:`~..pages.PagedSlotPool`: the target's ``page_len``, no prefix
+sharing, exact pages, and the FULL budget of pages, every slot's worst
+case at once, so the draft can never want for a page and has no failure
+path of its own), slot-for-slot aligned with the target engine's pool:
+admitting / retiring / crash-draining a target slot releases the draft
+slot through the SAME exit paths, so draft state can never leak past its
+request. The invariant the whole subsystem rests on is
 
     draft cache length == target cache length, holding the SAME
     accepted token stream
@@ -14,9 +16,16 @@ is
 steps past the shared current token (the extra step writes the key of
 the last draft so a fully-accepted iteration leaves the draft cache
 complete), and after the target commits ``e`` accepted positions the
-draft ROLLS BACK to ``length + e`` by rewriting its lengths vector from
-the host mirror — the rejected draft suffix simply becomes unreachable
-under the position mask, exactly how slot recycling already works.
+draft ROLLS BACK to ``length + e`` by rewriting its pool's lengths (a
+host array) from the mirror kept here: the rejected draft suffix simply
+becomes unreachable under the position mask, exactly how slot recycling
+already works; the pages it lies in stay the slot's and are written
+again by the next propose.
+
+A slot's draft row is ``draft_len + 1`` positions longer than the
+target's ``max_len``: propose writes ``k + 1`` keys past a row's
+accepted length, and a page table would clamp a position past its last
+page into that page, over keys the row has accepted.
 
 Proposals are argmax (greedy) and consume NO rng, so the request's
 ``jax.random.split`` schedule is untouched — the accepted stream's
@@ -29,10 +38,9 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, List, Optional, Sequence, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
-from ..cache import SlotPool, upload
+from ..pages import PagedSlotPool
 
 
 @dataclass
@@ -50,16 +58,21 @@ class SpecConfig:
 class SpecState:
     """Owns the draft slot pool and the host-side draft bookkeeping."""
 
-    def __init__(self, cfg: SpecConfig, n_slots: int, max_len: int):
+    def __init__(self, cfg: SpecConfig, n_slots: int, max_len: int,
+                 page_len: int):
         if cfg.draft_len < 1:
             raise ValueError(
                 f"draft_len must be >= 1, got {cfg.draft_len}")
         self.cfg = cfg
-        self.pool = SlotPool(cfg.draft_model, n_slots, max_len)
-        # host mirror of the DRAFT truth: ``SlotPool.lengths`` is a
-        # donated device array that propose advances k+1 steps past the
-        # accepted stream — rollback rewrites the device vector from
-        # this mirror (a fresh tiny int32 upload, never a recompile)
+        # room for a propose from a row's last accepted position (the
+        # module's docstring)
+        draft_max = max_len + cfg.draft_len + 1
+        self.pool = PagedSlotPool(
+            cfg.draft_model, n_slots, draft_max, page_len=page_len,
+            n_pages=n_slots * -(-draft_max // page_len),
+            prefix_share=False, kv_dtype="f32")
+        # the DRAFT truth: propose advances the pool's lengths k+1 steps
+        # past the accepted stream, rollback rewrites them from here
         self.len = np.zeros((n_slots,), np.int32)
         #: slot is speculating (draft prefilled and aligned)
         self.active = np.zeros((n_slots,), bool)
@@ -67,24 +80,13 @@ class SpecState:
     # -- lifecycle ---------------------------------------------------------
 
     def admit(self, prompt: np.ndarray, slot: int,
-              buckets: Sequence[int]) -> bool:
-        """Prefill the WHOLE prompt into the draft slot (the admit
-        logits are discarded — the target's admission token is the
-        stream's first token either way). Returns False — request runs
-        non-speculative — when no prefill bucket fits the full prompt
-        (the paged target only needs a bucket for the tail, the draft
-        has no prefix sharing to lean on)."""
-        s = int(prompt.shape[0])
-        bucket = next((b for b in buckets if b >= s), None)
-        if bucket is None or s + 1 > self.pool.max_len:
-            return False
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :s] = prompt
-        self.pool.admit(self.cfg.draft_params, jnp.asarray(padded), s,
-                        slot)
-        self.len[slot] = s
+              buckets: Sequence[int]) -> None:
+        """Prefill the WHOLE prompt into the draft slot, chunk by chunk
+        (the admit logits are discarded — the target's admission token
+        is the stream's first token either way)."""
+        self.pool.admit(self.cfg.draft_params, prompt, slot, tuple(buckets))
+        self.len[slot] = prompt.shape[0]
         self.active[slot] = True
-        return True
 
     def release(self, slot: int) -> None:
         """Every target-slot exit path (retire, typed failure, crash
@@ -107,6 +109,8 @@ class SpecState:
         n = self.pool.n_slots
         active = np.zeros((n,), bool)
         active[np.asarray(slots)] = True
+        for slot in slots:
+            self.pool.ensure_spec_capacity(slot, k + 1)
         toks = np.zeros((n,), np.int32)
         toks[np.asarray(slots)] = cur_tokens
         drafts = np.zeros((len(slots), k), np.int32)
@@ -125,8 +129,7 @@ class SpecState:
         one lengths rewrite."""
         if len(slots):
             self.len[np.asarray(slots)] += np.asarray(commits, np.int32)
-        # a copy of its own: self.len keeps changing under the host
-        self.pool.lengths = upload(self.len)
+        self.pool.lengths[:] = self.len
 
 
 def accept_greedy(drafts: np.ndarray, logits: np.ndarray,

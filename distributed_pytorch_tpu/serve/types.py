@@ -59,9 +59,9 @@ class ServeError(RuntimeError):
 
 class AdmissionRejected(ServeError):
     """The front door refused the request outright — bounded queue
-    full, prompt longer than the largest prefill bucket (the contiguous
-    pool; the paged one prefills in chunks), or a prompt+max_new that
-    cannot fit the slot cache. Raised
+    full, prompt longer than the largest prefill bucket (the
+    disaggregated router; the engine prefills in chunks), or a
+    prompt+max_new that cannot fit the slot cache. Raised
     synchronously from ``submit`` with ``reason`` set."""
 
     def __init__(self, msg: str, *, reason: str = "rejected",

@@ -8,12 +8,12 @@ engine's compile/occupancy stats. Runs on CPU in seconds:
 
     python examples/serve_lm.py [--requests N] [--max-new N]
         [--slots N] [--temperature T] [--metrics-log FILE]
-        [--paged] [--shared-prefix N] [--disagg] [--handoff-width W]
+        [--shared-prefix N] [--disagg] [--handoff-width W]
 
 With --metrics-log, per-request TTFT/TPOT events and periodic engine
 records are appended as line-JSON (the same stream training metrics
-use — utils/logging.MetricsLogger). With --paged the engine runs the
-paged, prefix-shared KV cache (serve/pages/); --shared-prefix N gives
+use — utils/logging.MetricsLogger). The engine runs the paged,
+prefix-shared KV cache (serve/pages/); --shared-prefix N gives
 every request the same N-token "system prompt", so the printed
 per-request records show the prefix pages being computed once and hit
 thereafter (prefix_hit_pages / prefill_tokens_saved). With --disagg
@@ -49,11 +49,9 @@ def parse_args(argv=None):
     p.add_argument("--max-len", type=int, default=128)
     p.add_argument("--temperature", type=float, default=0.8)
     p.add_argument("--metrics-log", type=str, default=None)
-    p.add_argument("--paged", action="store_true",
-                   help="paged, prefix-shared KV cache (serve/pages/)")
     p.add_argument("--shared-prefix", type=int, default=0,
                    help="give every request the same N-token system "
-                        "prompt (shows prefix sharing with --paged)")
+                        "prompt (shows prefix sharing)")
     from distributed_pytorch_tpu.runtime import env as dpxenv
     p.add_argument("--disagg", action="store_true",
                    default=bool(dpxenv.get("DPX_SERVE_DISAGG")),
@@ -82,7 +80,7 @@ def main(argv=None):
         make_engine = lambda: DisaggEngine(model, params, cfg)  # noqa: E731
     else:
         cfg = EngineConfig(n_slots=args.slots, max_len=args.max_len,
-                           metrics=logger, log_every=8, paged=args.paged)
+                           metrics=logger, log_every=8)
         make_engine = lambda: InferenceEngine(model, params, cfg)  # noqa: E731
     rng = np.random.default_rng(0)
     shared = rng.integers(0, 61, (args.shared_prefix,)).astype(np.int32) \
@@ -129,7 +127,7 @@ def main(argv=None):
                          f"{m['decode_ms']:.0f} ms; "
                          f"{m['handoff_bytes']} handoff B, "
                          f"prefix hit {m['prefix_hit_pages']} pages]")
-            elif args.paged:
+            else:
                 line += (f", prefix hit {m['prefix_hit_pages']} pages "
                          f"({m['prefill_tokens_saved']} prefill tokens "
                          f"saved)")
@@ -149,7 +147,7 @@ def main(argv=None):
                   f"{st['decode_compiles']}, prefill compiles "
                   f"{st['prefill_compiles']}, samplers "
                   f"{st['sample_compiles']}")
-        if args.paged and not args.disagg:
+        if not args.disagg:
             ps = st["pages"]
             hr = ps["prefix_hit_rate"]
             print(f"pages: {ps['pages_in_use']}/{ps['n_pages']} in use "

@@ -862,7 +862,7 @@ def test_no_block_pass_is_in_flight_across_an_idle_engine(lm):
 
 def test_every_other_path_refuses_a_block_generator_by_name(lm):
     model, params = lm
-    with pytest.raises(BlockGenerationUnsupported, match="SlotPool"):
+    with pytest.raises(ValueError, match="contiguous slot pool is gone"):
         _engine(model, params, paged=False)
     with pytest.raises(BlockGenerationUnsupported, match="quantized"):
         _engine(model, params, kv_dtype="q8")

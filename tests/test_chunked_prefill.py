@@ -239,8 +239,8 @@ def test_chunks_run_back_to_back_while_no_row_is_running(kv):
 
 
 def test_a_prompt_longer_than_every_bucket_is_served(kv):
-    """``prompt_too_long`` is the contiguous pool's: the paged engine
-    admits whatever its slot row holds."""
+    """No prompt is too long for the buckets: the engine admits whatever
+    its slot row holds."""
     model, params = kv
     prompt = _prompt(100, seed=6)
     with _engine(model, params) as eng:

@@ -4,7 +4,7 @@ bf16 mixed precision, and named remat policies.
 Contracts pinned here:
 
 - the blockwise decode kernel is value-equivalent to the dense
-  full-width softmax it replaces, for contiguous slot rows AND paged
+  full-width softmax it replaces, for contiguous rows AND paged
   pools (GQA, ragged widths, inactive-row write-reselect included);
 - dead blocks past every resident length are NEVER touched — proven by
   NaN-poisoning them (a single gathered element would poison the
@@ -33,8 +33,7 @@ import pytest
 
 import distributed_pytorch_tpu as dist
 from distributed_pytorch_tpu import models, optim
-from distributed_pytorch_tpu.models.generate import (decode_step_slots,
-                                                     decode_step_slots_paged,
+from distributed_pytorch_tpu.models.generate import (decode_step_slots_paged,
                                                      make_generate_fn)
 from distributed_pytorch_tpu.models.transformer import (REMAT_POLICIES,
                                                         resolve_remat)
@@ -54,7 +53,7 @@ SCALE = 0.125  # 1/sqrt(64); tests use Dh in {8, 64} with explicit scale
 
 def _dense_ref(hq, k, v, idx, scale):
     """The dense decode softmax the kernels replace (the exact
-    pre-blockwise math of decode_step_slots)."""
+    pre-blockwise math of a decode step)."""
     b, h, _, dh = hq.shape
     hkv = k.shape[1]
     hq_g = hq.reshape(b, hkv, h // hkv, 1, dh)
@@ -135,8 +134,8 @@ class TestBlockwiseKernel:
     def test_paged_matches_dense_gather_incl_inactive(self):
         """Paged kernel == gather-the-whole-table dense reference, with
         the write-position re-select giving INACTIVE rows (whose pool
-        scatter was dropped) their own key — decode_step_slots' exact
-        value semantics."""
+        scatter was dropped) their own key — a write-mask's exact value
+        semantics."""
         rng = np.random.default_rng(3)
         b, h, hkv, dh, pl, p, n_pages = 3, 4, 2, 8, 8, 6, 13
         hq = _rand(rng, (b, h, 1, dh))
@@ -246,18 +245,24 @@ class TestDecodePathIntegration:
                                      n_heads=4, n_kv_heads=2, pos="rope",
                                      max_seq=512)
         params = model.init(jax.random.PRNGKey(0))
-        b, w = 3, 320    # 3 DECODE_BLOCK-sized blocks when blk=128
+        b, per_row, page = 3, 20, 16     # rows of 320 positions
         dh = model.dim // model.n_heads
         rng = np.random.default_rng(5)
-        ks = [_rand(rng, (b, 2, w, dh)) for _ in range(2)]
-        vs = [_rand(rng, (b, 2, w, dh)) for _ in range(2)]
+        state = [KVPages(ExactSide(_rand(rng, (b * per_row, 2, page, dh))),
+                         ExactSide(_rand(rng, (b * per_row, 2, page, dh))))
+                 for _ in range(2)]
+        tables = jnp.asarray(rng.permutation(b * per_row).reshape(
+            b, per_row), jnp.int32)
         lengths = jnp.asarray([0, 130, 300], jnp.int32)
         tokens = jnp.asarray([1, 2, 3], jnp.int32)
-        lo_b, ks_b, vs_b = decode_step_slots(model, params, ks, vs,
-                                             lengths, tokens)
-        lo_d, ks_d, vs_d = decode_step_slots(model, params, ks, vs,
-                                             lengths, tokens,
-                                             blockwise=False)
+        step = lambda blockwise: decode_step_slots_paged(
+            model, params, state, tables, lengths, tokens,
+            jnp.ones((b,), bool), page_len=page, blockwise=blockwise)
+        (lo_b, st_b), (lo_d, st_d) = step(True), step(False)
+        ks_b, ks_d = ([st.k.rows(tables) for st in sts]
+                      for sts in (st_b, st_d))
+        vs_b, vs_d = ([st.v.rows(tables) for st in sts]
+                      for sts in (st_b, st_d))
         # layer 0's written K/V precede any attention, so they are
         # bit-identical; deeper layers' writes inherit the f32 merge-
         # order difference of the previous layer's attention output
@@ -290,7 +295,7 @@ class TestDecodePathIntegration:
             outs = [eng.submit(p, sp, rng=k).result(timeout=120)
                     for p, k in zip(prompts, keys)]
         assert eng.pool.compiles.decode == 1
-        # retirement releases the slot LENGTH too (SlotPool.release):
+        # retirement releases the slot LENGTH too (the pool's release):
         # a frozen long length would keep max(lengths) — the blockwise
         # trip count — paying for requests that no longer exist
         assert int(jnp.max(eng.pool.lengths)) == 0

@@ -23,7 +23,6 @@ from distributed_pytorch_tpu.nn.rotary import yarn_inv_freq, yarn_mscale
 from distributed_pytorch_tpu.parallel.moe import DroplessMoE
 from distributed_pytorch_tpu.serve import (EngineConfig, InferenceEngine,
                                            SamplingParams)
-from distributed_pytorch_tpu.serve.cache import SlotPool
 from distributed_pytorch_tpu.serve.pages import PagedSlotPool
 
 YARN = dict(factor=64, original_max_position_embeddings=4096, beta_fast=32,
@@ -495,10 +494,10 @@ def test_yarn_frequencies_against_values_computed_by_hand():
 
 def test_refusals_for_latent_blocks(tiny):
     model, params = tiny
-    with pytest.raises(LatentPagesUnsupported, match="SlotPool"):
-        SlotPool(model, 2, 32)
-    with pytest.raises(LatentPagesUnsupported, match="SlotPool"):
-        InferenceEngine(model, params, EngineConfig(n_slots=2, max_len=32))
+    # the engine's one pool serves it: a configuration that does not say
+    # ``paged`` builds latent pages
+    eng = InferenceEngine(model, params, EngineConfig(n_slots=2, max_len=32))
+    assert all(type(st).__name__ == "LatentPages" for st in eng.pool.state)
     for kv in ("q8", "q4"):
         with pytest.raises(LatentPagesUnsupported, match="quantized pages"):
             InferenceEngine(model, params, EngineConfig(

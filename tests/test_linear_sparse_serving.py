@@ -562,8 +562,10 @@ def _refuses(what, make):
     ("speculative decoding", lambda m, p: InferenceEngine(
         m, p, EngineConfig(**ENGINE, spec_decode=True, draft_model=m,
                            draft_params=p))),
-    ("contiguous SlotPool", lambda m, p: InferenceEngine(
-        m, p, EngineConfig(n_slots=2, max_len=72, buckets=(8, 16)))),
+    # a configuration that says nothing of sharing takes the default
+    ("prefix sharing", lambda m, p: InferenceEngine(
+        m, p, EngineConfig(n_slots=2, max_len=72, page_len=4,
+                           buckets=(8, 16)))),
     ("hand-off", lambda m, p: DisaggEngine(m, p, DisaggConfig())),
     ("generate", lambda m, p: make_generate_fn(m, 4)),
     ("hand-off", lambda m, p: new_pool(m).require("export")),

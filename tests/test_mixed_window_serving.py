@@ -245,7 +245,8 @@ def test_the_same_prompt_twice_shares_no_page(lm):
          **ENGINE, spec_decode=True, draft_model=m, draft_params=p))),
     ("the disaggregated hand-off",
      lambda m, p: DisaggEngine(m, p, DisaggConfig(n_slots=2, max_len=96))),
-    ("the contiguous SlotPool",
+    # a configuration that says nothing of sharing takes the default
+    ("prefix sharing",
      lambda m, p: InferenceEngine(m, p, EngineConfig(n_slots=2, max_len=96))),
     ("generate()", lambda m, p: make_generate_fn(m, 4)),
 ])

@@ -288,7 +288,9 @@ BLOCK_LM = dict(vocab=97, n_heads=8, head_dim=8, attn_bias=False,
 @pytest.mark.parametrize("pool,lm,cfg,program,arrays", [
     ("paged", {}, dict(paged=True, page_len=8, buckets=(8, 16)),
      "_decode_fn", 3),
-    ("contiguous", {}, {}, "_decode_fn", 1),
+    # window layers' rings beside no page by table: the same three copies
+    ("rings", dict(layer_windows=(8, 8)),
+     dict(page_len=8, buckets=(8, 16), prefix_share=False), "_decode_fn", 3),
     ("blocks", BLOCK_LM, dict(paged=True, page_len=8, buckets=(8, 16)),
      "_block_fn", 6)])
 def test_a_pass_uploads_under_one_span_before_its_program(pool, lm, cfg,
@@ -334,7 +336,7 @@ def test_a_pass_uploads_under_one_span_before_its_program(pool, lm, cfg,
     # a block pass: the tokens a request's first block opens with (the
     # blocks themselves stay on the device), their count, n_fill
     given = n * 4 * (model.gen_block or 0)
-    held = eng.pool.tables.nbytes + n * 4 if cfg else 0
+    held = eng.pool.tables.nbytes + n * 4
     extra = 2 * n * 4 if model.gen_block else 0
     assert {u["attrs"]["bytes"] for u in ups} == {held + given + n + extra}
     assert stats["host_ns"]["decode_upload"] == eng.pool.upload_ns \

@@ -19,14 +19,14 @@ import numpy as np
 import pytest
 
 from distributed_pytorch_tpu import models, serve
-from distributed_pytorch_tpu.models.generate import (decode_step,
-                                                     decode_step_slots,
-                                                     make_generate_fn,
-                                                     prefill,
-                                                     prefill_partial)
+from distributed_pytorch_tpu.models.generate import (
+    decode_step, decode_step_slots_paged, make_generate_fn, prefill,
+    prefill_partial_paged)
+from distributed_pytorch_tpu.nn.paged import ExactSide, KVPages
 from distributed_pytorch_tpu.runtime import faults
 from distributed_pytorch_tpu.serve import (AdmissionRejected, EngineConfig,
                                            EngineStopped, InferenceEngine,
+                                           PagedSlotPool,
                                            RequestDeadlineExceeded,
                                            SamplingParams)
 from distributed_pytorch_tpu.utils.logging import MetricsLogger
@@ -95,11 +95,42 @@ def _standalone(model, params, prompt, sp, key, max_len=MAX_LEN):
 # ---------------------------------------------------------------------------
 
 
+PAGE = 8      # page_len of the slot-level tests: MAX_LEN is 8 pages
+
+
+def _stores(model, n_pages, n_slots=1, page_len=PAGE):
+    """One empty page store a layer, as the pool makes them."""
+    return [blk.attn.make_pages(n_pages, n_slots, page_len, None,
+                                model.dtype) for blk in model.blocks]
+
+
+def _paged(cache, n_rows=1, rng=None):
+    """``prefill``'s contiguous (1, Hkv, MAX_LEN, Dh) rows cut into pages:
+    a pool of ``n_rows`` rows, row r holding pages ``r * P .. r * P + P -
+    1``, the cache in row 0 and noise (``rng``) in the others. Returns
+    (one KVPages a layer, tables (n_rows, P))."""
+    per_row = MAX_LEN // PAGE
+
+    def cut(row):
+        _, h, _, d = row.shape
+        pages = row[0].reshape(h, per_row, PAGE, d).transpose(1, 0, 2, 3)
+        if n_rows == 1:
+            return ExactSide(pages)
+        noise = jnp.asarray(rng.standard_normal(
+            ((n_rows - 1) * per_row,) + pages.shape[1:]), pages.dtype)
+        return ExactSide(jnp.concatenate([pages, noise]))
+    tables = jnp.arange(n_rows * per_row, dtype=jnp.int32).reshape(
+        n_rows, per_row)
+    return [KVPages(cut(k), cut(v))
+            for k, v in zip(cache.k, cache.v)], tables
+
+
 class TestSlotCacheOps:
     def test_prefill_partial_matches_prefill(self):
         """Right-padding is inert under causality: the logits at the
         last real position pick the same token as an exact-length
-        prefill, and they and the cached K/V prefix agree to a few f32
+        prefill, and they and the K/V written into the row's pages
+        (in table order, which is not the pool's) agree to a few f32
         ulps at their O(1) magnitude (2e-6). The 7-wide and the 16-wide
         programs are two XLA programs that reduce in different orders,
         so bit-identity across them is not a contract; the first
@@ -111,48 +142,94 @@ class TestSlotCacheOps:
         logits, cache = jax.jit(
             lambda p, t: prefill(model, p, t, MAX_LEN))(params, prompt)
         padded = jnp.zeros((1, 16), jnp.int32).at[:, :7].set(prompt)
-        logits_p, ks, vs = jax.jit(
-            lambda p, t, n: prefill_partial(model, p, t, n))(
-            params, padded, 7)
+        table = jnp.asarray([5, 2, 7, 0], jnp.int32)
+        logits_p, state = jax.jit(
+            lambda p, st, tr, t, n: prefill_partial_paged(
+                model, p, st, tr, t, 0, n, page_len=4))(
+            params, _stores(model, 8, page_len=4), table, padded, 7)
         logits, logits_p = np.asarray(logits), np.asarray(logits_p)
         assert logits.argmax() == logits_p.argmax()
         np.testing.assert_allclose(logits, logits_p, rtol=0, atol=2e-6)
+        ks = [np.asarray(st.k.rows(table)) for st in state]
+        vs = [np.asarray(st.v.rows(table)) for st in state]
         np.testing.assert_array_equal(np.asarray(cache.k[0])[:, :, :7],
-                                      np.asarray(ks[0])[:, :, :7])
+                                      ks[0][:, :, :7])
         np.testing.assert_array_equal(np.asarray(cache.v[0])[:, :, :7],
-                                      np.asarray(vs[0])[:, :, :7])
+                                      vs[0][:, :, :7])
         for i in range(model.n_layers):
             np.testing.assert_allclose(
-                np.asarray(cache.k[i])[:, :, :7],
-                np.asarray(ks[i])[:, :, :7], rtol=0, atol=2e-6)
+                np.asarray(cache.k[i])[:, :, :7], ks[i][:, :, :7],
+                rtol=0, atol=2e-6)
             np.testing.assert_allclose(
-                np.asarray(cache.v[i])[:, :, :7],
-                np.asarray(vs[i])[:, :, :7], rtol=0, atol=2e-6)
+                np.asarray(cache.v[i])[:, :, :7], vs[i][:, :, :7],
+                rtol=0, atol=2e-6)
+            # the pad tail was routed out of bounds: nothing past the
+            # prompt was written
+            assert not ks[i][:, :, 7:].any() and not vs[i][:, :, 7:].any()
 
     def test_prefill_partial_window_layout(self):
-        """The gather-built rolling layout (traced true_len) equals
-        prefill's roll-built layout, for prompts shorter AND longer
-        than the window (one compile serves both: true_len is traced)."""
+        """A window layer's ring (position p at ``p % ring``, the last
+        ``ring`` of them; a traced true_len) holds in the first layer,
+        over the window, what prefill's rolling cache holds (position p
+        at ``p % W``) of a model with the same embedding and first
+        attention and the window in its attn_fn; and the logits, and
+        those of a decode step over every layer's ring, are the model's
+        full forward's: for prompts shorter AND longer than the window
+        (one compile serves both)."""
         W = 8
-        model = _windowed_lm(W)
+        model = _lm(vocab=64, layer_windows=(W, W))
         params = model.init(jax.random.PRNGKey(0))
+        rolling = _windowed_lm(W)
+        theirs = rolling.init(jax.random.PRNGKey(1))
+        theirs["tok"] = params["tok"]
+        theirs["blocks"][0].update(attn=params["blocks"][0]["attn"],
+                                   ln1=params["blocks"][0]["ln1"])
         rng = np.random.default_rng(1)
-        partial_fn = jax.jit(
-            lambda p, t, n: prefill_partial(model, p, t, n, window=W))
+        traces = []
+
+        def paged(p, st, t, n):
+            traces.append(1)
+            return prefill_partial_paged(
+                model, p, st, jnp.zeros((8,), jnp.int32), t, 0, n, slot=1,
+                page_len=4)
+        partial_fn = jax.jit(paged)
+        step = jax.jit(lambda p, st, ln, t: decode_step_slots_paged(
+            model, p, st, jnp.zeros((2, 8), jnp.int32), ln, t,
+            jnp.asarray([False, True]), page_len=4))
+        last = lambda seq: np.asarray(model.apply(params, seq)[0, -1])
         for s in (5, 20):
             prompt = jnp.asarray(rng.integers(0, 64, (1, s)), jnp.int32)
-            _, cache = prefill(model, params, prompt, MAX_LEN, window=W)
+            _, cache = prefill(rolling, theirs, prompt, MAX_LEN, window=W)
             padded = jnp.zeros((1, 32), jnp.int32).at[:, :s].set(prompt)
-            _, ks, vs = partial_fn(params, padded, s)
-            for i in range(model.n_layers):
-                np.testing.assert_allclose(np.asarray(cache.k[i]),
-                                           np.asarray(ks[i]), atol=1e-6)
-                np.testing.assert_allclose(np.asarray(cache.v[i]),
-                                           np.asarray(vs[i]), atol=1e-6)
+            got, state = partial_fn(params, _stores(model, 0, 2, 4),
+                                    padded, s)
+            ring = state[0].ring
+            assert ring == 12       # the window in whole pages, and one
+            live = np.arange(max(0, s - W), s)
+            np.testing.assert_allclose(
+                np.asarray(cache.k[0])[0][:, live % W],
+                np.asarray(state[0].k)[1][:, live % ring], atol=1e-6)
+            np.testing.assert_allclose(
+                np.asarray(cache.v[0])[0][:, live % W],
+                np.asarray(state[0].v)[1][:, live % ring], atol=1e-6)
+            assert not any(np.asarray(st.k[0]).any() for st in state)
+            np.testing.assert_allclose(np.asarray(got)[0], last(prompt),
+                                       rtol=0, atol=1e-5)
+            tok = jnp.argmax(got, -1).astype(jnp.int32)
+            after, _ = step(params, state, jnp.asarray([0, s], jnp.int32),
+                            jnp.concatenate([tok, tok]))
+            np.testing.assert_allclose(
+                np.asarray(after)[1],
+                last(jnp.concatenate([prompt, tok[None]], axis=1)),
+                rtol=0, atol=1e-5)
+        assert len(traces) == 1
 
     def test_decode_step_slots_b1_bitwise(self):
-        """At the same batch shape the per-row formulation IS
-        decode_step: logits and cache writes bit-identical."""
+        """At the same batch shape the per-row formulation over pages IS
+        decode_step over the contiguous row: logits and cache writes
+        bit-identical under the dense softmax; under the blockwise one
+        the two walk blocks of different sizes (a page; 128 positions),
+        and the logits agree to a few f32 ulps."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(2)
@@ -160,19 +237,30 @@ class TestSlotCacheOps:
         logits, cache = jax.jit(
             lambda p, t: prefill(model, p, t, MAX_LEN))(params, prompt)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        ref_l, ref_c = jax.jit(
-            lambda p, c, t: decode_step(model, p, c, t))(params, cache, tok)
-        got_l, ks, vs = jax.jit(
-            lambda p, k, v, ln, t: decode_step_slots(model, p, k, v, ln, t))(
-            params, list(cache.k), list(cache.v),
-            jnp.asarray([9], jnp.int32), tok)
-        np.testing.assert_array_equal(np.asarray(ref_l), np.asarray(got_l))
-        for i in range(model.n_layers):
-            np.testing.assert_array_equal(np.asarray(ref_c.k[i]),
-                                          np.asarray(ks[i]))
+        state, tables = _paged(cache)
+        for blockwise in (False, True):
+            ref_l, ref_c = jax.jit(lambda p, c, t: decode_step(
+                model, p, c, t, blockwise=blockwise))(params, cache, tok)
+            got_l, new = jax.jit(
+                lambda p, st, tb, ln, t, a: decode_step_slots_paged(
+                    model, p, st, tb, ln, t, a, page_len=PAGE,
+                    blockwise=blockwise))(
+                params, state, tables, jnp.asarray([9], jnp.int32), tok,
+                jnp.asarray([True]))
+            if blockwise:
+                np.testing.assert_allclose(np.asarray(ref_l),
+                                           np.asarray(got_l), rtol=0,
+                                           atol=2e-6)
+            else:
+                np.testing.assert_array_equal(np.asarray(ref_l),
+                                              np.asarray(got_l))
+            np.testing.assert_array_equal(
+                np.asarray(ref_c.k[0]), np.asarray(new[0].k.rows(tables)))
+            np.testing.assert_array_equal(
+                np.asarray(ref_c.v[0]), np.asarray(new[0].v.rows(tables)))
 
     def test_decode_step_slots_row_isolation(self):
-        """Changing ANOTHER row's cache/token/length leaves a row's
+        """Changing ANOTHER row's pages/token/length leaves a row's
         logits bitwise unchanged — the slot-independence precondition
         of continuous batching."""
         model = _lm()
@@ -181,20 +269,17 @@ class TestSlotCacheOps:
         prompt = jnp.asarray(rng.integers(0, 61, (1, 6)), jnp.int32)
         _, cache = jax.jit(
             lambda p, t: prefill(model, p, t, MAX_LEN))(params, prompt)
-        f = jax.jit(lambda p, k, v, ln, t:
-                    decode_step_slots(model, p, k, v, ln, t))
-
-        def pool(rows):          # garbage pool with the real row at 0
-            return [jnp.asarray(
-                rng.standard_normal((3,) + r.shape[1:]),
-                jnp.float32).at[0:1].set(r) for r in rows]
-
-        k_a, v_a = pool(cache.k), pool(cache.v)
-        k_b = [c.at[1:].add(1.5) for c in k_a]
-        v_b = [c.at[1:].add(-0.5) for c in v_a]
-        la = f(params, k_a, v_a, jnp.asarray([6, 3, 11], jnp.int32),
+        f = jax.jit(lambda p, st, tb, ln, t: decode_step_slots_paged(
+            model, p, st, tb, ln, t, jnp.ones((3,), bool), page_len=PAGE))
+        # a garbage pool with the real row's pages first
+        state_a, tables = _paged(cache, n_rows=3, rng=rng)
+        own = MAX_LEN // PAGE
+        state_b = [KVPages(ExactSide(st.k.pages.at[own:].add(1.5)),
+                           ExactSide(st.v.pages.at[own:].add(-0.5)))
+                   for st in state_a]
+        la = f(params, state_a, tables, jnp.asarray([6, 3, 11], jnp.int32),
                jnp.asarray([7, 1, 2], jnp.int32))[0]
-        lb = f(params, k_b, v_b, jnp.asarray([6, 9, 0], jnp.int32),
+        lb = f(params, state_b, tables, jnp.asarray([6, 9, 0], jnp.int32),
                jnp.asarray([7, 5, 60], jnp.int32))[0]
         np.testing.assert_array_equal(np.asarray(la)[0], np.asarray(lb)[0])
 
@@ -258,27 +343,69 @@ class TestEngine:
         assert admits[3] < retires[0], (admits, retires)  # overlap
 
     def test_windowed_model_rolling_pool(self):
-        """Sliding-window model: slot rows are W wide, generation runs
-        past the window, streams equal standalone generate()."""
-        model = _windowed_lm(8)
+        """A model told a window for every layer: each slot keeps a ring
+        of O(window) a layer whatever ``max_len``, generation runs
+        several times past the window, and the streams are the greedy
+        ones of the model's full forward with no cache (``generate()``
+        refuses a model told its windows)."""
+        W = 8
+        model = _lm(vocab=64, layer_windows=(W, W))
         params = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(1)
-        eng = InferenceEngine(model, params,
-                              EngineConfig(n_slots=2, max_len=32))
-        assert eng.pool.width == 8                # O(window) memory
-        cases = [(4, 20), (20, 16)]
+        eng = InferenceEngine(model, params, EngineConfig(
+            n_slots=2, max_len=64, page_len=4, buckets=(8, 16),
+            prefix_share=False))
+        ring = W + 4                            # whole pages, and one
+        assert [st.k.shape for st in eng.pool.state] == [(2, 2, ring, 8)] * 2
+        pages = eng.stats()["pages"]
+        assert pages["kv_resident_bytes_global"] == 0
+        assert pages["kv_resident_bytes_window"] == \
+            2 * 2 * (2 * 2 * ring * 8 * 4)      # layers, K and V, f32
+        cases = [(4, 40), (20, 36)]             # 5 and 4.5 windows past
         with eng:
-            hs, refs = [], []
-            for i, (s, n) in enumerate(cases):
-                prompt = rng.integers(0, 64, (s,)).astype(np.int32)
-                key = jax.random.PRNGKey(i)
-                sp = SamplingParams(max_new_tokens=n)
-                hs.append(eng.submit(prompt, sp, rng=key))
-                refs.append(np.asarray(jax.jit(make_generate_fn(model, n))(
-                    params, jnp.asarray(prompt[None]), key))[0])
-            for h, ref in zip(hs, refs):
-                np.testing.assert_array_equal(h.result(timeout=60), ref)
+            hs, prompts = [], []
+            for s, n in cases:
+                prompts.append(rng.integers(0, 64, (s,)).astype(np.int32))
+                hs.append(eng.submit(prompts[-1],
+                                     SamplingParams(max_new_tokens=n)))
+            outs = [h.result(timeout=60) for h in hs]
+        for prompt, out in zip(prompts, outs):
+            seq = jnp.asarray(np.concatenate([prompt, out])[None])
+            want = np.asarray(jnp.argmax(model.apply(params, seq)[0], -1))
+            np.testing.assert_array_equal(
+                out, want[len(prompt) - 1:len(prompt) - 1 + len(out)])
         assert eng.stats()["decode_compiles"] == 1
+
+    def test_window_only_in_attn_fn_is_refused_by_name(self):
+        """A width only the attn_fn carries says nothing to the page
+        pool: the engine names what serves such a model."""
+        model = _windowed_lm(8)
+        params = model.init(jax.random.PRNGKey(0))
+        with pytest.raises(ValueError, match=r"only its attn_fn carries.*"
+                           r"layer_windows=\(W,\) \* n_layers"):
+            InferenceEngine(model, params, EngineConfig(n_slots=2,
+                                                        max_len=32))
+
+    def test_default_config_builds_the_page_pool(self):
+        """``EngineConfig()`` and ``EngineConfig(paged=True)`` build the
+        same engine, over the page pool, field for field."""
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        a = InferenceEngine(model, params, EngineConfig())
+        b = InferenceEngine(model, params, EngineConfig(paged=True))
+        assert type(a.pool) is type(b.pool) is PagedSlotPool
+        assert a.buckets == b.buckets
+        for name in ("n_slots", "max_len", "page_len", "n_pages",
+                     "prefix_share", "kv_dtype", "pages_per_slot"):
+            assert getattr(a.pool, name) == getattr(b.pool, name), name
+        assert "pages" in a.stats() and "paged" not in a.stats()
+
+    def test_paged_false_raises_and_names_what_took_its_place(self):
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        with pytest.raises(ValueError, match=r"paged=False.*contiguous slot "
+                           r"pool is gone.*PagedSlotPool.*layer_windows"):
+            InferenceEngine(model, params, EngineConfig(paged=False))
 
     def test_eos_truncates_stream(self):
         """eos_token stops the request early (eos included); the
@@ -321,9 +448,11 @@ class TestEngine:
         params = model.init(jax.random.PRNGKey(0))
         eng = InferenceEngine(model, params,
                               EngineConfig(n_slots=1, max_len=32))
+        # no prompt is too long for its bucket (the pool chunks): a
+        # request is refused for what its slot cannot hold
         with pytest.raises(AdmissionRejected) as ei:
             eng.submit(np.zeros(40, np.int32), SamplingParams())
-        assert ei.value.reason == "prompt_too_long"
+        assert ei.value.reason == "too_long"
         with pytest.raises(AdmissionRejected) as ei:
             eng.submit(np.zeros(20, np.int32),
                        SamplingParams(max_new_tokens=20))
@@ -493,7 +622,7 @@ class TestEngine:
 
         def boom(*a, **k):
             raise RuntimeError("injected engine bug")
-        eng.pool.admit = boom
+        eng.pool.begin = boom
         eng.start()
         h = eng.submit(np.arange(4, dtype=np.int32),
                        SamplingParams(max_new_tokens=4))
@@ -525,35 +654,32 @@ class TestEngine:
 # sampler a setting, one fetch an iteration (serve/sampling.py)
 # ---------------------------------------------------------------------------
 
-POOLS = [pytest.param(dict(paged=False), id="contiguous"),
-         pytest.param(dict(paged=True, page_len=8), id="paged")]
 SP_A = dict(temperature=0.7, top_k=8)
 SP_B = dict(temperature=0.9, top_p=0.9)
 
 
-def _decode_rows(specs, paged):
+def _decode_rows(specs):
     """Which requests of ``specs`` hold a row of the decode program in
     each iteration it runs in, when all were queued before the loop
     started: ``{iteration: [request index, ...]}``. A request's first
     token comes from its prefill, in the iteration ``a`` that admits it,
-    and it decodes from ``a`` to ``a + max_new - 2``. The contiguous pool
-    admits every queued prompt in iteration 1; the paged one prefills ONE
-    chunk an iteration once a row is running, so request ``i`` (a prompt
-    of one chunk) is admitted in iteration ``i + 1``."""
+    and it decodes from ``a`` to ``a + max_new - 2``. The engine prefills
+    ONE chunk an iteration once a row is running, so request ``i`` (a
+    prompt of one chunk) is admitted in iteration ``i + 1``."""
     rows = {}
     for i, (_, sp) in enumerate(specs):
-        a = i + 1 if paged else 1
+        a = i + 1
         for t in range(a, a + sp.max_new_tokens - 1):
             rows.setdefault(t, []).append(i)
     return rows
 
 
-def _sampler_calls(specs, paged):
+def _sampler_calls(specs):
     """The batched sampler programs ``_decode_rows`` implies, in the
     order they are dispatched: ``(iteration, sampler_key, [request
     index, ...])``, one a distinct sampling setting an iteration."""
     calls = []
-    for t, rows in sorted(_decode_rows(specs, paged).items()):
+    for t, rows in sorted(_decode_rows(specs).items()):
         groups = {}
         for i in rows:
             if specs[i][1].temperature > 0:
@@ -562,7 +688,7 @@ def _sampler_calls(specs, paged):
     return calls
 
 
-def _serve_together(model, params, specs, n_slots, record=None, **pool_kw):
+def _serve_together(model, params, specs, n_slots, record=None):
     """Every request of ``specs`` ((prompt_len, SamplingParams) each)
     queued BEFORE the loop starts (``n_slots`` >= their number), so that
     they are admitted in slot order on the schedule ``_decode_rows``
@@ -573,7 +699,7 @@ def _serve_together(model, params, specs, n_slots, record=None, **pool_kw):
                for s, _ in specs]
     keys = [jax.random.PRNGKey(300 + i) for i in range(len(specs))]
     eng = InferenceEngine(model, params, EngineConfig(
-        n_slots=n_slots, max_len=MAX_LEN, **pool_kw))
+        n_slots=n_slots, max_len=MAX_LEN, page_len=8))
     if record is not None:
         build = eng._sampler._build_rows
 
@@ -594,8 +720,7 @@ def _serve_together(model, params, specs, n_slots, record=None, **pool_kw):
 
 
 class TestRowSampling:
-    @pytest.mark.parametrize("pool_kw", POOLS)
-    def test_mixed_batch_bit_identical_one_fetch_an_iteration(self, pool_kw):
+    def test_mixed_batch_bit_identical_one_fetch_an_iteration(self):
         """Greedy rows and rows of two sampling settings in ONE batch:
         every stream is generate()'s, token for token; the tokens of an
         iteration come to the host in one read, and a sampler program
@@ -610,45 +735,42 @@ class TestRowSampling:
                  (7, SamplingParams(max_new_tokens=4, **SP_B)),
                  (12, SamplingParams(max_new_tokens=10, **SP_A))]
         prompts, keys, handles, st = _serve_together(
-            model, params, specs, n_slots=5, **pool_kw)
+            model, params, specs, n_slots=5)
         for i, (h, (_, sp)) in enumerate(zip(handles, specs)):
             np.testing.assert_array_equal(
                 h.result(), _standalone(model, params, prompts[i], sp,
                                         keys[i]), err_msg=f"request {i}")
-        paged = pool_kw["paged"]
         assert [h.metrics["admit_iteration"] for h in handles] == \
-            ([1, 2, 3, 4, 5] if paged else [1] * 5)
-        # the first token of each comes from its admission: contiguous,
-        # 9 decode iterations, setting A in all 9, setting B in the
-        # first 3; paged, the rows start an iteration apart
-        rows = _decode_rows(specs, paged)
-        assert len(rows) == (13 if paged else 9)
+            [1, 2, 3, 4, 5]
+        # the first token of each comes from its admission; the rows
+        # start an iteration apart: the 9 decode iterations of a long
+        # row span 13, setting A's two rows 9 + 2, setting B's 3 + 2
+        rows = _decode_rows(specs)
+        assert len(rows) == 13
         assert st["decode_fetches"] == len(rows), st
-        assert st["sample_dispatches"] == len(_sampler_calls(specs, paged)) \
-            == ((9 + 2) + (3 + 2) if paged else 9 + 3), st
+        assert st["sample_dispatches"] == len(_sampler_calls(specs)) \
+            == (9 + 2) + (3 + 2), st
         assert st["rows_decoded"] == 3 * 9 + 2 * 3, st
         assert st["decode_compiles"] == 1, st
         # three settings admitted, two of them sample in decode
         assert st["sample_compiles"] == 3 + 2, st
 
-    @pytest.mark.parametrize("pool_kw", POOLS)
-    def test_all_greedy_dispatches_no_sampler(self, pool_kw):
+    def test_all_greedy_dispatches_no_sampler(self):
         model = _lm1()
         params = model.init(jax.random.PRNGKey(0))
         record = []
         specs = [(4 + i, SamplingParams(max_new_tokens=3 + 2 * i))
                  for i in range(3)]
         _, _, handles, st = _serve_together(model, params, specs, 3,
-                                            record=record, **pool_kw)
+                                            record=record)
         assert [len(h.result()) for h in handles] == [3, 5, 7]
-        # the longest row's, admitted in iteration 3 of the paged engine
-        assert st["decode_fetches"] == (8 if pool_kw["paged"] else 6), st
+        # the longest row's 6, admitted in iteration 3
+        assert st["decode_fetches"] == 8, st
         assert st["sample_dispatches"] == 0 and record == [], st
         assert st["sample_compiles"] == 1, st     # admission's, greedy
 
     @pytest.mark.parametrize("rows", [1, 2, 4])
-    @pytest.mark.parametrize("pool_kw", POOLS)
-    def test_no_program_follows_the_rows(self, pool_kw, rows):
+    def test_no_program_follows_the_rows(self, rows):
         """One row, half the slots, all of them: one decode program,
         one sampler program a setting at admission and one a sampling
         setting in decode — every shape is n_slots wide."""
@@ -657,20 +779,16 @@ class TestRowSampling:
         settings = [SP_A, SP_B, {}, SP_A][:rows]
         specs = [(4 + i, SamplingParams(max_new_tokens=5, **kw))
                  for i, kw in enumerate(settings)]
-        _, _, _, st = _serve_together(model, params, specs, 4, **pool_kw)
+        _, _, _, st = _serve_together(model, params, specs, 4)
         sampling = len({tuple(kw.items()) for kw in settings if kw})
         assert st["decode_compiles"] == 1, st
         assert st["sample_compiles"] == \
             len({tuple(kw.items()) for kw in settings}) + sampling, st
-        paged = pool_kw["paged"]
-        assert st["decode_fetches"] == len(_decode_rows(specs, paged)) \
-            == (4 + rows - 1 if paged else 4), st
-        assert st["sample_dispatches"] == len(_sampler_calls(specs, paged)), st
-        if not paged:
-            assert st["sample_dispatches"] == 4 * sampling, st
+        assert st["decode_fetches"] == len(_decode_rows(specs)) \
+            == 4 + rows - 1, st
+        assert st["sample_dispatches"] == len(_sampler_calls(specs)), st
 
-    @pytest.mark.parametrize("pool_kw", POOLS)
-    def test_only_rows_that_sample_upload_a_key(self, pool_kw):
+    def test_only_rows_that_sample_upload_a_key(self):
         """What each batched sampler was given: the mask holds exactly
         the running rows of its setting, each with the key of its next
         token (generate()'s split schedule); every other row's key,
@@ -685,12 +803,9 @@ class TestRowSampling:
                  (6, SamplingParams(max_new_tokens=5, **SP_B)),
                  (7, SamplingParams(max_new_tokens=6, **SP_A))]
         _, keys, _, st = _serve_together(model, params, specs, 4,
-                                         record=record, **pool_kw)
-        paged = pool_kw["paged"]
-        calls = _sampler_calls(specs, paged)
+                                         record=record)
+        calls = _sampler_calls(specs)
         assert st["sample_dispatches"] == len(record) == len(calls)
-        if not paged:
-            assert len(calls) == 5 + 4
         splits = [np.asarray(jax.random.split(k, sp.max_new_tokens))
                   for k, (_, sp) in zip(keys, specs)]
         for (key, row_keys, mask), (t, want_key, want) in zip(record, calls):
@@ -699,13 +814,12 @@ class TestRowSampling:
             for slot in range(4):
                 # request ``slot`` was admitted in iteration a: its token
                 # of iteration t has the index t - a + 1
-                step = t - (slot if paged else 0)
+                step = t - slot
                 np.testing.assert_array_equal(
                     row_keys[slot],
                     splits[slot][step] if slot in want else 0)
 
-    @pytest.mark.parametrize("pool_kw", POOLS)
-    def test_failed_row_leaves_co_residents_alone(self, pool_kw):
+    def test_failed_row_leaves_co_residents_alone(self):
         """A row that misses its deadline mid-decode and a row whose
         callback raises: the greedy and the sampled co-resident still
         get generate()'s tokens."""
@@ -713,7 +827,7 @@ class TestRowSampling:
         params = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(7)
         eng = InferenceEngine(model, params, EngineConfig(
-            n_slots=4, max_len=128, **pool_kw))
+            n_slots=4, max_len=128, page_len=8))
         sp_g = SamplingParams(max_new_tokens=12)
         sp_s = SamplingParams(max_new_tokens=12, **SP_A)
         prompts = [rng.integers(0, 61, (5 + i,)).astype(np.int32)

@@ -32,8 +32,10 @@ from distributed_pytorch_tpu.serve import (EngineConfig, EngineStopped,
                                            SamplingParams)
 
 MAX_LEN = 64
-POOLS = [pytest.param(dict(paged=True, page_len=8), id="paged"),
-         pytest.param(dict(paged=False), id="contiguous")]
+# pages of 4: a row claims a page every fourth token, each time with the
+# pass before still unread
+POOLS = [pytest.param(dict(page_len=8), id="paged"),
+         pytest.param(dict(page_len=4), id="pages-of-4")]
 
 
 @pytest.fixture(autouse=True)
@@ -137,16 +139,15 @@ def test_staggered_mixed_streams_are_generates_and_run_ahead(pool_kw):
 
 
 @pytest.mark.parametrize("pool_kw", [
-    pytest.param(dict(paged=True, page_len=4, prefix_share=True),
-                 id="paged-shared"),
-    pytest.param(dict(paged=True, page_len=4, prefix_share=False),
-                 id="paged-unshared"),
-    pytest.param(dict(paged=False), id="contiguous")])
+    pytest.param(dict(page_len=4, prefix_share=True), id="paged-shared"),
+    pytest.param(dict(page_len=4, prefix_share=False), id="paged-unshared"),
+    pytest.param(dict(page_len=32, prefix_share=False), id="one-page")])
 def test_a_row_that_ends_on_eos_is_dropped_and_its_slot_reused(lm, pool_kw):
     """A ends on its ``eos_token`` with its next row-step already in
     flight: that token is in no stream and no callback, the counter
-    counts it, and B, which takes A's only slot (and, paged, its pages;
-    shared: A's first page by the prefix index) streams correctly over
+    counts it, and B, which takes A's only slot and its pages (shared:
+    A's first page by the prefix index; one-page: the one page that held
+    all of A, the stale step's key among B's own) streams correctly over
     what the stale step wrote."""
     model, params = lm
     a = _prompt(6, 31)
@@ -185,8 +186,8 @@ def test_a_row_is_not_run_past_its_last_token(lm, pool_kw, fit):
     """Requests that end by ``max_new_tokens`` alone (``to_max_len``:
     the longest fills its slot row to the last position ``_validate``
     admits): no row-step is dispatched for a token past the last, none
-    is dropped, and the paged pool is asked for a page only for a
-    position that a pass then writes."""
+    is dropped, and the pool is asked for a page only for a position
+    that a pass then writes."""
     model, params = lm
     max_len = 32
     lens = (5, 11, 8)
@@ -196,14 +197,13 @@ def test_a_row_is_not_run_past_its_last_token(lm, pool_kw, fit):
     eng = _engine(model, params, n_slots=3, max_len=max_len, **pool_kw)
     seen = _spy_dispatch(eng)
     asked = []
-    if pool_kw["paged"]:
-        grow = eng.pool.ensure_decode_capacity
+    grow = eng.pool.ensure_decode_capacity
 
-        def spied(slot):
-            asked.append((eng._running[slot].request_id,
-                          int(eng.pool.lengths[slot])))
-            return grow(slot)
-        eng.pool.ensure_decode_capacity = spied
+    def spied(slot):
+        asked.append((eng._running[slot].request_id,
+                      int(eng.pool.lengths[slot])))
+        return grow(slot)
+    eng.pool.ensure_decode_capacity = spied
     hs = [eng.submit(p, sp) for p, sp in zip(prompts, sps)]
     with eng:
         outs = [h.result(timeout=120) for h in hs]
@@ -221,10 +221,9 @@ def test_a_row_is_not_run_past_its_last_token(lm, pool_kw, fit):
     # written is s + max_new - 2, under max_len - 1
     last = {h.request_id: s + n - 2 for h, s, n in zip(hs, lens, news)}
     assert all(pos <= last[rid] < max_len - 1 for rid, pos in asked)
-    if pool_kw["paged"]:
-        assert sorted(asked) == sorted(
-            (h.request_id, pos) for h, s, n in zip(hs, lens, news)
-            for pos in range(s, s + n - 1))
+    assert sorted(asked) == sorted(
+        (h.request_id, pos) for h, s, n in zip(hs, lens, news)
+        for pos in range(s, s + n - 1))
 
 
 # -- (5) a failure between the two programs ----------------------------------
@@ -235,7 +234,7 @@ def test_a_deadline_fails_only_its_row_with_its_step_in_flight(lm):
     decode: the sweep fails A typed with A's row-step in flight (it is
     dropped), and B's sampled stream is bit for bit ``generate()``'s."""
     model, params = lm
-    eng = _engine(model, params, n_slots=2, paged=True, page_len=8).start()
+    eng = _engine(model, params, n_slots=2, page_len=8).start()
     try:
         sp_b = SamplingParams(max_new_tokens=20, temperature=0.7, top_k=8)
         # every program first: a compile must not eat the deadline
@@ -278,8 +277,8 @@ def test_page_pool_exhausted_ahead_fails_only_its_row(lm):
     step in flight is dropped, and A beside it, which grows into one of
     B's freed pages later, streams bit for bit."""
     model, params = lm
-    eng = _engine(model, params, n_slots=2, max_len=16, paged=True,
-                  page_len=4, n_pages=4)
+    eng = _engine(model, params, n_slots=2, max_len=16, page_len=4,
+                  n_pages=4)
     a, b = _prompt(4, 61), _prompt(6, 62)        # 1 page, 2 pages
     sp_a = SamplingParams(max_new_tokens=8)      # a second at 4, third at 8
     sp_b = SamplingParams(max_new_tokens=6)      # a third at position 8
@@ -441,7 +440,7 @@ def test_nothing_is_in_flight_across_an_idle_engine(lm):
     a = _prompt(6, 31)
     full = _standalone(model, params, a, SamplingParams(max_new_tokens=12))
     j = next(j for j in range(2, 10) if full[j] not in full[:j])
-    eng = _engine(model, params, n_slots=2, paged=True, page_len=8)
+    eng = _engine(model, params, n_slots=2, page_len=8)
     seen = _spy_dispatch(eng)
     with eng:
         out = eng.submit(a, SamplingParams(

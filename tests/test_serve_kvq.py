@@ -521,15 +521,17 @@ class TestAdmissionAndConfig:
         pool.release(1)
         assert list(pool.pool.refcount) == refs_before
 
-    def test_non_paged_explicit_kv_dtype_raises(self):
+    def test_explicit_kv_dtype_needs_no_other_word(self):
+        """The engine has one pool, so ``kv_dtype`` alone says how its
+        pages are kept."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
-        with pytest.raises(ValueError, match="paged"):
-            InferenceEngine(model, params,
-                            EngineConfig(kv_dtype="q8", max_len=MAX_LEN))
-        # f32 explicitly is fine (it IS the contiguous pool's contract)
-        InferenceEngine(model, params,
-                        EngineConfig(kv_dtype="f32", max_len=MAX_LEN))
+        eng = InferenceEngine(model, params,
+                              EngineConfig(kv_dtype="q8", max_len=MAX_LEN))
+        assert eng.pool.kv_dtype == "q8" and eng.pool.quant_bits == 8
+        eng = InferenceEngine(model, params,
+                              EngineConfig(kv_dtype="f32", max_len=MAX_LEN))
+        assert eng.pool.kv_dtype == "f32" and eng.pool.quant_bits is None
 
     def test_env_default_drives_paged_pool(self, monkeypatch):
         model = _lm()
@@ -539,12 +541,14 @@ class TestAdmissionAndConfig:
             paged=True, n_slots=2, max_len=MAX_LEN, page_len=L))
         assert eng.pool.kv_dtype == "q8"
         assert eng.pool.quant_bits == 8
-        # non-paged engines ignore the env var (fleet-wide setting must
-        # not break contiguous pools in the same process)
+        # every engine of the process: there is no pool it does not size
         eng2 = InferenceEngine(model, params,
                                EngineConfig(max_len=MAX_LEN))
-        assert not hasattr(eng2.pool, "quant_bits") or \
-            eng2.pool.__class__.__name__ == "SlotPool"
+        assert eng2.pool.kv_dtype == "q8"
+        # and an explicit width wins over it
+        eng3 = InferenceEngine(model, params, EngineConfig(
+            max_len=MAX_LEN, kv_dtype="f32"))
+        assert eng3.pool.quant_bits is None
 
     def test_unknown_kv_dtype_raises(self):
         model = _lm()

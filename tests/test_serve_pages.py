@@ -19,7 +19,7 @@ import pytest
 
 from distributed_pytorch_tpu import models
 from distributed_pytorch_tpu.models.generate import (make_generate_fn,
-                                                     prefill_partial,
+                                                     prefill,
                                                      prefill_partial_paged)
 from distributed_pytorch_tpu.nn.paged import ExactSide, KVPages
 from distributed_pytorch_tpu.runtime import faults
@@ -150,19 +150,18 @@ class TestPagePoolUnits:
 
 class TestPagedOps:
     @pytest.mark.slow
-    def test_cold_paged_prefill_matches_prefill_partial(self):
+    def test_cold_paged_prefill_matches_prefill(self):
         """offset=0 through the paged program computes the same last-
-        position logits as the contiguous prefill_partial (pad tail and
-        fully-masked prefix both causally inert)."""
+        position logits as an exact-length prefill over a contiguous
+        cache (pad tail and fully-masked prefix both causally inert)."""
         model = _lm()
         params = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(0)
         s, bucket, page_len, n_pages = 11, 16, 4, 8
         prompt = rng.integers(0, 61, (s,)).astype(np.int32)
         padded = jnp.zeros((1, bucket), jnp.int32).at[0, :s].set(prompt)
-        ref, _, _ = jax.jit(
-            lambda p, t, n: prefill_partial(model, p, t, n))(
-            params, padded, s)
+        ref, _ = jax.jit(lambda p, t: prefill(model, p, t, MAX_LEN))(
+            params, jnp.asarray(prompt[None]))
         dh = model.dim // model.n_heads
         shape = (n_pages, model.n_kv_heads, page_len, dh)
         kp = [jnp.zeros(shape, model.dtype) for _ in range(model.n_layers)]

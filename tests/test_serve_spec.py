@@ -4,7 +4,7 @@ decoding").
 What must hold (ISSUE 19):
 
 - the GREEDY CONTRACT: the accepted token stream is bit-identical to
-  ``generate()``'s for the contiguous pool, the paged pool, and the
+  ``generate()``'s for the page pool, at two page lengths, and the
   disaggregated split — speculation is a latency optimization, never a
   behavior change. In a quantized (q8) pool the reference is the same
   engine WITHOUT speculation: the pool's argmax stream is whatever the
@@ -20,6 +20,11 @@ What must hold (ISSUE 19):
   a page boundary leaves the next page unallocated and unquantized, a
   partially-filled page stays in the exact f32 tail until an ACCEPTED
   token completes it;
+- the draft model's keys live in a page pool of its own, which holds
+  every slot's worst case at once: a rollback rewinds its lengths and
+  keeps its pages, every exit of a request gives them all back, a
+  prompt of several chunks speculates, and a propose from a row's last
+  position writes past ``max_len`` into room of its own;
 - failures are contained: ``flaky@op=spec_verify`` fails ONLY the
   speculating victim (typed ``SpecDecodeError``, request + iteration +
   stage attributed) while a co-resident non-spec stream stays
@@ -42,11 +47,13 @@ from distributed_pytorch_tpu.models.generate import (generate,
 from distributed_pytorch_tpu.runtime import faults
 from distributed_pytorch_tpu.serve import (AdmissionRejected,
                                            DisaggConfig, DisaggEngine,
-                                           EngineConfig, InferenceEngine,
+                                           EngineConfig, EngineStopped,
+                                           InferenceEngine,
                                            RequestDeadlineExceeded,
                                            SamplingParams,
                                            SpecDecodeError, aggregate)
 from distributed_pytorch_tpu.serve.pages import PagedSlotPool
+from distributed_pytorch_tpu.serve.spec import SpecConfig, SpecState
 
 MAX_LEN = 64
 BUCKETS = (8, 16, 32)
@@ -156,8 +163,8 @@ class TestAcceptGreedy:
 
 class TestGreedyContract:
     @pytest.mark.parametrize("pool_kw", [
-        {}, {"paged": True}, {"paged": True, "kv_dtype": "q8"},
-    ], ids=["contig", "paged", "q8"])
+        {"page_len": 4}, {}, {"kv_dtype": "q8"},
+    ], ids=["pages-of-4", "paged", "q8"])
     def test_stream_matches_reference(self, pool_kw):
         """Spec output == the SAME engine's non-spec output; for exact
         pools that is ``generate()`` itself, for q8 it is a non-spec
@@ -191,6 +198,7 @@ class TestGreedyContract:
             np.testing.assert_array_equal(out, ref)
         st = eng.stats()
         assert st["spec_decode"] is True
+        assert type(eng.pool) is type(eng._spec.pool) is PagedSlotPool
         sp = st["spec"]
         # ONE verify + ONE commit program for the single k+1=4 bucket
         assert sp["verify_compiles"] == {4: 1}
@@ -232,8 +240,8 @@ class TestGreedyContract:
         assert d["prefill_compiles"] == {}     # the split held
 
     @pytest.mark.parametrize("pool_kw", [
-        pytest.param(dict(paged=False), id="contiguous"),
-        pytest.param(dict(paged=True, page_len=L), id="paged")])
+        pytest.param(dict(page_len=4), id="pages-of-4"),
+        pytest.param(dict(page_len=L), id="paged")])
     def test_mixed_spec_and_sampled_batch(self, pool_kw):
         """A speculating (greedy) row and sampled rows of two settings
         share the batch: every stream is bit-identical to its
@@ -267,13 +275,10 @@ class TestGreedyContract:
         assert st["spec"]["proposed"] > 0      # the greedy row DID spec
         # the three rows that sample: 9 decode iterations each, two
         # settings; the speculating row's tokens come from its verify.
-        # The contiguous pool admits all four in iteration 1; the paged
-        # one admits a prompt an iteration, so rows 1-3 decode in
+        # The engine admits a prompt an iteration, so rows 1-3 decode in
         # iterations 2-10, 3-11, 4-12: setting A in 2-12, B in 3-11
-        paged = pool_kw["paged"]
-        assert st["decode_fetches"] == (n + 1 if paged else n - 1), st
-        assert st["sample_dispatches"] == \
-            ((n + 1) + (n - 1) if paged else 2 * (n - 1)), st
+        assert st["decode_fetches"] == n + 1, st
+        assert st["sample_dispatches"] == (n + 1) + (n - 1), st
         assert st["rows_decoded"] == 3 * (n - 1), st
         assert st["decode_compiles"] == 1, st
 
@@ -285,7 +290,7 @@ class TestGreedyContract:
 
 class TestAcceptanceExtremes:
     def test_self_draft_accepts_everything(self):
-        """Draft == target on the SAME (contiguous) pool layout: every
+        """Draft == target on the SAME pool layout: every
         proposal matches, rate is exactly 1.0 and every iteration
         commits k+1 tokens. max_new = 1 + 3*(k+1) so no iteration is
         truncated by the remaining budget."""
@@ -406,6 +411,176 @@ class TestRollbackEdges:
         np.testing.assert_array_equal(out, ref)
         assert len(out) == n
         assert eng.stats()["spec"]["verify_compiles"] == {7: 1}
+
+
+# ---------------------------------------------------------------------------
+# the draft model's page pool
+# ---------------------------------------------------------------------------
+
+
+def _draft_rows(spec, slot):
+    """The draft's resident keys of ``slot``, one (Hkv, positions, Dh)
+    array a layer, in position order."""
+    table = jnp.asarray(spec.pool.tables[slot])
+    return [np.asarray(st.k.rows(table))[0] for st in spec.pool.state]
+
+
+class TestDraftPool:
+    def _state(self, max_len=32, page_len=4, k=3, n_slots=2):
+        dm = _draft()
+        dp = dm.init(jax.random.PRNGKey(1))
+        return SpecState(SpecConfig(dm, dp, draft_len=k), n_slots, max_len,
+                         page_len)
+
+    def test_holds_every_slots_worst_case(self):
+        """``draft_max = max_len + draft_len + 1`` positions a slot, in
+        whole pages, for every slot at once: nothing the draft asks for
+        can be refused."""
+        spec = self._state(max_len=32, page_len=4, k=3, n_slots=2)
+        pool = spec.pool
+        assert type(pool) is PagedSlotPool
+        assert pool.max_len == 36 and pool.pages_per_slot == 9
+        assert pool.n_pages == 18 and not pool.prefix_share
+        assert pool.kv_dtype == "f32" and pool.page_len == 4
+
+    def test_rollback_rewinds_lengths_and_keeps_pages(self):
+        """After a rollback the pool's length is the accepted one, the
+        pages the rejected suffix lies in stay the slot's (the next
+        propose writes them again), and that propose's drafts are those
+        of a draft that was given the accepted stream as its prompt."""
+        spec = self._state()
+        prompt = _prompts()[1][:6]
+        spec.admit(prompt, 1, (8, 16))
+        assert spec.len[1] == spec.pool.lengths[1] == 6
+        assert len(spec.pool.owned[1]) == 2 and spec.active[1]
+        first = spec.propose([1], np.array([5], np.int32))
+        assert spec.pool.lengths[1] == 10        # k + 1 steps ahead
+        owned = list(spec.pool.owned[1])
+        assert len(owned) == 3                   # positions 6 .. 9
+        free = spec.pool.pool.free_pages
+        spec.rollback([1], np.array([2], np.int32))
+        assert spec.len[1] == spec.pool.lengths[1] == 8
+        assert spec.pool.owned[1] == owned
+        assert spec.pool.pool.free_pages == free
+        assert spec.pool.lengths[0] == 0
+        again = spec.propose([1], np.array([9], np.int32))
+        assert spec.pool.owned[1] == owned       # 8 .. 11: no new page
+        fresh = self._state()
+        fresh.admit(np.concatenate([prompt, [5], first[0, :1]])
+                    .astype(np.int32), 0, (8, 16))
+        np.testing.assert_array_equal(
+            again, fresh.propose([0], np.array([9], np.int32)))
+
+    def test_a_propose_from_the_last_position_touches_no_accepted_key(self):
+        """A row two positions short of ``max_len`` (the furthest a row
+        that still has a token to emit can be): the propose's ``k + 1``
+        keys land past ``max_len`` in room of the draft's own, not
+        clamped into the row's last page over keys it has accepted."""
+        spec = self._state(max_len=16, page_len=4, k=3, n_slots=1)
+        spec.admit((np.arange(14, dtype=np.int32) * 7) % 61, 0, (8, 16))
+        before = _draft_rows(spec, 0)
+        spec.propose([0], np.array([3], np.int32))
+        assert spec.pool.lengths[0] == 18 and len(spec.pool.owned[0]) == 5
+        after = _draft_rows(spec, 0)
+        for b, a in zip(before, after):
+            np.testing.assert_array_equal(a[:, :14], b[:, :14])
+            assert np.abs(a[:, 14:18]).sum(axis=(0, 2)).all()  # four keys
+
+    def test_a_row_that_fills_its_slot_speculates_to_the_end(self):
+        """Engine level, a self-draft: prompt + max_new == max_len and
+        the last propose starts two positions short of it (10 + 4 * 5);
+        every proposal is accepted but the last iteration's, which the
+        one token left caps."""
+        model = _lm()
+        params = model.init(jax.random.PRNGKey(0))
+        prompt, n = _prompts()[1][:10], 22       # 10 + 22 == 32
+        ref = np.asarray(generate(model, params, jnp.asarray(prompt[None]),
+                                  n)[0])
+        eng = InferenceEngine(model, params, _spec_cfg(
+            model, params, n_slots=1, max_len=32, page_len=4))
+        starts, propose = [], eng._spec.propose
+
+        def spied(slots, cur):
+            starts.append(int(eng._spec.len[slots[0]]))
+            return propose(slots, cur)
+        eng._spec.propose = spied
+        with eng:
+            out = eng.submit(prompt, SamplingParams(max_new_tokens=n)) \
+                .result(timeout=120)
+        np.testing.assert_array_equal(out, ref)
+        assert starts == [10, 14, 18, 22, 26, 30]
+        st = eng.stats()["spec"]
+        assert (st["proposed"], st["accepted"]) == (18, 15)
+
+    def test_a_prompt_of_several_chunks_speculates(self):
+        """A prompt longer than the largest bucket is admitted to the
+        draft in chunks, as to the target: it speculates, and its stream
+        is ``generate()``'s."""
+        model = _lm()
+        params = model.init(jax.random.PRNGKey(0))
+        dm = _draft()
+        dp = dm.init(jax.random.PRNGKey(1))
+        prompt = (np.arange(3, 24, dtype=np.int32) * 5) % 61    # 21 > 8
+        n = 10
+        ref = np.asarray(generate(model, params, jnp.asarray(prompt[None]),
+                                  n)[0])
+        eng = InferenceEngine(model, params, _spec_cfg(
+            dm, dp, n_slots=2, buckets=(8,), page_len=4))
+        speculating = []
+        with eng:
+            h = eng.submit(prompt, SamplingParams(max_new_tokens=n),
+                           on_token=lambda t, i: speculating.append(
+                               bool(eng._spec.active.any())))
+            out = h.result(timeout=120)
+        np.testing.assert_array_equal(out, ref)
+        st = eng.stats()
+        assert all(speculating) and st["spec"]["proposed"] > 0
+        assert h.metrics["spec_proposed"] == st["spec"]["proposed"]
+        assert st["prefill_chunks"] == 3         # 8 + 8 + 5, the target's
+        assert eng._spec.pool.compiles.prefill == {8: 1}
+
+    @pytest.mark.parametrize("how", ["retire", "failure", "crash"])
+    def test_every_exit_gives_the_drafts_pages_back(self, how):
+        """Retirement, a typed failure (an injected verify fault) and
+        the crash drain each release the draft's slot: its free pages
+        are back to ``n_pages``, no slot active, every length 0."""
+        model = _lm1()
+        params = model.init(jax.random.PRNGKey(0))
+        dm = _draft()
+        dp = dm.init(jax.random.PRNGKey(1))
+        eng = InferenceEngine(model, params, _spec_cfg(
+            dm, dp, n_slots=2, page_len=4))
+        draft = eng._spec.pool
+        held = []
+
+        def on_token(tok, i):
+            if i != 2:
+                return
+            held.append(draft.n_pages - draft.pool.free_pages)
+            if how == "failure":
+                faults.install("flaky@op=spec_verify,count=1")
+            elif how == "crash":
+                eng.crash(RuntimeError("killed"), wait=False)
+        hs = [eng.submit(p, SamplingParams(max_new_tokens=12),
+                         on_token=on_token if i == 0 else None)
+              for i, p in enumerate(_prompts()[:2])]
+        eng.start()
+        try:
+            if how == "retire":
+                for h in hs:
+                    assert len(h.result(timeout=120)) == 12
+            else:
+                for h in hs:
+                    with pytest.raises(SpecDecodeError if how == "failure"
+                                       else EngineStopped):
+                        h.result(timeout=120)
+        finally:
+            eng.shutdown()
+        assert held and held[0] > 0              # it did hold pages
+        assert draft.pool.free_pages == draft.n_pages
+        assert not eng._spec.active.any() and not eng._spec.len.any()
+        assert not draft.lengths.any() and not any(draft.owned)
+        assert eng.pool.pool.live_pages() == 0   # and the target's
 
 
 # ---------------------------------------------------------------------------
@@ -535,11 +710,9 @@ class TestConstruction:
                          DisaggConfig(spec_decode=True))
 
     def test_draft_len_must_be_positive(self):
-        from distributed_pytorch_tpu.serve.spec import (SpecConfig,
-                                                        SpecState)
         model = _lm1()
         params = model.init(jax.random.PRNGKey(0))
         with pytest.raises(ValueError, match="draft_len"):
             SpecState(SpecConfig(draft_model=model,
                                  draft_params=params, draft_len=0),
-                      2, MAX_LEN)
+                      2, MAX_LEN, L)
